@@ -1,0 +1,348 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py                one TPU chip (what the driver runs)
+    python chip_smoke.py --four-chips   the hybrid-parallel path on four
+                                        chips against one; no other phase
+
+One chip: a full-width BERT-base trainer (12 layers, hidden 768, 12 heads,
+FFN 3072, vocab 30522; random weights from a seed) takes 5 ``step`` calls
+and one ``step_many`` of 2 through ``ParallelEngine``, exactly as
+``bench.py`` builds it, and each main-path Pallas kernel runs once against
+its XLA reference at a real width. Every check that fails raises: no phase
+may fail and the script still exit 0, and no kernel gives way to its
+reference. One process, no child that needs the chip.
+
+The last line of standard output is the contract's JSON object. Every
+time printed is a smoke reading on the host's clock, not a metric.
+"""
+
+import argparse
+import gc
+import json
+import math
+import os
+import shutil
+import time
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+BATCH, SEQ = 32, 128
+# MLM + NSP at chance: an untrained model should open near this
+CHANCE_LOSS = math.log(30522) + math.log(2)
+# The same seven optimizer steps (seed 0, the same batch, AdamW 1e-4) on
+# the CPU in f32 with no kernel — the reference the chip's bf16-AMP run is
+# held to. With no warm-up the loss first rises (15.1 at the third step)
+# and is below its start only at the seventh. Regenerate, after a change
+# to the model's init or the optimizer, with
+# tests/test_chip_smoke.py::test_cpu_reference_losses (slow).
+CPU_F32_LOSSES = (11.203659, 12.878224, 15.099131, 11.664064, 12.369730,
+                  11.776111, 10.886946)
+
+
+def check(ok, what):
+    if not ok:
+        raise SystemExit(f"chip_smoke: FAILED: {what}")
+    print(f"chip_smoke: ok: {what}", flush=True)
+
+
+def tpu_devices(need):
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke: found no TPU: JAX's backend is "
+            f"{devs[0].platform!r} ({devs[0].device_kind!r}). This script "
+            "runs on the chip only; it has no CPU path.")
+    if len(devs) < need:
+        raise SystemExit(f"chip_smoke: need {need} TPU chips, JAX reports "
+                         f"{len(devs)}")
+    return devs
+
+
+def build_trainer(devices, degrees, megatron=False, zero_stage=0,
+                  amp_dtype="bfloat16"):
+    """BERT-base + AdamW + ParallelEngine over ``devices``, as
+    bench.py's ``bench_bert_base`` builds it; the same seed gives the
+    same weights and batch to every call."""
+    import paddle1_tpu as paddle
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed import ParallelEngine, build_mesh
+    from paddle1_tpu.text.models import (BertForPretraining,
+                                         BertPretrainingCriterion,
+                                         apply_megatron_sharding, bert_base)
+    paddle.seed(0)
+    model = BertForPretraining(bert_base(
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
+    cfg = model.bert
+    check((cfg.num_hidden_layers, cfg.hidden_size, cfg.num_attention_heads,
+           cfg.intermediate_size, cfg.vocab_size)
+          == (12, 768, 12, 3072, 30522),
+          "BERT-base at full width: 12 layers, hidden 768, 12 heads, "
+          "FFN 3072, vocab 30522")
+    if megatron:
+        apply_megatron_sharding(model)
+    crit = BertPretrainingCriterion(cfg.vocab_size)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
+                                 parameters=model.parameters())
+
+    def loss_fn(m, b):
+        scores, rel = m(Tensor(b["ids"]))
+        return crit(scores, rel, Tensor(b["mlm"]), Tensor(b["nsp"]))
+
+    engine = ParallelEngine(
+        model, opt, loss_fn, mesh=build_mesh(devices=devices, **degrees),
+        zero_stage=zero_stage, amp_dtype=amp_dtype)
+    rng = np.random.default_rng(0)
+    v = cfg.vocab_size
+    batch = {"ids": rng.integers(1, v, (BATCH, SEQ)).astype(np.int32),
+             "mlm": rng.integers(0, v, (BATCH, SEQ)).astype(np.int32),
+             "nsp": rng.integers(0, 2, (BATCH,)).astype(np.int32)}
+    return engine, batch
+
+
+def timed_step(engine, batch):
+    """One ``engine.step`` ended by ``block_until_ready``; (loss, s)."""
+    import jax
+    t0 = time.perf_counter()
+    loss = engine.step(batch)
+    jax.block_until_ready(loss.data)
+    return float(loss), time.perf_counter() - t0
+
+
+def compiled_step_text(engine, batch):
+    """Optimized HLO of the engine's train step at this batch."""
+    import jax
+    import jax.numpy as jnp
+    return engine.train_step_fn.lower(
+        engine.params, engine.opt_state, engine.shard_batch(batch),
+        jax.random.key(0), jnp.asarray(0.0, jnp.float32)
+    ).compile().as_text()
+
+
+def peak_bytes(devices):
+    peaks = []
+    for d in devices:
+        stats = d.memory_stats()
+        check(stats is not None and stats.get("peak_bytes_in_use", 0) > 0,
+              f"device {d.id} memory_stats() reports a peak")
+        peaks.append(int(stats["peak_bytes_in_use"]))
+    return peaks
+
+
+# -- one chip ---------------------------------------------------------------
+
+def trainer_phase(dev):
+    import jax
+    t0 = time.perf_counter()
+    engine, batch = build_trainer([dev], {"dp": 1})
+    print(f"chip_smoke: built the model and the engine in "
+          f"{time.perf_counter() - t0:.1f}s", flush=True)
+    check(all(leaf.devices() == {dev}
+              for leaf in jax.tree_util.tree_leaves(engine.params)),
+          f"every param leaf lives on {dev}")
+
+    losses, secs = zip(*[timed_step(engine, batch) for _ in range(5)])
+    print(f"chip_smoke: step losses {[round(x, 4) for x in losses]}")
+    print(f"chip_smoke: first step (compile included) {secs[0]:.1f}s; "
+          f"later steps {[round(s * 1e3, 1) for s in secs[1:]]} ms "
+          "(smoke reading, not a metric)", flush=True)
+    check(engine.cache_stats() == {"hits": 4, "misses": 1},
+          f"the step compiled once over 5 calls: {engine.cache_stats()}")
+
+    t0 = time.perf_counter()
+    many = engine.step_many([batch, batch])
+    jax.block_until_ready(many.data)
+    many = np.asarray(many)
+    print(f"chip_smoke: step_many(2) losses "
+          f"{[round(float(x), 4) for x in many]} in "
+          f"{time.perf_counter() - t0:.1f}s (its own compile included)")
+    losses = list(losses) + many.tolist()
+    check(engine.trace_count == 2,
+          "one trace for step, one for step_many: trace_count == 2")
+    check(bool(np.all(np.isfinite(losses))), "all 7 losses are finite")
+    check(abs(losses[0] - CHANCE_LOSS) < 1.5,
+          f"first loss {losses[0]:.3f} is within 1.5 of chance "
+          f"{CHANCE_LOSS:.3f}")
+    drift = float(np.max(np.abs(np.asarray(losses) / CPU_F32_LOSSES - 1)))
+    check(drift <= 1e-2,
+          f"all 7 losses agree with the CPU f32 reference run, rtol 1e-2 "
+          f"(largest relative deviation {drift:.2e})")
+    check(losses[6] < losses[0],
+          f"the loss on the repeated batch ends below its start: "
+          f"{losses[0]:.3f} -> {losses[6]:.3f}")
+    check("tpu_custom_call" in compiled_step_text(engine, batch),
+          "the compiled train step holds a tpu_custom_call (the fused "
+          "layer-norm kernel, selected by fused_layer_norm=auto)")
+    print(f"chip_smoke: peak bytes in use {peak_bytes([dev])[0]}")
+
+
+def max_err(got, want):
+    return float(np.max(np.abs(np.asarray(got, np.float32)
+                               - np.asarray(want, np.float32))))
+
+
+def kernel_phase():
+    """Each main-path kernel, compiled for the chip, against its XLA
+    reference. The kernels are called directly — not through the
+    functional layer that may choose the reference instead."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.nn.functional.attention import attention_ref
+    from paddle1_tpu.ops.pallas import (flash_attention, layer_norm,
+                                        paged_attention)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = iter(jax.random.split(jax.random.key(0), 16))
+
+    def ref(fn, *args):
+        # XLA's default f32 matmul on the TPU is one bf16 pass; the
+        # reference has to be better than what it judges
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(fn)(*[a.astype(f32) if a.dtype == bf16 else a
+                                 for a in args])
+
+    # flash attention, forward and the Pallas backward, [8,512,12,64]
+    q, k, v = (jax.random.normal(next(keys), (8, 512, 12, 64), bf16)
+               for _ in range(3))
+    flash = jax.jit(flash_attention.flash_attention)
+    check("tpu_custom_call" in flash.lower(q, k, v).compile().as_text(),
+          "flash forward compiled to a tpu_custom_call")
+    err = max_err(flash(q, k, v), ref(attention_ref, q, k, v))
+    check(err <= 5e-2, f"flash forward [8,512,12,64] bf16 vs XLA "
+                       f"reference: max abs err {err:.2e} <= 5e-2")
+
+    def loss_of(attn):
+        return lambda q, k, v: (attn(q, k, v).astype(f32) ** 2).sum()
+    grad = jax.jit(jax.grad(loss_of(flash_attention.flash_attention),
+                            argnums=(0, 1, 2)))
+    n_calls = grad.lower(q, k, v).compile().as_text().count(
+        "tpu_custom_call")
+    check(n_calls >= 3, f"flash grad compiled to {n_calls} "
+                        "tpu_custom_calls (forward, dq, dk/dv)")
+    want = ref(jax.grad(loss_of(attention_ref), argnums=(0, 1, 2)),
+               q, k, v)
+    for name, g, w in zip(("dq", "dk", "dv"), grad(q, k, v), want):
+        scale = float(np.max(np.abs(np.asarray(w))))
+        err = max_err(g, w) / scale
+        check(err <= 5e-2, f"flash backward {name}: max abs err / max "
+                           f"|ref| = {err:.2e} <= 5e-2")
+
+    # fused layer norm [4096, 768]
+    x = jax.random.normal(next(keys), (4096, 768), bf16)
+    w = jax.random.normal(next(keys), (768,), f32)
+    b = jax.random.normal(next(keys), (768,), f32)
+    ln = jax.jit(layer_norm.fused_layer_norm)
+    check("tpu_custom_call" in ln.lower(x, w, b).compile().as_text(),
+          "layer norm compiled to a tpu_custom_call")
+
+    def ln_ref(x, w, b):
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        return (x - mu) * jax.lax.rsqrt(var + 1e-5) * w + b
+    err = max_err(ln(x, w, b), ref(ln_ref, x, w, b))
+    check(err <= 5e-2, f"layer norm [4096,768] bf16 vs XLA reference: "
+                       f"max abs err {err:.2e} <= 5e-2 (bf16 output)")
+
+    # paged attention at one decode shape: 8 slots, window 1, h12 d64,
+    # pages of 16, ragged lengths, page tables scattered over the pool
+    slots, heads, dim, page, per_slot, pages = 8, 12, 64, 16, 8, 80
+    qd = jax.random.normal(next(keys), (slots, 1, heads, dim), bf16)
+    kp = jax.random.normal(next(keys), (pages, heads, page, dim), bf16)
+    vp = jax.random.normal(next(keys), (pages, heads, page, dim), bf16)
+    rng = np.random.default_rng(0)
+    table = jnp.asarray(rng.permutation(np.arange(1, pages))
+                        [:slots * per_slot].reshape(slots, per_slot),
+                        jnp.int32)
+    base = jnp.asarray(rng.integers(0, page * per_slot - 1, slots),
+                       jnp.int32)
+    check(paged_attention.supported(qd.shape, kp.shape),
+          "paged attention admits the decode shape")
+    paged = jax.jit(paged_attention.paged_attention)
+    check("tpu_custom_call" in paged.lower(
+        qd, kp, vp, table, base).compile().as_text(),
+        "paged attention compiled to a tpu_custom_call")
+    err = max_err(paged(qd, kp, vp, table, base),
+                  ref(paged_attention.paged_attention_ref,
+                      qd, kp, vp, table, base))
+    check(err <= 5e-2, f"paged attention [8,1,12,64] page 16 bf16 vs XLA "
+                       f"gather reference: max abs err {err:.2e} <= 5e-2")
+
+
+# -- four chips -------------------------------------------------------------
+
+def four_chip_phase(devs):
+    """dp=2 x mp=2 with Megatron sharding and zero_stage=2 over four
+    chips, then the same model, seed and batch on one."""
+    import jax
+    four = devs[:4]
+    engine, batch = build_trainer(four, {"dp": 2, "mp": 2}, megatron=True,
+                                  zero_stage=2)
+    sharded = [k for k, s in engine.param_specs.items()
+               if any(ax is not None for ax in s)]
+    check(len(sharded) > 0 and all(
+        len(engine.params[k].sharding.device_set) == 4
+        and not engine.params[k].sharding.is_fully_replicated
+        for k in sharded),
+        f"{len(sharded)} Megatron-sharded params each span four devices")
+    losses4 = [timed_step(engine, batch)[0] for _ in range(3)]
+    print(f"chip_smoke: four-chip losses {losses4}")
+    check(engine.cache_stats()["misses"] == 1,
+          "the four-chip step compiled once")
+    text = compiled_step_text(engine, batch)
+    check("all-reduce" in text,
+          "the compiled four-chip step holds an all-reduce")
+    print("chip_smoke: four-chip step holds a tpu_custom_call: "
+          f"{'tpu_custom_call' in text}")
+    peaks4 = peak_bytes(four)
+    print(f"chip_smoke: four-chip peak bytes per device {peaks4}")
+    del engine, text
+    gc.collect()
+
+    engine, batch = build_trainer(devs[:1], {"dp": 1})
+    losses1 = [timed_step(engine, batch)[0] for _ in range(3)]
+    print(f"chip_smoke: one-chip losses  {losses1}")
+    peak1 = peak_bytes(devs[:1])[0]
+    print(f"chip_smoke: one-chip peak bytes {peak1}")
+    check(bool(np.all(np.isfinite(losses4 + losses1))),
+          "all losses are finite")
+    check(np.allclose(losses4, losses1, rtol=2e-2, atol=0),
+          "per-step losses on four chips agree with one chip, rtol 2e-2")
+    check(max(peaks4) < peak1,
+          f"per-device peak on four chips {max(peaks4)} is below the "
+          f"one-chip peak {peak1}")
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run the four-chip hybrid-parallel path and its "
+                         "one-chip comparison, and no other phase")
+    args = ap.parse_args()
+
+    devs = tpu_devices(4 if args.four_chips else 1)
+    import jax
+    from paddle1_tpu.core import flags as core_flags
+    from paddle1_tpu.core import native
+    core_flags.maybe_enable_compilation_cache(
+        default_dir=os.path.join(REPO, ".jax_cache"))
+    print(f"chip_smoke: device {devs[0].device_kind!r} x{len(devs)}, "
+          f"jax {jax.__version__}")
+    print(f"chip_smoke: compilation cache at "
+          f"{jax.config.jax_compilation_cache_dir}")
+    print(f"chip_smoke: g++ at {shutil.which('g++')}; native host runtime "
+          f"{native.origin()}", flush=True)
+
+    if args.four_chips:
+        four_chip_phase(devs)
+        count = 4
+    else:
+        trainer_phase(devs[0])
+        kernel_phase()
+        count = len(devs)
+    print(json.dumps({"ok": True, "device": {
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": count}}))
+
+
+if __name__ == "__main__":
+    main()

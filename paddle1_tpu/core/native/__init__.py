@@ -1,27 +1,31 @@
 """Native (C++) host runtime: blocking prefetch queue, shared-memory arena,
 stats registry. See src/native.cc for the component map to the reference.
 
-The library builds on first import (g++, ~1s, cached next to the source);
-every consumer has a pure-Python fallback so the framework degrades
-gracefully if no compiler is present.
+The library is never committed: it builds from src/native.cc on first use
+(g++, ~1s) into a file named by the source's hash, so a copied tree can
+neither ship nor pick up a binary of another source. Every consumer has a
+pure-Python fallback; a failed build warns with the compiler's reason and
+the framework runs on the fallbacks.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
-__all__ = ["available", "BoundedQueue", "ShmArena", "stat_add", "stat_set",
-           "stat_get", "stat_dump"]
+__all__ = ["available", "origin", "BoundedQueue", "ShmArena", "stat_add",
+           "stat_set", "stat_get", "stat_dump"]
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "native.cc")
-_LIB_PATH = os.path.join(_HERE, "libpaddle1_native.so")
 _CAPI_SRC = os.path.join(_HERE, "src", "capi.cc")
 _CAPI_LIB = os.path.join(_HERE, "libpaddle1_capi.so")
 _lib = None
+_origin = "unavailable"  # "built" / "loaded" once _load() succeeds
 _build_lock = threading.Lock()
 
 
@@ -52,29 +56,48 @@ def build_capi():
             return None
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"libpaddle1_native.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    # several processes (test workers) may build at once: each compiles
+    # to a name of its own and renames into place, so none loads a file
+    # another is still writing
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _LIB_PATH, "-lrt"]
+           _SRC, "-o", tmp, "-lrt"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        why = getattr(e, "stderr", None) or b""
+        warnings.warn(
+            f"native host runtime not built ({e!r} "
+            f"{why.decode(errors='replace').strip()[-300:]}); using the "
+            "pure-Python fallbacks")
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _load():
-    global _lib
+    global _lib, _origin
     if _lib is not None:
         return _lib
     with _build_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) or (
-                os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
-            if not _build():
-                return None
+        lib_path = _lib_path()
+        built = not os.path.exists(lib_path)
+        if built and not _build(lib_path):
+            return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(lib_path)
         except OSError:
             return None
         # signatures
@@ -120,12 +143,21 @@ def _load():
         lib.stat_dump.argtypes = [ctypes.c_char_p, ctypes.c_int64,
                                   ctypes.POINTER(ctypes.c_int64),
                                   ctypes.c_int64]
+        _origin = "built" if built else "loaded"
         _lib = lib
     return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def origin() -> str:
+    """How this process got the library: ``"built"`` from source just
+    now, ``"loaded"`` from an earlier build of the same source, or
+    ``"unavailable"`` (no compiler — the pure-Python fallbacks run)."""
+    _load()
+    return _origin
 
 
 # ---------------------------------------------------------------------------

@@ -474,7 +474,19 @@ def _c_identity(tensor: Tensor, group: Optional[Group] = None,
     return apply("c_identity", f, (tensor,))
 
 
+def _vary_like(g, vma):
+    """Cast cotangent ``g`` up to the primal's varying manual axes
+    ``vma``: a custom-VJP bwd rule must return the primal's type, and
+    under shard_map that type says over which mesh axes the value
+    differs — a ``psum`` result, or the cotangent of one, varies over
+    fewer than the primal did."""
+    missing = tuple(a for a in vma if a not in jax.typeof(g).vma)
+    return lax.pcast(g, missing, to="varying") if missing else g
+
+
 def _ident_psum_bwd(x, axis):
+    vma = jax.typeof(x).vma
+
     @jax.custom_vjp
     def ident(x):
         return x
@@ -483,13 +495,15 @@ def _ident_psum_bwd(x, axis):
         return x, None
 
     def bwd(_, g):
-        return (lax.psum(g, axis),)
+        return (_vary_like(lax.psum(g, axis), vma),)
 
     ident.defvjp(fwd, bwd)
     return ident(x)
 
 
 def _psum_ident_bwd(x, axis):
+    vma = jax.typeof(x).vma
+
     @jax.custom_vjp
     def red(x):
         return lax.psum(x, axis)
@@ -498,7 +512,7 @@ def _psum_ident_bwd(x, axis):
         return lax.psum(x, axis), None
 
     def bwd(_, g):
-        return (g,)
+        return (_vary_like(g, vma),)
 
     red.defvjp(fwd, bwd)
     return red(x)

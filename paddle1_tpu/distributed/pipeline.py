@@ -99,13 +99,8 @@ def pipeline_apply(stage_fn: Callable, stacked_params, micro_inputs,
 
     buf0 = jnp.zeros_like(micro_inputs[0])
     outs0 = jnp.zeros_like(micro_inputs)
-    _vary = getattr(lax, "pcast", None)
-    if _vary is not None:
-        buf0 = _vary(buf0, (axis_name,), to="varying")
-        outs0 = _vary(outs0, (axis_name,), to="varying")
-    else:  # pragma: no cover - older jax
-        buf0 = lax.pvary(buf0, (axis_name,))
-        outs0 = lax.pvary(outs0, (axis_name,))
+    buf0 = lax.pcast(buf0, (axis_name,), to="varying")
+    outs0 = lax.pcast(outs0, (axis_name,), to="varying")
     (buf, outputs), _ = lax.scan(
         jax.checkpoint(tick), (buf0, outs0), jnp.arange(ticks))
     # broadcast last stage's outputs to every pp rank (so the loss is

@@ -84,7 +84,8 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     acc0 = jnp.zeros((b, h, nl, d), jnp.float32)
     # Mark the running-softmax carries device-varying so the scan carry
     # type matches (k/v rotate, so the whole carry is varying over sp).
-    m0, l0, acc0 = (lax.pvary(x, (axis_name,)) for x in (m0, l0, acc0))
+    m0, l0, acc0 = (lax.pcast(x, (axis_name,), to="varying")
+                    for x in (m0, l0, acc0))
     (_, _, m, l, acc), _ = lax.scan(step, (k, v, m0, l0, acc0),
                                     jnp.arange(n))
     l_safe = jnp.where(l == 0.0, 1.0, l)

@@ -1,4 +1,4 @@
-"""fluid.layers breadth tier 2 (VERDICT r4 item 7): the mechanical
+"""fluid.layers breadth tier 2: the mechanical
 mappings from the reference's 36k-LoC layers surface
 (/root/reference/python/paddle/fluid/layers/{nn,tensor,loss,ops,
 sequence_lod,detection,learning_rate_scheduler,rnn}.py) onto the modern

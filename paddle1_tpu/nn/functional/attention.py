@@ -58,11 +58,11 @@ def use_flash_for(q, k) -> bool:
     attention entry point (sdpa here, ulysses_attention in
     distributed/sequence_parallel.py): ``never`` → False, ``always`` →
     True, ``auto`` → TPU only AND only when the dense path's transient
-    attention memory would threaten HBM headroom. The r5 on-chip
-    crossover sweep (chip_results/flash_crossover.txt) showed XLA's
-    fused dense attention beats the Pallas kernels at every
-    compute-bound length on this backend, so under ``auto`` flash earns
-    its place purely as the long-sequence memory escape.
+    attention memory would threaten HBM headroom. A crossover sweep
+    older than PRs 1-20, on another machine, found XLA's fused dense
+    attention faster at every compute-bound length, so under ``auto``
+    flash earns its place as the long-sequence memory escape; on the
+    v5e the crossover is not measured.
 
     Peak-memory estimate per score element of the dense path: the
     [b, h, sq, sk] logits in the compute dtype, the softmax's f32
@@ -104,7 +104,7 @@ def paged_attention(query, k_pool, v_pool, table, pos, name=None):
     """Decode attention over the block-paged KV pool.
 
     ``query``: [slots, window, heads, dim] — the decode window just
-    written; ``k_pool``/``v_pool``: [pages, page_size, heads, dim]
+    written; ``k_pool``/``v_pool``: [pages, heads, page_size, dim]
     global pools; ``table``: [slots, max_pages_per_slot] int32 page
     table; ``pos``: [slots] int32 per-slot cursor AFTER the window
     write (the cache's advanced ``pos``), so query row ``i`` attends

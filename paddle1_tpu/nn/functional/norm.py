@@ -204,8 +204,7 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
     # NCHW 4-D batch norm participates in the channels-last region
     # (_layout.py): computing with the channel axis last makes the
     # boundary transposes sit directly against the neighboring convs'
-    # and pools', where XLA cancels them (chip_results/conv_probe2.txt)
-    # — and is what makes the input eligible for the fused Pallas
+    # and pools', where XLA cancels them — and is what makes the input eligible for the fused Pallas
     # kernel (ops/pallas/fused_bn.py), which is NHWC-native.
     from ._layout import channels_last_region
     from ...ops.pallas import fused_bn as pbn

@@ -64,8 +64,9 @@ class MultiHeadAttention(Layer):
     # them as it advances.
     GenCache = collections.namedtuple("GenCache", ["k", "v", "pos"])
     # Block-paged serving decode cache (ISSUE 16): k/v are GLOBAL pools
-    # of fixed-size pages — [pages, page_size, heads, dim] — shared by
-    # every slot, with ``table`` ([slots, max_pages_per_slot] int32)
+    # of fixed-size pages — [pages, heads, page_size, dim], heads ahead
+    # of the page rows so the Pallas gather can block one (page, head)
+    # tile — shared by every slot, with ``table`` ([slots, max_pages_per_slot] int32)
     # mapping each slot's logical positions onto pool pages and ``pos``
     # the same per-slot cursor GenCache carries. A slot's HBM footprint
     # is ceil(len/page_size) pages instead of max_seq rows, and slots
@@ -121,12 +122,15 @@ class MultiHeadAttention(Layer):
                 # free/overflowing slots only scribble parking garbage;
                 # the min() clamp keeps the page-table gather in range
                 # for cursors past capacity.
-                ps = pool.shape[1]
+                ps = pool.shape[2]
                 w = new.shape[1]
                 idx = p[:, None] + jnp.arange(w, dtype=p.dtype)[None, :]
                 idx = jnp.minimum(idx, table.shape[1] * ps - 1)
                 pg = jnp.take_along_axis(table, idx // ps, axis=1)
-                return pool.at[pg, idx % ps].set(new.astype(pool.dtype))
+                # the two index arrays straddle the heads slice, so the
+                # indexed result is [S, W, H, D] — ``new``'s own layout
+                return pool.at[pg, :, idx % ps].set(
+                    new.astype(pool.dtype))
 
             k = apply("paged_cache_write_k", write,
                       (cache.k, k, cache.table, cache.pos))
@@ -193,7 +197,7 @@ class MultiHeadAttention(Layer):
         engine owns the real [slots, max_pages_per_slot] table and
         per-slot cursors and substitutes them per dispatch."""
         from ..ops import manip_ops as mo
-        shape = [int(pages), int(page_size), self.num_heads,
+        shape = [int(pages), self.num_heads, int(page_size),
                  self.head_dim]
         return self.PagedCache(mo.zeros(shape, dtype),
                                mo.zeros(shape, dtype),
@@ -383,10 +387,7 @@ class TransformerEncoder(Layer):
         """
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from ..distributed.pipeline import pipeline_apply
 
         axis = self.pipeline_axis

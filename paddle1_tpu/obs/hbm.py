@@ -150,7 +150,9 @@ def registered_bytes() -> Dict[str, int]:
 def device_live_bytes() -> Tuple[int, str]:
     """What the device itself says is alive: ``memory_stats()`` where
     the backend reports it (TPU), else the ``jax.live_arrays()`` sum
-    (CPU/tests). Returns (bytes, source)."""
+    (CPU/tests). Returns (bytes, source). Reads device 0 only: on a
+    mesh of several chips this is the first chip's gauge, never a
+    per-chip figure to quote."""
     import jax
     try:
         stats = jax.devices()[0].memory_stats()
@@ -166,7 +168,8 @@ def device_live_bytes() -> Tuple[int, str]:
 def census() -> Dict[str, object]:
     """The full picture: per-subsystem registered bytes, the device's
     own number, and the coverage ratio the acceptance gate asserts
-    (>= 0.95 = every big consumer is tagged)."""
+    (>= 0.95 = every big consumer is tagged). The device's numbers,
+    peak included, are device 0's (see :func:`device_live_bytes`)."""
     per = registered_bytes()
     total = _physical_total(per)
     dev, source = device_live_bytes()

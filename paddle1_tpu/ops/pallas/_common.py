@@ -11,7 +11,9 @@ _VMEM_BUDGET = 4 * 1024 * 1024  # input block + output block, f32
 
 def interpret() -> bool:
     """Run the kernel in interpreter mode off-TPU so tests exercise the
-    same code path the chip executes."""
+    same code path the chip executes. Every kernel reads this one
+    function at call time (``_common.interpret()``), so a test that
+    compiles for a described chip patches it here."""
     return jax.default_backend() != "tpu"
 
 

@@ -20,15 +20,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import _common
+
 BLOCK_Q = 128
 BLOCK_K = 128
 _LANES = 128  # Mosaic minor-dim tile: scalar-per-row outputs are stored
               # broadcast across one 128-lane register row
 _NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(q_shape, k_shape, causal: bool = False) -> bool:
@@ -162,7 +160,7 @@ def _flash_fwd(q, k, v, scale, causal, padding_mask=None):
             jax.ShapeDtypeStruct((b * h, nq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, _LANES), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(*args)
     out = out.reshape(b, h, nq, d).transpose(0, 2, 1, 3)
     lse = lse[:, :, 0].reshape(b, h, nq)

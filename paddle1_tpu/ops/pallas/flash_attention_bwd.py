@@ -15,9 +15,8 @@ ride a trailing singleton dim ([bh, n, 1]) which satisfies Mosaic's
 (8, 128)-or-equal tiling rule without lane broadcasting.
 
 Gated by core flag ``flash_backward`` — default ``auto`` (engaged on
-TPU) since tools/tpu_kernel_smoke.py validated the Mosaic lowering on a
-real chip (r5, TPU v5 lite: every dq/dk/dv variant bit-exact vs the XLA
-recompute backward — chip_results/kernel_smoke.txt). ``never`` restores
+TPU): chip_smoke.py runs dq/dk/dv on the v5e against the XLA reference
+and tests/test_chip_compile.py compiles them for it. ``never`` restores
 the XLA recompute backward; interpret mode (``always`` off-TPU) does not
 enforce the tiling rules (the forward's LSE layout bug only surfaced on
 hardware).
@@ -31,7 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import BLOCK_K, BLOCK_Q, _NEG_INF, _interpret
+from . import _common
+from .flash_attention import BLOCK_K, BLOCK_Q, _NEG_INF
 
 __all__ = ["flash_attention_bwd", "supported"]
 
@@ -216,7 +216,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((b * h, nk, d), k.dtype),
                    jax.ShapeDtypeStruct((b * h, nk, d), v.dtype)],
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(*args, *mask_arg)
 
     # dq pass
@@ -226,7 +226,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
         in_specs=[qspec, kfull, kfull, qspec, row_q, row_q, *mask_specs],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, nq, d), q.dtype),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(*args, *mask_arg)
 
     back = lambda x: x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)

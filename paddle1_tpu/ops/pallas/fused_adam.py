@@ -20,15 +20,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _common
+
 __all__ = ["fused_adam_update", "supported"]
 
 _COLS = 1024
 _ROWS = 8
 _CHUNK = _COLS * _ROWS
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(n_elements: int) -> bool:
@@ -97,7 +95,7 @@ def fused_adam_update(p, g, m1, m2, lr, step, beta1, beta2, eps, decay):
             jax.ShapeDtypeStruct((rows, _COLS), jnp.float32),
             jax.ShapeDtypeStruct((rows, _COLS), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(scalars, p2, g2, m12, m22)
 
     unflat = lambda a, dt: a.reshape(-1)[:n].reshape(shape).astype(dt)

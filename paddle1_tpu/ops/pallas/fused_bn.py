@@ -4,9 +4,9 @@ TPU-native analog of the reference's fused BN CUDA ops
 (/root/reference/paddle/fluid/operators/fused/fused_bn_activation_op.cu
 and fused_bn_add_activation_op.cu): ONE kernel owns the whole
 stats + normalize + activation (+ residual-add) chain instead of the
-multi-pass XLA lowering the ResNet-50 step trace pins ~46% of on-chip
-time on (multiply_reduce / convert_reduce / multiply_subtract fusions,
-chip_results/resnet_trace_b32.txt).
+multi-pass XLA lowering (multiply_reduce / convert_reduce /
+multiply_subtract fusions; their share of a ResNet-50 step on the v5e:
+not measured).
 
 The training kernel is a two-pass-in-one-call design: a sequential
 (2, row_blocks) grid whose first phase accumulates per-channel
@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import block_rows as _block_rows, interpret as _interpret
+from . import _common
+from ._common import block_rows as _block_rows
 
 __all__ = ["supported", "fused_bn_train", "fused_bn_norm",
            "local_moments", "ACTS"]
@@ -158,7 +159,7 @@ def _train_fwd(x2, g, b, res, eps, act):
             jax.ShapeDtypeStruct((1, c), jnp.float32),
             jax.ShapeDtypeStruct((1, c), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(*args)
     return y, mean.reshape(c), var.reshape(c)
 
@@ -276,7 +277,7 @@ def _norm_fwd(x2, m, v, g, b, res, eps, act):
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((rows, c), x2.dtype),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(*args)
 
 
@@ -382,7 +383,7 @@ def _moments_fwd(x2):
                    pl.BlockSpec((1, c), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(x2)
     return s.reshape(c), ss.reshape(c)
 

@@ -4,8 +4,7 @@ One kernel produces dx, dgamma, dbeta (and dresidual) — the analog of
 the reference's FusedBatchNormActGradKernel: the activation mask, the
 two per-channel reductions (sum dy, sum dy*xhat) and the dx recurrence
 never leave the kernel, where the XLA lowering spends three
-memory-bound passes plus layout copies per BN
-(chip_results/resnet_trace_b32.txt).
+memory-bound passes plus layout copies per BN.
 
 Training-mode dx couples every row to the batch reductions, so the
 kernel mirrors the forward's two-phase sequential grid: phase 0
@@ -28,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import block_rows as _block_rows, interpret as _interpret
+from . import _common
+from ._common import block_rows as _block_rows
 from .fused_bn import supported
 
 __all__ = ["train_bwd", "norm_bwd", "train_bwd_xla", "norm_bwd_xla"]
@@ -42,7 +42,9 @@ def _pallas_bwd_active(shape, dtype) -> bool:
 def _masked_dy(dy_ref, y_ref, act):
     dy = dy_ref[:].astype(jnp.float32)
     if act == "relu":
-        dy = dy * (y_ref[:] > 0).astype(jnp.float32)
+        # compare in f32: the v5e's vector unit has no bf16 comparison,
+        # and Mosaic refuses the kernel rather than widen it
+        dy = dy * (y_ref[:].astype(jnp.float32) > 0).astype(jnp.float32)
     return dy
 
 
@@ -111,7 +113,7 @@ def _train_bwd_pallas(x2, g, mean, var, y2, dy2, eps, act, with_res):
         in_specs=[row_spec, ch_spec, ch_spec, ch_spec, row_spec, row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(x2, g.reshape(1, c), mean.astype(jnp.float32).reshape(1, c),
       var.astype(jnp.float32).reshape(1, c), y2, dy2)
     dx, dg, db = outs[0], outs[1].reshape(c), outs[2].reshape(c)
@@ -206,7 +208,7 @@ def _norm_bwd_pallas(x2, g, mean, var, y2, dy2, eps, act, with_res):
         in_specs=[row_spec, ch_spec, ch_spec, ch_spec, row_spec, row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(x2, g.reshape(1, c), mean.astype(jnp.float32).reshape(1, c),
       var.astype(jnp.float32).reshape(1, c), y2, dy2)
     dx, dg, db = outs[0], outs[1].reshape(c), outs[2].reshape(c)

@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import block_rows as _block_rows, interpret as _interpret
+from . import _common
+from ._common import block_rows as _block_rows
 
 __all__ = ["fused_layer_norm", "supported"]
 
@@ -63,7 +64,7 @@ def _ln_fwd(x2, w, b, eps):
         ],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x2.dtype),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(x2, w.reshape(1, h), b.reshape(1, h))
 
 

@@ -1,7 +1,7 @@
 """Paged-attention gather kernel (decode over the block-paged KV pool).
 
 The paged decode cache (ISSUE 16) keeps K/V in per-layer global pools of
-fixed-size pages — ``[pages, page_size, heads, dim]`` — with a
+fixed-size pages — ``[pages, heads, page_size, dim]`` — with a
 ``[slots, max_pages_per_slot]`` int32 page table mapping each decode
 slot's logical positions onto pool pages. Attention then needs a
 *gather*: slot ``s``'s query window must read pages
@@ -36,22 +36,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _common
+
 _LANES = 128  # Mosaic minor-dim tile (see flash_attention)
 _NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def supported(q_shape, kp_shape) -> bool:
     """Tile-aligned shapes only; everything else uses the ref arm.
-    ``q``: [slots, window, heads, dim]; ``kp``: [pages, page_size,
-    heads, dim]."""
+    ``q``: [slots, window, heads, dim]; ``kp``: [pages, heads,
+    page_size, dim]."""
     if len(q_shape) != 4 or len(kp_shape) != 4:
         return False
     _, w, _, d = q_shape
-    _, ps, _, _ = kp_shape
+    _, _, ps, _ = kp_shape
     if d % 8 or d > 256:
         return False
     if ps % 8:
@@ -65,16 +63,20 @@ def paged_attention_ref(q, kp, vp, table, base,
                         scale: Optional[float] = None):
     """XLA gather arm: materialize each slot's K/V via ``take`` over the
     page table, then masked softmax. q: [S, W, H, D]; kp/vp:
-    [P, ps, H, D]; table: [S, mpps] int32; base: [S] int32 (slot length
+    [P, H, ps, D]; table: [S, mpps] int32; base: [S] int32 (slot length
     before this window). Returns [S, W, H, D]."""
     s_, w, h, d = q.shape
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     mpps = table.shape[1]
     cap = mpps * ps
     sc = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
     flat = table.astype(jnp.int32).reshape(-1)
-    k = jnp.take(kp, flat, axis=0).reshape(s_, cap, h, d)
-    v = jnp.take(vp, flat, axis=0).reshape(s_, cap, h, d)
+
+    def gather(pool):  # [S*mpps, H, ps, D] -> [S, cap, H, D]
+        g = jnp.take(pool, flat, axis=0).reshape(s_, mpps, h, ps, d)
+        return g.transpose(0, 1, 3, 2, 4).reshape(s_, cap, h, d)
+
+    k, v = gather(kp), gather(vp)
     logits = jnp.einsum("swhd,skhd->shwk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * sc
     kpos = jnp.arange(cap, dtype=jnp.int32)
@@ -144,30 +146,34 @@ def paged_attention(q, kp, vp, table, base,
     steers each step's K/V page DMA, scratch carries the online-softmax
     (m, l, acc) across the page axis."""
     s_, w, h, d = q.shape
-    ps = kp.shape[1]
+    ps = kp.shape[2]
     mpps = table.shape[1]
     sc = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
     kernel = functools.partial(_kernel, scale=sc, page_size=ps)
+    # Mosaic takes a block whose last two dims are whole array dims (or
+    # 8/128-aligned), so heads sit ahead of the rows in every operand:
+    # the pools are stored that way, and the query window — a few rows
+    # per slot — is transposed here and back.
+    q = q.transpose(0, 2, 1, 3)  # [S, H, W, D]
     # index maps under scalar-prefetch receive (*grid_idx, *scalar_refs)
-    qspec = pl.BlockSpec((None, w, None, d),
-                         lambda s, hh, j, t, b: (s, 0, hh, 0))
-    pspec = pl.BlockSpec((None, ps, None, d),
-                         lambda s, hh, j, t, b: (t[s, j], 0, hh, 0))
+    qspec = pl.BlockSpec((None, None, w, d),
+                         lambda s, hh, j, t, b: (s, hh, 0, 0))
+    pspec = pl.BlockSpec((None, None, ps, d),
+                         lambda s, hh, j, t, b: (t[s, j], hh, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s_, h, mpps),
             in_specs=[qspec, pspec, pspec],
-            out_specs=pl.BlockSpec((None, w, None, d),
-                                   lambda s, hh, j, t, b: (s, 0, hh, 0)),
+            out_specs=qspec,
             scratch_shapes=[
                 pltpu.VMEM((w, _LANES), jnp.float32),
                 pltpu.VMEM((w, _LANES), jnp.float32),
                 pltpu.VMEM((w, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((s_, w, h, d), q.dtype),
-        interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((s_, h, w, d), q.dtype),
+        interpret=_common.interpret(),
     )(table.astype(jnp.int32), base.astype(jnp.int32), q, kp, vp)
-    return out
+    return out.transpose(0, 2, 1, 3)

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import block_rows as _block_rows, interpret as _interpret
+from . import _common
+from ._common import block_rows as _block_rows
 
 __all__ = ["fused_softmax", "supported"]
 
@@ -53,7 +54,7 @@ def _softmax_fwd(x2):
         in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x2.dtype),
-        interpret=_interpret(),
+        interpret=_common.interpret(),
     )(x2)
 
 

@@ -686,7 +686,7 @@ class GenerationEngine:
         L = bucket
         small = []
         for k_arr, v_arr in kv:
-            H, D = k_arr.shape[2], k_arr.shape[3]
+            H, D = k_arr.shape[1 if self.paged else 2], k_arr.shape[3]
             z = jnp.zeros((1, L, H, D), k_arr.dtype)
             small.append(MultiHeadAttention.GenCache(
                 Tensor(z, stop_gradient=True),
@@ -702,9 +702,9 @@ class GenerationEngine:
             off = jnp.arange(L) % self.page_size
             for (k_arr, v_arr), c in zip(kv, filled):
                 new_kv.append((
-                    k_arr.at[row_pages, off].set(
+                    k_arr.at[row_pages, :, off].set(
                         c.k.data[0].astype(k_arr.dtype)),
-                    v_arr.at[row_pages, off].set(
+                    v_arr.at[row_pages, :, off].set(
                         c.v.data[0].astype(v_arr.dtype))))
         else:
             for (k_arr, v_arr), c in zip(kv, filled):

@@ -1,7 +1,7 @@
 """Host-side KV page accounting for the paged decode cache (ISSUE 16).
 
 The device half of paging is dumb on purpose — per layer, one
-``[pages, page_size, heads, dim]`` pool array plus a ``[slots,
+``[pages, heads, page_size, dim]`` pool array plus a ``[slots,
 max_pages_per_slot]`` int32 page table, both riding the ONE compiled
 decode signature. Everything that must not live in the trace lives
 here: the free list, per-page refcounts, and the copy-on-write prefix

@@ -1,4 +1,4 @@
-"""Regression tests for the ADVICE r1 findings (VERDICT r2 task 7):
+"""Regression tests for the ADVICE r1 findings:
 QAT-under-jit silent collapse, NMS negative-coordinate category offsets,
 box_coder axis semantics, shm create/attach ftruncate discipline, profiler
 cross-thread trace state."""

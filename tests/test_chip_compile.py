@@ -1,0 +1,195 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e, at real
+widths, without a chip: what Mosaic refuses here it refuses on the chip.
+
+The only file that describes the chip. The topology is described inside
+a module-scoped fixture — never at import, in a ``skipif`` or in
+``parametrize`` arguments — because only one process may load the TPU's
+library: under several test workers every worker imports this file, and
+only the one that is handed it may make the call. Nothing runs: there is
+no device to hold an array, so every case lowers ``ShapeDtypeStruct``s.
+A compile that passes is not a chip run.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle1_tpu.core.flags import flags_guard
+from paddle1_tpu.ops.pallas import (_common, flash_attention, fused_adam,
+                                    fused_bn, layer_norm, paged_attention,
+                                    softmax)
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """Kernels out of interpret mode, their ``auto`` backward flags on
+    (``auto`` asks the default backend, which is the CPU here), and the
+    persistent cache off: an executable compiled for a described chip is
+    written to it but cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(_common, "interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with flags_guard(flash_backward="always", fused_bn_bwd="always"):
+            yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _flash(causal=False, masked=False, grad=False):
+    def build(b, s, h, d):
+        qkv = [((b, s, h, d), BF16)] * 3
+        if masked:
+            def fn(q, k, v, m):
+                return flash_attention.flash_attention(
+                    q, k, v, causal=causal, padding_mask=m)
+            args = qkv + [((b, s), F32)]
+        else:
+            def fn(q, k, v):
+                return flash_attention.flash_attention(q, k, v,
+                                                       causal=causal)
+            args = qkv
+        if grad:
+            fwd = fn
+            fn = jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                          argnums=(0, 1, 2))
+        return fn, args
+    return build
+
+
+def _layer_norm():
+    return (layer_norm.fused_layer_norm,
+            [((4096, 768), BF16), ((768,), F32), ((768,), F32)])
+
+
+def _softmax():
+    return softmax.fused_softmax, [((32 * 12 * 128, 128), BF16)]
+
+
+def _adam():
+    def fn(p, g, m1, m2, lr, step):
+        return fused_adam.fused_adam_update(p, g, m1, m2, lr, step,
+                                            0.9, 0.999, 1e-8, 0.01)
+    w = ((768, 3072), F32)
+    return fn, [w, w, w, w, ((), F32), ((), I32)]
+
+
+def _bn(grad):
+    def fwd(x, g, b):
+        return fused_bn.fused_bn_train(x, g, b, 1e-5, act="relu")[0]
+    fn = fwd
+    if grad:
+        fn = jax.grad(lambda *a: fwd(*a).astype(F32).sum(),
+                      argnums=(0, 1, 2))
+    return fn, [((128 * 14 * 14, 256), BF16), ((256,), F32), ((256,), F32)]
+
+
+def _paged(window):
+    slots, heads, dim, pages, page, per_slot = 8, 12, 64, 64, 16, 8
+    pool = ((pages, heads, page, dim), BF16)
+    return (paged_attention.paged_attention,
+            [((slots, window, heads, dim), BF16), pool, pool,
+             ((slots, per_slot), I32), ((slots,), I32)])
+
+
+B32_S128 = (32, 128, 12, 64)
+B8_S512 = (8, 512, 12, 64)
+
+CASES = {
+    "flash_fwd_b32_s128": lambda: _flash()(*B32_S128),
+    "flash_fwd_b8_s512": lambda: _flash()(*B8_S512),
+    "flash_causal_b8_s512": lambda: _flash(causal=True)(*B8_S512),
+    "flash_masked_b32_s128": lambda: _flash(masked=True)(*B32_S128),
+    "flash_grad_b32_s128": lambda: _flash(grad=True)(*B32_S128),
+    "flash_grad_b8_s512": lambda: _flash(grad=True)(*B8_S512),
+    "flash_masked_grad_b32_s128":
+        lambda: _flash(masked=True, grad=True)(*B32_S128),
+    "layer_norm_4096x768": _layer_norm,
+    "softmax_49152x128": _softmax,
+    "adam_768x3072": _adam,
+    "bn_train_fwd_25088x256": lambda: _bn(grad=False),
+    "bn_train_grad_25088x256": lambda: _bn(grad=True),
+    "paged_w1_h12_d64_p16": lambda: _paged(1),
+    "paged_w4_h12_d64_p16": lambda: _paged(4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, for_the_chip):
+    fn, args = CASES[case]()
+    shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+              for s, dt in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_supported_admits_only_what_compiles(one_chip, for_the_chip):
+    """Every shape ``supported()`` admits at the edges of its ranges
+    (narrowest and widest head dim, window and page) must compile."""
+    for w, d, page, dt in [(1, 8, 8, F32), (64, 256, 8, BF16),
+                           (1, 128, 24, BF16), (4, 64, 128, F32)]:
+        q = (8, w, 4, d)
+        pool = (16, 4, page, d)
+        assert paged_attention.supported(q, pool)
+        shapes = [jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+                  for s, t in [(q, dt), (pool, dt), (pool, dt),
+                               ((8, 4), I32), ((8,), I32)]]
+        compiled = jax.jit(paged_attention.paged_attention).lower(
+            *shapes).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gspmd_step_takes_the_xla_composition(topo, for_the_chip,
+                                              monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: on a mesh of four chips
+    an ``auto`` kernel flag must resolve to the XLA composition inside
+    ``auto_partitioned_region`` (where ParallelEngine traces a
+    multi-device step), and the bare kernel is refused."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle1_tpu.core.flags import auto_partitioned_region
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.nn import functional as F
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), ("dp", "mp"))
+    x = jax.ShapeDtypeStruct((4096, 768), BF16,
+                             sharding=NamedSharding(mesh, P("dp", None)))
+    wb = jax.ShapeDtypeStruct((768,), F32,
+                              sharding=NamedSharding(mesh, P()))
+
+    def ln(x, w, b):
+        return F.layer_norm(Tensor(x), 768, Tensor(w), Tensor(b)).data
+
+    def ln_gspmd(x, w, b):
+        with auto_partitioned_region():
+            return ln(x, w, b)
+
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        jax.jit(ln).lower(x, wb, wb)
+    text = jax.jit(ln_gspmd).lower(x, wb, wb).compile().as_text()
+    assert "tpu_custom_call" not in text

@@ -18,10 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle1_tpu.distributed.collective as C
 from paddle1_tpu.core.errors import InvalidArgumentError
@@ -378,14 +375,13 @@ class TestEagerGroupMode:
 
 
 class TestHierarchicalAllReduce:
-    """Functional two-level collective (VERDICT r3 missing #5; reference
+    """Functional two-level collective (reference
     hierarchical_allreduce strategy)."""
 
     def test_matches_flat_psum_on_2x4_mesh(self):
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from paddle1_tpu.distributed.collective import (
             hierarchical_all_reduce)
         devs = np.asarray(jax.devices()[:8]).reshape(2, 4)
@@ -413,7 +409,6 @@ class TestHierarchicalAllReduce:
         import jax
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from paddle1_tpu.distributed.collective import (
             hierarchical_all_reduce)
         devs = np.asarray(jax.devices()[:8]).reshape(2, 4)

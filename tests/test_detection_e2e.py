@@ -1,5 +1,5 @@
 """End-to-end two-stage detection training (the RCNN-family
-composition VERDICT r4 asked the training ops for): backbone → RPN
+composition the training ops exist for): backbone → RPN
 (rpn_target_assign loss + generate_proposals) → proposal sampling
 (generate_proposal_labels) → ROI head (prroi_pool + cls/reg losses)
 → mask head (generate_mask_labels + per-class mask loss). The whole

@@ -1,4 +1,4 @@
-"""Detection training ops (VERDICT r4 missing #4): rpn_target_assign,
+"""Detection training ops: rpn_target_assign,
 generate_proposals, ssd_loss, multi_box_head, deformable_conv.
 
 Numerics pinned against numpy references built from the C++ kernels

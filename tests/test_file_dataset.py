@@ -1,4 +1,4 @@
-"""Out-of-core file datasets (VERDICT r2 missing item 3): InMemoryDataset
+"""Out-of-core file datasets: InMemoryDataset
 load/shuffle semantics, shared-filesystem global shuffle covering all
 trainers disjointly, QueueDataset streaming with bounded memory, and the
 pipe_command filter. Reference fluid/dataset.py + data_feed.cc roles."""

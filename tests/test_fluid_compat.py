@@ -208,7 +208,7 @@ class TestReviewRegressions:
         np.testing.assert_allclose(outs[0], outs[1])
 
     def test_same_line_two_creations_train_distinct_params(self):
-        # reference per-creation semantics (VERDICT r3 weak #7): two
+        # reference per-creation semantics: two
         # textual calls on ONE line are two parameter sets
         x = fluid.dygraph.to_variable(
             np.random.default_rng(3).standard_normal(

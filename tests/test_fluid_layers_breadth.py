@@ -1,7 +1,7 @@
-"""fluid.layers breadth tier 2 (VERDICT r4 item 7): namespace sweep
+"""fluid.layers breadth tier 2: namespace sweep
 pinning coverage counts against the reference surface, plus functional
 spot-checks of the newly mapped groups and the transpiler teaching
-error (VERDICT r3 missing #3)."""
+error."""
 
 import os
 import re

@@ -1,4 +1,4 @@
-"""Fluid tier 7 (VERDICT r4 item 4c): py_func, random_crop,
+"""Fluid tier 7: py_func, random_crop,
 conv3d_transpose, adaptive_pool3d, scatter_nd."""
 
 import numpy as np

@@ -1,4 +1,4 @@
-"""Fluid tier 8 (VERDICT r4 item 4 remainder): ctc_greedy_decoder,
+"""Fluid tier 8: ctc_greedy_decoder,
 similarity_focus, filter_by_instag, reorder_lod_tensor_by_rank,
 load/read_file, inplace_abn, detection_output, box_decoder_and_assign,
 collect_fpn_proposals, locality_aware_nms."""

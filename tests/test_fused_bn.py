@@ -280,13 +280,13 @@ class TestCompiledTrainerIntegration:
 class TestSyncBatchNormFused:
     """SyncBatchNorm reuses the kernel's local-stats pass and keeps its
     cross-replica psum. Pallas calls carry no shard_map replication
-    rule, so the fused variant runs under check_rep=False (any Pallas
+    rule, so the fused variant runs under check_vma=False (any Pallas
     kernel does); grads go through the engine discipline (tape off,
     outer jax.grad)."""
 
     def _run(self, fused):
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle1_tpu import nn
         from paddle1_tpu.distributed.env import spmd_axes
         from paddle1_tpu.autograd import engine as ae
@@ -309,7 +309,7 @@ class TestSyncBatchNormFused:
 
         mapped = shard_map(shard_fn, mesh=mesh,
                            in_specs=(P("data"), P(), P()),
-                           out_specs=P("data"), check_rep=False)
+                           out_specs=P("data"), check_vma=False)
         y = jax.jit(mapped)(jnp.asarray(x), w, b)
         grads = jax.grad(lambda xs, w, b: (mapped(xs, w, b) ** 2).sum(),
                          argnums=(0, 1, 2))(jnp.asarray(x), w, b)

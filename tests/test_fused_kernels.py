@@ -139,7 +139,7 @@ class TestFlashPaddingMask:
         assert np.isfinite(np.asarray(out)).all()
 
     def test_bert_routes_flash_for_bench_shapes(self):
-        """The flagship-path regression VERDICT r2 flagged: BERT's padding
+        """The flagship-path regression: BERT's padding
         mask must not knock attention off the flash path."""
         from paddle1_tpu.ops.pallas import flash_attention as fa
         from paddle1_tpu.nn import functional as F
@@ -420,8 +420,8 @@ class TestFlashBackwardKernels:
                                    rtol=5e-3, atol=5e-3)
 
     def test_flag_default_is_auto(self):
-        # flipped never -> auto after the r5 on-chip smoke passed
-        # (chip_results/kernel_smoke.txt: all bwd variants max_err=0)
+        # auto: chip_smoke.py checks dq/dk/dv on the chip against the
+        # XLA reference, tests/test_chip_compile.py compiles them
         from paddle1_tpu.core.flags import flag
         assert flag("flash_backward") == "auto"
 
@@ -446,9 +446,9 @@ class TestFlashBackwardKernels:
 
 class TestFlashAutoDispatch:
     """r5: flash_attention=auto is memory-adaptive — XLA dense attention
-    below flash_auto_score_mb, Pallas flash above (the on-chip crossover
-    sweep showed dense is faster at every compute-bound length;
-    chip_results/flash_crossover.txt)."""
+    below flash_auto_score_mb, Pallas flash above (a sweep older than
+    PRs 1-20, on another machine, found dense faster at every
+    compute-bound length; not re-measured on the v5e)."""
 
     def _route(self, monkeypatch, b, s, h=4, d=64, threshold_mb=4,
                mode="auto"):

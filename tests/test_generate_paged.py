@@ -279,8 +279,8 @@ class TestPagedParity:
         k = jax.random.split(jax.random.key(0), 4)
         S, W, H, D, NP = 3, 1, 2, 8, 5
         q = jax.random.normal(k[0], (S, W, H, D), "float32")
-        kp = jax.random.normal(k[1], (NP, PS, H, D), "float32")
-        vp = jax.random.normal(k[2], (NP, PS, H, D), "float32")
+        kp = jax.random.normal(k[1], (NP, H, PS, D), "float32")
+        vp = jax.random.normal(k[2], (NP, H, PS, D), "float32")
         table = np.array([[1, 2], [3, 0], [4, 1]], np.int32)
         base = np.array([9, 5, 12], np.int32)
         ref = pa.paged_attention_ref(q, kp, vp, table, base)
@@ -288,6 +288,19 @@ class TestPagedParity:
         out = pa.paged_attention(q, kp, vp, table, base)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    def test_engine_decodes_through_the_kernel(self, lm, paged):
+        # the whole paged engine — pool writes in the heads-first
+        # layout, prefill scatter, decode gather — through the Pallas
+        # arm (interpret mode) gives the tokens of the XLA arm
+        prompt = (np.arange(11) % VOCAB).astype(np.int32)
+        want = _run(paged, 1, prompt, PS + 3, temperature=0.7, top_k=4)
+        with flags_guard(pallas_paged_attention="always"):
+            kern = GenerationEngine(lm, slots=SLOTS, max_seq=MAX_SEQ,
+                                    prefill_buckets=(8, 24), paged=True,
+                                    page_size=PS, prefix_cache=8)
+            got = _run(kern, 1, prompt, PS + 3, temperature=0.7, top_k=4)
+        assert got == want
 
     def test_paged_needs_paged_cache_contract(self):
         class NoPaged:

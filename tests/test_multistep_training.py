@@ -7,6 +7,7 @@ call; Model.fit completes an epoch with zero per-batch readbacks;
 DataLoader prefetch threads shut down cleanly after a broken-out loop;
 bench.py parses its own JSON line."""
 
+import os
 import threading
 import time
 import unittest
@@ -348,6 +349,47 @@ class TestBenchJson(unittest.TestCase):
             bench.parse_result_line('{"metric": "x"}')
         with self.assertRaises(ValueError):
             bench.parse_result_line("not json at all")
+
+
+@pytest.mark.parametrize("env_dir,flag_dir,default_dir,want", [
+    # the environment places the cache: nothing is set in code
+    ("/env/cache", "", "", None),
+    ("/env/cache", "", "DEFAULT", None),
+    ("/env/cache", "FLAG", "", None),          # flag ignored, one warning
+    # unset: the flag, else the entry point's fixed path, else no cache
+    (None, "FLAG", "DEFAULT", "FLAG"),
+    (None, "", "DEFAULT", "DEFAULT"),
+    (None, "", "", None),
+])
+def test_compilation_cache_rule(monkeypatch, tmp_path, env_dir, flag_dir,
+                                default_dir, want):
+    import jax
+    from paddle1_tpu.core import flags as core_flags
+    place = lambda name: str(tmp_path / name) if name else ""
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(core_flags, "_compilation_cache_wired", False)
+    with core_flags.flags_guard(jit_cache_dir=place(flag_dir)):
+        if env_dir and flag_dir:
+            with pytest.warns(UserWarning, match="jit_cache_dir=.*ignored"):
+                did = core_flags.maybe_enable_compilation_cache(
+                    place(default_dir))
+        else:
+            did = core_flags.maybe_enable_compilation_cache(
+                place(default_dir))
+        # idempotent: a second call sets nothing more
+        assert core_flags.maybe_enable_compilation_cache(
+            place(default_dir)) is False
+    assert did is (want is not None)
+    assert updates.get("jax_compilation_cache_dir") == (
+        place(want) if want else None)
+    if want:
+        assert os.path.isdir(place(want))
 
 
 class TestMeshIdentityPassThrough(unittest.TestCase):

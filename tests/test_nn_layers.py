@@ -398,8 +398,7 @@ class TestConvNHWCInternal(OpTest):
         np.testing.assert_allclose(o1, o2, rtol=1e-5, atol=1e-5)
 
     def test_pool_flag_path_matches_nchw(self):
-        # r5: pools joined the channels-last region (NCHW reduce_window
-        # measured ~100x slower on chip — chip_results/conv_probe2.txt)
+        # pools join the channels-last region (_layout.py)
         import numpy as np
         from paddle1_tpu.core.flags import flags_guard
         from paddle1_tpu.core.tensor import to_tensor
@@ -642,8 +641,8 @@ class TestConvBlockLayoutStability(OpTest):
     layout-stable end to end in the channels-last region — only the
     stem/head boundary transposes survive XLA's cancellation, and the
     fused-BN Pallas path (NHWC-native) adds ZERO transposes of its own.
-    This is the CPU-measurable face of the ~15% copy/layout overhead in
-    chip_results/resnet_trace_b32.txt."""
+    This is the CPU-measurable face of copy/layout overhead in the
+    step."""
 
     def _block_hlo_counts(self, fused):
         import warnings
@@ -706,7 +705,7 @@ class TestSyncBatchNorm(OpTest):
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle1_tpu.core.flags import flags_guard
         from paddle1_tpu.core.tensor import Tensor
         from paddle1_tpu.distributed.env import spmd_axes

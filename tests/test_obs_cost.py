@@ -124,6 +124,23 @@ class TestCostModel:
         assert costmodel.device_peak_flops(dev) > 0
         assert costmodel.device_peak_hbm_bw(dev) > 0
 
+    def test_unknown_tpu_kind_is_an_error(self):
+        # no device may borrow another's peaks: a TPU whose kind matches
+        # no row raises, naming the kind
+        class Dev:
+            platform = "tpu"
+            device_kind = "TPU v9 imaginary"
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            costmodel.device_peak_flops(Dev())
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            costmodel.device_peak_hbm_bw(Dev())
+
+        class V5e:
+            platform = "tpu"
+            device_kind = "TPU v5 lite"
+        assert costmodel.device_peak_flops(V5e()) == 197e12
+        assert costmodel.device_peak_hbm_bw(V5e()) == 819e9
+
     def test_summary_gains_flops_column(self, capsys):
         import paddle1_tpu as paddle
         net = paddle.nn.Sequential(paddle.nn.Linear(8, 8))

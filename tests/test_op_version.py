@@ -1,5 +1,5 @@
-"""Op-version / artifact compat registry (VERDICT r3 missing #7;
-reference op_version_registry.h): jit.save artifacts carry versions,
+"""Op-version / artifact compat registry (reference
+op_version_registry.h): jit.save artifacts carry versions,
 loaders refuse newer-runtime artifacts and warn across semantic
 changes."""
 

@@ -1,5 +1,5 @@
 """LARS / Ftrl / AdaDelta numeric checks vs the reference kernel
-formulas (VERDICT r4 missing #6: lars_momentum_op.h, ftrl_op.h,
+formulas (lars_momentum_op.h, ftrl_op.h,
 adadelta_op.h) + the fleet lars/lamb meta-optimizer toggles."""
 
 import numpy as np

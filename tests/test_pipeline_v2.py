@@ -1,4 +1,4 @@
-"""Pipeline parallelism v2 (VERDICT r2 task 5).
+"""Pipeline parallelism v2.
 
 * In-graph path: a real BERT (embeddings + blocks + tied MLM head) trains
   through ParallelEngine at pp=4 on the virtual mesh and matches pp=1

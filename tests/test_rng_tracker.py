@@ -1,4 +1,4 @@
-"""TP dropout RNG tracker (VERDICT r4 missing #5): per-rank streams
+"""TP dropout RNG tracker: per-rank streams
 via meta_parallel.model_parallel_random_seed +
 get_rng_state_tracker().rng_state(), eager and jit."""
 

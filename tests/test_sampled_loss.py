@@ -1,4 +1,4 @@
-"""Sampled large-vocab losses (VERDICT r4 missing #3): nce +
+"""Sampled large-vocab losses: nce +
 sampled_softmax_with_cross_entropy vs numpy references built from the
 kernel formulas (nce_op.h cost loop; sample_logits_op + math/sampler.cc
 probabilities)."""

@@ -8,16 +8,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _sm
+from jax import shard_map as _sm
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _sm_old
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def shard_map(f, mesh, in_specs, out_specs):
+    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 from paddle1_tpu.distributed.sequence_parallel import (ring_attention,
                                                        ulysses_attention)

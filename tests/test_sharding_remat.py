@@ -1,5 +1,5 @@
 """The hybrid dp x mp x ZeRO-2 step must lower without GSPMD's
-"involuntary full rematerialization" fallback (VERDICT r3 weak #3):
+"involuntary full rematerialization" fallback:
 grads reduce-scatter into the slot layout instead of replicate-and-
 repartition. Reference intent: sharding_optimizer.py:146 "reduce rather
 than allreduce"."""

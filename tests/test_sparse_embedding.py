@@ -1,5 +1,5 @@
 """Sparse embedding gradients (IndexedSlices / SelectedRows analog) and the
-host-RAM embedding-table service (scoped PS analog). VERDICT r2 task 4;
+host-RAM embedding-table service (scoped PS analog);
 reference selected_rows.h, adam_op.h SparseAdamFunctor,
 distributed/table/common_sparse_table.h."""
 

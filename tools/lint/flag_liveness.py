@@ -1,6 +1,6 @@
 """Lint pass: every defined flag must have a reader (ISSUE 11).
 
-The VERDICT dead-flag class: ``define_flag("x", ...)`` with validator
+The dead-flag class: ``define_flag("x", ...)`` with validator
 and help text but ZERO consumers — the flag validates, documents, and
 does nothing. This pass cross-references every ``define_flag`` site
 against every *read* across the walked files and fails on a flag

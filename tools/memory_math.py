@@ -1,6 +1,6 @@
 """Published memory math for BASELINE config 4 (ERNIE-1.5B on v5e).
 
-Answers VERDICT r3 weak #5: can full-depth ernie_1p5b (1.637B params)
+Answers: can full-depth ernie_1p5b (1.637B params)
 train on ONE v5e (16 GiB HBM) under the bench's regime (bf16 compute,
 f32 Adam masters, per-block remat)? Run:  python tools/memory_math.py
 

@@ -51,8 +51,8 @@ def main():
                     out = sdpa(Tensor(q), Tensor(q), Tensor(q),
                                is_causal=False)
                 return jnp.sum(out.data.astype(jnp.float32))
-            # scalar output only: downloading dq (25 MB) through the
-            # relay would swamp the op time
+            # scalar output only: downloading dq (25 MB) to the host
+            # would swamp the op time
             g = jax.jit(lambda q: jnp.sum(
                 jax.grad(loss)(q).astype(jnp.float32)))
             return lambda: g(q)
