@@ -1973,8 +1973,8 @@ def bench_cost(on_tpu, steps_override=None):
         overhead = en_bo.best_s / dis_bo.best_s - 1.0
         snap = obs.process_registry().snapshot()
         gauges_ok = all(k in snap["gauges"] for k in
-                        ("train_mfu", "train_hbm_bw_util",
-                         "train_step_flops", "hbm_params_bytes",
+                        ("train_step_flops", "train_step_bytes",
+                         "hbm_params_bytes",
                          "hbm_census_bytes"))
         overhead_ok = disabled_clean and overhead < 0.05 and gauges_ok
 

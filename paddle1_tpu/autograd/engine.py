@@ -17,6 +17,7 @@ reference's BasicEngine that is not kernel dispatch.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import weakref
 from collections import deque
@@ -32,7 +33,8 @@ from ..core.errors import (InvalidArgumentError, PreconditionNotMetError,
 from ..core.tensor import Tensor
 
 __all__ = ["apply", "apply_custom_vjp", "run_backward", "grad", "no_grad",
-           "enable_grad", "is_grad_enabled", "set_grad_enabled", "GradNode"]
+           "enable_grad", "is_grad_enabled", "set_grad_enabled", "GradNode",
+           "traced_scopes"]
 
 _tls = threading.local()
 
@@ -78,6 +80,22 @@ def no_grad(fn=None):
 def enable_grad(fn=None):
     ctx = _GradCtx(True)
     return ctx(fn) if fn is not None else ctx
+
+
+@contextlib.contextmanager
+def traced_scopes():
+    """Name the work while a step is traced: inside, ``Layer.__call__``
+    and :func:`apply` enter ``jax.named_scope`` (the layer's name in its
+    parent, the op's name), so that each instruction of the compiled
+    program carries its layer path and op in ``op_name``. Scopes are
+    trace-time metadata: the instructions themselves do not change.
+    Outside, the eager path pays one attribute test."""
+    was = getattr(_tls, "scopes", False)
+    _tls.scopes = True
+    try:
+        yield
+    finally:
+        _tls.scopes = was
 
 
 def _is_float(x) -> bool:
@@ -166,9 +184,16 @@ def apply(name: str, pure_fn: Callable, tensor_inputs: Sequence[Tensor],
     choke-point all eager ops go through — the TraceOp analog.
     """
     from .. import profiler as _prof
-    if not _prof._enabled:
+    scoped = getattr(_tls, "scopes", False)
+    if not (scoped or _prof._enabled):
         return _apply_impl(name, pure_fn, tensor_inputs, n_outputs, **attrs)
-    with _prof.RecordEvent(name):
+    with contextlib.ExitStack() as stack:
+        if scoped:
+            # every instruction of a traced step carries its op's name
+            # behind the layer path, whichever implementation runs
+            stack.enter_context(jax.named_scope(name))
+        if _prof._enabled:
+            stack.enter_context(_prof.RecordEvent(name))
         return _apply_impl(name, pure_fn, tensor_inputs, n_outputs, **attrs)
 
 
@@ -251,7 +276,11 @@ def apply_custom_vjp(name: str, fwd_fn: Callable, bwd_fn: Callable,
     (fluid/framework/custom_operator.cc) at the tape level.
     """
     arrays = [t.data if isinstance(t, Tensor) else t for t in tensor_inputs]
-    outs, residuals = fwd_fn(*arrays, **attrs)
+    if getattr(_tls, "scopes", False):
+        with jax.named_scope(name):
+            outs, residuals = fwd_fn(*arrays, **attrs)
+    else:
+        outs, residuals = fwd_fn(*arrays, **attrs)
 
     diff_idx = []
     if is_grad_enabled():

@@ -67,6 +67,21 @@ def _count_readback() -> None:
     jit_sanitizer.note_host_sync("loss_readback")
 
 
+def _read_back(arr) -> np.ndarray:
+    """The one device→host fetch of a handle, inside a
+    ``train/readback`` profiler annotation (a no-op outside a profiler
+    session), counted, its duration handed to the observer."""
+    from jax.profiler import TraceAnnotation
+    obs = _observer
+    t0 = time.perf_counter() if obs is not None else 0.0
+    with TraceAnnotation("train/readback"):
+        out = np.asarray(arr)
+    _count_readback()
+    if obs is not None:
+        obs(time.perf_counter() - t0)
+    return out
+
+
 class LossFuture:
     """A loss value still living on device. Reads materialize it.
 
@@ -111,12 +126,7 @@ class LossFuture:
 
     def numpy(self) -> np.ndarray:
         if self._result is None:
-            obs = _observer
-            t0 = time.perf_counter() if obs is not None else 0.0
-            self._result = np.asarray(self._arr)
-            _count_readback()
-            if obs is not None:
-                obs(time.perf_counter() - t0)
+            self._result = _read_back(self._arr)
         return self._result
 
     def item(self) -> float:
@@ -228,12 +238,7 @@ class StepFuture(LossFuture):
 
     def _fetch(self) -> np.ndarray:
         if self._raw is None:
-            obs = _observer
-            t0 = time.perf_counter() if obs is not None else 0.0
-            self._raw = np.asarray(self._arr)
-            _count_readback()
-            if obs is not None:
-                obs(time.perf_counter() - t0)
+            self._raw = _read_back(self._arr)
         return self._raw
 
     def numpy(self) -> np.ndarray:

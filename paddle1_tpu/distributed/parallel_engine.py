@@ -36,11 +36,13 @@ from __future__ import annotations
 import collections
 import functools
 import time
-from typing import Any, Callable, Dict, Optional, Sequence
+from typing import (Any, Callable, Dict, NamedTuple, Optional,
+                    Sequence)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import flags as core_flags
@@ -76,33 +78,17 @@ def _obs_step_registry():
 # eval, GAN pairs) contribute to ONE aggregate instead of clobbering
 # each other with per-engine numbers against a process-wide readback
 # counter
-_obs_thru = {"rb_base": None, "last_t": None, "rate": None,
-             "mfu": None, "bw": None, "peaks": None}
+_obs_thru = {"rb_base": None, "last_t": None, "rate": None}
 
 
-def _obs_peaks():
-    """(peak_flops, peak_hbm_bw) of device 0, taken as every chip's
-    of this process (one host holds one kind of chip) — cached
-    (the cost-model denominators; shared with bench.py's analytic
-    MFU via obs.costmodel's tables)."""
-    st = _obs_thru
-    if st["peaks"] is None:
-        dev = jax.devices()[0]
-        st["peaks"] = (obs_costmodel.device_peak_flops(dev),
-                       obs_costmodel.device_peak_hbm_bw(dev))
-    return st["peaks"]
-
-
-def _obs_note_steps(m, k: int, rows: int, t_now: float,
-                    cost=None) -> None:
+def _obs_note_steps(m, k: int, rows: int, t_now: float) -> None:
     """Feed the throughput gauges after an instrumented dispatch:
-    samples/s as an EWMA over wall time between dispatches,
+    samples/s as an EWMA over wall time between dispatches, and
     steps-per-readback (how well the lazy-loss window amortizes the
-    host round trip — the step_many story in one number), and — when
-    the jit-site cost is known (ISSUE 13) — the per-step cost gauges
-    plus MFU / HBM-bandwidth utilization against the device peaks.
-    Wall-clock MFU is trustworthy once the in-flight window saturates
-    (dispatch run-ahead can inflate the first instants)."""
+    host round trip — the step_many story in one number). Under
+    run-ahead the time between two enqueues is not a step time, so no
+    utilization is derived from it: the benchmark reads those off the
+    device trace."""
     st = _obs_thru
     if st["rb_base"] is None:
         st["rb_base"] = async_loss.readback_count()
@@ -119,29 +105,12 @@ def _obs_note_steps(m, k: int, rows: int, t_now: float,
     total = c.value
     m.gauge("train_steps_per_readback").set(
         total / rb if rb > 0 else float(total))
-    mfu = None
-    if cost is not None and cost.flops:
-        m.gauge("train_step_flops").set(cost.flops)
-        m.gauge("train_step_bytes").set(cost.bytes_accessed)
-        m.gauge("train_cost_exact").set(1.0 if cost.exact else 0.0)
-        if dt is not None:
-            peak_f, peak_bw = _obs_peaks()
-            mfu_i = (k * cost.flops / dt) / peak_f
-            st["mfu"] = mfu_i if st["mfu"] is None else \
-                0.8 * st["mfu"] + 0.2 * mfu_i
-            m.gauge("train_mfu").set(st["mfu"])
-            mfu = st["mfu"]
-            bw_i = (k * cost.bytes_accessed / dt) / peak_bw
-            st["bw"] = bw_i if st["bw"] is None else \
-                0.8 * st["bw"] + 0.2 * bw_i
-            m.gauge("train_hbm_bw_util").set(st["bw"])
     # flight ring first: if the leak detector below raises, the crash
     # dump still holds this step
     fr = obs_flight.recorder()
     if fr is not None:
         fr.note_step(step=total,
                      samples_per_s=round(st["rate"] or 0.0, 2),
-                     mfu=(round(mfu, 4) if mfu is not None else None),
                      hbm_bytes=obs_hbm.last_total())
     # HBM census: per-subsystem registered bytes, sampled (at most
     # once per interval — the walk is O(registered leaves)) and fed
@@ -169,6 +138,32 @@ def _ensure_readback_observer():
                 "train_readback_seconds").observe(dt)
 
     async_loss.set_readback_observer(observe)
+
+
+class StepPhases(NamedTuple):
+    """What the host did in one dispatch of :meth:`ParallelEngine.step`
+    / ``step_many``: one record of the engine's ring
+    (:meth:`ParallelEngine.phase_records`). Times are nanoseconds of
+    ``time.time_ns()``, the clock of ``obs/trace.py``'s spans."""
+
+    step: int                # dispatch_count after this dispatch
+    start_ns: int            # when step() was entered
+    shard_ns: int            # placement inside step() PLUS every
+    #                          shard_batch() call since the last record
+    #                          (a trainer that places its batch itself
+    #                          makes step()'s own call a pass-through)
+    guard_ns: int            # retrace guard, sanitizer bookkeeping
+    dispatch_ns: int         # the jit call (trace + compile when
+    #                          ``compiled``), LR schedule step
+    inflight_wait_ns: int    # blocked on the oldest outstanding step
+    compiled: bool           # the dispatch traced a new executable
+    shard_calls: int         # how many placements ``shard_ns`` sums
+    k: int                   # optimizer steps in the dispatch
+
+
+PHASE_RING = 4096            # records kept: hours of steps do not grow it
+_PHASES = ("train/shard", "train/guard", "train/dispatch",
+           "train/inflight_wait")
 
 
 def _as_arrays(batch):
@@ -223,22 +218,28 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
             # bf16 autocast: compute params in bf16, masters stay f32 in
             # the optimizer (reference pure-fp16 mode, fp16_utils.py:322)
             cdt = jnp.dtype(amp_dtype)
-            params = {k: (v.astype(cdt)
-                          if jnp.issubdtype(v.dtype, jnp.floating) else v)
-                      for k, v in params.items()}
-            # feeds too (reference pure-fp16 casts the feed vars as well,
-            # fp16_utils.py cast_model_to_fp16): f32 images x bf16 conv
-            # weights is a dtype error on TPU
-            batch = jax.tree_util.tree_map(
-                lambda a: a.astype(cdt)
-                if (hasattr(a, "dtype")
-                    and jnp.issubdtype(a.dtype, jnp.floating)) else a,
-                batch)
+            with jax.named_scope("amp_cast"):
+                params = {k: (v.astype(cdt)
+                              if jnp.issubdtype(v.dtype, jnp.floating)
+                              else v)
+                          for k, v in params.items()}
+                # feeds too (reference pure-fp16 casts the feed vars as
+                # well, fp16_utils.py cast_model_to_fp16): f32 images x
+                # bf16 conv weights is a dtype error on TPU
+                batch = jax.tree_util.tree_map(
+                    lambda a: a.astype(cdt)
+                    if (hasattr(a, "dtype")
+                        and jnp.issubdtype(a.dtype, jnp.floating)) else a,
+                    batch)
         from ..nn.functional import norm as fnorm
         with autograd_engine.no_grad(), rng_scope(key):
             with layer.load_functional_state(params):
                 with fnorm.collect_stat_updates() as stat_updates:
-                    out = loss_fn(layer, batch)
+                    # autodiff names the forward ops jvp(loss)/... and
+                    # the backward ops transpose(jvp(loss))/... itself
+                    with autograd_engine.traced_scopes(), \
+                            jax.named_scope("loss"):
+                        out = loss_fn(layer, batch)
         out = out.data if isinstance(out, Tensor) else out
         aux = {}
         if stat_updates:
@@ -302,9 +303,10 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
             # raw position is what mirrors the reference
             # check_finite_and_unscale op (amp/check_finite_and_unscale
             # _op.cu) and stays correct if those transforms change
-            finite = jnp.isfinite(loss)
-            for g in jax.tree_util.tree_leaves(grads):
-                finite &= jnp.all(jnp.isfinite(g))
+            with jax.named_scope("finite_check"):
+                finite = jnp.isfinite(loss)
+                for g in jax.tree_util.tree_leaves(grads):
+                    finite &= jnp.all(jnp.isfinite(g))
         if grad_shardings is not None:
             # Pin each grad to its ZeRO layout HERE, at the autodiff
             # boundary: the batch reduction then lowers to a
@@ -317,15 +319,19 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
             # sharding_optimizer.py:146 "reduce rather than allreduce".
             grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
         if clip_global_norm is not None:
-            leaves = jax.tree_util.tree_leaves(grads)
-            gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                              for g in leaves))
-            scale = jnp.minimum(1.0, clip_global_norm / (gn + 1e-6))
-            grads = jax.tree_util.tree_map(
-                lambda g: (g.astype(jnp.float32) * scale).astype(g.dtype),
-                grads)
-        new_params, new_state = optimizer.functional_update(
-            params, grads, opt_state, lr)
+            with jax.named_scope("grad_clip"):
+                leaves = jax.tree_util.tree_leaves(grads)
+                gn = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in leaves))
+                scale = jnp.minimum(1.0, clip_global_norm / (gn + 1e-6))
+                grads = jax.tree_util.tree_map(
+                    lambda g: (g.astype(jnp.float32)
+                               * scale).astype(g.dtype),
+                    grads)
+        with jax.named_scope("optimizer"):
+            new_params, new_state = optimizer.functional_update(
+                params, grads, opt_state, lr)
         if aux:
             # functionalized running stats: new = m*old + (1-m)*batch
             # (sequentially per micro-step under grad_accum, matching
@@ -333,25 +339,27 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
             # optimizer computed for the buffer entries. check_finite's
             # keep-select below covers these too: a bad step keeps the
             # old stats along with the old params.
-            for name, stat in aux.items():
-                m = stat_momentum[name]
-                cur = params[name].astype(jnp.float32)
-                if grad_accum > 1:  # stacked [accum, C] from the scan
-                    for i in range(grad_accum):
-                        cur = m * cur + (1 - m) * stat[i]
-                else:
-                    cur = m * cur + (1 - m) * stat
-                new_params[name] = cur.astype(params[name].dtype)
+            with jax.named_scope("stat_update"):
+                for name, stat in aux.items():
+                    m = stat_momentum[name]
+                    cur = params[name].astype(jnp.float32)
+                    if grad_accum > 1:  # stacked [accum, C] from the scan
+                        for i in range(grad_accum):
+                            cur = m * cur + (1 - m) * stat[i]
+                    else:
+                        cur = m * cur + (1 - m) * stat
+                    new_params[name] = cur.astype(params[name].dtype)
         if check_finite:
             # bad step → keep the incoming params/slots/step-count (the
             # reference update_loss_scaling "skip update" semantics),
             # selected on device so run-ahead dispatches after a NaN
             # step still consume good params
-            keep = lambda new, old: jax.tree_util.tree_map(
-                lambda n, o: jnp.where(finite, n, o), new, old)
-            new_params = keep(new_params, params)
-            new_state = keep(new_state, opt_state)
-            packed = jnp.stack([loss, (~finite).astype(jnp.float32)])
+            with jax.named_scope("finite_check"):
+                keep = lambda new, old: jax.tree_util.tree_map(
+                    lambda n, o: jnp.where(finite, n, o), new, old)
+                new_params = keep(new_params, params)
+                new_state = keep(new_state, opt_state)
+                packed = jnp.stack([loss, (~finite).astype(jnp.float32)])
             return packed, new_params, new_state
         return loss, new_params, new_state
 
@@ -530,15 +538,7 @@ class ParallelEngine:
         # pointer test per dispatch, nothing else (core/locks.py idiom)
         self._jsan = jit_sanitizer.site("ParallelEngine")
 
-        def counted_step(params, opt_state, batch, key, lr):
-            self.trace_count += 1
-            return self._step_fn(params, opt_state, batch, key, lr)
-
-        self._jit = jax.jit(
-            counted_step,
-            in_shardings=(param_sh, slot_sh, None, None, None),
-            out_shardings=(ns(P()), param_sh, slot_sh),
-            donate_argnums=(0, 1) if donate else ())
+        self._jit = self._jit_body(self._step_fn, "counted_step")
         self._jit_many_cache: Dict[int, Callable] = {}
 
         self.train_steps_per_sync = max(int(train_steps_per_sync), 1)
@@ -603,11 +603,33 @@ class ParallelEngine:
         # per-signature executable cost (obs.costmodel), computed
         # lazily on the first INSTRUMENTED dispatch of each signature
         self._cost_cache: Dict[tuple, Any] = {}
+        # the host's phases of the last PHASE_RING dispatches, always on
+        self._phases: collections.deque = collections.deque(
+            maxlen=PHASE_RING)
+        self._preplaced_ns = 0       # shard_batch() time not yet in a record
+        self._preplaced_calls = 0
+        # (kind, k, signature) of the last dispatch and the abstract
+        # batch of each signature: what compiled_step_text() lowers
+        self._last_run: Optional[tuple] = None
+        self._sig_batch: Dict[tuple, Any] = {}
+        self._step_text: Dict[tuple, str] = {}
 
     # -- data placement -----------------------------------------------------
 
     def shard_batch(self, batch):
-        """Host batch → device arrays sharded batch-dim over (dp, sharding)."""
+        """Host batch → device arrays sharded batch-dim over (dp, sharding).
+
+        The time spent here goes into the next dispatch's
+        :class:`StepPhases` record under ``shard_ns``, beside the
+        pass-through placement ``step`` then makes itself."""
+        t0 = time.time_ns()
+        with TraceAnnotation("train/shard"):
+            placed = self._place_batch(batch)
+        self._preplaced_ns += time.time_ns() - t0
+        self._preplaced_calls += 1
+        return placed
+
+    def _place_batch(self, batch):
         multi = jax.process_count() > 1
         # multi-host: keep leaves on HOST — make_array_from_process_local_data
         # consumes numpy directly; converting to device first would buy a
@@ -683,18 +705,41 @@ class ParallelEngine:
 
     # -- training -----------------------------------------------------------
 
+    def _jit_body(self, body, name: str, counted: bool = True):
+        """``body`` (the step, or the k-step scan over it) jitted over
+        the mesh under ``name``. ``counted=False`` gives the same
+        program without the trace-side-effect counter, for lowerings
+        that must leave ``cache_stats()`` alone (``step_cost``,
+        ``compiled_step_text``): the same name, arguments, shardings and
+        donation make the same module, hence the same entry of the
+        persistent compile cache."""
+        def fn(params, opt_state, batch, key, lr):
+            if counted:
+                self.trace_count += 1
+            return body(params, opt_state, batch, key, lr)
+
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(
+            fn,
+            in_shardings=(self._param_sh, self._slot_sh, None, None, None),
+            out_shardings=(NamedSharding(self.mesh, P()), self._param_sh,
+                           self._slot_sh),
+            donate_argnums=(0, 1) if self._donate else ())
+
     def _shape_sig(self, tree) -> tuple:
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         return (str(treedef),) + tuple(
             (tuple(np.shape(l)), str(getattr(l, "dtype", type(l))))
             for l in leaves)
 
-    def _guard_retrace(self, kind: str, batch) -> tuple:
+    def _guard_retrace(self, kind: str, batch, feeds=None) -> tuple:
         """Warn once when a new batch-shape signature forces a retrace
         (each retrace is a full XLA recompile — the silent host-loop
         serializer the jit_retrace_warn flag exists to surface).
         Returns the signature so instrumentation (step_cost) reuses it
-        instead of re-walking the batch tree."""
+        instead of re-walking the batch tree. ``feeds`` is what the
+        dispatch hands the executable where that is not ``batch``
+        (``step_many``'s stack)."""
         seen = self._seen_sigs.setdefault(kind, set())
         sig = self._shape_sig(batch)
         if sig in seen:
@@ -714,15 +759,59 @@ class ParallelEngine:
                 "batches to fixed shapes (set FLAGS_jit_retrace_warn=0 "
                 "to silence).")
         seen.add(sig)
+        # the shapes (not the arrays) of the signature, for
+        # compiled_step_text() to lower the same program later
+        self._sig_batch[(kind, sig)] = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+            batch if feeds is None else feeds)
         return sig
 
     def _push_inflight(self, fut: LossFuture) -> LossFuture:
         self._inflight.append(fut)
-        while len(self._inflight) > self.inflight_window:
-            # bound dispatch run-ahead: wait on (don't read back) the
-            # oldest outstanding executable
-            self._inflight.popleft().block()
+        with TraceAnnotation("train/inflight_wait"):
+            while len(self._inflight) > self.inflight_window:
+                # bound dispatch run-ahead: wait on (don't read back)
+                # the oldest outstanding executable
+                self._inflight.popleft().block()
         return fut
+
+    def _note_phases(self, m, k: int, kind: str, batch, sig, stamps,
+                     compiled: bool) -> None:
+        """The one set of stamps of a dispatch (``time.time_ns()`` at
+        each phase boundary) and its three readers: the ring, the JSONL
+        span sink and the ``obs_metrics`` histograms. The profiler reads
+        the same phases through the ``TraceAnnotation``s round them."""
+        t0, t1, t2, t3, t4 = stamps
+        shard_ns = (t1 - t0) + self._preplaced_ns
+        self._phases.append(StepPhases(
+            self.dispatch_count, t0, shard_ns, t2 - t1, t3 - t2, t4 - t3,
+            compiled, k + self._preplaced_calls, k))
+        self._preplaced_ns = self._preplaced_calls = 0
+        self._last_run = (kind, k, sig)
+        obs_trace.record_phases(
+            "train/step" if k == 1 else "train/step_many", _PHASES, stamps,
+            cat="Engine", args={"k": k} if k > 1 else None)
+        if m is not None:
+            m.histogram("train_shard_seconds").observe(shard_ns * 1e-9)
+            m.histogram("train_dispatch_seconds").observe((t3 - t2) * 1e-9)
+            m.histogram("train_inflight_wait_seconds").observe(
+                (t4 - t3) * 1e-9)
+            cost = self._cost_cache.get(sig)
+            if cost is None:
+                # once a signature, never per step: the lowering traces
+                cost = self.step_cost(batch, sharded=True, sig=sig)
+            if cost.flops:
+                m.gauge("train_step_flops").set(cost.flops)
+                m.gauge("train_step_bytes").set(cost.bytes_accessed)
+                m.gauge("train_cost_exact").set(1.0 if cost.exact else 0.0)
+            _obs_note_steps(m, k, self._obs_rows(batch, self.grad_accum),
+                            t3 * 1e-9)
+
+    def phase_records(self) -> list:
+        """The :class:`StepPhases` of the last ``PHASE_RING``
+        dispatches, oldest first."""
+        return list(self._phases)
 
     # -- per-step observability (obs_metrics flag; ISSUE 10) ---------------
 
@@ -755,19 +844,13 @@ class ParallelEngine:
             sig = self._shape_sig(batch)
         c = self._cost_cache.get(sig)
         if c is None:
-            ns = lambda spec: NamedSharding(self.mesh, spec)
-
             def lower():
                 # a SEPARATE jit of the uncounted step body: lowering
                 # the counted self._jit would run its trace-side-effect
                 # counters and corrupt the compile accounting the
                 # acceptance gates read
-                return jax.jit(
-                    self._step_fn,
-                    in_shardings=(self._param_sh, self._slot_sh,
-                                  None, None, None),
-                    out_shardings=(ns(P()), self._param_sh,
-                                   self._slot_sh)).lower(
+                return self._jit_body(
+                    self._step_fn, "counted_step", counted=False).lower(
                     self.params, self.opt_state, batch,
                     jax.random.key(0), jnp.asarray(0.0, jnp.float32))
 
@@ -782,13 +865,15 @@ class ParallelEngine:
         m = _obs_step_registry()
         if m is not None:
             _ensure_readback_observer()
-        t0 = time.perf_counter() if m is not None else 0.0
         lr_val = jnp.asarray(lr if lr is not None else
                              self.optimizer.get_lr(), jnp.float32)
-        with obs_trace.span("train/step", cat="Engine"):
-            with obs_trace.span("train/shard", cat="Engine"):
-                batch = self.shard_batch(batch)
-            t1 = time.perf_counter() if m is not None else 0.0
+        # one stamp at each phase boundary; _note_phases feeds every
+        # reader from them
+        t0 = time.time_ns()
+        with TraceAnnotation("train/shard"):
+            batch = self._place_batch(batch)
+        t1 = time.time_ns()
+        with TraceAnnotation("train/guard"):
             sig = self._guard_retrace("step", batch)
             self.dispatch_count += 1
             donated = None
@@ -796,39 +881,31 @@ class ParallelEngine:
                 donated = jax.tree_util.tree_leaves(
                     (self.params, self.opt_state))
                 self._jsan.guard_args(donated, "step")
-            with obs_trace.span("train/dispatch", cat="Engine"):
-                loss, self.params, self.opt_state = self._jit(
-                    self.params, self.opt_state, batch, next_key(),
-                    lr_val)
+        t2 = time.time_ns()
+        traces = self.trace_count
+        with TraceAnnotation("train/dispatch"):
+            loss, self.params, self.opt_state = self._jit(
+                self.params, self.opt_state, batch, next_key(), lr_val)
             if donated is not None:
                 # the old params/opt_state buffers were donated: poison
                 # them so a use-after-donate (a stale alias anywhere)
                 # fails deterministically instead of silently reading
                 # XLA-owned storage on TPU while passing on CPU
                 self._jsan.poison_donated(donated)
-        if m is not None:
-            t2 = time.perf_counter()
-            m.histogram("train_shard_seconds").observe(t1 - t0)
-            m.histogram("train_dispatch_seconds").observe(t2 - t1)
-            _obs_note_steps(m, 1,
-                            self._obs_rows(batch, self.grad_accum), t2,
-                            cost=self.step_cost(batch, sharded=True,
-                                                sig=sig))
-        sched = getattr(self.optimizer, "_learning_rate", None)
-        if hasattr(sched, "step"):
-            sched.step()
+            sched = getattr(self.optimizer, "_learning_rate", None)
+            if hasattr(sched, "step"):
+                sched.step()
+        t3 = time.time_ns()
         wrap = StepFuture if self.check_finite else LossFuture
-        return self._push_inflight(wrap(loss))
+        fut = self._push_inflight(wrap(loss))
+        self._note_phases(m, 1, "step", batch, sig,
+                          (t0, t1, t2, t3, time.time_ns()),
+                          self.trace_count != traces)
+        return fut
 
-    def _jit_many(self, k: int):
-        fn = self._jit_many_cache.get(k)
-        if fn is not None:
-            return fn
-        ns = lambda spec: NamedSharding(self.mesh, spec)
-
+    def _many_body(self):
+        """k optimizer steps as one ``lax.scan`` over the step."""
         def multi_step(params, opt_state, batches, keys, lrs):
-            self.trace_count += 1
-
             def body(carry, xs):
                 p, s = carry
                 b, key, lr_ = xs
@@ -838,14 +915,46 @@ class ParallelEngine:
             (params, opt_state), losses = jax.lax.scan(
                 body, (params, opt_state), (batches, keys, lrs))
             return losses, params, opt_state
+        return multi_step
 
-        fn = jax.jit(
-            multi_step,
-            in_shardings=(self._param_sh, self._slot_sh, None, None, None),
-            out_shardings=(ns(P()), self._param_sh, self._slot_sh),
-            donate_argnums=(0, 1) if self._donate else ())
-        self._jit_many_cache[k] = fn
+    def _jit_many(self, k: int):
+        fn = self._jit_many_cache.get(k)
+        if fn is None:
+            fn = self._jit_many_cache[k] = self._jit_body(
+                self._many_body(), "multi_step")
         return fn
+
+    def compiled_step_text(self) -> Optional[str]:
+        """The HLO text of the compiled program that the last dispatch
+        ran (``lower(...).compile().as_text()``): every instruction with
+        its ``op_name``, the layer path and op that ``make_train_step``'s
+        scopes gave it. ``obs.costmodel.step_op_scopes`` parses it.
+
+        Lowers the UNCOUNTED body under the step's own module name, so
+        ``cache_stats()`` stays as it is and the executable comes out of
+        the persistent compile cache where one is kept. Still a trace, a
+        lowering and a cache load: seconds at real size. Memoised per
+        signature; for after a run, never for the step loop. None before
+        the first dispatch."""
+        if self._last_run is None:
+            return None
+        kind, k, sig = self._last_run
+        text = self._step_text.get((kind, sig))
+        if text is None:
+            feeds = self._sig_batch[(kind, sig)]
+            if k == 1:
+                fn = self._jit_body(self._step_fn, "counted_step",
+                                    counted=False)
+                key, lr = jax.random.key(0), jnp.zeros((), jnp.float32)
+            else:
+                fn = self._jit_body(self._many_body(), "multi_step",
+                                    counted=False)
+                key = jax.random.split(jax.random.key(0), k)
+                lr = jnp.zeros((k,), jnp.float32)
+            text = fn.lower(self.params, self.opt_state, feeds, key,
+                            lr).compile().as_text()
+            self._step_text[(kind, sig)] = text
+        return text
 
     def step_many(self, batches: Sequence[Any],  # hot-path: k steps, one dispatch
                   lr: Optional[float] = None) -> LossFuture:
@@ -865,15 +974,15 @@ class ParallelEngine:
         m = _obs_step_registry()
         if m is not None:
             _ensure_readback_observer()
-        t0 = time.perf_counter() if m is not None else 0.0
-        with obs_trace.span("train/step_many", cat="Engine",
-                            args={"k": k}):
-            with obs_trace.span("train/shard", cat="Engine"):
-                sharded = [self.shard_batch(b) for b in batches]
-                stacked = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *sharded)
-            t1 = time.perf_counter() if m is not None else 0.0
-            sig = self._guard_retrace(f"step_many[k={k}]", sharded[0])
+        t0 = time.time_ns()
+        with TraceAnnotation("train/shard"):
+            sharded = [self._place_batch(b) for b in batches]
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *sharded)
+        t1 = time.time_ns()
+        kind = f"step_many[k={k}]"
+        with TraceAnnotation("train/guard"):
+            sig = self._guard_retrace(kind, sharded[0], feeds=stacked)
             sched = getattr(self.optimizer, "_learning_rate", None)
             lrs = []
             for _ in range(k):
@@ -889,25 +998,25 @@ class ParallelEngine:
                 donated = jax.tree_util.tree_leaves(
                     (self.params, self.opt_state))
                 self._jsan.guard_args(donated, "step_many")
-            with obs_trace.span("train/dispatch", cat="Engine"):
-                losses, self.params, self.opt_state = self._jit_many(k)(
-                    self.params, self.opt_state, stacked, keys, lrs)
+        t2 = time.time_ns()
+        traces = self.trace_count
+        with TraceAnnotation("train/dispatch"):
+            losses, self.params, self.opt_state = self._jit_many(k)(
+                self.params, self.opt_state, stacked, keys, lrs)
             if donated is not None:
                 self._jsan.poison_donated(donated)
-        if m is not None:
-            t2 = time.perf_counter()
-            m.histogram("train_shard_seconds").observe(t1 - t0)
-            m.histogram("train_dispatch_seconds").observe(t2 - t1)
-            # cost of the k-step scan = k x the single-step executable
-            # (same signature — the scan body IS the step fn)
-            _obs_note_steps(
-                m, k, self._obs_rows(sharded[0], self.grad_accum), t2,
-                cost=self.step_cost(sharded[0], sharded=True, sig=sig))
+        t3 = time.time_ns()
         # check_finite: the scan body already emits packed [loss,
         # notfinite] pairs, so `losses` is [k, 2] and the per-step flags
         # ride the same single readback
         wrap = StepFuture if self.check_finite else LossFuture
-        return self._push_inflight(wrap(losses))
+        fut = self._push_inflight(wrap(losses))
+        # cost of the k-step scan = k x the single-step executable
+        # (same signature — the scan body IS the step fn)
+        self._note_phases(m, k, kind, sharded[0], sig,
+                          (t0, t1, t2, t3, time.time_ns()),
+                          self.trace_count != traces)
+        return fut
 
     def step_stream(self, batches, lr: Optional[float] = None):
         """Drive training from any batch iterable at the engine's

@@ -19,8 +19,10 @@ import contextlib
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
+from ..autograd.engine import _tls as _trace_tls
 from ..core.errors import InvalidArgumentError, NotFoundError
 from ..core.tensor import Parameter, Tensor, to_tensor
 from ..core import dtype as dtypes
@@ -94,6 +96,7 @@ class Layer:
             raise InvalidArgumentError(
                 f"add_sublayer expects Layer, got {type(sublayer)}")
         self._sub_layers[str(name)] = sublayer
+        sublayer.__dict__["_scope_name"] = str(name)
         return sublayer
 
     def register_buffer(self, name: str, tensor: Optional[Tensor],
@@ -163,6 +166,8 @@ class Layer:
             params is not None and params.pop(name, None)
             buffers is not None and buffers.pop(name, None)
             layers[name] = value
+            # what a traced step calls this layer's scope (__call__)
+            value.__dict__["_scope_name"] = name
             return
         if buffers is not None and name in buffers:
             if value is None or isinstance(value, Tensor):
@@ -301,7 +306,14 @@ class Layer:
             out = hook(self, inputs)
             if out is not None:
                 inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
+        if getattr(_trace_tls, "scopes", False):
+            # a step is being traced (autograd.engine.traced_scopes):
+            # an op's scope becomes the layer path
+            with jax.named_scope(self.__dict__.get("_scope_name")
+                                 or type(self).__name__):
+                outputs = self.forward(*inputs, **kwargs)
+        else:
+            outputs = self.forward(*inputs, **kwargs)
         for hook in list(self._forward_post_hooks.values()):
             res = hook(self, inputs, outputs)
             if res is not None:

@@ -32,8 +32,9 @@ Three layers, each opt-in and independently cheap:
 
 The cost observatory (ISSUE 13) adds what things *cost*:
 :mod:`obs.costmodel` derives per-executable FLOPs/bytes from XLA's
-cost analysis (``train_mfu`` / ``train_hbm_bw_util`` gauges, the
-``hapi.summary`` FLOPs column), :mod:`obs.hbm` is the live-buffer
+cost analysis (``train_step_flops`` / ``train_step_bytes`` gauges, the
+``hapi.summary`` FLOPs column) and maps the compiled step's
+instructions to the scopes they were traced under, :mod:`obs.hbm` is the live-buffer
 census by subsystem plus the flag-gated monotone-growth leak detector,
 :mod:`obs.slo` evaluates declarative SLOs (burn-rate gauges +
 ``/healthz`` verdicts — ROADMAP #4's sensor), and :mod:`obs.flight` is

@@ -31,21 +31,34 @@ Contract (the ``bench.py --cost`` gate):
   PR 9 structural-zero discipline).
 
 Peak-rate tables (:func:`device_peak_flops`,
-:func:`device_peak_hbm_bw`) turn the per-step costs into the
-``train_mfu`` / ``train_hbm_bw_util`` gauges; ``bench.py`` shares the
-FLOPs table so the bench's analytic MFU and the engine's cost-model
-MFU are measured against the same peak.
+:func:`device_peak_hbm_bw`): ``bench.py`` divides its analytic and its
+cost-model MFU by the same peak. The engine derives no utilization
+itself: under run-ahead the time between two enqueues is not a step
+time, so a share of peak comes from a device trace (``benchmarks/``).
+
+**Which instruction is whose** (:func:`step_op_scopes`,
+:func:`step_fused_regions`, :func:`step_phase_records`): a device trace
+names each instruction by its HLO text, which holds no metadata. The
+compiled program's text does: every instruction's ``op_name`` is the
+path of ``jax.named_scope``s it was traced under (``make_train_step``'s
+regions, the layer path, the op's name). These functions read it from
+the live engine (found through ``obs.hbm``'s weak registry, so a reader
+needs no handle on one), after a run and never inside it.
 """
 
 from __future__ import annotations
 
+import functools
+import re
 import threading
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 __all__ = ["ExecutableCost", "analyze", "site_cost", "tree_bytes",
            "tree_size_cost", "forward_cost", "device_peak_flops",
-           "device_peak_hbm_bw", "clear_cache"]
+           "device_peak_hbm_bw", "clear_cache", "parse_op_scopes",
+           "region_of", "step_op_scopes", "step_fused_regions",
+           "step_phase_records"]
 
 
 @dataclass(frozen=True)
@@ -227,3 +240,117 @@ def device_peak_hbm_bw(device) -> float:
     """Peak HBM bandwidth (bytes/s) per chip by device kind — the
     denominator of ``train_hbm_bw_util``."""
     return _peaks(device)[1]
+
+
+# -- which instruction is whose ---------------------------------------------
+
+# the regions make_train_step names; everything the loss function traces
+# is "forward" or, behind autodiff's transpose(...), "backward"
+REGIONS = ("forward", "backward", "amp_cast", "finite_check", "grad_clip",
+           "optimizer", "stat_update")
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([^\s(]+)\s*\(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%([^\s=]+)\s*=\s*(.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"\bcalls=%([^\s,)}]+)")
+
+
+def region_of(op_name: str) -> str:
+    """The region of :data:`REGIONS` an ``op_name`` lies in, "" when in
+    none. ``jit(counted_step)/transpose(jvp(loss))/bert/...`` is
+    ``backward``; under ``step_many`` or gradient accumulation the
+    regions sit behind ``while/body/``. XLA joins the names of merged
+    instructions with ``;``: the first that names a region decides."""
+    for one in op_name.split(";"):
+        for part in one.split("/"):
+            inner = part.replace("transpose(", "").replace("jvp(", "")
+            inner = inner.rstrip(")")
+            if inner == "loss":
+                return "backward" if "transpose(" in part else "forward"
+            if inner in REGIONS:
+                return inner
+    return ""
+
+
+@functools.lru_cache(maxsize=2)     # the engine hands the same string back
+def parse_op_scopes(text: str):
+    """``compiled.as_text()`` -> (``{instruction name: op_name}``,
+    ``{fusion name: regions inside}``). Every instruction of every
+    computation is in the first (names are unique in a module), with ""
+    where it carries no ``op_name``; a fusion that carries none takes
+    the commonest of its called computation's, as the profiler's
+    framework-op view does. The second holds, per fusion, the distinct
+    regions (:func:`region_of`) of the instructions fused into it.
+    Memoised: every caller gets the same two dicts, to read."""
+    bodies: Dict[str, list] = {}
+    scopes: Dict[str, str] = {}
+    fusions: Dict[str, str] = {}
+    body = None
+    for line in text.splitlines():
+        if body is not None:
+            m = _INSTRUCTION.match(line)
+            if m:
+                name, rhs = m.groups()
+                found = _OP_NAME.search(rhs)
+                scopes[name] = found.group(1) if found else ""
+                body.append(scopes[name])
+                called = _CALLS.search(rhs)
+                if called and " fusion(" in rhs:
+                    fusions[name] = called.group(1)
+            elif line.startswith("}"):
+                body = None
+        else:
+            m = _COMPUTATION.match(line)
+            if m:
+                body = bodies.setdefault(m.group(1), [])
+    regions: Dict[str, Tuple[str, ...]] = {}
+    for name, called in fusions.items():
+        inside = [s for s in bodies.get(called, ()) if s]
+        if not scopes[name] and inside:
+            scopes[name] = max(set(inside), key=inside.count)
+        regions[name] = tuple(sorted(
+            {region_of(s) for s in inside} - {""}))
+    return scopes, regions
+
+
+def _stepping_engine():
+    """The engine that dispatched last among the live ones, or None."""
+    from . import hbm
+    engine, at = None, -1
+    for owner in hbm.live_owners():
+        records = getattr(owner, "phase_records", list)()
+        if records and records[-1].start_ns > at:
+            engine, at = owner, records[-1].start_ns
+    return engine
+
+
+def _step_parsed():
+    engine = _stepping_engine()
+    text = engine.compiled_step_text() if engine is not None else None
+    return None if text is None else parse_op_scopes(text)
+
+
+def step_op_scopes() -> Optional[Dict[str, str]]:
+    """``{HLO instruction name: op_name}`` of the compiled step program
+    that the process's live engine dispatched last; None where no engine
+    has stepped. Lowers and loads the program on the first call for a
+    signature (seconds at real size; see
+    ``ParallelEngine.compiled_step_text``), so call it after the run."""
+    parsed = _step_parsed()
+    return None if parsed is None else parsed[0]
+
+
+def step_fused_regions() -> Optional[Dict[str, Tuple[str, ...]]]:
+    """``{fusion instruction name: regions of the instructions inside}``
+    of the same program: XLA fuses across regions (a weight-gradient
+    matmul with the optimizer update behind it), and a fusion's time is
+    counted under its own ``op_name`` alone."""
+    parsed = _step_parsed()
+    return None if parsed is None else parsed[1]
+
+
+def step_phase_records() -> list:
+    """The ``StepPhases`` ring of the same engine (``[]`` with none):
+    what the host did in each of its last dispatches."""
+    engine = _stepping_engine()
+    return engine.phase_records() if engine is not None else []

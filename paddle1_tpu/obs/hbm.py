@@ -43,6 +43,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..core.errors import EnforceNotMet
 
 __all__ = ["SUBSYSTEMS", "HbmLeakSuspected", "register", "unregister",
+           "live_owners",
            "census", "publish", "device_live_bytes", "reset",
            "leak_note", "step_sample"]
 
@@ -99,6 +100,17 @@ def reset() -> None:
         _providers.clear()
     _leak["last"], _leak["growth"] = None, 0
     _sample["t"], _sample["total"] = 0.0, 0
+
+
+def live_owners() -> list:
+    """The live owners of the registered trees, oldest registration
+    first, each once: the process's engines, for readers that have no
+    handle on one (``obs.costmodel.step_op_scopes``)."""
+    out = []
+    for _, _, owner, _ in _live_providers():
+        if not any(owner is o for o in out):
+            out.append(owner)
+    return out
 
 
 def _live_providers():

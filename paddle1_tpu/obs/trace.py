@@ -50,8 +50,10 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from ..core import flags as core_flags
 
-__all__ = ["TRACE_CTX_ENV", "sink_active", "new_trace_id", "new_span_id",
+__all__ = ["TRACE_CTX_ENV", "sink_active", "recording", "new_trace_id",
+           "new_span_id",
            "current", "context", "span", "instant", "record_span",
+           "record_phases",
            "wire_header", "adopt_header", "set_process_context",
            "process_context", "export_chrome_trace", "set_span_tap"]
 
@@ -99,9 +101,10 @@ def set_span_tap(fn) -> None:
     _tap = fn
 
 
-def _recording() -> bool:
+def recording() -> bool:
     """Spans are generated when a sink OR a tap wants them."""
     return _tap is not None or sink_active()
+
 
 
 # Ids are a random base + pid + counter: unique across a pod (the pid
@@ -311,7 +314,7 @@ def record_span(name: str, dur_s: float,
     finishing a span another thread opened; omitted, the current
     context is used. Returns the span's id (None when the sink is
     off)."""
-    if not _recording():
+    if not recording():
         return None
     if ctx is None:
         ctx = current()
@@ -337,7 +340,7 @@ def instant(name: str, ctx: Optional[Tuple[str, str]] = None,
     """Record a zero-duration marker NOW (written and flushed
     immediately — survives a SIGKILL a microsecond later, which is how
     a wedged replica's request receipt stays visible)."""
-    if not _recording():
+    if not recording():
         return
     if ctx is None:
         ctx = current()
@@ -391,23 +394,51 @@ class _LiveSpan:
 
     def __exit__(self, *exc):
         _tls.ctx.pop()
-        end = time.time()
-        dur = end - self._t0
-        if self.args:
-            try:
-                extra = ',"args":' + json.dumps(self.args, default=repr)
-            except (TypeError, ValueError):
-                extra = ""
-        else:
-            extra = ""
-        parent = f'"{self._parent}"' if self._parent else "null"
-        _write_line(
-            f'{{"ph":"X","name":{_q(self.name)},"cat":{_q(self.cat)},'
-            f'"ts":{self._t0 * 1e6:.1f},"dur":{dur * 1e6:.1f},'
-            f'"pid":{os.getpid()},"tid":{threading.get_ident()},'
-            f'"trace":"{self._tid}","span":"{self._sid}",'
-            f'"parent":{parent}{extra}}}\n')
+        _write_line(_span_line(
+            self.name, self.cat, self._t0 * 1e6,
+            (time.time() - self._t0) * 1e6, self._tid, self._sid,
+            self._parent, self.args))
         return False
+
+
+def _span_line(name, cat, ts_us, dur_us, trace_id, span_id, parent,
+               args=None) -> str:
+    """Hot-path serialization of one completed span; the ids are this
+    module's own or ``_ID_RE``-checked, and interpolate raw."""
+    extra = ""
+    if args:
+        try:
+            extra = ',"args":' + json.dumps(args, default=repr)
+        except (TypeError, ValueError):
+            pass
+    parent = f'"{parent}"' if parent else "null"
+    return (f'{{"ph":"X","name":{_q(name)},"cat":{_q(cat)},'
+            f'"ts":{ts_us:.1f},"dur":{dur_us:.1f},'
+            f'"pid":{os.getpid()},"tid":{threading.get_ident()},'
+            f'"trace":"{trace_id}","span":"{span_id}",'
+            f'"parent":{parent}{extra}}}\n')
+
+
+def record_phases(name: str, phases: Sequence[str],
+                  stamps_ns: Sequence[int], cat: str = "obs",
+                  args: Optional[dict] = None) -> None:
+    """Record one span ``name`` over ``[stamps_ns[0], stamps_ns[-1]]``
+    under the current context and, as its children, one span a phase
+    between consecutive stamps. The stamps are the caller's own
+    ``time.time_ns()`` readings, taken once at each phase boundary: a
+    hot loop that also feeds them to other readers (the training
+    engine's ring, histograms and profiler annotations) times each
+    phase once. Written children first, like nested :func:`span`s."""
+    if not recording():
+        return
+    trace_id, parent = current() or process_context()
+    sid = new_span_id()
+    for phase, a, b in zip(phases, stamps_ns, stamps_ns[1:]):
+        _write_line(_span_line(phase, cat, a * 1e-3, (b - a) * 1e-3,
+                               trace_id, new_span_id(), sid))
+    _write_line(_span_line(name, cat, stamps_ns[0] * 1e-3,
+                           (stamps_ns[-1] - stamps_ns[0]) * 1e-3,
+                           trace_id, sid, parent, args))
 
 
 def span(name: str, cat: str = "obs",
@@ -416,7 +447,7 @@ def span(name: str, cat: str = "obs",
     making it the parent of anything opened inside). A shared no-op
     object when neither the sink nor the flight tap is armed — safe on
     hot paths."""
-    if not _recording():
+    if not recording():
         return _NULL
     return _LiveSpan(name, cat, args)
 

@@ -160,6 +160,7 @@ def _flash_fwd(q, k, v, scale, causal, padding_mask=None):
             jax.ShapeDtypeStruct((b * h, nq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, _LANES), jnp.float32),
         ],
+        name="p1t_flash_attention_fwd",
         interpret=_common.interpret(),
     )(*args)
     out = out.reshape(b, h, nq, d).transpose(0, 2, 1, 3)

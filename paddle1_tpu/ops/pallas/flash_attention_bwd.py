@@ -216,6 +216,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((b * h, nk, d), k.dtype),
                    jax.ShapeDtypeStruct((b * h, nk, d), v.dtype)],
+        name="p1t_flash_attention_bwd_dkv",
         interpret=_common.interpret(),
     )(*args, *mask_arg)
 
@@ -226,6 +227,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
         in_specs=[qspec, kfull, kfull, qspec, row_q, row_q, *mask_specs],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((b * h, nq, d), q.dtype),
+        name="p1t_flash_attention_bwd_dq",
         interpret=_common.interpret(),
     )(*args, *mask_arg)
 

@@ -95,6 +95,7 @@ def fused_adam_update(p, g, m1, m2, lr, step, beta1, beta2, eps, decay):
             jax.ShapeDtypeStruct((rows, _COLS), jnp.float32),
             jax.ShapeDtypeStruct((rows, _COLS), jnp.float32),
         ],
+        name="p1t_fused_adam_update",
         interpret=_common.interpret(),
     )(scalars, p2, g2, m12, m22)
 

@@ -159,6 +159,7 @@ def _train_fwd(x2, g, b, res, eps, act):
             jax.ShapeDtypeStruct((1, c), jnp.float32),
             jax.ShapeDtypeStruct((1, c), jnp.float32),
         ],
+        name="p1t_fused_bn_fwd_stats",
         interpret=_common.interpret(),
     )(*args)
     return y, mean.reshape(c), var.reshape(c)
@@ -277,6 +278,7 @@ def _norm_fwd(x2, m, v, g, b, res, eps, act):
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((rows, c), x2.dtype),
+        name="p1t_fused_bn_fwd_norm",
         interpret=_common.interpret(),
     )(*args)
 
@@ -383,6 +385,7 @@ def _moments_fwd(x2):
                    pl.BlockSpec((1, c), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
+        name="p1t_fused_bn_fwd_moments",
         interpret=_common.interpret(),
     )(x2)
     return s.reshape(c), ss.reshape(c)

@@ -113,6 +113,7 @@ def _train_bwd_pallas(x2, g, mean, var, y2, dy2, eps, act, with_res):
         in_specs=[row_spec, ch_spec, ch_spec, ch_spec, row_spec, row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
+        name="p1t_fused_bn_bwd_dx",
         interpret=_common.interpret(),
     )(x2, g.reshape(1, c), mean.astype(jnp.float32).reshape(1, c),
       var.astype(jnp.float32).reshape(1, c), y2, dy2)
@@ -208,6 +209,7 @@ def _norm_bwd_pallas(x2, g, mean, var, y2, dy2, eps, act, with_res):
         in_specs=[row_spec, ch_spec, ch_spec, ch_spec, row_spec, row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
+        name="p1t_fused_bn_bwd_norm",
         interpret=_common.interpret(),
     )(x2, g.reshape(1, c), mean.astype(jnp.float32).reshape(1, c),
       var.astype(jnp.float32).reshape(1, c), y2, dy2)

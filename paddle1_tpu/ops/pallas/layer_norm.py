@@ -64,6 +64,7 @@ def _ln_fwd(x2, w, b, eps):
         ],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x2.dtype),
+        name="p1t_layer_norm_fwd",
         interpret=_common.interpret(),
     )(x2, w.reshape(1, h), b.reshape(1, h))
 

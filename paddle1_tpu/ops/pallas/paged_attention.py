@@ -174,6 +174,7 @@ def paged_attention(q, kp, vp, table, base,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s_, h, w, d), q.dtype),
+        name="p1t_paged_attention_fwd",
         interpret=_common.interpret(),
     )(table.astype(jnp.int32), base.astype(jnp.int32), q, kp, vp)
     return out.transpose(0, 2, 1, 3)

@@ -54,6 +54,7 @@ def _softmax_fwd(x2):
         in_specs=[pl.BlockSpec((br, h), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, h), x2.dtype),
+        name="p1t_softmax_fwd",
         interpret=_common.interpret(),
     )(x2)
 

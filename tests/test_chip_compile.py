@@ -11,6 +11,7 @@ A compile that passes is not a chip run.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,8 +144,20 @@ def test_kernel_compiles_for_v5e(case, one_chip, for_the_chip):
     fn, args = CASES[case]()
     shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
               for s, dt in args]
-    compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name reaches the chip's program twice: as the
+    # instruction's own name, which a device trace shows, and in its
+    # op_name, which obs.costmodel.step_op_scopes maps it to
+    from paddle1_tpu.obs import costmodel
+    scopes, _ = costmodel.parse_op_scopes(text)
+    calls = re.findall(r"^\s+(?:ROOT\s+)?%(\S+) = .*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert calls
+    for name in calls:       # p1t_layer_norm_fwd.3, jvp_p1t_fused_bn_..._.1
+        kernel = re.search(r"p1t_[a-z0-9_]*[a-z0-9]", name)
+        assert kernel, name
+        assert kernel.group(0) in scopes[name], (name, scopes[name])
 
 
 def test_paged_supported_admits_only_what_compiles(one_chip, for_the_chip):

@@ -185,8 +185,9 @@ class TestEngineCost:
         assert g["train_step_flops"] > 0
         assert g["train_step_bytes"] > 0
         assert g["train_cost_exact"] == 1.0
-        assert 0 < g["train_mfu"] < 1.0
-        assert 0 < g["train_hbm_bw_util"]
+        # the time between two enqueues is not a step time under
+        # run-ahead: the engine derives no utilization from it
+        assert "train_mfu" not in g and "train_hbm_bw_util" not in g
         assert g["hbm_params_bytes"] > 0
         assert g["hbm_census_bytes"] > 0
 
@@ -605,8 +606,7 @@ class TestExpositionConformanceCostFamilies:
     def test_cost_hbm_slo_gauge_families_conform(self):
         from tests.test_obs import parse_exposition
         m = obs.MetricsRegistry(namespace="p1t")
-        m.gauge("train_mfu").set(0.41)
-        m.gauge("train_hbm_bw_util").set(0.6)
+        m.gauge("train_cost_exact").set(1.0)
         m.gauge("train_step_flops").set(1e12)
         m.gauge("train_step_bytes").set(2e9)
         m.gauge("hbm_params_bytes").set(4.4e8)
@@ -615,7 +615,7 @@ class TestExpositionConformanceCostFamilies:
         m.gauge("slo_lat_ok").set(1.0)
         m.histogram("train_readback_seconds").observe(0.01)
         types, samples = parse_exposition(m.render_text())
-        for fam in ("p1t_train_mfu", "p1t_train_hbm_bw_util",
+        for fam in ("p1t_train_cost_exact", "p1t_train_step_flops",
                     "p1t_hbm_params_bytes",
                     "p1t_hbm_census_coverage_ratio",
                     "p1t_slo_lat_burn_rate_ratio"):
