@@ -1372,27 +1372,21 @@ def bench_conv_block(on_tpu, steps_override=None):
     (their share of a ResNet-50 step on the v5e: not measured).
 
     Runs the block's training step under ``fused_bn=never`` (the XLA
-    multi-pass lowering) and ``fused_bn=always`` (the Pallas kernels —
-    interpret-mode emulation off-TPU, so its CPU step time measures the
-    EMULATOR, not the kernel). CPU-measurable gates:
+    composition, which is also what ``auto`` runs in training mode)
+    and ``fused_bn=always`` (the Pallas kernels — interpret-mode
+    emulation off-TPU, so its CPU step time measures the EMULATOR, not
+    the kernel). Gates:
 
     - numeric parity: k training steps land on the same params (1e-4
       across the compounded Momentum run; 1e-6-grade per step) and the
       same running stats;
-    - op count: the fused step's jax-op census (pallas_call opaque =
-      one kernel on chip) is STRICTLY SMALLER than the XLA lowering's,
-      and the fused path actually selected kernels (pallas_calls > 0);
+    - ``always`` selected kernels (pallas_calls > 0), ``never`` none;
     - layout stability: the compiled forward keeps the SAME transpose
-      count as the XLA path (<= the stem/head boundary pair + residual
-      — zero layout churn between conv/BN/act/pool stages), the ~15%
-      copy overhead class in the trace;
-    - default-path safety off-TPU: ``fused_bn=auto`` resolves to the
-      XLA lowering on CPU, so the shipped default cannot regress.
+      count as the XLA path (<= the stem/head boundary pair + residual);
+    - ``fused_bn=auto`` resolves to the composition in training mode.
 
-    On TPU the step-time gate arms for real: fused best-of-3 must beat
-    never (this is the pre-wired half of the next-chip-window check in
-    chip_results/NOTES.md; BN family <25% step time and >=2.5x
-    ResNet-50 samples/s are measured there, not here).
+    The two step times are recorded, not gated: on the v5e ResNet-50's
+    step is shorter with the composition (PERF.md, PR 26).
     ``vs_baseline`` is 1.0 iff every gate holds; the metric is the
     default path's steps/s."""
     import jax
@@ -1498,17 +1492,15 @@ def bench_conv_block(on_tpu, steps_override=None):
         results[fused] = {"params": params, "ops": ops, "hlo": hlo,
                           "step_s": bo.best_s / steps}
 
-    # the shipped default: auto. Two distinct probes — a shape ABOVE
-    # the fused_bn_auto_mb crossover isolates the backend resolution
-    # (off-TPU it must refuse the emulated kernel even when size
-    # qualifies), and the bench's own block shape decides which path
-    # the default actually runs here (this micro block sits UNDER the
-    # crossover, so auto keeps XLA for it on every backend)
+    # the shipped default: auto, which in training mode is the XLA
+    # composition on every shape and backend (PERF.md, PR 26)
     with core_flags.flags_guard(fused_bn="auto"):
-        auto_backend_kernel = fused_bn_active((32768, 128), np.float32)
-        auto_is_fused = fused_bn_active((8 * 16 * 16, c), np.float32)
-    assert on_tpu or not auto_backend_kernel, \
-        "auto resolved to the (emulated) kernel off-TPU"
+        auto_backend_kernel = fused_bn_active((32768, 128), np.float32,
+                                              training=True)
+        auto_is_fused = fused_bn_active((8 * 16 * 16, c), np.float32,
+                                        training=True)
+    assert not auto_backend_kernel and not auto_is_fused, \
+        "auto resolved to a training kernel"
 
     never, fused = results["never"], results["always"]
     # 1e-4: the kernel's sum/sqsum stats round differently from
@@ -1520,16 +1512,15 @@ def bench_conv_block(on_tpu, steps_override=None):
         for k in never["params"]))
     parity_ok = parity <= 1e-4
     ops_ok = (fused["ops"]["pallas_calls"] >= 3        # 2 fwd + >=1 bwd
-              and never["ops"]["pallas_calls"] == 0
-              and fused["ops"]["ops"] < never["ops"]["ops"])
+              and never["ops"]["pallas_calls"] == 0)
     layout_ok = (fused["hlo"]["transposes"]
                  <= never["hlo"]["transposes"] <= 4)
-    time_ok = (not on_tpu) or fused["step_s"] <= never["step_s"]
-    default_steps_per_s = 1.0 / (fused["step_s"] if (on_tpu and
-                                                     auto_is_fused)
-                                 else never["step_s"])
+    # recorded, not gated: on the v5e the composition gives the shorter
+    # ResNet-50 step (PERF.md, PR 26)
+    time_ok = fused["step_s"] <= never["step_s"]
+    default_steps_per_s = 1.0 / never["step_s"]     # auto = never here
 
-    ok = parity_ok and ops_ok and layout_ok and time_ok
+    ok = parity_ok and ops_ok and layout_ok
     detail = {
         "steps": steps,
         "parity_max_err": float(parity),
@@ -1553,9 +1544,9 @@ def bench_conv_block(on_tpu, steps_override=None):
           1.0 if ok else 0.0, detail)
     if not ok:
         raise AssertionError(
-            "conv-block gate failed (need param parity 1e-4, fewer "
-            "jax ops with kernels selected, layout-stable forward, "
-            f"and no on-chip step regression): {json.dumps(detail)}")
+            "conv-block gate failed (need param parity 1e-4, kernels "
+            "selected under always alone and a layout-stable forward): "
+            f"{json.dumps(detail)}")
 
 
 def bench_obs(on_tpu, steps_override=None):

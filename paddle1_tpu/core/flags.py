@@ -364,36 +364,30 @@ def _define_builtin_flags() -> None:
                 "Pallas fused LayerNorm: auto (TPU only), always, never.",
                 validator=lambda v: v in ("auto", "always", "never"))
     define_flag("fused_bn", "auto",
-                "Pallas fused batch norm (one kernel for stats + "
-                "normalize + activation + residual-add, the reference "
+                "Pallas batch-norm kernels (stats + normalize + "
+                "activation + residual-add in one call, the reference "
                 "fused_bn_activation_op/fused_bn_add_activation_op "
-                "role): auto (TPU only, AND only when the channels-"
-                "last activation is at least fused_bn_auto_mb — small "
-                "BNs are latency-bound and XLA's fusion handles them; "
-                "the crossover lives where the multi-pass stat chain "
-                "becomes HBM-bound), always "
-                "(interpret-mode on CPU, for tests and the "
-                "bench.py --conv-block gate), never (the XLA lowering "
-                "— the ablation arm). "
-                "Requires a channels-last layout (NHWC data_format or "
-                "the conv_nhwc region) and affine weight+bias.",
+                "role): auto = the kernels on a TPU where the "
+                "statistics are GIVEN (eval mode, SyncBatchNorm's "
+                "local halves; on the v5e: not measured) and the XLA "
+                "composition in training mode on every shape; always "
+                "= the kernels in training mode too (interpret-mode "
+                "on CPU, for tests and the ablation); never = the XLA "
+                "compositions. Measured on the v5e (PERF.md, PR 26): "
+                "ResNet-50's training step is shorter with the "
+                "composition, which XLA fuses into the neighbouring "
+                "convolutions, than with the training kernels, whose "
+                "custom calls it cannot fuse across and has to copy "
+                "activations for. The kernels require a channels-last "
+                "layout (NHWC data_format or the conv_nhwc region) "
+                "and affine weight+bias.",
                 validator=lambda v: v in ("auto", "always", "never"))
-    define_flag("fused_bn_auto_mb", 4.0,
-                "Crossover threshold (MiB of the BN input activation) "
-                "below which fused_bn=auto keeps the XLA lowering: "
-                "under it the stat passes fit the compiler's fusion "
-                "budget and kernel launch overhead dominates; above "
-                "it each extra pass is a full HBM round-trip. "
-                "PROVISIONAL: the sweep chip_results/NOTES.md queues "
-                "has not run — 'always'/'never' bypass it for A/B "
-                "runs.",
-                validator=lambda v: v > 0)
     define_flag("fused_bn_bwd", "auto",
-                "Pallas fused batch-norm BACKWARD (one-pass "
+                "Pallas batch-norm BACKWARD kernels (one-pass "
                 "dx/dgamma/dbeta): auto (TPU only), always (interpret "
-                "on CPU), never (XLA composition backward — the "
-                "ablation arm; forward fusion still applies). Only "
-                "consulted when the forward ran the fused kernel.",
+                "on CPU), never (XLA composition backward behind a "
+                "kernel forward: the forward-only ablation arm). Only "
+                "consulted when the forward ran a kernel.",
                 validator=lambda v: v in ("auto", "always", "never"))
     define_flag("fused_adam", "never",
                 "Pallas fused Adam/AdamW update: auto (TPU only), "

@@ -10,6 +10,7 @@ implementation is the fallback and the numeric ground truth.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Optional
 
@@ -121,26 +122,20 @@ def warn_traced_stats_skipped(buffer, what: str) -> None:
         "(warned once per buffer).")
 
 
-def fused_bn_active(shape, dtype) -> bool:
-    """Resolve the ``fused_bn`` flag family against a channels-LAST
-    input: always / never are absolute, auto additionally requires a
-    TPU backend (flag_active) and an activation at least
-    ``fused_bn_auto_mb`` — below the crossover the multi-pass XLA
-    lowering fits the fusion budget and kernel overhead dominates."""
+def fused_bn_active(shape, dtype, training: bool = False) -> bool:
+    """Resolve the ``fused_bn`` flag against a channels-LAST input.
+    ``always`` / ``never`` are absolute. ``auto`` takes the Pallas
+    kernels only where they are given statistics (eval mode,
+    SyncBatchNorm's local halves) and on a TPU backend (flag_active);
+    in training mode ``auto`` is the XLA composition on every shape:
+    on the v5e ResNet-50's step is shorter with it on each of its 53
+    norms (PERF.md, PR 26), because the compiler fuses it into the
+    convolutions on either side and a custom call is a wall."""
     from ...core.flags import flag, flag_active
     from ...ops.pallas import fused_bn as pbn
-    if not flag_active("fused_bn"):
+    if training and flag("fused_bn") != "always":
         return False
-    if not pbn.supported(shape, dtype):
-        return False
-    if flag("fused_bn") == "auto":
-        n = 1
-        for s in shape:
-            n *= s
-        if n * jnp.dtype(dtype).itemsize < \
-                flag("fused_bn_auto_mb") * 1024 * 1024:
-            return False
-    return True
+    return flag_active("fused_bn") and pbn.supported(shape, dtype)
 
 
 # Cached weak-typed device scalars (epsilon, momentum, the relu zero).
@@ -170,6 +165,99 @@ def _apply_act(y, act):
     if act == "relu":
         return jnp.maximum(y, _scalar(0.0))
     return y
+
+
+def _bn_reduction(x, ch_axis):
+    """-> (the axes batch norm reduces over, 1 / their element count as
+    a cached scalar, the shape that broadcasts a [C] vector against x)."""
+    axes = tuple(i for i in range(x.ndim) if i != ch_axis)
+    n = 1
+    for i in axes:
+        n *= x.shape[i]
+    bshape = [1] * x.ndim
+    bshape[ch_axis] = -1
+    return axes, _scalar(1.0 / n), bshape
+
+
+def _bn_train_forward(x, gamma, beta, residual, eps, act, ch_axis):
+    """Training-mode batch norm as one XLA composition, on the tensor as
+    it comes (any channel axis, no reshape). For 16-bit ``x``:
+    per-channel sum and sum of squares in ONE pass (a multi-output
+    reduction the compiler hangs on the convolution that produces
+    ``x``), mean / var / rstd in float32 with the count an exact
+    constant and the variance clamped at 0; the normalise + affine
+    (+ residual) + activation chain in float32, rounded once to ``x``'s
+    dtype (the compiler fuses it into the convolution that consumes
+    ``y``). The same mathematics as ``ops/pallas/fused_bn.py``'s
+    training kernel, without the custom call's wall. Wider ``x`` takes a
+    second, centred pass for the variance. -> (y, mean, var, rstd)."""
+    axes, inv, bshape = _bn_reduction(x, ch_axis)
+    ft = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(ft)
+    mean = jnp.sum(xf, axis=axes) * inv
+    xc = xf - mean.reshape(bshape)
+    if jnp.dtype(x.dtype).itemsize <= 2:
+        # 16-bit data: float32 sums carry 16 bits more than the data,
+        # so E[x^2] - mean^2 costs nothing that the data had
+        var = jnp.maximum(jnp.sum(xf * xf, axis=axes) * inv - mean * mean,
+                          _scalar(0.0))
+    else:
+        # float32 data in float32 sums: E[x^2] - mean^2 loses
+        # log2(E[x^2] / var) of the 24 bits (a 50-layer net leaves its
+        # float32 reference by 3e-3 in the first gradient), so the
+        # variance takes a second, centred pass
+        var = jnp.sum(xc * xc, axis=axes) * inv
+    rstd = jax.lax.rsqrt(var + _scalar(eps))
+    y = xc * rstd.reshape(bshape)
+    if gamma is not None:
+        y = (y * gamma.astype(ft).reshape(bshape)
+             + beta.astype(ft).reshape(bshape))
+    if residual is not None:
+        y = y + residual.astype(ft)
+    return _apply_act(y, act).astype(x.dtype), mean, var, rstd
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _bn_train(x, gamma, beta, residual, eps, act, ch_axis):
+    return _bn_train_forward(x, gamma, beta, residual, eps, act, ch_axis)[:3]
+
+
+def _bn_train_fwd_rule(x, gamma, beta, residual, eps, act, ch_axis):
+    y, mean, var, rstd = _bn_train_forward(x, gamma, beta, residual, eps,
+                                           act, ch_axis)
+    # y is kept for the activation's mask alone (the convolution that
+    # consumes it keeps it anyway); of the residual only its dtype
+    return (y, mean, var), (
+        x, gamma, beta, mean, rstd, y if act == "relu" else None,
+        None if residual is None else jnp.zeros((0,), residual.dtype))
+
+
+def _bn_train_bwd_rule(eps, act, ch_axis, saved, cts):
+    """dgamma = sum(dy * xhat), dbeta = sum(dy), one expression for dx,
+    all in float32 inside whatever fusion the compiler builds. The
+    statistics' cotangents are dropped: they feed the running averages
+    only (the reference's SavedMean / SavedVariance are not
+    differentiable outputs), and a term for them, zero or not, is a
+    full pass over x."""
+    x, gamma, beta, mean, rstd, y, res_proto = saved
+    axes, inv, bshape = _bn_reduction(x, ch_axis)
+    ft = mean.dtype
+    dyf = cts[0].astype(ft)
+    if act == "relu":
+        dyf = jnp.where(y > 0, dyf, _scalar(0.0))
+    xhat = (x.astype(ft) - mean.reshape(bshape)) * rstd.reshape(bshape)
+    dg = jnp.sum(dyf * xhat, axis=axes)
+    db = jnp.sum(dyf, axis=axes)
+    scale = rstd if gamma is None else rstd * gamma.astype(ft)
+    dx = scale.reshape(bshape) * (
+        dyf - (db * inv).reshape(bshape) - xhat * (dg * inv).reshape(bshape))
+    return (dx.astype(x.dtype),
+            None if gamma is None else dg.astype(gamma.dtype),
+            None if beta is None else db.astype(beta.dtype),
+            None if res_proto is None else dyf.astype(res_proto.dtype))
+
+
+_bn_train.defvjp(_bn_train_fwd_rule, _bn_train_bwd_rule)
 
 
 def _update_running_stats(running_mean, running_var, mean, var, momentum,
@@ -204,15 +292,15 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
     # NCHW 4-D batch norm participates in the channels-last region
     # (_layout.py): computing with the channel axis last makes the
     # boundary transposes sit directly against the neighboring convs'
-    # and pools', where XLA cancels them — and is what makes the input eligible for the fused Pallas
-    # kernel (ops/pallas/fused_bn.py), which is NHWC-native.
+    # and pools', where XLA cancels them — and is what makes the input
+    # eligible for the Pallas kernels (ops/pallas/fused_bn.py), which
+    # are NHWC-native.
     from ._layout import channels_last_region
     from ...ops.pallas import fused_bn as pbn
     nhwc_internal, to_internal, from_internal = channels_last_region(
         x.ndim, channel_last)
     eff_last = channel_last or nhwc_internal
     ch_axis = x.ndim - 1 if eff_last else 1
-    reduce_axes = tuple(i for i in range(x.ndim) if i != ch_axis)
     use_stats = (not training) if use_global_stats is None else use_global_stats
     has_wb = weight is not None
     has_res = residual is not None
@@ -227,9 +315,9 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
         res = rest[-1] if has_res else None
         return wb, res
 
-    def fused_ok(xi):
+    def fused_ok(xi, training=False):
         return (has_wb and eff_last
-                and fused_bn_active(xi.shape, xi.dtype))
+                and fused_bn_active(xi.shape, xi.dtype, training))
 
     res_args = (_t(residual),) if has_res else ()
     wb_args = (_t(weight), _t(bias)) if has_wb else ()
@@ -263,34 +351,16 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
         wb, res = split_rest(rest)
         if res is not None:
             res = to_internal(res)
-        if fused_ok(x):
+        if fused_ok(x, training=True):
             c = x.shape[-1]
             y2, mean, var = pbn.fused_bn_train(
                 x.reshape(-1, c), wb[0], wb[1], epsilon, act=act,
                 residual=None if res is None else res.reshape(-1, c))
             return from_internal(y2.reshape(x.shape)), mean, var
-        # stats via sum * cached-reciprocal rather than jnp.mean/var:
-        # their internal divide lifts the element COUNT as a fresh
-        # device scalar per call — one more per-BN host->device
-        # transfer on the eager train path (satellite-6 audit).
-        # 16-bit inputs keep jnp.mean's f32 accumulator (and its
-        # result dtype), matching the fused kernel's discipline.
-        n_elems = 1
-        for i in reduce_axes:
-            n_elems *= x.shape[i]
-        inv = _scalar(1.0 / n_elems)
-        half = jnp.dtype(x.dtype).itemsize == 2
-        xf = x.astype(jnp.float32) if half else x
-        mean = (jnp.sum(xf, axis=reduce_axes) * inv).astype(x.dtype)
-        xc = x - bshape(mean, x.ndim)
-        xcf = xc.astype(jnp.float32) if half else xc
-        var = (jnp.sum(xcf * xcf, axis=reduce_axes) * inv).astype(x.dtype)
-        y = xc * jax.lax.rsqrt(bshape(var, x.ndim) + _scalar(epsilon))
-        if wb:
-            y = y * bshape(wb[0], x.ndim) + bshape(wb[1], x.ndim)
-        if res is not None:
-            y = y + res
-        return from_internal(_apply_act(y, act)), mean, var
+        y, mean, var = _bn_train(
+            x, wb[0] if wb else None, wb[1] if wb else None, res,
+            float(epsilon), act, ch_axis)
+        return from_internal(y), mean, var
 
     args = (x,) + wb_args + res_args
     y, mean, var = apply(f"{what}_train", f, args, n_outputs=3)
@@ -304,9 +374,12 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None,
                data_format="NCHW", use_global_stats=None, name=None):
     """Batch norm with running-stat update (reference batch_norm_op.cc).
     Running stats are updated in-place on the passed tensors, mirroring the
-    reference's mutable mean/variance variables. Under the ``fused_bn``
-    flag a channels-last affine BN lowers to the one-pass Pallas kernel
-    (ops/pallas/fused_bn.py)."""
+    reference's mutable mean/variance variables. Training mode is one
+    XLA composition (``_bn_train_forward``: float32 statistics in one
+    pass, one rounding) that the compiler fuses into the neighbouring
+    convolutions; the Pallas kernels (ops/pallas/fused_bn.py) take a
+    channels-last affine BN in eval mode under ``fused_bn=auto`` on a
+    TPU, and in training mode only under ``fused_bn=always``."""
     return _batch_norm_impl(x, running_mean, running_var, weight, bias,
                             training, momentum, epsilon, data_format,
                             use_global_stats, "identity", None,
@@ -319,10 +392,11 @@ def fused_batch_norm_act(x, running_mean, running_var, weight, bias,
                          use_global_stats=None, name=None):
     """``y = act(batch_norm(x) + residual)`` as ONE op — the analog of
     the reference's fused_bn_activation_op (act only) and
-    fused_bn_add_activation_op (act + residual). Under the ``fused_bn``
-    flag the whole chain runs as a single Pallas kernel; otherwise it
-    is the eager/XLA composition with identical semantics (including
-    the running-stat update and the ``collect_stat_updates``
+    fused_bn_add_activation_op (act + residual). The whole chain is
+    one XLA composition in float32, rounded once (training mode:
+    ``_bn_train_forward``), or a single Pallas kernel where ``fused_bn``
+    resolves to it (see ``batch_norm``), with identical semantics
+    (including the running-stat update and the ``collect_stat_updates``
     functionalization under a compiled trainer step)."""
     from ...ops.pallas.fused_bn import ACTS
     if act not in ACTS:

@@ -3,10 +3,19 @@
 TPU-native analog of the reference's fused BN CUDA ops
 (/root/reference/paddle/fluid/operators/fused/fused_bn_activation_op.cu
 and fused_bn_add_activation_op.cu): ONE kernel owns the whole
-stats + normalize + activation (+ residual-add) chain instead of the
-multi-pass XLA lowering (multiply_reduce / convert_reduce /
-multiply_subtract fusions; their share of a ResNet-50 step on the v5e:
-not measured).
+stats + normalize + activation (+ residual-add) chain.
+
+What the v5e said of the TRAINING kernels (PERF.md, PR 26;
+ResNet-50, batch 128, bf16): the step is shorter without them. A
+``tpu_custom_call`` is a wall to XLA: it cannot hang the statistics on
+the convolution that produces x nor the normalize + ReLU on the one
+that consumes y, and it copies convolution outputs into the row-major
+layout the call demands; the plain composition
+(``nn/functional/norm.py::_bn_train_forward``) has neither cost, so
+``fused_bn=auto`` takes it in training mode and ``fused_bn_train``
+runs under ``fused_bn=always`` only (tests, the ablation). The
+given-stats kernels (``fused_bn_norm``, ``local_moments``) are still
+what ``auto`` picks on a TPU; on the v5e they are not measured.
 
 The training kernel is a two-pass-in-one-call design: a sequential
 (2, row_blocks) grid whose first phase accumulates per-channel
@@ -167,9 +176,14 @@ def _train_fwd(x2, g, b, res, eps, act):
 
 def _stat_cotangent_terms(x2, mean, dmean, dvar, inv_count):
     """Fold cotangents that flow INTO the batch-stat outputs back into
-    dx (rare — running-stat consumers detach the stats, so these are
-    zeros on the training path and XLA folds the broadcast away under
-    jit): mean = sum(x)/n, var = sum(x^2)/n - mean^2."""
+    dx: mean = sum(x)/n, var = sum(x^2)/n - mean^2. Running-stat
+    consumers detach the stats, so on the training path these are
+    zeros, but a ``custom_vjp`` rule receives them as zero ARRAYS and
+    XLA may not fold ``0 * (x - mean)`` in floating point: the compiled
+    step keeps a full-size ``multiply`` by a zero constant, a read of
+    x and a read and a write of dx for every norm (seen in the v5e's
+    compiled text, PR 26). The composition that ``auto`` runs gives
+    the statistics no gradient and has no such pass."""
     xf = x2.astype(jnp.float32)
     extra = (dmean[None, :]
              + 2.0 * dvar[None, :] * (xf - mean[None, :])) * inv_count

@@ -3,8 +3,9 @@
 One kernel produces dx, dgamma, dbeta (and dresidual) — the analog of
 the reference's FusedBatchNormActGradKernel: the activation mask, the
 two per-channel reductions (sum dy, sum dy*xhat) and the dx recurrence
-never leave the kernel, where the XLA lowering spends three
-memory-bound passes plus layout copies per BN.
+never leave the kernel. On the v5e the training-mode kernel lost to
+the XLA composition, which rides the dgrad and wgrad convolution
+fusions (PERF.md, PR 26): it runs behind ``fused_bn=always`` only.
 
 Training-mode dx couples every row to the batch reductions, so the
 kernel mirrors the forward's two-phase sequential grid: phase 0
@@ -14,8 +15,7 @@ phase that accumulates dgamma/dbeta while it streams.
 
 The ``fused_bn_bwd`` flag picks between these kernels and
 ``*_bwd_xla`` — the jnp composition that is both the CPU/unaligned
-fallback and the on-chip ablation arm (the ``fused_adam`` lesson:
-publish the ablation if XLA wins). Same bf16 discipline as the
+fallback behind a kernel forward and the forward-only ablation arm. Same bf16 discipline as the
 forward: reductions in f32, count exact, outputs cast at the edge.
 """
 

@@ -1,5 +1,7 @@
 """Compile the main-path Pallas kernels for a described TPU v5e, at real
 widths, without a chip: what Mosaic refuses here it refuses on the chip.
+And one ResNet-50 stage-1 bottleneck, forward and backward, whose text
+shows whether XLA fused batch norm into the convolutions (ISSUE 26).
 
 The only file that describes the chip. The topology is described inside
 a module-scoped fixture — never at import, in a ``skipif`` or in
@@ -158,6 +160,127 @@ def test_kernel_compiles_for_v5e(case, one_chip, for_the_chip):
         kernel = re.search(r"p1t_[a-z0-9_]*[a-z0-9]", name)
         assert kernel, name
         assert kernel.group(0) in scopes[name], (name, scopes[name])
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of an HLO module, the
+    entry computation under ``"ENTRY"`` too."""
+    out, body = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            body = out.setdefault(m.group(2), [])
+            if m.group(1):
+                out["ENTRY"] = body
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line)
+    return out
+
+
+def _multiplies_by_zero(text, elements):
+    """Instructions ``multiply(a, broadcast(constant(0)))`` of at least
+    ``elements`` elements, in any computation."""
+    hits = []
+    for lines in _computations(text).values():
+        zeros, zero_broadcasts = set(), set()
+        for line in lines:
+            m = re.match(r"\s+(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                         r"(\w[\w\-]*)\((.*?)\)", line)
+            if not m:
+                continue
+            name, dims, op, operands = m.groups()
+            names = set(re.findall(r"%([\w.\-]+)", operands))
+            if op == "constant" and operands.strip() in ("0", "-0"):
+                zeros.add(name)
+            elif op == "broadcast" and names & zeros:
+                zero_broadcasts.add(name)
+            elif op == "multiply" and names & zero_broadcasts:
+                size = 1
+                for d in dims.split(","):
+                    size *= int(d or 1)
+                if size >= elements:
+                    hits.append(line.strip()[:160])
+    return hits
+
+
+def _stage1_bottleneck(one_chip, fused):
+    """Compiled text of loss + gradients of one stage-1 bottleneck
+    (b128, 56x56, 256 -> 64 -> 64 -> 256, bf16, ``relu(bn(conv(x)))``,
+    residual add) traced as ``make_train_step`` traces a model: tape
+    off, ``jax.grad`` outside, the batch statistics leaving as aux."""
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.nn import functional as F
+    from paddle1_tpu.nn.functional.norm import collect_stat_updates
+
+    def conv_bn(x, p, i, pad, res=None):
+        x = F.conv2d(x, Tensor(p[f"w{i}"]), padding=pad)
+        c = p[f"g{i}"].shape[0]
+        x = F.batch_norm(x, Tensor(jnp.zeros((c,), BF16)),
+                         Tensor(jnp.ones((c,), BF16)), Tensor(p[f"g{i}"]),
+                         Tensor(p[f"b{i}"]), training=True)
+        return F.relu(x if res is None else x + res)
+
+    def loss(p, x):
+        with ae.no_grad(), collect_stat_updates() as sink:
+            t = Tensor(x)
+            out = conv_bn(t, p, 1, 0)
+            out = conv_bn(out, p, 2, 1)
+            out = conv_bn(out, p, 3, 0, res=t)
+        return ((out.data.astype(F32) ** 2).mean(),
+                [(u.mean, u.var) for u in sink])
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    p = {"w1": s(64, 256, 1, 1), "w2": s(64, 64, 3, 3),
+         "w3": s(256, 64, 1, 1), "g1": s(64), "b1": s(64), "g2": s(64),
+         "b2": s(64), "g3": s(256), "b3": s(256)}
+    with flags_guard(fused_bn=fused):
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)).lower(
+                p, s(128, 256, 56, 56)).compile().as_text()
+
+
+def test_bottleneck_batch_norm_fuses_into_the_convolutions(
+        one_chip, for_the_chip, monkeypatch):
+    """Under ``auto`` training-mode batch norm is an XLA composition the
+    compiler is free to fuse: no custom call, no pass over a zero
+    cotangent, each norm's two sums out of a fusion that holds a
+    convolution, fewer layout copies than the kernels need. A compile
+    guards the fusion, not the time."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    auto = _stage1_bottleneck(one_chip, "auto")
+    kernels = _stage1_bottleneck(one_chip, "always")
+    full = 128 * 56 * 56 * 64
+    assert "tpu_custom_call" not in auto
+    assert kernels.count('custom_call_target="tpu_custom_call"') == 6
+    assert _multiplies_by_zero(auto, full) == []
+    assert _multiplies_by_zero(kernels, full)     # the census can see one
+
+    # the forward of each of the three norms: a fusion that holds the
+    # convolution and gives (sum[C], sum of squares[C], activation);
+    # autodiff marks the backward's instructions transpose(jvp(..))
+    bodies = _computations(auto)
+    forward_sums = []
+    for line in bodies["ENTRY"]:
+        m = re.search(r"= \(f32\[(\d+)\]\S*, f32\[\1\]\S*, "
+                      r"bf16\[128,56,56,\1\]\S*\) fusion\(.*"
+                      r"calls=%([\w.\-]+)", line)
+        if m and "transpose(jvp" not in line:
+            body = "\n".join(bodies[m.group(2)])
+            assert " convolution(" in body and " reduce(" in body, line
+            forward_sums.append(int(m.group(1)))
+    assert sorted(forward_sums) == [64, 64, 256]
+
+    def activation_copies(entry):
+        return len(re.findall(r"= bf16\[128,(?:56,56,\d+|\d+,56,56)\]\S* "
+                              r"copy\(", "\n".join(entry)))
+    assert activation_copies(bodies["ENTRY"]) <= 2
+    assert activation_copies(bodies["ENTRY"]) < activation_copies(
+        _computations(kernels)["ENTRY"])
 
 
 def test_paged_supported_admits_only_what_compiles(one_chip, for_the_chip):

@@ -4,7 +4,9 @@ discipline. Parity matrix: fwd + bwd, fp32 + bf16, train + eval,
 with/without residual-add and relu, kernel path vs the XLA lowering;
 plus the flag gating, the SyncBatchNorm local-stats reuse, the
 collect_stat_updates functionalization, and the eval-mode
-no-copy/no-retrace regressions (ISSUE 15 satellite 6)."""
+no-copy/no-retrace regressions (ISSUE 15 satellite 6). ISSUE 26: the
+training-mode XLA composition that ``fused_bn=auto`` runs, against a
+float64 numpy batch norm, and what ``auto`` / ``always`` resolve to."""
 
 import warnings
 
@@ -196,21 +198,325 @@ class TestFusedBnParity:
         np.testing.assert_allclose(outs["never"], outs["always"],
                                    rtol=1e-5, atol=1e-6)
 
-    def test_auto_threshold_crossover(self):
-        # fused_bn=auto applies the fused_bn_auto_mb crossover; on CPU
-        # auto additionally resolves to the XLA path (flag_active), so
-        # probe the resolution helper directly
+    # what the flag resolves to, on shapes either side of the 4 MiB
+    # threshold that ``auto`` had (fused_bn_auto_mb, never measured):
+    # (flag, shape, training) -> kernel?
+    BIG, SMALL = (1024, 1024, 64), (8, 8, 64)    # 256 MiB / 16 KiB of f32
+
+    @pytest.mark.parametrize("flag_value,shape,training,want", [
+        ("always", BIG, True, True),
+        ("always", SMALL, True, True),
+        ("always", SMALL, False, True),
+        ("never", BIG, True, False),
+        ("never", BIG, False, False),
+        ("auto", BIG, True, False),      # on every backend
+        ("auto", SMALL, True, False),
+    ])
+    def test_flag_resolution(self, flag_value, shape, training, want):
         from paddle1_tpu.nn.functional.norm import fused_bn_active
-        big = (1024, 1024, 64)    # 256 MiB of f32
-        small = (8, 8, 64)
-        with flags_guard(fused_bn="always"):
-            assert fused_bn_active(big, jnp.float32)
-            assert fused_bn_active(small, jnp.float32)  # always bypasses
-        with flags_guard(fused_bn="never"):
-            assert not fused_bn_active(big, jnp.float32)
-        if jax.default_backend() != "tpu":
-            with flags_guard(fused_bn="auto"):
-                assert not fused_bn_active(big, jnp.float32)
+        with flags_guard(fused_bn=flag_value):
+            assert fused_bn_active(shape, jnp.float32, training) is want
+
+    @pytest.mark.parametrize("shape", [BIG, SMALL])
+    def test_auto_on_a_tpu_takes_kernels_for_given_stats_only(
+            self, shape, monkeypatch):
+        # auto asks the default backend: make it say "tpu"
+        from paddle1_tpu.nn.functional.norm import fused_bn_active
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with flags_guard(fused_bn="auto"):
+            assert not fused_bn_active(shape, jnp.float32, training=True)
+            assert fused_bn_active(shape, jnp.float32, training=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        with flags_guard(fused_bn="auto"):
+            assert not fused_bn_active(shape, jnp.float32, training=False)
+
+    def test_the_threshold_flag_is_gone(self):
+        from paddle1_tpu.core.errors import InvalidArgumentError
+        from paddle1_tpu.core.flags import flag
+        with pytest.raises(InvalidArgumentError):
+            flag("fused_bn_auto_mb")
+
+
+def _bn_float64(x, g, b, res, cot, eps, act, ch_axis):
+    """Plain batch norm, forward and backward, in float64 numpy."""
+    x, g, b, cot = (np.asarray(a, np.float64) for a in (x, g, b, cot))
+    axes = tuple(i for i in range(x.ndim) if i != ch_axis)
+    bs = [1] * x.ndim
+    bs[ch_axis] = -1
+    n = x.size // x.shape[ch_axis]
+    mean = x.mean(axis=axes)
+    var = x.var(axis=axes)
+    rstd = 1.0 / np.sqrt(var + eps)
+    xhat = (x - mean.reshape(bs)) * rstd.reshape(bs)
+    pre = xhat * g.reshape(bs) + b.reshape(bs)
+    if res is not None:
+        pre = pre + np.asarray(res, np.float64)
+    y = np.maximum(pre, 0.0) if act == "relu" else pre
+    dy = cot * (pre > 0) if act == "relu" else cot
+    dg = (dy * xhat).sum(axis=axes)
+    db = dy.sum(axis=axes)
+    dx = (g * rstd).reshape(bs) * (
+        dy - db.reshape(bs) / n - xhat * dg.reshape(bs) / n)
+    return {"y": y, "dx": dx, "dgamma": dg, "dbeta": db, "dres": dy,
+            "mean": mean, "var": var}
+
+
+class TestTrainComposition:
+    """What ``fused_bn=auto`` runs in training mode, through the public
+    functional and the tape, against float64 numpy."""
+
+    def _run(self, dtype, act, use_res, layout, fused="auto"):
+        import ml_dtypes
+        half = dtype == "bfloat16"
+        dt = np.dtype(ml_dtypes.bfloat16) if half else np.dtype(np.float32)
+        x, g, b, m0, v0, res = _data(dtype=dt)
+        cot = np.random.default_rng(7).standard_normal(x.shape).astype(dt)
+        g, b = g.astype(dt), b.astype(dt)
+        ch_axis = 1
+        if layout == "NHWC":
+            x, res, cot = (np.ascontiguousarray(a.transpose(0, 2, 3, 1))
+                           for a in (x, res, cot))
+            ch_axis = 3
+        ts = [to_tensor(a) for a in (x, g, b, res)]
+        for t in ts:
+            t.stop_gradient = False
+        xt, gw, bw, rt = ts
+        m, v = to_tensor(m0.copy()), to_tensor(v0.copy())
+        with flags_guard(conv_nhwc="never", fused_bn=fused):
+            if act == "identity" and not use_res:
+                out = F.batch_norm(xt, m, v, gw, bw, training=True,
+                                   data_format=layout)
+            else:
+                out = F.fused_batch_norm_act(
+                    xt, m, v, gw, bw, training=True, act=act,
+                    data_format=layout, residual=rt if use_res else None)
+            assert out.data.dtype == x.dtype       # rounded once, to x's
+            (out.astype("float32") * to_tensor(
+                cot.astype(np.float32))).sum().backward()
+        got = {"y": out, "dx": xt.grad, "dgamma": gw.grad, "dbeta": bw.grad}
+        if use_res:
+            got["dres"] = rt.grad
+        got = {k: np.asarray(t.astype("float32").numpy(), np.float64)
+               for k, t in got.items()}
+        got["mean"] = (np.asarray(m.numpy(), np.float64) - 0.9 * m0) / 0.1
+        got["var"] = (np.asarray(v.numpy(), np.float64) - 0.9 * v0) / 0.1
+        want = _bn_float64(x, g, b, res if use_res else None, cot, 1e-5,
+                           act, ch_axis)
+        return got, want
+
+    @pytest.mark.parametrize("layout", ["NHWC", "NCHW"])
+    @pytest.mark.parametrize("use_res", [False, True])
+    @pytest.mark.parametrize("act", ["identity", "relu"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_against_float64(self, dtype, act, use_res, layout):
+        got, want = self._run(dtype, act, use_res, layout)
+        # float32: sums of 256 terms; bfloat16: y, dx, dgamma and dbeta
+        # are each rounded ONCE (2^-9 relative), everything before the
+        # rounding is float32
+        rtol, atol = (5e-3, 2e-3) if dtype == "bfloat16" else (2e-5, 2e-5)
+        for k, a in got.items():
+            tol = (2e-4, 2e-4) if k in ("mean", "var") else (rtol, atol)
+            np.testing.assert_allclose(
+                a, want[k], rtol=tol[0], atol=tol[1],
+                err_msg=f"{k} {dtype} act={act} res={use_res} {layout}")
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_statistics_stay_float32(self, dtype):
+        from paddle1_tpu.nn.functional.norm import collect_stat_updates
+        x, g, b, m0, v0, _ = _data()
+
+        def step(xa):
+            return F.batch_norm(
+                to_tensor(xa), to_tensor(m0.copy()), to_tensor(v0.copy()),
+                to_tensor(g.astype(dtype)), to_tensor(b.astype(dtype)),
+                training=True).data
+
+        with flags_guard(conv_nhwc="always", fused_bn="auto"):
+            with collect_stat_updates() as sink:
+                y = jax.jit(step)(jnp.asarray(x, dtype))
+        assert y.dtype == jnp.dtype(dtype)
+        assert sink[0].mean.dtype == jnp.float32
+        assert sink[0].var.dtype == jnp.float32
+
+    @pytest.mark.parametrize("dtype,centre,rtol", [
+        # bf16 data at mean 300, spread 1: a bf16 mean is off by up to 1
+        # and a bf16 sum of squares loses the variance altogether; the
+        # float32 sums keep what the data had
+        ("bfloat16", 300.0, 2e-2),
+        # float32 data at mean 1000: E[x^2] - mean^2 in float32 keeps 4
+        # of the variance's 24 bits, the centred second pass all
+        ("float32", 1000.0, 1e-5),
+    ])
+    def test_variance_keeps_the_data_s_bits(self, dtype, centre, rtol):
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(centre + rng.standard_normal((8, 4, 4, 16)), dtype)
+        m, v = to_tensor(np.zeros(16, "f4")), to_tensor(np.zeros(16, "f4"))
+        with flags_guard(fused_bn="auto"):
+            F.batch_norm(to_tensor(x), m, v, to_tensor(np.ones(16, "f4")),
+                         to_tensor(np.zeros(16, "f4")), training=True,
+                         momentum=0.0, data_format="NHWC")
+        x64 = np.asarray(x.astype(jnp.float32), np.float64)
+        np.testing.assert_allclose(m.numpy(), x64.mean(axis=(0, 1, 2)),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(v.numpy(), x64.var(axis=(0, 1, 2)),
+                                   rtol=rtol, atol=rtol)
+
+    def test_no_affine(self):
+        x, _, _, m0, v0, _ = _data()
+        xt = to_tensor(x)
+        xt.stop_gradient = False
+        with flags_guard(conv_nhwc="never", fused_bn="auto"):
+            out = F.batch_norm(xt, to_tensor(m0.copy()),
+                               to_tensor(v0.copy()), training=True)
+            cot = np.random.default_rng(7).standard_normal(
+                x.shape).astype(np.float32)
+            (out * to_tensor(cot)).sum().backward()
+        c = x.shape[1]
+        want = _bn_float64(x, np.ones(c), np.zeros(c), None, cot, 1e-5,
+                           "identity", 1)
+        np.testing.assert_allclose(out.numpy(), want["y"], rtol=2e-5,
+                                   atol=2e-5)
+        np.testing.assert_allclose(xt.grad.numpy(), want["dx"], rtol=2e-5,
+                                   atol=2e-5)
+
+
+def _zero_fed_full_size_ops(jaxpr, full_size):
+    """Census of a (closed) jaxpr, sub-jaxprs included: the ``mul`` /
+    ``add`` / ``sub`` equations of ``full_size`` elements with an
+    operand that is known to be all zeros (a zero literal, a broadcast
+    of one, or elementwise / shape ops of such)."""
+    hits = []
+
+    def is_zero_literal(v):      # a Literal has a value, a Var has none
+        return (hasattr(v, "val") and np.ndim(v.val) == 0
+                and float(v.val) == 0.0)
+
+    def walk(jp, zero_in):
+        zero = set(zero_in)
+
+        def z(v):
+            return is_zero_literal(v) or (
+                not hasattr(v, "val") and v in zero)
+
+        for eqn in jp.eqns:
+            name = eqn.primitive.name
+            subs = [p for p in eqn.params.values()
+                    if hasattr(p, "eqns") or hasattr(p, "jaxpr")]
+            if subs:
+                for sub in subs:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if len(inner.invars) != len(eqn.invars):
+                        walk(inner, ())
+                        continue
+                    walk(inner, [iv for iv, ov in zip(inner.invars,
+                                                      eqn.invars) if z(ov)])
+                continue
+            ins = [z(v) for v in eqn.invars]
+            size = int(np.prod(eqn.outvars[0].aval.shape)) \
+                if eqn.outvars else 0
+            if name in ("mul", "add", "sub", "add_any") and any(ins) \
+                    and size >= full_size:
+                hits.append(str(eqn))
+            if (name in ("broadcast_in_dim", "reshape", "convert_element_type",
+                         "squeeze", "expand_dims", "neg", "copy")
+                    and all(ins)) or (name == "mul" and any(ins)) \
+                    or (name == "zeros_like"):
+                zero.update(eqn.outvars)
+    walk(getattr(jaxpr, "jaxpr", jaxpr), ())
+    return hits
+
+
+class TestStatisticsCarryNoGradient:
+    """The batch statistics feed the running averages alone: no term for
+    their cotangents, zero or not, in the backward."""
+
+    ROWS, C = (4, 8, 8), 64
+
+    def _fn(self, fused):
+        x, g, b, m0, v0, _ = _data(self.ROWS, self.C)
+        from paddle1_tpu.autograd import engine as ae
+        from paddle1_tpu.nn.functional.norm import collect_stat_updates
+
+        def loss(xa, ga, ba, weight_of_stats=0.0):
+            with flags_guard(conv_nhwc="always", fused_bn=fused), \
+                    ae.no_grad(), collect_stat_updates() as sink:
+                y = F.fused_batch_norm_act(
+                    Tensor(xa), to_tensor(m0.copy()), to_tensor(v0.copy()),
+                    Tensor(ga), Tensor(ba), training=True, act="relu").data
+            stats = sink[0].mean.sum() + sink[0].var.sum()
+            return (y * y).sum() + weight_of_stats * stats, stats
+
+        return loss, (jnp.asarray(x), jnp.asarray(g), jnp.asarray(b))
+
+    def test_gradient_asked_of_the_statistics_is_zero(self):
+        loss, args = self._fn("auto")
+        grads = jax.grad(lambda *a: loss(*a)[1], argnums=(0, 1, 2))(*args)
+        for gr in grads:
+            assert not np.asarray(gr).any()
+        # and weighting them into a loss changes no gradient
+        a = jax.grad(lambda *a: loss(*a)[0])(*args)
+        b = jax.grad(lambda *a: loss(*a, weight_of_stats=3.0)[0])(*args)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_no_full_size_op_on_a_zero_cotangent(self):
+        full = int(np.prod(self.ROWS)) * self.C
+        loss, args = self._fn("auto")
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2),
+                                        has_aux=True))(*args)
+        assert _zero_fed_full_size_ops(jaxpr, full) == []
+        # the census sees what it looks for: the kernel arm's rule adds
+        # (dmean + 2 dvar (x - mean)) / n with dmean = dvar = zeros
+        loss, args = self._fn("always")
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2),
+                                        has_aux=True))(*args)
+        assert _zero_fed_full_size_ops(jaxpr, full)
+
+
+def _pallas_calls(jaxpr):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "pallas_call"
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                n += _pallas_calls(inner)
+    return n
+
+
+class TestBottleneckCensus:
+    """A ResNet bottleneck's lowered training step: ``auto`` reaches no
+    ``pallas_call`` in training mode, on any backend; ``always`` does."""
+
+    def _jaxpr(self, fused, backend, monkeypatch):
+        from paddle1_tpu.autograd import engine as ae
+        from paddle1_tpu.nn.functional.norm import collect_stat_updates
+        from paddle1_tpu.vision.models.resnet import BottleneckBlock
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        paddle.seed(0)
+        block = BottleneckBlock(64, 16)
+        block.train()
+        names = list(block.state_dict())
+        params = {k: block.state_dict()[k].data for k in names}
+
+        def loss(params, xa):
+            with ae.no_grad(), block.load_functional_state(params), \
+                    collect_stat_updates():
+                return (block(Tensor(xa)).data ** 2).mean()
+
+        x = jnp.zeros((4, 64, 8, 8), jnp.float32)
+        # round the whole trace: a custom_vjp's backward rule is traced
+        # after the forward function has returned
+        with flags_guard(conv_nhwc="always", fused_bn=fused,
+                         fused_bn_bwd=fused):
+            return jax.make_jaxpr(jax.grad(loss))(params, x)
+
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_auto_reaches_no_pallas_call(self, backend, monkeypatch):
+        assert _pallas_calls(self._jaxpr("auto", backend, monkeypatch)) == 0
+
+    def test_always_still_does(self, monkeypatch):
+        # three norms, a forward and a backward kernel each
+        assert _pallas_calls(self._jaxpr("always", "cpu", monkeypatch)) == 6
 
 
 class TestCompiledTrainerIntegration:
