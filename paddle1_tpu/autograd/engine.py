@@ -34,7 +34,7 @@ from ..core.tensor import Tensor
 
 __all__ = ["apply", "apply_custom_vjp", "run_backward", "grad", "no_grad",
            "enable_grad", "is_grad_enabled", "set_grad_enabled", "GradNode",
-           "traced_scopes"]
+           "traced_scopes", "scope"]
 
 _tls = threading.local()
 
@@ -96,6 +96,16 @@ def traced_scopes():
         yield
     finally:
         _tls.scopes = was
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` inside :func:`traced_scopes`, nothing
+    outside: for a model that names a part of its forward pass which is no
+    layer and no op of its own (a loop over shared layers, a group of
+    heads)."""
+    if getattr(_tls, "scopes", False):
+        return jax.named_scope(name)
+    return contextlib.nullcontext()
 
 
 def _is_float(x) -> bool:

@@ -53,8 +53,10 @@ def recompute(function: Callable, *args, **kwargs):
             full[pos] = Tensor(arr, stop_gradient=True)
         return full
 
+    # a Layer is called as a Layer: its hooks run and, in a traced step,
+    # its scope is opened inside the segment
     fwd_callable = (bound_method if bound_method is not None else
-                    layer.forward if layer is not None else function)
+                    layer if layer is not None else function)
 
     if layer is not None:
         names = list(layer.functional_state().keys())

@@ -262,19 +262,21 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
     if recompute:
         # Rematerialisation must be per-BLOCK to cut peak memory
         # (checkpointing the whole loss would re-run the forward without
-        # reducing the residual set). Flip the recompute switch on every
-        # block-structured sublayer that supports it.
-        from ..nn.layer_transformer import TransformerEncoder
+        # reducing the residual set). Flip the switch on every sublayer
+        # that declares one: TransformerEncoder, a model's own block
+        # stack, whatever wraps its blocks in fleet.utils.recompute
+        # while ``enable_recompute`` is set.
         flipped = 0
         for sub in layer.sublayers(include_self=True):
-            if isinstance(sub, TransformerEncoder):
+            if hasattr(sub, "enable_recompute"):
                 sub.enable_recompute = True
                 flipped += 1
         if not flipped:
             import warnings
             warnings.warn(
-                "recompute=True: no recompute-capable blocks found "
-                "(TransformerEncoder); wrap your own blocks with "
+                "recompute=True: no recompute-capable blocks found (no "
+                "sublayer declares enable_recompute, as "
+                "TransformerEncoder does); wrap your own blocks with "
                 "fleet.utils.recompute for per-segment remat")
 
     def train_step(params, opt_state, batch, key, lr):

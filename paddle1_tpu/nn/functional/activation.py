@@ -20,7 +20,7 @@ __all__ = [
     "tanhshrink", "leaky_relu", "prelu", "rrelu", "log_sigmoid", "maxout",
     "silu", "swish", "mish", "softplus", "softsign", "tanh", "tanh_",
     "thresholded_relu", "log_softmax", "softmax", "softmax_", "glu",
-    "gumbel_softmax",
+    "swiglu", "gumbel_softmax",
 ]
 
 
@@ -205,6 +205,13 @@ def log_softmax(x, axis=-1, dtype=None, name=None):
 
 def glu(x, axis=-1, name=None):
     return apply("glu", lambda x: jax.nn.glu(x, axis=axis), (_t(x),))
+
+
+def swiglu(gate, up, name=None):
+    """``silu(gate) * up``: the gate of a SwiGLU feed-forward (Shazeer
+    2020, arXiv:2002.05202) on its two projections."""
+    return apply("swiglu", lambda g, u: jax.nn.silu(g) * u,
+                 (_t(gate), _t(up)))
 
 
 def gumbel_softmax(x, temperature=1.0, hard=False, axis=-1, name=None):

@@ -18,7 +18,7 @@ from ...autograd.engine import apply
 from ...core.tensor import Tensor, to_tensor
 
 __all__ = ["scaled_dot_product_attention", "attention_ref",
-           "paged_attention"]
+           "paged_attention", "rotary_embedding"]
 
 
 def _t(x):
@@ -51,6 +51,29 @@ def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, vh)
     return jnp.swapaxes(out, 1, 2)
+
+
+def rotary_embedding(x, theta=10000.0, positions=None, name=None):
+    """Rotary positions (Su et al. 2021, arXiv:2104.09864) on a
+    [batch, seq, heads, dim] query or key, rotate-half pairing: channel
+    ``i`` of the first half turns with channel ``i`` of the second by the
+    angle ``position * theta ** (-2 i / dim)``. ``positions``: [seq] or
+    [batch, seq] integers, ``0..seq-1`` when None. The angles and the
+    rotation are float32 whatever ``x`` is; the result has ``x``'s dtype."""
+    args = (_t(x),) + ((_t(positions),) if positions is not None else ())
+
+    def f(x, *pos):
+        half = x.shape[-1] // 2
+        at = (pos[0] if pos else jnp.arange(x.shape[1])).astype(jnp.float32)
+        inv_freq = jnp.float32(theta) ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+        angle = at[..., None, None] * inv_freq       # [(batch,) seq, 1, half]
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        xf = x.astype(jnp.float32)
+        a, b = xf[..., :half], xf[..., half:]
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               axis=-1).astype(x.dtype)
+    return apply("rotary_embedding", f, args)
 
 
 def use_flash_for(q, k) -> bool:

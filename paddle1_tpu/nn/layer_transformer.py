@@ -26,9 +26,9 @@ from .layer_base import Layer
 from .layer_common import Dropout, Linear
 from .layer_norm_act import LayerNorm, LayerList
 
-__all__ = ["MultiHeadAttention", "TransformerEncoderLayer",
-           "TransformerEncoder", "TransformerDecoderLayer",
-           "TransformerDecoder", "Transformer"]
+__all__ = ["MultiHeadAttention", "GatedFeedForward",
+           "TransformerEncoderLayer", "TransformerEncoder",
+           "TransformerDecoderLayer", "TransformerDecoder", "Transformer"]
 
 
 def _convert_attention_mask(attn_mask, dtype):
@@ -257,6 +257,25 @@ class MultiHeadAttention(Layer):
         return out if len(outs) == 1 else tuple(outs)
 
 
+class GatedFeedForward(Layer):
+    """``down(silu(gate(x)) * up(x))``, the SwiGLU feed-forward of the
+    decoder families after 2020; no reference analog. Three ``linear``
+    ops and one ``swiglu`` in a trace."""
+
+    def __init__(self, d_model, dim_feedforward, weight_attr=None,
+                 bias_attr=False):
+        super().__init__()
+        self.gate_proj = Linear(d_model, dim_feedforward, weight_attr,
+                                bias_attr)
+        self.up_proj = Linear(d_model, dim_feedforward, weight_attr,
+                              bias_attr)
+        self.down_proj = Linear(dim_feedforward, d_model, weight_attr,
+                                bias_attr)
+
+    def forward(self, x):
+        return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
+
+
 class TransformerEncoderLayer(Layer):
     def __init__(self, d_model, nhead, dim_feedforward, dropout=0.1,
                  activation="relu", attn_dropout=None, act_dropout=None,
@@ -318,6 +337,8 @@ class TransformerEncoder(Layer):
             [copy.deepcopy(encoder_layer) for _ in range(num_layers - 1)])
         self.num_layers = num_layers
         self.norm = norm
+        # declared, so that ParallelEngine(recompute=True) finds it
+        self.enable_recompute = False
 
     def forward(self, src, src_mask=None, cache=None):
         # In-graph pipeline parallelism: when the engine tagged this

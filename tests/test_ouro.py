@@ -1,0 +1,397 @@
+"""Ouro, the looped language model (ISSUE 27): rotary positions, the
+sandwich-norm block and the loop over shared weights against the plain
+reference (``benchmarks/reference/ouro_2p6b.py``), the exit distribution and
+its loss, recomputation, and the names a traced step carries. CPU, tiny
+sizes, seeded weights."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import paddle1_tpu as paddle  # noqa: E402
+from benchmarks.reference import ouro_2p6b as ref  # noqa: E402
+from benchmarks.reference.numerics import Numerics  # noqa: E402
+from paddle1_tpu import obs  # noqa: E402
+from paddle1_tpu.autograd.engine import no_grad  # noqa: E402
+from paddle1_tpu.core.tensor import Tensor  # noqa: E402
+from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
+from paddle1_tpu.nn import functional as F  # noqa: E402
+from paddle1_tpu.obs import costmodel  # noqa: E402
+from paddle1_tpu.text.models import (OuroForPretraining,  # noqa: E402
+                                     OuroPretrainingCriterion)
+
+CFG = {"vocab_size": 96, "hidden_size": 32, "num_hidden_layers": 2,
+       "num_attention_heads": 2, "head_dim": 16, "intermediate_size": 48,
+       "total_ut_steps": 4, "rope_theta": 1e4, "rms_norm_eps": 1e-6,
+       "initializer_range": 0.2, "exit_entropy_beta": 0.1}
+NM = Numerics()
+
+
+def _model(**over):
+    cfg = {**CFG, **over}
+    model = OuroForPretraining(**{k: cfg[k] for k in cfg
+                                  if k != "exit_entropy_beta"})
+    weights = jax.device_get(ref.init_params(cfg, jax.random.key(3)))
+    # the norm scales and the gate's bias away from their 1 and 0
+    rng = np.random.default_rng(0)
+    for k in sorted(weights):
+        if k[0] == "n" or k == "gate_b":
+            weights[k] = (weights[k] + 0.3 * rng.standard_normal(
+                weights[k].shape)).astype(np.float32)
+    from benchmarks.programs import load_weights, ouro_2p6b as program
+    load_weights(model, {p: jnp.asarray(weights[r] if i is None
+                                        else weights[r][i])
+                         for p, r, i in program.leaves(cfg)})
+    return model, weights, cfg
+
+
+def _ids(rows=2, seq=12, seed=1):
+    return np.random.default_rng(seed).integers(
+        0, CFG["vocab_size"], (rows, seq)).astype(np.int32)
+
+
+@pytest.mark.parametrize("positions", [None, "row", "batch"])
+def test_rotary_embedding_is_a_complex_rotation(positions):
+    """Channel i of the first half and channel i of the second are the
+    real and imaginary part of one number, turned by exp(1j * angle)."""
+    rng = np.random.default_rng(2)
+    x = rng.standard_normal((2, 7, 3, 8)).astype(np.float32)
+    at = {None: np.arange(7), "row": np.arange(7)[::-1].copy(),
+          "batch": rng.integers(0, 50, (2, 7))}[positions]
+    got = F.rotary_embedding(
+        Tensor(x), theta=100.0,
+        positions=None if positions is None else Tensor(at.astype(np.int32)))
+    z = x[..., :4] + 1j * x[..., 4:]
+    angle = at[..., None, None] * 100.0 ** (-np.arange(4) / 4.0)
+    want = z * np.exp(1j * angle)
+    np.testing.assert_allclose(
+        got.numpy(), np.concatenate([want.real, want.imag], -1), atol=2e-6)
+    # a rotation: norms stay, and q.k depends on the distance alone
+    np.testing.assert_allclose(np.linalg.norm(got.numpy(), axis=-1),
+                               np.linalg.norm(x, axis=-1), rtol=1e-5)
+
+
+def test_rotary_embedding_keeps_the_dtype_and_differentiates():
+    x = Tensor(jnp.ones((1, 4, 1, 8), jnp.bfloat16), stop_gradient=False)
+    y = F.rotary_embedding(x, theta=1e6)
+    assert y.dtype == x.dtype
+    y.sum().backward()
+    assert x.grad.shape == x.shape
+
+
+def test_swiglu_and_the_gated_feed_forward():
+    rng = np.random.default_rng(4)
+    g, u = rng.standard_normal((2, 3, 5)).astype(np.float32), \
+        rng.standard_normal((2, 3, 5)).astype(np.float32)
+    np.testing.assert_allclose(F.swiglu(Tensor(g), Tensor(u)).numpy(),
+                               g / (1 + np.exp(-g)) * u, rtol=1e-6)
+    ffn = paddle.nn.GatedFeedForward(4, 6)
+    assert sorted(ffn.state_dict()) == [
+        "down_proj.weight", "gate_proj.weight", "up_proj.weight"]
+    x = rng.standard_normal((2, 4)).astype(np.float32)
+    w = {k: v.numpy() for k, v in ffn.state_dict().items()}
+    a = x @ w["gate_proj.weight"]
+    np.testing.assert_allclose(
+        ffn(Tensor(x)).numpy(),
+        (a / (1 + np.exp(-a)) * (x @ w["up_proj.weight"]))
+        @ w["down_proj.weight"], rtol=1e-5, atol=1e-6)
+
+
+def test_the_block_follows_the_reference_layer_by_layer():
+    model, weights, cfg = _model()
+    x = np.random.default_rng(5).standard_normal((2, 12, 32)).astype(
+        np.float32)
+    ours, theirs = Tensor(x), jnp.asarray(x)
+    for i, block in enumerate(model.layers.blocks):
+        ours = block(ours)
+        theirs = ref.layer(theirs, ref.layer_weights(weights, i), cfg, NM)
+        np.testing.assert_allclose(ours.numpy(), theirs, rtol=2e-5,
+                                   atol=2e-5)
+    # a change of a later position leaves an earlier one alone: causal
+    moved = x.copy()
+    moved[:, 8:] += 1.0
+    out = model.layers.blocks[0](Tensor(moved)).numpy()
+    base = model.layers.blocks[0](Tensor(x)).numpy()
+    np.testing.assert_array_equal(out[:, :8], base[:, :8])
+    assert np.abs(out[:, 8:] - base[:, 8:]).max() > 0.1
+
+
+def _loss(model, ids, beta=0.1):
+    t = Tensor(ids)
+    labels = model.next_token_labels(t)
+    return OuroPretrainingCriterion(beta)(*model(t, labels), labels)
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_the_model_follows_the_reference(steps):
+    model, weights, cfg = _model(total_ut_steps=steps)
+    ids = _ids()
+    want, _ = ref.loss({k: jnp.asarray(v) for k, v in weights.items()},
+                       {"ids": jnp.asarray(ids)}, cfg, NM)
+    assert float(_loss(model, ids)) == pytest.approx(float(want), rel=2e-6)
+    logits, gates = model(Tensor(ids))
+    assert logits.shape == [steps, 2, 12, 96] and gates.shape == [steps, 2, 12]
+
+
+def test_one_loop_step_is_one_pass():
+    model, _, _ = _model(total_ut_steps=1)
+    ids = _ids()
+    with no_grad():
+        h = model.layers(model.embed_tokens(Tensor(ids)))
+        logits, gate = model.exit_head(h)
+        got, got_gate = model(Tensor(ids))
+    np.testing.assert_allclose(got.numpy()[0], logits.numpy(), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(got_gate.numpy()[0], gate.numpy(), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_a_shared_weights_gradient_is_the_sum_over_its_uses():
+    """At T = 4 the gradient of each weight of the stack and of the head
+    equals the sum of the four per-use gradients of an unrolled copy that
+    has separate weights for every loop step."""
+    model, _, cfg = _model()
+    ids = _ids()
+    loss = _loss(model, ids)
+    loss.backward()
+    shared = {"layers." + k: v for k, v in
+              model.layers.functional_state().items()}
+    shared.update({"exit_head." + k: v for k, v in
+                   model.exit_head.functional_state().items()})
+    crit = OuroPretrainingCriterion(0.1)
+
+    def unrolled(copies):
+        t = Tensor(ids)
+        labels = model.next_token_labels(t)
+        h, losses, gates = model.embed_tokens(t), [], []
+        for own in copies:
+            with model.load_functional_state(own):
+                h = model.layers(h)
+                l_t, g_t = model.exit_head(h, labels)
+            losses.append(l_t.data)
+            gates.append(g_t.data)
+        return crit(Tensor(jnp.stack(losses)), Tensor(jnp.stack(gates)),
+                    labels).data
+
+    with no_grad():
+        value, grads = jax.value_and_grad(unrolled)([dict(shared)
+                                                     for _ in range(4)])
+    assert float(value) == pytest.approx(float(loss), rel=1e-6)
+    params = dict(model.named_parameters())
+    for name in shared:
+        per_use = [np.asarray(g[name]) for g in grads]
+        total = sum(per_use)
+        np.testing.assert_allclose(params[name].grad.numpy(), total,
+                                   rtol=2e-4, atol=1e-5 * np.abs(total).max())
+        # and every use takes part, but the last use of the gate: the
+        # last exit takes what is left, whatever its gate says
+        used = [bool(np.abs(g).max() > 0) for g in per_use]
+        assert used == [True, True, True,
+                        not name.startswith("exit_head.gate")], name
+
+
+def test_exit_distribution_and_loss_by_hand():
+    """Two tokens, four exits: p sums to 1 and the loss is the hand
+    formula, with a token that has no label left out of the mean."""
+    gates = np.array([[[0.3, -1.2, 0.0]], [[-0.5, 2.0, 0.0]],
+                      [[1.5, 0.1, 0.0]], [[0.7, -0.4, 0.0]]], np.float32)
+    losses = np.array([[[4.0, 1.0, 9.0]], [[3.0, 2.0, 9.0]],
+                       [[2.5, 2.5, 9.0]], [[2.0, 3.5, 9.0]]], np.float32)
+    labels = np.array([[5, 7, -100]], np.int32)
+    lam = 1 / (1 + np.exp(-gates.astype(np.float64)))
+    p = np.stack([lam[0], lam[1] * (1 - lam[0]),
+                  lam[2] * (1 - lam[0]) * (1 - lam[1]),
+                  (1 - lam[0]) * (1 - lam[1]) * (1 - lam[2])])
+    np.testing.assert_allclose(p.sum(0), 1.0, rtol=1e-12)
+    per_token = (p * losses).sum(0) + 0.1 * (p * np.log(p)).sum(0)
+    want = per_token[0, :2].mean()
+    got = OuroPretrainingCriterion(0.1)(Tensor(losses), Tensor(gates),
+                                        Tensor(labels))
+    assert float(got) == pytest.approx(want, rel=1e-6)
+    ours = ref.exit_distribution([jnp.asarray(lam[t], jnp.float32)
+                                  for t in range(4)])
+    np.testing.assert_allclose(np.stack(ours), p, rtol=1e-5)
+    np.testing.assert_allclose(np.sum(ours, 0), 1.0, rtol=1e-6)
+    # one exit: it takes everything, whatever the gate says
+    one = OuroPretrainingCriterion(0.1)(Tensor(losses[:1]), Tensor(gates[:1]),
+                                        Tensor(labels))
+    assert float(one) == pytest.approx(2.5, rel=1e-6)
+
+
+def test_the_exit_arithmetic_is_float32_under_autocast():
+    model, _, _ = _model()
+    h = Tensor(jnp.ones((1, 4, 32), jnp.bfloat16))
+    labels = Tensor(np.array([[1, 2, 3, -100]], np.int32))
+    half = {k: v.astype(jnp.bfloat16)
+            for k, v in model.exit_head.functional_state().items()}
+    with no_grad(), model.exit_head.load_functional_state(half):
+        token_loss, gate = model.exit_head(h, labels)
+    assert token_loss.data.dtype == jnp.float32
+    assert gate.data.dtype == jnp.float32
+    assert float(token_loss.numpy()[0, 3]) == 0.0
+    out = OuroPretrainingCriterion(0.1)(
+        Tensor(jnp.ones((4, 1, 4), jnp.bfloat16)),
+        Tensor(jnp.zeros((4, 1, 4), jnp.bfloat16)), labels)
+    assert out.data.dtype == jnp.float32
+
+
+def test_recomputation_changes_neither_loss_nor_gradients():
+    ids = _ids()
+    got = {}
+    for remat in (False, True):
+        model, _, _ = _model()
+        model.layers.enable_recompute = remat
+        loss = _loss(model, ids)
+        loss.backward()
+        got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
+                                    model.named_parameters()})
+    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
+    for k, g in got[False][1].items():
+        np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
+                                   atol=1e-6 * np.abs(g).max())
+    # and in evaluation nothing is recomputed
+    model.eval()
+    assert float(_loss(model, ids)) == pytest.approx(got[False][0], rel=1e-6)
+
+
+# -- through the engine -----------------------------------------------------
+
+@pytest.fixture
+def _fresh_obs():
+    obs.reset_process_registry()
+    obs.hbm.reset()
+    yield
+    obs.reset_process_registry()
+    obs.hbm.reset()
+
+
+def _engine(recompute, amp=None):
+    model, _, _ = _model()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    crit = OuroPretrainingCriterion(0.1)
+
+    def loss_fn(m, b):
+        ids = Tensor(b["ids"])
+        labels = m.next_token_labels(ids)
+        return crit(*m(ids, labels), labels)
+    return ParallelEngine(model, opt, loss_fn, amp_dtype=amp,
+                          mesh=build_mesh(dp=1, devices=jax.devices()[:1]),
+                          recompute=recompute)
+
+
+def test_the_engine_switches_recomputation_on_and_the_step_is_the_same():
+    batch = {"ids": _ids()}
+    seen = {}
+    for remat in (False, True):
+        engine = _engine(remat)
+        assert engine.model.layers.enable_recompute is remat
+        loss = float(engine.step(engine.shard_batch(batch), lr=1e-3))
+        first = {k: np.asarray(v["moment1"])
+                 for k, v in engine.opt_state[0].items()}
+        seen[remat] = (loss, first, engine.compiled_step_text())
+    assert seen[True][0] == pytest.approx(seen[False][0], rel=1e-6)
+    for k, g in seen[False][1].items():
+        np.testing.assert_allclose(seen[True][1][k], g, rtol=1e-4,
+                                   atol=1e-6 * np.abs(g).max())
+    assert "rematted_computation" in seen[True][2]
+    assert "rematted_computation" not in seen[False][2]
+
+
+def test_the_loop_and_the_heads_have_scopes_of_their_own(_fresh_obs):
+    """Loop step t's copy of the stack under ``ut_step/<t>``; the heads,
+    the gate and the exit loss under ``exit_head``; nothing under both."""
+    engine = _engine(True, amp="bfloat16")
+    float(engine.step(engine.shard_batch({"ids": _ids()}), lr=1e-3))
+    scopes = costmodel.step_op_scopes()
+    named = [s for s in scopes.values() if "jvp(loss)" in s]
+    stack = [s for s in named if "/ut_step/" in s]
+    heads = [s for s in named if "/exit_head/" in s]
+    assert stack and heads
+    assert not [s for s in stack if "/exit_head/" in s]
+    assert not [s for s in heads if "/ut_step/" in s]
+    for t in range(CFG["total_ut_steps"]):
+        for i in range(CFG["num_hidden_layers"]):
+            for op in ("rms_norm", "rotary_embedding", "linear", "swiglu",
+                       "scaled_dot_product_attention"):
+                assert any(f"/ut_step/{t}/layers/recompute/{i}/" in s
+                           and f"/{op}" in s for s in stack), (t, i, op)
+        assert any(f"/ut_step/{t}/layers/norm/rms_norm" in s for s in stack)
+    # no while loop: the trace reduction would count its time and its
+    # body's both (PERF.md, PR 27)
+    assert not [s for s in named if "/while/" in s]
+    for op in ("exit_cross_entropy", "exit_gate", "exit_loss"):
+        assert any(s.split(";")[0].endswith(op) or f"/{op}/" in s
+                   for s in heads), op
+    assert any("OuroPretrainingCriterion/exit_head/exit_loss" in s
+               for s in heads)
+    # forward work run again in the backward pass says so
+    again = [s for s in named if "/rematted_computation/" in s]
+    assert again and all("transpose(jvp(loss))" in s for s in again)
+    assert {costmodel.region_of(s) for s in stack} == {"forward", "backward"}
+
+
+# -- ParallelEngine(recompute=True) finds blocks by what they declare ------
+
+class _Blocks(paddle.nn.Layer):
+    """A user's own block stack that knows how to recompute."""
+
+    def __init__(self):
+        super().__init__()
+        self.blocks = paddle.nn.LayerList(
+            [paddle.nn.Sequential(paddle.nn.Linear(8, 8), paddle.nn.Tanh())
+             for _ in range(3)])
+        self.enable_recompute = False
+
+    def forward(self, x):
+        from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+        for block in self.blocks:
+            x = recompute(block, x) if self.enable_recompute else block(x)
+        return x
+
+
+def _flip(model):
+    opt = paddle.optimizer.SGD(learning_rate=0.1,
+                               parameters=model.parameters())
+    return ParallelEngine(
+        model, opt, lambda m, b: (m(Tensor(b["x"])) ** 2).mean(),
+        mesh=build_mesh(dp=1, devices=jax.devices()[:1]), recompute=True)
+
+
+@pytest.mark.parametrize("kind", ["user_stack", "transformer_encoder",
+                                  "ouro"])
+def test_recompute_flips_whatever_declares_the_switch(kind):
+    if kind == "user_stack":
+        model = paddle.nn.Sequential(paddle.nn.Linear(8, 8), _Blocks())
+        stack = model[1]
+    elif kind == "transformer_encoder":
+        model = paddle.nn.TransformerEncoder(
+            paddle.nn.TransformerEncoderLayer(8, 2, 16, dropout=0.0), 2)
+        stack = model
+    else:
+        model = _model()[0]
+        stack = model.layers
+    assert stack.enable_recompute is False
+    import warnings
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", UserWarning)
+        engine = _flip(model)
+    assert stack.enable_recompute is True
+    if kind == "user_stack":
+        x = np.ones((4, 8), np.float32)
+        assert np.isfinite(float(engine.step({"x": x})))
+        assert "rematted_computation" in engine.compiled_step_text()
+
+
+def test_recompute_warns_where_nothing_declares_the_switch():
+    model = paddle.nn.Sequential(paddle.nn.Linear(8, 8), paddle.nn.ReLU())
+    with pytest.warns(UserWarning, match="no recompute-capable blocks"):
+        _flip(model)
