@@ -17,6 +17,7 @@ time printed is a smoke reading on the host's clock, not a metric.
 """
 
 import argparse
+import functools
 import gc
 import json
 import math
@@ -201,31 +202,48 @@ def kernel_phase():
             return jax.jit(fn)(*[a.astype(f32) if a.dtype == bf16 else a
                                  for a in args])
 
-    # flash attention, forward and the Pallas backward, [8,512,12,64]
-    q, k, v = (jax.random.normal(next(keys), (8, 512, 12, 64), bf16)
-               for _ in range(3))
-    flash = jax.jit(flash_attention.flash_attention)
-    check("tpu_custom_call" in flash.lower(q, k, v).compile().as_text(),
-          "flash forward compiled to a tpu_custom_call")
-    err = max_err(flash(q, k, v), ref(attention_ref, q, k, v))
-    check(err <= 5e-2, f"flash forward [8,512,12,64] bf16 vs XLA "
-                       f"reference: max abs err {err:.2e} <= 5e-2")
-
+    # flash attention, forward and both backward kernels: BERT's width
+    # (d 64: the transposed layout) and Ouro's causal [2,4096,16,128]
+    # (d 128: the packed layout, blocks above the diagonal skipped)
     def loss_of(attn):
         return lambda q, k, v: (attn(q, k, v).astype(f32) ** 2).sum()
-    grad = jax.jit(jax.grad(loss_of(flash_attention.flash_attention),
-                            argnums=(0, 1, 2)))
-    n_calls = grad.lower(q, k, v).compile().as_text().count(
-        "tpu_custom_call")
-    check(n_calls >= 3, f"flash grad compiled to {n_calls} "
-                        "tpu_custom_calls (forward, dq, dk/dv)")
-    want = ref(jax.grad(loss_of(attention_ref), argnums=(0, 1, 2)),
-               q, k, v)
-    for name, g, w in zip(("dq", "dk", "dv"), grad(q, k, v), want):
-        scale = float(np.max(np.abs(np.asarray(w))))
-        err = max_err(g, w) / scale
-        check(err <= 5e-2, f"flash backward {name}: max abs err / max "
-                           f"|ref| = {err:.2e} <= 5e-2")
+
+    def by_row(fn, *args):
+        # the reference a batch row at a time: at s4096 its float32
+        # scores are 1 GiB a row, and autodiff keeps several such
+        rows = [fn(*[a[i:i + 1] for a in args])
+                for i in range(args[0].shape[0])]
+        return jax.tree_util.tree_map(lambda *r: jnp.concatenate(r), *rows)
+
+    for shape, causal in (((8, 512, 12, 64), False),
+                          ((2, 4096, 16, 128), True)):
+        at = f"{list(shape)}{' causal' if causal else ''}"
+        q, k, v = (jax.random.normal(next(keys), shape, bf16)
+                   for _ in range(3))
+        attn = functools.partial(flash_attention.flash_attention,
+                                 causal=causal)
+        plain = functools.partial(attention_ref, is_causal=causal)
+        flash = jax.jit(attn)
+        check("tpu_custom_call" in flash.lower(q, k, v).compile().as_text(),
+              f"flash forward {at} compiled to a tpu_custom_call")
+        err = max_err(flash(q, k, v),
+                      by_row(lambda *a: ref(plain, *a), q, k, v))
+        check(err <= 5e-2, f"flash forward {at} bf16 vs XLA reference: "
+                           f"max abs err {err:.2e} <= 5e-2")
+        grad = jax.jit(jax.grad(loss_of(attn), argnums=(0, 1, 2)))
+        text = grad.lower(q, k, v).compile().as_text()
+        n_calls = text.count('custom_call_target="tpu_custom_call"')
+        check(n_calls == 3 and " while(" not in text,
+              f"flash grad {at} compiled to {n_calls} tpu_custom_calls "
+              "(forward, dk/dv, dq) and no while")
+        want = by_row(lambda *a: ref(jax.grad(loss_of(plain),
+                                              argnums=(0, 1, 2)), *a),
+                      q, k, v)
+        for name, g, w in zip(("dq", "dk", "dv"), grad(q, k, v), want):
+            scale = float(np.max(np.abs(np.asarray(w))))
+            err = max_err(g, w) / scale
+            check(err <= 5e-2, f"flash backward {at} {name}: max abs err "
+                               f"/ max |ref| = {err:.2e} <= 5e-2")
 
     # fused layer norm [4096, 768]
     x = jax.random.normal(next(keys), (4096, 768), bf16)

@@ -331,27 +331,13 @@ def _define_builtin_flags() -> None:
                 "(module import), not per call.")
     # Fused kernels (reference operators/fused/ role)
     define_flag("flash_attention", "auto",
-                "Pallas flash attention: auto (TPU only, AND only when "
-                "the dense score tensor would exceed "
-                "flash_auto_score_mb — a sweep older than PRs 1-20, on "
-                "another machine, found XLA's fused dense attention "
-                "faster at every compute-bound length, so flash earns "
-                "its place as the long-sequence memory escape; on the "
-                "v5e: not measured), always "
-                "(interpret-mode on CPU, for tests), never.",
+                "Pallas blockwise attention kernels, forward and "
+                "backward: auto (a TPU, a single-device step, and a "
+                "shape on which the v5e ran them faster than XLA's "
+                "dense composition: nn.functional.attention."
+                "use_flash_for holds the rule and the sweep's numbers), "
+                "always (interpret-mode on CPU, for tests), never.",
                 validator=lambda v: v in ("auto", "always", "never"))
-    define_flag("flash_auto_score_mb", 65536.0,
-                "Estimated transient attention memory (MiB) above which "
-                "flash_attention=auto switches from XLA dense attention "
-                "to the Pallas flash kernels: batch*heads*seq_q*seq_k *"
-                " (2*compute-dtype itemsize + 8) bytes. The 64 GiB "
-                "default routes every shape of an older sweep (seq 128 "
-                "through 16384, another machine, before PRs 1-20) to "
-                "XLA's dense attention, which streams the softmax "
-                "without materializing the scores; flash remains the "
-                "escape beyond that, and 'always' forces it. On the "
-                "v5e the crossover is not measured.",
-                validator=lambda v: v > 0)
     define_flag("pallas_paged_attention", "auto",
                 "Pallas paged-attention gather kernel for the paged "
                 "decode path (serve_gen_paged): auto (TPU only — the "
@@ -398,13 +384,6 @@ def _define_builtin_flags() -> None:
                 validator=lambda v: v in ("auto", "always", "never"))
     define_flag("fused_softmax", "auto",
                 "Pallas fused softmax: auto (TPU only), always, never.",
-                validator=lambda v: v in ("auto", "always", "never"))
-    define_flag("flash_backward", "auto",
-                "Pallas flash-attention BACKWARD kernels: auto (TPU "
-                "only), always (interpret on CPU), never (XLA recompute "
-                "backward). chip_smoke.py runs them on the v5e "
-                "against the XLA reference; tests/test_chip_compile.py "
-                "compiles them for it.",
                 validator=lambda v: v in ("auto", "always", "never"))
     # Fault tolerance (reference incubate/auto_checkpoint +
     # update_loss_scaling roles; consumed by distributed.resilience and

@@ -121,11 +121,10 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = False,
     qg, kg, vg = seq2head(q), seq2head(k), seq2head(v)
     from ..ops.pallas import flash_attention as fa
     from ..nn.functional.attention import attention_ref, use_flash_for
-    # same dense-vs-flash policy as scaled_dot_product_attention (r5:
-    # XLA dense wins at compute-bound lengths; flash is the
-    # long-sequence memory escape) applied to the post-all-to-all
-    # GLOBAL sequence length
-    if fa.supported(qg.shape, kg.shape) and use_flash_for(qg, kg):
+    # same dense-vs-flash policy as scaled_dot_product_attention,
+    # applied to the post-all-to-all GLOBAL sequence length
+    if (fa.supported(qg.shape, kg.shape, causal=causal)
+            and use_flash_for(qg, kg)):
         og = fa.flash_attention(qg, kg, vg, causal=causal, scale=scale)
     else:
         og = attention_ref(qg, kg, vg, is_causal=causal, scale=scale)

@@ -76,39 +76,58 @@ def rotary_embedding(x, theta=10000.0, positions=None, name=None):
     return apply("rotary_embedding", f, args)
 
 
-def use_flash_for(q, k) -> bool:
-    """The dense-vs-flash dispatch policy (r5), shared by every
-    attention entry point (sdpa here, ulysses_attention in
-    distributed/sequence_parallel.py): ``never`` → False, ``always`` →
-    True, ``auto`` → TPU only AND only when the dense path's transient
-    attention memory would threaten HBM headroom. A crossover sweep
-    older than PRs 1-20, on another machine, found XLA's fused dense
-    attention faster at every compute-bound length, so under ``auto``
-    flash earns its place as the long-sequence memory escape; on the
-    v5e the crossover is not measured.
+# The shortest sequence at which the blockwise kernels ran a shorter
+# forward + backward than XLA's dense composition on the v5e.
+FLASH_MIN_SEQ = 1024
 
-    Peak-memory estimate per score element of the dense path: the
-    [b, h, sq, sk] logits in the compute dtype, the softmax's f32
-    stabilized-logits and probs copies, and the cast of probs back to
-    the compute dtype — ``2 * itemsize + 8`` bytes. q/k are
-    [batch, seq, heads, dim] arrays (or tracers)."""
+
+def use_flash_for(q, k) -> bool:
+    """The dense-vs-flash dispatch policy, shared by every attention
+    entry point (sdpa here, ulysses_attention in
+    distributed/sequence_parallel.py). ``never`` → False, ``always`` →
+    True (interpret mode off a TPU: the tests' switch). ``auto`` → the
+    Pallas kernels where the chip showed them faster, from what the code
+    can observe: the backend is a TPU, the step is one device's (inside
+    ``core.flags.auto_partitioned_region`` GSPMD refuses a Mosaic
+    kernel) and both sequences are at least ``FLASH_MIN_SEQ`` long. Tile
+    alignment is ``flash_attention.supported``'s to say. q/k are
+    [batch, seq, heads, dim] arrays (or tracers).
+
+    The sweep (tools/tpu_flash_crossover.py on a TPU v5 lite, PR 28:
+    one call's forward + backward in isolation, bf16, 8192 tokens a
+    call, ms dense / kernel; the kernels fetched 2048 rows a grid step
+    then, and read about 5% more with the 1024 they ship with, which
+    moves no crossing):
+
+    ====== ============= ============= ============= =============
+    seq    d128 causal   d128 full     d64 causal    d64 full
+    ====== ============= ============= ============= =============
+    512    2.00 / 2.10   2.00 / 2.10   0.75 / 1.26   0.75 / 1.27
+    1024   4.11 / 2.88   4.10 / 2.93   2.94 / 1.74   2.86 / 1.89
+    2048   7.47 / 3.81   7.44 / 4.52   5.29 / 2.42   5.27 / 3.09
+    4096   14.00 / 5.92  13.87 / 8.35  10.26 / 3.90  10.21 / 5.74
+    8192   30.34 / 10.24 28.24 / 15.39 20.89 / 6.71  20.64 / 10.69
+    ====== ============= ============= ============= =============
+
+    Dense wins at 512 and below whatever the mask (BERT's [64,512,12,64]
+    5.46 / 6.14, [256,128,12,64] 1.61 / 7.90: one or no key block to
+    skip, and at d 64 the kernels pay XLA transposes), the kernels from
+    1024 up, causal or not, by more the longer the sequence. In a step:
+    ``ouro_2p6b.pretrain_s4096`` 1257.3 -> 966.9 ms (PERF.md, PR 28);
+    ``bert_base.pretrain_s512`` forced onto the kernels gained 0.27%
+    end to end, under the 1% that would have moved the rule."""
     from ...core.flags import flag, flag_active
     if not flag_active("flash_attention"):
         return False
-    if flag("flash_attention") != "auto":
+    if flag("flash_attention") == "always":
         return True
-    bytes_per = 2 * jnp.dtype(q.dtype).itemsize + 8
-    score_mb = (q.shape[0] * q.shape[2] * q.shape[1] * k.shape[1]
-                * bytes_per) / (1 << 20)
-    threshold = float(flag("flash_auto_score_mb"))
-    if not (isinstance(q, jax.core.Tracer)
-            or isinstance(k, jax.core.Tracer)):
-        # EAGER execution: the dense measurements behind the large
-        # default threshold relied on XLA fusing the whole attention
-        # under jit — op-by-op eager really does materialize the score
-        # tensor, so cap the eager threshold at 1 GiB of transient
-        threshold = min(threshold, 1024.0)
-    return score_mb >= threshold
+    return min(q.shape[1], k.shape[1]) >= FLASH_MIN_SEQ
+
+
+def _count_arm(arm: str) -> None:
+    """One increment a traced call of sdpa: which arm the step holds."""
+    from ...obs.registry import process_group
+    process_group("arm").child(arm).counter("attention_arm_total").inc()
 
 
 def use_paged_kernel() -> bool:
@@ -151,9 +170,9 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None,
                                  use_flash=True):
-    """Fused attention entry. Uses the Pallas flash kernel on TPU when
-    shapes are tile-aligned, else the XLA composition (which XLA still fuses
-    well)."""
+    """Fused attention entry. Takes the Pallas blockwise kernels where
+    ``use_flash_for`` says they win and the shapes are tile-aligned, else
+    the XLA composition."""
     q, k, v = _t(query), _t(key), _t(value)
     drop = dropout_p if training else 0.0
     dropout_key = None
@@ -186,16 +205,17 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         return jnp.asarray(fl > -1e4, jnp.float32)
 
     def f(q, k, v, *m):
-        flash_ok = use_flash_for(q, k)
         mask = m[0] if m else None
-        if (use_flash and drop == 0.0 and flash_ok
+        if (use_flash and drop == 0.0
+                and use_flash_for(q, k)
                 and fa.supported(q.shape, k.shape, causal=is_causal)):
-            if mask is None:
-                return fa.flash_attention(q, k, v, causal=is_causal)
-            pm = _as_padding_mask(mask, k.shape[1])
-            if pm is not None:
+            pm = (None if mask is None
+                  else _as_padding_mask(mask, k.shape[1]))
+            if mask is None or pm is not None:
+                _count_arm("flash")
                 return fa.flash_attention(q, k, v, causal=is_causal,
                                           padding_mask=pm)
+        _count_arm("dense")
         return attention_ref(q, k, v, mask=mask, dropout_p=drop,
                              is_causal=is_causal, dropout_key=dropout_key)
     return apply("scaled_dot_product_attention", f,

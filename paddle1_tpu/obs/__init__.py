@@ -53,14 +53,16 @@ from .hbm import HbmLeakSuspected
 from .http import TelemetryServer, start_telemetry_from_flags
 from .registry import (Counter, Gauge, Histogram, MetricsGroup,
                        MetricsRegistry, ServingMetrics, merge_snapshots,
-                       metrics_on, process_registry, render_snapshot_text,
+                       metrics_on, process_group, process_registry,
+                       render_snapshot_text,
                        reset_process_registry, step_registry)
 from .slo import SloSet, SloSpec, parse_slos
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "ServingMetrics",
     "MetricsGroup", "merge_snapshots", "render_snapshot_text",
-    "process_registry", "reset_process_registry", "metrics_on",
+    "process_registry", "process_group", "reset_process_registry",
+    "metrics_on",
     "step_registry", "TelemetryServer", "start_telemetry_from_flags",
     "trace", "events", "costmodel", "hbm", "slo", "flight",
     "ExecutableCost", "FlightRecorder", "HbmLeakSuspected",

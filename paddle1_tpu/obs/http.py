@@ -101,9 +101,10 @@ class TelemetryServer:
         parts = []
         reg = self._registry
         if reg is None:
-            from .registry import process_registry
-            reg = process_registry()
-        if reg is not False:
+            from .registry import process_registry, render_process_groups
+            parts.append(process_registry().render_text())
+            parts.append(render_process_groups())
+        elif reg is not False:
             parts.append(reg.render_text())
         for slot in self._providers:
             try:

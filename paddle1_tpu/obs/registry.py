@@ -48,7 +48,7 @@ from ..core.errors import InvalidArgumentError
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "ServingMetrics", "MetricsGroup", "merge_snapshots",
-           "render_snapshot_text", "process_registry",
+           "render_snapshot_text", "process_registry", "process_group",
            "reset_process_registry", "metrics_on", "step_registry",
            "SNAPSHOT_ENV", "write_snapshot_file"]
 
@@ -465,6 +465,7 @@ def render_snapshot_text(snap: Dict[str, object], namespace: str,
 
 _process_lock = threading.Lock()
 _process: Optional[MetricsRegistry] = None
+_groups: Dict[str, MetricsGroup] = {}  # guarded-by: _process_lock
 _snapshot_thread: Optional[threading.Thread] = None
 
 
@@ -486,6 +487,25 @@ def process_registry() -> MetricsRegistry:
     return m
 
 
+def process_group(label_key: str) -> MetricsGroup:
+    """THE process's labeled family keyed by ``label_key`` (namespace
+    ``p1t``), created on first touch: ``process_group("arm")
+    .child("flash").counter("attention_arm_total")`` is the series
+    ``p1t_attention_arm_total{arm="flash"}`` on the ``/metrics`` page."""
+    g = _groups.get(label_key)
+    if g is None:
+        with _process_lock:
+            g = _groups.setdefault(
+                label_key, MetricsGroup(label_key, namespace="p1t"))
+    return g
+
+
+def render_process_groups() -> str:
+    with _process_lock:
+        groups = [g for _, g in sorted(_groups.items())]
+    return "".join(g.render_text() for g in groups)
+
+
 def reset_process_registry() -> MetricsRegistry:
     """Replace the process registry with a fresh one (test isolation).
     Arms the snapshot writer like first touch does — a worker that
@@ -493,6 +513,7 @@ def reset_process_registry() -> MetricsRegistry:
     global _process
     with _process_lock:
         _process = MetricsRegistry(namespace="p1t")
+        _groups.clear()
         _maybe_start_snapshot_writer()
         return _process
 

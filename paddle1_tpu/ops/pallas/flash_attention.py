@@ -1,14 +1,34 @@
-"""Flash attention Pallas kernel (TPU MXU/VMEM-native fused attention).
+"""Blockwise attention Pallas kernels (TPU MXU/VMEM-native fused attention).
 
 Replaces the reference's fused multihead attention CUDA kernels
 (/root/reference/paddle/fluid/operators/fused/ attention ops) with the
-TPU idiom: online-softmax blocking in VMEM, one pass over K/V per query
-block, logits never materialized in HBM.
+TPU idiom: online-softmax blocking in VMEM, logits never in HBM.
 
 Layout: [B, N, H, D] (paddle layout, matching nn.functional.attention).
-Forward = Pallas kernel (+ log-sum-exp residual); backward = XLA
-recompute from the LSE (flash-style, no stored probabilities).
-Runs in interpreter mode off-TPU so tests exercise the same code path.
+This file holds the forward kernel and the entry point; the two
+backward kernels are in flash_attention_bwd.py. All three share one
+blocked grid ``(batch*heads, outer blocks, inner blocks)`` with the
+inner axis sequential and accumulators in VMEM scratch; a grid step
+takes its fetched block a chunk at a time, and under a causal mask
+(bottom-right aligned, query ``r`` sees keys ``<= r + nk - nq``) a
+score tile (resident block x chunk) is one of three kinds:
+
+* wholly above the diagonal: no work, and the index map is clamped to
+  the last needed block so nothing is fetched for a block of such;
+* wholly below: the plain body, no iota, no select;
+* crossed by the diagonal: the body with the mask.
+
+Precision is the caller's: the products take q/k/v/dout in the dtype
+they arrive in (bf16 under AMP) and accumulate in float32; max, sum and
+LSE are float32; the probabilities (and ``ds`` in the backward) are
+rounded to the operand dtype once, before their product. The softmax
+scale is folded into q (forward, dQ) or k (dK/dV) once a resident block.
+
+Where ``head_dim % 128 == 0`` the kernels read and write ``[B, N, H*D]``
+with a ``(block, D)`` window at column ``h*D`` (a free reshape of the
+paddle layout); otherwise q/k/v/out are transposed to ``[B*H, N, D]`` in
+XLA on the way in and out. Runs in interpreter mode off-TPU so tests
+exercise the same code path.
 """
 
 from __future__ import annotations
@@ -19,23 +39,25 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
 
-BLOCK_Q = 128
-BLOCK_K = 128
-_LANES = 128  # Mosaic minor-dim tile: scalar-per-row outputs are stored
-              # broadcast across one 128-lane register row
+_LANES = 128  # Mosaic minor-dim tile: per-row statistics are kept
+              # replicated across one 128-lane register row
 _NEG_INF = -1e30
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
 def supported(q_shape, k_shape, causal: bool = False) -> bool:
-    """Tile-aligned shapes only; everything else uses attention_ref."""
+    """Tile-aligned shapes only; everything else uses attention_ref.
+    No VMEM gate: no kernel keeps more than a block of any operand."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
     _, nq, _, d = q_shape
     _, nk, _, _ = k_shape
-    if nq % BLOCK_Q or nk % BLOCK_K:
+    if nq % _LANES or nk % _LANES:
         return False
     if causal and nq > nk:
         # bottom-right causal leaves leading queries with ZERO visible
@@ -44,282 +66,263 @@ def supported(q_shape, k_shape, causal: bool = False) -> bool:
         return False
     if d % 8 or d > 256:
         return False
-    # K+V rows for one (batch, head) must fit in VMEM comfortably.
-    # ">=": nk=16384/d=64 lands EXACTLY on the 8 MiB boundary and the
-    # real scoped-vmem cost (16.12 MiB vs the 16 MiB limit, r5 on-chip
-    # compile report) makes it a coin flip across compile contexts —
-    # boundary shapes must not pass
-    if 2 * nk * d * 4 >= 8 * 1024 * 1024:
-        return False
     return True
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_k, mask_ref=None):
-    # q_ref: [BLOCK_Q, D]; k_ref/v_ref: [N_k, D]; o_ref: [BLOCK_Q, D]
-    # mask_ref (optional): [1, N_k] f32, 1.0 = attend / 0.0 = padding.
-    q_blk = pl.program_id(1)
-    nk = k_ref.shape[0]
-    nq = pl.num_programs(1) * BLOCK_Q
-    d = q_ref.shape[1]
-    q = q_ref[:].astype(jnp.float32) * scale
+def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
+    """(block_q, block_k, chunk): the resident block of the outer axis,
+    the block fetched a grid step along the inner axis, and the slice of
+    it that one pass of the body takes. The largest rungs that divide
+    the lengths, of ladders set from the sweep on the v5e
+    (tools/tpu_flash_crossover.py, PERF.md PR 28) at [2, 4096, 16, 128]
+    bf16 causal. A 512 x 512 float32 score tile is what a pass handles
+    best (256 is 25% slower, 1024 no faster). A grid step costs about
+    0.2 us whatever it does, so a longer fetched block is faster (512 /
+    1024 / 2048 rows: 1.88 / 1.76 / 1.64 ms a forward call), but every
+    chunk of it is unrolled code: at 2048 rows the 96 kernel instances
+    of Ouro's step make its executable 137.0 MiB in the compile cache
+    against 129.7 at 1024 (the parent's dense step: 130.7), which a
+    capped cache then fails to hold beside the other programs of a run.
+    And no more than 512 KiB an operand: dK/dV fetches two, double
+    buffered, beside two resident blocks, two outputs and the score
+    tiles in 16 MiB of scoped VMEM (float32 at d 256 is refused from
+    1 MiB up)."""
+    rows = (512 << 10) // (d * jnp.dtype(dtype).itemsize)
+    fetched = tuple(b for b in (1024, 512, 256, 128) if b <= rows)
+    return (_rung(nq, (512, 256, 128)), _rung(nk, fetched),
+            _rung(nk, (512, 256, 128)))
 
-    def body(i, carry):
-        m_prev, l_prev, acc = carry
-        k = k_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [BQ, BK]
-        if mask_ref is not None:
-            mk = mask_ref[0, pl.ds(i * block_k, block_k)]  # [BK]
-            s = jnp.where(mk[None, :] > 0.5, s, _NEG_INF)
-        if causal:
-            # bottom-right alignment (query i attends keys j <= i + nk-nq),
-            # matching attention_ref's tril(..., nk - nq)
-            q_ids = (q_blk * BLOCK_Q + (nk - nq) +
-                     jax.lax.broadcasted_iota(jnp.int32,
-                                              (BLOCK_Q, block_k), 0))
-            k_ids = (i * block_k +
-                     jax.lax.broadcasted_iota(jnp.int32,
-                                              (BLOCK_Q, block_k), 1))
-            s = jnp.where(q_ids >= k_ids, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
+
+def _rung(n: int, ladder: tuple) -> int:
+    return next(b for b in ladder if n % b == 0)
+
+
+def _dot(a, b, dims):
+    """``a`` x ``b`` in the dtype they arrive in, float32 accumulation.
+    A bf16 product has one precision on the MXU, so it names it: a
+    process-wide ``jax_default_matmul_precision`` of float32 would ask
+    Mosaic for an fp32 contraction of bf16 operands, which it refuses."""
+    precision = (None if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] statistic as [rows, n]."""
+    if n % _LANES == 0:
+        return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _layout(x):
+    """[B, N, H, D] -> (the array a kernel windows, its index map).
+    The map takes (batch*head index, row block) to a block index."""
+    b, n, h, d = x.shape
+    if d % _LANES == 0:
+        return x.reshape(b, n, h * d), lambda g, r: (g // h, r, g % h)
+    return (x.transpose(0, 2, 1, 3).reshape(b * h, n, d),
+            lambda g, r: (g, r, 0))
+
+
+def _unlayout(x, b, h, d):
+    """Inverse of :func:`_layout` for a kernel's output."""
+    if d % _LANES == 0:
+        return x.reshape(b, -1, h, d)
+    return x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)
+
+
+def _causal_keep(shape, q0, k0, off, q_axis):
+    """Keep-mask of one score tile whose ``q_axis`` runs over queries
+    from q0 and whose other axis runs over keys from k0."""
+    q_ids = q0 + off + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_ids = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return q_ids >= k_ids
+
+
+def _run_tile(body, causal, q0, bq, k0, bk, off):
+    """Call ``body(masked)`` as the kind of the score tile of queries
+    [q0, q0+bq) x keys [k0, k0+bk) asks, or not at all."""
+    if not causal:
+        body(False)
+        return
+    needed = k0 <= q0 + bq - 1 + off        # some pair unmasked
+    full = k0 + bk - 1 <= q0 + off          # every pair unmasked
+    pl.when(full)(lambda: body(False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
+        lambda: body(True))
+
+
+def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
+    # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [BK, D]; mask_ref: [1, BK] f32,
+    # 1.0 = attend / 0.0 = padding; lse_ref: [BQ, 128]
+    q_ref, k_ref, v_ref = refs[:3]
+    mask_ref = refs[3] if has_mask else None
+    o_ref, lse_ref, qs_ref, m_ref, l_ref, acc_ref = refs[3 + has_mask:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    bq, d = q_ref.shape
+    bk = k_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        qs_ref[...] = (q_ref[...] * scale).astype(qs_ref.dtype)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def one(c, masked):
+        ks = pl.ds(c * chunk, chunk)
+        s = _dot(qs_ref[...], k_ref[ks, :], _NT)
+        if has_mask:
+            s = jnp.where(mask_ref[:, ks] > 0.5, s, _NEG_INF)
+        if masked:
+            s = jnp.where(
+                _causal_keep(s.shape, i * bq, j * bk + c * chunk, off, 0),
+                s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l_prev * alpha + jnp.sum(p, axis=1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
+        p = jnp.exp(s - _lanes(m_new, chunk))
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)[:, None]
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + _dot(
+            p.astype(v_ref.dtype), v_ref[ks, :], _NN)
 
-    m0 = jnp.full((BLOCK_Q,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((BLOCK_Q,), jnp.float32)
-    acc0 = jnp.zeros((BLOCK_Q, d), jnp.float32)
-    n_blocks = nk // block_k
-    if causal:
-        # blocks strictly above the (aligned) diagonal contribute nothing
-        hi = (q_blk + 1) * BLOCK_Q + (nk - nq)
-        n_blocks_eff = jnp.minimum(n_blocks, pl.cdiv(hi, block_k))
-        m, l, acc = jax.lax.fori_loop(0, n_blocks_eff, body, (m0, l0, acc0))
-    else:
-        m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    # Rows with zero visible keys (fully-padded batch entry): m is still the
-    # sentinel and p degenerated to exp(0)=1 per key inside the loop. Gate
-    # those rows to zero output and sentinel LSE so the backward (which
-    # keys p off the LSE) produces exact zero gradients for them.
-    visible = m > _NEG_INF * 0.5
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    out = jnp.where(visible[:, None], acc / l_safe[:, None], 0.0)
-    o_ref[:] = out.astype(o_ref.dtype)
-    # [BLOCK_Q] → [BLOCK_Q, _LANES]: Mosaic requires the last two block dims
-    # tile to (8, 128), so the per-row LSE is broadcast across one lane row
-    # (same layout as jax's own TPU flash kernel's l/m outputs)
-    lse = jnp.where(visible, m + jnp.log(l_safe), _NEG_INF)
-    lse_ref[:] = jax.lax.broadcast_in_dim(
-        lse.astype(jnp.float32), (BLOCK_Q, _LANES), (0,))
+    for c in range(bk // chunk):
+        _run_tile(functools.partial(one, c), causal, i * bq, bq,
+                  j * bk + c * chunk, chunk, off)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        # Rows with zero visible keys (fully-padded batch entry): m is
+        # still the sentinel and p degenerated to exp(0)=1 per key. Gate
+        # those rows to zero output and sentinel LSE so the backward
+        # (which keys p off the LSE) gives exact zero gradients for them.
+        m, l = m_ref[...], l_ref[...]
+        visible = m > _NEG_INF * 0.5
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        inv = jnp.where(visible, 1.0 / l_safe, 0.0)
+        o_ref[...] = (acc_ref[...] * _lanes(inv, d)).astype(o_ref.dtype)
+        lse_ref[...] = jnp.where(visible, m + jnp.log(l_safe), _NEG_INF)
 
 
-def _flash_fwd(q, k, v, scale, causal, padding_mask=None):
+def split_blocks(blocks):
+    """``blocks`` as (forward, dK/dV, dQ) triples: None, one
+    (resident, fetched, chunk) triple for all three kernels, or three."""
+    if blocks is None or isinstance(blocks[0], int):
+        return blocks, blocks, blocks
+    return tuple(blocks)
+
+
+def _key_block_map(causal, bq, bk, off, nk):
+    """(query block i, grid step j) -> the key block to fetch: j, held at
+    the last block that query block i sees, so that a step above the
+    diagonal fetches nothing new."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(
+        j, jnp.minimum((i * bq + bq - 1 + off) // bk, nk // bk - 1))
+
+
+def _flash_fwd(q, k, v, scale, causal, padding_mask=None, blocks=None):
+    """(out [B, Nq, H, D], lse [B*H, Nq]). One ``jit`` inside the
+    caller's: a model's step calls this once a layer application, and
+    the step's trace and lowering then take the kernel once a shape
+    (Ouro's 48 calls cost its set-up 11 s otherwise)."""
+    return _fwd_call(q, k, v, padding_mask, scale=scale, causal=causal,
+                     blocks=blocks, interpret=_common.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "blocks",
+                                             "interpret"))
+def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
     b, nq, h, d = q.shape
     nk = k.shape[1]
-    # [B, N, H, D] → [B*H, N, D]
-    qh = q.transpose(0, 2, 1, 3).reshape(b * h, nq, d)
-    kh = k.transpose(0, 2, 1, 3).reshape(b * h, nk, d)
-    vh = v.transpose(0, 2, 1, 3).reshape(b * h, nk, d)
+    bq, bk, chunk = split_blocks(blocks)[0] or block_sizes(nq, nk, d,
+                                                           q.dtype)
+    off = nk - nq
+    qa, at = _layout(q)
+    ka, _ = _layout(k)
+    va, _ = _layout(v)
 
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_k=BLOCK_K)
+    kj = _key_block_map(causal, bq, bk, off, nk)
     in_specs = [
-        pl.BlockSpec((None, BLOCK_Q, d), lambda bh, i: (bh, i, 0)),
-        pl.BlockSpec((None, nk, d), lambda bh, i: (bh, 0, 0)),
-        pl.BlockSpec((None, nk, d), lambda bh, i: (bh, 0, 0)),
+        pl.BlockSpec((None, bq, d), lambda g, i, j: at(g, i)),
+        pl.BlockSpec((None, bk, d), lambda g, i, j: at(g, kj(i, j))),
+        pl.BlockSpec((None, bk, d), lambda g, i, j: at(g, kj(i, j))),
     ]
-    args = (qh, kh, vh)
+    args = [qa, ka, va]
     if padding_mask is not None:
         # [B, Nk] keep-mask as f32; each (batch, head) program reads its
         # batch row (index map folds bh → b).
-        mk = padding_mask.astype(jnp.float32).reshape(b, 1, nk)
-        in_specs.append(
-            pl.BlockSpec((None, 1, nk), lambda bh, i: (bh // h, 0, 0)))
-        args = args + (mk,)
-
-        def kernel(q_r, k_r, v_r, m_r, o_r, l_r):
-            _fwd_kernel(q_r, k_r, v_r, o_r, l_r, scale=scale, causal=causal,
-                        block_k=BLOCK_K, mask_ref=m_r)
+        in_specs.append(pl.BlockSpec(
+            (None, 1, bk), lambda g, i, j: (g // h, 0, kj(i, j))))
+        args.append(padding_mask.astype(jnp.float32).reshape(b, 1, nk))
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq // BLOCK_Q),
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, off=off,
+                          chunk=chunk, has_mask=padding_mask is not None),
+        grid=(b * h, nq // bq, nk // bk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, BLOCK_Q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((None, BLOCK_Q, _LANES), lambda bh, i: (bh, i, 0)),
+            pl.BlockSpec((None, bq, d), lambda g, i, j: at(g, i)),
+            pl.BlockSpec((None, bq, _LANES), lambda g, i, j: (g, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, nq, d), q.dtype),
+            jax.ShapeDtypeStruct(qa.shape, q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, _LANES), jnp.float32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), q.dtype),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="p1t_flash_attention_fwd",
-        interpret=_common.interpret(),
+        interpret=interpret,
     )(*args)
-    out = out.reshape(b, h, nq, d).transpose(0, 2, 1, 3)
-    lse = lse[:, :, 0].reshape(b, h, nq)
-    return out, lse
+    return _unlayout(out, b, h, d), lse[:, :, 0]
 
 
-def _bwd_xla(q, k, v, out, lse, dout, scale, causal, padding_mask=None,
-             q_chunk=None):
-    """Flash-style backward in XLA: recompute P per (b,h) from the saved
-    LSE; XLA blocks/fuses the einsums onto the MXU. Long sequences scan
-    over query chunks so the transient [B,H,C,Nk] score block stays
-    bounded (~512 MiB) instead of materializing the full [B,H,Nq,Nk]
-    matrix — this is the memory-escape backward for shapes the Pallas
-    kernels' VMEM model rejects (flash_attention_bwd.supported)."""
-    qh = jnp.swapaxes(q, 1, 2).astype(jnp.float32)   # [B,H,Nq,D]
-    kh = jnp.swapaxes(k, 1, 2).astype(jnp.float32)
-    vh = jnp.swapaxes(v, 1, 2).astype(jnp.float32)
-    doh = jnp.swapaxes(dout, 1, 2).astype(jnp.float32)
-    oh = jnp.swapaxes(out, 1, 2).astype(jnp.float32)
-    b, h, nq, d = qh.shape
-    nk = kh.shape[2]
-    # fully-masked rows carry the sentinel LSE from the forward: exp(s-lse)
-    # would be exp(0)=1 per key there — gate p to zero instead so such rows
-    # contribute no gradient (matching their zeroed forward output)
-    lse = jnp.where(lse > _NEG_INF * 0.1, lse, jnp.inf)
-
-    def block_grads(qs, dos, os_, lses, q0):
-        """Gradient contributions of one query block [B,H,C,D]."""
-        s = jnp.einsum("bhqd,bhkd->bhqk", qs, kh) * scale
-        if padding_mask is not None:
-            s = jnp.where(padding_mask[:, None, None, :] > 0.5, s,
-                          _NEG_INF)
-        if causal:
-            c = qs.shape[2]
-            q_ids = (q0 + (nk - nq) +
-                     jax.lax.broadcasted_iota(jnp.int32, (c, nk), 0))
-            k_ids = jax.lax.broadcasted_iota(jnp.int32, (c, nk), 1)
-            s = jnp.where((q_ids >= k_ids)[None, None], s, _NEG_INF)
-        p = jnp.exp(s - lses[..., None])              # [B,H,C,Nk]
-        dv_c = jnp.einsum("bhqk,bhqd->bhkd", p, dos)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", dos, vh)
-        delta = jnp.sum(dos * os_, axis=-1, keepdims=True)
-        ds = p * (dp - delta) * scale
-        dq_c = jnp.einsum("bhqk,bhkd->bhqd", ds, kh)
-        dk_c = jnp.einsum("bhqk,bhqd->bhkd", ds, qs)
-        return dq_c, dk_c, dv_c
-
-    # chunk size: bound the f32 score block near 512 MiB, keep the
-    # q dim a multiple that divides nq (nq is BLOCK_Q-aligned here);
-    # q_chunk overrides for tests
-    if q_chunk is not None:
-        if nq % q_chunk:
-            raise ValueError(
-                f"q_chunk={q_chunk} must divide nq={nq} (a non-divisor "
-                "would silently drop the tail rows' gradients)")
-        chunk = q_chunk
-    else:
-        target = max(1, (512 * 1024 * 1024) // max(b * h * nk * 4, 1))
-        # floor at 128 (nq is BLOCK_Q-aligned on every path that
-        # reaches here): for the very largest workloads target drops
-        # below every candidate, and falling back to chunk=nq would
-        # materialize the full score matrix — the exact OOM this
-        # chunking exists to prevent
-        chunk = 128 if nq % 128 == 0 else nq
-        for cand in (4096, 2048, 1024, 512, 256):
-            if cand <= target and nq % cand == 0:
-                chunk = cand
-                break
-    if chunk >= nq:
-        dq, dk, dv = block_grads(qh, doh, oh, lse, 0)
-    else:
-        n_chunks = nq // chunk
-
-        def body(carry, i):
-            dk_acc, dv_acc = carry
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(
-                a, i * chunk, chunk, axis=2)
-            dq_c, dk_c, dv_c = block_grads(sl(qh), sl(doh), sl(oh),
-                                           sl(lse), i * chunk)
-            return (dk_acc + dk_c, dv_acc + dv_c), dq_c
-        (dk, dv), dq_chunks = jax.lax.scan(
-            body, (jnp.zeros_like(kh), jnp.zeros_like(vh)),
-            jnp.arange(n_chunks))
-        # [n_chunks, B, H, C, D] -> [B, H, Nq, D]
-        dq = jnp.moveaxis(dq_chunks, 0, 2).reshape(b, h, nq, d)
-    to = lambda x: jnp.swapaxes(x, 1, 2)
-    return (to(dq).astype(q.dtype), to(dk).astype(k.dtype),
-            to(dv).astype(v.dtype))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash(q, k, v, padding_mask, scale, causal, blocks):
+    return _flash_fwd(q, k, v, scale, causal, padding_mask, blocks)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, scale, causal):
-    out, _ = _flash_fwd(q, k, v, scale, causal)
-    return out
+def _flash_vjp_fwd(q, k, v, padding_mask, scale, causal, blocks):
+    out, lse = _flash_fwd(q, k, v, scale, causal, padding_mask, blocks)
+    return out, (q, k, v, padding_mask, out, lse)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal):
-    out, lse = _flash_fwd(q, k, v, scale, causal)
-    return out, (q, k, v, out, lse)
-
-
-def _bwd_dispatch(q, k, v, out, lse, dout, scale, causal,
-                  padding_mask=None):
-    """XLA recompute backward by default; the Pallas backward kernels
-    when the flash_backward flag allows (chip-smoked lowering only —
-    see flash_attention_bwd.py)."""
-    from ...core.flags import flag_active
-    if flag_active("flash_backward"):
-        from .flash_attention_bwd import flash_attention_bwd, supported
-        if supported(q.shape, k.shape):
-            return flash_attention_bwd(q, k, v, out, lse, dout, scale,
-                                       causal, padding_mask=padding_mask)
-    return _bwd_xla(q, k, v, out, lse, dout, scale, causal,
-                    padding_mask=padding_mask)
-
-
-def _flash_vjp_bwd(scale, causal, res, dout):
-    q, k, v, out, lse = res
-    return _bwd_dispatch(q, k, v, out, lse, dout, scale, causal)
+def _flash_vjp_bwd(scale, causal, blocks, res, dout):
+    from .flash_attention_bwd import flash_attention_bwd
+    q, k, v, padding_mask, out, lse = res
+    dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
+                                     padding_mask=padding_mask,
+                                     blocks=blocks)
+    # the mask enters as f32 0/1 (see flash_attention), so a plain zero
+    # cotangent is the right "non-differentiable" answer
+    dm = None if padding_mask is None else jnp.zeros_like(padding_mask)
+    return dq, dk, dv, dm
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash_masked(q, k, v, padding_mask, scale, causal):
-    out, _ = _flash_fwd(q, k, v, scale, causal, padding_mask=padding_mask)
-    return out
-
-
-def _flash_masked_vjp_fwd(q, k, v, padding_mask, scale, causal):
-    out, lse = _flash_fwd(q, k, v, scale, causal, padding_mask=padding_mask)
-    return out, (q, k, v, padding_mask, out, lse)
-
-
-def _flash_masked_vjp_bwd(scale, causal, res, dout):
-    q, k, v, padding_mask, out, lse = res
-    dq, dk, dv = _bwd_dispatch(q, k, v, out, lse, dout, scale, causal,
-                               padding_mask=padding_mask)
-    # mask enters as f32 0/1 (see flash_attention), so a plain zero
-    # cotangent is the right "non-differentiable" answer
-    return dq, dk, dv, jnp.zeros_like(padding_mask)
-
-
-_flash_masked.defvjp(_flash_masked_vjp_fwd, _flash_masked_vjp_bwd)
-
-
 def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None, padding_mask=None):
+                    scale: Optional[float] = None, padding_mask=None,
+                    blocks: Optional[tuple] = None):
     """Fused attention. ``padding_mask``: optional [B, Nk] keep-mask
     (bool/0-1); padded key positions are excluded from the softmax —
     the Pallas analog of the reference's additive attention-mask input
-    (nn/layer/transformer.py MultiHeadAttention attn_mask)."""
+    (nn/layer/transformer.py MultiHeadAttention attn_mask). ``blocks``
+    overrides :func:`block_sizes` (tests and the sweep tool)."""
     d = q.shape[-1]
     s = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
-    if padding_mask is None:
-        return _flash(q, k, v, s, causal)
-    pm = jnp.asarray(padding_mask)
-    if pm.dtype == jnp.bool_:
-        pm = pm.astype(jnp.float32)
-    return _flash_masked(q, k, v, pm, s, causal)
+    pm = padding_mask
+    if pm is not None:
+        pm = jnp.asarray(pm).astype(jnp.float32)
+    return _flash(q, k, v, pm, s, causal, blocks)

@@ -1,25 +1,18 @@
-"""Pallas flash-attention BACKWARD kernels (FlashAttention-2 split).
+"""Pallas flash-attention BACKWARD kernels (FlashAttention-2 split), on
+the blocked grid of flash_attention.py: logits and probabilities never
+touch HBM, no kernel keeps a whole row of any operand, and a causal
+mask's blocks above the diagonal cost nothing.
 
-The forward (flash_attention.py) recomputes probabilities in XLA for the
-backward; these kernels do the recompute in VMEM instead — logits and
-probabilities never touch HBM in either pass:
-
-* ``_dkv_kernel``: grid over (batch·head, k-block); one pass over the
-  q-blocks accumulates dK and dV for the resident k-block.
-* ``_dq_kernel``: grid over (batch·head, q-block); one pass over the
-  k-blocks accumulates dQ for the resident q-block.
+* ``_dkv_kernel``: grid (batch·head, k-block, q-block); a k-block stays
+  resident while the q-blocks from the diagonal down pass, dK and dV
+  accumulate in VMEM. The scores are computed transposed ([BK, BQ]) so
+  that every product is a plain ``a @ b`` or ``a @ b.T``.
+* ``_dq_kernel``: grid (batch·head, q-block, k-block); a q-block stays
+  resident while the k-blocks up to the diagonal pass.
 
 Both consume the forward's LSE and ``delta = rowsum(dout * out)``
-(computed in XLA — one cheap fused reduction). Scalar-per-row inputs
-ride a trailing singleton dim ([bh, n, 1]) which satisfies Mosaic's
-(8, 128)-or-equal tiling rule without lane broadcasting.
-
-Gated by core flag ``flash_backward`` — default ``auto`` (engaged on
-TPU): chip_smoke.py runs dq/dk/dv on the v5e against the XLA reference
-and tests/test_chip_compile.py compiles them for it. ``never`` restores
-the XLA recompute backward; interpret mode (``always`` off-TPU) does not
-enforce the tiling rules (the forward's LSE layout bug only surfaced on
-hardware).
+(computed in XLA, one fused reduction) as [batch·head, 1, Nq] rows, lane
+dense; the dQ kernel turns its block of them into a column once.
 """
 
 from __future__ import annotations
@@ -29,207 +22,213 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
-from .flash_attention import BLOCK_K, BLOCK_Q, _NEG_INF
+from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _causal_keep,
+                              _dot, _key_block_map, _lanes, _layout,
+                              _run_tile, _unlayout, split_blocks)
+from .flash_attention import block_sizes as forward_block_sizes
 
-__all__ = ["flash_attention_bwd", "supported"]
-
-
-def supported(q_shape, k_shape) -> bool:
-    _, nq, _, d = q_shape
-    _, nk, _, _ = k_shape
-    if nq % BLOCK_Q or nk % BLOCK_K:
-        return False
-    if d % 8 or d > 256:
-        return False
-    # the dkv pass keeps FULL q+do rows resident; the dq pass keeps
-    # full k+v. Measured scoped-VMEM cost (r5, on-chip compile report
-    # at nq=nk=16384, d=64: 32.25 MiB vs the 16 MiB limit) is ~32
-    # bytes per row-element — operands + accumulators + pipeline
-    # double-buffering — so gate on that model with headroom. Shapes
-    # rejected here take the chunked XLA recompute backward
-    # (_bwd_xla), which is HBM-bounded instead.
-    budget = 14 * 1024 * 1024
-    if 32 * max(nq, nk) * d > budget:
-        return False
-    return True
+__all__ = ["flash_attention_bwd", "block_sizes"]
 
 
-def _masks(s_shape, q0, k0, nk, nq, causal, mask_ref):
-    """Additive -inf mask for one [BQ, BK] logits tile."""
-    add = None
-    if causal:
-        q_ids = (q0 + (nk - nq) +
-                 jax.lax.broadcasted_iota(jnp.int32, s_shape, 0))
-        k_ids = k0 + jax.lax.broadcasted_iota(jnp.int32, s_shape, 1)
-        add = jnp.where(q_ids >= k_ids, 0.0, _NEG_INF)
-    if mask_ref is not None:
-        mk = mask_ref[0, pl.ds(k0, s_shape[1]), 0]        # [BK]
-        pad = jnp.where(mk[None, :] > 0.5, 0.0, _NEG_INF)
-        add = pad if add is None else add + pad
-    return add
+def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
+    """((block_k, block_q, chunk) of dK/dV, (block_q, block_k, chunk) of
+    dQ): resident block, block fetched a grid step, slice of it a pass of
+    the body takes. From the same sweep as the forward's, and the same
+    ladders."""
+    bq, bk, chunk_k = forward_block_sizes(nq, nk, d, dtype)
+    _, bq_long, chunk_q = forward_block_sizes(nk, nq, d, dtype)
+    return (chunk_k, bq_long, chunk_q), (bq, bk, chunk_k)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, scale, causal, mask_ref=None):
-    # k_ref/v_ref: [BLOCK_K, D] (resident); q/do: [N_q, D] full rows;
-    # lse/delta: [N_q, 1]
-    k_blk = pl.program_id(1)
-    nq = q_ref.shape[0]
-    nk = pl.num_programs(1) * BLOCK_K
-    d = q_ref.shape[1]
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
+def _dkv_kernel(*refs, scale, causal, off, chunk, has_mask):
+    # k_ref/v_ref: [BK, D] (resident); q_ref/do_ref: [BQ, D];
+    # lse_ref/delta_ref: [1, BQ]; mask_ref: [BK, 1]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    mask_ref = refs[6] if has_mask else None
+    dk_ref, dv_ref, ks_ref, dk_acc, dv_acc = refs[6 + has_mask:]
+    j, i = pl.program_id(1), pl.program_id(2)
+    bq = q_ref.shape[0]
+    bk = k_ref.shape[0]
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[pl.ds(i * BLOCK_Q, BLOCK_Q), :].astype(jnp.float32)
-        do = do_ref[pl.ds(i * BLOCK_Q, BLOCK_Q), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(i * BLOCK_Q, BLOCK_Q), 0]
-        delta = delta_ref[pl.ds(i * BLOCK_Q, BLOCK_Q), 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale
-        add = _masks(s.shape, i * BLOCK_Q, k_blk * BLOCK_K, nk, nq,
-                     causal, mask_ref)
-        if add is not None:
-            s = s + add
+    @pl.when(i == 0)
+    def _():
+        ks_ref[...] = (k_ref[...] * scale).astype(ks_ref.dtype)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def one(c, masked):
+        qs = pl.ds(c * chunk, chunk)
+        q, do = q_ref[qs, :], do_ref[qs, :]
+        s = _dot(ks_ref[...], q, _NT)                        # [BK, C]
+        if has_mask:
+            s = jnp.where(mask_ref[...] > 0.5, s, _NEG_INF)
+        if masked:
+            s = jnp.where(
+                _causal_keep(s.shape, i * bq + c * chunk, j * bk, off, 1),
+                s, _NEG_INF)
         # lse is +inf for fully-masked rows (remapped by the wrapper):
         # p underflows to an exact 0 there
-        p = jnp.exp(s - lse[:, None])                     # [BQ, BK]
-        dv = dv + jax.lax.dot_general(p, do,
-                                      (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dk = dk + jax.lax.dot_general(ds, q,
-                                      (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-        return dk, dv
+        p = jnp.exp(s - lse_ref[:, qs])
+        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
+        dp = _dot(v_ref[...], do, _NT)
+        ds = p * (dp - delta_ref[:, qs])
+        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
 
-    dk0 = jnp.zeros((BLOCK_K, d), jnp.float32)
-    dv0 = jnp.zeros((BLOCK_K, d), jnp.float32)
-    if causal:
-        # q-blocks strictly before this k-block see none of it
-        lo = jnp.maximum(
-            (k_blk * BLOCK_K - (nk - nq)) // BLOCK_Q, 0)
-    else:
-        lo = 0
-    dk, dv = jax.lax.fori_loop(lo, nq // BLOCK_Q, body, (dk0, dv0))
-    dk_ref[:] = dk.astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+    for c in range(bq // chunk):
+        _run_tile(functools.partial(one, c), causal, i * bq + c * chunk,
+                  chunk, j * bk, bk, off)
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, *, scale, causal, mask_ref=None):
-    # q/do: [BLOCK_Q, D] resident; k/v full; lse/delta: [BLOCK_Q, 1]
-    q_blk = pl.program_id(1)
-    nk = k_ref.shape[0]
-    nq = pl.num_programs(1) * BLOCK_Q
-    d = q_ref.shape[1]
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[:, 0]
-    delta = delta_ref[:, 0]
+def _column(row_ref):
+    """A [1, N] block as a lane-replicated [N, 128] value."""
+    col = jnp.expand_dims(row_ref[0], -1)
+    return jnp.broadcast_to(col, (col.shape[0], _LANES))
 
-    def body(i, dq):
-        k = k_ref[pl.ds(i * BLOCK_K, BLOCK_K), :].astype(jnp.float32)
-        v = v_ref[pl.ds(i * BLOCK_K, BLOCK_K), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale
-        add = _masks(s.shape, q_blk * BLOCK_Q, i * BLOCK_K, nk, nq,
-                     causal, mask_ref)
-        if add is not None:
-            s = s + add
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
 
-    dq0 = jnp.zeros((BLOCK_Q, d), jnp.float32)
-    if causal:
-        hi = pl.cdiv((q_blk + 1) * BLOCK_Q + (nk - nq), BLOCK_K)
-        hi = jnp.minimum(hi, nk // BLOCK_K)
-    else:
-        hi = nk // BLOCK_K
-    dq = jax.lax.fori_loop(0, hi, body, dq0)
-    dq_ref[:] = dq.astype(dq_ref.dtype)
+def _dq_kernel(*refs, scale, causal, off, chunk, has_mask):
+    # q_ref/do_ref: [BQ, D] resident; k_ref/v_ref: [BK, D];
+    # lse_ref/delta_ref: [1, BQ]; mask_ref: [1, BK]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    mask_ref = refs[6] if has_mask else None
+    dq_ref, qs_ref, lse_col, delta_col, dq_acc = refs[6 + has_mask:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    bq = q_ref.shape[0]
+    bk = k_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _():
+        qs_ref[...] = (q_ref[...] * scale).astype(qs_ref.dtype)
+        lse_col[...] = _column(lse_ref)
+        delta_col[...] = _column(delta_ref)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def one(c, masked):
+        ks = pl.ds(c * chunk, chunk)
+        k = k_ref[ks, :]
+        s = _dot(qs_ref[...], k, _NT)
+        if has_mask:
+            s = jnp.where(mask_ref[:, ks] > 0.5, s, _NEG_INF)
+        if masked:
+            s = jnp.where(
+                _causal_keep(s.shape, i * bq, j * bk + c * chunk, off, 0),
+                s, _NEG_INF)
+        p = jnp.exp(s - _lanes(lse_col[...], chunk))
+        dp = _dot(do_ref[...], v_ref[ks, :], _NT)
+        ds = p * (dp - _lanes(delta_col[...], chunk))
+        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
+
+    for c in range(bk // chunk):
+        _run_tile(functools.partial(one, c), causal, i * bq, bq,
+                  j * bk + c * chunk, chunk, off)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
-                        padding_mask=None):
-    """(dq, dk, dv) in the paddle [B, N, H, D] layout — drop-in for
-    flash_attention._bwd_xla."""
+                        padding_mask=None, blocks=None):
+    """(dq, dk, dv) in the paddle [B, N, H, D] layout from the
+    forward's residuals; ``lse`` is [batch*heads, Nq]. A ``jit`` of its
+    own, as the forward and for its reason."""
+    return _bwd_call(q, k, v, out, lse, dout, padding_mask, scale=scale,
+                     causal=causal, blocks=blocks,
+                     interpret=_common.interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "blocks",
+                                             "interpret"))
+def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, causal,
+              blocks, interpret):
     b, nq, h, d = q.shape
     nk = k.shape[1]
-    to_bhnd = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
-    qh, kh, vh = to_bhnd(q), to_bhnd(k), to_bhnd(v)
-    doh, oh = to_bhnd(dout), to_bhnd(out)
+    off = nk - nq
+    defaults = block_sizes(nq, nk, d, q.dtype)
+    _, dkv, dq = split_blocks(blocks)
+    kb_kv, qb_kv, c_kv = dkv or defaults[0]
+    qb_q, kb_q, c_q = dq or defaults[1]
+    qa, at = _layout(q)
+    ka, _ = _layout(k)
+    va, _ = _layout(v)
+    doa, _ = _layout(dout)
 
     # delta = rowsum(dout * out): one fused XLA reduction
-    delta = jnp.sum(doh.astype(jnp.float32) * oh.astype(jnp.float32),
-                    axis=-1, keepdims=True)               # [bh, nq, 1]
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)                                  # [B, Nq, H]
+    delta = delta.transpose(0, 2, 1).reshape(b * h, 1, nq)
     # fully-padded rows carry the forward's FINITE sentinel LSE; remap to
-    # +inf so exp(s - lse) is an exact 0 for every key (same guard as
-    # _bwd_xla — exp(s - (-1e30)) would be exp(0) = 1, garbage grads)
-    lse3 = lse.reshape(b * h, nq, 1).astype(jnp.float32)
-    lse3 = jnp.where(lse3 > _NEG_INF * 0.1, lse3, jnp.inf)
+    # +inf so exp(s - lse) is an exact 0 for every key (exp(s - (-1e30))
+    # would be exp(0) = 1, garbage grads)
+    lse = lse.reshape(b * h, 1, nq).astype(jnp.float32)
+    lse = jnp.where(lse > _NEG_INF * 0.1, lse, jnp.inf)
 
-    args = [qh, kh, vh, doh, lse3, delta]
-    qspec = pl.BlockSpec((None, BLOCK_Q, d), lambda bh, i: (bh, i, 0))
-    kfull = pl.BlockSpec((None, nk, d), lambda bh, i: (bh, 0, 0))
-    qfull = pl.BlockSpec((None, nq, d), lambda bh, i: (bh, 0, 0))
-    kspec = pl.BlockSpec((None, BLOCK_K, d), lambda bh, i: (bh, i, 0))
-    row_q = pl.BlockSpec((None, BLOCK_Q, 1), lambda bh, i: (bh, i, 0))
-    row_qfull = pl.BlockSpec((None, nq, 1), lambda bh, i: (bh, 0, 0))
+    args = [qa, ka, va, doa, lse, delta]
+    has_mask = padding_mask is not None
+    params = dict(scale=scale, causal=causal, off=off, has_mask=has_mask)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
-    mask_arg, mask_specs = (), ()
-    if padding_mask is not None:
-        mk = padding_mask.astype(jnp.float32).reshape(b, 1, nk, 1)
-        mask_arg = (mk,)
-        mask_specs = (pl.BlockSpec((None, 1, nk, 1),
-                                   lambda bh, i: (bh // h, 0, 0, 0)),)
-
-    def with_mask(kern, n_outs):
-        if padding_mask is None:
-            return functools.partial(kern, scale=scale, causal=causal)
-
-        def k2(*refs):
-            *ins, m_ref = refs[:len(refs) - n_outs]
-            outs = refs[len(refs) - n_outs:]
-            kern(*ins, *outs, scale=scale, causal=causal,
-                 mask_ref=m_ref)
-        return k2
-
-    # dkv pass
+    # dK/dV: the first query block that sees key block j
+    if causal:
+        qi = lambda j, i: jnp.maximum(
+            i, jnp.maximum(j * kb_kv - off, 0) // qb_kv)
+    else:
+        qi = lambda j, i: i
+    rows = pl.BlockSpec((None, qb_kv, d), lambda g, j, i: at(g, qi(j, i)))
+    keys = pl.BlockSpec((None, kb_kv, d), lambda g, j, i: at(g, j))
+    stat = pl.BlockSpec((None, 1, qb_kv), lambda g, j, i: (g, 0, qi(j, i)))
+    in_specs = [rows, keys, keys, rows, stat, stat]
+    if has_mask:
+        in_specs.append(pl.BlockSpec((None, kb_kv, 1),
+                                     lambda g, j, i: (g // h, j, 0)))
+        args.append(padding_mask.astype(jnp.float32).reshape(b, nk, 1))
     dk, dv = pl.pallas_call(
-        with_mask(_dkv_kernel, 2),
-        grid=(b * h, nk // BLOCK_K),
-        in_specs=[qfull, kspec, kspec, qfull, row_qfull, row_qfull,
-                  *mask_specs],
-        out_specs=[kspec, kspec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, nk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, nk, d), v.dtype)],
+        functools.partial(_dkv_kernel, chunk=c_kv, **params),
+        grid=(b * h, nk // kb_kv, nq // qb_kv),
+        in_specs=in_specs,
+        out_specs=[keys, keys],
+        out_shape=[jax.ShapeDtypeStruct(ka.shape, k.dtype),
+                   jax.ShapeDtypeStruct(va.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((kb_kv, d), k.dtype),
+                        pltpu.VMEM((kb_kv, d), jnp.float32),
+                        pltpu.VMEM((kb_kv, d), jnp.float32)],
+        compiler_params=semantics,
         name="p1t_flash_attention_bwd_dkv",
-        interpret=_common.interpret(),
-    )(*args, *mask_arg)
+        interpret=interpret,
+    )(*args)
 
-    # dq pass
+    # dQ: the last key block that query block i sees
+    kj = _key_block_map(causal, qb_q, kb_q, off, nk)
+    rows = pl.BlockSpec((None, qb_q, d), lambda g, i, j: at(g, i))
+    keys = pl.BlockSpec((None, kb_q, d), lambda g, i, j: at(g, kj(i, j)))
+    stat = pl.BlockSpec((None, 1, qb_q), lambda g, i, j: (g, 0, i))
+    in_specs = [rows, keys, keys, rows, stat, stat]
+    if has_mask:
+        in_specs.append(pl.BlockSpec(
+            (None, 1, kb_q), lambda g, i, j: (g // h, 0, kj(i, j))))
+        args[-1] = args[-1].reshape(b, 1, nk)
     dq = pl.pallas_call(
-        with_mask(_dq_kernel, 1),
-        grid=(b * h, nq // BLOCK_Q),
-        in_specs=[qspec, kfull, kfull, qspec, row_q, row_q, *mask_specs],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((b * h, nq, d), q.dtype),
+        functools.partial(_dq_kernel, chunk=c_q, **params),
+        grid=(b * h, nq // qb_q, nk // kb_q),
+        in_specs=in_specs,
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct(qa.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((qb_q, d), q.dtype),
+                        pltpu.VMEM((qb_q, _LANES), jnp.float32),
+                        pltpu.VMEM((qb_q, _LANES), jnp.float32),
+                        pltpu.VMEM((qb_q, d), jnp.float32)],
+        compiler_params=semantics,
         name="p1t_flash_attention_bwd_dq",
-        interpret=_common.interpret(),
-    )(*args, *mask_arg)
+        interpret=interpret,
+    )(*args)
 
-    back = lambda x: x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)
-    return back(dq), back(dk), back(dv)
+    return (_unlayout(dq, b, h, d), _unlayout(dk, b, h, d),
+            _unlayout(dv, b, h, d))

@@ -3,6 +3,8 @@ Adam) — run in interpreter mode on the CPU sim, exercising the same kernel
 code the TPU executes. Mirrors the reference's fused-op unit tests
 (test_fused_* over operators/fused/)."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -10,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle1_tpu.core.flags import flags_guard
+from paddle1_tpu.nn.functional.attention import FLASH_MIN_SEQ as N
 
 
 class TestFlashPaddingMask:
@@ -348,201 +351,265 @@ class TestFusedAdam:
         assert n >= fadam._CHUNK  # the weight actually took the fused path
 
 
-class TestFlashBackwardKernels:
-    """Pallas flash BACKWARD (ops/pallas/flash_attention_bwd.py) vs the
-    XLA recompute backward and vs autodiff of the dense reference —
-    interpret mode (flag default stays 'never' until the chip smoke)."""
+def _attention_problem(nq, nk, d, dtype, mask, b=2, h=2, seed=0):
+    rng = np.random.default_rng(seed)
+    mk = lambda n: jnp.asarray(rng.standard_normal((b, n, h, d)), dtype)
+    q, k, v, dout = mk(nq), mk(nk), mk(nk), mk(nq)
+    keep = None
+    if mask:
+        keep = np.ones((b, nk), bool)
+        keep[0, nk - 37:] = False            # a ragged tail in one block
+        if mask == "padded_row":
+            keep[1, :] = False               # batch entry 1 sees nothing
+    return q, k, v, dout, keep
 
-    def _problem(self, causal=False, masked=False, nq=256, nk=256):
-        rng = np.random.default_rng(0)
-        B, H, D = 2, 4, 64
-        q, k, v = (jnp.asarray(rng.standard_normal((B, n, H, D))
-                               .astype(np.float32))
-                   for n in (nq, nk, nk))
-        pm = jnp.asarray((rng.random((B, nk)) > 0.25)
-                         .astype(np.float32)) if masked else None
-        dout = jnp.asarray(rng.standard_normal((B, nq, H, D))
-                           .astype(np.float32))
-        return q, k, v, pm, dout
 
-    def _grads(self, q, k, v, pm, dout, causal):
+def _flash_and_ref(q, k, v, dout, keep, causal, blocks):
+    """(out, dq, dk, dv) of the kernels, and of attention_ref followed
+    in float32 (a fully padded batch entry's rows zeroed: the kernels
+    give zeros there, a softmax over nothing gives a mean)."""
+    from paddle1_tpu.nn.functional.attention import attention_ref
+    from paddle1_tpu.ops.pallas import flash_attention as fa
+    f32 = lambda x: x.astype(jnp.float32)
+    pm = None if keep is None else jnp.asarray(keep)
+    dead = (np.zeros(q.shape[0], bool) if keep is None
+            else ~keep.any(axis=1))
+    alive = jnp.asarray(~dead, jnp.float32)[:, None, None, None]
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, padding_mask=pm,
+                                  blocks=blocks)
+
+    def plain(q, k, v):
+        m4 = None if pm is None else pm[:, None, None, :]
+        return attention_ref(q, k, v, mask=m4, is_causal=causal) * alive
+
+    out, vjp = jax.vjp(kernel, q, k, v)
+    want, vjp_ref = jax.vjp(plain, f32(q), f32(k), f32(v))
+    return ((out,) + vjp(dout), (want,) + vjp_ref(f32(dout)), dead)
+
+
+# (causal, nq, nk, d, dtype, mask, blocks): every pairing the kernels
+# branch on. blocks = (resident, fetched, chunk) of all three kernels.
+_B128 = (128, 128, 128)
+FLASH_CASES = [
+    (causal, nq, nk, d, dtype, mask, _B128)
+    for causal in (False, True)
+    for nq, nk in ((256, 256), (128, 384))    # nq < nk: bottom-right
+    for d in (64, 128)                        # transposed / packed layout
+    for dtype, mask in (("float32", None), ("float32", "padding"),
+                        ("bfloat16", "padding" if d == 64 else None))
+] + [
+    (False, 256, 256, 64, "float32", "padded_row", _B128),
+    (True, 256, 256, 128, "float32", "padded_row", _B128),
+    (True, 128, 384, 64, "bfloat16", "padded_row", _B128),
+    # some key blocks wholly above the diagonal: skipped, index map
+    # clamped; fetched block of two chunks: the chunk-level skip too
+    (True, 512, 512, 128, "float32", None, (128, 256, 128)),
+    (True, 512, 512, 64, "float32", "padding", (128, 256, 128)),
+    (True, 512, 512, 128, "bfloat16", None, (256, 128, 128)),
+    (True, 256, 512, 64, "float32", None, (128, 256, 128)),
+    (True, 384, 640, 128, "float32", "padding", (128, 128, 128)),
+    # the sizes the code picks by itself
+    (True, 512, 512, 128, "bfloat16", None, None),
+    (False, 256, 384, 64, "float32", "padding", None),
+]
+
+
+class TestFlashKernels:
+    """The blockwise forward and both backward kernels
+    (ops/pallas/flash_attention.py, flash_attention_bwd.py) against
+    autodiff of the dense reference, in interpret mode."""
+
+    @pytest.mark.parametrize(
+        "causal,nq,nk,d,dtype,mask,blocks", FLASH_CASES,
+        ids=lambda c: "x".join(map(str, c)) if isinstance(c, tuple)
+        else str(c))
+    def test_forward_and_gradients_match_the_reference(
+            self, causal, nq, nk, d, dtype, mask, blocks):
+        q, k, v, dout, keep = _attention_problem(nq, nk, d,
+                                                 jnp.dtype(dtype), mask)
+        got, want, dead = _flash_and_ref(q, k, v, dout, keep, causal,
+                                         blocks)
+        # bf16: the probabilities and ds are rounded once (2^-9 of
+        # values up to 1 and up to |dout| |v| sqrt(d))
+        tol = 2e-5 if dtype == "float32" else 6e-2
+        for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+            assert g.dtype == q.dtype, name
+            g = np.asarray(g.astype(jnp.float32))
+            assert np.isfinite(g).all(), name
+            np.testing.assert_allclose(g, np.asarray(w), rtol=tol,
+                                       atol=tol, err_msg=name)
+            # a batch entry that sees nothing: exact zeros, not the
+            # exp(0) = 1 of a sentinel maximum
+            np.testing.assert_array_equal(g[dead], 0.0, err_msg=name)
+
+    def test_degenerate_alignments_are_left_to_the_reference(self):
         from paddle1_tpu.ops.pallas import flash_attention as fa
-        from paddle1_tpu.ops.pallas.flash_attention_bwd import \
-            flash_attention_bwd
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        out, lse = fa._flash_fwd(q, k, v, scale, causal,
-                                 padding_mask=pm)
-        got = flash_attention_bwd(q, k, v, out, lse, dout, scale,
-                                  causal, padding_mask=pm)
-        want = fa._bwd_xla(q, k, v, out, lse, dout, scale, causal,
-                           padding_mask=pm)
-        return got, want
+        ok = (2, 256, 4, 64)
+        assert fa.supported(ok, ok, causal=True)
+        assert fa.supported((1, 65536, 1, 128), (1, 65536, 1, 128), True)
+        assert fa.supported((2, 128, 4, 64), ok, causal=True)
+        assert not fa.supported(ok, (2, 128, 4, 64), causal=True)
+        assert fa.supported(ok, (2, 128, 4, 64), causal=False)
+        assert not fa.supported((2, 200, 4, 64), ok)
+        assert not fa.supported(ok, (2, 200, 4, 64))
+        assert not fa.supported((2, 256, 4, 260), (2, 256, 4, 260))
+        assert not fa.supported((2, 256, 4, 60), (2, 256, 4, 60))
+        assert not fa.supported((256, 4, 64), (256, 4, 64))
 
-    def _check(self, got, want):
-        for g, w, name in zip(got, want, "q k v".split()):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=2e-3, atol=2e-3,
-                                       err_msg=f"d{name}")
-
-    def test_plain(self):
-        q, k, v, pm, dout = self._problem()
-        got, want = self._grads(q, k, v, None, dout, causal=False)
-        self._check(got, want)
-
-    def test_causal(self):
-        q, k, v, pm, dout = self._problem(causal=True)
-        got, want = self._grads(q, k, v, None, dout, causal=True)
-        self._check(got, want)
-
-    def test_padding_mask(self):
-        q, k, v, pm, dout = self._problem(masked=True)
-        got, want = self._grads(q, k, v, pm, dout, causal=False)
-        self._check(got, want)
-
-    def test_causal_rectangular(self):
-        # nq < nk (bottom-right alignment)
-        q, k, v, pm, dout = self._problem(causal=True, nq=128, nk=256)
-        got, want = self._grads(q, k, v, None, dout, causal=True)
-        self._check(got, want)
-
-    def test_matches_dense_autodiff_end_to_end(self):
-        from paddle1_tpu.core.flags import flags_guard
-        from paddle1_tpu.nn.functional.attention import attention_ref
-        from paddle1_tpu.ops.pallas.flash_attention import flash_attention
-        q, k, v, pm, dout = self._problem(masked=True)
-
-        with flags_guard(flash_backward="always"):
-            dq_p = jax.grad(lambda q: jnp.sum(
-                flash_attention(q, k, v, padding_mask=pm) * dout))(q)
-        dq_ref = jax.grad(lambda q: jnp.sum(attention_ref(
-            q, k, v, mask=(pm[:, None, None, :] > 0.5)) * dout))(q)
-        np.testing.assert_allclose(np.asarray(dq_p), np.asarray(dq_ref),
-                                   rtol=5e-3, atol=5e-3)
-
-    def test_flag_default_is_auto(self):
-        # auto: chip_smoke.py checks dq/dk/dv on the chip against the
-        # XLA reference, tests/test_chip_compile.py compiles them
-        from paddle1_tpu.core.flags import flag
-        assert flag("flash_backward") == "auto"
-
-    def test_fully_padded_row_zero_grads(self):
-        # one batch entry entirely padded: all three grads must be EXACT
-        # zeros for it (the sentinel-LSE remap; review r3 finding)
-        q, k, v, pm, dout = self._problem(masked=True)
-        pm = pm.at[1].set(0.0)
-        got, want = self._grads(q, k, v, pm, dout, causal=False)
-        for g, name in zip(got, "q k v".split()):
-            np.testing.assert_array_equal(
-                np.asarray(g)[1], 0.0,
-                err_msg=f"d{name} row 1 must be exactly zero")
-        self._check(got, want)
-
-    def test_supported_bounds_full_sequence_residency(self):
-        from paddle1_tpu.ops.pallas.flash_attention_bwd import supported
-        assert supported((2, 256, 4, 64), (2, 256, 4, 64))
-        # 65536 q rows x 128 head dim: full q+do residency > VMEM budget
-        assert not supported((1, 65536, 1, 128), (1, 1024, 1, 128))
+    @pytest.mark.parametrize("d,dtype", [(128, "bfloat16"), (256, "float32")])
+    @pytest.mark.parametrize("nq,nk", [(128, 128), (384, 640), (4096, 4096),
+                                       (512, 8192), (1536, 1536)])
+    def test_the_chosen_blocks_divide_the_lengths_and_fit(self, nq, nk, d,
+                                                          dtype):
+        from paddle1_tpu.ops.pallas import flash_attention as fa
+        from paddle1_tpu.ops.pallas import flash_attention_bwd as fb
+        bq, bk, chunk = fa.block_sizes(nq, nk, d, dtype)
+        assert bk * d * jnp.dtype(dtype).itemsize <= 512 << 10
+        assert nq % bq == 0 and nk % bk == 0 and bk % chunk == 0
+        (kb, qb, c1), (qb2, kb2, c2) = fb.block_sizes(nq, nk, d, dtype)
+        assert nk % kb == 0 and nq % qb == 0 and qb % c1 == 0
+        assert nq % qb2 == 0 and nk % kb2 == 0 and kb2 % c2 == 0
 
 
-class TestFlashAutoDispatch:
-    """r5: flash_attention=auto is memory-adaptive — XLA dense attention
-    below flash_auto_score_mb, Pallas flash above (a sweep older than
-    PRs 1-20, on another machine, found dense faster at every
-    compute-bound length; not re-measured on the v5e)."""
+def _primitives(jaxpr, seen=None):
+    """Names of the primitives of a jaxpr and of every jaxpr inside it,
+    a ``pallas_call``'s own body left out (its grid is the kernel's)."""
+    seen = [] if seen is None else seen
+    for eqn in jaxpr.eqns:
+        seen.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, seen)
+    return seen
 
-    def _route(self, monkeypatch, b, s, h=4, d=64, threshold_mb=4,
-               mode="auto"):
-        import jax
-        import numpy as np
+
+def test_a_causal_models_step_holds_no_while_on_the_kernels_path():
+    """``trace_reduce`` counts a ``while`` instruction's event and its
+    body's events both (PERF.md, PR 27): the kernels' wrappers put no
+    ``scan`` / ``while`` / ``fori_loop`` in the step they are traced
+    into, forward, recomputed forward or backward."""
+    import paddle1_tpu as paddle
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed import ParallelEngine, build_mesh
+    from paddle1_tpu.text.models import (OuroForPretraining,
+                                         OuroPretrainingCriterion)
+    model = OuroForPretraining(
+        vocab_size=96, hidden_size=32, num_hidden_layers=1,
+        num_attention_heads=2, head_dim=16, intermediate_size=48,
+        total_ut_steps=2, rope_theta=1e4, rms_norm_eps=1e-6,
+        initializer_range=0.2)
+    crit = OuroPretrainingCriterion(0.1)
+
+    def loss_fn(m, b):
+        ids = Tensor(b["ids"])
+        labels = m.next_token_labels(ids)
+        return crit(*m(ids, labels), labels)
+    engine = ParallelEngine(
+        model, paddle.optimizer.AdamW(learning_rate=1e-3,
+                                      parameters=model.parameters()),
+        loss_fn, amp_dtype="bfloat16", recompute=True,
+        mesh=build_mesh(dp=1, devices=jax.devices()[:1]))
+    ids = jnp.zeros((2, 128), jnp.int32)
+    with flags_guard(flash_attention="always"):
+        names = _primitives(jax.make_jaxpr(engine._step_fn)(
+            engine.params, engine.opt_state, {"ids": ids},
+            jax.random.key(0), jnp.float32(1e-3)).jaxpr)
+    # 2 loop steps x 1 layer: forward, recomputed forward, dK/dV, dQ
+    assert names.count("pallas_call") == 8
+    assert not {"while", "scan"} & set(names)
+
+
+# (platform, one device's step, flag, causal, seq_q, seq_k, d) -> arm
+ARM_RULE = [
+    ("tpu", True, "auto", True, 4096, 4096, 128, "flash"),      # ouro
+    ("tpu", True, "auto", True, N, N, 128, "flash"),
+    ("tpu", True, "auto", True, N, N, 64, "flash"),
+    ("tpu", True, "auto", True, N - 128, N - 128, 128, "dense"),
+    ("tpu", True, "auto", True, N, 2 * N, 128, "flash"),
+    ("tpu", True, "auto", True, N - 128, 2 * N, 128, "dense"),
+    ("tpu", True, "auto", False, 128, 128, 64, "dense"),        # bert
+    ("tpu", True, "auto", False, 512, 512, 64, "dense"),
+    ("tpu", True, "auto", False, N, N, 64, "flash"),            # mask or
+    ("tpu", True, "auto", False, 4096, 4096, 128, "flash"),     # not
+    ("tpu", True, "auto", True, 4096 + 64, 4096 + 64, 128, "dense"),
+    ("tpu", True, "auto", True, 4096, 4096, 132, "dense"),
+    ("tpu", False, "auto", True, 4096, 4096, 128, "dense"),     # GSPMD
+    ("cpu", True, "auto", True, 4096, 4096, 128, "dense"),
+    ("cpu", True, "always", False, 128, 128, 64, "flash"),
+    ("tpu", False, "always", True, 256, 256, 64, "flash"),
+    ("tpu", True, "never", True, 4096, 4096, 128, "dense"),
+]
+
+
+class TestAttentionArm:
+    """``use_flash_for``: which arm ``scaled_dot_product_attention``
+    traces, from platform, devices, mask and shape alone, and the
+    counter that says so."""
+
+    @pytest.mark.parametrize("platform,single,mode,causal,sq,sk,d,arm",
+                             ARM_RULE)
+    def test_the_rule(self, platform, single, mode, causal, sq, sk, d, arm,
+                      monkeypatch):
+        from paddle1_tpu import obs
         from paddle1_tpu.core import flags as core_flags
         from paddle1_tpu.core.tensor import Tensor
-        from paddle1_tpu.nn.functional.attention import \
-            scaled_dot_product_attention as sdpa
+        from paddle1_tpu.nn.functional import attention as A
         from paddle1_tpu.ops.pallas import flash_attention as fa
+        monkeypatch.setattr(core_flags, "_on_tpu",
+                            lambda: platform == "tpu")
+        took = []
 
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        hit = {"flash": False}
+        def stop(name):
+            def spy(*a, **k):
+                took.append(name)
+                raise RuntimeError("stop-at-dispatch")
+            return spy
+        monkeypatch.setattr(fa, "flash_attention", stop("flash"))
+        monkeypatch.setattr(A, "attention_ref", stop("dense"))
+        obs.reset_process_registry()
+        q = Tensor(np.zeros((1, sq, 1, d), np.float32))
+        k = Tensor(np.zeros((1, sk, 1, d), np.float32))
+        region = (contextlib.nullcontext() if single
+                  else core_flags.auto_partitioned_region())
+        with core_flags.flags_guard(flash_attention=mode), region:
+            with pytest.raises(RuntimeError, match="stop-at-dispatch"):
+                A.scaled_dot_product_attention(q, k, k, is_causal=causal)
+        assert took == [arm]
+        arms = obs.registry.process_group("arm")
+        assert arms.labels() == [arm]
+        assert arms.child(arm).counter("attention_arm_total").value == 1
+        obs.reset_process_registry()
 
-        def spy(*a, **k):
-            hit["flash"] = True
-            raise RuntimeError("stop-at-dispatch")
-        monkeypatch.setattr(fa, "flash_attention", spy)
-        x = Tensor(np.zeros((b, s, h, d), np.float32))
-        with core_flags.flags_guard(flash_attention=mode,
-                                    flash_auto_score_mb=threshold_mb):
-            try:
-                sdpa(x, x, x)
-            except RuntimeError as e:
-                assert "stop-at-dispatch" in str(e)
-        return hit["flash"]
+    def test_the_counter_counts_traces_not_runs(self):
+        from paddle1_tpu import obs
+        from paddle1_tpu.core.tensor import Tensor
+        from paddle1_tpu.nn.functional import attention as A
+        from paddle1_tpu.obs import http
+        obs.reset_process_registry()
+        x = jnp.ones((1, 128, 2, 16), jnp.float32)
 
-    def test_small_seq_routes_dense(self, monkeypatch):
-        # est = 2*4*128*128*(2*4+8)B = 2 MiB < 4 MiB -> dense
-        assert self._route(monkeypatch, b=2, s=128) is False
-
-    def test_large_seq_routes_flash(self, monkeypatch):
-        # est = 2*4*1024*1024*(2*4+8)B = 128 MiB >= 4 MiB -> flash
-        assert self._route(monkeypatch, b=2, s=1024) is True
-
-    def test_always_ignores_threshold(self, monkeypatch):
-        assert self._route(monkeypatch, b=2, s=128, threshold_mb=10**6,
-                           mode="always") is True
-
-    def test_bad_threshold_rejected(self):
-        import pytest
-        from paddle1_tpu.core import flags as core_flags
-        from paddle1_tpu.core.errors import InvalidArgumentError
-        for bad in (0, -5):
-            with pytest.raises(InvalidArgumentError):
-                core_flags.set_flags({"flash_auto_score_mb": bad})
-        # fractional thresholds are legal (float flag, not int)
-        with core_flags.flags_guard(flash_auto_score_mb=0.5):
-            assert core_flags.flag("flash_auto_score_mb") == 0.5
-
-
-class TestChunkedXlaBackward:
-    """r5: _bwd_xla scans over query chunks for long sequences (the
-    memory-escape backward when the Pallas kernels' VMEM model rejects
-    the shape). Chunked must equal dense exactly."""
-
-    def _problem(self, b=2, nq=256, nk=256, h=2, d=32, masked=False):
-        rng = np.random.default_rng(0)
-        mk = lambda *s: jnp.asarray(
-            rng.standard_normal(s).astype(np.float32) * 0.3)
-        q, k, v = mk(b, nq, h, d), mk(b, nk, h, d), mk(b, nk, h, d)
-        dout = mk(b, nq, h, d)
-        pm = None
-        if masked:
-            keep = np.ones((b, nk), np.float32)
-            keep[:, nk - 40:] = 0.0
-            pm = jnp.asarray(keep)
-        return q, k, v, pm, dout
-
-    @pytest.mark.parametrize("causal,masked,nq,nk", [
-        (False, False, 256, 256),
-        (True, False, 256, 256),
-        (True, False, 128, 256),     # rectangular bottom-right causal
-        (False, True, 256, 256),
-    ])
-    def test_chunked_equals_dense(self, causal, masked, nq, nk):
-        from paddle1_tpu.ops.pallas import flash_attention as fa
-        q, k, v, pm, dout = self._problem(nq=nq, nk=nk, masked=masked)
-        scale = 1.0 / (q.shape[-1] ** 0.5)
-        out, lse = fa._flash_fwd(q, k, v, scale, causal,
-                                 padding_mask=pm)
-        dense = fa._bwd_xla(q, k, v, out, lse, dout, scale, causal,
-                            padding_mask=pm, q_chunk=nq)
-        chunked = fa._bwd_xla(q, k, v, out, lse, dout, scale, causal,
-                              padding_mask=pm, q_chunk=64)
-        for g1, g2, name in zip(dense, chunked, "dq dk dv".split()):
-            np.testing.assert_allclose(
-                np.asarray(g1), np.asarray(g2), rtol=1e-5, atol=1e-5,
-                err_msg=f"{name} causal={causal} masked={masked}")
-
-    def test_vmem_model_rejects_long_seq(self):
-        from paddle1_tpu.ops.pallas.flash_attention_bwd import supported
-        assert supported((1, 4096, 12, 64), (1, 4096, 12, 64))
-        # 32 * 16384 * 64 = 32 MiB > the 14 MiB budget (measured OOM
-        # at 32.25 MiB scoped vmem on chip)
-        assert not supported((1, 16384, 12, 64), (1, 16384, 12, 64))
-        assert not supported((1, 8192, 12, 64), (1, 8192, 12, 64))
+        @jax.jit
+        def f(x):
+            return A.scaled_dot_product_attention(
+                Tensor(x), Tensor(x), Tensor(x), is_causal=True).data
+        with flags_guard(flash_attention="always"):
+            f(x), f(x), f(x)
+        with flags_guard(flash_attention="never"):
+            A.scaled_dot_product_attention(Tensor(x), Tensor(x), Tensor(x))
+        arms = obs.registry.process_group("arm")
+        count = lambda a: arms.child(a).counter(
+            "attention_arm_total").value
+        assert (count("flash"), count("dense")) == (1, 1)
+        server = http.TelemetryServer(port=0)
+        page = server._metrics_page()
+        server._httpd.server_close()   # never started: nothing to stop
+        assert 'p1t_attention_arm_total{arm="flash"} 1' in page
+        assert 'p1t_attention_arm_total{arm="dense"} 1' in page
+        obs.reset_process_registry()
+        assert obs.registry.process_group("arm").labels() == []

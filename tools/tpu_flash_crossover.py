@@ -1,70 +1,224 @@
-"""Measure the flash-vs-dense attention crossover on chip (r5): at
-seq 128 XLA's dense attention beat the Pallas flash path by 1.5x at the
-BERT-step level; find the sequence length where flash starts winning so
-the dispatch can pick per-shape. Constant token count (b*s = 16384),
-BERT-base head geometry, fwd+bwd via the public functional API.
+"""The attention sweep behind ``use_flash_for`` and ``block_sizes``
+(PR 28), on the chip it is run on: forward + backward of one attention
+call in isolation, milliseconds a call, one table out.
 
-``python tools/tpu_flash_crossover.py``
+Arms: ``dense`` (XLA's composition, ``attention_ref``), ``kernel`` (the
+Pallas blockwise kernels of ops/pallas/flash_attention*.py, under each
+candidate block triple), ``chunked`` (the same skip as an unrolled XLA
+composition: query chunk i against keys [0, (i+1) * chunk)), ``jax``
+(``jax.experimental.pallas.ops.tpu.flash_attention`` in its own
+[B, H, N, D] layout, the yardstick).
+
+    python tools/tpu_flash_crossover.py [--part blocks|lengths|all]
+
+``blocks``: the Ouro shape [2, 4096, 16, 128] bf16 causal, each kernel
+alone under each block triple. ``lengths``: 8192 tokens at sequence
+lengths 512 .. 8192 (d 128 and d 64, causal and not, and BERT's two
+shapes), kernel against dense: the crossover.
+Writes chiprun_out/flash_sweep.json beside the table.
 """
 
+import argparse
+import functools
+import json
+import os
 import sys
 import time
 
-import numpy as np
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _min_time(f, k=6, trials=4):
+def _ms(f, *args, k=10, trials=3):
     import jax
-    np.asarray(jax.device_get(f()))
+    jax.block_until_ready(f(*args))
     best = None
     for _ in range(trials):
         t0 = time.perf_counter()
         r = None
         for _ in range(k):
-            r = f()
-        np.asarray(jax.device_get(r))
+            r = f(*args)
+        jax.block_until_ready(r)
         dt = (time.perf_counter() - t0) / k
         best = dt if best is None else min(best, dt)
-    return best
+    return best * 1e3
+
+
+def chunked_causal(q, k, v, chunks):
+    """Causal attention as ``chunks`` dense pieces, no ``while``: piece i
+    takes queries [i*c, (i+1)*c) against keys [0, (i+1)*c). bf16
+    operands, float32 scores and softmax, probabilities in q's dtype."""
+    import jax
+    import jax.numpy as jnp
+    n, d = q.shape[1], q.shape[-1]
+    c = n // chunks
+    outs = []
+    for i in range(chunks):
+        hi = (i + 1) * c
+        s = jnp.einsum("bqhd,bkhd->bhqk", q[:, i * c:hi], k[:, :hi],
+                       preferred_element_type=jnp.float32) / (d ** 0.5)
+        rows = i * c + jax.lax.broadcasted_iota(jnp.int32, (c, hi), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (c, hi), 1)
+        s = jnp.where(rows >= cols, s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        outs.append(jnp.einsum("bhqk,bkhd->bqhd", p, v[:, :hi]))
+    return jnp.concatenate(outs, axis=1)
+
+
+def _inputs(b, s, h, d, dtype, layout="bnhd"):
+    import jax
+    import jax.numpy as jnp
+    shape = (b, s, h, d) if layout == "bnhd" else (b, h, s, d)
+    keys = jax.random.split(jax.random.key(0), 4)
+    return [jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+            for kk in keys]
+
+
+def _fwd_bwd(attn):
+    """jitted (q, k, v, do) -> a scalar of every gradient: no download."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(q, k, v, do):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return sum(jnp.sum(g.astype(jnp.float32)) for g in vjp(do))
+    return jax.jit(f)
+
+
+def _fwd(attn):
+    import jax
+    return jax.jit(lambda q, k, v, do: attn(q, k, v))
+
+
+def arms(causal, blocks=None):
+    from paddle1_tpu.nn.functional.attention import attention_ref
+    from paddle1_tpu.ops.pallas import flash_attention as fa
+    out = {
+        "dense": lambda q, k, v: attention_ref(q, k, v, is_causal=causal),
+        "kernel": lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, blocks=blocks),
+    }
+    if causal:
+        out["chunked4"] = functools.partial(chunked_causal, chunks=4)
+        out["chunked8"] = functools.partial(chunked_causal, chunks=8)
+    return out
+
+
+def jax_flash(causal, block):
+    from jax.experimental.pallas.ops.tpu import flash_attention as jf
+
+    def attn(q, k, v):
+        bs = jf.BlockSizes(
+            block_q=block, block_k_major=block, block_k=block, block_b=1,
+            block_q_major_dkv=block, block_k_major_dkv=block,
+            block_k_dkv=block, block_q_dkv=block,
+            block_k_major_dq=block, block_k_dq=block, block_q_dq=block)
+        return jf.flash_attention(q, k, v, causal=causal,
+                                  sm_scale=q.shape[-1] ** -0.5,
+                                  block_sizes=bs)
+    return attn
+
+
+TRIPLES = [(256, 512, 512), (512, 512, 512), (512, 1024, 512),
+           (512, 1024, 1024), (1024, 512, 512), (1024, 1024, 512),
+           (1024, 1024, 1024), (512, 2048, 512), (512, 512, 256),
+           (256, 1024, 256), (1024, 2048, 1024), (1024, 2048, 512),
+           (512, 2048, 1024), (512, 4096, 512), (1024, 4096, 512),
+           (1024, 4096, 1024)]
+
+
+def part_blocks(rows, shape=(2, 4096, 16, 128), triples=TRIPLES,
+                jax_blocks=(512, 1024)):
+    """Each kernel alone at the Ouro shape, under each block triple."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.ops.pallas import flash_attention as fa
+    from paddle1_tpu.ops.pallas.flash_attention_bwd import \
+        flash_attention_bwd
+    q, k, v, do = _inputs(*shape, jnp.bfloat16)
+    scale = shape[-1] ** -0.5
+    out, lse = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, scale,
+                                                     True))(q, k, v)
+
+    def one(name, f, *a):
+        try:
+            ms = _ms(f, *a)
+        except Exception as e:  # noqa: broad-except — a triple Mosaic
+            # refuses is a row of the table, not the end of the sweep
+            ms = None
+            print(f"  {name}: refused: {str(e)[-300:]}", flush=True)
+        rows.append({"part": "blocks", "arm": name, "ms": ms})
+        print(f"  {name}: {ms}", flush=True)
+
+    for t in triples:
+        one(f"fwd {t}", jax.jit(lambda q, k, v, t=t: fa._flash_fwd(
+            q, k, v, scale, True, blocks=t)[0]), q, k, v)
+    for t in triples:
+        def dkv(q, k, v, out, lse, do, t=t):
+            _, dk, dv = flash_attention_bwd(q, k, v, out, lse, do, scale,
+                                            True, blocks=(None, t, None))
+            return dk, dv
+
+        def dq(q, k, v, out, lse, do, t=t):
+            return flash_attention_bwd(q, k, v, out, lse, do, scale, True,
+                                       blocks=(None, None, t))[0]
+        one(f"dkv {t}", jax.jit(dkv), q, k, v, out, lse, do)
+        one(f"dq {t}", jax.jit(dq), q, k, v, out, lse, do)
+    for name, attn in arms(True).items():
+        one(f"{name} fwd", _fwd(attn), q, k, v, do)
+        one(f"{name} fwd+bwd", _fwd_bwd(attn), q, k, v, do)
+    qh, kh, vh, doh = _inputs(*shape, jnp.bfloat16, layout="bhnd")
+    for block in jax_blocks:
+        attn = jax_flash(True, block)
+        one(f"jax{block} fwd", _fwd(attn), qh, kh, vh, doh)
+        one(f"jax{block} fwd+bwd", _fwd_bwd(attn), qh, kh, vh, doh)
+
+
+def part_lengths(rows, tokens=8192, lengths=(512, 1024, 2048, 4096, 8192),
+                 extra=((64, 512, 12, 64, False), (256, 128, 12, 64, False))):
+    """Kernel against dense by sequence length, 8192 tokens a call."""
+    import jax.numpy as jnp
+    cases = []
+    for s in lengths:
+        b = max(tokens // s, 1)
+        cases += [(b, s, 16, 128, True), (b, s, 16, 128, False),
+                  (b, s, 12, 64, True), (b, s, 12, 64, False)]
+    cases += list(extra)
+    for b, s, h, d, causal in cases:
+        q, k, v, do = _inputs(b, s, h, d, jnp.bfloat16)
+        line = {"part": "lengths", "shape": [b, s, h, d], "causal": causal}
+        for name, attn in arms(causal).items():
+            if name.startswith("chunked"):
+                continue      # lost at the Ouro shape (part blocks)
+            try:
+                line[name] = _ms(_fwd_bwd(attn), q, k, v, do)
+            except Exception as e:  # noqa: broad-except — see part_blocks
+                line[name] = None
+                print(f"  {name} refused: {str(e)[-300:]}", flush=True)
+        rows.append(line)
+        print(" ", json.dumps(line), flush=True)
 
 
 def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--part", choices=("blocks", "lengths", "all"),
+                    default="all")
+    ap.add_argument("--triples", default="",
+                    help="'512,2048,512;1024,2048,512': only these")
+    args = ap.parse_args()
+    triples = [tuple(int(x) for x in t.split(","))
+               for t in args.triples.split(";") if t] or TRIPLES
     import jax
-    import jax.numpy as jnp
-    from paddle1_tpu.core.flags import flags_guard
-    from paddle1_tpu.nn.functional.attention import \
-        scaled_dot_product_attention as sdpa
-    from paddle1_tpu.core.tensor import Tensor
-
-    heads, d = 12, 64
-    print("device:", jax.devices()[0])
-    for b, s in [(128, 128), (64, 256), (32, 512), (16, 1024),
-                 (8, 2048), (4, 4096)]:
-        rng = np.random.default_rng(0)
-        q = jnp.asarray(rng.standard_normal((b, s, heads, d)),
-                        jnp.bfloat16)
-        # grad wrt q through the public functional path
-        def make(mode):
-            def loss(q):
-                with flags_guard(flash_attention=mode,
-                                 flash_backward=mode):
-                    out = sdpa(Tensor(q), Tensor(q), Tensor(q),
-                               is_causal=False)
-                return jnp.sum(out.data.astype(jnp.float32))
-            # scalar output only: downloading dq (25 MB) to the host
-            # would swamp the op time
-            g = jax.jit(lambda q: jnp.sum(
-                jax.grad(loss)(q).astype(jnp.float32)))
-            return lambda: g(q)
-        # fwd = 2 matmuls (qk^T, av) = 4*b*h*s^2*d FLOPs; bwd ~ 2x fwd
-        fl = 4 * b * heads * s * s * d * 3
-        t_flash = _min_time(make("always"))
-        t_dense = _min_time(make("never"))
-        w = "flash" if t_flash < t_dense else "dense"
-        print(f"b={b:4d} s={s:5d}: flash {t_flash * 1e3:8.2f} ms "
-              f"({fl / t_flash / 1e12:5.1f} TF/s)  dense "
-              f"{t_dense * 1e3:8.2f} ms ({fl / t_dense / 1e12:5.1f} "
-              f"TF/s)  -> {w}")
+    dev = jax.devices()[0]
+    print("device:", dev.platform, dev.device_kind, flush=True)
+    rows = []
+    if args.part in ("blocks", "all"):
+        part_blocks(rows, triples=triples)
+    if args.part in ("lengths", "all"):
+        part_lengths(rows)
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/flash_sweep.json", "w") as f:
+        json.dump({"device": [dev.platform, dev.device_kind],
+                   "rows": rows}, f, indent=1)
 
 
 if __name__ == "__main__":

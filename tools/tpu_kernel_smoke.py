@@ -55,8 +55,7 @@ def main():
               q, k, v, mask=(pm[:, None, None, :] > 0.5))
               .astype(jnp.float32).sum())(q), 8e-2)
 
-    # flash BACKWARD kernels (this smoke passed on-chip in r5, so the
-    # core flag flash_backward now defaults to 'auto')
+    # flash BACKWARD kernels against autodiff of the dense reference
     from paddle1_tpu.ops.pallas import flash_attention as fa_mod
     from paddle1_tpu.ops.pallas.flash_attention_bwd import \
         flash_attention_bwd
@@ -68,8 +67,9 @@ def main():
                                      padding_mask=mask)
         got = flash_attention_bwd(q, k, v, out, lse, dout, scale,
                                   causal, padding_mask=mask)
-        want = fa_mod._bwd_xla(q, k, v, out, lse, dout, scale, causal,
-                               padding_mask=mask)
+        m4 = None if mask is None else mask[:, None, None, :] > 0.5
+        want = jax.vjp(lambda q, k, v: attention_ref(
+            q, k, v, mask=m4, is_causal=causal), q, k, v)[1](dout)
         return got, want
     for nm, ca, mk in (("flash_bwd", False, None),
                        ("flash_bwd_causal", True, None),
