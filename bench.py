@@ -1,29 +1,16 @@
-"""Headline benchmark: BERT-base pretraining samples/sec/chip (BASELINE.md
-config 3). Prints ONE JSON line. ``vs_baseline`` = achieved MFU / 0.40 (the
-north-star MFU target; the reference publishes no numeric baseline —
-BASELINE.md).
+"""CPU acceptance gates and soaks: fault injection, elasticity, the
+input pipeline, serving, generation, the recommender, observability and
+the cost observatory, one mode flag each (``python bench.py`` with no
+mode lists them). Each mode prints JSON result lines that
+``tools/bench_history.py`` reads. None of them is the speed benchmark:
+that is ``python3 -m benchmarks.run`` (BENCHMARK.json; PERF.md
+section 2), which the driver runs on the chip.
 
 Runs on the backend JAX gives it and records that device (platform, kind,
 count) in its JSON line. There is no probe child and no CPU fallback: on a
 non-TPU backend it runs only when started with ``JAX_PLATFORMS=cpu`` (a
 test's count run, whose metric name is suffixed ``@cpu_count_run``), and
 fails otherwise.
-
-Timing (not yet calibrated on the v5e — the benchmark PR's to calibrate
-and replace; chip_smoke.py uses plain ``block_until_ready``):
-* slope timing with a host readback barrier — wall-time a window of k
-  chained steps ending in a device->host fetch of the result, at two
-  window sizes; per-step cost = (T_hi - T_lo)/(hi - lo). Fixed costs
-  appear in both windows and cancel, and the params dependency chain
-  serializes the steps on device, so the slope can be neither inflated by
-  async dispatch nor deflated by pipelining;
-* ``mfu <= 1.0`` hard assert with a loud diagnostic dump on violation;
-* the median slope across 3 trials is reported (warmup + recompiles are
-  flushed through a readback before timing starts);
-* bf16 autocast (the intended config-3 arithmetic) with f32 masters.
-
-Other configs (BASELINE.md 1/2/4/5) run via ``--config``; the driver's
-default invocation stays config 3.
 """
 
 import argparse
@@ -119,124 +106,6 @@ def parse_result_line(line):
     if not isinstance(rec["detail"], dict):
         raise ValueError("bench detail must be an object")
     return rec
-
-
-def _assert_sane_mfu(mfu, detail, step_fn=None):
-    if mfu > 1.0:
-        if step_fn is not None:
-            # capture a device trace of one step so the violation can be
-            # root-caused offline
-            try:
-                import jax
-                import tempfile
-                trace_dir = tempfile.mkdtemp(prefix="p1t_bench_trace_")
-                with jax.profiler.trace(trace_dir):
-                    _read_back(step_fn())
-                detail = dict(detail, profiler_trace=trace_dir)
-            except Exception as e:  # the assert must still fire
-                detail = dict(detail, profiler_trace_error=str(e))
-        raise AssertionError(
-            f"IMPOSSIBLE MFU {mfu:.3f} (>100%) — timing or peak-FLOPs "
-            f"accounting is broken; diagnostics: {json.dumps(detail)}")
-
-
-def bench_bert_base(on_tpu, batch_override=None, seq_override=None,
-                    steps_override=None, steps_per_dispatch=1):
-    import jax
-    import paddle1_tpu as paddle
-    from paddle1_tpu.distributed import ParallelEngine, build_mesh
-    from paddle1_tpu.text.models import (BertForPretraining,
-                                         BertPretrainingCriterion, bert_base)
-
-    dev = jax.devices()[0]
-    # batch 128 won a sweep older than PRs 1-20, on another machine
-    # (chip_results/bert_b*.json); on the v5e: not measured
-    batch, seq = (128, 128) if on_tpu else (4, 64)
-    batch = batch if batch_override is None else batch_override
-    seq = seq if seq_override is None else seq_override
-
-    model = BertForPretraining(bert_base(
-        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
-    crit = BertPretrainingCriterion(model.bert.vocab_size)
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4,
-                                 parameters=model.parameters())
-
-    def loss_fn(m, b):
-        from paddle1_tpu.core.tensor import Tensor
-        scores, rel = m(Tensor(b["ids"]))
-        return crit(scores, rel, Tensor(b["mlm"]), Tensor(b["nsp"]))
-
-    mesh = build_mesh(dp=1, devices=[dev])
-    engine = ParallelEngine(model, opt, loss_fn, mesh=mesh,
-                            amp_dtype="bfloat16" if on_tpu else None)
-
-    rng = np.random.default_rng(0)
-    v = model.bert.vocab_size
-    b = {"ids": rng.integers(1, v, (batch, seq)).astype(np.int32),
-         "mlm": rng.integers(0, v, (batch, seq)).astype(np.int32),
-         "nsp": rng.integers(0, 2, (batch,)).astype(np.int32)}
-
-    k = max(int(steps_per_dispatch), 1)
-    if k > 1:
-        # device-resident multi-step: k optimizer steps per dispatch via
-        # ONE lax.scan executable — the per-step dispatch+readback cost
-        # this axis exists to measure away
-        step_fn = lambda: engine.step_many([b] * k)
-    else:
-        step_fn = lambda: engine.step(b)
-    _read_back(step_fn())  # warmup (compile) flushed to completion
-
-    n_steps = (20 if on_tpu else 3) if steps_override is None \
-        else steps_override
-    times, loss = _timed_steps(step_fn, n_steps)
-    dt = statistics.median(times) / k  # slope is per DISPATCH; k steps each
-
-    sps = batch / dt
-    # FLOPs: 6 * matmul-params * tokens (fwd+bwd dense) + attention
-    # score/value matmuls 12 * L * B * S^2 * hidden. Embedding tables that
-    # are only gathered (position/token-type) are excluded; the word
-    # embedding stays (it is the tied MLM decoder matmul).
-    n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
-    cfg = model.bert
-    lookup_only = (cfg.embeddings.position_embeddings.weight.size +
-                   cfg.embeddings.token_type_embeddings.weight.size)
-    matmul_params = n_params - int(lookup_only)
-    attn_flops = 12 * cfg.num_hidden_layers * batch * seq * seq * \
-        cfg.hidden_size
-    flops_per_step = 6 * matmul_params * batch * seq + attn_flops
-    mfu = (flops_per_step / dt) / _peak_flops(dev)
-    detail = {"batch": batch, "seq_len": seq, "steps": n_steps,
-              "params": n_params, "mfu": round(mfu, 4),
-              "step_ms_median": round(dt * 1e3, 2),   # median slope, 3 trials
-              "step_ms_min": round(min(times) / k * 1e3, 2),
-              "step_ms_max": round(max(times) / k * 1e3, 2),
-              "timing": "slope+readback",
-              "amp": "bfloat16" if on_tpu else "none",
-              "peak_flops": _peak_flops(dev),
-              "device": getattr(dev, "device_kind", dev.platform),
-              # optimizer steps completed per host readback barrier: k
-              # steps per dispatch times the n_steps dispatches between
-              # the slope-timing readbacks
-              "steps_per_dispatch": k,
-              "steps_per_readback": k * n_steps,
-              "compile_cache": engine.cache_stats(),
-              "loss": float(np.ravel(np.asarray(loss))[-1])}
-    # cost-model cross-check (ISSUE 13): the engine derives its own
-    # FLOPs from XLA's cost analysis of the lowered step — same dt,
-    # same peak table, so the ratio isolates attribution quality. The
-    # hard 15% gate lives in bench --cost; here the numbers ride the
-    # detail so every headline run carries the cross-check.
-    cost = engine.step_cost(b)
-    detail["costmodel"] = {
-        "flops_per_step": cost.flops,
-        "bytes_per_step": cost.bytes_accessed,
-        "source": cost.source,
-        "mfu": round((cost.flops / dt) / _peak_flops(dev), 4),
-        "vs_analytic": (round(cost.flops / flops_per_step, 4)
-                        if flops_per_step else None)}
-    _assert_sane_mfu(mfu, detail, step_fn=step_fn)
-    _emit("bert_base_pretrain_samples_per_sec_per_chip", sps, "samples/s",
-          mfu / 0.40, detail)
 
 
 def bench_chaos_soak(on_tpu, steps_override=None):
@@ -1329,224 +1198,6 @@ def _bench_generate_spec(vocab):
             "speculation gate failed (need >= 1.8x tokens/s at >= 70% "
             "acceptance with bit-identical greedy output, one decode "
             f"compile): {json.dumps(detail)}")
-
-
-def _count_jaxpr_ops(jaxpr):
-    """Recursive jax-op census with pallas_call OPAQUE (on TPU a
-    pallas_call lowers to ONE custom call, so the jaxpr eqn count is
-    the CPU-measurable proxy for the chip executable's op count — the
-    compiled CPU HLO is useless for this, interpret mode expands the
-    kernel emulation into hundreds of host ops)."""
-    import jax
-
-    counts = {"ops": 0, "pallas_calls": 0, "transposes": 0,
-              "reduces": 0}
-
-    def walk(j):
-        for eq in j.eqns:
-            counts["ops"] += 1
-            name = eq.primitive.name
-            if name == "pallas_call":
-                counts["pallas_calls"] += 1
-                continue  # opaque: one kernel on chip
-            if name == "transpose":
-                counts["transposes"] += 1
-            if name in ("reduce_sum", "reduce_max", "reduce_min",
-                        "reduce_prod"):
-                counts["reduces"] += 1
-            for v in eq.params.values():
-                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
-                        walk(sub.jaxpr)
-                    elif isinstance(sub, jax.core.Jaxpr):
-                        walk(sub)
-
-    walk(jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr)
-    return counts
-
-
-def bench_conv_block(on_tpu, steps_override=None):
-    """``--conv-block``: ResNet basic-block micro-gate for the fused
-    batch-norm Pallas kernels (ISSUE 15) — conv/BN/relu/conv/BN+res+
-    relu/pool, the chain whose BN stat passes the kernels replace
-    (their share of a ResNet-50 step on the v5e: not measured).
-
-    Runs the block's training step under ``fused_bn=never`` (the XLA
-    composition, which is also what ``auto`` runs in training mode)
-    and ``fused_bn=always`` (the Pallas kernels — interpret-mode
-    emulation off-TPU, so its CPU step time measures the EMULATOR, not
-    the kernel). Gates:
-
-    - numeric parity: k training steps land on the same params (1e-4
-      across the compounded Momentum run; 1e-6-grade per step) and the
-      same running stats;
-    - ``always`` selected kernels (pallas_calls > 0), ``never`` none;
-    - layout stability: the compiled forward keeps the SAME transpose
-      count as the XLA path (<= the stem/head boundary pair + residual);
-    - ``fused_bn=auto`` resolves to the composition in training mode.
-
-    The two step times are recorded, not gated: on the v5e ResNet-50's
-    step is shorter with the composition (PERF.md, PR 26).
-    ``vs_baseline`` is 1.0 iff every gate holds; the metric is the
-    default path's steps/s."""
-    import jax
-    import jax.numpy as jnp
-    import paddle1_tpu as paddle
-    import paddle1_tpu.nn.functional as F
-    from bench_utils import best_of
-    from paddle1_tpu.core import flags as core_flags
-    from paddle1_tpu.core.tensor import Tensor
-    from paddle1_tpu.distributed import ParallelEngine, build_mesh
-    from paddle1_tpu.nn.functional.norm import fused_bn_active
-
-    steps = steps_override or 8
-    c = 64
-    rng = np.random.default_rng(0)
-    batches = [
-        {"x": rng.standard_normal((8, c, 16, 16)).astype(np.float32),
-         "y": rng.standard_normal((8, 4)).astype(np.float32)}
-        for _ in range(4)]
-
-    class BasicBlock(paddle.nn.Layer):
-        """conv -> BN -> relu -> conv -> fused BN+residual+relu ->
-        pool -> head (the fused functional drives the residual-add
-        variant, the reference fused_bn_add_activation_op shape)."""
-
-        def __init__(self):
-            super().__init__()
-            self.conv1 = paddle.nn.Conv2D(c, c, 3, padding=1,
-                                          bias_attr=False)
-            self.bn1 = paddle.nn.BatchNorm2D(c)
-            self.conv2 = paddle.nn.Conv2D(c, c, 3, padding=1,
-                                          bias_attr=False)
-            self.bn2 = paddle.nn.BatchNorm2D(c)
-            self.pool = paddle.nn.MaxPool2D(2, 2)
-            self.head = paddle.nn.Linear(c, 4)
-
-        def forward(self, x):
-            h = F.relu(self.bn1(self.conv1(x)))
-            h = F.fused_batch_norm_act(
-                self.conv2(h), self.bn2._mean, self.bn2._variance,
-                self.bn2.weight, self.bn2.bias,
-                training=self.bn2.training, act="relu", residual=x)
-            h = self.pool(h)
-            return self.head(h.mean(axis=[2, 3]))
-
-    def build(fused):
-        paddle.seed(0)
-        np.random.seed(0)
-        model = BasicBlock()
-        opt = paddle.optimizer.Momentum(learning_rate=0.02,
-                                        parameters=model.parameters())
-        loss_fn = lambda m, b: \
-            ((m(Tensor(b["x"])) - Tensor(b["y"])) ** 2).mean()
-        mesh = build_mesh(dp=1, devices=jax.devices()[:1])
-        return model, ParallelEngine(model, opt, loss_fn, mesh=mesh)
-
-    def fwd_hlo_counts(model, flag_ctx):
-        """Compiled-HLO transpose census of the block FORWARD (the
-        layout-stability probe, via the shared bench_utils helper)."""
-        import warnings
-
-        from bench_utils import compiled_hlo_layout_census
-        from paddle1_tpu.autograd import engine as ae
-
-        def fwd(xa):
-            with ae.no_grad():
-                return model(Tensor(xa)).data
-        with flag_ctx, warnings.catch_warnings():
-            # train-mode probe outside the engine's stat collector:
-            # the traced-stats warn-and-skip is expected here
-            warnings.simplefilter("ignore")
-            return compiled_hlo_layout_census(
-                fwd, jnp.asarray(batches[0]["x"]))
-
-    results = {}
-    for fused in ("never", "always"):
-        guard = core_flags.flags_guard(conv_nhwc="always",
-                                       fused_bn=fused,
-                                       fused_bn_bwd=fused)
-        with guard:
-            model, engine = build(fused)
-            for b in batches[:2]:   # compile + settle
-                float(engine.step(b))
-            # deterministic parity run
-            for i in range(steps):
-                float(engine.step(batches[i % len(batches)]))
-            engine.sync_model()
-            params = {k: np.asarray(v.data)
-                      for k, v in model.state_dict().items()}
-            jaxpr = jax.make_jaxpr(engine._step_fn)(
-                engine.params, engine.opt_state,
-                engine.shard_batch(batches[0]), jax.random.key(0),
-                jnp.asarray(0.0, jnp.float32))
-            ops = _count_jaxpr_ops(jaxpr)
-
-            def timed():
-                for i in range(steps):
-                    float(engine.step(batches[i % len(batches)]))
-            (bo,) = best_of(3, timed)
-        hlo = fwd_hlo_counts(
-            model, core_flags.flags_guard(conv_nhwc="always",
-                                          fused_bn=fused))
-        results[fused] = {"params": params, "ops": ops, "hlo": hlo,
-                          "step_s": bo.best_s / steps}
-
-    # the shipped default: auto, which in training mode is the XLA
-    # composition on every shape and backend (PERF.md, PR 26)
-    with core_flags.flags_guard(fused_bn="auto"):
-        auto_backend_kernel = fused_bn_active((32768, 128), np.float32,
-                                              training=True)
-        auto_is_fused = fused_bn_active((8 * 16 * 16, c), np.float32,
-                                        training=True)
-    assert not auto_backend_kernel and not auto_is_fused, \
-        "auto resolved to a training kernel"
-
-    never, fused = results["never"], results["always"]
-    # 1e-4: the kernel's sum/sqsum stats round differently from
-    # jnp.var at every step and Momentum compounds the difference
-    # over the k-step run (single-step parity is 1e-6-grade in
-    # tests/test_fused_bn.py)
-    parity = float(max(
-        np.abs(never["params"][k] - fused["params"][k]).max()
-        for k in never["params"]))
-    parity_ok = parity <= 1e-4
-    ops_ok = (fused["ops"]["pallas_calls"] >= 3        # 2 fwd + >=1 bwd
-              and never["ops"]["pallas_calls"] == 0)
-    layout_ok = (fused["hlo"]["transposes"]
-                 <= never["hlo"]["transposes"] <= 4)
-    # recorded, not gated: on the v5e the composition gives the shorter
-    # ResNet-50 step (PERF.md, PR 26)
-    time_ok = fused["step_s"] <= never["step_s"]
-    default_steps_per_s = 1.0 / never["step_s"]     # auto = never here
-
-    ok = parity_ok and ops_ok and layout_ok
-    detail = {
-        "steps": steps,
-        "parity_max_err": float(parity),
-        "xla_step_s": round(never["step_s"], 5),
-        "fused_step_s": round(fused["step_s"], 5),
-        "fused_is_emulated": not on_tpu,
-        "xla_step_ops": never["ops"]["ops"],
-        "fused_step_ops": fused["ops"]["ops"],
-        "fused_pallas_calls": fused["ops"]["pallas_calls"],
-        "xla_step_reduces": never["ops"]["reduces"],
-        "fused_step_reduces": fused["ops"]["reduces"],
-        "fwd_transposes_xla": never["hlo"]["transposes"],
-        "fwd_transposes_fused": fused["hlo"]["transposes"],
-        "fwd_copies_xla": never["hlo"]["copies"],
-        "auto_selects_kernel": bool(auto_is_fused),
-        "auto_backend_kernel": bool(auto_backend_kernel),
-        "gates": {"parity": bool(parity_ok), "ops": bool(ops_ok),
-                  "layout": bool(layout_ok), "time": bool(time_ok)},
-    }
-    _emit("conv_block_steps_per_s", default_steps_per_s, "steps/s",
-          1.0 if ok else 0.0, detail)
-    if not ok:
-        raise AssertionError(
-            "conv-block gate failed (need param parity 1e-4, kernels "
-            "selected under always alone and a layout-stable forward): "
-            f"{json.dumps(detail)}")
 
 
 def bench_obs(on_tpu, steps_override=None):
@@ -3200,20 +2851,12 @@ def bench_recommender_chaos(on_tpu, steps_override=None):
 def main():
     import os
     ap = argparse.ArgumentParser()
-    ap.add_argument("--config", default="bert_base")
     def _pos(v):
         v = int(v)
         if v <= 0:
             raise argparse.ArgumentTypeError("must be > 0")
         return v
-    ap.add_argument("--batch", type=_pos, default=None,
-                    help="override the config's batch (MFU sweeps)")
-    ap.add_argument("--seq", type=_pos, default=None)
     ap.add_argument("--steps", type=_pos, default=None)
-    ap.add_argument("--steps-per-dispatch", type=_pos, default=1,
-                    help="fuse k train steps into one executable "
-                         "(engine.step_many) — measures the multi-step "
-                         "amortization of dispatch + readback")
     ap.add_argument("--elastic", action="store_true",
                     help="supervised kill/restart soak: SIGKILL the "
                          "worker mid-run via worker_kill chaos, let the "
@@ -3325,14 +2968,6 @@ def main():
                          "holding the final K step records, and the "
                          "whole observatory costs < 5% enabled / "
                          "structurally zero disabled")
-    ap.add_argument("--conv-block", dest="conv_block",
-                    action="store_true",
-                    help="ResNet basic-block micro-gate for the fused "
-                         "batch-norm Pallas kernels: training-step "
-                         "parity fused vs fused_bn=never, fewer jax "
-                         "ops with kernels selected, transpose-free "
-                         "conv/BN/act/pool interior; vs_baseline is "
-                         "1.0 iff every gate holds")
     ap.add_argument("--chaos", action="store_true",
                     help="fault-injection soak: run the ResilientTrainer "
                          "through a poisoned batch, a failed checkpoint "
@@ -3347,12 +2982,15 @@ def main():
                          "match a clean run that pre-excludes exactly "
                          "the quarantined indices, to 1e-6")
     args = ap.parse_args()
+    if not any(v is True for v in vars(args).values()):
+        ap.error("give one mode flag (the speed benchmark is "
+                 "`python3 -m benchmarks.run`)")
 
     import jax
     if os.environ.get("JAX_PLATFORMS") == "cpu":
-        # a test asked for a CPU count run; the collective bench and the
-        # recommender need a multi-device mesh to smoke their paths
-        if args.config == "allreduce_busbw" or args.recommender:
+        # a test asked for a CPU count run; the recommender needs a
+        # multi-device mesh to smoke its path
+        if args.recommender:
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "") +
                 " --xla_force_host_platform_device_count=8")
@@ -3388,20 +3026,10 @@ def main():
         bench_obs(on_tpu, steps_override=args.steps)
     elif args.cost:
         bench_cost(on_tpu, steps_override=args.steps)
-    elif args.conv_block:
-        bench_conv_block(on_tpu, steps_override=args.steps)
     elif args.chaos:
         bench_chaos_soak(on_tpu, steps_override=args.steps)
     elif args.loader_chaos:
         bench_loader_chaos(on_tpu, steps_override=args.steps)
-    elif args.config == "bert_base":
-        bench_bert_base(on_tpu, batch_override=args.batch,
-                        seq_override=args.seq,
-                        steps_override=args.steps,
-                        steps_per_dispatch=args.steps_per_dispatch)
-    else:
-        from benches import run_config  # configs 1/2/4/5
-        run_config(args.config, on_tpu, batch=args.batch)
 
 
 if __name__ == "__main__":

@@ -85,10 +85,8 @@ def best_of(n: int, *fns: Callable[[], Any]) -> List[BestOf]:
 def compiled_hlo_layout_census(fn, *args) -> dict:
     """jit-compile ``fn(*args)`` and count layout ops in the OPTIMIZED
     HLO — the channels-last region's CPU-measurable layout-stability
-    probe (transposes/copies that survived XLA's cancellation). One
-    definition shared by ``bench.py --conv-block`` and the
-    ``TestConvBlockLayoutStability`` regression so the two censuses
-    cannot drift."""
+    probe (transposes/copies that survived XLA's cancellation), read by
+    the ``TestConvBlockLayoutStability`` regression."""
     import re
 
     import jax

@@ -6,11 +6,11 @@
 
 One chip: a full-width BERT-base trainer (12 layers, hidden 768, 12 heads,
 FFN 3072, vocab 30522; random weights from a seed) takes 5 ``step`` calls
-and one ``step_many`` of 2 through ``ParallelEngine``, exactly as
-``bench.py`` builds it, and each main-path Pallas kernel runs once against
-its XLA reference at a real width. Every check that fails raises: no phase
-may fail and the script still exit 0, and no kernel gives way to its
-reference. One process, no child that needs the chip.
+and one ``step_many`` of 2 through ``ParallelEngine``, and each main-path
+Pallas kernel runs once against its XLA reference at a real width. Every
+check that fails raises: no phase may fail and the script still exit 0,
+and no kernel gives way to its reference. One process, no child that
+needs the chip.
 
 The last line of standard output is the contract's JSON object. Every
 time printed is a smoke reading on the host's clock, not a metric.
@@ -63,9 +63,8 @@ def tpu_devices(need):
 
 def build_trainer(devices, degrees, megatron=False, zero_stage=0,
                   amp_dtype="bfloat16"):
-    """BERT-base + AdamW + ParallelEngine over ``devices``, as
-    bench.py's ``bench_bert_base`` builds it; the same seed gives the
-    same weights and batch to every call."""
+    """BERT-base + AdamW + ParallelEngine over ``devices``; the same
+    seed gives the same weights and batch to every call."""
     import paddle1_tpu as paddle
     from paddle1_tpu.core.tensor import Tensor
     from paddle1_tpu.distributed import ParallelEngine, build_mesh

@@ -350,37 +350,21 @@ def _define_builtin_flags() -> None:
                 "Pallas fused LayerNorm: auto (TPU only), always, never.",
                 validator=lambda v: v in ("auto", "always", "never"))
     define_flag("fused_bn", "auto",
-                "Pallas batch-norm kernels (stats + normalize + "
-                "activation + residual-add in one call, the reference "
-                "fused_bn_activation_op/fused_bn_add_activation_op "
-                "role): auto = the kernels on a TPU where the "
-                "statistics are GIVEN (eval mode, SyncBatchNorm's "
-                "local halves; on the v5e: not measured) and the XLA "
-                "composition in training mode on every shape; always "
-                "= the kernels in training mode too (interpret-mode "
-                "on CPU, for tests and the ablation); never = the XLA "
-                "compositions. Measured on the v5e (PERF.md, PR 26): "
-                "ResNet-50's training step is shorter with the "
-                "composition, which XLA fuses into the neighbouring "
-                "convolutions, than with the training kernels, whose "
-                "custom calls it cannot fuse across and has to copy "
-                "activations for. The kernels require a channels-last "
+                "Pallas batch-norm kernels for GIVEN statistics "
+                "(normalize + activation + residual-add in one call, "
+                "one-pass dx/dgamma/dbeta backward, local moments; the "
+                "reference fused_bn_activation_op/"
+                "fused_bn_add_activation_op role): eval mode and "
+                "SyncBatchNorm's local halves. auto = the kernels on a "
+                "TPU (on the v5e: not measured), always = the kernels "
+                "on any backend (interpret-mode on CPU, for tests), "
+                "never = the XLA compositions. Training-mode batch "
+                "norm has no kernel and does not read this flag: it is "
+                "one XLA composition, which the compiler fuses into "
+                "the neighbouring convolutions (measured on the v5e: "
+                "PERF.md, PR 26). The kernels require a channels-last "
                 "layout (NHWC data_format or the conv_nhwc region) "
                 "and affine weight+bias.",
-                validator=lambda v: v in ("auto", "always", "never"))
-    define_flag("fused_bn_bwd", "auto",
-                "Pallas batch-norm BACKWARD kernels (one-pass "
-                "dx/dgamma/dbeta): auto (TPU only), always (interpret "
-                "on CPU), never (XLA composition backward behind a "
-                "kernel forward: the forward-only ablation arm). Only "
-                "consulted when the forward ran a kernel.",
-                validator=lambda v: v in ("auto", "always", "never"))
-    define_flag("fused_adam", "never",
-                "Pallas fused Adam/AdamW update: auto (TPU only), "
-                "always, never. Default never since an ablation older "
-                "than PRs 1-20, on another machine, in which XLA's "
-                "plain update chain beat the kernel on BERT-base; on "
-                "the v5e: not measured.",
                 validator=lambda v: v in ("auto", "always", "never"))
     define_flag("fused_softmax", "auto",
                 "Pallas fused softmax: auto (TPU only), always, never.",

@@ -316,8 +316,8 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
             # GSPMD propagates the slot sharding backward THROUGH the
             # reduction onto the batch-sharded activation grad — a
             # batch-dim→hidden-dim transition it can only satisfy by
-            # "involuntary full rematerialization" (replicate-then-slice;
-            # the MULTICHIP_r03 warnings). Reference intent:
+            # "involuntary full rematerialization" (replicate-then-slice,
+            # which the compiler warns of). Reference intent:
             # sharding_optimizer.py:146 "reduce rather than allreduce".
             grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
         if clip_global_norm is not None:
@@ -487,8 +487,8 @@ class ParallelEngine:
         # the slot shardings ('sharding' on a hidden dim) through the
         # param-grad einsums onto batch-sharded activation grads — a
         # batch-dim→hidden-dim transition it can only satisfy by
-        # "involuntary full rematerialization" (the MULTICHIP_r03
-        # warnings: replicate-then-repartition of every activation grad).
+        # "involuntary full rematerialization" (the compiler's warning:
+        # replicate-then-repartition of every activation grad).
         # Pinned to the param spec, grads materialize via a plain
         # reduction over the batch axes and the slot-sharded update
         # consumes a local slice; XLA's allreduce+slice→reduce-scatter

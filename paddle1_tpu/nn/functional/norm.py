@@ -122,19 +122,15 @@ def warn_traced_stats_skipped(buffer, what: str) -> None:
         "(warned once per buffer).")
 
 
-def fused_bn_active(shape, dtype, training: bool = False) -> bool:
-    """Resolve the ``fused_bn`` flag against a channels-LAST input.
-    ``always`` / ``never`` are absolute. ``auto`` takes the Pallas
-    kernels only where they are given statistics (eval mode,
-    SyncBatchNorm's local halves) and on a TPU backend (flag_active);
-    in training mode ``auto`` is the XLA composition on every shape:
-    on the v5e ResNet-50's step is shorter with it on each of its 53
-    norms (PERF.md, PR 26), because the compiler fuses it into the
-    convolutions on either side and a custom call is a wall."""
-    from ...core.flags import flag, flag_active
+def fused_bn_active(shape, dtype) -> bool:
+    """Resolve the ``fused_bn`` flag against a channels-LAST input whose
+    statistics are GIVEN (eval mode, SyncBatchNorm's local halves):
+    ``always`` / ``never`` are absolute, ``auto`` takes the Pallas
+    kernels on a TPU backend (flag_active). Training-mode batch norm
+    never asks: it is the XLA composition on every shape (PERF.md,
+    PR 26)."""
+    from ...core.flags import flag_active
     from ...ops.pallas import fused_bn as pbn
-    if training and flag("fused_bn") != "always":
-        return False
     return flag_active("fused_bn") and pbn.supported(shape, dtype)
 
 
@@ -188,9 +184,8 @@ def _bn_train_forward(x, gamma, beta, residual, eps, act, ch_axis):
     constant and the variance clamped at 0; the normalise + affine
     (+ residual) + activation chain in float32, rounded once to ``x``'s
     dtype (the compiler fuses it into the convolution that consumes
-    ``y``). The same mathematics as ``ops/pallas/fused_bn.py``'s
-    training kernel, without the custom call's wall. Wider ``x`` takes a
-    second, centred pass for the variance. -> (y, mean, var, rstd)."""
+    ``y``). Wider ``x`` takes a second, centred pass for the variance.
+    -> (y, mean, var, rstd)."""
     axes, inv, bshape = _bn_reduction(x, ch_axis)
     ft = jnp.promote_types(x.dtype, jnp.float32)
     xf = x.astype(ft)
@@ -292,9 +287,9 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
     # NCHW 4-D batch norm participates in the channels-last region
     # (_layout.py): computing with the channel axis last makes the
     # boundary transposes sit directly against the neighboring convs'
-    # and pools', where XLA cancels them — and is what makes the input
-    # eligible for the Pallas kernels (ops/pallas/fused_bn.py), which
-    # are NHWC-native.
+    # and pools', where XLA cancels them — and is what makes an
+    # eval-mode input eligible for the Pallas kernels
+    # (ops/pallas/fused_bn.py), which are NHWC-native.
     from ._layout import channels_last_region
     from ...ops.pallas import fused_bn as pbn
     nhwc_internal, to_internal, from_internal = channels_last_region(
@@ -315,10 +310,6 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
         res = rest[-1] if has_res else None
         return wb, res
 
-    def fused_ok(xi, training=False):
-        return (has_wb and eff_last
-                and fused_bn_active(xi.shape, xi.dtype, training))
-
     res_args = (_t(residual),) if has_res else ()
     wb_args = (_t(weight), _t(bias)) if has_wb else ()
 
@@ -328,7 +319,8 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
             wb, res = split_rest(rest)
             if res is not None:
                 res = to_internal(res)
-            if fused_ok(x):
+            if (has_wb and eff_last
+                    and fused_bn_active(x.shape, x.dtype)):
                 c = x.shape[-1]
                 y2 = pbn.fused_bn_norm(
                     x.reshape(-1, c), m, v, wb[0], wb[1], epsilon,
@@ -351,12 +343,6 @@ def _batch_norm_impl(x, running_mean, running_var, weight, bias,
         wb, res = split_rest(rest)
         if res is not None:
             res = to_internal(res)
-        if fused_ok(x, training=True):
-            c = x.shape[-1]
-            y2, mean, var = pbn.fused_bn_train(
-                x.reshape(-1, c), wb[0], wb[1], epsilon, act=act,
-                residual=None if res is None else res.reshape(-1, c))
-            return from_internal(y2.reshape(x.shape)), mean, var
         y, mean, var = _bn_train(
             x, wb[0] if wb else None, wb[1] if wb else None, res,
             float(epsilon), act, ch_axis)
@@ -379,7 +365,7 @@ def batch_norm(x, running_mean, running_var, weight=None, bias=None,
     pass, one rounding) that the compiler fuses into the neighbouring
     convolutions; the Pallas kernels (ops/pallas/fused_bn.py) take a
     channels-last affine BN in eval mode under ``fused_bn=auto`` on a
-    TPU, and in training mode only under ``fused_bn=always``."""
+    TPU."""
     return _batch_norm_impl(x, running_mean, running_var, weight, bias,
                             training, momentum, epsilon, data_format,
                             use_global_stats, "identity", None,
@@ -394,10 +380,11 @@ def fused_batch_norm_act(x, running_mean, running_var, weight, bias,
     the reference's fused_bn_activation_op (act only) and
     fused_bn_add_activation_op (act + residual). The whole chain is
     one XLA composition in float32, rounded once (training mode:
-    ``_bn_train_forward``), or a single Pallas kernel where ``fused_bn``
-    resolves to it (see ``batch_norm``), with identical semantics
-    (including the running-stat update and the ``collect_stat_updates``
-    functionalization under a compiled trainer step)."""
+    ``_bn_train_forward``), or in eval mode a single Pallas kernel where
+    ``fused_bn`` resolves to it (see ``batch_norm``), with identical
+    semantics (including the running-stat update and the
+    ``collect_stat_updates`` functionalization under a compiled trainer
+    step)."""
     from ...ops.pallas.fused_bn import ACTS
     if act not in ACTS:
         raise InvalidArgumentError(

@@ -1,8 +1,8 @@
 """Pallas TPU kernels (the analog of the reference's hand-fused CUDA kernels
 in /root/reference/paddle/fluid/operators/fused/): flash attention, fused
-layer_norm, fused softmax, fused adam, fused batch norm
-(stats+normalize+activation+residual forward and one-pass dx/dgamma/dbeta
-backward), ring attention.
+layer_norm, fused softmax, paged attention, fused batch norm for given
+statistics (normalize+activation+residual forward, one-pass
+dx/dgamma/dbeta backward, local moments).
 
 Each kernel module exposes ``supported(...)`` gates so callers fall back to
 plain XLA compositions on CPU/interpret mode or unaligned shapes.

@@ -1,45 +1,35 @@
-"""Fused batch-norm Pallas kernels (forward family).
+"""Fused batch-norm Pallas kernels for GIVEN statistics.
 
 TPU-native analog of the reference's fused BN CUDA ops
 (/root/reference/paddle/fluid/operators/fused/fused_bn_activation_op.cu
-and fused_bn_add_activation_op.cu): ONE kernel owns the whole
-stats + normalize + activation (+ residual-add) chain.
+and fused_bn_add_activation_op.cu) where the per-channel statistics
+arrive from outside the kernel: eval mode, and ``SyncBatchNorm``, whose
+statistics cross replicas between its two local halves.
 
-What the v5e said of the TRAINING kernels (PERF.md, PR 26;
-ResNet-50, batch 128, bf16): the step is shorter without them. A
-``tpu_custom_call`` is a wall to XLA: it cannot hang the statistics on
-the convolution that produces x nor the normalize + ReLU on the one
-that consumes y, and it copies convolution outputs into the row-major
-layout the call demands; the plain composition
-(``nn/functional/norm.py::_bn_train_forward``) has neither cost, so
-``fused_bn=auto`` takes it in training mode and ``fused_bn_train``
-runs under ``fused_bn=always`` only (tests, the ablation). The
-given-stats kernels (``fused_bn_norm``, ``local_moments``) are still
-what ``auto`` picks on a TPU; on the v5e they are not measured.
+- ``fused_bn_norm``: normalize + affine (+ residual) + activation in
+  one kernel, forward, and one kernel for dx / dgamma / dbeta
+  (+ dresidual) backward: the activation mask and the two per-channel
+  reductions (sum dy, sum dy * xhat) never leave it. dx is row-local
+  given the statistics, so the backward is a single phase that
+  accumulates dgamma / dbeta in VMEM while it streams.
+- ``local_moments``: per-channel (sum, sum of squares) in one f32 pass,
+  the local half of ``SyncBatchNorm``'s statistics.
 
-The training kernel is a two-pass-in-one-call design: a sequential
-(2, row_blocks) grid whose first phase accumulates per-channel
-sum / sum-of-squares into the f32 stat outputs resident in VMEM and
-whose second phase finalizes mean/var once and streams the normalized,
-affine-transformed, optionally residual-added and activated output.
-No stat intermediate ever round-trips HBM, and the output (and
-residual) windows ride a ``p * i`` index map so they stay parked on
-block 0 through the stats phase — the data moves x twice, y and the
-residual once.
+``fused_bn=auto`` takes these on a TPU; on the v5e they are not
+measured. Training-mode batch norm has no kernel: it is the XLA
+composition ``nn/functional/norm.py::_bn_train_forward``, which the
+compiler fuses into the convolutions on either side of it, where a
+``tpu_custom_call`` is a wall (PERF.md, PR 26, has the arms measured
+on the v5e).
 
-bf16-safe exact-count discipline (the one ``SyncBatchNorm`` documents):
-every reduction accumulates in f32 regardless of the compute dtype, and
-the element count enters once as an exact host-side constant — a bf16
-count is inexact past 256 and E[x^2]-mean^2 cancels catastrophically,
-so the variance is clamped at 0 the same way ``sync_batch_norm_op``
-does.
+bf16 discipline (the one ``SyncBatchNorm`` documents): every reduction
+accumulates in f32 regardless of the compute dtype, and outputs are
+cast at the edge.
 
 Inputs are channels-last ``[rows, C]`` (NHWC flattened), so under
 ``conv_nhwc=auto`` the conv/BN/act/pool residual block stays
-layout-stable end to end. Backward lives in ``fused_bn_bwd.py``
-(Pallas one-pass dx/dgamma/dbeta behind ``fused_bn_bwd``, with the XLA
-composition as the reference/ablation path). Interpret mode runs the
-same kernels on CPU for tests.
+layout-stable end to end. Interpret mode runs the same kernels on CPU
+for tests.
 """
 
 from __future__ import annotations
@@ -53,8 +43,7 @@ from jax.experimental import pallas as pl
 from . import _common
 from ._common import block_rows as _block_rows
 
-__all__ = ["supported", "fused_bn_train", "fused_bn_norm",
-           "local_moments", "ACTS"]
+__all__ = ["supported", "fused_bn_norm", "local_moments", "ACTS"]
 
 ACTS = ("identity", "relu")
 
@@ -93,168 +82,8 @@ def _act_fwd(y, act: str):
 
 
 # ---------------------------------------------------------------------------
-# Training kernel: stats + normalize + act (+ residual) in one call
-# ---------------------------------------------------------------------------
-
-
-def _bn_train_kernel(*refs, eps, act, inv_count, with_res):
-    if with_res:
-        x_ref, g_ref, b_ref, r_ref, y_ref, mean_ref, var_ref = refs
-    else:
-        x_ref, g_ref, b_ref, y_ref, mean_ref, var_ref = refs
-        r_ref = None
-    p = pl.program_id(0)
-    i = pl.program_id(1)
-    x = x_ref[:].astype(jnp.float32)                      # [BR, C]
-
-    @pl.when(p == 0)
-    def _accumulate():
-        s = jnp.sum(x, axis=0, keepdims=True)
-        ss = jnp.sum(x * x, axis=0, keepdims=True)
-
-        @pl.when(i == 0)
-        def _():
-            mean_ref[:] = s
-            var_ref[:] = ss
-
-        @pl.when(i != 0)
-        def _():
-            mean_ref[:] = mean_ref[:] + s
-            var_ref[:] = var_ref[:] + ss
-
-    @pl.when(p == 1)
-    def _normalize():
-        @pl.when(i == 0)
-        def _finalize():
-            m = mean_ref[:] * inv_count
-            var_ref[:] = jnp.maximum(var_ref[:] * inv_count - m * m, 0.0)
-            mean_ref[:] = m
-
-        y = (x - mean_ref[:]) * jax.lax.rsqrt(var_ref[:] + eps)
-        y = y * g_ref[:].astype(jnp.float32) + b_ref[:].astype(jnp.float32)
-        if r_ref is not None:
-            y = y + r_ref[:].astype(jnp.float32)
-        y_ref[:] = _act_fwd(y, act).astype(y_ref.dtype)
-
-
-def _train_fwd(x2, g, b, res, eps, act):
-    rows, c = x2.shape
-    br = _block_rows(rows, c)
-    kernel = functools.partial(
-        _bn_train_kernel, eps=eps, act=act, inv_count=1.0 / rows,
-        with_res=res is not None)
-    in_specs = [
-        pl.BlockSpec((br, c), lambda p, i: (i, 0)),
-        pl.BlockSpec((1, c), lambda p, i: (0, 0)),
-        pl.BlockSpec((1, c), lambda p, i: (0, 0)),
-    ]
-    args = [x2, g.reshape(1, c), b.reshape(1, c)]
-    if res is not None:
-        # parked on block 0 through the stats phase (fetched once),
-        # streamed in lockstep with x through the normalize phase
-        in_specs.append(pl.BlockSpec((br, c), lambda p, i: (p * i, 0)))
-        args.append(res)
-    y, mean, var = pl.pallas_call(
-        kernel,
-        grid=(2, rows // br),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((br, c), lambda p, i: (p * i, 0)),
-            pl.BlockSpec((1, c), lambda p, i: (0, 0)),
-            pl.BlockSpec((1, c), lambda p, i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, c), x2.dtype),
-            jax.ShapeDtypeStruct((1, c), jnp.float32),
-            jax.ShapeDtypeStruct((1, c), jnp.float32),
-        ],
-        name="p1t_fused_bn_fwd_stats",
-        interpret=_common.interpret(),
-    )(*args)
-    return y, mean.reshape(c), var.reshape(c)
-
-
-def _stat_cotangent_terms(x2, mean, dmean, dvar, inv_count):
-    """Fold cotangents that flow INTO the batch-stat outputs back into
-    dx: mean = sum(x)/n, var = sum(x^2)/n - mean^2. Running-stat
-    consumers detach the stats, so on the training path these are
-    zeros, but a ``custom_vjp`` rule receives them as zero ARRAYS and
-    XLA may not fold ``0 * (x - mean)`` in floating point: the compiled
-    step keeps a full-size ``multiply`` by a zero constant, a read of
-    x and a read and a write of dx for every norm (seen in the v5e's
-    compiled text, PR 26). The composition that ``auto`` runs gives
-    the statistics no gradient and has no such pass."""
-    xf = x2.astype(jnp.float32)
-    extra = (dmean[None, :]
-             + 2.0 * dvar[None, :] * (xf - mean[None, :])) * inv_count
-    return extra
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _bn_train(x2, g, b, eps, act):
-    return _train_fwd(x2, g, b, None, eps, act)
-
-
-def _bn_train_fwd_rule(x2, g, b, eps, act):
-    y, mean, var = _train_fwd(x2, g, b, None, eps, act)
-    return (y, mean, var), (x2, g, mean, var, y)
-
-
-def _bn_train_bwd_rule(eps, act, resids, cts):
-    x2, g, mean, var, y = resids
-    dy, dmean, dvar = cts
-    from .fused_bn_bwd import train_bwd
-    dx, dg, db = train_bwd(x2, g, mean, var, y, dy, eps, act)
-    extra = _stat_cotangent_terms(x2, mean, dmean, dvar, 1.0 / x2.shape[0])
-    dx = (dx.astype(jnp.float32) + extra).astype(x2.dtype)
-    return dx, dg.astype(g.dtype), db.astype(g.dtype)
-
-
-_bn_train.defvjp(_bn_train_fwd_rule, _bn_train_bwd_rule)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _bn_train_res(x2, g, b, res, eps, act):
-    return _train_fwd(x2, g, b, res, eps, act)
-
-
-def _bn_train_res_fwd_rule(x2, g, b, res, eps, act):
-    y, mean, var = _train_fwd(x2, g, b, res, eps, act)
-    # zero-size carrier: residuals must be jax types, and bwd only
-    # needs the residual's dtype
-    return (y, mean, var), (x2, g, mean, var, y,
-                            jnp.zeros((0,), res.dtype))
-
-
-def _bn_train_res_bwd_rule(eps, act, resids, cts):
-    x2, g, mean, var, y, res_proto = resids
-    dy, dmean, dvar = cts
-    from .fused_bn_bwd import train_bwd
-    dx, dg, db, dres = train_bwd(x2, g, mean, var, y, dy, eps, act,
-                                 with_res=True)
-    extra = _stat_cotangent_terms(x2, mean, dmean, dvar, 1.0 / x2.shape[0])
-    dx = (dx.astype(jnp.float32) + extra).astype(x2.dtype)
-    return (dx, dg.astype(g.dtype), db.astype(g.dtype),
-            dres.astype(res_proto.dtype))
-
-
-_bn_train_res.defvjp(_bn_train_res_fwd_rule, _bn_train_res_bwd_rule)
-
-
-def fused_bn_train(x2, gamma, beta, epsilon, act="identity", residual=None):
-    """Training-mode fused BN over channels-last ``x2: [rows, C]``.
-
-    Returns ``(y, batch_mean, batch_var)`` with the stats in f32 —
-    ``y = act((x - mean) * rsqrt(var + eps) * gamma + beta [+ residual])``.
-    """
-    _check_act(act)
-    if residual is None:
-        return _bn_train(x2, gamma, beta, float(epsilon), act)
-    return _bn_train_res(x2, gamma, beta, residual, float(epsilon), act)
-
-
-# ---------------------------------------------------------------------------
-# Normalize kernel: given stats (eval mode / SyncBatchNorm post-psum)
+# Normalize kernels, forward and backward: given stats (eval mode /
+# SyncBatchNorm post-psum)
 # ---------------------------------------------------------------------------
 
 
@@ -297,6 +126,71 @@ def _norm_fwd(x2, m, v, g, b, res, eps, act):
     )(*args)
 
 
+def _norm_bwd_kernel(*refs, eps, act, with_res):
+    if with_res:
+        (x_ref, g_ref, m_ref, v_ref, y_ref, dy_ref,
+         dx_ref, dg_ref, db_ref, dr_ref) = refs
+    else:
+        (x_ref, g_ref, m_ref, v_ref, y_ref, dy_ref,
+         dx_ref, dg_ref, db_ref) = refs
+        dr_ref = None
+    i = pl.program_id(0)
+    dy = dy_ref[:].astype(jnp.float32)
+    if act == "relu":
+        # compare in f32: the v5e's vector unit has no bf16 comparison,
+        # and Mosaic refuses the kernel rather than widen it
+        dy = dy * (y_ref[:].astype(jnp.float32) > 0).astype(jnp.float32)
+    rstd = jax.lax.rsqrt(v_ref[:] + eps)
+    xhat = (x_ref[:].astype(jnp.float32) - m_ref[:]) * rstd
+    sg = jnp.sum(dy * xhat, axis=0, keepdims=True)
+    sb = jnp.sum(dy, axis=0, keepdims=True)
+
+    @pl.when(i == 0)
+    def _():
+        dg_ref[:] = sg
+        db_ref[:] = sb
+
+    @pl.when(i != 0)
+    def _():
+        dg_ref[:] = dg_ref[:] + sg
+        db_ref[:] = db_ref[:] + sb
+
+    dx_ref[:] = (dy * g_ref[:].astype(jnp.float32) * rstd).astype(
+        dx_ref.dtype)
+    if dr_ref is not None:
+        dr_ref[:] = dy.astype(dr_ref.dtype)
+
+
+def _norm_bwd(x2, g, mean, var, y2, dy2, eps, act, with_res):
+    rows, c = x2.shape
+    br = _block_rows(rows, c)
+    kernel = functools.partial(_norm_bwd_kernel, eps=eps, act=act,
+                               with_res=with_res)
+    row_spec = pl.BlockSpec((br, c), lambda i: (i, 0))
+    ch_spec = pl.BlockSpec((1, c), lambda i: (0, 0))
+    out_specs = [row_spec, ch_spec, ch_spec]
+    out_shape = [jax.ShapeDtypeStruct((rows, c), x2.dtype),
+                 jax.ShapeDtypeStruct((1, c), jnp.float32),
+                 jax.ShapeDtypeStruct((1, c), jnp.float32)]
+    if with_res:
+        out_specs.append(row_spec)
+        out_shape.append(jax.ShapeDtypeStruct((rows, c), dy2.dtype))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(rows // br,),
+        in_specs=[row_spec, ch_spec, ch_spec, ch_spec, row_spec, row_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        name="p1t_fused_bn_bwd_norm",
+        interpret=_common.interpret(),
+    )(x2, g.reshape(1, c), mean.astype(jnp.float32).reshape(1, c),
+      var.astype(jnp.float32).reshape(1, c), y2, dy2)
+    dx, dg, db = outs[0], outs[1].reshape(c), outs[2].reshape(c)
+    if with_res:
+        return dx, dg, db, outs[3]
+    return dx, dg, db
+
+
 def _norm_stat_grads(g, var, dg, db, eps):
     """Channel-sized cotangents for the given stats: y depends on mean
     only through the shift and on var only through rstd."""
@@ -319,8 +213,7 @@ def _bn_norm_fwd_rule(x2, m, v, g, b, eps, act):
 
 def _bn_norm_bwd_rule(eps, act, resids, dy):
     x2, m, v, g, y = resids
-    from .fused_bn_bwd import norm_bwd
-    dx, dg, db = norm_bwd(x2, g, m, v, y, dy, eps, act)
+    dx, dg, db = _norm_bwd(x2, g, m, v, y, dy, eps, act, False)
     dm, dv = _norm_stat_grads(g, v, dg, db, eps)
     return (dx, dm.astype(m.dtype), dv.astype(v.dtype),
             dg.astype(g.dtype), db.astype(g.dtype))
@@ -341,9 +234,7 @@ def _bn_norm_res_fwd_rule(x2, m, v, g, b, res, eps, act):
 
 def _bn_norm_res_bwd_rule(eps, act, resids, dy):
     x2, m, v, g, y, res_proto = resids
-    from .fused_bn_bwd import norm_bwd
-    dx, dg, db, dres = norm_bwd(x2, g, m, v, y, dy, eps, act,
-                                with_res=True)
+    dx, dg, db, dres = _norm_bwd(x2, g, m, v, y, dy, eps, act, True)
     dm, dv = _norm_stat_grads(g, v, dg, db, eps)
     return (dx, dm.astype(m.dtype), dv.astype(v.dtype),
             dg.astype(g.dtype), db.astype(g.dtype),

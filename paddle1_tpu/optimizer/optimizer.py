@@ -27,21 +27,6 @@ __all__ = ["Optimizer", "SGD", "Momentum", "Adagrad", "Adam", "AdamW",
            "Adamax", "AdaDelta", "RMSProp", "Lamb", "Lars"]
 
 
-def _fused_adam_path(param, g, slots, lr, step, beta1, beta2, eps, decay):
-    """Route large tensors through the Pallas fused-Adam kernel when the
-    ``fused_adam`` flag allows; returns None to fall back to plain jnp."""
-    from ..core.flags import flag_active
-    from ..ops.pallas import fused_adam as fadam
-    if not flag_active("fused_adam"):
-        return None
-    if not fadam.supported(int(np.prod(param.shape))):
-        return None
-    new_p, m1, m2 = fadam.fused_adam_update(
-        param, g, slots["moment1"], slots["moment2"], lr, step,
-        beta1, beta2, eps, decay)
-    return new_p, {"moment1": m1, "moment2": m2}
-
-
 class Optimizer:
     """Base optimizer (reference python/paddle/optimizer/optimizer.py).
 
@@ -390,11 +375,6 @@ class Adam(Optimizer):
 
     def _update(self, param, grad, slots, lr, step):
         g = self._l2(grad.astype(jnp.float32), param.astype(jnp.float32))
-        if type(self)._decoupled_decay is Adam._decoupled_decay:
-            fused = _fused_adam_path(param, g, slots, lr, step, self._beta1,
-                                     self._beta2, self._epsilon, decay=0.0)
-            if fused is not None:
-                return fused
         m1 = self._beta1 * slots["moment1"] + (1 - self._beta1) * g
         m2 = self._beta2 * slots["moment2"] + (1 - self._beta2) * g * g
         bc1 = 1 - self._beta1 ** step
@@ -449,10 +429,6 @@ class AdamW(Adam):
                 not self._apply_decay_fn(self._current_param_name):
             decay = 0.0
         g = grad.astype(jnp.float32)
-        fused = _fused_adam_path(param, g, slots, lr, step, self._beta1,
-                                 self._beta2, self._epsilon, decay=decay)
-        if fused is not None:
-            return fused
         m1 = self._beta1 * slots["moment1"] + (1 - self._beta1) * g
         m2 = self._beta2 * slots["moment2"] + (1 - self._beta2) * g * g
         bc1 = 1 - self._beta1 ** step

@@ -291,64 +291,42 @@ class TestFusedSoftmax:
                                    rtol=1e-5)
 
 
-class TestFusedAdam:
-    def test_matches_plain_adamw(self):
-        from paddle1_tpu.ops.pallas import fused_adam as fadam
-        rng = np.random.default_rng(0)
-        n = fadam._CHUNK + 123  # force padding path
-        p = jnp.asarray(rng.standard_normal((n,)).astype(np.float32))
-        g = jnp.asarray(rng.standard_normal((n,)).astype(np.float32))
-        m1 = jnp.asarray(rng.standard_normal((n,)).astype(np.float32) * 0.01)
-        m2 = jnp.abs(jnp.asarray(
-            rng.standard_normal((n,)).astype(np.float32) * 0.01))
-        beta1, beta2, eps, decay, lr = 0.9, 0.999, 1e-8, 0.01, 1e-3
-        step = jnp.asarray(3, jnp.int32)
+@pytest.mark.parametrize("name", ["Adam", "AdamW"])
+def test_adam_is_the_plain_update_chain(name, monkeypatch):
+    """The Adam rule has no kernel: on a backend that says "tpu" the
+    update traces to no ``pallas_call`` (XLA fuses the chain behind the
+    weight-gradient matmuls: PERF.md, PR 29) and is the plain AdamW
+    rule, moments and padding-sized tensors included."""
+    import paddle1_tpu as paddle
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.default_rng(0)
+    n = 16384 + 123
+    mk = lambda scale=1.0: jnp.asarray(
+        rng.standard_normal((n,)).astype(np.float32) * scale)
+    p, g, m1, m2 = mk(), mk(), mk(0.01), jnp.abs(mk(0.01))
+    beta1, beta2, eps, lr = 0.9, 0.999, 1e-8, 1e-3
+    decay = 0.01 if name == "AdamW" else 0.0
+    opt = getattr(paddle.optimizer, name)(learning_rate=lr)
+    state = ({"w": {"moment1": m1, "moment2": m2}},
+             jnp.asarray(2, jnp.int32))
 
-        np_, nm1, nm2 = fadam.fused_adam_update(
-            p, g, m1, m2, lr, step, beta1, beta2, eps, decay)
+    def update(p, g):
+        return opt.functional_update({"w": p}, {"w": g}, state,
+                                     jnp.float32(lr))
 
-        em1 = beta1 * m1 + (1 - beta1) * g
-        em2 = beta2 * m2 + (1 - beta2) * g * g
-        bc1 = 1 - beta1 ** 3
-        bc2 = 1 - beta2 ** 3
-        upd = (em1 / bc1) / (jnp.sqrt(em2 / bc2) + eps)
-        ep = p * (1 - lr * decay) - lr * upd
-        np.testing.assert_allclose(np.asarray(np_), np.asarray(ep),
+    assert "pallas_call" not in _primitives(
+        jax.make_jaxpr(update)(p, g).jaxpr)
+    new_p, (slots, step) = update(p, g)
+    em1 = beta1 * m1 + (1 - beta1) * g
+    em2 = beta2 * m2 + (1 - beta2) * g * g
+    upd = (em1 / (1 - beta1 ** 3)) / (jnp.sqrt(em2 / (1 - beta2 ** 3))
+                                      + eps)
+    assert int(step) == 3
+    for got, want in [(new_p["w"], p * (1 - lr * decay) - lr * upd),
+                      (slots["w"]["moment1"], em1),
+                      (slots["w"]["moment2"], em2)]:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6, atol=1e-7)
-        np.testing.assert_allclose(np.asarray(nm1), np.asarray(em1),
-                                   rtol=1e-6, atol=1e-7)
-        np.testing.assert_allclose(np.asarray(nm2), np.asarray(em2),
-                                   rtol=1e-6, atol=1e-7)
-
-    def test_optimizer_fused_equals_unfused(self):
-        """AdamW.functional_update with the flag on vs off is bit-close."""
-        import paddle1_tpu as paddle
-        from paddle1_tpu.ops.pallas import fused_adam as fadam
-        from paddle1_tpu.nn.layer_common import Linear
-        rng = np.random.default_rng(3)
-        lin = Linear(128, 128)  # 16k params >= _CHUNK? ensure threshold
-        n = int(np.prod(lin.weight.shape))
-        params = {k: t.data for k, t in lin.state_dict().items()}
-        grads = {k: jnp.asarray(
-            rng.standard_normal(v.shape).astype(np.float32) * 0.01)
-            for k, v in params.items()}
-
-        def run(flag_val):
-            opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                         parameters=lin.parameters())
-            state = opt.functional_init(params)
-            with flags_guard({"fused_adam": flag_val}):
-                newp, _ = opt.functional_update(params, grads, state,
-                                                jnp.float32(1e-3))
-            return newp
-
-        p_plain = run("never")
-        p_fused = run("always")
-        for k in params:
-            np.testing.assert_allclose(np.asarray(p_fused[k]),
-                                       np.asarray(p_plain[k]),
-                                       rtol=1e-6, atol=1e-7)
-        assert n >= fadam._CHUNK  # the weight actually took the fused path
 
 
 def _attention_problem(nq, nk, d, dtype, mask, b=2, h=2, seed=0):

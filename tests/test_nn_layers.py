@@ -639,10 +639,9 @@ class TestConvNHWCInternal(OpTest):
 class TestConvBlockLayoutStability(OpTest):
     """ISSUE 15: a conv -> BN -> act -> pool residual block must stay
     layout-stable end to end in the channels-last region — only the
-    stem/head boundary transposes survive XLA's cancellation, and the
-    fused-BN Pallas path (NHWC-native) adds ZERO transposes of its own.
-    This is the CPU-measurable face of copy/layout overhead in the
-    step."""
+    stem/head boundary transposes survive XLA's cancellation, whatever
+    ``fused_bn`` says (training mode does not read it). This is the
+    CPU-measurable face of copy/layout overhead in the step."""
 
     def _block_hlo_counts(self, fused):
         import warnings
@@ -682,17 +681,12 @@ class TestConvBlockLayoutStability(OpTest):
 
     def test_residual_block_transpose_free_interior(self):
         tr_xla, cp_xla = self._block_hlo_counts("never")
-        tr_fused, _ = self._block_hlo_counts("always")
         # stem input + head output only: conv/BN/act/pool boundaries
         # all cancel. 3 allows one residual-edge transpose on some XLA
         # versions; the pre-fix layout-churn trace showed dozens.
         assert tr_xla <= 3, f"XLA path grew interior transposes: {tr_xla}"
-        # the copy census is only meaningful on the non-interpreted
-        # path (interpret-mode pallas emulation uses host copies)
         assert cp_xla <= 3, f"XLA path grew interior copies: {cp_xla}"
-        # the fused kernel is NHWC-native: selecting it must not add a
-        # single transpose anywhere in the compiled block
-        assert tr_fused <= tr_xla, (tr_fused, tr_xla)
+        assert self._block_hlo_counts("auto") == (tr_xla, cp_xla)
 
 
 class TestSyncBatchNorm(OpTest):

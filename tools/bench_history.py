@@ -1,9 +1,9 @@
 """Bench trajectory: persist every bench result, fail on regression.
 
 Every ``bench.py`` invocation prints one JSON result line and the
-number evaporates — the repo had BENCH_r0*.json snapshots from manual
-rounds but nothing that accumulates run-over-run (ISSUE 13 satellite:
-"the trajectory is currently empty"). This tool is the pipe fitting::
+number evaporates: nothing accumulated run-over-run (ISSUE 13
+satellite: "the trajectory is currently empty"). This tool is the pipe
+fitting::
 
     set -o pipefail
     python bench.py --serving | python tools/bench_history.py append --compare

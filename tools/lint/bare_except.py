@@ -45,7 +45,7 @@ from typing import Iterator, List, Tuple
 from .framework import Finding, LintPass, iter_py_files
 
 MARKER = "noqa: broad-except"
-DEFAULT_PATHS = ("paddle1_tpu", "tools", "bench.py", "benches.py")
+DEFAULT_PATHS = ("paddle1_tpu", "tools", "bench.py")
 BROAD_NAMES = {"BaseException", "KeyboardInterrupt", "SystemExit",
                "GeneratorExit"}
 # catching the preemption notice without re-raising is only sound in

@@ -34,8 +34,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 # the runtime packages every pass defaults to (tests/ is deliberately
 # absent: seeded violation fixtures live there)
-DEFAULT_PATHS = ("paddle1_tpu", "tools", "bench.py", "benches.py",
-                 "bench_utils.py")
+DEFAULT_PATHS = ("paddle1_tpu", "tools", "bench.py", "bench_utils.py")
 
 # "# noqa: rule1,rule2 — reason" — the reason separator is an em/en
 # dash or a spaced hyphen, so rule ids may themselves contain hyphens

@@ -1,4 +1,4 @@
-"""Repro/diagnosis for the MULTICHIP_r03 involuntary-remat warnings.
+"""Repro/diagnosis for GSPMD's involuntary-remat warnings on the hybrid step.
 
 Builds the exact dryrun hybrid engine (dp2 x mp2 x zero2) on a virtual
 8-device CPU mesh, compiles the train step, and greps the optimized HLO
