@@ -10,25 +10,46 @@ re-forward inside the compiled backward, so the implementation collapses to
 wrapping the segment's pure function. RNG parity (reference saves/restores
 CUDA seeds, recompute.py:88-114) comes for free: the segment's dropout keys
 are explicit inputs, so the re-forward reuses identical keys.
+
+One thing inside a segment is kept beside its inputs: what the blockwise
+attention kernel made (``ops/pallas/flash_attention.py`` names its ``out``
+and ``lse``, the two things its backward kernels need of it). Running
+that kernel again costs more a byte kept than anything else in a decoder
+block (0.042 ms a MB on a v5e against 0.011-0.015 for q / k / v, the
+SwiGLU inputs or a whole block: PERF.md, PR 30).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
 
-from ....autograd.engine import apply
+from ....autograd.engine import apply, no_grad
 from ....core.generator import next_key, rng_scope
 from ....core.tensor import Tensor
 from ....nn.layer_base import Layer
+from ....ops.pallas.flash_attention import KEPT_RESIDUALS
 
 __all__ = ["recompute", "recompute_sequential"]
 
+# what a recomputed segment keeps beside its inputs
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
+
 
 def recompute(function: Callable, *args, **kwargs):
-    """Run ``function(*args)`` without keeping its internal activations;
-    backward re-executes it (reference recompute.py:162 recompute())."""
+    """Run ``function(*args)`` keeping its inputs and, where its attention
+    took the Pallas kernels (``use_flash_for``: both sequences >= 1024 on
+    a TPU), each kernel call's ``out`` and ``lse``; backward re-executes
+    the rest (reference recompute.py:162 recompute()) and does not run
+    the forward kernel a second time. A segment with XLA's dense
+    attention, or with none, holds no such name and keeps its inputs
+    alone. What the kernel's outputs cost: 2 bytes x tokens x heads x
+    head_dim a call (``lse`` is 4 bytes x tokens x heads), so a
+    transformer block at sequence >= 1024 under recomputation keeps about
+    twice what it kept before, its input and one more tensor of that
+    size."""
     preserve = kwargs.pop("preserve_rng_state", True)
     use_reentrant = kwargs.pop("use_reentrant", True)
     if kwargs:
@@ -62,15 +83,15 @@ def recompute(function: Callable, *args, **kwargs):
         names = list(layer.functional_state().keys())
         params = [layer.state_dict()[n] for n in names]
 
-        @jax.checkpoint
+        @functools.partial(jax.checkpoint, policy=_KEEP)
         def seg(key, param_arrays, *input_arrays):
-            with rng_scope(key):
-                with layer.load_functional_state(
-                        dict(zip(names, param_arrays))):
-                    out = fwd_callable(*_rebuild_args(input_arrays))
-                    return (tuple(t.data for t in out)
-                            if isinstance(out, (tuple, list))
-                            else out.data)
+            # tape off: the segment is differentiated as a whole, by
+            # jax, and an op's own vjp rule has to reach it unopened
+            with rng_scope(key), no_grad(), layer.load_functional_state(
+                    dict(zip(names, param_arrays))):
+                out = fwd_callable(*_rebuild_args(input_arrays))
+                return (tuple(t.data for t in out)
+                        if isinstance(out, (tuple, list)) else out.data)
 
         def op(*flat):
             p = list(flat[:len(params)])

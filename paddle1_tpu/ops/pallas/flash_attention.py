@@ -38,6 +38,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -48,6 +49,10 @@ _LANES = 128  # Mosaic minor-dim tile: per-row statistics are kept
 _NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
+# ``jax.ad_checkpoint.checkpoint_name``s of the forward kernel's two
+# outputs: a recomputation whose policy keeps both
+# (distributed/fleet/utils/recompute.py) does not run the kernel again
+KEPT_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
 
 
 def supported(q_shape, k_shape, causal: bool = False) -> bool:
@@ -230,9 +235,23 @@ def _flash_fwd(q, k, v, scale, causal, padding_mask=None, blocks=None):
     """(out [B, Nq, H, D], lse [B*H, Nq]). One ``jit`` inside the
     caller's: a model's step calls this once a layer application, and
     the step's trace and lowering then take the kernel once a shape
-    (Ouro's 48 calls cost its set-up 11 s otherwise)."""
-    return _fwd_call(q, k, v, padding_mask, scale=scale, causal=causal,
-                     blocks=blocks, interpret=_common.interpret())
+    (Ouro's 48 calls cost its set-up 11 s otherwise).
+
+    The kernel's two outputs carry names, outside that ``jit``: inert
+    anywhere but under a ``jax.checkpoint`` whose policy keeps both
+    (``fleet.utils.recompute``), where they spare the kernel's second
+    run in the backward pass; one alone spares nothing, the kernel makes
+    both or neither. ``out`` is named as the kernel wrote it, before
+    :func:`_unlayout`: named as [B, Nq, H, D] the same bytes cost Ouro's
+    step 48 more layout copies and a compile-cache entry of 131.8 MiB
+    against 126.9 (the step without the names: 129.7; PERF.md, PR 30).
+    q, k and v are not named: a block's projections and rotary run again
+    for 0.015 ms a MB kept, the kernel for 0.042."""
+    b, _, h, d = q.shape
+    out, lse = _fwd_call(q, k, v, padding_mask, scale=scale, causal=causal,
+                         blocks=blocks, interpret=_common.interpret())
+    out = checkpoint_name(out, KEPT_RESIDUALS[0])
+    return _unlayout(out, b, h, d), checkpoint_name(lse, KEPT_RESIDUALS[1])
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "blocks",
@@ -284,7 +303,7 @@ def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
         name="p1t_flash_attention_fwd",
         interpret=interpret,
     )(*args)
-    return _unlayout(out, b, h, d), lse[:, :, 0]
+    return out, lse[:, :, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -293,6 +312,8 @@ def _flash(q, k, v, padding_mask, scale, causal, blocks):
 
 
 def _flash_vjp_fwd(q, k, v, padding_mask, scale, causal, blocks):
+    # of the forward kernel the backward kernels need ``out`` and ``lse``:
+    # the two values _flash_fwd names for a recomputation to keep
     out, lse = _flash_fwd(q, k, v, scale, causal, padding_mask, blocks)
     return out, (q, k, v, padding_mask, out, lse)
 

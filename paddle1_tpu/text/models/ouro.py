@@ -32,7 +32,12 @@ stack sits under the scope ``ut_step/<t>`` (``.../ut_step/<t>/layers/
 With ``enable_recompute`` (``ParallelEngine(...,
 recompute=True)`` sets it) every layer application and every exit head with
 its cross-entropy is re-run in the backward pass instead of kept: one
-exit's float32 logits are ``tokens x vocab x 4`` bytes.
+exit's float32 logits are ``tokens x vocab x 4`` bytes. All of a layer
+application but its attention kernel, that is: where attention takes the
+Pallas kernels (sequences >= 1024 on a TPU) ``fleet.utils.recompute``
+keeps the kernel's ``out`` and ``lse`` beside the layer's input, one more
+hidden-sized tensor an application, and the backward pass does not run the
+forward kernel again (PERF.md, PR 30).
 """
 
 from __future__ import annotations

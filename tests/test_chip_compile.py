@@ -1,7 +1,9 @@
 """Compile the main-path Pallas kernels for a described TPU v5e, at real
 widths, without a chip: what Mosaic refuses here it refuses on the chip.
 And one ResNet-50 stage-1 bottleneck, forward and backward, whose text
-shows whether XLA fused batch norm into the convolutions (ISSUE 26).
+shows whether XLA fused batch norm into the convolutions (ISSUE 26), and
+one recomputed Ouro decoder block, whose text shows how often the
+attention forward kernel runs (ISSUE 30).
 
 The only file that describes the chip. The topology is described inside
 a module-scoped fixture — never at import, in a ``skipif`` or in
@@ -316,6 +318,44 @@ def test_bottleneck_batch_norm_fuses_into_the_convolutions(
         return len(re.findall(r"= bf16\[128,(?:56,56,\d+|\d+,56,56)\]\S* "
                               r"copy\(", "\n".join(entry)))
     assert activation_copies(bodies["ENTRY"]) <= 2
+
+
+def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
+        one_chip, for_the_chip, monkeypatch):
+    """One decoder block at Ouro-2.6B's widths ([2, 4096, 2048] bf16, 16
+    heads x 128) under ``fleet.utils.recompute``, loss and gradients: the
+    recomputation keeps the attention kernel's ``out`` and ``lse``, so
+    the backward pass holds the two backward kernels and no second
+    forward kernel (ISSUE 30: the parent's text has two). Traced as
+    ``make_train_step`` traces a model: tape off, ``jax.grad`` outside."""
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle1_tpu.text.models import OuroDecoderLayer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = OuroDecoderLayer(2048, 16, 128, 5632)
+    state = {k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
+             for k, v in layer.state_dict().items()}
+
+    def loss(state, x):
+        with ae.no_grad(), ae.traced_scopes(), \
+                layer.load_functional_state(state):
+            out = recompute(layer, Tensor(x))
+        return (out.data.astype(F32) ** 2).mean()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        state, jax.ShapeDtypeStruct((2, 4096, 2048), BF16,
+                                    sharding=one_chip)).compile().as_text()
+    calls = re.findall(r'^.*custom_call_target="tpu_custom_call".*$', text,
+                       re.M)
+    kernels = sorted(re.search(r"%\w*?(p1t_[a-z_]*[a-z])", c).group(1)
+                     for c in calls)
+    assert kernels == ["p1t_flash_attention_bwd_dkv",
+                       "p1t_flash_attention_bwd_dq",
+                       "p1t_flash_attention_fwd"]
+    # the rest of the block is still run again in the backward pass
+    assert "/rematted_computation/" in text
+    assert not [c for c in calls if "/rematted_computation/" in c]
 
 
 def test_paged_supported_admits_only_what_compiles(one_chip, for_the_chip):

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import print_saved_residuals
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -21,6 +22,7 @@ from benchmarks.reference import ouro_2p6b as ref  # noqa: E402
 from benchmarks.reference.numerics import Numerics  # noqa: E402
 from paddle1_tpu import obs  # noqa: E402
 from paddle1_tpu.autograd.engine import no_grad  # noqa: E402
+from paddle1_tpu.core.flags import flags_guard  # noqa: E402
 from paddle1_tpu.core.tensor import Tensor  # noqa: E402
 from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
 from paddle1_tpu.nn import functional as F  # noqa: E402
@@ -243,23 +245,96 @@ def test_the_exit_arithmetic_is_float32_under_autocast():
     assert out.data.dtype == jnp.float32
 
 
-def test_recomputation_changes_neither_loss_nor_gradients():
-    ids = _ids()
+@pytest.mark.parametrize("attention", ["dense", "kernel"])
+def test_recomputation_changes_neither_loss_nor_gradients(attention):
+    """Whatever a recomputed segment keeps: with XLA's dense attention
+    its inputs alone, with the kernels (forced: 128 is their tile, and
+    off a TPU they run in interpret mode) their ``out`` and ``lse`` too."""
+    ids = _ids(seq=128 if attention == "kernel" else 12)
     got = {}
-    for remat in (False, True):
-        model, _, _ = _model()
-        model.layers.enable_recompute = remat
-        loss = _loss(model, ids)
-        loss.backward()
-        got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
-                                    model.named_parameters()})
-    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
-    for k, g in got[False][1].items():
-        np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
-                                   atol=1e-6 * np.abs(g).max())
-    # and in evaluation nothing is recomputed
-    model.eval()
-    assert float(_loss(model, ids)) == pytest.approx(got[False][0], rel=1e-6)
+    with flags_guard(
+            flash_attention="always" if attention == "kernel" else "never"):
+        for remat in (False, True):
+            model, _, _ = _model()
+            model.layers.enable_recompute = remat
+            loss = _loss(model, ids)
+            loss.backward()
+            got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
+                                        model.named_parameters()})
+        assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
+        for k, g in got[False][1].items():
+            np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
+                                       atol=1e-6 * np.abs(g).max())
+        # and in evaluation nothing is recomputed
+        model.eval()
+        assert float(_loss(model, ids)) == pytest.approx(got[False][0],
+                                                         rel=1e-6)
+
+
+def _kernels(jaxpr):
+    """The names of the ``pallas_call``s of a jaxpr and of every jaxpr
+    inside it, one a call site, a kernel's own body left out."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    names += _kernels(inner)
+    return names
+
+
+# (the segment, the flag ``flash_attention``) -> forward kernels in the
+# segment's gradient, and what it keeps beside its inputs
+KEPT = {
+    "kernel_attention": ("block", "always", 1,
+                         ["f32[4,128,16]", "f32[4,128] named "
+                          "'flash_attention_lse'"]),
+    "dense_attention": ("block", "never", 0, []),
+    "exit_head": ("exit_head", "always", 0, []),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEPT))
+def test_a_recomputed_segment_keeps_the_kernels_outputs_and_no_more(
+        case, capsys):
+    """``recompute`` keeps a segment's inputs and what the attention
+    kernel made (``out``, ``lse``), so that the backward pass does not run
+    the forward kernel again; a segment that took dense attention, or has
+    no attention, keeps its inputs alone (ISSUE 30)."""
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    segment, flag, forward_kernels, kept = KEPT[case]
+    model, _, _ = _model()
+    layer = (model.layers.blocks[0] if segment == "block"
+             else model.exit_head)
+    state = {k: v.data for k, v in layer.state_dict().items()}
+    h = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (2, 128, 32)), jnp.float32)
+    inputs = (h,) if segment == "block" else (
+        h, jnp.asarray(_ids(seq=128)))
+
+    def loss(state, *inputs):
+        with no_grad(), layer.load_functional_state(state):
+            out = recompute(layer, *map(Tensor, inputs))
+        return sum(jnp.sum(o.data) for o in
+                   (out if isinstance(out, tuple) else (out,)))
+
+    with flags_guard(flash_attention=flag):
+        grad = jax.make_jaxpr(jax.grad(loss))(state, *inputs).jaxpr
+        print_saved_residuals(loss, state, *inputs)
+    assert sorted(_kernels(grad)) == forward_kernels * [
+        "p1t_flash_attention_bwd_dkv", "p1t_flash_attention_bwd_dq",
+        "p1t_flash_attention_fwd"]
+    lines = capsys.readouterr().out.strip().splitlines()
+    beside = [l for l in lines if " from the argument " not in l]
+    assert len(lines) - len(beside) == len(state) + 1   # + the hidden input
+    assert len(beside) == len(kept), beside
+    for line, what in zip(beside, kept):
+        assert line.startswith(what), line
+        assert "flash_attention.py" in line
 
 
 # -- through the engine -----------------------------------------------------
