@@ -6,8 +6,10 @@
 
 One chip: a full-width BERT-base trainer (12 layers, hidden 768, 12 heads,
 FFN 3072, vocab 30522; random weights from a seed) takes 5 ``step`` calls
-and one ``step_many`` of 2 through ``ParallelEngine``, and each main-path
-Pallas kernel runs once against its XLA reference at a real width. Every
+and one ``step_many`` of 2 through ``ParallelEngine``, each main-path
+Pallas kernel runs once against its XLA reference at a real width, and
+the routed-expert layer takes more held picks than its grouped products
+have rows. Every
 check that fails raises: no phase may fail and the script still exit 0,
 and no kernel gives way to its reference. One process, no child that
 needs the chip.
@@ -202,8 +204,9 @@ def kernel_phase():
                                  for a in args])
 
     # flash attention, forward and both backward kernels: BERT's width
-    # (d 64: the transposed layout) and Ouro's causal [2,4096,16,128]
-    # (d 128: the packed layout, blocks above the diagonal skipped)
+    # (d 64: the transposed layout), Ouro's causal [2,4096,16,128]
+    # (d 128: the packed layout, blocks above the diagonal skipped) and
+    # latent attention's keys 192 / values 128 wide (one layout each)
     def loss_of(attn):
         return lambda q, k, v: (attn(q, k, v).astype(f32) ** 2).sum()
 
@@ -214,11 +217,13 @@ def kernel_phase():
                 for i in range(args[0].shape[0])]
         return jax.tree_util.tree_map(lambda *r: jnp.concatenate(r), *rows)
 
-    for shape, causal in (((8, 512, 12, 64), False),
-                          ((2, 4096, 16, 128), True)):
-        at = f"{list(shape)}{' causal' if causal else ''}"
-        q, k, v = (jax.random.normal(next(keys), shape, bf16)
-                   for _ in range(3))
+    for shape, causal, v_width in (((8, 512, 12, 64), False, 64),
+                                   ((2, 4096, 16, 128), True, 128),
+                                   ((1, 4096, 8, 192), True, 128)):
+        at = (f"{list(shape)}{f' v{v_width}' if v_width != shape[3] else ''}"
+              f"{' causal' if causal else ''}")
+        q, k, v = (jax.random.normal(next(keys), shape[:3] + (d,), bf16)
+                   for d in (shape[3], shape[3], v_width))
         attn = functools.partial(flash_attention.flash_attention,
                                  causal=causal)
         plain = functools.partial(attention_ref, is_causal=causal)
@@ -283,6 +288,66 @@ def kernel_phase():
                       qd, kp, vp, table, base))
     check(err <= 5e-2, f"paged attention [8,1,12,64] page 16 bf16 vs XLA "
                        f"gather reference: max abs err {err:.2e} <= 5e-2")
+
+
+def experts_phase(tokens=4096, hidden=512, width=256):
+    """``nn.RoutedExperts`` with more held picks than its grouped products
+    have rows: every token picks the same six held experts, so five
+    eighths of the picks take the path that runs each held expert over
+    all tokens. Output and gradients against the plain sum over the held
+    experts."""
+    import jax
+    import jax.numpy as jnp
+    import paddle1_tpu as paddle
+    from paddle1_tpu import nn
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.nn import layer_moe
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    experts, held, top_k = 128, 16, 6
+
+    paddle.seed(0)
+    layer = nn.RoutedExperts(hidden, width, experts, top_k, held=(0, held),
+                             routed_scaling_factor=2.448)
+    for p in (layer.gate_up_proj, layer.down_proj):
+        p.data = p.data.astype(bf16)
+    # a selection bias that puts every token on the first six experts
+    layer.e_score_correction_bias.data = jnp.zeros(
+        experts, f32).at[:top_k].set(5.0)
+    x = jax.random.normal(jax.random.key(1), (tokens, hidden), bf16)
+
+    def plain(x, gate_up, down):
+        weights, chosen = layer_moe.route(
+            x, layer.router.data, layer.e_score_correction_bias.data,
+            top_k, 2.448)
+        y = jnp.zeros(x.shape, f32)
+        for e in range(held):
+            mine = jnp.sum(jnp.where(chosen == e, weights, 0.), -1)
+            both = x.astype(f32) @ gate_up[e].astype(f32)
+            half = both.shape[-1] // 2
+            y = y + mine[:, None] * ((jax.nn.silu(both[:, :half])
+                                      * both[:, half:]) @ down[e].astype(f32))
+        return y
+
+    rows = layer_moe.capacity_rows(tokens, top_k, held, experts)
+    check(rows < tokens * top_k,
+          f"routed experts [{tokens},{hidden}]: {tokens * top_k} held "
+          f"picks against {rows} rows of the grouped products")
+    with jax.default_matmul_precision("highest"):
+        want_y = jax.jit(plain)(x, layer.gate_up_proj.data,
+                                layer.down_proj.data)
+        want_dx, want_dg = jax.jit(jax.grad(
+            lambda *a: plain(*a).sum(), (0, 1)))(
+                x, layer.gate_up_proj.data, layer.down_proj.data)
+    xt = Tensor(x, stop_gradient=False)
+    y = layer(xt)
+    y.astype("float32").sum().backward()
+    for name, g, w in (("output", y.data, want_y),
+                       ("dx", xt.grad.data, want_dx),
+                       ("d gate_up", layer.gate_up_proj.grad.data, want_dg)):
+        scale = float(np.max(np.abs(np.asarray(w, np.float32))))
+        err = max_err(g, w) / scale
+        check(err <= 5e-2, f"routed experts, late picks, {name}: max abs "
+                           f"err / max |ref| = {err:.2e} <= 5e-2")
 
 
 # -- four chips -------------------------------------------------------------
@@ -355,6 +420,7 @@ def main():
     else:
         trainer_phase(devs[0])
         kernel_phase()
+        experts_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,

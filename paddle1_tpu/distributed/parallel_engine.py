@@ -212,6 +212,7 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
     # like eager training and sync_model/checkpoints see them — no
     # extra outputs, no extra transfers.
     stat_momentum: Dict[str, float] = {}
+    buffer_names = [name for name, _ in layer.named_buffers()]
 
     def pure_loss(params, batch, key):
         if amp_dtype is not None:
@@ -334,6 +335,12 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
         with jax.named_scope("optimizer"):
             new_params, new_state = optimizer.functional_update(
                 params, grads, opt_state, lr)
+            # a buffer is no parameter: whatever the update rule made of
+            # its zero gradient (AdamW's decay shrinks it), it leaves the
+            # step as it came, unless a running statistic is written below
+            for name in buffer_names:
+                if name in params and name not in aux:
+                    new_params[name] = params[name]
         if aux:
             # functionalized running stats: new = m*old + (1-m)*batch
             # (sequentially per micro-step under grad_accum, matching

@@ -12,5 +12,6 @@ from .layer_norm_act import *  # noqa: F401,F403
 from .layer_rnn import *  # noqa: F401,F403
 from .decode import *  # noqa: F401,F403
 from .layer_transformer import *  # noqa: F401,F403
+from .layer_moe import *  # noqa: F401,F403
 from .tiered_embedding import TieredEmbedding  # noqa: F401
 from ..framework.param_attr import ParamAttr  # re-export convenience

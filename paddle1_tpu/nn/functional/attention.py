@@ -28,7 +28,8 @@ def _t(x):
 def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
                   is_causal=False, dropout_key=None):
     """Pure-jax reference attention. q,k,v: [B, N, H, D] (paddle layout:
-    batch, seq, heads, head_dim)."""
+    batch, seq, heads, head_dim); v may have a head width of its own,
+    which the result takes."""
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(q.dtype)
     # -> [B, H, N, D]
@@ -53,13 +54,17 @@ def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
     return jnp.swapaxes(out, 1, 2)
 
 
-def rotary_embedding(x, theta=10000.0, positions=None, name=None):
+def rotary_embedding(x, theta=10000.0, positions=None, interleaved=False,
+                     name=None):
     """Rotary positions (Su et al. 2021, arXiv:2104.09864) on a
-    [batch, seq, heads, dim] query or key, rotate-half pairing: channel
-    ``i`` of the first half turns with channel ``i`` of the second by the
-    angle ``position * theta ** (-2 i / dim)``. ``positions``: [seq] or
-    [batch, seq] integers, ``0..seq-1`` when None. The angles and the
-    rotation are float32 whatever ``x`` is; the result has ``x``'s dtype."""
+    [batch, seq, heads, dim] query or key. Pair ``i`` turns by the angle
+    ``position * theta ** (-2 i / dim)``; rotate-half pairing makes it of
+    channel ``i`` of the first half and channel ``i`` of the second,
+    ``interleaved`` (the paper's own, ``rope_interleave`` of the
+    DeepSeek-V3 family) of channels ``2i`` and ``2i + 1``. ``positions``:
+    [seq] or [batch, seq] integers, ``0..seq-1`` when None. The angles
+    and the rotation are float32 whatever ``x`` is; the result has
+    ``x``'s dtype."""
     args = (_t(x),) + ((_t(positions),) if positions is not None else ())
 
     def f(x, *pos):
@@ -70,6 +75,11 @@ def rotary_embedding(x, theta=10000.0, positions=None, name=None):
         angle = at[..., None, None] * inv_freq       # [(batch,) seq, 1, half]
         cos, sin = jnp.cos(angle), jnp.sin(angle)
         xf = x.astype(jnp.float32)
+        if interleaved:
+            pairs = xf.reshape(xf.shape[:-1] + (half, 2))
+            a, b = pairs[..., 0], pairs[..., 1]
+            return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                             axis=-1).reshape(x.shape).astype(x.dtype)
         a, b = xf[..., :half], xf[..., half:]
         return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                                axis=-1).astype(x.dtype)
@@ -90,8 +100,11 @@ def use_flash_for(q, k) -> bool:
     can observe: the backend is a TPU, the step is one device's (inside
     ``core.flags.auto_partitioned_region`` GSPMD refuses a Mosaic
     kernel) and both sequences are at least ``FLASH_MIN_SEQ`` long. Tile
-    alignment is ``flash_attention.supported``'s to say. q/k are
-    [batch, seq, heads, dim] arrays (or tracers).
+    alignment is ``flash_attention.supported``'s to say, which sees v's
+    shape too. q/k are [batch, seq, heads, dim] arrays (or tracers); a
+    value width of its own (latent attention: keys 192, values 128)
+    moves no crossing in the sweep below, so the rule reads the
+    sequences alone.
 
     The sweep (tools/tpu_flash_crossover.py on a TPU v5 lite, PR 28:
     one call's forward + backward in isolation, bf16, 8192 tokens a
@@ -99,15 +112,23 @@ def use_flash_for(q, k) -> bool:
     then, and read about 5% more with the 1024 they ship with, which
     moves no crossing):
 
-    ====== ============= ============= ============= =============
-    seq    d128 causal   d128 full     d64 causal    d64 full
-    ====== ============= ============= ============= =============
-    512    2.00 / 2.10   2.00 / 2.10   0.75 / 1.26   0.75 / 1.27
-    1024   4.11 / 2.88   4.10 / 2.93   2.94 / 1.74   2.86 / 1.89
-    2048   7.47 / 3.81   7.44 / 4.52   5.29 / 2.42   5.27 / 3.09
-    4096   14.00 / 5.92  13.87 / 8.35  10.26 / 3.90  10.21 / 5.74
-    8192   30.34 / 10.24 28.24 / 15.39 20.89 / 6.71  20.64 / 10.69
-    ====== ============= ============= ============= =============
+    ====== ============= ============= ============= ============= ==================
+    seq    d128 causal   d128 full     d64 causal    d64 full      d192 / v128 causal
+    ====== ============= ============= ============= ============= ==================
+    512    2.00 / 2.10   2.00 / 2.10   0.75 / 1.26   0.75 / 1.27   not measured
+    1024   4.11 / 2.88   4.10 / 2.93   2.94 / 1.74   2.86 / 1.89   not measured
+    2048   7.47 / 3.81   7.44 / 4.52   5.29 / 2.42   5.27 / 3.09   not measured
+    4096   14.00 / 5.92  13.87 / 8.35  10.26 / 3.90  10.21 / 5.74  14.32 / 8.78
+    8192   30.34 / 10.24 28.24 / 15.39 20.89 / 6.71  20.64 / 10.69 29.40 / 14.77
+    ====== ============= ============= ============= ============= ==================
+
+    The last column (PR 31, the shipped 1024-row blocks, 16 heads):
+    keys 192 and values 128 wide, latent attention's shape. 192 is no
+    multiple of the 128-lane tile, so q, k, dq and dk take the kernels'
+    transposed [B*H, N, D] layout and pay XLA transposes that the
+    128-wide column does not: 1.25 x its products, 1.45 x its time.
+    The kernels still halve dense at 8192, where a 32-head row of dense
+    scores (8.6 GB) does not fit at all.
 
     Dense wins at 512 and below whatever the mask (BERT's [64,512,12,64]
     5.46 / 6.14, [256,128,12,64] 1.61 / 7.90: one or no key block to
@@ -208,7 +229,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         mask = m[0] if m else None
         if (use_flash and drop == 0.0
                 and use_flash_for(q, k)
-                and fa.supported(q.shape, k.shape, causal=is_causal)):
+                and fa.supported(q.shape, k.shape, causal=is_causal,
+                                 v_shape=v.shape)):
             pm = (None if mask is None
                   else _as_padding_mask(mask, k.shape[1]))
             if mask is None or pm is not None:

@@ -1,0 +1,269 @@
+"""A routed-expert feed-forward (mixture of experts) that is told which
+experts it holds; no reference analog (the reference's model-parallel
+layers split one dense product, ``fleet/meta_parallel``).
+
+The layer routes every token over ALL ``num_experts`` of the model, and
+computes the part of the result that the ``held`` experts give: what one
+chip of an expert-parallel deployment computes of the layer. What the
+absent experts would add is left out (on several chips it arrives by an
+exchange this layer does not make), the shared experts' output is added
+whole. Per token ``u`` (DeepSeek-V3's router, ``topk_method: noaux_tc``
+with one group):
+
+  ``s = sigmoid(float32(u) W_g)`` over all the experts;
+  chosen = the ``top_k`` of ``s + b``: the selection bias ``b`` takes part
+  in the choice and not in the weight, is a buffer and has no gradient;
+  ``w = s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling_factor``;
+  ``y = sum_{e chosen and held} w_e E_e(u) + S(u)``, ``E_e`` and ``S``
+  SwiGLU feed-forwards.
+
+**No token is dropped, shapes are static, and the work follows the picks
+that land here.** The ``tokens x top_k`` picks are sorted by held expert
+(the picks of absent experts last); the first ``capacity`` rows of that
+order are gathered, run through two grouped matrix products whose groups
+are the held experts' picks, weighted and summed back per token.
+``capacity`` is ``CAPACITY_FACTOR`` (3) times the picks this share expects
+under even routing (``tokens x top_k x held / num_experts``), a multiple
+of 512, at most every pick. A share that computes alone is favoured by
+its own router as training goes on (the absent experts add nothing, so
+the gradient lifts the held ones): at twice the even count Kanana-2's
+share ran out of rows some 67 steps into a run on the chip, at three
+times it does not within 68 (PERF.md section 6, PR 31). The picks of
+held experts beyond it, under any imbalance up to every token choosing
+held experts alone, run under a ``lax.cond`` through each held expert
+over all tokens with a mask: slow, exact, and not executed while the
+main path holds them all. Every data movement is a gather, forward and
+backward: the transpose of "rows in sorted order" is "sum a token's
+picks".
+
+Names in a traced step: everything under the scope ``moe``; ops
+``moe_router`` (float32 whatever the autocast), ``moe_dispatch`` (sort,
+gather), ``routed_experts`` (the grouped products), ``moe_combine``,
+``moe_overflow``; the shared experts a ``GatedFeedForward`` named
+``shared_experts``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
+
+from ..autograd.engine import apply, scope
+from ..core.tensor import Tensor
+from ..obs.costmodel import SCOPE_ATTRIBUTE
+from .initializer import Constant
+from .layer_base import Layer
+from .layer_transformer import GatedFeedForward
+
+__all__ = ["RoutedExperts"]
+
+_ROW_TILE = 512     # capacity is a multiple: a grouped product's row tile
+CAPACITY_FACTOR = 3  # rows of the grouped products over even routing's picks
+
+
+def route(x, w_gate, bias, top_k, scale):
+    """[tokens, hidden] -> (weights float32 [tokens, top_k], experts int32
+    [tokens, top_k]). Float32 products whatever x and w_gate arrive in:
+    a score rounded to bf16 moves the choice."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               w_gate.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    weights = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) * scale
+    return weights, chosen.astype(jnp.int32)
+
+
+def sort_picks(chosen, first, held, capacity):
+    """The picks in the order the grouped products take them.
+
+    -> (``order`` [capacity]: the flat pick (token * top_k + slot) at each
+    sorted row; ``where`` [tokens * top_k]: the sorted row of each pick, or
+    ``capacity`` (a row of zeros) for a pick that is not held or lies
+    beyond the capacity; ``sizes`` [held]: rows of each held expert
+    within the capacity; ``overflow``: held picks beyond it)."""
+    local = chosen.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    row = jnp.argsort(order).astype(jnp.int32)      # the inverse
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    sizes = jnp.minimum(ends, capacity) - jnp.minimum(ends - counts, capacity)
+    where = jnp.where(here & (row < capacity), row, capacity)
+    return order[:capacity], where, sizes, ends[-1] - jnp.sum(sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def rows_in_order(a, order, where, fan):
+    """``a`` [n, ...] -> [capacity, ...]: row ``order // fan`` of ``a``
+    at each sorted row (``fan`` picks a row of ``a``)."""
+    return a[order // fan]
+
+
+def _rows_fwd(a, order, where, fan):
+    return a[order // fan], (order, where)
+
+
+def _rows_bwd(fan, res, d):
+    order, where = res
+    return _sum_picks(d, where, fan), None, None
+
+
+rows_in_order.defvjp(_rows_fwd, _rows_bwd)
+
+
+def _sum_picks(o, where, fan):
+    """[capacity, ...] -> [picks / fan, ...]: each pick's sorted row (or
+    the row of zeros), summed over the ``fan`` picks of a row in
+    float32."""
+    padded = jnp.concatenate([o, jnp.zeros_like(o[:1])])
+    picked = padded[where].reshape((-1, fan) + o.shape[1:])
+    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(o.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def sum_of_picks(o, order, where, fan):
+    """The transpose of :func:`rows_in_order`."""
+    return _sum_picks(o, where, fan)
+
+
+def _sum_fwd(o, order, where, fan):
+    return _sum_picks(o, where, fan), (order, where)
+
+
+def _sum_bwd(fan, res, d):
+    order, where = res
+    return d[order // fan], None, None
+
+
+sum_of_picks.defvjp(_sum_fwd, _sum_bwd)
+
+
+def grouped_matmul(rows, weights, sizes):
+    """``rows`` [m, k] x ``weights`` [groups, k, n] -> [m, n]: the first
+    ``sizes[0]`` rows through ``weights[0]`` and so on; rows past the
+    groups are not to be read. The TPU's compiler lowers the product, and
+    autodiff's two transposes of it, to a grouped kernel of its own under
+    its own name (``ragged-dot-none``) and drops the scope; the frontend
+    attribute it keeps says where ``obs.costmodel`` is to count them."""
+    with set_xla_metadata(**{SCOPE_ATTRIBUTE: "moe/routed_experts"}):
+        return jax.lax.ragged_dot(rows, weights, sizes)
+
+
+def expert_ffn(xs, sizes, gate_up, down):
+    """Held experts' SwiGLU over their own rows: ``gate_up`` [held,
+    hidden, 2 * width] (gate columns first), ``down`` [held, width,
+    hidden]."""
+    both = grouped_matmul(xs, gate_up, sizes)
+    width = both.shape[-1] // 2
+    return grouped_matmul(jax.nn.silu(both[:, :width]) * both[:, width:],
+                          down, sizes)
+
+
+def capacity_rows(tokens, top_k, held, num_experts):
+    """Rows of the grouped products: ``CAPACITY_FACTOR`` times the picks
+    that land on ``held`` of ``num_experts`` experts under even routing, a
+    multiple of the row tile, at most every pick there is."""
+    picks = tokens * top_k
+    even = -(-picks * held // num_experts)
+    return min(picks, -(-CAPACITY_FACTOR * even // _ROW_TILE) * _ROW_TILE)
+
+
+class RoutedExperts(Layer):
+    """See the module's docstring. ``held = (first, count)``: the experts
+    this layer holds, all of them when None. ``forward``: [..., d_model]
+    -> the same shape."""
+
+    def __init__(self, d_model, expert_width, num_experts, top_k,
+                 held=None, shared_width=0, routed_scaling_factor=1.0,
+                 weight_attr=None):
+        super().__init__()
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first, self.held = held if held is not None else (0, num_experts)
+        if not 0 <= self.first <= self.first + self.held <= num_experts:
+            raise ValueError(f"held={held} of {num_experts} experts")
+        self.routed_scaling_factor = routed_scaling_factor
+        self.router = self.create_parameter([d_model, num_experts],
+                                            attr=weight_attr)
+        # DeepSeek-V3's e_score_correction_bias: moved by a balancing
+        # rule outside the gradient, never by the optimizer
+        self.register_buffer("e_score_correction_bias", Tensor(
+            Constant(0.0)([num_experts], "float32"), stop_gradient=True))
+        self.gate_up_proj = self.create_parameter(
+            [self.held, d_model, 2 * expert_width], attr=weight_attr)
+        self.down_proj = self.create_parameter(
+            [self.held, expert_width, d_model], attr=weight_attr)
+        self.shared_experts = (GatedFeedForward(d_model, shared_width,
+                                                weight_attr)
+                               if shared_width else None)
+
+    def forward(self, x):
+        from ..ops import manip_ops
+        shape = list(x.shape)
+        with scope("moe"):
+            flat = manip_ops.reshape(x, [-1, shape[-1]])
+            y = self._routed(flat)
+            if self.shared_experts is not None:
+                y = y + self.shared_experts(flat)
+            return manip_ops.reshape(y, shape)
+
+    def _routed(self, x):
+        k, first, held = self.top_k, self.first, self.held
+        tokens = x.shape[0]
+        capacity = capacity_rows(tokens, k, held, self.num_experts)
+        weights, chosen = apply(
+            "moe_router", functools.partial(
+                route, top_k=k, scale=self.routed_scaling_factor),
+            (x, self.router, self.e_score_correction_bias))
+
+        def dispatch(x, weights, chosen):
+            order, where, sizes, overflow = sort_picks(chosen, first, held,
+                                                       capacity)
+            return (rows_in_order(x, order, where, k),
+                    rows_in_order(weights.reshape(-1), order, where, 1),
+                    order, where, sizes, overflow)
+        xs, ws, order, where, sizes, overflow = apply(
+            "moe_dispatch", dispatch, (x, weights, chosen))
+        out = apply("routed_experts", expert_ffn,
+                    (xs, sizes, self.gate_up_proj, self.down_proj))
+
+        def combine(out, ws, order, where, sizes):
+            # a row past the groups holds whatever the product left there
+            live = jnp.arange(capacity) < jnp.sum(sizes)
+            weighted = (out.astype(jnp.float32) * ws[:, None]).astype(out.dtype)
+            return sum_of_picks(jnp.where(live[:, None], weighted, 0),
+                                order, where, k)
+        y = apply("moe_combine", combine, (out, ws, order, where, sizes))
+        if capacity == tokens * k:
+            return y            # the main path holds every pick there is
+
+        @jax.checkpoint
+        def through_each(y, x, weights, late, gate_up, down):
+            """Every held expert over all tokens, the late picks' weights
+            as the mask."""
+            for e in range(held):
+                mine = jnp.sum(jnp.where(late == e, weights, 0.0), -1)
+                both = jnp.dot(x, gate_up[e])
+                width = both.shape[-1] // 2
+                out = jnp.dot(jax.nn.silu(both[:, :width]) * both[:, width:],
+                              down[e])
+                y = y + (mine[:, None] * out).astype(y.dtype)
+            return y
+
+        def beyond(y, x, weights, chosen, where, overflow, gate_up, down):
+            # the held expert of each pick that found no row, else -1
+            local = chosen - first
+            late = jnp.where((local >= 0) & (local < held)
+                             & (where.reshape(chosen.shape) == capacity),
+                             local, -1)
+            return jax.lax.cond(
+                overflow > 0, through_each, lambda y, *_: y,
+                y, x, weights, late, gate_up, down)
+        return apply("moe_overflow", beyond,
+                     (y, x, weights, chosen, where, overflow,
+                      self.gate_up_proj, self.down_proj))

@@ -253,6 +253,11 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([^\s(]+)\s*\(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%([^\s=]+)\s*=\s*(.*)$")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _CALLS = re.compile(r"\bcalls=%([^\s,)}]+)")
+# the frontend attribute under which the program hands the compiler the
+# scope of an op whose instruction the compiler renames
+SCOPE_ATTRIBUTE = "p1t_scope"
+_SCOPE_ATTR = re.compile(SCOPE_ATTRIBUTE + r'="([^"]*)"')
+_OPERAND = re.compile(r"%([^\s,(){}]+)")
 
 
 def region_of(op_name: str) -> str:
@@ -272,6 +277,31 @@ def region_of(op_name: str) -> str:
     return ""
 
 
+def _pass_of(scopes, operands, unnamed, depth=4) -> str:
+    """The start of an ``op_name``, through the part that names the
+    forward or backward pass or, where it lies in a recomputed segment,
+    through ``rematted_computation``, ``/`` at its end; "" where no
+    operand lies in either pass. Taken from the last operand that does
+    (autodiff hands a transposed product the cotangent last, and its
+    other operand may be a recomputed one), looked for behind the copies
+    and bitcasts that carry no name (``unnamed``: their operands)."""
+    for operand in reversed(operands):
+        path = scopes.get(operand, "").split(";")[0]
+        if region_of(path) in ("forward", "backward"):
+            parts = path.split("/")
+            if "rematted_computation" in parts:
+                upto = parts.index("rematted_computation")
+            else:
+                upto = next(i for i, part in enumerate(parts)
+                            if region_of(part))
+            return "/".join(parts[:upto + 1]) + "/"
+        if depth and operand in unnamed:
+            behind = _pass_of(scopes, unnamed[operand], unnamed, depth - 1)
+            if behind:
+                return behind
+    return ""
+
+
 @functools.lru_cache(maxsize=2)     # the engine hands the same string back
 def parse_op_scopes(text: str):
     """``compiled.as_text()`` -> (``{instruction name: op_name}``,
@@ -281,10 +311,17 @@ def parse_op_scopes(text: str):
     the commonest of its called computation's, as the profiler's
     framework-op view does. The second holds, per fusion, the distinct
     regions (:func:`region_of`) of the instructions fused into it.
+    The compiler renames some ops it lowers itself and drops their
+    ``op_name`` (the TPU's ``ragged-dot-none`` for ``lax.ragged_dot``),
+    but keeps their frontend attributes: such an instruction that carries
+    :data:`SCOPE_ATTRIBUTE` is put under that scope, in the pass
+    (:func:`_pass_of`) its operands were made in.
     Memoised: every caller gets the same two dicts, to read."""
     bodies: Dict[str, list] = {}
     scopes: Dict[str, str] = {}
     fusions: Dict[str, str] = {}
+    renamed: Dict[str, Tuple[str, list]] = {}
+    unnamed: Dict[str, list] = {}
     body = None
     for line in text.splitlines():
         if body is not None:
@@ -293,6 +330,11 @@ def parse_op_scopes(text: str):
                 name, rhs = m.groups()
                 found = _OP_NAME.search(rhs)
                 scopes[name] = found.group(1) if found else ""
+                label = _SCOPE_ATTR.search(rhs)
+                if label and "/" not in scopes[name]:
+                    renamed[name] = (label.group(1), _OPERAND.findall(rhs))
+                elif not found:
+                    unnamed[name] = _OPERAND.findall(rhs)
                 body.append(scopes[name])
                 called = _CALLS.search(rhs)
                 if called and " fusion(" in rhs:
@@ -303,6 +345,8 @@ def parse_op_scopes(text: str):
             m = _COMPUTATION.match(line)
             if m:
                 body = bodies.setdefault(m.group(1), [])
+    for name, (label, operands) in renamed.items():
+        scopes[name] = _pass_of(scopes, operands, unnamed) + label
     regions: Dict[str, Tuple[str, ...]] = {}
     for name, called in fusions.items():
         inside = [s for s in bodies.get(called, ()) if s]

@@ -24,11 +24,15 @@ LSE are float32; the probabilities (and ``ds`` in the backward) are
 rounded to the operand dtype once, before their product. The softmax
 scale is folded into q (forward, dQ) or k (dK/dV) once a resident block.
 
-Where ``head_dim % 128 == 0`` the kernels read and write ``[B, N, H*D]``
-with a ``(block, D)`` window at column ``h*D`` (a free reshape of the
-paddle layout); otherwise q/k/v/out are transposed to ``[B*H, N, D]`` in
-XLA on the way in and out. Runs in interpreter mode off-TPU so tests
-exercise the same code path.
+q and k share one head width; v has its own (latent attention: keys
+192 wide, values 128), which ``out``, ``dout``, ``delta`` and dV follow
+while the scores, dQ and dK follow q / k. Each operand takes the layout
+of its own width: where it is a multiple of 128 the kernels read and
+write ``[B, N, H*D]`` with a ``(block, D)`` window at column ``h*D`` (a
+free reshape of the paddle layout); otherwise the operand is transposed
+to ``[B*H, N, D]`` in XLA on the way in and out (a 192-wide window would
+straddle a 128-lane tile on every other head). Runs in interpreter mode
+off-TPU so tests exercise the same code path.
 """
 
 from __future__ import annotations
@@ -55,13 +59,19 @@ _NN = (((1,), (0,)), ((), ()))   # a @ b
 KEPT_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
 
 
-def supported(q_shape, k_shape, causal: bool = False) -> bool:
+def supported(q_shape, k_shape, causal: bool = False,
+              v_shape=None) -> bool:
     """Tile-aligned shapes only; everything else uses attention_ref.
-    No VMEM gate: no kernel keeps more than a block of any operand."""
+    No VMEM gate: no kernel keeps more than a block of any operand.
+    Assumes that q and k share one head width and that v (``v_shape``,
+    k's where None) has one of its own, each a multiple of 8 up to 256."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
     _, nq, _, d = q_shape
     _, nk, _, _ = k_shape
+    dv = d if v_shape is None else v_shape[-1]
+    if dv % 8 or dv > 256:
+        return False
     if nq % _LANES or nk % _LANES:
         return False
     if causal and nq > nk:
@@ -77,7 +87,9 @@ def supported(q_shape, k_shape, causal: bool = False) -> bool:
 def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
     """(block_q, block_k, chunk): the resident block of the outer axis,
     the block fetched a grid step along the inner axis, and the slice of
-    it that one pass of the body takes. The largest rungs that divide
+    it that one pass of the body takes. ``d`` is the wider of the key and
+    the value head widths: the VMEM budget below is the widest operand's.
+    The largest rungs that divide
     the lengths, of ladders set from the sweep on the v5e
     (tools/tpu_flash_crossover.py, PERF.md PR 28) at [2, 4096, 16, 128]
     bf16 causal. A 512 x 512 float32 score tile is what a pass handles
@@ -132,6 +144,11 @@ def _layout(x):
             lambda g, r: (g, r, 0))
 
 
+def _layout_shape(b, n, h, d):
+    """The shape :func:`_layout` gives a [b, n, h, d] array."""
+    return (b, n, h * d) if d % _LANES == 0 else (b * h, n, d)
+
+
 def _unlayout(x, b, h, d):
     """Inverse of :func:`_layout` for a kernel's output."""
     if d % _LANES == 0:
@@ -167,7 +184,7 @@ def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
     mask_ref = refs[3] if has_mask else None
     o_ref, lse_ref, qs_ref, m_ref, l_ref, acc_ref = refs[3 + has_mask:]
     i, j = pl.program_id(1), pl.program_id(2)
-    bq, d = q_ref.shape
+    bq, dv = q_ref.shape[0], v_ref.shape[1]     # out is as wide as v
     bk = k_ref.shape[0]
 
     @pl.when(j == 0)
@@ -192,7 +209,7 @@ def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
         p = jnp.exp(s - _lanes(m_new, chunk))
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)[:, None]
         m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + _dot(
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, dv) + _dot(
             p.astype(v_ref.dtype), v_ref[ks, :], _NN)
 
     for c in range(bk // chunk):
@@ -209,7 +226,7 @@ def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
         visible = m > _NEG_INF * 0.5
         l_safe = jnp.where(l == 0.0, 1.0, l)
         inv = jnp.where(visible, 1.0 / l_safe, 0.0)
-        o_ref[...] = (acc_ref[...] * _lanes(inv, d)).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * _lanes(inv, dv)).astype(o_ref.dtype)
         lse_ref[...] = jnp.where(visible, m + jnp.log(l_safe), _NEG_INF)
 
 
@@ -232,7 +249,7 @@ def _key_block_map(causal, bq, bk, off, nk):
 
 
 def _flash_fwd(q, k, v, scale, causal, padding_mask=None, blocks=None):
-    """(out [B, Nq, H, D], lse [B*H, Nq]). One ``jit`` inside the
+    """(out [B, Nq, H, Dv], lse [B*H, Nq]). One ``jit`` inside the
     caller's: a model's step calls this once a layer application, and
     the step's trace and lowering then take the kernel once a shape
     (Ouro's 48 calls cost its set-up 11 s otherwise).
@@ -247,30 +264,30 @@ def _flash_fwd(q, k, v, scale, causal, padding_mask=None, blocks=None):
     against 126.9 (the step without the names: 129.7; PERF.md, PR 30).
     q, k and v are not named: a block's projections and rotary run again
     for 0.015 ms a MB kept, the kernel for 0.042."""
-    b, _, h, d = q.shape
+    b, _, h, dv = v.shape
     out, lse = _fwd_call(q, k, v, padding_mask, scale=scale, causal=causal,
                          blocks=blocks, interpret=_common.interpret())
     out = checkpoint_name(out, KEPT_RESIDUALS[0])
-    return _unlayout(out, b, h, d), checkpoint_name(lse, KEPT_RESIDUALS[1])
+    return _unlayout(out, b, h, dv), checkpoint_name(lse, KEPT_RESIDUALS[1])
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "blocks",
                                              "interpret"))
 def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
     b, nq, h, d = q.shape
-    nk = k.shape[1]
-    bq, bk, chunk = split_blocks(blocks)[0] or block_sizes(nq, nk, d,
-                                                           q.dtype)
+    nk, dv = k.shape[1], v.shape[3]
+    bq, bk, chunk = split_blocks(blocks)[0] or block_sizes(
+        nq, nk, max(d, dv), q.dtype)
     off = nk - nq
     qa, at = _layout(q)
     ka, _ = _layout(k)
-    va, _ = _layout(v)
+    va, at_v = _layout(v)       # v and out take the layout of their width
 
     kj = _key_block_map(causal, bq, bk, off, nk)
     in_specs = [
         pl.BlockSpec((None, bq, d), lambda g, i, j: at(g, i)),
         pl.BlockSpec((None, bk, d), lambda g, i, j: at(g, kj(i, j))),
-        pl.BlockSpec((None, bk, d), lambda g, i, j: at(g, kj(i, j))),
+        pl.BlockSpec((None, bk, dv), lambda g, i, j: at_v(g, kj(i, j))),
     ]
     args = [qa, ka, va]
     if padding_mask is not None:
@@ -285,18 +302,18 @@ def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
         grid=(b * h, nq // bq, nk // bk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, bq, d), lambda g, i, j: at(g, i)),
+            pl.BlockSpec((None, bq, dv), lambda g, i, j: at_v(g, i)),
             pl.BlockSpec((None, bq, _LANES), lambda g, i, j: (g, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qa.shape, q.dtype),
+            jax.ShapeDtypeStruct(_layout_shape(b, nq, h, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), q.dtype),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -341,7 +358,7 @@ def flash_attention(q, k, v, causal: bool = False,
     the Pallas analog of the reference's additive attention-mask input
     (nn/layer/transformer.py MultiHeadAttention attn_mask). ``blocks``
     overrides :func:`block_sizes` (tests and the sweep tool)."""
-    d = q.shape[-1]
+    d = q.shape[-1]             # the scale is the key width's, v has its own
     s = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
     pm = padding_mask
     if pm is not None:

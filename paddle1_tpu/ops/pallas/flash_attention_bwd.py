@@ -37,15 +37,15 @@ def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
     """((block_k, block_q, chunk) of dK/dV, (block_q, block_k, chunk) of
     dQ): resident block, block fetched a grid step, slice of it a pass of
     the body takes. From the same sweep as the forward's, and the same
-    ladders."""
+    ladders; ``d`` is the wider of the key and the value head widths."""
     bq, bk, chunk_k = forward_block_sizes(nq, nk, d, dtype)
     _, bq_long, chunk_q = forward_block_sizes(nk, nq, d, dtype)
     return (chunk_k, bq_long, chunk_q), (bq, bk, chunk_k)
 
 
 def _dkv_kernel(*refs, scale, causal, off, chunk, has_mask):
-    # k_ref/v_ref: [BK, D] (resident); q_ref/do_ref: [BQ, D];
-    # lse_ref/delta_ref: [1, BQ]; mask_ref: [BK, 1]
+    # k_ref [BK, D], v_ref [BK, Dv] (resident); q_ref [BQ, D], do_ref
+    # [BQ, Dv]; lse_ref/delta_ref: [1, BQ]; mask_ref: [BK, 1]
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     mask_ref = refs[6] if has_mask else None
     dk_ref, dv_ref, ks_ref, dk_acc, dv_acc = refs[6 + has_mask:]
@@ -94,8 +94,8 @@ def _column(row_ref):
 
 
 def _dq_kernel(*refs, scale, causal, off, chunk, has_mask):
-    # q_ref/do_ref: [BQ, D] resident; k_ref/v_ref: [BK, D];
-    # lse_ref/delta_ref: [1, BQ]; mask_ref: [1, BK]
+    # q_ref [BQ, D], do_ref [BQ, Dv] resident; k_ref [BK, D], v_ref
+    # [BK, Dv]; lse_ref/delta_ref: [1, BQ]; mask_ref: [1, BK]
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     mask_ref = refs[6] if has_mask else None
     dq_ref, qs_ref, lse_col, delta_col, dq_acc = refs[6 + has_mask:]
@@ -136,9 +136,9 @@ def _dq_kernel(*refs, scale, causal, off, chunk, has_mask):
 
 def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
                         padding_mask=None, blocks=None):
-    """(dq, dk, dv) in the paddle [B, N, H, D] layout from the
-    forward's residuals; ``lse`` is [batch*heads, Nq]. A ``jit`` of its
-    own, as the forward and for its reason."""
+    """(dq, dk, dv) in the paddle [B, N, H, D] layout (dv as wide as
+    v) from the forward's residuals; ``lse`` is [batch*heads, Nq]. A
+    ``jit`` of its own, as the forward and for its reason."""
     return _bwd_call(q, k, v, out, lse, dout, padding_mask, scale=scale,
                      causal=causal, blocks=blocks,
                      interpret=_common.interpret())
@@ -149,15 +149,15 @@ def flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
 def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, causal,
               blocks, interpret):
     b, nq, h, d = q.shape
-    nk = k.shape[1]
+    nk, dv = k.shape[1], v.shape[3]
     off = nk - nq
-    defaults = block_sizes(nq, nk, d, q.dtype)
+    defaults = block_sizes(nq, nk, max(d, dv), q.dtype)
     _, dkv, dq = split_blocks(blocks)
     kb_kv, qb_kv, c_kv = dkv or defaults[0]
     qb_q, kb_q, c_q = dq or defaults[1]
     qa, at = _layout(q)
     ka, _ = _layout(k)
-    va, _ = _layout(v)
+    va, at_v = _layout(v)       # v, dout and dV: the value width's layout
     doa, _ = _layout(dout)
 
     # delta = rowsum(dout * out): one fused XLA reduction
@@ -183,23 +183,26 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, causal,
     else:
         qi = lambda j, i: i
     rows = pl.BlockSpec((None, qb_kv, d), lambda g, j, i: at(g, qi(j, i)))
+    douts = pl.BlockSpec((None, qb_kv, dv),
+                         lambda g, j, i: at_v(g, qi(j, i)))
     keys = pl.BlockSpec((None, kb_kv, d), lambda g, j, i: at(g, j))
+    values = pl.BlockSpec((None, kb_kv, dv), lambda g, j, i: at_v(g, j))
     stat = pl.BlockSpec((None, 1, qb_kv), lambda g, j, i: (g, 0, qi(j, i)))
-    in_specs = [rows, keys, keys, rows, stat, stat]
+    in_specs = [rows, keys, values, douts, stat, stat]
     if has_mask:
         in_specs.append(pl.BlockSpec((None, kb_kv, 1),
                                      lambda g, j, i: (g // h, j, 0)))
         args.append(padding_mask.astype(jnp.float32).reshape(b, nk, 1))
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(_dkv_kernel, chunk=c_kv, **params),
         grid=(b * h, nk // kb_kv, nq // qb_kv),
         in_specs=in_specs,
-        out_specs=[keys, keys],
+        out_specs=[keys, values],
         out_shape=[jax.ShapeDtypeStruct(ka.shape, k.dtype),
                    jax.ShapeDtypeStruct(va.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((kb_kv, d), k.dtype),
                         pltpu.VMEM((kb_kv, d), jnp.float32),
-                        pltpu.VMEM((kb_kv, d), jnp.float32)],
+                        pltpu.VMEM((kb_kv, dv), jnp.float32)],
         compiler_params=semantics,
         name="p1t_flash_attention_bwd_dkv",
         interpret=interpret,
@@ -208,9 +211,12 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, causal,
     # dQ: the last key block that query block i sees
     kj = _key_block_map(causal, qb_q, kb_q, off, nk)
     rows = pl.BlockSpec((None, qb_q, d), lambda g, i, j: at(g, i))
+    douts = pl.BlockSpec((None, qb_q, dv), lambda g, i, j: at_v(g, i))
     keys = pl.BlockSpec((None, kb_q, d), lambda g, i, j: at(g, kj(i, j)))
+    values = pl.BlockSpec((None, kb_q, dv),
+                          lambda g, i, j: at_v(g, kj(i, j)))
     stat = pl.BlockSpec((None, 1, qb_q), lambda g, i, j: (g, 0, i))
-    in_specs = [rows, keys, keys, rows, stat, stat]
+    in_specs = [rows, keys, values, douts, stat, stat]
     if has_mask:
         in_specs.append(pl.BlockSpec(
             (None, 1, kb_q), lambda g, i, j: (g // h, 0, kj(i, j))))
@@ -231,4 +237,4 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, causal,
     )(*args)
 
     return (_unlayout(dq, b, h, d), _unlayout(dk, b, h, d),
-            _unlayout(dv, b, h, d))
+            _unlayout(dv_out, b, h, dv))

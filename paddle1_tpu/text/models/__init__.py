@@ -4,6 +4,9 @@ from .bert import (BertForPretraining, BertForSequenceClassification,
                    BertModel, BertPretrainingCriterion, ErnieForPretraining,
                    ErnieModel, apply_megatron_sharding, bert_base, bert_large,
                    ernie_1p5b)
+from .kanana2 import (Kanana2DecoderLayer, Kanana2ForPretraining,
+                      Kanana2Head, Kanana2PretrainingCriterion, Kanana2Stack,
+                      LatentAttention)
 from .ouro import (OuroDecoderLayer, OuroExitHead, OuroForPretraining,
                    OuroPretrainingCriterion, OuroStack)
 
@@ -11,4 +14,7 @@ __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "BertForSequenceClassification", "ErnieModel",
            "ErnieForPretraining", "apply_megatron_sharding", "bert_base",
            "bert_large", "ernie_1p5b", "OuroDecoderLayer", "OuroStack",
-           "OuroExitHead", "OuroForPretraining", "OuroPretrainingCriterion"]
+           "OuroExitHead", "OuroForPretraining", "OuroPretrainingCriterion",
+           "LatentAttention", "Kanana2DecoderLayer", "Kanana2Stack",
+           "Kanana2Head", "Kanana2ForPretraining",
+           "Kanana2PretrainingCriterion"]

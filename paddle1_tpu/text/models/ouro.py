@@ -148,17 +148,19 @@ class OuroExitHead(Layer):
         if labels is None:
             return self.lm_head(h), gate_logit
 
-        def xent(h, w, y):
-            # not F.linear + F.cross_entropy: those keep the logits and
-            # their log-sum-exp in the autocast's dtype
-            logits = jnp.matmul(h, w, preferred_element_type=jnp.float32)
-            valid = y != IGNORE_INDEX
-            at = jnp.where(valid, y, 0).astype(jnp.int32)
-            picked = jnp.take_along_axis(logits, at[..., None], -1)[..., 0]
-            return jnp.where(valid,
-                             jax.nn.logsumexp(logits, axis=-1) - picked, 0.0)
-        return (apply("exit_cross_entropy", xent,
+        return (apply("exit_cross_entropy", token_cross_entropy,
                       (h, self.lm_head.weight, labels)), gate_logit)
+
+
+def token_cross_entropy(h, w, y):
+    """Per-token cross-entropy of ``h @ w`` against ``y`` (0 where the
+    label is ``-100``), logits and log-sum-exp in float32: ``F.linear`` +
+    ``F.cross_entropy`` keep both in the autocast's dtype."""
+    logits = jnp.matmul(h, w, preferred_element_type=jnp.float32)
+    valid = y != IGNORE_INDEX
+    at = jnp.where(valid, y, 0).astype(jnp.int32)
+    picked = jnp.take_along_axis(logits, at[..., None], -1)[..., 0]
+    return jnp.where(valid, jax.nn.logsumexp(logits, axis=-1) - picked, 0.0)
 
 
 def _run(layer, remat, *args):

@@ -63,8 +63,8 @@ def for_the_chip(monkeypatch):
 
 
 def _flash(causal=False, masked=False, grad=False, dtype=BF16):
-    def build(b, s, h, d):
-        qkv = [((b, s, h, d), dtype)] * 3
+    def build(b, s, h, d, dv=None):
+        qkv = [((b, s, h, d), dtype)] * 2 + [((b, s, h, dv or d), dtype)]
         if masked:
             def fn(q, k, v, m):
                 return flash_attention.flash_attention(
@@ -122,6 +122,9 @@ def _paged(window):
 B32_S128 = (32, 128, 12, 64)
 B8_S512 = (8, 512, 12, 64)
 OURO = (2, 4096, 16, 128)   # ouro_2p6b.pretrain_s4096's attention call
+# kanana2_30b_a3b.pretrain_s8192's: keys 192 wide (no multiple of the
+# 128-lane tile: the [B*H, N, D] layout), values 128 (the [B, N, H*D] one)
+KANANA2 = (2, 8192, 32, 192, 128)
 
 CASES = {
     "flash_fwd_b32_s128": lambda: _flash()(*B32_S128),
@@ -137,6 +140,10 @@ CASES = {
         lambda: _flash(causal=True, grad=True)(*OURO),
     "flash_causal_masked_grad_ouro_s4096_d128":
         lambda: _flash(causal=True, masked=True, grad=True)(*OURO),
+    "flash_causal_kanana2_s8192_d192_v128":
+        lambda: _flash(causal=True)(*KANANA2),
+    "flash_causal_grad_kanana2_s8192_d192_v128":
+        lambda: _flash(causal=True, grad=True)(*KANANA2),
     # the widest operand block supported() admits: VMEM's worst case
     "flash_grad_f32_s4096_d256":
         lambda: _flash(grad=True, dtype=F32)(1, 4096, 2, 256),
@@ -404,3 +411,49 @@ def test_gspmd_step_takes_the_xla_composition(topo, for_the_chip,
         jax.jit(ln).lower(x, wb, wb)
     text = jax.jit(ln_gspmd).lower(x, wb, wb).compile().as_text()
     assert "tpu_custom_call" not in text
+
+
+def test_the_grouped_products_keep_their_scope(one_chip, for_the_chip):
+    """The TPU's compiler lowers ``lax.ragged_dot`` and its two transposes
+    to grouped kernels it names ``ragged-dot-none`` itself, and drops the
+    ``op_name``; the frontend attribute that ``grouped_matmul`` hands it
+    survives, and ``parse_op_scopes`` puts each under the expert layer's
+    scope in the pass its operands were made in: two products forward,
+    one of them recomputed (the second's output is not needed again
+    here) and four backward, named as a recomputed segment of
+    ``make_train_step`` names its instructions."""
+    from paddle1_tpu.nn import layer_moe
+    from paddle1_tpu.obs import costmodel
+
+    @jax.checkpoint
+    def segment(xs, gate_up, down, sizes):
+        with jax.named_scope("moe"):
+            with jax.named_scope("moe_dispatch"):
+                xs = xs * 2
+            with jax.named_scope("routed_experts"):
+                out = layer_moe.expert_ffn(xs, sizes, gate_up, down)
+            with jax.named_scope("moe_combine"):
+                return out * 3
+
+    def loss(xs, gate_up, down, sizes):
+        with jax.named_scope("loss"), jax.named_scope("Model"):
+            return jnp.sum(segment(xs, gate_up, down, sizes).astype(F32))
+
+    def s(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    # the cell's: 36864 rows, 16 held experts, 2048 -> 2 x 768 -> 2048;
+    # the chip's default precision (conftest asks float32 products of the
+    # CPU, which the grouped kernel refuses of bf16 operands)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            s((36864, 2048)), s((16, 2048, 1536)), s((16, 768, 2048)),
+            s((16,), I32)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    where = sorted(scopes[n] for n in scopes
+                   if re.match(r"ragged-dot-none(\.\d+)?$", n))
+    assert all(w.endswith("/moe/routed_experts") for w in where), where
+    passes = sorted((costmodel.region_of(w), "rematted_computation" in w)
+                    for w in where)
+    assert passes == ([("backward", False)] * 4 + [("backward", True)]
+                      + [("forward", False)] * 2), where

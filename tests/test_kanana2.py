@@ -1,0 +1,552 @@
+"""Kanana-2 (ISSUE 31): interleaved rotary positions, latent attention with
+keys and values of different widths on the blockwise kernels, the
+routed-expert layer that is told which experts it holds, and the model
+against the plain reference (``benchmarks/reference/kanana2_30b_a3b.py``);
+recomputation, the selection bias through a step, the names a traced step
+carries. CPU, tiny sizes, seeded weights."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import paddle1_tpu as paddle  # noqa: E402
+from benchmarks.reference import kanana2_30b_a3b as ref  # noqa: E402
+from benchmarks.reference.numerics import Numerics  # noqa: E402
+from paddle1_tpu import nn, obs  # noqa: E402
+from paddle1_tpu.core.flags import flags_guard  # noqa: E402
+from paddle1_tpu.core.tensor import Tensor  # noqa: E402
+from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
+from paddle1_tpu.framework.param_attr import ParamAttr  # noqa: E402
+from paddle1_tpu.nn import functional as F  # noqa: E402
+from paddle1_tpu.nn import layer_moe  # noqa: E402
+from paddle1_tpu.nn.functional.attention import attention_ref  # noqa: E402
+from paddle1_tpu.nn.initializer import Normal  # noqa: E402
+from paddle1_tpu.obs import costmodel  # noqa: E402
+from paddle1_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+from paddle1_tpu.text.models import (Kanana2ForPretraining,  # noqa: E402
+                                     Kanana2PretrainingCriterion,
+                                     LatentAttention)
+
+# the reference's configuration keys, at a tiny size: 8 routed experts of
+# which this share holds 4 (rank 0 of 2), top-3
+CFG = {"vocab_size": 96, "hidden_size": 32, "num_hidden_layers": 3,
+       "num_attention_heads": 2, "qk_nope_head_dim": 16,
+       "qk_rope_head_dim": 8, "v_head_dim": 16, "kv_lora_rank": 24,
+       "intermediate_size": 48, "moe_intermediate_size": 16,
+       "n_routed_experts": 4, "expert_parallel": 2, "expert_rank": 0,
+       "num_experts_per_tok": 3, "n_shared_experts": 2,
+       "first_k_dense_replace": 1, "routed_scaling_factor": 2.448,
+       "rope_theta": 1e4, "rms_norm_eps": 1e-6, "initializer_range": 0.2}
+NM = Numerics()
+
+
+def _model(cfg=CFG):
+    """(the Layer, the reference's weights it was loaded with)."""
+    from benchmarks.programs import kanana2_30b_a3b as program
+    from benchmarks.programs import load_weights
+    weights = ref.init_params(cfg, jax.random.key(4))
+    held = cfg["n_routed_experts"]
+    model = Kanana2ForPretraining(
+        n_routed_experts=held * cfg["expert_parallel"],
+        held_experts=(cfg["expert_rank"] * held, held),
+        **{k: cfg[k] for k in (
+            "vocab_size", "hidden_size", "num_hidden_layers",
+            "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim", "kv_lora_rank", "intermediate_size",
+            "moe_intermediate_size", "num_experts_per_tok",
+            "n_shared_experts", "first_k_dense_replace",
+            "routed_scaling_factor", "rope_theta", "rms_norm_eps",
+            "initializer_range")})
+    load_weights(model, {p: weights[r] for p, r, _ in program.leaves(cfg)})
+    return model, weights
+
+
+def _ids(batch=2, seq=12, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, CFG["vocab_size"], (batch, seq)).astype(np.int32)
+
+
+def _loss(model, ids):
+    ids = Tensor(ids)
+    labels = model.next_token_labels(ids)
+    return Kanana2PretrainingCriterion()(model(ids, labels), labels)
+
+
+# -- rotary positions, pairs (2i, 2i + 1) -----------------------------------
+
+@pytest.mark.parametrize("positions", [None, "given"])
+def test_interleaved_rotary_is_a_complex_rotation(positions):
+    rng = np.random.default_rng(1)
+    x = rng.standard_normal((2, 5, 3, 8)).astype(np.float32)
+    pos = (rng.integers(0, 50, (2, 5)) if positions else
+           np.broadcast_to(np.arange(5), (2, 5)))
+    got = F.rotary_embedding(
+        Tensor(x), 1e4, None if positions is None else Tensor(pos),
+        interleaved=True).numpy()
+    z = x[..., 0::2] + 1j * x[..., 1::2]
+    angle = pos[..., None, None] * 1e4 ** (-np.arange(4) / 4)
+    turned = z * np.exp(1j * angle)
+    want = np.stack([turned.real, turned.imag], -1).reshape(x.shape)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # and it is the rotate-half pairing of the de-interleaved channels
+    half = F.rotary_embedding(
+        Tensor(np.concatenate([x[..., 0::2], x[..., 1::2]], -1)), 1e4,
+        None if positions is None else Tensor(pos)).numpy()
+    np.testing.assert_allclose(
+        np.concatenate([got[..., 0::2], got[..., 1::2]], -1), half,
+        rtol=1e-6, atol=1e-6)
+
+
+# -- the kernels at a value width of their own ------------------------------
+
+@pytest.mark.parametrize("d,dv", [(192, 128), (64, 32), (128, 64),
+                                  (128, 128)])
+def test_kernels_take_a_value_width_of_their_own(d, dv):
+    """Forward, dq, dk, dv against ``attention_ref`` in interpret mode;
+    (128, 128) is Ouro's shape, through the same code."""
+    ks = jax.random.split(jax.random.key(0), 4)
+    b, n, h = 1, 256, 2
+    q, k = (jax.random.normal(kk, (b, n, h, d)) for kk in ks[:2])
+    v, do = (jax.random.normal(kk, (b, n, h, dv)) for kk in ks[2:])
+    assert fa.supported(q.shape, k.shape, causal=True, v_shape=v.shape)
+
+    def plain(q, k, v):
+        return attention_ref(q, k, v, is_causal=True)
+    out = fa.flash_attention(q, k, v, causal=True)
+    assert out.shape == (b, n, h, dv)
+    np.testing.assert_allclose(out, plain(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(
+        fa.flash_attention(*a, causal=True) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * do), (0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape
+        np.testing.assert_allclose(a, w, atol=5e-5)
+
+
+def test_supported_sees_the_value_width():
+    q = (2, 256, 4, 192)
+    assert fa.supported(q, q, v_shape=(2, 256, 4, 128))
+    assert not fa.supported(q, q, v_shape=(2, 256, 4, 100))
+    assert not fa.supported(q, q, v_shape=(2, 256, 4, 512))
+    # the VMEM budget is the wider operand's
+    assert fa.block_sizes(8192, 8192, 192, jnp.bfloat16) \
+        == fa.block_sizes(8192, 8192, 256, jnp.bfloat16) == (512, 1024, 512)
+
+
+def test_sdpa_passes_the_two_widths_to_the_kernels():
+    rng = np.random.default_rng(3)
+    q, k = (rng.standard_normal((1, 128, 2, 24)).astype(np.float32)
+            for _ in range(2))
+    v = rng.standard_normal((1, 128, 2, 16)).astype(np.float32)
+    want = attention_ref(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                         is_causal=True)
+    for flag in ("always", "never"):
+        with flags_guard(flash_attention=flag):
+            got = F.scaled_dot_product_attention(
+                Tensor(q), Tensor(k), Tensor(v), is_causal=True).numpy()
+        assert got.shape == (1, 128, 2, 16)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# -- latent attention -------------------------------------------------------
+
+def _attention_weights(weights, i=0):
+    return {k: weights[f"{k}.{i}"] for k in ref.ATTENTION_KEYS}
+
+
+@pytest.mark.parametrize("attention", ["dense", "kernel"])
+def test_latent_attention_follows_the_reference(attention):
+    model, weights = _model()
+    seq = 128 if attention == "kernel" else 12
+    u = np.random.default_rng(5).standard_normal(
+        (2, seq, CFG["hidden_size"])).astype(np.float32)
+    with flags_guard(
+            flash_attention="always" if attention == "kernel" else "never"):
+        got = model.layers.blocks[0].self_attn(Tensor(u)).numpy()
+    lp = _attention_weights(weights)
+    want = np.stack([ref.attend(jnp.asarray(row), lp, CFG, NM) @ lp["wo"]
+                     for row in u])
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+def test_latent_attention_is_multi_head_attention_over_expanded_k_and_v():
+    """The latent expanded to every head's key and value, the one rotary
+    key copied to every head, then plain multi-head attention."""
+    model, weights = _model()
+    attn = model.layers.blocks[0].self_attn
+    assert isinstance(attn, LatentAttention)
+    lp = _attention_weights(weights)
+    s, heads = 10, CFG["num_attention_heads"]
+    nope, rope, vd, rank = (CFG[k] for k in (
+        "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "kv_lora_rank"))
+    u = np.random.default_rng(6).standard_normal(
+        (1, s, CFG["hidden_size"])).astype(np.float32)
+    q = (u @ lp["wq"]).reshape(1, s, heads, nope + rope)
+    both = u @ lp["wkva"]
+    c = both[..., :rank]
+    c = c / np.sqrt((c * c).mean(-1, keepdims=True) + 1e-6) * lp["nkv"]
+    kv = np.asarray(c @ lp["wkvb"]).reshape(1, s, heads, nope + vd)
+
+    def turn(x):
+        return F.rotary_embedding(Tensor(np.asarray(x, np.float32)),
+                                  CFG["rope_theta"], interleaved=True).numpy()
+    k_pe = turn(both[..., None, rank:])
+    assert k_pe.shape == (1, s, 1, rope)            # one a token, not a head
+    q = np.concatenate([q[..., :nope], turn(q[..., nope:])], -1)
+    k = np.concatenate([kv[..., :nope],
+                        np.broadcast_to(k_pe, (1, s, heads, rope))], -1)
+    want = attention_ref(jnp.asarray(q), jnp.asarray(k),
+                         jnp.asarray(kv[..., nope:]), is_causal=True)
+    want = np.asarray(want).reshape(1, s, heads * vd) @ lp["wo"]
+    np.testing.assert_allclose(attn(Tensor(u)).numpy(), want, rtol=2e-4,
+                               atol=2e-5)
+
+
+# -- the router -------------------------------------------------------------
+
+def test_the_router_by_hand_on_four_tokens():
+    """Sigmoid scores over all the experts; the bias moves the choice and
+    not the weight; the weights sum to the scaling factor."""
+    logits = np.log(np.array([[4, 3, 2, 1, .5, .25], [1, 2, 3, 4, 5, 6],
+                              [1, 1.1, 1.2, 1.3, 1.4, 1.5],
+                              [9, 1, 8, 2, 7, 3]], np.float32))
+    x = np.eye(4, dtype=np.float32)                   # token t reads row t
+    s = 1 / (1 + np.exp(-logits))
+    weights, chosen = layer_moe.route(jnp.asarray(x), jnp.asarray(logits),
+                                      jnp.zeros(6), 2, 2.448)
+    assert np.asarray(chosen).tolist() == [[0, 1], [5, 4], [5, 4], [0, 2]]
+    np.testing.assert_allclose(np.sum(weights, -1), 2.448, rtol=1e-6)
+    np.testing.assert_allclose(
+        weights[0], 2.448 * s[0, :2] / s[0, :2].sum(), rtol=1e-6)
+    bias = jnp.asarray([0, 0, 0, 5.0, 0, 0])          # lifts expert 3
+    w2, c2 = layer_moe.route(jnp.asarray(x), jnp.asarray(logits), bias, 2,
+                             2.448)
+    assert np.asarray(c2).tolist() == [[3, 0], [3, 5], [3, 5], [3, 0]]
+    np.testing.assert_allclose(np.sum(w2, -1), 2.448, rtol=1e-6)
+    # the weight is made of the scores, without the bias
+    np.testing.assert_allclose(
+        w2[0], 2.448 * s[0, [3, 0]] / s[0, [3, 0]].sum(), rtol=1e-6)
+    # float32 whatever the operands arrive in
+    w3, _ = layer_moe.route(jnp.asarray(x, jnp.bfloat16),
+                            jnp.asarray(logits), jnp.zeros(6), 2, 2.448)
+    assert w3.dtype == jnp.float32
+
+
+# -- the routed-expert layer ------------------------------------------------
+
+def _experts(tokens, num_experts, top_k, held, seed=0, hidden=16, width=8,
+             shared=0):
+    paddle.seed(seed)
+    layer = nn.RoutedExperts(
+        hidden, width, num_experts, top_k, held=held, shared_width=shared,
+        routed_scaling_factor=2.448,
+        weight_attr=ParamAttr(initializer=Normal(std=0.3)))
+    x = np.random.default_rng(seed).standard_normal(
+        (tokens, hidden)).astype(np.float32)
+    return layer, x
+
+
+def _every_expert_over_every_token(layer, x, router, bias, gate_up, down):
+    """The held experts' part, plainly: no sort, no capacity."""
+    weights, chosen = layer_moe.route(x, router, bias, layer.top_k,
+                                      layer.routed_scaling_factor)
+    y = jnp.zeros_like(x)
+    for e in range(layer.held):
+        mine = jnp.sum(jnp.where(chosen == layer.first + e, weights, 0.), -1)
+        both = x @ gate_up[e]
+        width = both.shape[-1] // 2
+        y = y + mine[:, None] * (
+            (jax.nn.silu(both[:, :width]) * both[:, width:]) @ down[e])
+    return y
+
+
+def _plain(layer, x):
+    return _every_expert_over_every_token(
+        layer, jnp.asarray(x), layer.router.data,
+        layer.e_score_correction_bias.data, layer.gate_up_proj.data,
+        layer.down_proj.data)
+
+
+# (tokens, experts, top_k, held, selection bias on the held experts)
+ROUTING = {
+    "even": (64, 8, 6, (0, 4), 0.0),
+    "a_slice_in_the_middle": (640, 16, 2, (2, 2), 0.0),
+    "every_token_picks_held_experts": (640, 16, 2, (2, 2), 5.0),
+    "no_token_picks_a_held_expert": (640, 16, 2, (2, 2), -5.0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTING))
+def test_no_token_is_dropped_whatever_the_imbalance(case):
+    tokens, num_experts, top_k, held, lift = ROUTING[case]
+    layer, x = _experts(tokens, num_experts, top_k, held)
+    bias = np.zeros(num_experts, np.float32)
+    bias[held[0]:held[0] + held[1]] = lift
+    layer.e_score_correction_bias.data = jnp.asarray(bias)
+    capacity = layer_moe.capacity_rows(tokens, top_k, held[1], num_experts)
+    got = layer(Tensor(x)).numpy()
+    np.testing.assert_allclose(got, _plain(layer, x), rtol=1e-4, atol=1e-5)
+    picks = tokens * top_k
+    _, chosen = layer_moe.route(jnp.asarray(x), layer.router.data,
+                                jnp.asarray(bias), top_k, 2.448)
+    rows = layer_moe.sort_picks(chosen, held[0], held[1], capacity)[2]
+    if lift > 0:        # every pick lands here: 1280 picks, 512 rows
+        assert capacity < picks and int(np.sum(rows)) == capacity
+        assert np.abs(got).min(axis=-1).max() > 0     # and none is lost
+    if lift < 0:
+        assert int(np.sum(rows)) == 0 and not got.any()
+
+
+@pytest.mark.parametrize("case", ["even", "every_token_picks_held_experts"])
+def test_the_layers_gradients_are_the_plain_ones(case):
+    """The sort, the gathers written as each other's transposes and the
+    overflow path under autodiff, against every expert over every token."""
+    tokens, num_experts, top_k, held, lift = ROUTING[case]
+    layer, x = _experts(tokens, num_experts, top_k, held)
+    bias = np.zeros(num_experts, np.float32)
+    bias[held[0]:held[0] + held[1]] = lift
+    layer.e_score_correction_bias.data = jnp.asarray(bias)
+    xt = Tensor(x, stop_gradient=False)
+    layer(xt).sum().backward()
+    want = jax.grad(
+        lambda *a: jnp.sum(_every_expert_over_every_token(
+            layer, a[0], a[1], jnp.asarray(bias), a[2], a[3])),
+        (0, 1, 2, 3))(jnp.asarray(x), layer.router.data,
+                      layer.gate_up_proj.data, layer.down_proj.data)
+    got = (xt.grad, layer.router.grad, layer.gate_up_proj.grad,
+           layer.down_proj.grad)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g.numpy(), w, rtol=2e-4,
+                                   atol=1e-5 * np.abs(w).max())
+    assert layer.e_score_correction_bias.stop_gradient
+
+
+def test_the_eight_shares_add_up_to_the_whole_layer():
+    """The share test: with the same weights, the routed outputs of the
+    eight shares (2 experts of 16 each) plus the shared experts' output
+    counted once are the uncut layer's."""
+    tokens, hidden, width, total = 48, 16, 8, 16
+    whole, x = _experts(tokens, total, 4, None, shared=12)
+    want = whole(Tensor(x)).numpy()
+    shared = whole.shared_experts(Tensor(x)).numpy()
+    # the uncut layer against the reference's expert layer, uncut too
+    cfg = {"n_routed_experts": total, "expert_parallel": 1, "expert_rank": 0,
+           "num_experts_per_tok": 4, "moe_intermediate_size": width,
+           "routed_scaling_factor": 2.448}
+    lp = {"router": whole.router.data,
+          "e_bias": whole.e_score_correction_bias.data,
+          "e_gate_up": whole.gate_up_proj.data, "e_down": whole.down_proj.data,
+          "s_gate": whole.shared_experts.gate_proj.weight.data,
+          "s_up": whole.shared_experts.up_proj.weight.data,
+          "s_down": whole.shared_experts.down_proj.weight.data}
+    np.testing.assert_allclose(want, ref.experts(jnp.asarray(x), lp, cfg, NM),
+                               rtol=1e-4, atol=1e-5)
+    parts = np.zeros_like(want)
+    for rank in range(8):
+        share, _ = _experts(tokens, total, 4, (2 * rank, 2))
+        share.router.data = whole.router.data
+        share.gate_up_proj.data = whole.gate_up_proj.data[2 * rank:][:2]
+        share.down_proj.data = whole.down_proj.data[2 * rank:][:2]
+        parts += share(Tensor(x)).numpy()
+    np.testing.assert_allclose(parts + shared, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.fixture
+def _fresh_obs():
+    obs.reset_process_registry()
+    obs.hbm.reset()
+    yield
+    obs.reset_process_registry()
+    obs.hbm.reset()
+
+
+@pytest.mark.parametrize("capacity", [4, 8, 64])
+def test_sort_picks_by_hand(capacity):
+    """12 picks (4 tokens x top-3) over 8 experts of which 2, 3 and 4 are
+    held: the rows in the order of the held experts, each expert's rows
+    within the capacity, and the held picks that found no row."""
+    chosen = jnp.asarray([[2, 0, 3], [3, 4, 7], [4, 3, 2], [1, 3, 5]])
+    order, where, sizes, overflow = layer_moe.sort_picks(chosen, 2, 3,
+                                                         capacity)
+    # expert 2 has picks 0 and 8, expert 3 has 2, 3, 7, 10, expert 4 has 4, 6
+    held = [0, 8, 2, 3, 7, 10, 4, 6]
+    kept = min(capacity, len(held))
+    assert np.asarray(order)[:kept].tolist() == held[:kept]
+    assert int(overflow) == len(held) - kept
+    ends = np.minimum(np.cumsum([2, 4, 2]), capacity)
+    assert np.asarray(sizes).tolist() == np.diff(ends, prepend=0).tolist()
+    where = np.asarray(where)
+    assert where[held[:kept]].tolist() == list(range(kept))
+    # a pick of an absent expert, or beyond the capacity: the row of zeros
+    late = sorted(set(range(12)) - set(held[:kept]))
+    assert (where[late] == capacity).all()
+
+
+# -- the model --------------------------------------------------------------
+
+def test_the_model_follows_the_reference():
+    model, weights = _model()
+    ids = _ids()
+    got = float(_loss(model, ids))
+    want, state = ref.loss(weights, {"ids": jnp.asarray(ids)}, CFG, NM)
+    assert got == pytest.approx(float(want), rel=2e-5)
+    assert sorted(state) == ["e_bias.1", "e_bias.2"]
+    # a leading dense layer, then expert layers
+    kinds = [type(b.mlp).__name__ for b in model.layers.blocks]
+    assert kinds == ["GatedFeedForward", "RoutedExperts", "RoutedExperts"]
+    logits = model(Tensor(ids)).numpy()
+    assert logits.shape == (2, 12, CFG["vocab_size"])
+
+
+@pytest.mark.parametrize("precision", ["float32", "float8_matmul"])
+def test_the_reference_in_blocks_is_the_reference(monkeypatch, precision):
+    """At the cell's size the reference takes the rows, the heads and
+    groups of the held experts one at a time through ``lax.map``, and a
+    head's queries in blocks; at a test's size it takes each whole. The same loss and
+    gradients either way (the control's float8 scale is a block's own, so
+    there only the loss is held, loosely)."""
+    weights = ref.init_params(CFG, jax.random.key(4))
+    batch = {"ids": jnp.asarray(_ids(seq=16))}
+    nm = Numerics(precision)
+
+    def run():
+        return jax.value_and_grad(
+            lambda w: ref.loss(w, batch, CFG, nm)[0])(weights)
+    whole, g_whole = run()
+    # one head and 8 of its 16 queries a block of scores; one expert a group
+    monkeypatch.setattr(ref, "SCORE_BLOCK_BYTES", 4 * 16 * 8)
+    monkeypatch.setattr(ref, "EXPERTS_BLOCK_BYTES", 4 * 16 * 32)
+    monkeypatch.setattr(ref, "BLOCK_TOKENS", 16)     # and a row a block
+    text = str(jax.make_jaxpr(lambda w: ref.loss(w, batch, CFG, nm)[0])(
+        weights))
+    assert text.count("scan") >= 3
+    blocks, g_blocks = run()
+    if precision != "float32":
+        # other blocks, other scales: the control's noise is drawn anew
+        assert float(blocks) == pytest.approx(float(whole), rel=0.05)
+        assert all(np.isfinite(np.asarray(g)).all()
+                   for g in g_blocks.values())
+        return
+    assert float(blocks) == pytest.approx(float(whole), rel=1e-5)
+    for k in g_whole:
+        if not k.startswith("e_bias."):
+            a, b = np.asarray(g_blocks[k]), np.asarray(g_whole[k])
+            assert np.linalg.norm(a - b) <= 1e-5 * max(
+                np.linalg.norm(b), 1e-3), k
+
+
+@pytest.mark.parametrize("attention", ["dense", "kernel"])
+def test_recomputation_changes_neither_loss_nor_gradients(attention):
+    """A segment now holds a sort, gathers and grouped products with
+    integer residuals beside the kernel's kept ``out`` and ``lse``."""
+    ids = _ids(seq=128 if attention == "kernel" else 12)
+    got = {}
+    with flags_guard(
+            flash_attention="always" if attention == "kernel" else "never"):
+        for remat in (False, True):
+            model, _ = _model()
+            model.layers.enable_recompute = remat
+            loss = _loss(model, ids)
+            loss.backward()
+            got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
+                                        model.named_parameters()})
+    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
+    for k, g in got[False][1].items():
+        np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
+                                   atol=1e-6 * np.abs(g).max())
+
+
+def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(capsys):
+    from jax.ad_checkpoint import print_saved_residuals
+    from paddle1_tpu.autograd.engine import no_grad
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    model, _ = _model()
+    layer = model.layers.blocks[1]
+    state = {k: v.data for k, v in layer.state_dict().items()}
+    h = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (2, 128, CFG["hidden_size"])), jnp.float32)
+
+    def loss(state, h):
+        with no_grad(), layer.load_functional_state(state):
+            return jnp.sum(recompute(layer, Tensor(h)).data)
+    with flags_guard(flash_attention="always"):
+        print_saved_residuals(loss, state, h)
+    lines = capsys.readouterr().out.strip().splitlines()
+    beside = [l for l in lines if " from the argument " not in l]
+    assert len(lines) - len(beside) == len(state) + 1   # + the hidden input
+    assert len(beside) == 2 and all("flash_attention.py" in l
+                                    for l in beside), beside
+
+
+def _engine(recompute=True, amp=None, bias=None):
+    model, _ = _model()
+    if bias is not None:
+        for _, b in model.named_buffers():
+            b.data = jnp.asarray(bias, jnp.float32)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-2, weight_decay=0.1,
+                                 parameters=model.parameters())
+    crit = Kanana2PretrainingCriterion()
+
+    def loss_fn(m, b):
+        ids = Tensor(b["ids"])
+        labels = m.next_token_labels(ids)
+        return crit(m(ids, labels), labels)
+    return ParallelEngine(model, opt, loss_fn, amp_dtype=amp,
+                          mesh=build_mesh(dp=1, devices=jax.devices()[:1]),
+                          recompute=recompute)
+
+
+def test_the_selection_bias_passes_through_a_step_untouched():
+    """A buffer with no gradient: AdamW's decay would shrink it."""
+    bias = np.linspace(-0.1, 0.1, 8)
+    engine = _engine(bias=bias)
+    names = [k for k in engine.params if k.endswith("e_score_correction_bias")]
+    assert len(names) == 2
+    batch = engine.shard_batch({"ids": _ids()})
+    losses = [float(engine.step(batch)) for _ in range(3)]
+    assert losses[2] < losses[0]
+    for k in names:
+        np.testing.assert_array_equal(np.asarray(engine.params[k]),
+                                      bias.astype(np.float32))
+    moved = engine.params["layers.blocks.1.mlp.router"]
+    assert not np.allclose(np.asarray(moved), np.asarray(
+        engine.model.layers.blocks[1].mlp.router.data))
+
+
+def test_the_expert_layer_and_latent_attention_have_scopes(_fresh_obs):
+    engine = _engine(amp="bfloat16")
+    float(engine.step(engine.shard_batch({"ids": _ids()}), lr=1e-3))
+    scopes = costmodel.step_op_scopes()
+    named = [s for s in scopes.values() if "jvp(loss)" in s]
+    assert not [s for s in named if "/while/" in s]
+    for i in (1, 2):
+        at = f"/layers/recompute/{i}/mlp/moe/"
+        for op in ("moe_router", "moe_dispatch", "routed_experts",
+                   "moe_combine", "shared_experts/gate_proj/linear",
+                   "shared_experts/swiglu"):
+            assert any(at + op in s for s in named), (i, op)
+    assert not [s for s in named if "/layers/recompute/0/mlp/moe" in s]
+    assert any("/layers/recompute/0/mlp/gate_proj/linear" in s for s in named)
+    for i in range(3):
+        at = f"/layers/recompute/{i}/self_attn/"
+        for op in ("q_proj/linear", "kv_a_proj_with_mqa/linear",
+                   "kv_a_layernorm/rms_norm", "kv_b_proj/linear",
+                   "rotary_embedding", "scaled_dot_product_attention",
+                   "o_proj/linear"):
+            assert any(at + op in s for s in named), (i, op)
+    assert any("/lm_head/head_cross_entropy" in s for s in named)
+    again = [s for s in named if "/rematted_computation/" in s]
+    assert any("/moe/routed_experts" in s for s in again)
+    # the router is a float32 island under the bf16 autocast
+    text = engine.compiled_step_text()
+    router = [l for l in text.splitlines()
+              if "moe_router" in l and " dot(" in l]
+    assert router and all(" f32[" in l.split(" dot(")[0] for l in router)

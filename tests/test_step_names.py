@@ -124,6 +124,39 @@ def test_parse_op_scopes_on_a_hand_made_program():
                      "tanh_fusion": ("forward",)}
 
 
+RENAMED = '''HloModule jit_counted_step, is_scheduled=true
+
+ENTRY %main.9 (w: bf16[4,8,8]) -> bf16[16,8] {
+  %w = bf16[4,8,8]{2,1,0} parameter(0), metadata={op_name="params[\\'w\\']"}
+  %rows = bf16[16,8]{1,0} fusion(%w), kind=kLoop, calls=%f, metadata={op_name="jit(counted_step)/transpose(jvp(loss))/M/recompute/checkpoint/rematted_computation/1/moe/moe_dispatch/gather"}
+  %dout = bf16[16,8]{1,0} fusion(%w), kind=kLoop, calls=%g, metadata={op_name="jit(counted_step)/transpose(jvp(loss))/M/recompute/checkpoint/1/moe/moe_combine/mul"}
+  %copy-start = (bf16[16,8]{1,0}, bf16[16,8]{1,0}, u32[]) copy-start(%dout)
+  %copy-done = bf16[16,8]{1,0} copy-done(%copy-start)
+  %ragged-dot-none = bf16[4,8,8]{2,1,0} custom-call(%rows, %copy-done), custom_call_target="tpu_custom_call", frontend_attributes={mosaic_fusion_entry_point="true",p1t_scope="moe/routed_experts"}, metadata={op_name="ragged-dot-none"}
+  %ragged-dot-none.1 = bf16[16,8]{1,0} custom-call(%rows, %w), custom_call_target="tpu_custom_call", frontend_attributes={p1t_scope="moe/routed_experts"}, metadata={op_name="ragged-dot-none"}
+  %ragged-dot-none.2 = bf16[16,8]{1,0} custom-call(%w, %w), custom_call_target="tpu_custom_call", frontend_attributes={p1t_scope="moe/routed_experts"}, metadata={op_name="ragged-dot-none"}
+  ROOT %other = bf16[16,8]{1,0} custom-call(%rows), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+}
+'''
+
+
+def test_parse_op_scopes_places_an_op_the_compiler_renamed():
+    """``ragged-dot-none`` carries the scope the program handed the
+    compiler as a frontend attribute, in the pass of its last operand
+    that names one, behind a copy if need be."""
+    scopes, _ = costmodel.parse_op_scopes(RENAMED)
+    back = "jit(counted_step)/transpose(jvp(loss))/"
+    # the weight gradient: recomputed rows x the cotangent, which decides
+    assert scopes["ragged-dot-none"] == back + "moe/routed_experts"
+    assert scopes["ragged-dot-none.1"] == (
+        back + "M/recompute/checkpoint/rematted_computation/"
+        "moe/routed_experts")
+    assert costmodel.region_of(scopes["ragged-dot-none.1"]) == "backward"
+    # no operand in either pass: the scope alone; no attribute: as it was
+    assert scopes["ragged-dot-none.2"] == "moe/routed_experts"
+    assert scopes["other"] == "ragged-dot-none"
+
+
 @pytest.mark.parametrize("cell", ["bert_base.pretrain_s128",
                                   "resnet50.train_b128"])
 def test_step_op_scopes_names_the_compiled_step(cell):

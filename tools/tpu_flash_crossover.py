@@ -9,12 +9,14 @@ composition: query chunk i against keys [0, (i+1) * chunk)), ``jax``
 (``jax.experimental.pallas.ops.tpu.flash_attention`` in its own
 [B, H, N, D] layout, the yardstick).
 
-    python tools/tpu_flash_crossover.py [--part blocks|lengths|all]
+    python tools/tpu_flash_crossover.py [--part blocks|lengths|latent|all]
 
 ``blocks``: the Ouro shape [2, 4096, 16, 128] bf16 causal, each kernel
 alone under each block triple. ``lengths``: 8192 tokens at sequence
 lengths 512 .. 8192 (d 128 and d 64, causal and not, and BERT's two
-shapes), kernel against dense: the crossover.
+shapes), kernel against dense: the crossover. ``latent`` (PR 31): the
+same at keys 192 and values 128 wide (latent attention), causal, at 4096
+and 8192.
 Writes chiprun_out/flash_sweep.json beside the table.
 """
 
@@ -64,13 +66,15 @@ def chunked_causal(q, k, v, chunks):
     return jnp.concatenate(outs, axis=1)
 
 
-def _inputs(b, s, h, d, dtype, layout="bnhd"):
+def _inputs(b, s, h, d, dtype, layout="bnhd", dv=None):
+    """q, k (``d`` wide), v, dout (``dv`` wide, ``d`` when None)."""
     import jax
     import jax.numpy as jnp
-    shape = (b, s, h, d) if layout == "bnhd" else (b, h, s, d)
     keys = jax.random.split(jax.random.key(0), 4)
-    return [jax.random.normal(kk, shape, jnp.float32).astype(dtype)
-            for kk in keys]
+    return [jax.random.normal(
+        kk, (b, s, h, w) if layout == "bnhd" else (b, h, s, w),
+        jnp.float32).astype(dtype)
+        for kk, w in zip(keys, (d, d, dv or d, dv or d))]
 
 
 def _fwd_bwd(attn):
@@ -174,18 +178,19 @@ def part_blocks(rows, shape=(2, 4096, 16, 128), triples=TRIPLES,
 
 
 def part_lengths(rows, tokens=8192, lengths=(512, 1024, 2048, 4096, 8192),
-                 extra=((64, 512, 12, 64, False), (256, 128, 12, 64, False))):
-    """Kernel against dense by sequence length, 8192 tokens a call."""
+                 extra=((64, 512, 12, 64, False), (256, 128, 12, 64, False)),
+                 widths=((16, 128, 128, (True, False)),
+                         (12, 64, 64, (True, False)))):
+    """Kernel against dense by sequence length, 8192 tokens a call.
+    ``widths``: (heads, key width, value width, masks)."""
     import jax.numpy as jnp
-    cases = []
-    for s in lengths:
-        b = max(tokens // s, 1)
-        cases += [(b, s, 16, 128, True), (b, s, 16, 128, False),
-                  (b, s, 12, 64, True), (b, s, 12, 64, False)]
-    cases += list(extra)
-    for b, s, h, d, causal in cases:
-        q, k, v, do = _inputs(b, s, h, d, jnp.bfloat16)
-        line = {"part": "lengths", "shape": [b, s, h, d], "causal": causal}
+    cases = [(max(tokens // s, 1), s, h, d, dv, causal) for s in lengths
+             for h, d, dv, masks in widths for causal in masks]
+    cases += [(b, s, h, d, d, causal) for b, s, h, d, causal in extra]
+    for b, s, h, d, dv, causal in cases:
+        q, k, v, do = _inputs(b, s, h, d, jnp.bfloat16, dv=dv)
+        line = {"part": "lengths", "shape": [b, s, h, d, dv],
+                "causal": causal}
         for name, attn in arms(causal).items():
             if name.startswith("chunked"):
                 continue      # lost at the Ouro shape (part blocks)
@@ -200,7 +205,7 @@ def part_lengths(rows, tokens=8192, lengths=(512, 1024, 2048, 4096, 8192),
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--part", choices=("blocks", "lengths", "all"),
+    ap.add_argument("--part", choices=("blocks", "lengths", "latent", "all"),
                     default="all")
     ap.add_argument("--triples", default="",
                     help="'512,2048,512;1024,2048,512': only these")
@@ -215,6 +220,9 @@ def main():
         part_blocks(rows, triples=triples)
     if args.part in ("lengths", "all"):
         part_lengths(rows)
+    if args.part in ("latent", "all"):
+        part_lengths(rows, lengths=(4096, 8192), extra=(),
+                     widths=((16, 192, 128, (True,)),))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/flash_sweep.json", "w") as f:
         json.dump({"device": [dev.platform, dev.device_kind],
