@@ -23,7 +23,8 @@ from typing import Any, Callable, Dict, List, Optional
 from .errors import InvalidArgumentError
 
 __all__ = ["define_flag", "get_flags", "set_flags", "flag", "flags_guard",
-           "auto_partitioned_region", "maybe_enable_compilation_cache"]
+           "auto_partitioned_region", "in_auto_partitioned_region",
+           "maybe_enable_compilation_cache"]
 
 
 @dataclass
@@ -126,13 +127,19 @@ def auto_partitioned_region():
         _gspmd.on = was
 
 
+def in_auto_partitioned_region() -> bool:
+    """Whether the caller is traced inside an
+    :func:`auto_partitioned_region`: a kernel without a flag asks here."""
+    return getattr(_gspmd, "on", False)
+
+
 def flag_active(name: str) -> bool:
     """Resolve a Pallas kernel's auto/always/never flag: True when
     ``always``, or when ``auto``, the default backend is TPU and the
     caller is not inside an :func:`auto_partitioned_region`."""
     v = flag(name)
     return v == "always" or (
-        v == "auto" and _on_tpu() and not getattr(_gspmd, "on", False))
+        v == "auto" and _on_tpu() and not in_auto_partitioned_region())
 
 
 def conv_nhwc_active() -> bool:

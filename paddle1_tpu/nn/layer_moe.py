@@ -32,13 +32,22 @@ times it does not within 68 (PERF.md section 6, PR 31). The picks of
 held experts beyond it, under any imbalance up to every token choosing
 held experts alone, run under a ``lax.cond`` through each held expert
 over all tokens with a mask: slow, exact, and not executed while the
-main path holds them all. Every data movement is a gather, forward and
-backward: the transpose of "rows in sorted order" is "sum a token's
-picks".
+main path holds them all. No data movement is a scatter, forward or
+backward: the transpose of "rows in sorted order" (a gather of
+``capacity`` rows from the tokens) is "sum a token's picks", and that
+reads the rows that hold a pick and no other: ``capacity`` rows of the
+hidden width relaid as whole tiles, each held row fetched once into a
+zeroed ``[top_k, block, hidden]`` buffer in VMEM, ``tokens`` rows written
+(``ops/pallas/sum_picks.py``; the pick weights' vector and rows that are
+no whole tiles take a gather with an index for every pick). A gather
+over every pick paid 36.7 ns for each of the 98,304 rows it fetched from
+HBM at Kanana-2's size, 87% of them a row of zeros (PERF.md section 6,
+PR 32).
 
 Names in a traced step: everything under the scope ``moe``; ops
 ``moe_router`` (float32 whatever the autocast), ``moe_dispatch`` (sort,
-gather), ``routed_experts`` (the grouped products), ``moe_combine``,
+gather; backward, the kernel ``p1t_sum_picks_fwd``), ``routed_experts``
+(the grouped products), ``moe_combine`` (the same kernel),
 ``moe_overflow``; the shared experts a ``GatedFeedForward`` named
 ``shared_experts``.
 """
@@ -52,8 +61,10 @@ import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..autograd.engine import apply, scope
+from ..core.flags import in_auto_partitioned_region
 from ..core.tensor import Tensor
 from ..obs.costmodel import SCOPE_ATTRIBUTE
+from ..ops.pallas import sum_picks as sum_picks_kernel
 from .initializer import Constant
 from .layer_base import Layer
 from .layer_transformer import GatedFeedForward
@@ -82,8 +93,8 @@ def sort_picks(chosen, first, held, capacity):
 
     -> (``order`` [capacity]: the flat pick (token * top_k + slot) at each
     sorted row; ``where`` [tokens * top_k]: the sorted row of each pick, or
-    ``capacity`` (a row of zeros) for a pick that is not held or lies
-    beyond the capacity; ``sizes`` [held]: rows of each held expert
+    ``capacity`` (no row: it adds nothing) for a pick that is not held or
+    lies beyond the capacity; ``sizes`` [held]: rows of each held expert
     within the capacity; ``overflow``: held picks beyond it)."""
     local = chosen.reshape(-1) - first
     here = (local >= 0) & (local < held)
@@ -119,11 +130,22 @@ rows_in_order.defvjp(_rows_fwd, _rows_bwd)
 
 def _sum_picks(o, where, fan):
     """[capacity, ...] -> [picks / fan, ...]: each pick's sorted row (or
-    the row of zeros), summed over the ``fan`` picks of a row in
-    float32."""
-    padded = jnp.concatenate([o, jnp.zeros_like(o[:1])])
-    picked = padded[where].reshape((-1, fan) + o.shape[1:])
-    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(o.dtype)
+    nothing, where ``where`` says ``capacity``), summed over the ``fan``
+    picks of a row in float32 and cast once.
+
+    Rows of whole tiles go through ``ops/pallas/sum_picks.py``, which
+    reads the rows that hold a pick and no other (its docstring: a gather
+    pays for every pick, held or not). A vector (the pick weights'
+    gradient), rows of another width and a step that XLA partitions by
+    itself take the gather: indexed ``[fan, picks / fan]`` so that the
+    sum runs over the leading axis, an index past the rows filled with
+    zeros."""
+    if sum_picks_kernel.supported(o, where, fan) \
+            and not in_auto_partitioned_region():
+        return sum_picks_kernel.sum_picks(o, where, fan)
+    picked = jnp.take(o, where.reshape(-1, fan).T, axis=0, mode="fill",
+                      fill_value=0)
+    return jnp.sum(picked.astype(jnp.float32), axis=0).astype(o.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

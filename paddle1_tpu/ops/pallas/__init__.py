@@ -2,7 +2,8 @@
 in /root/reference/paddle/fluid/operators/fused/): flash attention, fused
 layer_norm, fused softmax, paged attention, fused batch norm for given
 statistics (normalize+activation+residual forward, one-pass
-dx/dgamma/dbeta backward, local moments).
+dx/dgamma/dbeta backward, local moments), the sum of a token's picks over
+the rows an expert layer holds.
 
 Each kernel module exposes ``supported(...)`` gates so callers fall back to
 plain XLA compositions on CPU/interpret mode or unaligned shapes.

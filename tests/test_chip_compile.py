@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle1_tpu.core.flags import flags_guard
 from paddle1_tpu.ops.pallas import (_common, flash_attention, fused_bn,
-                                    layer_norm, paged_attention, softmax)
+                                    layer_norm, paged_attention, softmax,
+                                    sum_picks)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -119,6 +120,11 @@ def _paged(window):
              ((slots, per_slot), I32), ((slots,), I32)])
 
 
+def _sum_picks(rows, hidden, tokens, fan, dtype=BF16):
+    return (lambda o, where: sum_picks.sum_picks(o, where, fan),
+            [((rows, hidden), dtype), ((tokens * fan,), I32)])
+
+
 B32_S128 = (32, 128, 12, 64)
 B8_S512 = (8, 512, 12, 64)
 OURO = (2, 4096, 16, 128)   # ouro_2p6b.pretrain_s4096's attention call
@@ -154,6 +160,14 @@ CASES = {
         lambda: _bn_norm(act="relu", residual=True),
     "bn_norm_grad_25088x256": lambda: _bn_norm(act="relu", grad=True),
     "bn_moments_25088x256": _bn_moments,
+    # kanana2's combine: 16384 tokens x top-6 over 36864 rows of 2048
+    "sum_picks_kanana2_36864x2048_t16384_top6":
+        lambda: _sum_picks(36864, 2048, 16384, 6),
+    "sum_picks_f32_4096x1024_t2048_top8":
+        lambda: _sum_picks(4096, 1024, 2048, 8, F32),
+    # the most picks supported() admits: SMEM's worst case
+    "sum_picks_8192x2048_t32768_top6":
+        lambda: _sum_picks(8192, 2048, 32768, 6),
     "paged_w1_h12_d64_p16": lambda: _paged(1),
     "paged_w4_h12_d64_p16": lambda: _paged(4),
 }
@@ -365,6 +379,24 @@ def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
     assert not [c for c in calls if "/rematted_computation/" in c]
 
 
+def test_sum_picks_supported_admits_only_what_fits():
+    def ok(rows, hidden, tokens, fan, dtype=BF16):
+        return sum_picks.supported(jax.ShapeDtypeStruct((rows, hidden), dtype),
+                                   jax.ShapeDtypeStruct((tokens * fan,), I32),
+                                   fan)
+    assert ok(36864, 2048, 16384, 6) and ok(4096, 1024, 2048, 8, F32)
+    assert ok(8192, 2048, 32768, 6)
+    assert not ok(8192, 2048, 32768, 8)     # 1 MiB of picks: SMEM's whole
+    assert not ok(36864, 1024, 16384, 6)    # bf16 rows of half a tile
+    assert not ok(36864, 2048 + 128, 16384, 6)
+    assert not ok(1 << 20, 2048, 16384, 6)  # a row past its 20 bits
+    assert not ok(4096, 2048, 2048, 9)      # a slot past its 3 bits
+    assert not ok(4096, 2048, 2044, 6)      # tokens in no block of eight
+    assert not sum_picks.supported(
+        jax.ShapeDtypeStruct((36864,), F32),
+        jax.ShapeDtypeStruct((98304,), I32), 1)     # the weights' vector
+
+
 def test_paged_supported_admits_only_what_compiles(one_chip, for_the_chip):
     """Every shape ``supported()`` admits at the edges of its ranges
     (narrowest and widest head dim, window and page) must compile."""
@@ -411,6 +443,61 @@ def test_gspmd_step_takes_the_xla_composition(topo, for_the_chip,
         jax.jit(ln).lower(x, wb, wb)
     text = jax.jit(ln_gspmd).lower(x, wb, wb).compile().as_text()
     assert "tpu_custom_call" not in text
+
+
+def test_the_expert_layer_gathers_no_row_for_a_pick_it_does_not_hold(
+        one_chip, for_the_chip, monkeypatch):
+    """Kanana-2's expert layer at the cell's shape ([2, 8192, 2048] bf16,
+    16 of 128 experts, top-6: 98,304 picks over 36,864 rows) under
+    ``fleet.utils.recompute``, loss and gradients (ISSUE 32). The sum of
+    a token's picks is the kernel, once forward under ``moe_combine`` and
+    once backward as the transpose of ``moe_dispatch``'s gather, and not
+    again in the recomputed segment; the text holds none of what the
+    gather over every pick made: no ``[36865, 2048]`` rows with a row of
+    zeros behind them, no ``[98304, 2048]`` or ``[6, 16384, 2048]`` buffer
+    of every pick's row (402 MB), no ``[16384, 6, 2048]`` relayout, in
+    either layout of a row. The grouped products are all still under the
+    layer's scope in their pass."""
+    from paddle1_tpu import nn
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle1_tpu.obs import costmodel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = nn.RoutedExperts(2048, 768, 128, 6, held=(0, 16),
+                             shared_width=1536, routed_scaling_factor=2.448)
+    state = {k: jax.ShapeDtypeStruct(
+        v.shape, F32 if k == "e_score_correction_bias" else BF16,
+        sharding=one_chip) for k, v in layer.state_dict().items()}
+
+    def loss(state, x):
+        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes(), \
+                layer.load_functional_state(state):
+            out = recompute(layer, Tensor(x))
+        return (out.data.astype(F32) ** 2).mean()
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            state, jax.ShapeDtypeStruct((2, 8192, 2048), BF16,
+                                        sharding=one_chip)
+        ).compile().as_text()
+    for gone in (r"\[36865,2048\]", r"\[98304,2048\]", r"\[98304,16,128\]",
+                 r"\[6,16384,2048\]", r"\[6,16384,16,128\]",
+                 r"\[16384,6,2048\]", r"\[16384,6,16,128\]"):
+        assert not re.search(gone, text), gone
+    assert not re.search(r"\bwhile\(", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted(scopes[n] for n in scopes
+                     if re.match(r"p1t_sum_picks_fwd(\.\d+)?$", n))
+    assert [(costmodel.region_of(k), k.split("/moe/")[1].split("/")[0],
+             "rematted_computation" in k) for k in kernels] == [
+        ("forward", "moe_combine", False),
+        ("backward", "moe_dispatch", False)], kernels
+    products = sorted(scopes[n] for n in scopes
+                      if re.match(r"ragged-dot-none(\.\d+)?$", n))
+    assert len(products) == 8 and all(
+        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
+        for w in products), products
 
 
 def test_the_grouped_products_keep_their_scope(one_chip, for_the_chip):

@@ -31,6 +31,7 @@ from paddle1_tpu.nn.functional.attention import attention_ref  # noqa: E402
 from paddle1_tpu.nn.initializer import Normal  # noqa: E402
 from paddle1_tpu.obs import costmodel  # noqa: E402
 from paddle1_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+from paddle1_tpu.ops.pallas import sum_picks  # noqa: E402
 from paddle1_tpu.text.models import (Kanana2ForPretraining,  # noqa: E402
                                      Kanana2PretrainingCriterion,
                                      LatentAttention)
@@ -388,6 +389,144 @@ def test_sort_picks_by_hand(capacity):
     # a pick of an absent expert, or beyond the capacity: the row of zeros
     late = sorted(set(range(12)) - set(held[:kept]))
     assert (where[late] == capacity).all()
+
+
+# -- a token's picks, summed (ISSUE 32) -------------------------------------
+
+def _sum_picks_before_issue_32(o, where, fan):
+    """``layer_moe._sum_picks`` as PR 31 shipped it: a row of zeros behind
+    the rows, every pick's row gathered, a token's picks side by side."""
+    padded = jnp.concatenate([o, jnp.zeros_like(o[:1])])
+    picked = padded[where].reshape((-1, fan) + o.shape[1:])
+    return jnp.sum(picked.astype(jnp.float32), axis=1).astype(o.dtype)
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+# (the experts the 16 tokens choose their 6 from, of 16; 0-7 are held)
+HELD_PICKS = {"none": (8, 16), "some": (0, 16), "all": (0, 8)}
+# (fan, the operand's trailing shape): the rows of a hidden width that
+# `moe_dispatch` and `moe_combine` move, through the gather (40 wide) and
+# through the kernel (2048 wide: whole tiles in either dtype, interpret
+# mode here), the pick weights' vector, and rows a pick each
+OPERANDS = {"fan6-rows": (6, (40,)), "fan6-rows_of_tiles": (6, (2048,)),
+            "fan1-vector": (1, ()), "fan1-rows": (1, (40,))}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("held", sorted(HELD_PICKS))
+@pytest.mark.parametrize("capacity", ["below_the_held_picks", "every_pick"])
+@pytest.mark.parametrize("operand", sorted(OPERANDS))
+def test_the_two_gathers_are_one_hot_products_and_transposes(
+        operand, capacity, held, dtype):
+    """``rows_in_order`` and ``sum_of_picks``, values and VJPs, against
+    the products with the plain one-hot matrices R [capacity, n] (sorted
+    row r reads row ``order[r] // fan``) and S [n, capacity] (row i sums
+    the rows its picks found); against PR 31's body to the last bit,
+    whatever the dtype: the sum is float32 and cast once; and as each
+    other's transposes on the rows that hold a pick."""
+    fan, trailing = OPERANDS[operand]
+    tokens, top_k, lo_hi = 16, 6, HELD_PICKS[held]
+    rng = np.random.default_rng(sorted(HELD_PICKS).index(held))
+    chosen = lo_hi[0] + np.argsort(
+        rng.random((tokens, lo_hi[1] - lo_hi[0])), axis=-1)[:, :top_k]
+    picks = tokens * top_k
+    rows = picks if capacity == "every_pick" else 24
+    order, where, sizes, _ = layer_moe.sort_picks(
+        jnp.asarray(chosen, jnp.int32), 0, 8, rows)
+    live = int(np.sum(sizes))
+    assert live == {"none": 0, "all": rows}.get(held, live)
+    n = picks // fan
+    order_np, where_np = np.asarray(order), np.asarray(where)
+    R = np.zeros((rows, n), np.float32)
+    R[np.arange(rows), order_np // fan] = 1
+    S = np.zeros((n, rows + 1), np.float32)
+    np.add.at(S, (np.arange(picks) // fan, where_np), 1)
+    S = S[:, :rows]                       # without the row of zeros
+    np.testing.assert_array_equal(S[:, :live], R[:live].T)
+    assert not S[:, live:].any()
+
+    a = jnp.asarray(rng.standard_normal((n,) + trailing), dtype)
+    o = jnp.asarray(rng.standard_normal((rows,) + trailing), dtype)
+    assert sum_picks.supported(o, where, fan) == (trailing == (2048,))
+
+    def product(m, v):              # float32, then the operand's rounding
+        return (m @ _f32(v).reshape(v.shape[0], -1)).reshape(
+            (m.shape[0],) + v.shape[1:])
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == "float32" else \
+        dict(rtol=2 ** -8, atol=2 ** -8)
+
+    def close(got, want):
+        assert got.dtype == jnp.dtype(dtype) and got.shape == want.shape
+        np.testing.assert_allclose(_f32(got), want, **tol)
+    got_rows, rows_vjp = jax.vjp(
+        lambda a: layer_moe.rows_in_order(a, order, where, fan), a)
+    got_sum, sum_vjp = jax.vjp(
+        lambda o: layer_moe.sum_of_picks(o, order, where, fan), o)
+    close(got_rows, product(R, a))
+    close(got_sum, product(S, o))
+    close(rows_vjp(o)[0], product(S, o))
+    close(sum_vjp(a)[0], product(R, a))
+    # to the last bit, values and the dispatch's backward alike
+    before = _sum_picks_before_issue_32(o, where, fan)
+    for got in (got_sum, rows_vjp(o)[0],
+                jax.jit(layer_moe.sum_of_picks, static_argnums=3)(
+                    o, order, where, fan)):
+        np.testing.assert_array_equal(_f32(got), _f32(before))
+    # <rows_in_order(a), d> == <a, sum_of_picks(d)>, d on the live rows
+    d = jnp.where((jnp.arange(rows) < live).reshape((-1,) + (1,) * len(
+        trailing)), o, 0)
+    lhs = np.vdot(_f32(got_rows), _f32(d))
+    rhs = np.vdot(_f32(a), _f32(layer_moe.sum_of_picks(d, order, where, fan)))
+    np.testing.assert_allclose(lhs, rhs, rtol=1e-5 if dtype == "float32"
+                               else 2e-2, atol=1e-5)
+
+
+@pytest.mark.parametrize("tokens,lift", [(40, 0.0), (256, 0.0), (40, 5.0)])
+def test_the_kernel_sums_block_after_block(tokens, lift):
+    """Five blocks of 8 tokens and two of 128 (the buffer's two halves in
+    turn, the next block's rows asked for while this one is summed), and
+    four held picks a token against rows for fewer: bfloat16 rows against
+    PR 31's body to the last bit, under ``jit`` as a step runs it."""
+    rng = np.random.default_rng(tokens)
+    scores = rng.random((tokens, 16))
+    scores[:, :4] += lift                       # experts 0-3 are held
+    chosen = np.argsort(-scores, axis=-1)[:, :6]
+    rows = 96 if lift else 128
+    _, where, sizes, _ = layer_moe.sort_picks(
+        jnp.asarray(chosen, jnp.int32), 0, 4, rows)
+    assert 0 < int(np.sum(sizes)) <= rows
+    o = jnp.asarray(rng.standard_normal((rows, 2048)), jnp.bfloat16)
+    assert sum_picks.supported(o, where, 6)
+    got = jax.jit(layer_moe._sum_picks, static_argnums=2)(o, where, 6)
+    np.testing.assert_array_equal(
+        _f32(got), _f32(_sum_picks_before_issue_32(o, where, 6)))
+
+
+def test_a_step_partitioned_by_xla_takes_the_gather():
+    """Inside ``auto_partitioned_region`` (GSPMD refuses a Mosaic
+    kernel) rows of whole tiles go through the gather, to the same bits."""
+    import contextlib
+    from paddle1_tpu.core.flags import auto_partitioned_region
+    where = jnp.asarray([0, 2, 2, 1, 2, 2, 0, 2], jnp.int32)   # 2: no row
+    o = jnp.asarray(np.random.default_rng(0).standard_normal((2, 1024)),
+                    jnp.float32)
+    assert sum_picks.supported(o, where, 1)
+
+    def calls(region):
+        with region:
+            return str(jax.make_jaxpr(
+                lambda o: layer_moe._sum_picks(o, where, 1))(o))
+    assert "p1t_sum_picks_fwd" in calls(contextlib.nullcontext())
+    assert "pallas_call" not in calls(auto_partitioned_region())
+    with auto_partitioned_region():
+        gathered = layer_moe._sum_picks(o, where, 1)
+    np.testing.assert_array_equal(gathered, layer_moe._sum_picks(o, where, 1))
+    np.testing.assert_array_equal(
+        gathered, np.where((np.asarray(where) < 2)[:, None],
+                           np.asarray(o)[np.minimum(where, 1)], 0))
 
 
 # -- the model --------------------------------------------------------------
