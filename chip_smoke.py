@@ -7,9 +7,11 @@
 One chip: a full-width BERT-base trainer (12 layers, hidden 768, 12 heads,
 FFN 3072, vocab 30522; random weights from a seed) takes 5 ``step`` calls
 and one ``step_many`` of 2 through ``ParallelEngine``, each main-path
-Pallas kernel runs once against its XLA reference at a real width, and
-the routed-expert layer takes more held picks than its grouped products
-have rows. Every
+Pallas kernel runs once against its XLA reference at a real width, the
+blockwise attention kernels run block diffusion's mask rule with grouped
+key/value heads at SDAR's size against a float32 dense-mask reference in
+blocks, and the routed-expert layer takes more held picks than its grouped
+products have rows. Every
 check that fails raises: no phase may fail and the script still exit 0,
 and no kernel gives way to its reference. One process, no child that
 needs the chip.
@@ -290,6 +292,98 @@ def kernel_phase():
                        f"gather reference: max abs err {err:.2e} <= 5e-2")
 
 
+def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
+                          dim=128):
+    """The blockwise kernels under block diffusion's mask with grouped
+    key/value heads, at SDAR's size ([1, 2 x 8192, 32 / 4, 128] bf16),
+    forward and backward against a float32 reference that holds the dense
+    mask a block of queries at a time, written from the mask's four cases
+    and not from ``mask_rules``; then each kernel's ms a call from a
+    trace."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks import trace_reduce
+    from benchmarks.model_flops.sdar_30b_a3b import KERNELS
+    from benchmarks.reducers.kernel_mxu_pct import seconds_a_step
+    from paddle1_tpu.ops.pallas import flash_attention
+    from paddle1_tpu.ops.pallas.mask_rules import BlockDiffusion
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    group, s = heads // kv_heads, 2 * length
+    kq, kk, kv_, kd = jax.random.split(jax.random.key(33), 4)
+    q = jax.random.normal(kq, (1, s, heads, dim), bf16)
+    k = jax.random.normal(kk, (1, s, kv_heads, dim), bf16)
+    v = jax.random.normal(kv_, (1, s, kv_heads, dim), bf16)
+    dout = jax.random.normal(kd, (1, s, heads, dim), bf16)
+    rule = BlockDiffusion(length, block, noisy_first=True)
+    at = f"[1, {s}, {heads}/{kv_heads}, {dim}] block diffusion B={block}"
+
+    def kernels(q, k, v):
+        return flash_attention.flash_attention(q, k, v, mask=rule)
+
+    r = jnp.arange(s)
+    noisy, blk = r < length, r % length // block
+    rows = 512
+
+    def plain(q, k, v):
+        """float32, one key/value head and ``rows`` queries of its group
+        at a time against every key."""
+        qg = q[0].astype(f32).reshape(s // rows, rows, kv_heads, group, dim)
+        kf, vf = k[0].astype(f32), v[0].astype(f32)
+
+        @jax.checkpoint
+        def some(args):
+            qb, qn, qblk = args                 # [rows, kv, group, dim]
+            scores = jnp.einsum("qngd,knd->ngqk", qb, kf) / dim ** 0.5
+            seen = jnp.where(
+                qn[:, None],
+                jnp.where(noisy[None], blk[None] == qblk[:, None],
+                          blk[None] < qblk[:, None]),
+                ~noisy[None] & (blk[None] <= qblk[:, None]))
+            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            return jnp.einsum("ngqk,knd->qngd", probs, vf)
+        out = jax.lax.map(some, (qg, noisy.reshape(-1, rows),
+                                 blk.reshape(-1, rows)))
+        return out.reshape(1, s, heads, dim)
+
+    def vjp_of(fn):
+        def f(q, k, v, dout):
+            out, pull = jax.vjp(fn, q, k, v)
+            return (out,) + pull(dout.astype(out.dtype))
+        return jax.jit(f)
+
+    got = vjp_of(kernels)
+    text = got.lower(q, k, v, dout).compile().as_text()
+    n_calls = text.count('custom_call_target="tpu_custom_call"')
+    check(n_calls == 3 and " while(" not in text,
+          f"flash grad {at} compiled to {n_calls} tpu_custom_calls and no "
+          "while")
+    with jax.default_matmul_precision("highest"):
+        want = vjp_of(plain)(q, k, v, dout.astype(f32))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got(q, k, v, dout),
+                          want):
+        scale = float(np.max(np.abs(np.asarray(w))))
+        err = max_err(g, w) / scale
+        check(err <= 5e-2, f"flash {at} {name}: max abs err / max |ref| = "
+                           f"{err:.2e} <= 5e-2")
+    trace_dir = os.path.join(REPO, ".bench_trace", "chip_smoke")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    calls = 5
+    jax.profiler.start_trace(trace_dir)
+    for _ in range(calls):
+        out = got(q, k, v, dout)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    trace = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    views = trace_reduce.views(trace)
+    check(len(views) == 1 and len(views[0]["step_s"]) == calls,
+          f"the trace holds the {calls} calls of one chip")
+    for kernel, took in seconds_a_step(views[0], KERNELS).items():
+        check(took > 0, f"{kernel} is in the trace")
+        print(f"chip_smoke: {kernel} {at}: {1e3 * took:.3f} ms a call "
+              "(smoke reading, not a metric)", flush=True)
+
+
 def experts_phase(tokens=4096, hidden=512, width=256):
     """``nn.RoutedExperts`` with more held picks than its grouped products
     have rows: every token picks the same six held experts, so five
@@ -420,6 +514,7 @@ def main():
     else:
         trainer_phase(devs[0])
         kernel_phase()
+        block_diffusion_phase()
         experts_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {

@@ -26,12 +26,18 @@ def _t(x):
 
 
 def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
-                  is_causal=False, dropout_key=None):
+                  is_causal=False, dropout_key=None, mask_rule=None):
     """Pure-jax reference attention. q,k,v: [B, N, H, D] (paddle layout:
     batch, seq, heads, head_dim); v may have a head width of its own,
-    which the result takes."""
+    which the result takes; k and v may have fewer heads than q
+    (grouped-query attention), each then serves ``H / H_kv`` query heads.
+    ``mask_rule``: a structured mask as ``ops.pallas.mask_rules``
+    describes it, built dense here: for sizes the kernels do not take."""
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(q.dtype)
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     # -> [B, H, N, D]
     qh = jnp.swapaxes(q, 1, 2)
     kh = jnp.swapaxes(k, 1, 2)
@@ -41,6 +47,10 @@ def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
         nq, nk = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((nq, nk), bool), nk - nq)
         logits = jnp.where(causal, logits, jnp.finfo(logits.dtype).min)
+    if mask_rule is not None:
+        from ...ops.pallas.mask_rules import dense_mask
+        seen = dense_mask(mask_rule, logits.shape[-2], logits.shape[-1])
+        logits = jnp.where(seen, logits, jnp.finfo(logits.dtype).min)
     if mask is not None:
         if mask.dtype == jnp.bool_:
             logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
@@ -190,10 +200,14 @@ def paged_attention(query, k_pool, v_pool, table, pos, name=None):
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None,
-                                 use_flash=True):
+                                 use_flash=True, mask_rule=None):
     """Fused attention entry. Takes the Pallas blockwise kernels where
     ``use_flash_for`` says they win and the shapes are tile-aligned, else
-    the XLA composition."""
+    the XLA composition. ``key`` and ``value`` may have fewer heads than
+    ``query`` (grouped-query attention). ``mask_rule``: a structured mask
+    as a description (``ops.pallas.mask_rules``: block diffusion's), not
+    a dense array: the kernels skip its hidden tiles, the composition
+    builds the dense mask from it."""
     q, k, v = _t(query), _t(key), _t(value)
     drop = dropout_p if training else 0.0
     dropout_key = None
@@ -230,15 +244,16 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         if (use_flash and drop == 0.0
                 and use_flash_for(q, k)
                 and fa.supported(q.shape, k.shape, causal=is_causal,
-                                 v_shape=v.shape)):
+                                 v_shape=v.shape, mask=mask_rule)):
             pm = (None if mask is None
                   else _as_padding_mask(mask, k.shape[1]))
             if mask is None or pm is not None:
                 _count_arm("flash")
                 return fa.flash_attention(q, k, v, causal=is_causal,
-                                          padding_mask=pm)
+                                          padding_mask=pm, mask=mask_rule)
         _count_arm("dense")
         return attention_ref(q, k, v, mask=mask, dropout_p=drop,
-                             is_causal=is_causal, dropout_key=dropout_key)
+                             is_causal=is_causal, dropout_key=dropout_key,
+                             mask_rule=mask_rule)
     return apply("scaled_dot_product_attention", f,
                  tuple(a if isinstance(a, Tensor) else _t(a) for a in args))

@@ -7,8 +7,11 @@ computes the part of the result that the ``held`` experts give: what one
 chip of an expert-parallel deployment computes of the layer. What the
 absent experts would add is left out (on several chips it arrives by an
 exchange this layer does not make), the shared experts' output is added
-whole. Per token ``u`` (DeepSeek-V3's router, ``topk_method: noaux_tc``
-with one group):
+whole. Per token ``u`` (``scoring="sigmoid"``: DeepSeek-V3's router,
+``topk_method: noaux_tc`` with one group; ``scoring="softmax"``: ``p =
+softmax(float32(u) W_g)`` over all the experts, chosen = the ``top_k`` of
+``p``, ``w = p[chosen] / sum p[chosen] * routed_scaling_factor``, no
+selection bias):
 
   ``s = sigmoid(float32(u) W_g)`` over all the experts;
   chosen = the ``top_k`` of ``s + b``: the selection bias ``b`` takes part
@@ -75,13 +78,21 @@ _ROW_TILE = 512     # capacity is a multiple: a grouped product's row tile
 CAPACITY_FACTOR = 3  # rows of the grouped products over even routing's picks
 
 
-def route(x, w_gate, bias, top_k, scale):
+def route(x, w_gate, bias, top_k, scale, scoring="sigmoid"):
     """[tokens, hidden] -> (weights float32 [tokens, top_k], experts int32
     [tokens, top_k]). Float32 products whatever x and w_gate arrive in:
-    a score rounded to bf16 moves the choice."""
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
-                               w_gate.astype(jnp.float32),
-                               precision=jax.lax.Precision.HIGHEST))
+    a score rounded to bf16 moves the choice. ``scoring``: ``sigmoid``
+    (DeepSeek-V3's rule, the module's docstring) or ``softmax`` (the
+    Qwen3-MoE family's, ``norm_topk_prob`` true: probabilities over all
+    the experts, the ``top_k`` largest, renormalised to sum ``scale``;
+    ``bias`` takes no part)."""
+    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        picked, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        weights = picked / jnp.sum(picked, -1, keepdims=True) * scale
+        return weights, chosen.astype(jnp.int32)
+    s = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     weights = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) * scale
@@ -203,9 +214,12 @@ class RoutedExperts(Layer):
 
     def __init__(self, d_model, expert_width, num_experts, top_k,
                  held=None, shared_width=0, routed_scaling_factor=1.0,
-                 weight_attr=None):
+                 weight_attr=None, scoring="sigmoid"):
         super().__init__()
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring={scoring!r}")
         self.num_experts, self.top_k = num_experts, top_k
+        self.scoring = scoring
         self.first, self.held = held if held is not None else (0, num_experts)
         if not 0 <= self.first <= self.first + self.held <= num_experts:
             raise ValueError(f"held={held} of {num_experts} experts")
@@ -213,9 +227,12 @@ class RoutedExperts(Layer):
         self.router = self.create_parameter([d_model, num_experts],
                                             attr=weight_attr)
         # DeepSeek-V3's e_score_correction_bias: moved by a balancing
-        # rule outside the gradient, never by the optimizer
-        self.register_buffer("e_score_correction_bias", Tensor(
-            Constant(0.0)([num_experts], "float32"), stop_gradient=True))
+        # rule outside the gradient, never by the optimizer. The softmax
+        # rule has none
+        if scoring == "sigmoid":
+            self.register_buffer("e_score_correction_bias", Tensor(
+                Constant(0.0)([num_experts], "float32"),
+                stop_gradient=True))
         self.gate_up_proj = self.create_parameter(
             [self.held, d_model, 2 * expert_width], attr=weight_attr)
         self.down_proj = self.create_parameter(
@@ -238,10 +255,13 @@ class RoutedExperts(Layer):
         k, first, held = self.top_k, self.first, self.held
         tokens = x.shape[0]
         capacity = capacity_rows(tokens, k, held, self.num_experts)
+        # the softmax rule has no selection bias to hand in
+        bias = ((self.e_score_correction_bias,)
+                if self.scoring == "sigmoid" else ())
         weights, chosen = apply(
-            "moe_router", functools.partial(
-                route, top_k=k, scale=self.routed_scaling_factor),
-            (x, self.router, self.e_score_correction_bias))
+            "moe_router", lambda x, w, b=None: route(
+                x, w, b, k, self.routed_scaling_factor, self.scoring),
+            (x, self.router) + bias)
 
         def dispatch(x, weights, chosen):
             order, where, sizes, overflow = sort_picks(chosen, first, held,

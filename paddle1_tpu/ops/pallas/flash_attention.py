@@ -9,14 +9,23 @@ This file holds the forward kernel and the entry point; the two
 backward kernels are in flash_attention_bwd.py. All three share one
 blocked grid ``(batch*heads, outer blocks, inner blocks)`` with the
 inner axis sequential and accumulators in VMEM scratch; a grid step
-takes its fetched block a chunk at a time, and under a causal mask
-(bottom-right aligned, query ``r`` sees keys ``<= r + nk - nq``) a
-score tile (resident block x chunk) is one of three kinds:
+takes its fetched block a chunk at a time, and under a mask rule
+(``mask_rules.py``: bottom-right causal, query ``r`` sees keys ``<= r +
+nk - nq``, or block diffusion's mask over a noisy and a clean copy of a
+row) a score tile (resident block x chunk) is one of three kinds:
 
-* wholly above the diagonal: no work, and the index map is clamped to
-  the last needed block so nothing is fetched for a block of such;
-* wholly below: the plain body, no iota, no select;
-* crossed by the diagonal: the body with the mask.
+* wholly hidden: no work, and the index map fetches nothing for a
+  block of such (under the causal rule it is clamped to the last needed
+  block; under block diffusion the inner axis counts the needed blocks
+  alone, and holds the last one where a resident block needs fewer);
+* wholly visible: the plain body, no iota, no select;
+* crossed: the body with the rule's element-wise keep.
+
+k and v may have fewer heads than q (grouped-query attention: ``H_kv``
+divides ``H``): the forward and dQ kernels read key/value head ``h //
+(H / H_kv)`` through the index map, dK/dV passes a group's query heads
+one after the other along its sequential axis and writes ``H_kv`` heads.
+No copy of k or v per query head is made anywhere.
 
 Precision is the caller's: the products take q/k/v/dout in the dtype
 they arrive in (bf16 under AMP) and accumulate in float32; max, sum and
@@ -47,6 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
+from .mask_rules import CAUSAL, NO_MASK
 
 _LANES = 128  # Mosaic minor-dim tile: per-row statistics are kept
               # replicated across one 128-lane register row
@@ -59,25 +69,38 @@ _NN = (((1,), (0,)), ((), ()))   # a @ b
 KEPT_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
 
 
+def rule_of(causal: bool, mask):
+    """The one mask rule of a call: ``mask`` (``mask_rules``), the causal
+    rule where ``causal``, the rule that hides nothing where neither."""
+    if causal and mask is not None and mask != CAUSAL:
+        raise ValueError("causal=True beside another mask rule")
+    return CAUSAL if causal else NO_MASK if mask is None else mask
+
+
 def supported(q_shape, k_shape, causal: bool = False,
-              v_shape=None) -> bool:
+              v_shape=None, mask=None) -> bool:
     """Tile-aligned shapes only; everything else uses attention_ref.
     No VMEM gate: no kernel keeps more than a block of any operand.
     Assumes that q and k share one head width and that v (``v_shape``,
-    k's where None) has one of its own, each a multiple of 8 up to 256."""
+    k's where None) has one of its own, each a multiple of 8 up to 256;
+    k's heads divide q's. ``mask``: a rule of ``mask_rules``; a rule
+    whose alignment would leave a query with no key (bottom-right causal
+    with more queries than keys: the zero-sumexp sentinel would poison
+    the vjp) is attention_ref's."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
-    _, nq, _, d = q_shape
-    _, nk, _, _ = k_shape
+    _, nq, h, d = q_shape
+    _, nk, h_kv, _ = k_shape
     dv = d if v_shape is None else v_shape[-1]
     if dv % 8 or dv > 256:
         return False
     if nq % _LANES or nk % _LANES:
         return False
-    if causal and nq > nk:
-        # bottom-right causal leaves leading queries with ZERO visible
-        # keys; the zero-sumexp sentinel would poison the vjp — let
-        # attention_ref handle this degenerate alignment
+    if h % h_kv or (v_shape is not None and v_shape[2] != h_kv):
+        return False
+    rule = rule_of(causal, mask)
+    if not (rule.lengths_ok(nq, nk)
+            and all(n % _LANES == 0 for n in rule.sizes(nq, nk))):
         return False
     if d % 8 or d > 256:
         return False
@@ -135,13 +158,19 @@ def _lanes(x, n: int):
 
 
 def _layout(x):
-    """[B, N, H, D] -> (the array a kernel windows, its index map).
-    The map takes (batch*head index, row block) to a block index."""
+    """[B, N, H, D] -> (the array a kernel windows, its index map)."""
     b, n, h, d = x.shape
     if d % _LANES == 0:
-        return x.reshape(b, n, h * d), lambda g, r: (g // h, r, g % h)
-    return (x.transpose(0, 2, 1, 3).reshape(b * h, n, d),
-            lambda g, r: (g, r, 0))
+        return x.reshape(b, n, h * d), _window(h, d)
+    return x.transpose(0, 2, 1, 3).reshape(b * h, n, d), _window(h, d)
+
+
+def _window(h, d):
+    """The index map of :func:`_layout`'s array of ``h`` heads ``d``
+    wide: (batch*head index, row block) -> a block index."""
+    if d % _LANES == 0:
+        return lambda g, r: (g // h, r, g % h)
+    return lambda g, r: (g, r, 0)
 
 
 def _layout_shape(b, n, h, d):
@@ -156,28 +185,41 @@ def _unlayout(x, b, h, d):
     return x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)
 
 
-def _causal_keep(shape, q0, k0, off, q_axis):
-    """Keep-mask of one score tile whose ``q_axis`` runs over queries
-    from q0 and whose other axis runs over keys from k0."""
-    q_ids = q0 + off + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
-    k_ids = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return q_ids >= k_ids
+def _kv_head(h, h_kv):
+    """batch*head index of q -> that of its key/value head."""
+    if h == h_kv:
+        return lambda g: g
+    return lambda g: g // h * h_kv + g % h // (h // h_kv)
 
 
-def _run_tile(body, causal, q0, bq, k0, bk, off):
+def _run_tile(body, rule, q0, bq, k0, bk, off, live=None):
     """Call ``body(masked)`` as the kind of the score tile of queries
-    [q0, q0+bq) x keys [k0, k0+bk) asks, or not at all."""
-    if not causal:
+    [q0, q0+bq) x keys [k0, k0+bk) asks, or not at all. ``live``: whether
+    the grid step fetched a block this resident block needs (None: the
+    rule's grid has no other)."""
+    needed, full = rule.tile(q0, bq, k0, bk, off)   # some, every pair seen
+    if full is True:        # the trace knows: a rule that hides nothing
         body(False)
         return
-    needed = k0 <= q0 + bq - 1 + off        # some pair unmasked
-    full = k0 + bk - 1 <= q0 + off          # every pair unmasked
+    if live is not None:
+        needed, full = live & needed, live & full
     pl.when(full)(lambda: body(False))
     pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
         lambda: body(True))
 
 
-def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
+def _count_tiles(rule, nq, nk, bq, bk, calls):
+    """``flash_tiles_total{kind}``: the score tiles of one lowered kernel
+    call over its ``calls`` (batch x heads) rows of the grid, by what the
+    rule makes of them. A wrong rule shows as a count, not as a time."""
+    from ...obs.registry import process_group
+    from .mask_rules import tile_counts
+    for kind, n in tile_counts(rule, nq, nk, bq, bk).items():
+        process_group("kind").child(kind).counter(
+            "flash_tiles_total").inc(n * calls)
+
+
+def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [BK, D]; mask_ref: [1, BK] f32,
     # 1.0 = attend / 0.0 = padding; lse_ref: [BQ, 128]
     q_ref, k_ref, v_ref = refs[:3]
@@ -186,6 +228,7 @@ def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
     i, j = pl.program_id(1), pl.program_id(2)
     bq, dv = q_ref.shape[0], v_ref.shape[1]     # out is as wide as v
     bk = k_ref.shape[0]
+    at, live = rule.key_blocks(i, j, bq, bk)
 
     @pl.when(j == 0)
     def _():
@@ -201,7 +244,7 @@ def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
             s = jnp.where(mask_ref[:, ks] > 0.5, s, _NEG_INF)
         if masked:
             s = jnp.where(
-                _causal_keep(s.shape, i * bq, j * bk + c * chunk, off, 0),
+                rule.keep(s.shape, i * bq, at * bk + c * chunk, off, 0),
                 s, _NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -213,8 +256,8 @@ def _fwd_kernel(*refs, scale, causal, off, chunk, has_mask):
             p.astype(v_ref.dtype), v_ref[ks, :], _NN)
 
     for c in range(bk // chunk):
-        _run_tile(functools.partial(one, c), causal, i * bq, bq,
-                  j * bk + c * chunk, chunk, off)
+        _run_tile(functools.partial(one, c), rule, i * bq, bq,
+                  at * bk + c * chunk, chunk, off, live)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -238,17 +281,7 @@ def split_blocks(blocks):
     return tuple(blocks)
 
 
-def _key_block_map(causal, bq, bk, off, nk):
-    """(query block i, grid step j) -> the key block to fetch: j, held at
-    the last block that query block i sees, so that a step above the
-    diagonal fetches nothing new."""
-    if not causal:
-        return lambda i, j: j
-    return lambda i, j: jnp.minimum(
-        j, jnp.minimum((i * bq + bq - 1 + off) // bk, nk // bk - 1))
-
-
-def _flash_fwd(q, k, v, scale, causal, padding_mask=None, blocks=None):
+def _flash_fwd(q, k, v, scale, rule, padding_mask=None, blocks=None):
     """(out [B, Nq, H, Dv], lse [B*H, Nq]). One ``jit`` inside the
     caller's: a model's step calls this once a layer application, and
     the step's trace and lowering then take the kernel once a shape
@@ -264,30 +297,33 @@ def _flash_fwd(q, k, v, scale, causal, padding_mask=None, blocks=None):
     against 126.9 (the step without the names: 129.7; PERF.md, PR 30).
     q, k and v are not named: a block's projections and rotary run again
     for 0.015 ms a MB kept, the kernel for 0.042."""
-    b, _, h, dv = v.shape
-    out, lse = _fwd_call(q, k, v, padding_mask, scale=scale, causal=causal,
+    (b, _, h, _), dv = q.shape, v.shape[3]
+    out, lse = _fwd_call(q, k, v, padding_mask, scale=scale, rule=rule,
                          blocks=blocks, interpret=_common.interpret())
     out = checkpoint_name(out, KEPT_RESIDUALS[0])
     return _unlayout(out, b, h, dv), checkpoint_name(lse, KEPT_RESIDUALS[1])
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "causal", "blocks",
+@functools.partial(jax.jit, static_argnames=("scale", "rule", "blocks",
                                              "interpret"))
-def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
+def _fwd_call(q, k, v, padding_mask, *, scale, rule, blocks, interpret):
     b, nq, h, d = q.shape
-    nk, dv = k.shape[1], v.shape[3]
+    nk, h_kv, dv = k.shape[1], k.shape[2], v.shape[3]
     bq, bk, chunk = split_blocks(blocks)[0] or block_sizes(
-        nq, nk, max(d, dv), q.dtype)
+        *rule.sizes(nq, nk), max(d, dv), q.dtype)
     off = nk - nq
     qa, at = _layout(q)
-    ka, _ = _layout(k)
+    ka, at_k = _layout(k)
     va, at_v = _layout(v)       # v and out take the layout of their width
+    at_o = _window(h, dv)       # out: q's heads, v's width
+    kv = _kv_head(h, h_kv)
 
-    kj = _key_block_map(causal, bq, bk, off, nk)
+    steps, kj = rule.key_map(nq, nk, bq, bk)
+    _count_tiles(rule, nq, nk, bq, chunk, b * h)
     in_specs = [
         pl.BlockSpec((None, bq, d), lambda g, i, j: at(g, i)),
-        pl.BlockSpec((None, bk, d), lambda g, i, j: at(g, kj(i, j))),
-        pl.BlockSpec((None, bk, dv), lambda g, i, j: at_v(g, kj(i, j))),
+        pl.BlockSpec((None, bk, d), lambda g, i, j: at_k(kv(g), kj(i, j))),
+        pl.BlockSpec((None, bk, dv), lambda g, i, j: at_v(kv(g), kj(i, j))),
     ]
     args = [qa, ka, va]
     if padding_mask is not None:
@@ -297,12 +333,12 @@ def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
             (None, 1, bk), lambda g, i, j: (g // h, 0, kj(i, j))))
         args.append(padding_mask.astype(jnp.float32).reshape(b, 1, nk))
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, causal=causal, off=off,
+        functools.partial(_fwd_kernel, scale=scale, rule=rule, off=off,
                           chunk=chunk, has_mask=padding_mask is not None),
-        grid=(b * h, nq // bq, nk // bk),
+        grid=(b * h, nq // bq, steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, bq, dv), lambda g, i, j: at_v(g, i)),
+            pl.BlockSpec((None, bq, dv), lambda g, i, j: at_o(g, i)),
             pl.BlockSpec((None, bq, _LANES), lambda g, i, j: (g, i, 0)),
         ],
         out_shape=[
@@ -324,21 +360,21 @@ def _fwd_call(q, k, v, padding_mask, *, scale, causal, blocks, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, padding_mask, scale, causal, blocks):
-    return _flash_fwd(q, k, v, scale, causal, padding_mask, blocks)[0]
+def _flash(q, k, v, padding_mask, scale, rule, blocks):
+    return _flash_fwd(q, k, v, scale, rule, padding_mask, blocks)[0]
 
 
-def _flash_vjp_fwd(q, k, v, padding_mask, scale, causal, blocks):
+def _flash_vjp_fwd(q, k, v, padding_mask, scale, rule, blocks):
     # of the forward kernel the backward kernels need ``out`` and ``lse``:
     # the two values _flash_fwd names for a recomputation to keep
-    out, lse = _flash_fwd(q, k, v, scale, causal, padding_mask, blocks)
+    out, lse = _flash_fwd(q, k, v, scale, rule, padding_mask, blocks)
     return out, (q, k, v, padding_mask, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, blocks, res, dout):
+def _flash_vjp_bwd(scale, rule, blocks, res, dout):
     from .flash_attention_bwd import flash_attention_bwd
     q, k, v, padding_mask, out, lse = res
-    dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, dout, scale, causal,
+    dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, dout, scale, rule,
                                      padding_mask=padding_mask,
                                      blocks=blocks)
     # the mask enters as f32 0/1 (see flash_attention), so a plain zero
@@ -352,15 +388,17 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, padding_mask=None,
-                    blocks: Optional[tuple] = None):
-    """Fused attention. ``padding_mask``: optional [B, Nk] keep-mask
-    (bool/0-1); padded key positions are excluded from the softmax —
-    the Pallas analog of the reference's additive attention-mask input
-    (nn/layer/transformer.py MultiHeadAttention attn_mask). ``blocks``
-    overrides :func:`block_sizes` (tests and the sweep tool)."""
+                    blocks: Optional[tuple] = None, mask=None):
+    """Fused attention. ``mask``: a rule of ``mask_rules`` (``causal`` is
+    short for its ``CAUSAL``). ``padding_mask``: optional [B, Nk]
+    keep-mask (bool/0-1); padded key positions are excluded from the
+    softmax — the Pallas analog of the reference's additive
+    attention-mask input (nn/layer/transformer.py MultiHeadAttention
+    attn_mask). ``blocks`` overrides :func:`block_sizes` (tests and the
+    sweep tool)."""
     d = q.shape[-1]             # the scale is the key width's, v has its own
     s = float(scale) if scale is not None else float(1.0 / (d ** 0.5))
     pm = padding_mask
     if pm is not None:
         pm = jnp.asarray(pm).astype(jnp.float32)
-    return _flash(q, k, v, pm, s, causal, blocks)
+    return _flash(q, k, v, pm, s, rule_of(causal, mask), blocks)

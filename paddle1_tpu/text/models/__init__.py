@@ -9,6 +9,8 @@ from .kanana2 import (Kanana2DecoderLayer, Kanana2ForPretraining,
                       LatentAttention)
 from .ouro import (OuroDecoderLayer, OuroExitHead, OuroForPretraining,
                    OuroPretrainingCriterion, OuroStack)
+from .sdar import (SdarAttention, SdarBlockDiffusionCriterion,
+                   SdarDecoderLayer, SdarForBlockDiffusion, SdarStack)
 
 __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "BertForSequenceClassification", "ErnieModel",
@@ -17,4 +19,6 @@ __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "OuroExitHead", "OuroForPretraining", "OuroPretrainingCriterion",
            "LatentAttention", "Kanana2DecoderLayer", "Kanana2Stack",
            "Kanana2Head", "Kanana2ForPretraining",
-           "Kanana2PretrainingCriterion"]
+           "Kanana2PretrainingCriterion", "SdarAttention",
+           "SdarDecoderLayer", "SdarStack", "SdarForBlockDiffusion",
+           "SdarBlockDiffusionCriterion"]

@@ -24,8 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle1_tpu.core.flags import flags_guard
 from paddle1_tpu.ops.pallas import (_common, flash_attention, fused_bn,
-                                    layer_norm, paged_attention, softmax,
-                                    sum_picks)
+                                    layer_norm, mask_rules, paged_attention,
+                                    softmax, sum_picks)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -63,18 +63,22 @@ def for_the_chip(monkeypatch):
         compilation_cache.reset_cache()
 
 
-def _flash(causal=False, masked=False, grad=False, dtype=BF16):
+def _flash(causal=False, masked=False, grad=False, dtype=BF16, mask=None,
+           kv_heads=None):
     def build(b, s, h, d, dv=None):
-        qkv = [((b, s, h, d), dtype)] * 2 + [((b, s, h, dv or d), dtype)]
+        hk = kv_heads or h
+        qkv = [((b, s, h, d), dtype), ((b, s, hk, d), dtype),
+               ((b, s, hk, dv or d), dtype)]
         if masked:
             def fn(q, k, v, m):
                 return flash_attention.flash_attention(
-                    q, k, v, causal=causal, padding_mask=m)
+                    q, k, v, causal=causal, padding_mask=m, mask=mask)
             args = qkv + [((b, s), F32)]
         else:
             def fn(q, k, v):
                 return flash_attention.flash_attention(q, k, v,
-                                                       causal=causal)
+                                                       causal=causal,
+                                                       mask=mask)
             args = qkv
         if grad:
             fwd = fn
@@ -132,6 +136,9 @@ OURO = (2, 4096, 16, 128)   # ouro_2p6b.pretrain_s4096's attention call
 # 128-lane tile: the [B*H, N, D] layout), values 128 (the [B, N, H*D] one)
 KANANA2 = (2, 8192, 32, 192, 128)
 
+SDAR = (1, 16384, 32, 128)
+SDAR_RULE = mask_rules.BlockDiffusion(8192, 4)
+
 CASES = {
     "flash_fwd_b32_s128": lambda: _flash()(*B32_S128),
     "flash_fwd_b8_s512": lambda: _flash()(*B8_S512),
@@ -150,6 +157,20 @@ CASES = {
         lambda: _flash(causal=True)(*KANANA2),
     "flash_causal_grad_kanana2_s8192_d192_v128":
         lambda: _flash(causal=True, grad=True)(*KANANA2),
+    # SDAR's doubled row [1, 2 x 8192, 32 / 4, 128] under block
+    # diffusion's rule, both orders of the copies; and grouped heads
+    # under the rules there were
+    "flash_block_diffusion_sdar_s16384_h32_kv4":
+        lambda: _flash(mask=SDAR_RULE, kv_heads=4)(*SDAR),
+    "flash_block_diffusion_grad_sdar_s16384_h32_kv4":
+        lambda: _flash(mask=SDAR_RULE, kv_heads=4, grad=True)(*SDAR),
+    "flash_block_diffusion_clean_first_grad_s4096_h8_kv2_d64":
+        lambda: _flash(mask=mask_rules.BlockDiffusion(2048, 32, False),
+                       kv_heads=2, grad=True)(1, 4096, 8, 64),
+    "flash_causal_grad_s4096_h16_kv2_d128":
+        lambda: _flash(causal=True, kv_heads=2, grad=True)(1, 4096, 16, 128),
+    "flash_grad_s2048_h8_kv1_d192_v128":
+        lambda: _flash(kv_heads=1, grad=True)(1, 2048, 8, 192, 128),
     # the widest operand block supported() admits: VMEM's worst case
     "flash_grad_f32_s4096_d256":
         lambda: _flash(grad=True, dtype=F32)(1, 4096, 2, 256),
@@ -377,6 +398,69 @@ def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
     # the rest of the block is still run again in the backward pass
     assert "/rematted_computation/" in text
     assert not [c for c in calls if "/rematted_computation/" in c]
+
+
+def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
+        one_chip, for_the_chip, monkeypatch):
+    """One decoder block of SDAR's step at the cell's shape ([1, 2 x 8192,
+    2048] bf16, 32 query heads over 4 key/value heads of 128, blocks of 4,
+    16 of 128 experts, top-8) under ``fleet.utils.recompute``, loss and
+    gradients (ISSUE 33): its attention is the three blockwise kernels
+    under block diffusion's rule, the forward one not run again; the
+    kernels read k and v 4 heads wide and dK/dV writes them so (no copy
+    per query head); nothing in the text is shaped like the dense mask or
+    the scores of the doubled row; the sum of a token's 8 picks is the
+    kernel."""
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle1_tpu.text.models import SdarDecoderLayer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    length = 8192
+    layer = SdarDecoderLayer(
+        2048, dict(num_heads=32, num_kv_heads=4, head_dim=128,
+                   block_length=4),
+        dict(expert_width=768, num_experts=128, top_k=8, held=(0, 16)))
+    state = {k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
+             for k, v in layer.state_dict().items()}
+
+    def loss(state, x):
+        at = jnp.tile(jnp.arange(length, dtype=I32), 2)
+        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes(), \
+                layer.load_functional_state(state):
+            out = recompute(layer, Tensor(x), Tensor(at))
+        return (out.data.astype(F32) ** 2).mean()
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            state, jax.ShapeDtypeStruct((1, 2 * length, 2048), BF16,
+                                        sharding=one_chip)
+        ).compile().as_text()
+    calls = {re.search(r"%\w*?(p1t_[a-z_]*[a-z])", c).group(1): c
+             for c in re.findall(
+                 r'^.*custom_call_target="tpu_custom_call".*$', text, re.M)
+             if "p1t_" in c.split(" = ")[0]}
+    assert sorted(calls) == ["p1t_flash_attention_bwd_dkv",
+                             "p1t_flash_attention_bwd_dq",
+                             "p1t_flash_attention_fwd", "p1t_sum_picks_fwd"]
+    assert len(re.findall(r"%\w*p1t_flash_attention_fwd[.\d]* = ", text)) == 1
+    narrow, wide = "bf16[1,16384,512]", "bf16[1,16384,4096]"
+
+    def operands(call):
+        return re.findall(r"(?:bf16|f32)\[[\d,]*\]", re.search(
+            r"operand_layout_constraints=\{(.*?)\}\}", call).group(1))
+    assert operands(calls["p1t_flash_attention_fwd"]) == [wide, narrow,
+                                                          narrow]
+    for kernel in ("p1t_flash_attention_bwd_dkv",
+                   "p1t_flash_attention_bwd_dq"):
+        assert operands(calls[kernel])[:4] == [wide, narrow, narrow, wide]
+    # dK/dV writes 4 heads, summed over each one's 8 query heads inside
+    assert calls["p1t_flash_attention_bwd_dkv"].split(" custom-call(")[0] \
+        .count(narrow) == 2
+    # no dense mask, no scores of the doubled row, in any layout
+    assert not re.search(r"\[(\d+,)*16384,16384\]", text)
+    assert not re.search(r"\bwhile\(", text)
+    assert "/rematted_computation/" in text
 
 
 def test_sum_picks_supported_admits_only_what_fits():

@@ -9,14 +9,19 @@ composition: query chunk i against keys [0, (i+1) * chunk)), ``jax``
 (``jax.experimental.pallas.ops.tpu.flash_attention`` in its own
 [B, H, N, D] layout, the yardstick).
 
-    python tools/tpu_flash_crossover.py [--part blocks|lengths|latent|all]
+    python tools/tpu_flash_crossover.py [--part blocks|lengths|latent|diffusion|all]
 
 ``blocks``: the Ouro shape [2, 4096, 16, 128] bf16 causal, each kernel
 alone under each block triple. ``lengths``: 8192 tokens at sequence
 lengths 512 .. 8192 (d 128 and d 64, causal and not, and BERT's two
 shapes), kernel against dense: the crossover. ``latent`` (PR 31): the
 same at keys 192 and values 128 wide (latent attention), causal, at 4096
-and 8192.
+and 8192. ``diffusion`` (PR 33): block diffusion's mask rule with grouped
+key/value heads at SDAR's size, a doubled row [1, 2 x 8192, 32 / 4, 128]
+with blocks of 4: each kernel alone under a few block triples, and forward
++ backward beside the same shape under a causal mask over the 16384 (twice
+the visible pairs, and not the model's mask: what the rule saves). Dense
+does not fit there (1 GiB of float32 scores a head).
 Writes chiprun_out/flash_sweep.json beside the table.
 """
 
@@ -130,43 +135,74 @@ TRIPLES = [(256, 512, 512), (512, 512, 512), (512, 1024, 512),
            (1024, 4096, 1024)]
 
 
-def part_blocks(rows, shape=(2, 4096, 16, 128), triples=TRIPLES,
-                jax_blocks=(512, 1024)):
-    """Each kernel alone at the Ouro shape, under each block triple."""
+def _one(rows, part, name, f, *a):
+    try:
+        ms = _ms(f, *a)
+    except Exception as e:  # noqa: broad-except — a triple Mosaic
+        # refuses is a row of the table, not the end of the sweep
+        ms = None
+        print(f"  {name}: refused: {str(e)[-300:]}", flush=True)
+    rows.append({"part": part, "arm": name, "ms": ms})
+    print(f"  {name}: {ms}", flush=True)
+
+
+def _kernels_alone(rows, part, inputs, rule, triples):
+    """Each of the three kernels alone under each block triple."""
     import jax
-    import jax.numpy as jnp
     from paddle1_tpu.ops.pallas import flash_attention as fa
     from paddle1_tpu.ops.pallas.flash_attention_bwd import \
         flash_attention_bwd
-    q, k, v, do = _inputs(*shape, jnp.bfloat16)
-    scale = shape[-1] ** -0.5
+    q, k, v, do = inputs
+    scale = q.shape[-1] ** -0.5
     out, lse = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, scale,
-                                                     True))(q, k, v)
-
-    def one(name, f, *a):
-        try:
-            ms = _ms(f, *a)
-        except Exception as e:  # noqa: broad-except — a triple Mosaic
-            # refuses is a row of the table, not the end of the sweep
-            ms = None
-            print(f"  {name}: refused: {str(e)[-300:]}", flush=True)
-        rows.append({"part": "blocks", "arm": name, "ms": ms})
-        print(f"  {name}: {ms}", flush=True)
-
+                                                     rule))(q, k, v)
     for t in triples:
-        one(f"fwd {t}", jax.jit(lambda q, k, v, t=t: fa._flash_fwd(
-            q, k, v, scale, True, blocks=t)[0]), q, k, v)
+        _one(rows, part, f"fwd {t}",
+             jax.jit(lambda q, k, v, t=t: fa._flash_fwd(
+                 q, k, v, scale, rule, blocks=t)[0]), q, k, v)
     for t in triples:
         def dkv(q, k, v, out, lse, do, t=t):
             _, dk, dv = flash_attention_bwd(q, k, v, out, lse, do, scale,
-                                            True, blocks=(None, t, None))
+                                            rule, blocks=(None, t, None))
             return dk, dv
 
         def dq(q, k, v, out, lse, do, t=t):
-            return flash_attention_bwd(q, k, v, out, lse, do, scale, True,
+            return flash_attention_bwd(q, k, v, out, lse, do, scale, rule,
                                        blocks=(None, None, t))[0]
-        one(f"dkv {t}", jax.jit(dkv), q, k, v, out, lse, do)
-        one(f"dq {t}", jax.jit(dq), q, k, v, out, lse, do)
+        _one(rows, part, f"dkv {t}", jax.jit(dkv), q, k, v, out, lse, do)
+        _one(rows, part, f"dq {t}", jax.jit(dq), q, k, v, out, lse, do)
+
+
+def part_diffusion(rows, length=8192, block=4, heads=32, kv_heads=4, dim=128,
+                   triples=((512, 1024, 512), (512, 512, 512),
+                            (1024, 1024, 512), (512, 2048, 512))):
+    """Block diffusion's rule with grouped key/value heads at SDAR's
+    size; beside it a causal mask over the doubled row."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.ops.pallas import flash_attention as fa
+    from paddle1_tpu.ops.pallas.mask_rules import CAUSAL, BlockDiffusion
+    keys = jax.random.split(jax.random.key(0), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, 2 * length, h, dim),
+                                     jnp.bfloat16)
+                   for kk, h in zip(keys, (heads, kv_heads, kv_heads, heads)))
+    rule = BlockDiffusion(length, block)
+    _kernels_alone(rows, "diffusion", (q, k, v, do), rule, triples)
+    for name, mask in (("block diffusion", rule), ("causal over 2L", CAUSAL)):
+        attn = functools.partial(fa.flash_attention, mask=mask)
+        _one(rows, "diffusion", f"{name} fwd", _fwd(attn), q, k, v, do)
+        _one(rows, "diffusion", f"{name} fwd+bwd", _fwd_bwd(attn), q, k, v,
+             do)
+
+
+def part_blocks(rows, shape=(2, 4096, 16, 128), triples=TRIPLES,
+                jax_blocks=(512, 1024)):
+    """Each kernel alone at the Ouro shape, under each block triple."""
+    import jax.numpy as jnp
+    from paddle1_tpu.ops.pallas.mask_rules import CAUSAL
+    q, k, v, do = _inputs(*shape, jnp.bfloat16)
+    _kernels_alone(rows, "blocks", (q, k, v, do), CAUSAL, triples)
+    one = functools.partial(_one, rows, "blocks")
     for name, attn in arms(True).items():
         one(f"{name} fwd", _fwd(attn), q, k, v, do)
         one(f"{name} fwd+bwd", _fwd_bwd(attn), q, k, v, do)
@@ -205,8 +241,8 @@ def part_lengths(rows, tokens=8192, lengths=(512, 1024, 2048, 4096, 8192),
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--part", choices=("blocks", "lengths", "latent", "all"),
-                    default="all")
+    ap.add_argument("--part", choices=("blocks", "lengths", "latent",
+                                       "diffusion", "all"), default="all")
     ap.add_argument("--triples", default="",
                     help="'512,2048,512;1024,2048,512': only these")
     args = ap.parse_args()
@@ -223,6 +259,8 @@ def main():
     if args.part in ("latent", "all"):
         part_lengths(rows, lengths=(4096, 8192), extra=(),
                      widths=((16, 192, 128, (True,)),))
+    if args.part in ("diffusion", "all"):
+        part_diffusion(rows)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/flash_sweep.json", "w") as f:
         json.dump({"device": [dev.platform, dev.device_kind],

@@ -66,10 +66,11 @@ def main():
     scale = 1.0 / (q.shape[-1] ** 0.5)
 
     def bwd_pair(causal, mask):
-        out, lse = fa_mod._flash_fwd(q, k, v, scale, causal,
+        rule = fa_mod.rule_of(causal, None)
+        out, lse = fa_mod._flash_fwd(q, k, v, scale, rule,
                                      padding_mask=mask)
         got = flash_attention_bwd(q, k, v, out, lse, dout, scale,
-                                  causal, padding_mask=mask)
+                                  rule, padding_mask=mask)
         m4 = None if mask is None else mask[:, None, None, :] > 0.5
         want = jax.vjp(lambda q, k, v: attention_ref(
             q, k, v, mask=m4, is_causal=causal), q, k, v)[1](dout)
