@@ -205,7 +205,7 @@ def kernel_phase():
             return jax.jit(fn)(*[a.astype(f32) if a.dtype == bf16 else a
                                  for a in args])
 
-    # flash attention, forward and both backward kernels: BERT's width
+    # flash attention, the forward and the backward kernel: BERT's width
     # (d 64: the transposed layout), Ouro's causal [2,4096,16,128]
     # (d 128: the packed layout, blocks above the diagonal skipped) and
     # latent attention's keys 192 / values 128 wide (one layout each)
@@ -239,9 +239,9 @@ def kernel_phase():
         grad = jax.jit(jax.grad(loss_of(attn), argnums=(0, 1, 2)))
         text = grad.lower(q, k, v).compile().as_text()
         n_calls = text.count('custom_call_target="tpu_custom_call"')
-        check(n_calls == 3 and " while(" not in text,
+        check(n_calls == 2 and " while(" not in text,
               f"flash grad {at} compiled to {n_calls} tpu_custom_calls "
-              "(forward, dk/dv, dq) and no while")
+              "(forward, backward) and no while")
         want = by_row(lambda *a: ref(jax.grad(loss_of(plain),
                                               argnums=(0, 1, 2)), *a),
                       q, k, v)
@@ -303,7 +303,6 @@ def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
     import jax
     import jax.numpy as jnp
     from benchmarks import trace_reduce
-    from benchmarks.model_flops.sdar_30b_a3b import KERNELS
     from benchmarks.reducers.kernel_mxu_pct import seconds_a_step
     from paddle1_tpu.ops.pallas import flash_attention
     from paddle1_tpu.ops.pallas.mask_rules import BlockDiffusion
@@ -354,7 +353,7 @@ def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
     got = vjp_of(kernels)
     text = got.lower(q, k, v, dout).compile().as_text()
     n_calls = text.count('custom_call_target="tpu_custom_call"')
-    check(n_calls == 3 and " while(" not in text,
+    check(n_calls == 2 and " while(" not in text,
           f"flash grad {at} compiled to {n_calls} tpu_custom_calls and no "
           "while")
     with jax.default_matmul_precision("highest"):
@@ -378,7 +377,10 @@ def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
     views = trace_reduce.views(trace)
     check(len(views) == 1 and len(views[0]["step_s"]) == calls,
           f"the trace holds the {calls} calls of one chip")
-    for kernel, took in seconds_a_step(views[0], KERNELS).items():
+    # the backward kernel keeps the name dK/dV had (PERF.md section 7)
+    for kernel, took in seconds_a_step(views[0], (
+            "p1t_flash_attention_fwd",
+            "p1t_flash_attention_bwd_dkv")).items():
         check(took > 0, f"{kernel} is in the trace")
         print(f"chip_smoke: {kernel} {at}: {1e3 * took:.3f} ms a call "
               "(smoke reading, not a metric)", flush=True)

@@ -116,37 +116,42 @@ def use_flash_for(q, k) -> bool:
     moves no crossing in the sweep below, so the rule reads the
     sequences alone.
 
-    The sweep (tools/tpu_flash_crossover.py on a TPU v5 lite, PR 28:
-    one call's forward + backward in isolation, bf16, 8192 tokens a
-    call, ms dense / kernel; the kernels fetched 2048 rows a grid step
-    then, and read about 5% more with the 1024 they ship with, which
-    moves no crossing):
+    The sweep (tools/tpu_flash_crossover.py on a TPU v5 lite, re-run
+    2026-09-30 with PR 35's one backward kernel and the shipped 1024-row
+    fetched blocks: one call's forward + backward in isolation, bf16,
+    8192 tokens a call, ms dense / kernel; PR 28's table, with two
+    backward kernels, read 2.10, 2.88, 3.81, 5.92 and 10.24 for the
+    kernels in the first column):
 
     ====== ============= ============= ============= ============= ==================
     seq    d128 causal   d128 full     d64 causal    d64 full      d192 / v128 causal
     ====== ============= ============= ============= ============= ==================
-    512    2.00 / 2.10   2.00 / 2.10   0.75 / 1.26   0.75 / 1.27   not measured
-    1024   4.11 / 2.88   4.10 / 2.93   2.94 / 1.74   2.86 / 1.89   not measured
-    2048   7.47 / 3.81   7.44 / 4.52   5.29 / 2.42   5.27 / 3.09   not measured
-    4096   14.00 / 5.92  13.87 / 8.35  10.26 / 3.90  10.21 / 5.74  14.32 / 8.78
-    8192   30.34 / 10.24 28.24 / 15.39 20.89 / 6.71  20.64 / 10.69 29.40 / 14.77
+    512    2.00 / 1.74   2.01 / 1.75   0.75 / 1.07   0.74 / 1.08   not measured
+    1024   4.10 / 2.29   4.09 / 2.46   2.93 / 1.36   2.85 / 1.54   not measured
+    2048   7.47 / 3.16   7.45 / 4.01   5.28 / 1.97   5.26 / 2.60   not measured
+    4096   13.99 / 4.91  13.86 / 6.85  10.25 / 3.15  10.21 / 4.66  14.31 / 6.89
+    8192   30.34 / 8.41  28.38 / 12.91 20.90 / 5.36  20.65 / 8.68  29.39 / 11.89
     ====== ============= ============= ============= ============= ==================
 
-    The last column (PR 31, the shipped 1024-row blocks, 16 heads):
-    keys 192 and values 128 wide, latent attention's shape. 192 is no
-    multiple of the 128-lane tile, so q, k, dq and dk take the kernels'
-    transposed [B*H, N, D] layout and pay XLA transposes that the
-    128-wide column does not: 1.25 x its products, 1.45 x its time.
-    The kernels still halve dense at 8192, where a 32-head row of dense
-    scores (8.6 GB) does not fit at all.
+    The last column (16 heads): keys 192 and values 128 wide, latent
+    attention's shape. 192 is no multiple of the 128-lane tile, so q, k,
+    dq and dk take the kernels' transposed [B*H, N, D] layout and pay
+    XLA transposes that the 128-wide column does not: 1.25 x its
+    products, 1.4 x its time. The kernels still take under half of
+    dense's time at 8192, where a 32-head row of dense scores (8.6 GB)
+    does not fit at all.
 
-    Dense wins at 512 and below whatever the mask (BERT's [64,512,12,64]
-    5.46 / 6.14, [256,128,12,64] 1.61 / 7.90: one or no key block to
-    skip, and at d 64 the kernels pay XLA transposes), the kernels from
-    1024 up, causal or not, by more the longer the sequence. In a step:
-    ``ouro_2p6b.pretrain_s4096`` 1257.3 -> 966.9 ms (PERF.md, PR 28);
+    Dense wins at 512 at d 64 whatever the mask, and at BERT's shapes
+    ([64,512,12,64] 5.47 / 5.44, level; [256,128,12,64] 1.62 / 6.50:
+    one or no key block to skip, and at d 64 the kernels pay XLA
+    transposes); at d 128 the kernels are now ahead at 512 too (by 13%;
+    PR 28 had dense ahead by 5%), and from 1024 up, causal or not, by
+    more the longer the sequence. **The rule stays where PR 28 set it**:
+    no cell runs d 128 at 512, a crossing moves on a step's evidence, and
     ``bert_base.pretrain_s512`` forced onto the kernels gained 0.27%
-    end to end, under the 1% that would have moved the rule."""
+    end to end (under the 1% that would have moved the rule) and failed
+    the benchmark's comparison on the bf16 ``delta``. In a step:
+    ``ouro_2p6b.pretrain_s4096`` 1257.3 -> 966.9 ms (PERF.md, PR 28)."""
     from ...core.flags import flag, flag_active
     if not flag_active("flash_attention"):
         return False

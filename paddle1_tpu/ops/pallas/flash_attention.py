@@ -5,10 +5,17 @@ Replaces the reference's fused multihead attention CUDA kernels
 TPU idiom: online-softmax blocking in VMEM, logits never in HBM.
 
 Layout: [B, N, H, D] (paddle layout, matching nn.functional.attention).
-This file holds the forward kernel and the entry point; the two
-backward kernels are in flash_attention_bwd.py. All three share one
-blocked grid ``(batch*heads, outer blocks, inner blocks)`` with the
-inner axis sequential and accumulators in VMEM scratch; a grid step
+This file holds the forward kernel and the entry point; the one
+backward kernel (dQ, dK and dV from a score tile made once) is in
+flash_attention_bwd.py. Both walk one blocked grid, query-major: a query
+block resident while the key blocks it sees pass along the innermost,
+sequential axis, accumulators in VMEM scratch. **Resident**: the forward
+keeps a query block with its running max, sum and output ([BQ, D]); the
+backward a query block with its dout, LSE, delta and dQ, and besides,
+for every query block and query head of its group, one key head's whole
+dK and dV in float32 ([Nk, D] + [Nk, Dv]: 8 MiB at 4096 keys of 128, 32
+at SDAR's 16,384, with the blocks they are written back through), for
+which it raises its own scoped-VMEM limit. A grid step
 takes its fetched block a chunk at a time, and under a mask rule
 (``mask_rules.py``: bottom-right causal, query ``r`` sees keys ``<= r +
 nk - nq``, or block diffusion's mask over a noisy and a clean copy of a
@@ -22,16 +29,18 @@ row) a score tile (resident block x chunk) is one of three kinds:
 * crossed: the body with the rule's element-wise keep.
 
 k and v may have fewer heads than q (grouped-query attention: ``H_kv``
-divides ``H``): the forward and dQ kernels read key/value head ``h //
-(H / H_kv)`` through the index map, dK/dV passes a group's query heads
-one after the other along its sequential axis and writes ``H_kv`` heads.
-No copy of k or v per query head is made anywhere.
+divides ``H``): the forward kernel reads key/value head ``h // (H /
+H_kv)`` through the index map, the backward kernel's grid runs over the
+key heads and passes a group's query heads one after the other along
+its query axis while that key head's dK and dV stay resident, and writes
+``H_kv`` heads. No copy of k or v per query head is made anywhere.
 
 Precision is the caller's: the products take q/k/v/dout in the dtype
 they arrive in (bf16 under AMP) and accumulate in float32; max, sum and
 LSE are float32; the probabilities (and ``ds`` in the backward) are
-rounded to the operand dtype once, before their product. The softmax
-scale is folded into q (forward, dQ) or k (dK/dV) once a resident block.
+rounded to the operand dtype once, before their products. The softmax
+scale is folded into q once a resident block (and into dQ and dK as they
+are stored).
 
 q and k share one head width; v has its own (latent attention: keys
 192 wide, values 128), which ``out``, ``dout``, ``delta`` and dV follow
@@ -80,7 +89,10 @@ def rule_of(causal: bool, mask):
 def supported(q_shape, k_shape, causal: bool = False,
               v_shape=None, mask=None) -> bool:
     """Tile-aligned shapes only; everything else uses attention_ref.
-    No VMEM gate: no kernel keeps more than a block of any operand.
+    No VMEM gate: the forward keeps no more than a block of any operand,
+    and the backward, which keeps a key head's dK and dV resident, takes
+    the keys a range at a time where they would not fit
+    (``flash_attention_bwd.key_span``).
     Assumes that q and k share one head width and that v (``v_shape``,
     k's where None) has one of its own, each a multiple of 8 up to 256;
     k's heads divide q's. ``mask``: a rule of ``mask_rules``; a rule
@@ -123,10 +135,10 @@ def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
     of Ouro's step make its executable 137.0 MiB in the compile cache
     against 129.7 at 1024 (the parent's dense step: 130.7), which a
     capped cache then fails to hold beside the other programs of a run.
-    And no more than 512 KiB an operand: dK/dV fetches two, double
-    buffered, beside two resident blocks, two outputs and the score
-    tiles in 16 MiB of scoped VMEM (float32 at d 256 is refused from
-    1 MiB up)."""
+    And no more than 512 KiB an operand: the forward fetches two, double
+    buffered, beside a resident block, its output and the score tiles in
+    the default 16 MiB of scoped VMEM (the backward, which takes these
+    sizes too, sets its own limit: ``flash_attention_bwd._vmem_bytes``)."""
     rows = (512 << 10) // (d * jnp.dtype(dtype).itemsize)
     fetched = tuple(b for b in (1024, 512, 256, 128) if b <= rows)
     return (_rung(nq, (512, 256, 128)), _rung(nk, fetched),
@@ -199,7 +211,10 @@ def _run_tile(body, rule, q0, bq, k0, bk, off, live=None):
     rule's grid has no other)."""
     needed, full = rule.tile(q0, bq, k0, bk, off)   # some, every pair seen
     if full is True:        # the trace knows: a rule that hides nothing
-        body(False)
+        if live is None:
+            body(False)
+        else:
+            pl.when(live)(lambda: body(False))
         return
     if live is not None:
         needed, full = live & needed, live & full
@@ -274,10 +289,10 @@ def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
 
 
 def split_blocks(blocks):
-    """``blocks`` as (forward, dK/dV, dQ) triples: None, one
-    (resident, fetched, chunk) triple for all three kernels, or three."""
+    """``blocks`` as (forward, backward) triples: None, one (block_q,
+    block_k, chunk) triple for both kernels, or two."""
     if blocks is None or isinstance(blocks[0], int):
-        return blocks, blocks, blocks
+        return blocks, blocks
     return tuple(blocks)
 
 
@@ -365,7 +380,7 @@ def _flash(q, k, v, padding_mask, scale, rule, blocks):
 
 
 def _flash_vjp_fwd(q, k, v, padding_mask, scale, rule, blocks):
-    # of the forward kernel the backward kernels need ``out`` and ``lse``:
+    # of the forward kernel the backward kernel needs ``out`` and ``lse``:
     # the two values _flash_fwd names for a recomputation to keep
     out, lse = _flash_fwd(q, k, v, scale, rule, padding_mask, blocks)
     return out, (q, k, v, padding_mask, out, lse)

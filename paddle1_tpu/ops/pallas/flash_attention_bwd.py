@@ -1,21 +1,36 @@
-"""Pallas flash-attention BACKWARD kernels (FlashAttention-2 split), on
-the blocked grid of flash_attention.py: logits and probabilities never
-touch HBM, no kernel keeps a whole row of any operand, and the blocks a
-mask rule hides cost nothing.
+"""Pallas flash-attention BACKWARD kernel, on the blocked grid of
+flash_attention.py: one ``pallas_call`` makes a score tile's ``s``,
+``p = exp(s - lse)``, ``dp = dout v^T`` and ``ds = p (dp - delta)`` once
+and accumulates dQ, dK and dV from them (five score-shaped products and
+one exponent a tile). Logits and probabilities never touch HBM, and the
+blocks a mask rule hides cost nothing.
 
-* ``_dkv_kernel``: grid (batch·key head, k-block, q-block); a k-block
-  stays resident while the q-blocks that see it pass (from the diagonal
-  down under the causal rule), dK and dV accumulate in VMEM. Where a
-  key head serves a group of query heads, the group's heads pass one
-  after the other along the same sequential axis. The scores are
-  computed transposed ([BK, BQ]) so that every product is a plain ``a @
-  b`` or ``a @ b.T``.
-* ``_dq_kernel``: grid (batch·head, q-block, k-block); a q-block stays
-  resident while the k-blocks it sees pass.
+Grid (batch·key head, key range, group·q-block, k-block), query-major: a
+q-block (with its dout, LSE and delta) stays resident while the k-blocks
+it sees pass, as in the forward kernel, and dQ accumulates in a [BQ, D]
+float32 scratch. **dK and dV of one key head stay resident in VMEM for
+the whole of its sequence** ([Nk, D] + [Nk, Dv] float32, written at the
+rows of the fetched chunk) while every q-block of every query head of
+its group passes, and are stored once, after the last: no partial
+gradient is written to HBM. The scores are computed transposed ([chunk of
+keys, BQ]) so that dK, dV, ``s`` and ``dp`` are plain ``a @ b`` or ``a @
+b.T`` and dQ alone contracts over the first axis of both operands, and so
+that LSE and delta are read as the lane-dense [1, BQ] rows they arrive
+as.
 
-Both consume the forward's LSE and ``delta = rowsum(dout * out)``
-(computed in XLA, one fused reduction) as [batch·head, 1, Nq] rows, lane
-dense; the dQ kernel turns its block of them into a column once.
+What is resident sets the scoped VMEM limit (:func:`_vmem_bytes`, a
+reckoning from the shapes; the v5e has 128 MiB and the default is 16):
+47.2 MiB at SDAR's 16,384 keys of 128 + 128, of which Mosaic uses 35.6
+(the float32 dK and dV 16, the bf16 blocks they are stored through,
+held twice by the pipeline, 16 more); 40.6 and 29.0 at Kanana-2's 8,192
+x (192 + 128); 23.2 and 11.4 at Ouro's 4,096 (compiled for a described
+v5e: ``tests/test_chip_compile.py``). A sequence whose gradients do not
+fit :data:`_VMEM_CAP` takes the same kernel a key range at a time along
+the grid's second axis: the range's rows resident, every q-block passing
+once a range, and dQ a float32 partial a range, summed in XLA.
+
+``delta = rowsum(dout * out)`` is computed in XLA (one fused reduction)
+and arrives with the forward's LSE as [batch·head, 1, Nq] rows.
 """
 
 from __future__ import annotations
@@ -29,116 +44,129 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
 from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_tiles,
-                              _dot, _kv_head, _lanes, _layout, _run_tile,
-                              _unlayout, split_blocks)
-from .flash_attention import block_sizes as forward_block_sizes
+                              _dot, _layout, _run_tile, _unlayout,
+                              block_sizes, split_blocks)
 
 __all__ = ["flash_attention_bwd", "block_sizes"]
 
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+# what one call may ask of the v5e's 128 MiB of VMEM: past it the keys
+# are taken a range at a time
+_VMEM_CAP = 96 << 20
+# ``block_sizes`` (block_q, block_k, chunk: the resident query block, the
+# key block fetched a grid step, the slice of it a pass of the body takes)
+# is the forward's, from the same sweep and for its reasons; what it does
+# not bound is the resident dK and dV: :func:`_vmem_bytes`.
 
-def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
-    """((block_k, block_q, chunk) of dK/dV, (block_q, block_k, chunk) of
-    dQ): resident block, block fetched a grid step, slice of it a pass of
-    the body takes. From the same sweep as the forward's, and the same
-    ladders; ``d`` is the wider of the key and the value head widths."""
-    bq, bk, chunk_k = forward_block_sizes(nq, nk, d, dtype)
-    _, bq_long, chunk_q = forward_block_sizes(nk, nq, d, dtype)
-    return (chunk_k, bq_long, chunk_q), (bq, bk, chunk_k)
+
+def _vmem_bytes(span, bq, bk, chunk, d, dv, dtype):
+    """The VMEM one call needs with ``span`` keys' gradients resident,
+    reckoned from its shapes (a width takes whole 128-lane tiles, a
+    [1, n] row 8 sublanes; what the pipeline fetches or writes back is
+    held twice; a padding mask's column is counted whether there is one
+    or not), and no less than the default 16 MiB."""
+    size = jnp.dtype(dtype).itemsize
+    wide = sum(-(-w // _LANES) * _LANES for w in (d, dv))
+    resident = span * wide * 4                      # dk_acc, dv_acc
+    written = 2 * span * wide * size                # the dK and dV blocks
+    fetched = 2 * (bq + bk) * wide * size           # q, dout, k, v
+    rows = 2 * 2 * 8 * bq * 4 + 2 * bk * _LANES * 4  # lse, delta; the mask
+    query = bq * -(-d // _LANES) * _LANES * (3 * size + 4)  # qs, dQ, dq_acc
+    tiles = 8 * chunk * bq * 4                      # s, p, dp, ds and casts
+    need = resident + written + fetched + rows + query + tiles + (4 << 20)
+    return max(need, 16 << 20)      # never under the compiler's default
 
 
-def _dkv_kernel(*refs, scale, rule, off, chunk, has_mask, steps):
-    # k_ref [BK, D], v_ref [BK, Dv] (resident); q_ref [BQ, D], do_ref
-    # [BQ, Dv]; lse_ref/delta_ref: [1, BQ]; mask_ref: [BK, 1]
+def key_span(nk, bq, bk, chunk, d, dv, dtype) -> int:
+    """The keys whose dK and dV one pass keeps resident: all ``nk`` where
+    they fit :data:`_VMEM_CAP` (every shape a model here runs), else the
+    largest halving of them that does and that whole fetched blocks
+    make up."""
+    span = nk
+    while (_vmem_bytes(span, bq, bk, chunk, d, dv, dtype) > _VMEM_CAP
+           and span % (2 * bk) == 0):
+        span //= 2
+    return span
+
+
+def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, blocks_q, span,
+                ranges):
+    # q_ref [BQ, D], do_ref [BQ, Dv], lse_ref/delta_ref [1, BQ] (resident);
+    # k_ref [BK, D], v_ref [BK, Dv]; mask_ref [BK, 1]; dk_ref [span, D],
+    # dv_ref [span, Dv] with their float32 accumulators
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     mask_ref = refs[6] if has_mask else None
-    dk_ref, dv_ref, ks_ref, dk_acc, dv_acc = refs[6 + has_mask:]
-    j, t = pl.program_id(1), pl.program_id(2)
+    dq_ref, dk_ref, dv_ref, qs_ref, dq_acc, dk_acc, dv_acc = \
+        refs[6 + has_mask:]
+    r, t, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     bq = q_ref.shape[0]
     bk = k_ref.shape[0]
-    # a group's query heads pass one after the other, ``steps`` each
-    i = t if steps is None else t % steps
-    at, live = rule.query_blocks(j, i, bk, bq)
-
-    @pl.when(t == 0)
-    def _():
-        ks_ref[...] = (k_ref[...] * scale).astype(ks_ref.dtype)
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    def one(c, masked):
-        qs = pl.ds(c * chunk, chunk)
-        q, do = q_ref[qs, :], do_ref[qs, :]
-        s = _dot(ks_ref[...], q, _NT)                        # [BK, C]
-        if has_mask:
-            s = jnp.where(mask_ref[...] > 0.5, s, _NEG_INF)
-        if masked:
-            s = jnp.where(
-                rule.keep(s.shape, at * bq + c * chunk, j * bk, off, 1),
-                s, _NEG_INF)
-        # lse is +inf for fully-masked rows (remapped by the wrapper):
-        # p underflows to an exact 0 there
-        p = jnp.exp(s - lse_ref[:, qs])
-        dv_acc[...] += _dot(p.astype(do.dtype), do, _NN)
-        dp = _dot(v_ref[...], do, _NT)
-        ds = p * (dp - delta_ref[:, qs])
-        dk_acc[...] += _dot(ds.astype(q.dtype), q, _NN)
-
-    for c in range(bq // chunk):
-        _run_tile(functools.partial(one, c), rule, at * bq + c * chunk,
-                  chunk, j * bk, bk, off, live)
-
-    @pl.when(t == pl.num_programs(2) - 1)
-    def _():
-        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _column(row_ref):
-    """A [1, N] block as a lane-replicated [N, 128] value."""
-    col = jnp.expand_dims(row_ref[0], -1)
-    return jnp.broadcast_to(col, (col.shape[0], _LANES))
-
-
-def _dq_kernel(*refs, scale, rule, off, chunk, has_mask):
-    # q_ref [BQ, D], do_ref [BQ, Dv] resident; k_ref [BK, D], v_ref
-    # [BK, Dv]; lse_ref/delta_ref: [1, BQ]; mask_ref: [1, BK]
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    mask_ref = refs[6] if has_mask else None
-    dq_ref, qs_ref, lse_col, delta_col, dq_acc = refs[6 + has_mask:]
-    i, j = pl.program_id(1), pl.program_id(2)
-    bq = q_ref.shape[0]
-    bk = k_ref.shape[0]
+    # a group's query heads pass one after the other, ``blocks_q`` each
+    i = t if blocks_q is None else t % blocks_q
     at, live = rule.key_blocks(i, j, bq, bk)
+    base = 0                    # the first key of this step's range
+    if ranges > 1:
+        base = r * span
+        mine = (at * bk >= base) & (at * bk < base + span)
+        live = mine if live is None else live & mine
+    first = j == 0
+    last = j == pl.num_programs(3) - 1
 
-    @pl.when(j == 0)
+    def rows_of(n):
+        return pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
+
+    @pl.when(first & (t == 0))
+    def _():
+        def zero(n, _):
+            dk_acc[rows_of(n), :] = jnp.zeros((chunk, dk_acc.shape[1]),
+                                              jnp.float32)
+            dv_acc[rows_of(n), :] = jnp.zeros((chunk, dv_acc.shape[1]),
+                                              jnp.float32)
+        jax.lax.fori_loop(0, span // chunk, zero, None)
+
+    @pl.when(first)
     def _():
         qs_ref[...] = (q_ref[...] * scale).astype(qs_ref.dtype)
-        lse_col[...] = _column(lse_ref)
-        delta_col[...] = _column(delta_ref)
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def one(c, masked):
         ks = pl.ds(c * chunk, chunk)
-        k = k_ref[ks, :]
-        s = _dot(qs_ref[...], k, _NT)
+        k, v = k_ref[ks, :], v_ref[ks, :]
+        q, do = q_ref[...], do_ref[...]
+        s = _dot(k, qs_ref[...], _NT)                        # [C, BQ]
         if has_mask:
-            s = jnp.where(mask_ref[:, ks] > 0.5, s, _NEG_INF)
+            s = jnp.where(mask_ref[ks, :] > 0.5, s, _NEG_INF)
         if masked:
             s = jnp.where(
-                rule.keep(s.shape, i * bq, at * bk + c * chunk, off, 0),
+                rule.keep(s.shape, i * bq, at * bk + c * chunk, off, 1),
                 s, _NEG_INF)
-        p = jnp.exp(s - _lanes(lse_col[...], chunk))
-        dp = _dot(do_ref[...], v_ref[ks, :], _NT)
-        ds = p * (dp - _lanes(delta_col[...], chunk))
-        dq_acc[...] += _dot(ds.astype(k.dtype), k, _NN)
+        # lse is +inf for fully-masked rows (remapped by the wrapper):
+        # p underflows to an exact 0 there
+        p = jnp.exp(s - lse_ref[...])
+        dp = _dot(v, do, _NT)
+        ds = (p * (dp - delta_ref[...])).astype(q.dtype)
+        here = pl.ds(pl.multiple_of(at * bk + c * chunk - base, chunk),
+                     chunk)
+        dv_acc[here, :] += _dot(p.astype(do.dtype), do, _NN)
+        dk_acc[here, :] += _dot(ds, q, _NN)
+        dq_acc[...] += _dot(ds, k, _TN)
 
     for c in range(bk // chunk):
         _run_tile(functools.partial(one, c), rule, i * bq, bq,
                   at * bk + c * chunk, chunk, off, live)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _():
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last & (t == pl.num_programs(2) - 1))
+    def _():
+        def store(n, _):
+            dk_ref[rows_of(n), :] = (dk_acc[rows_of(n), :]
+                                     * scale).astype(dk_ref.dtype)
+            dv_ref[rows_of(n), :] = dv_acc[rows_of(n), :].astype(
+                dv_ref.dtype)
+        jax.lax.fori_loop(0, span // chunk, store, None)
 
 
 def flash_attention_bwd(q, k, v, out, lse, dout, scale, rule,
@@ -160,15 +188,17 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
     nk, h_kv, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // h_kv
     off = nk - nq
-    defaults = block_sizes(*rule.sizes(nq, nk), max(d, dv), q.dtype)
-    _, dkv, dq = split_blocks(blocks)
-    kb_kv, qb_kv, c_kv = dkv or defaults[0]
-    qb_q, kb_q, c_q = dq or defaults[1]
+    # blocks: (block_q, block_k, chunk) and, for the tests, the keys
+    # resident at a time as a fourth
+    bq, bk, chunk, *span = split_blocks(blocks)[1] or block_sizes(
+        *rule.sizes(nq, nk), max(d, dv), q.dtype)
+    span, = span or (key_span(nk, bq, bk, chunk, d, dv, q.dtype),)
+    ranges = nk // span
+    has_mask = padding_mask is not None
     qa, at = _layout(q)
     ka, at_k = _layout(k)
     va, at_v = _layout(v)       # v and dV: the value width's layout
     doa, at_do = _layout(dout)  # dout: q's heads, v's width
-    kv = _kv_head(h, h_kv)
 
     # delta = rowsum(dout * out): one fused XLA reduction
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
@@ -180,81 +210,81 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
     lse = lse.reshape(b * h, 1, nq).astype(jnp.float32)
     lse = jnp.where(lse > _NEG_INF * 0.1, lse, jnp.inf)
 
-    args = [qa, ka, va, doa, lse, delta]
-    has_mask = padding_mask is not None
-    params = dict(scale=scale, rule=rule, off=off, has_mask=has_mask)
-    semantics = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    # dK/dV: the query blocks that see key block j, from the first on
-    # under the causal rule, the needed ones alone under another
-    steps, qi = rule.query_map(nq, nk, kb_kv, qb_kv)
+    # grid step t of key head g: q-block t % blocks_q of query head
+    # t // blocks_q of its group; the key blocks that q-block sees, held
+    # inside the range where there is more than one
+    blocks_q = nq // bq
+    steps, kj = rule.key_map(nq, nk, bq, bk)
     if group == 1:
-        head, step = (lambda g, t: g), (lambda t: t)
+        head, qi = (lambda g, t: g), (lambda t: t)
     else:
-        # grid step t of key head g: query head t // steps of its group
-        head = lambda g, t: g // h_kv * h + g % h_kv * group + t // steps
-        step = lambda t: t % steps
-    _count_tiles(rule, nq, nk, c_kv, kb_kv, b * h)
-    rows = pl.BlockSpec(
-        (None, qb_kv, d), lambda g, j, i: at(head(g, i), qi(j, step(i))))
-    douts = pl.BlockSpec(
-        (None, qb_kv, dv),
-        lambda g, j, i: at_do(head(g, i), qi(j, step(i))))
-    keys = pl.BlockSpec((None, kb_kv, d), lambda g, j, i: at_k(g, j))
-    values = pl.BlockSpec((None, kb_kv, dv), lambda g, j, i: at_v(g, j))
-    stat = pl.BlockSpec((None, 1, qb_kv),
-                        lambda g, j, i: (head(g, i), 0, qi(j, step(i))))
+        head = lambda g, t: g // h_kv * h + g % h_kv * group + t // blocks_q
+        qi = lambda t: t % blocks_q
+    if ranges == 1:
+        key = lambda r, t, j: kj(qi(t), j)
+    else:
+        key = lambda r, t, j: jnp.clip(kj(qi(t), j), r * (span // bk),
+                                       (r + 1) * (span // bk) - 1)
+    _count_tiles(rule, nq, nk, bq, chunk, b * h)
+    _count_ranges(ranges)
+
+    rows = pl.BlockSpec((None, bq, d),
+                        lambda g, r, t, j: at(head(g, t), qi(t)))
+    douts = pl.BlockSpec((None, bq, dv),
+                         lambda g, r, t, j: at_do(head(g, t), qi(t)))
+    keys = pl.BlockSpec((None, bk, d),
+                        lambda g, r, t, j: at_k(g, key(r, t, j)))
+    values = pl.BlockSpec((None, bk, dv),
+                          lambda g, r, t, j: at_v(g, key(r, t, j)))
+    stat = pl.BlockSpec((None, 1, bq),
+                        lambda g, r, t, j: (head(g, t), 0, qi(t)))
     in_specs = [rows, keys, values, douts, stat, stat]
+    args = [qa, ka, va, doa, lse, delta]
     if has_mask:
-        in_specs.append(pl.BlockSpec((None, kb_kv, 1),
-                                     lambda g, j, i: (g // h_kv, j, 0)))
+        in_specs.append(pl.BlockSpec(
+            (None, bk, 1), lambda g, r, t, j: (g // h_kv, key(r, t, j), 0)))
         args.append(padding_mask.astype(jnp.float32).reshape(b, nk, 1))
-    dk, dv_out = pl.pallas_call(
-        functools.partial(_dkv_kernel, chunk=c_kv,
-                          steps=None if group == 1 else steps, **params),
-        grid=(b * h_kv, nk // kb_kv, group * steps),
+    dq, dk, dv_out = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, scale=scale, rule=rule, off=off, chunk=chunk,
+            has_mask=has_mask, blocks_q=None if group == 1 else blocks_q,
+            span=span, ranges=ranges),
+        grid=(b * h_kv, ranges, group * blocks_q, steps),
         in_specs=in_specs,
-        out_specs=[keys, values],
-        out_shape=[jax.ShapeDtypeStruct(ka.shape, k.dtype),
-                   jax.ShapeDtypeStruct(va.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((kb_kv, d), k.dtype),
-                        pltpu.VMEM((kb_kv, d), jnp.float32),
-                        pltpu.VMEM((kb_kv, dv), jnp.float32)],
-        compiler_params=semantics,
+        out_specs=[
+            pl.BlockSpec((None, None, bq, d), lambda g, r, t, j: (
+                r,) + at(head(g, t), qi(t))),
+            pl.BlockSpec((None, span, d), lambda g, r, t, j: at_k(g, r)),
+            pl.BlockSpec((None, span, dv), lambda g, r, t, j: at_v(g, r)),
+        ],
+        out_shape=[
+            # a partial a range is summed below: float32 until then
+            jax.ShapeDtypeStruct((ranges,) + qa.shape,
+                                 q.dtype if ranges == 1 else jnp.float32),
+            jax.ShapeDtypeStruct(ka.shape, k.dtype),
+            jax.ShapeDtypeStruct(va.shape, v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((bq, d), q.dtype),
+                        pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((span, d), jnp.float32),
+                        pltpu.VMEM((span, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_vmem_bytes(span, bq, bk, chunk, d, dv,
+                                         q.dtype)),
+        # the name the dK/dV kernel had: the benchmark's reducers find the
+        # backward's time by it (PERF.md section 7)
         name="p1t_flash_attention_bwd_dkv",
         interpret=interpret,
     )(*args)
-
-    # dQ: the key blocks that query block i sees
-    steps, kj = rule.key_map(nq, nk, qb_q, kb_q)
-    _count_tiles(rule, nq, nk, qb_q, c_q, b * h)
-    rows = pl.BlockSpec((None, qb_q, d), lambda g, i, j: at(g, i))
-    douts = pl.BlockSpec((None, qb_q, dv), lambda g, i, j: at_do(g, i))
-    keys = pl.BlockSpec((None, kb_q, d),
-                        lambda g, i, j: at_k(kv(g), kj(i, j)))
-    values = pl.BlockSpec((None, kb_q, dv),
-                          lambda g, i, j: at_v(kv(g), kj(i, j)))
-    stat = pl.BlockSpec((None, 1, qb_q), lambda g, i, j: (g, 0, i))
-    in_specs = [rows, keys, values, douts, stat, stat]
-    if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (None, 1, kb_q), lambda g, i, j: (g // h, 0, kj(i, j))))
-        args[-1] = args[-1].reshape(b, 1, nk)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, chunk=c_q, **params),
-        grid=(b * h, nq // qb_q, steps),
-        in_specs=in_specs,
-        out_specs=rows,
-        out_shape=jax.ShapeDtypeStruct(qa.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((qb_q, d), q.dtype),
-                        pltpu.VMEM((qb_q, _LANES), jnp.float32),
-                        pltpu.VMEM((qb_q, _LANES), jnp.float32),
-                        pltpu.VMEM((qb_q, d), jnp.float32)],
-        compiler_params=semantics,
-        name="p1t_flash_attention_bwd_dq",
-        interpret=interpret,
-    )(*args)
-
+    dq = dq[0] if ranges == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     return (_unlayout(dq, b, h, d), _unlayout(dk, b, h_kv, d),
             _unlayout(dv_out, b, h_kv, dv))
+
+
+def _count_ranges(ranges):
+    """``flash_backward_ranges_total``: the key ranges of each traced
+    backward call (1 where the resident gradients fit)."""
+    from ...obs.registry import process_registry
+    process_registry().counter("flash_backward_ranges_total").inc(ranges)

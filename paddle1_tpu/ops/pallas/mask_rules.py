@@ -4,16 +4,16 @@ by tile (``flash_attention.py``, ``flash_attention_bwd.py``) and
 
 A rule is a small hashable value (it is a static argument of the kernels'
 ``jit``) that depends on positions alone, never on data, and answers three
-questions for the forward, dK/dV and dQ kernels alike:
+questions for the forward and the backward kernel alike (both keep a
+query block resident while key blocks pass):
 
-* which fetched blocks a resident block needs at all (``key_blocks`` for
-  the two kernels that keep a query block resident, ``query_blocks`` for
-  dK/dV): the index maps fetch nothing for the others;
+* which fetched key blocks a resident query block needs at all
+  (``key_blocks``): the index maps fetch nothing for the others;
 * whether a score tile is wholly visible, crossed or hidden (``tile``);
 * the element-wise keep of a crossed tile (``keep``).
 
 A rule also gives the grid's inner axis its length and index map
-(``key_map``, ``query_map``), the lengths the kernels' block sizes have
+(``key_map``), the lengths the kernels' block sizes have
 to divide (``sizes``), whether it describes given lengths at all
 (``lengths_ok``), its dense mask and its count of visible pairs.
 
@@ -63,13 +63,8 @@ class NoMask:
     def key_map(self, nq, nk, bq, bk):
         return nk // bk, lambda i, j: j
 
-    def query_map(self, nq, nk, bk, bq):
-        return nq // bq, lambda j, i: i
-
     def key_blocks(self, i, step, bq, bk):
         return step, None
-
-    query_blocks = key_blocks
 
     def tile(self, q0, bq, k0, bk, off=0):
         return True, True
@@ -99,7 +94,7 @@ class Causal:
         return nq, nk
 
     def key_map(self, nq, nk, bq, bk):
-        """-> (steps of the inner axis of the forward and dQ grids,
+        """-> (steps of the inner axis of the kernels' grids,
         (query block i, grid step j) -> the key block to fetch): j, held
         at the last block that query block i sees, so that a step above
         the diagonal fetches nothing new."""
@@ -107,20 +102,11 @@ class Causal:
         return nk // bk, lambda i, j: jnp.minimum(
             j, jnp.minimum((i * bq + bq - 1 + off) // bk, nk // bk - 1))
 
-    def query_map(self, nq, nk, bk, bq):
-        """The transpose, for dK/dV: step i, held at the first query
-        block that sees key block j."""
-        off = nk - nq
-        return nq // bq, lambda j, i: jnp.maximum(
-            i, jnp.maximum(j * bk - off, 0) // bq)
-
     def key_blocks(self, i, step, bq, bk):
         """-> (the block a grid step fetched as the kernels place it,
         whether the resident block needs it: None, the grid counts every
         block and the tiles are skipped by position)."""
         return step, None
-
-    query_blocks = key_blocks
 
     def tile(self, q0, bq, k0, bk, off):
         """(some pair visible, every pair visible) of the score tile of
@@ -183,10 +169,6 @@ class BlockDiffusion:
         return (self.key_steps(bq, bk),
                 lambda i, j: self.key_blocks(i, j, bq, bk)[0])
 
-    def query_map(self, nq, nk, bk, bq):
-        return (self.query_steps(bk, bq),
-                lambda j, i: self.query_blocks(j, i, bk, bq)[0])
-
     # -- where a tile lies
 
     def _place(self, r0):
@@ -227,8 +209,8 @@ class BlockDiffusion:
         most = jnp.where(qn & ~kn, -1, 0)
         return (apart >= least) & (apart <= most)
 
-    # -- which blocks a resident block needs: two runs of consecutive
-    # blocks, [a0, a0 + na) then [b0, b0 + nb)
+    # -- which key blocks a resident query block needs: two runs of
+    # consecutive blocks, [a0, a0 + na) then [b0, b0 + nb)
 
     def _key_runs(self, i, bq, bk):
         per = self.length // bk
@@ -244,22 +226,6 @@ class BlockDiffusion:
         nb = _xp(qn).where(qn, upto - first + 1, 0)
         return clean, na, noisy + first, nb
 
-    def _query_runs(self, j, bk, bq):
-        per = self.length // bq
-        kn, kp = self._place(j * bk)
-        first = self._floor(kp)                  # its first key's block
-        clean, noisy = self._base(False, per), self._base(True, per)
-        where = _xp(kn).where
-        # a noisy key block: the noisy queries of its own blocks. A clean
-        # one: the clean queries from its first key's block on, then the
-        # noisy ones from the block after that
-        own_last = (self._floor(kp + bk - 1) + self.block - 1) // bq
-        a0 = where(kn, noisy + first // bq, clean + first // bq)
-        na = where(kn, own_last - first // bq + 1, per - first // bq)
-        after = (first + self.block) // bq
-        nb = where(kn, 0, per - after)
-        return a0, na, noisy + after, nb
-
     @staticmethod
     def _pick(step, a0, na, b0, nb):
         """Grid step -> (the block to fetch, whether the step is one of
@@ -274,20 +240,11 @@ class BlockDiffusion:
         its ``step``-th grid step fetches, and whether it needs one."""
         return self._pick(step, *self._key_runs(i, bq, bk))
 
-    def query_blocks(self, j, step, bk, bq):
-        """The transpose: of key block ``j``, for dK/dV."""
-        return self._pick(step, *self._query_runs(j, bk, bq))
-
     def key_steps(self, bq, bk):
         """Grid steps along the keys that the hungriest query block
-        needs: the inner axis of the forward and dQ grids."""
+        needs: the inner axis of the kernels' grids."""
         _, na, _, nb = self._key_runs(
             np.arange(2 * self.length // bq, dtype=np.int32), bq, bk)
-        return int(np.max(na + nb))
-
-    def query_steps(self, bk, bq):
-        _, na, _, nb = self._query_runs(
-            np.arange(2 * self.length // bk, dtype=np.int32), bk, bq)
         return int(np.max(na + nb))
 
     def dense(self, nq, nk):

@@ -329,10 +329,16 @@ def test_adam_is_the_plain_update_chain(name, monkeypatch):
                                    rtol=1e-6, atol=1e-7)
 
 
-def _attention_problem(nq, nk, d, dtype, mask, b=2, h=2, seed=0):
+def _attention_problem(nq, nk, d, dtype, mask, b=2, h=2, seed=0, h_kv=None,
+                       dv=None):
+    """q and dout with ``h`` heads, k and v with ``h_kv`` (``h`` when
+    None); q and k ``d`` wide, v and dout ``dv`` (``d`` when None)."""
     rng = np.random.default_rng(seed)
-    mk = lambda n: jnp.asarray(rng.standard_normal((b, n, h, d)), dtype)
-    q, k, v, dout = mk(nq), mk(nk), mk(nk), mk(nq)
+    mk = lambda n, heads, width: jnp.asarray(
+        rng.standard_normal((b, n, heads, width)), dtype)
+    h_kv, dv = h_kv or h, dv or d
+    q, k, v, dout = (mk(nq, h, d), mk(nk, h_kv, d), mk(nk, h_kv, dv),
+                     mk(nq, h, dv))
     keep = None
     if mask:
         keep = np.ones((b, nk), bool)
@@ -342,10 +348,14 @@ def _attention_problem(nq, nk, d, dtype, mask, b=2, h=2, seed=0):
     return q, k, v, dout, keep
 
 
-def _flash_and_ref(q, k, v, dout, keep, causal, blocks):
+def _flash_and_ref(q, k, v, dout, keep, causal, blocks, rule=None):
     """(out, dq, dk, dv) of the kernels, and of attention_ref followed
     in float32 (a fully padded batch entry's rows zeroed: the kernels
-    give zeros there, a softmax over nothing gives a mean)."""
+    give zeros there, a softmax over nothing gives a mean). The
+    reference sees a key/value head once a query head of its group, so
+    autodiff sums dK and dV over the group; a ``rule`` of ``mask_rules``
+    as its dense mask."""
+    from paddle1_tpu.ops.pallas import mask_rules
     from paddle1_tpu.nn.functional.attention import attention_ref
     from paddle1_tpu.ops.pallas import flash_attention as fa
     f32 = lambda x: x.astype(jnp.float32)
@@ -354,12 +364,19 @@ def _flash_and_ref(q, k, v, dout, keep, causal, blocks):
             else ~keep.any(axis=1))
     alive = jnp.asarray(~dead, jnp.float32)[:, None, None, None]
 
+    group = q.shape[2] // k.shape[2]
+
     def kernel(q, k, v):
         return fa.flash_attention(q, k, v, causal=causal, padding_mask=pm,
-                                  blocks=blocks)
+                                  blocks=blocks, mask=rule)
 
     def plain(q, k, v):
         m4 = None if pm is None else pm[:, None, None, :]
+        if rule is not None:
+            seen = jnp.asarray(mask_rules.dense_mask(
+                rule, q.shape[1], k.shape[1]))[None, None]
+            m4 = seen if m4 is None else m4 & seen
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
         return attention_ref(q, k, v, mask=m4, is_causal=causal) * alive
 
     out, vjp = jax.vjp(kernel, q, k, v)
@@ -394,8 +411,45 @@ FLASH_CASES = [
 ]
 
 
+# (mask rule, nq, nk, heads, key/value heads, d, dv, dtype, padding,
+# blocks): what the one backward kernel branches on that FLASH_CASES does
+# not reach. blocks = (forward triple, backward (block_q, block_k, chunk,
+# keys resident at a time)): fewer resident keys than there are keys take
+# the kernel a key range at a time, dQ summed over the ranges.
+_BD = ("block_diffusion", 4)
+_RANGES = (_B128, (128, 128, 128, 128))
+BACKWARD_CASES = [
+    # a group of query heads passes each resident dK / dV
+    ("causal", 256, 256, 8, 2, 64, 64, "float32", None, _B128),
+    ("none", 256, 256, 8, 2, 128, 128, "float32", "padding", _B128),
+    (_BD, 256, 256, 8, 2, 64, 64, "float32", None, _B128),
+    ("causal", 128, 128, 32, 4, 128, 128, "float32", None, _B128),
+    (_BD, 256, 256, 32, 4, 128, 128, "bfloat16", None, _B128),
+    # keys 192 wide, values 128: one layout each
+    ("causal", 256, 256, 2, 2, 192, 128, "float32", None, _B128),
+    ("none", 128, 384, 2, 2, 192, 128, "float32", "padding", _B128),
+    ("causal", 256, 256, 4, 2, 192, 128, "bfloat16", None, _B128),
+    (_BD, 256, 256, 4, 1, 192, 128, "float32", None, _B128),
+    # block diffusion at h == h_kv, fetched blocks of two chunks
+    (_BD, 512, 512, 2, 2, 128, 128, "float32", None, (128, 256, 128)),
+    (("block_diffusion", 32), 256, 256, 2, 2, 64, 64, "float32", None,
+     _B128),
+    # more than one key range
+    ("causal", 256, 256, 2, 2, 64, 64, "float32", None, _RANGES),
+    ("causal", 384, 640, 2, 2, 128, 128, "float32", "padding", _RANGES),
+    ("none", 256, 256, 2, 2, 128, 128, "float32", None, _RANGES),
+    ("none", 256, 512, 4, 2, 64, 64, "float32", "padded_row",
+     (_B128, (128, 256, 128, 256))),
+    (_BD, 256, 256, 8, 2, 128, 128, "float32", None, _RANGES),
+    (_BD, 512, 512, 2, 1, 192, 128, "bfloat16", None,
+     (_B128, (128, 256, 128, 512))),
+    ("causal", 512, 512, 2, 2, 128, 128, "bfloat16", "padding",
+     (_B128, (256, 256, 128, 256))),
+]
+
+
 class TestFlashKernels:
-    """The blockwise forward and both backward kernels
+    """The blockwise forward kernel and the one backward kernel
     (ops/pallas/flash_attention.py, flash_attention_bwd.py) against
     autodiff of the dense reference, in interpret mode."""
 
@@ -407,20 +461,65 @@ class TestFlashKernels:
             self, causal, nq, nk, d, dtype, mask, blocks):
         q, k, v, dout, keep = _attention_problem(nq, nk, d,
                                                  jnp.dtype(dtype), mask)
-        got, want, dead = _flash_and_ref(q, k, v, dout, keep, causal,
-                                         blocks)
+        self._held_to_the_reference(q, *_flash_and_ref(
+            q, k, v, dout, keep, causal, blocks), dtype)
+
+    @staticmethod
+    def _held_to_the_reference(q, got, want, dead, dtype, group=1):
         # bf16: the probabilities and ds are rounded once (2^-9 of
-        # values up to 1 and up to |dout| |v| sqrt(d))
+        # values up to 1 and up to |dout| |v| sqrt(d)); dK and dV sum a
+        # group's heads, each rounded so
         tol = 2e-5 if dtype == "float32" else 6e-2
         for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
-            assert g.dtype == q.dtype, name
+            assert g.dtype == q.dtype and g.shape == w.shape, name
             g = np.asarray(g.astype(jnp.float32))
             assert np.isfinite(g).all(), name
-            np.testing.assert_allclose(g, np.asarray(w), rtol=tol,
-                                       atol=tol, err_msg=name)
+            loose = tol * (group ** 0.5 if name in ("dk", "dv") else 1)
+            np.testing.assert_allclose(g, np.asarray(w), rtol=loose,
+                                       atol=loose, err_msg=name)
             # a batch entry that sees nothing: exact zeros, not the
             # exp(0) = 1 of a sentinel maximum
             np.testing.assert_array_equal(g[dead], 0.0, err_msg=name)
+
+    @pytest.mark.parametrize(
+        "rule,nq,nk,h,h_kv,d,dv,dtype,mask,blocks", BACKWARD_CASES,
+        ids=lambda c: "x".join(map(str, c)) if isinstance(c, tuple)
+        else str(c))
+    def test_the_one_backward_kernel_gives_all_three_gradients(
+            self, rule, nq, nk, h, h_kv, d, dv, dtype, mask, blocks):
+        """dQ, dK and dV of one ``pallas_call``: grouped heads, the two
+        widths of latent attention, block diffusion's rule, and the keys
+        a range at a time, within the limits the split kernels had."""
+        from paddle1_tpu.ops.pallas import mask_rules
+        q, k, v, dout, keep = _attention_problem(
+            nq, nk, d, jnp.dtype(dtype), mask, h=h, h_kv=h_kv, dv=dv)
+        if isinstance(rule, tuple):
+            rule = mask_rules.BlockDiffusion(nq // 2, rule[1])
+        else:
+            rule = {"causal": mask_rules.CAUSAL, "none": None}[rule]
+        self._held_to_the_reference(q, *_flash_and_ref(
+            q, k, v, dout, keep, False, blocks, rule=rule), dtype,
+            group=h // h_kv)
+
+    def test_a_call_counts_its_key_ranges(self):
+        """``flash_backward_ranges_total``: 1 a traced backward call
+        whose dK and dV are resident whole, the ranges where not."""
+        from paddle1_tpu import obs
+        from paddle1_tpu.ops.pallas import flash_attention as fa
+        obs.reset_process_registry()
+        x = jax.ShapeDtypeStruct((1, 512, 2, 64), jnp.float32)
+
+        def grad(blocks):
+            return jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+                fa.flash_attention(q, k, v, causal=True, blocks=blocks)),
+                argnums=(0, 1, 2)))(x, x, x)
+        count = lambda: obs.registry.process_registry().counter(
+            "flash_backward_ranges_total").value
+        assert "pallas_call" in str(grad((128, 256, 128)))
+        assert count() == 1
+        grad((_B128, (128, 128, 128, 128)))
+        assert count() == 1 + 4
+        obs.reset_process_registry()
 
     def test_degenerate_alignments_are_left_to_the_reference(self):
         from paddle1_tpu.ops.pallas import flash_attention as fa
@@ -446,9 +545,18 @@ class TestFlashKernels:
         bq, bk, chunk = fa.block_sizes(nq, nk, d, dtype)
         assert bk * d * jnp.dtype(dtype).itemsize <= 512 << 10
         assert nq % bq == 0 and nk % bk == 0 and bk % chunk == 0
-        (kb, qb, c1), (qb2, kb2, c2) = fb.block_sizes(nq, nk, d, dtype)
-        assert nk % kb == 0 and nq % qb == 0 and qb % c1 == 0
-        assert nq % qb2 == 0 and nk % kb2 == 0 and kb2 % c2 == 0
+        # the backward takes the forward's, and keeps every key's dK and
+        # dV resident where they fit what it may ask of the VMEM
+        assert fb.block_sizes(nq, nk, d, dtype) == (bq, bk, chunk)
+        span = fb.key_span(nk, bq, bk, chunk, d, d, dtype)
+        assert span == nk
+        assert fb._vmem_bytes(span, bq, bk, chunk, d, d, dtype) \
+            <= fb._VMEM_CAP
+        # 65,536 keys do not: the fewest halvings that do
+        long = fb.key_span(65536, bq, 512, 512, d, d, dtype)
+        assert long == (32768 if d == 128 else 8192)
+        need = lambda n: fb._vmem_bytes(n, bq, 512, 512, d, d, dtype)
+        assert need(long) <= fb._VMEM_CAP < need(2 * long)
 
 
 def _primitives(jaxpr, seen=None):
@@ -498,9 +606,9 @@ def test_a_causal_models_step_holds_no_while_on_the_kernels_path():
         names = _primitives(jax.make_jaxpr(engine._step_fn)(
             engine.params, engine.opt_state, {"ids": ids},
             jax.random.key(0), jnp.float32(1e-3)).jaxpr)
-    # 2 loop steps x 1 layer: forward, dK/dV, dQ (the recomputation keeps
+    # 2 loop steps x 1 layer: forward, backward (the recomputation keeps
     # the forward kernel's outputs: no second call of it)
-    assert names.count("pallas_call") == 6
+    assert names.count("pallas_call") == 4
     assert not {"while", "scan"} & set(names)
 
 

@@ -11,14 +11,15 @@ composition: query chunk i against keys [0, (i+1) * chunk)), ``jax``
 
     python tools/tpu_flash_crossover.py [--part blocks|lengths|latent|diffusion|all]
 
-``blocks``: the Ouro shape [2, 4096, 16, 128] bf16 causal, each kernel
-alone under each block triple. ``lengths``: 8192 tokens at sequence
+``blocks``: the Ouro shape [2, 4096, 16, 128] bf16 causal, the forward
+kernel and the backward kernel alone under each block triple.
+``lengths``: 8192 tokens at sequence
 lengths 512 .. 8192 (d 128 and d 64, causal and not, and BERT's two
 shapes), kernel against dense: the crossover. ``latent`` (PR 31): the
 same at keys 192 and values 128 wide (latent attention), causal, at 4096
 and 8192. ``diffusion`` (PR 33): block diffusion's mask rule with grouped
 key/value heads at SDAR's size, a doubled row [1, 2 x 8192, 32 / 4, 128]
-with blocks of 4: each kernel alone under a few block triples, and forward
+with blocks of 4: either kernel alone under a few block triples, and forward
 + backward beside the same shape under a causal mask over the 16384 (twice
 the visible pairs, and not the model's mask: what the rule saves). Dense
 does not fit there (1 GiB of float32 scores a head).
@@ -147,7 +148,8 @@ def _one(rows, part, name, f, *a):
 
 
 def _kernels_alone(rows, part, inputs, rule, triples):
-    """Each of the three kernels alone under each block triple."""
+    """The forward kernel and the one backward kernel (dQ, dK and dV
+    together), each alone under each block triple."""
     import jax
     from paddle1_tpu.ops.pallas import flash_attention as fa
     from paddle1_tpu.ops.pallas.flash_attention_bwd import \
@@ -161,16 +163,9 @@ def _kernels_alone(rows, part, inputs, rule, triples):
              jax.jit(lambda q, k, v, t=t: fa._flash_fwd(
                  q, k, v, scale, rule, blocks=t)[0]), q, k, v)
     for t in triples:
-        def dkv(q, k, v, out, lse, do, t=t):
-            _, dk, dv = flash_attention_bwd(q, k, v, out, lse, do, scale,
-                                            rule, blocks=(None, t, None))
-            return dk, dv
-
-        def dq(q, k, v, out, lse, do, t=t):
-            return flash_attention_bwd(q, k, v, out, lse, do, scale, rule,
-                                       blocks=(None, None, t))[0]
-        _one(rows, part, f"dkv {t}", jax.jit(dkv), q, k, v, out, lse, do)
-        _one(rows, part, f"dq {t}", jax.jit(dq), q, k, v, out, lse, do)
+        _one(rows, part, f"bwd {t}",
+             jax.jit(lambda *a, t=t: flash_attention_bwd(
+                 *a, scale, rule, blocks=(None, t))), q, k, v, out, lse, do)
 
 
 def part_diffusion(rows, length=8192, block=4, heads=32, kv_heads=4, dim=128,

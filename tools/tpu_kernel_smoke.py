@@ -58,7 +58,8 @@ def main():
               q, k, v, mask=(pm[:, None, None, :] > 0.5))
               .astype(jnp.float32).sum())(q), 8e-2)
 
-    # flash BACKWARD kernels against autodiff of the dense reference
+    # the flash BACKWARD kernel (one call: dq, dk and dv) against autodiff
+    # of the dense reference
     from paddle1_tpu.ops.pallas import flash_attention as fa_mod
     from paddle1_tpu.ops.pallas.flash_attention_bwd import \
         flash_attention_bwd
