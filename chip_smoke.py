@@ -11,7 +11,8 @@ Pallas kernel runs once against its XLA reference at a real width, the
 blockwise attention kernels run block diffusion's mask rule with grouped
 key/value heads at SDAR's size against a float32 dense-mask reference in
 blocks, and the routed-expert layer takes more held picks than its grouped
-products have rows. Every
+products have rows and counts the late ones on the device, eagerly and in
+two compiled steps (``ParallelEngine.expert_load()``). Every
 check that fails raises: no phase may fail and the script still exit 0,
 and no kernel gives way to its reference. One process, no child that
 needs the chip.
@@ -120,7 +121,7 @@ def compiled_step_text(engine, batch):
     import jax.numpy as jnp
     return engine.train_step_fn.lower(
         engine.params, engine.opt_state, engine.shard_batch(batch),
-        jax.random.key(0), jnp.asarray(0.0, jnp.float32)
+        jax.random.key(0), jnp.asarray(0.0, jnp.float32), engine.step_state
     ).compile().as_text()
 
 
@@ -444,6 +445,32 @@ def experts_phase(tokens=4096, hidden=512, width=256):
         err = max_err(g, w) / scale
         check(err <= 5e-2, f"routed experts, late picks, {name}: max abs "
                            f"err / max |ref| = {err:.2e} <= 5e-2")
+
+    # what the layer counted of that forward, and what an engine over it
+    # has after two compiled steps (it starts from the layer's own
+    # counts): every pick is a held one, the grouped products have
+    # ``rows`` rows and the rest are late
+    from paddle1_tpu.distributed import ParallelEngine, build_mesh
+    held_picks = tokens * top_k
+    want = {"held_picks": held_picks, "late_picks": held_picks - rows,
+            "late_steps": 1, "steps": 1}
+    eager = layer.read_load(np.asarray(layer.expert_load.data))
+    engine = ParallelEngine(
+        layer, paddle.optimizer.SGD(learning_rate=0.0,
+                                    parameters=layer.parameters()),
+        lambda m, b: m(Tensor(b["x"])).astype("float32").sum(),
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    for _ in range(2):
+        engine.step({"x": x})
+    compiled = engine.expert_load()[""]
+    for name, load, n in (("eager", eager, 1),
+                          ("and two compiled steps", compiled, 3)):
+        got = {k: load[k] for k in want}
+        check(got == {k: n * v for k, v in want.items()}
+              and sum(load["rows"]) == n * rows
+              and load["capacity_rows"] == rows,
+              f"routed experts, expert_load(), {name}: {got}, "
+              f"{sum(load['rows'])} rows within the capacity of {rows}")
 
 
 # -- four chips -------------------------------------------------------------

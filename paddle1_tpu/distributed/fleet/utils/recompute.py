@@ -17,6 +17,13 @@ and ``lse``, the two things its backward kernels need of it). Running
 that kernel again costs more a byte kept than anything else in a decoder
 block (0.042 ms a MB on a v5e against 0.011-0.015 for q / k / v, the
 SwiGLU inputs or a whole block: PERF.md, PR 30).
+
+State that the segment's forward writes (batch norm's running
+statistics, the expert layer's load counts) cannot stay in the side list
+it is recorded in (``nn/functional/norm.py::collect_stat_updates``): the
+values are tracers of the segment's own trace. The segment collects them
+itself, returns them as outputs that carry no gradient, and they are
+recorded again outside, in whatever collector encloses the call.
 """
 
 from __future__ import annotations
@@ -29,6 +36,7 @@ import jax
 from ....autograd.engine import apply, no_grad
 from ....core.generator import next_key, rng_scope
 from ....core.tensor import Tensor
+from ....nn.functional import norm as fnorm
 from ....nn.layer_base import Layer
 from ....ops.pallas.flash_attention import KEPT_RESIDUALS
 
@@ -82,23 +90,48 @@ def recompute(function: Callable, *args, **kwargs):
     if layer is not None:
         names = list(layer.functional_state().keys())
         params = [layer.state_dict()[n] for n in names]
+        # what the segment's forward wrote (batch norm's statistics, the
+        # expert layer's counts), filled while ``seg`` is traced: the
+        # record of each write without its value ("written"), how many of
+        # the segment's outputs are the function's own ("outputs") and
+        # whether it returned a tuple
+        made: dict = {}
 
         @functools.partial(jax.checkpoint, policy=_KEEP)
         def seg(key, param_arrays, *input_arrays):
             # tape off: the segment is differentiated as a whole, by
-            # jax, and an op's own vjp rule has to reach it unopened
+            # jax, and an op's own vjp rule has to reach it unopened.
+            # The collector is the segment's own: a value recorded here
+            # is a tracer of this trace and leaves it as an output
             with rng_scope(key), no_grad(), layer.load_functional_state(
-                    dict(zip(names, param_arrays))):
+                    dict(zip(names, param_arrays))), \
+                    fnorm.collect_stat_updates() as sink:
                 out = fwd_callable(*_rebuild_args(input_arrays))
-                return (tuple(t.data for t in out)
-                        if isinstance(out, (tuple, list)) else out.data)
+            made["tuple"] = isinstance(out, (tuple, list))
+            outs = tuple(t.data for t in out) if made["tuple"] \
+                else (out.data,)
+            made["outputs"] = len(outs)
+            made["written"] = [(u.buffer, u.rule, u.momentum, u.what)
+                               for u in sink]
+            return outs + tuple(jax.lax.stop_gradient(u.value) for u in sink)
 
         def op(*flat):
             p = list(flat[:len(params)])
             x = flat[len(params):]
-            return seg(key, p, *x)
+            outs = seg(key, p, *x)
+            return outs[0] if len(outs) == 1 else outs
 
-        return apply("recompute", op, tuple(params + tensor_args))
+        flat = apply("recompute", op, tuple(params + tensor_args))
+        flat = flat if isinstance(flat, tuple) else (flat,)
+        # written again where the segment was called: the enclosing
+        # collector's (the step's, an outer segment's), or the buffer
+        # itself where nothing is being traced
+        for (buffer, rule, momentum, what), value in zip(
+                made["written"], flat[made["outputs"]:]):
+            fnorm.record_state_update(buffer, value.data, rule, momentum,
+                                      what)
+        outs = flat[:made["outputs"]]
+        return outs if made["tuple"] else outs[0]
 
     # Opaque callable: parameters it closes over cannot be threaded into
     # jax.checkpoint as differentiable inputs, and capturing them as trace

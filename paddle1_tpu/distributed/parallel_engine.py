@@ -123,9 +123,11 @@ _readback_obs_installed = False
 
 def _ensure_readback_observer():
     """Route LossFuture materialization durations into the process
-    registry's train_readback_seconds histogram (idempotent; installed
-    the first time an instrumented step runs, so uninstrumented
-    processes never pay the per-fetch perf_counter)."""
+    registry's train_readback_seconds histogram, and at that host sync
+    (it happens anyway) the live engines' expert-load counts into the
+    ``moe_*`` series (idempotent; installed the first time an
+    instrumented step runs, so uninstrumented processes never pay the
+    per-fetch perf_counter)."""
     global _readback_obs_installed
     if _readback_obs_installed:
         return
@@ -134,8 +136,11 @@ def _ensure_readback_observer():
 
     def observe(dt: float) -> None:
         if obs_registry.metrics_on():
-            obs_registry.process_registry().histogram(
-                "train_readback_seconds").observe(dt)
+            m = obs_registry.process_registry()
+            m.histogram("train_readback_seconds").observe(dt)
+            for owner in obs_hbm.live_owners():
+                if getattr(owner, "_load_seen", None):
+                    owner._publish_load(m)
 
     async_loss.set_readback_observer(observe)
 
@@ -184,8 +189,18 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
                     recompute: bool = False,
                     grad_shardings=None,
                     check_finite: bool = False):
-    """Build the pure train-step: (params, opt_state, batch, key, lr) →
-    (loss, params, opt_state).
+    """Build the pure train-step: (params, opt_state, batch, key, lr,
+    step_state) → (loss, params, opt_state, step_state).
+
+    ``step_state`` is the state of the run that is no parameter: the
+    model's non-persistable buffers (``{name: array}``: the expert
+    layer's load counts), written by a forward through
+    ``nn/functional/norm.py::record_state_update``. It stays outside
+    ``jax.value_and_grad``'s arguments, outside the optimizer and outside
+    ``check_finite``'s keep-select (a step that is refused still ran its
+    forward). Left out (None) or empty, the step carries none, returns
+    ``{}`` and is the program it would be without: a model that records
+    nothing compiles to the same text.
 
     ``check_finite=True`` folds device-side bad-step detection into the
     same executable: a non-finite loss or gradient (NaN batch, amp
@@ -203,15 +218,18 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
     basic_engine.cc).
     """
 
-    # Functionalized batch-norm running stats (ADVICE r5 medium): the
-    # momentum per captured buffer, recorded at trace time — a plain
-    # Python side channel, like the trace counters. The traced batch
-    # stats ride pure_loss's aux output; train_step blends them with
-    # the incoming buffer values and writes the result into the step's
-    # OUTPUT params, so compiled training keeps running stats exactly
-    # like eager training and sync_model/checkpoints see them — no
-    # extra outputs, no extra transfers.
-    stat_momentum: Dict[str, float] = {}
+    # State a forward writes, functionalized (ADVICE r5 medium): the
+    # rule and momentum per captured buffer, recorded at trace time — a
+    # plain Python side channel, like the trace counters. The traced
+    # values ride pure_loss's aux output; train_step folds them into
+    # the incoming buffer values by their rule and writes the result
+    # into the step's OUTPUT params (a persistable buffer: batch norm's
+    # running statistics) or its output step state (a non-persistable
+    # one: the expert layer's load counts), so compiled training keeps
+    # them exactly like eager training and sync_model/checkpoints see
+    # them — no transfers beside the step's own.
+    from ..nn.functional import norm as fnorm
+    stat_rule: Dict[str, tuple] = {}
     buffer_names = [name for name, _ in layer.named_buffers()]
 
     def pure_loss(params, batch, key):
@@ -232,7 +250,6 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
                     if (hasattr(a, "dtype")
                         and jnp.issubdtype(a.dtype, jnp.floating)) else a,
                     batch)
-        from ..nn.functional import norm as fnorm
         with autograd_engine.no_grad(), rng_scope(key):
             with layer.load_functional_state(params):
                 with fnorm.collect_stat_updates() as stat_updates:
@@ -244,20 +261,26 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
         out = out.data if isinstance(out, Tensor) else out
         aux = {}
         if stat_updates:
-            # map each captured OLD buffer array back to its params key
-            # by identity (load_functional_state swapped exactly these
-            # arrays in), and emit the raw batch stats as aux — the
-            # old/new blend happens in train_step, where composing
+            # map each written buffer back to its key in the step's
+            # state by the identity of its Tensor (load_functional_state
+            # swaps arrays inside these very Tensors; a recomputed
+            # segment swaps its own tracers in, so the array's identity
+            # would not do), and emit the raw values as aux — the fold
+            # into the old value happens in train_step, where composing
             # multiple micro-steps is well-defined
-            ids = {id(v): k for k, v in params.items()}
+            ids = {id(t): k for k, t in
+                   reversed(list(layer.named_buffers()))}
             for u in stat_updates:
-                for old, stat in ((u.old_mean, u.mean),
-                                  (u.old_var, u.var)):
-                    name = ids.get(id(old))
-                    if name is None:
-                        continue  # buffer not threaded through params
-                    stat_momentum[name] = float(u.momentum)
-                    aux[name] = stat.astype(jnp.float32)
+                name = ids.get(id(u.buffer))
+                if name is None:
+                    continue  # buffer not threaded through the step
+                if u.rule == "add":
+                    stat_rule[name] = ("add", None)
+                    aux[name] = aux[name] + u.value if name in aux \
+                        else u.value
+                else:
+                    stat_rule[name] = ("blend", float(u.momentum))
+                    aux[name] = u.value.astype(jnp.float32)
         return out.astype(jnp.float32), aux
 
     if recompute:
@@ -280,7 +303,8 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
                 "TransformerEncoder does); wrap your own blocks with "
                 "fleet.utils.recompute for per-segment remat")
 
-    def train_step(params, opt_state, batch, key, lr):
+    def train_step(params, opt_state, batch, key, lr, step_state=None):
+        step_state = {} if step_state is None else step_state
         if grad_accum > 1:
             # micro-batch scan: batch leaves are [accum, micro, ...]
             def micro(carry, xs):
@@ -337,27 +361,41 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
                 params, grads, opt_state, lr)
             # a buffer is no parameter: whatever the update rule made of
             # its zero gradient (AdamW's decay shrinks it), it leaves the
-            # step as it came, unless a running statistic is written below
+            # step as it came, unless a forward's write is folded in below.
+            # The buffers that still take this road through the optimizer
+            # are the persistable float ones, which are entries of
+            # ``params`` because ``functional_state()`` is
+            # ``state_dict()``: batch norm's running statistics and the
+            # expert router's ``e_score_correction_bias``. Taking them out
+            # of ``params`` would change every program that holds one
+            # (resnet's step); the non-persistable ones never enter it:
+            # they are ``step_state``
             for name in buffer_names:
                 if name in params and name not in aux:
                     new_params[name] = params[name]
+        new_step_state = dict(step_state)
         if aux:
-            # functionalized running stats: new = m*old + (1-m)*batch
-            # (sequentially per micro-step under grad_accum, matching
-            # eager), OVERRIDING whatever zero-grad update the
-            # optimizer computed for the buffer entries. check_finite's
-            # keep-select below covers these too: a bad step keeps the
-            # old stats along with the old params.
+            # what the forward wrote, by its rule
+            # (``fold_state_update``: blend, new = m*old + (1-m)*batch;
+            # add, new = old + value), one micro-step after the other
+            # under grad_accum, matching eager. A persistable buffer's
+            # OVERRIDES whatever
+            # zero-grad update the optimizer computed for its entry, and
+            # check_finite's keep-select below covers it too: a bad step
+            # keeps the old stats along with the old params. The step
+            # state is not kept back: the refused step ran its forward.
             with jax.named_scope("stat_update"):
                 for name, stat in aux.items():
-                    m = stat_momentum[name]
-                    cur = params[name].astype(jnp.float32)
-                    if grad_accum > 1:  # stacked [accum, C] from the scan
-                        for i in range(grad_accum):
-                            cur = m * cur + (1 - m) * stat[i]
-                    else:
-                        cur = m * cur + (1 - m) * stat
-                    new_params[name] = cur.astype(params[name].dtype)
+                    cur = params[name] if name in params \
+                        else step_state.get(name)
+                    if cur is None:
+                        continue    # a step that carries no step state
+                    rule, m = stat_rule[name]
+                    # stacked [accum, ...] from the scan under grad_accum
+                    for one in (stat if grad_accum > 1 else (stat,)):
+                        cur = fnorm.fold_state_update(cur, one, rule, m)
+                    (new_params if name in params
+                     else new_step_state)[name] = cur
         if check_finite:
             # bad step → keep the incoming params/slots/step-count (the
             # reference update_loss_scaling "skip update" semantics),
@@ -369,8 +407,8 @@ def make_train_step(layer: Layer, optimizer, loss_fn: Callable,
                 new_params = keep(new_params, params)
                 new_state = keep(new_state, opt_state)
                 packed = jnp.stack([loss, (~finite).astype(jnp.float32)])
-            return packed, new_params, new_state
-        return loss, new_params, new_state
+            return packed, new_params, new_state, new_step_state
+        return loss, new_params, new_state, new_step_state
 
     return train_step
 
@@ -527,11 +565,33 @@ class ParallelEngine:
                     return gspmd_step(*args)
             self._step_fn = _step_fn
 
+        # The state of the run that is no parameter: the model's
+        # non-persistable buffers (the expert layers' load counts), a
+        # small tree beside params and opt_state, replicated, in and out
+        # of every step. Not donated: a few hundred bytes, and a reader
+        # may hold a step's copy while later steps run. Empty for a model
+        # that has none, and then the step is the program it always was.
+        self.step_state = {
+            name: b.data for name, b in model.named_buffers()
+            if name not in sd}
+        from ..nn.layer_moe import RoutedExperts
+        # {layer path: (the layer, its counts' key in step_state)}
+        self._expert_layers = {
+            path: (sub, f"{path}.expert_load" if path else "expert_load")
+            for path, sub in model.named_sublayers(include_self=True)
+            if isinstance(sub, RoutedExperts)}
+        # obs_metrics: the counts of the steps not yet on the /metrics
+        # page, oldest first, and what the page has had (_publish_load)
+        self._load_seen: collections.deque = collections.deque(
+            maxlen=max(int(inflight_window), 1) + 2)
+        self._load_shown: Dict[str, np.ndarray] = {}
+
         ns = lambda spec: NamedSharding(self.mesh, spec)
         param_sh = {k: ns(s) for k, s in self.param_specs.items()}
         slot_sh = ({k: {n: ns(s) for n, s in d.items()}
                     for k, d in self.slot_specs.items()}, ns(P()))
         self._param_sh, self._slot_sh = param_sh, slot_sh
+        self._state_sh = {k: ns(P()) for k in self.step_state}
         self._donate = donate
 
         # Dispatch/trace accounting: one dispatch per _jit/_jit_many
@@ -579,6 +639,8 @@ class ParallelEngine:
 
         self.params = {k: _owned(v, param_sh[k])
                        for k, v in self.params.items()}
+        self.step_state = {k: _owned(v, self._state_sh[k])
+                           for k, v in self.step_state.items()}
         # The optimizer state is born on its shards, as the outputs of
         # a jit that carry the slot shardings: built eagerly it would
         # stand whole on the default device first (found on four chips,
@@ -722,17 +784,18 @@ class ParallelEngine:
         ``compiled_step_text``): the same name, arguments, shardings and
         donation make the same module, hence the same entry of the
         persistent compile cache."""
-        def fn(params, opt_state, batch, key, lr):
+        def fn(params, opt_state, batch, key, lr, step_state):
             if counted:
                 self.trace_count += 1
-            return body(params, opt_state, batch, key, lr)
+            return body(params, opt_state, batch, key, lr, step_state)
 
         fn.__name__ = fn.__qualname__ = name
         return jax.jit(
             fn,
-            in_shardings=(self._param_sh, self._slot_sh, None, None, None),
+            in_shardings=(self._param_sh, self._slot_sh, None, None, None,
+                          self._state_sh),
             out_shardings=(NamedSharding(self.mesh, P()), self._param_sh,
-                           self._slot_sh),
+                           self._slot_sh, self._state_sh),
             donate_argnums=(0, 1) if self._donate else ())
 
     def _shape_sig(self, tree) -> tuple:
@@ -816,11 +879,66 @@ class ParallelEngine:
                 m.gauge("train_cost_exact").set(1.0 if cost.exact else 0.0)
             _obs_note_steps(m, k, self._obs_rows(batch, self.grad_accum),
                             t3 * 1e-9)
+            if self._expert_layers:
+                self._load_seen.append(self._load_arrays())
 
     def phase_records(self) -> list:
         """The :class:`StepPhases` of the last ``PHASE_RING``
         dispatches, oldest first."""
         return list(self._phases)
+
+    def _load_arrays(self) -> Dict[str, Any]:
+        """{layer path: the newest step's ``expert_load`` array}."""
+        return {path: self.step_state[key]
+                for path, (_, key) in self._expert_layers.items()}
+
+    def expert_load(self) -> Dict[str, Dict[str, Any]]:
+        """What every ``RoutedExperts`` layer of the model has counted on
+        the device (the engine starts from the layer's own counts, as it
+        does from its parameters), ``{layer path: {"rows":
+        [held], "held_picks", "late_picks", "late_steps", "steps",
+        "picks_made_a_step", "capacity_rows", "held", "num_experts"}}``
+        (``nn/layer_moe.py``'s docstring says what each is); ``{}`` for a
+        model with no such layer. One ``device_get``, on demand: it waits
+        for the newest dispatched step, as any read of ``engine.params``
+        does. The counts are 32-bit totals: a field reads negative after
+        2^31 (see the layer's docstring); the ``moe_*`` series of the
+        process registry are fed by differences and do not."""
+        counts = jax.device_get(self._load_arrays())
+        return {path: self._expert_layers[path][0].read_load(c)
+                for path, c in counts.items()}
+
+    def _publish_load(self, m) -> None:
+        """``obs_metrics``, at a loss readback: the newest counts whose
+        step has finished (no wait: the step whose loss was just read
+        has) go to the process registry as differences since the last
+        call, modulo 2^32. ``moe_picks_held_total{layer}``,
+        ``moe_picks_late_total{layer}``, ``moe_late_steps_total{layer}``,
+        ``moe_expert_rows_total{layer,expert}`` and the gauge
+        ``moe_capacity_rows{layer}``."""
+        ready = None
+        while self._load_seen and all(
+                a.is_ready() for a in self._load_seen[0].values()):
+            ready = self._load_seen.popleft()
+        if ready is None:
+            return
+        from ..obs import registry as obs_registry
+        by_layer = obs_registry.process_group("layer")
+        by_expert = obs_registry.process_group(("layer", "expert"))
+        for path, now in jax.device_get(ready).items():
+            layer = self._expert_layers[path][0]
+            now = now.astype(np.int64)
+            new = (now - self._load_shown.get(path, 0)) & 0xFFFFFFFF
+            self._load_shown[path] = now
+            load = layer.read_load(new)
+            one = by_layer.child(path or ".")
+            one.counter("moe_picks_held_total").inc(load["held_picks"])
+            one.counter("moe_picks_late_total").inc(load["late_picks"])
+            one.counter("moe_late_steps_total").inc(load["late_steps"])
+            one.gauge("moe_capacity_rows").set(load["capacity_rows"] or 0)
+            for e, rows in enumerate(load["rows"]):
+                by_expert.child((path or ".", layer.first + e)).counter(
+                    "moe_expert_rows_total").inc(rows)
 
     # -- per-step observability (obs_metrics flag; ISSUE 10) ---------------
 
@@ -861,7 +979,8 @@ class ParallelEngine:
                 return self._jit_body(
                     self._step_fn, "counted_step", counted=False).lower(
                     self.params, self.opt_state, batch,
-                    jax.random.key(0), jnp.asarray(0.0, jnp.float32))
+                    jax.random.key(0), jnp.asarray(0.0, jnp.float32),
+                    self.step_state)
 
             fb = obs_costmodel.tree_size_cost(
                 self.params, batch=batch, extra=self.opt_state)
@@ -893,8 +1012,9 @@ class ParallelEngine:
         t2 = time.time_ns()
         traces = self.trace_count
         with TraceAnnotation("train/dispatch"):
-            loss, self.params, self.opt_state = self._jit(
-                self.params, self.opt_state, batch, next_key(), lr_val)
+            loss, self.params, self.opt_state, self.step_state = self._jit(
+                self.params, self.opt_state, batch, next_key(), lr_val,
+                self.step_state)
             if donated is not None:
                 # the old params/opt_state buffers were donated: poison
                 # them so a use-after-donate (a stale alias anywhere)
@@ -914,16 +1034,16 @@ class ParallelEngine:
 
     def _many_body(self):
         """k optimizer steps as one ``lax.scan`` over the step."""
-        def multi_step(params, opt_state, batches, keys, lrs):
+        def multi_step(params, opt_state, batches, keys, lrs, step_state):
             def body(carry, xs):
-                p, s = carry
+                p, s, st = carry
                 b, key, lr_ = xs
-                loss, p, s = self._step_fn(p, s, b, key, lr_)
-                return (p, s), loss
+                loss, p, s, st = self._step_fn(p, s, b, key, lr_, st)
+                return (p, s, st), loss
 
-            (params, opt_state), losses = jax.lax.scan(
-                body, (params, opt_state), (batches, keys, lrs))
-            return losses, params, opt_state
+            (params, opt_state, step_state), losses = jax.lax.scan(
+                body, (params, opt_state, step_state), (batches, keys, lrs))
+            return losses, params, opt_state, step_state
         return multi_step
 
     def _jit_many(self, k: int):
@@ -960,8 +1080,8 @@ class ParallelEngine:
                                     counted=False)
                 key = jax.random.split(jax.random.key(0), k)
                 lr = jnp.zeros((k,), jnp.float32)
-            text = fn.lower(self.params, self.opt_state, feeds, key,
-                            lr).compile().as_text()
+            text = fn.lower(self.params, self.opt_state, feeds, key, lr,
+                            self.step_state).compile().as_text()
             self._step_text[(kind, sig)] = text
         return text
 
@@ -1010,8 +1130,9 @@ class ParallelEngine:
         t2 = time.time_ns()
         traces = self.trace_count
         with TraceAnnotation("train/dispatch"):
-            losses, self.params, self.opt_state = self._jit_many(k)(
-                self.params, self.opt_state, stacked, keys, lrs)
+            losses, self.params, self.opt_state, self.step_state = \
+                self._jit_many(k)(self.params, self.opt_state, stacked,
+                                  keys, lrs, self.step_state)
             if donated is not None:
                 self._jsan.poison_donated(donated)
         t3 = time.time_ns()
@@ -1101,6 +1222,9 @@ class ParallelEngine:
             if k in sd:
                 sd[k]._data = jnp.array(arr, copy=True) if self._donate \
                     else arr
+        for k, b in self.model.named_buffers():
+            if k in self.step_state:        # never donated: no copy
+                b._data = self.step_state[k]
 
     # -- sharded checkpoint (reference save_persistables sliced-vars
     # analog; see distributed/checkpoint.py) ---------------------------------

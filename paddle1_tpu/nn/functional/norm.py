@@ -36,37 +36,46 @@ def _t(x):
 # buffer's warning and the registry can't grow unboundedly.
 _warned_stat_buffers: dict = {}
 
-# Functionalized running-stat capture (ADVICE r5 medium; PR 3 only
+# State written in a forward, functionalized (ADVICE r5 medium; PR 3 only
 # added the warning): a framework-owned compiled path (ParallelEngine's
-# train step) opens a collector around the traced forward; batch-norm
-# layers whose batch stats come back as tracers REGISTER the update
-# here instead of warning, the step builder folds the blended running
-# stats back into the step's output params, and the engine's normal
-# param flow (sync_model / checkpoints) assigns them outside the trace.
-# User-compiled fns (plain jax.jit / to_static) have no collector, so
-# they keep the loud warn-and-skip path.
+# train step) opens a collector around the traced forward; a layer whose
+# new state comes back as tracers (batch norm's batch statistics, the
+# expert layer's load counts) RECORDS the update here instead of
+# assigning a tracer into its buffer, the step builder folds it into the
+# step's outputs by the record's rule, and the engine's normal flow
+# (sync_model / checkpoints) assigns it outside the trace.
+# ``fleet.utils.recompute`` opens a collector of its own inside its
+# ``jax.checkpoint`` segment, hands what was recorded out as outputs of
+# the segment and records it again outside: a record never holds a
+# tracer of a trace that has ended. User-compiled fns (plain jax.jit /
+# to_static) have no collector, so they keep the loud warn-and-skip
+# path. This is the one channel: no second sink, no host callback.
 _stat_sink = threading.local()
 
 
-class _StatUpdate:
-    """One traced running-stat update: the OLD buffer arrays (identity
-    keys into the compiled step's params dict), the traced batch stats,
-    and the layer momentum."""
+class StateUpdate:
+    """One traced write to a buffer: the buffer's Tensor (its identity
+    is the key into the compiled step's state: ``load_functional_state``
+    swaps arrays inside the same Tensor), the traced value and the rule
+    that folds it into the old value: ``blend`` (``momentum * old + (1 -
+    momentum) * value``: a running statistic) or ``add`` (``old +
+    value``: a counter; several writes of one forward sum)."""
 
-    __slots__ = ("old_mean", "old_var", "mean", "var", "momentum")
+    __slots__ = ("buffer", "value", "rule", "momentum", "what")
 
-    def __init__(self, old_mean, old_var, mean, var, momentum):
-        self.old_mean = old_mean
-        self.old_var = old_var
-        self.mean = mean
-        self.var = var
+    def __init__(self, buffer, value, rule, momentum=None, what=None):
+        self.buffer = buffer
+        self.value = value
+        self.rule = rule
         self.momentum = momentum
+        self.what = what
 
 
 @contextlib.contextmanager
 def collect_stat_updates():
-    """Arm the functionalized running-stat capture for this thread's
-    current trace; yields the list the step builder consumes."""
+    """Arm the functionalized capture of state written in a forward for
+    this thread's current trace; yields the list of :class:`StateUpdate`
+    the step builder (or the recomputed segment) consumes."""
     prev = getattr(_stat_sink, "sink", None)
     sink: list = []
     _stat_sink.sink = sink
@@ -76,16 +85,38 @@ def collect_stat_updates():
         _stat_sink.sink = prev
 
 
+def fold_state_update(old, value, rule, momentum=None):
+    """``old`` after one recorded write, in ``old``'s dtype."""
+    if rule == "add":
+        return old + value.astype(old.dtype)
+    cur = old.astype(jnp.float32)
+    return (momentum * cur + (1 - momentum) * value).astype(old.dtype)
+
+
+def record_state_update(buffer, value, rule, momentum=None,
+                        what: Optional[str] = None) -> None:
+    """A forward writes ``buffer`` (a Tensor) by ``rule``. A concrete
+    ``value`` is folded in at once (the eager path). A traced one goes
+    to the active collector; with none (a user-compiled fn) the write is
+    skipped, with a warning where ``what`` names a statistic that eval
+    forwards will miss, silently for a counter (``what`` None)."""
+    if not isinstance(value, jax.core.Tracer):
+        buffer._data = fold_state_update(buffer.data, value, rule, momentum)
+        return
+    sink = getattr(_stat_sink, "sink", None)
+    if sink is not None:
+        sink.append(StateUpdate(buffer, value, rule, momentum, what))
+    elif what is not None:
+        warn_traced_stats_skipped(buffer, what)
+
+
 def _record_traced_stat_update(running_mean, running_var, mean_arr,
                                var_arr, momentum, what: str) -> None:
     """Batch stats arrived as tracers: functionalize under an active
     collector, else warn-and-skip (user-compiled fn)."""
-    sink = getattr(_stat_sink, "sink", None)
-    if sink is None:
-        warn_traced_stats_skipped(running_mean, what)
-        return
-    sink.append(_StatUpdate(running_mean.data, running_var.data,
-                            mean_arr, var_arr, momentum))
+    record_state_update(running_mean, mean_arr, "blend", momentum, what)
+    # the mean's warning covers the pair
+    record_state_update(running_var, var_arr, "blend", momentum)
 
 
 def warn_traced_stats_skipped(buffer, what: str) -> None:

@@ -47,6 +47,25 @@ over every pick paid 36.7 ns for each of the 98,304 rows it fetched from
 HBM at Kanana-2's size, 87% of them a row of zeros (PERF.md section 6,
 PR 32).
 
+**The load is counted where it falls** (PR 36): every forward adds to
+the buffer ``expert_load`` (int32 ``[held + 4]``, non-persistable: state
+of a run, not of the model, so not in ``state_dict()``) what
+``sort_picks`` already made: the rows each held expert ran within the
+capacity, then ``held_picks`` (those rows plus the late picks),
+``late_picks`` (held picks that found no row and went through
+``moe_overflow``), ``late_steps`` (forwards with a late pick) and
+``steps`` (forwards counted: one a step, one a micro-step under
+gradient accumulation). Exact integers, on the device, always on: the
+eager path adds into the buffer, a compiled step records the add through
+``nn/functional/norm.py::record_state_update`` and ``ParallelEngine``
+carries the buffer beside the parameters (``ParallelEngine.expert_load()``
+reads it; :meth:`RoutedExperts.read_load` names the fields). A forward
+compiled by the user's own ``jax.jit`` has no collector and counts
+nothing. 32 bits: a raw read wraps to negative after 2^31 of a field
+(131,072 held picks a step reach that in 16 k steps); a reader that takes
+differences modulo 2^32 at least that often stays exact, as the process
+registry's ``moe_*`` series do.
+
 Names in a traced step: everything under the scope ``moe``; ops
 ``moe_router`` (float32 whatever the autocast), ``moe_dispatch`` (sort,
 gather; backward, the kernel ``p1t_sum_picks_fwd``), ``routed_experts``
@@ -68,6 +87,7 @@ from ..core.flags import in_auto_partitioned_region
 from ..core.tensor import Tensor
 from ..obs.costmodel import SCOPE_ATTRIBUTE
 from ..ops.pallas import sum_picks as sum_picks_kernel
+from .functional.norm import record_state_update
 from .initializer import Constant
 from .layer_base import Layer
 from .layer_transformer import GatedFeedForward
@@ -76,6 +96,8 @@ __all__ = ["RoutedExperts"]
 
 _ROW_TILE = 512     # capacity is a multiple: a grouped product's row tile
 CAPACITY_FACTOR = 3  # rows of the grouped products over even routing's picks
+# ``expert_load`` behind its first ``held`` entries (rows an expert)
+LOAD_TAIL = ("held_picks", "late_picks", "late_steps", "steps")
 
 
 def route(x, w_gate, bias, top_k, scale, scoring="sigmoid"):
@@ -240,6 +262,23 @@ class RoutedExperts(Layer):
         self.shared_experts = (GatedFeedForward(d_model, shared_width,
                                                 weight_attr)
                                if shared_width else None)
+        # the module's docstring: rows of each held expert, LOAD_TAIL
+        self.register_buffer("expert_load", Tensor(
+            jnp.zeros((self.held + len(LOAD_TAIL),), jnp.int32),
+            stop_gradient=True), persistable=False)
+        self._load_shape = None     # (picks made, capacity) of a forward
+
+    def read_load(self, counts):
+        """``expert_load`` (or a copy of it from a compiled step) as a
+        dict: ``rows`` [held], the fields of :data:`LOAD_TAIL`, and what
+        is static beside them (``picks_made_a_step`` and ``capacity_rows``
+        of the last forward's shape, None before the first)."""
+        counts = [int(c) for c in counts]
+        made, capacity = self._load_shape or (None, None)
+        return {"rows": counts[:self.held],
+                **dict(zip(LOAD_TAIL, counts[self.held:])),
+                "picks_made_a_step": made, "capacity_rows": capacity,
+                "held": self.held, "num_experts": self.num_experts}
 
     def forward(self, x):
         from ..ops import manip_ops
@@ -266,11 +305,16 @@ class RoutedExperts(Layer):
         def dispatch(x, weights, chosen):
             order, where, sizes, overflow = sort_picks(chosen, first, held,
                                                        capacity)
+            load = jnp.concatenate([sizes, jnp.stack([
+                jnp.sum(sizes) + overflow, overflow,
+                (overflow > 0).astype(jnp.int32), jnp.int32(1)])])
             return (rows_in_order(x, order, where, k),
                     rows_in_order(weights.reshape(-1), order, where, 1),
-                    order, where, sizes, overflow)
-        xs, ws, order, where, sizes, overflow = apply(
+                    order, where, sizes, overflow, load)
+        xs, ws, order, where, sizes, overflow, load = apply(
             "moe_dispatch", dispatch, (x, weights, chosen))
+        self._load_shape = (tokens * k, capacity)
+        record_state_update(self.expert_load, load.data, "add")
         out = apply("routed_experts", expert_ffn,
                     (xs, sizes, self.gate_up_proj, self.down_proj))
 

@@ -58,7 +58,7 @@ __all__ = ["ExecutableCost", "analyze", "site_cost", "tree_bytes",
            "tree_size_cost", "forward_cost", "device_peak_flops",
            "device_peak_hbm_bw", "clear_cache", "parse_op_scopes",
            "region_of", "step_op_scopes", "step_fused_regions",
-           "step_phase_records"]
+           "step_phase_records", "step_expert_load"]
 
 
 @dataclass(frozen=True)
@@ -391,6 +391,15 @@ def step_fused_regions() -> Optional[Dict[str, Tuple[str, ...]]]:
     counted under its own ``op_name`` alone."""
     parsed = _step_parsed()
     return None if parsed is None else parsed[1]
+
+
+def step_expert_load() -> Optional[Dict[str, dict]]:
+    """``ParallelEngine.expert_load()`` of the same engine: what its
+    routed-expert layers counted on the device, by layer path. None
+    where no engine has stepped or no layer of its model counts."""
+    engine = _stepping_engine()
+    load = getattr(engine, "expert_load", dict)()
+    return load or None
 
 
 def step_phase_records() -> list:

@@ -166,7 +166,10 @@ def _fmt_line(name, value, pairs=(), label=None):
     them)."""
     pairs = [p for p in pairs if p is not None]
     if label is not None:
-        pairs = pairs + [label]
+        # one (key, value) pair, or a tuple of them (a group keyed by
+        # several labels)
+        pairs = pairs + (list(label) if isinstance(label[0], tuple)
+                         else [label])
     if pairs:
         lab = ",".join(f'{k}="{v}"' for k, v in pairs)
         return f"{name}{{{lab}}} {value}"
@@ -353,16 +356,19 @@ class MetricsGroup:
     serves two versions at once; mixing their latency histograms would
     hide a regression in the new one behind the old one's volume).
     Children are created on first touch, like the registry's own
-    counters; :meth:`aggregate` folds them into one fleet-wide view."""
+    counters; :meth:`aggregate` folds them into one fleet-wide view.
+    ``label_key`` may be a tuple of keys: a child's label is then a
+    tuple of as many values (``{layer="...",expert="3"}``)."""
 
-    def __init__(self, label_key: str, namespace: str = "p1t_serving"):
+    def __init__(self, label_key, namespace: str = "p1t_serving"):
         self.label_key = label_key
         self.namespace = namespace
         self._lock = locks.make_lock("MetricsGroup._lock")
-        self._children: Dict[str, MetricsRegistry] = {}  # guarded-by: self._lock
+        self._children: Dict[object, MetricsRegistry] = {}  # guarded-by: self._lock
 
     def child(self, label) -> MetricsRegistry:
-        label = str(label)
+        label = (tuple(str(v) for v in label)
+                 if isinstance(self.label_key, tuple) else str(label))
         m = self._children.get(label)
         if m is None:
             with self._lock:
@@ -377,7 +383,8 @@ class MetricsGroup:
     def snapshot(self) -> Dict[str, Dict[str, object]]:
         with self._lock:
             kids = dict(self._children)
-        return {label: m.snapshot() for label, m in sorted(kids.items())}
+        return {",".join(label) if isinstance(label, tuple) else label:
+                m.snapshot() for label, m in sorted(kids.items())}
 
     def aggregate(self) -> Dict[str, object]:
         return merge_snapshots(self.snapshot().values())
@@ -386,8 +393,11 @@ class MetricsGroup:
         with self._lock:
             kids = dict(self._children)
         return "".join(
-            m.render_text(label=(self.label_key, label),
-                          type_headers=False)
+            m.render_text(
+                label=(tuple(zip(self.label_key, label))
+                       if isinstance(label, tuple)
+                       else (self.label_key, label)),
+                type_headers=False)
             for label, m in sorted(kids.items()))
 
 
@@ -465,7 +475,7 @@ def render_snapshot_text(snap: Dict[str, object], namespace: str,
 
 _process_lock = threading.Lock()
 _process: Optional[MetricsRegistry] = None
-_groups: Dict[str, MetricsGroup] = {}  # guarded-by: _process_lock
+_groups: Dict[object, MetricsGroup] = {}  # guarded-by: _process_lock
 _snapshot_thread: Optional[threading.Thread] = None
 
 
@@ -487,11 +497,22 @@ def process_registry() -> MetricsRegistry:
     return m
 
 
-def process_group(label_key: str) -> MetricsGroup:
+def process_group(label_key) -> MetricsGroup:
     """THE process's labeled family keyed by ``label_key`` (namespace
     ``p1t``), created on first touch: ``process_group("arm")
     .child("flash").counter("attention_arm_total")`` is the series
-    ``p1t_attention_arm_total{arm="flash"}`` on the ``/metrics`` page."""
+    ``p1t_attention_arm_total{arm="flash"}`` on the ``/metrics`` page;
+    ``process_group(("layer", "expert")).child((path, 3))`` carries both
+    labels. The labeled series of the training side: ``attention_arm_
+    total{arm}``, ``flash_tiles_total{kind}`` (counted when a kernel call
+    is traced), and what the routed-expert layers count on the device in
+    every compiled step, fed by differences at a loss readback under
+    ``obs_metrics`` (``ParallelEngine._publish_load``), each beside the
+    benchmark metric that reads the same counter: ``moe_picks_held_
+    total{layer}`` (``moe_held_picks_pct``), ``moe_expert_rows_total
+    {layer,expert}`` (``moe_expert_rows_max``), ``moe_picks_late_total
+    {layer}`` and ``moe_late_steps_total{layer}`` (``moe_late_picks``),
+    and the gauge ``moe_capacity_rows{layer}`` they are read against."""
     g = _groups.get(label_key)
     if g is None:
         with _process_lock:
@@ -502,7 +523,8 @@ def process_group(label_key: str) -> MetricsGroup:
 
 def render_process_groups() -> str:
     with _process_lock:
-        groups = [g for _, g in sorted(_groups.items())]
+        groups = [g for _, g in sorted(_groups.items(),
+                                       key=lambda kv: str(kv[0]))]
     return "".join(g.render_text() for g in groups)
 
 

@@ -320,8 +320,9 @@ class TestTrainComposition:
             with collect_stat_updates() as sink:
                 y = jax.jit(step)(jnp.asarray(x, dtype))
         assert y.dtype == jnp.dtype(dtype)
-        assert sink[0].mean.dtype == jnp.float32
-        assert sink[0].var.dtype == jnp.float32
+        assert [u.rule for u in sink] == ["blend", "blend"]
+        assert sink[0].value.dtype == jnp.float32      # the mean
+        assert sink[1].value.dtype == jnp.float32      # the variance
 
     @pytest.mark.parametrize("dtype,centre,rtol", [
         # bf16 data at mean 300, spread 1: a bf16 mean is off by up to 1
@@ -428,7 +429,7 @@ class TestStatisticsCarryNoGradient:
                 y = F.fused_batch_norm_act(
                     Tensor(xa), to_tensor(m0.copy()), to_tensor(v0.copy()),
                     Tensor(ga), Tensor(ba), training=True, act="relu").data
-            stats = sink[0].mean.sum() + sink[0].var.sum()
+            stats = sink[0].value.sum() + sink[1].value.sum()
             return (y * y).sum() + weight_of_stats * stats, stats
 
         return loss, (jnp.asarray(x), jnp.asarray(g), jnp.asarray(b))
@@ -584,8 +585,8 @@ class TestCompiledTrainerIntegration:
                                         to_tensor(g), to_tensor(b),
                                         training=True).data
                 jax.jit(step)(jnp.asarray(x))
-        assert len(sink) == 1
-        assert sink[0].momentum == 0.9
+        assert len(sink) == 2          # the mean's record and the variance's
+        assert [u.momentum for u in sink] == [0.9, 0.9]
 
 
 class TestSyncBatchNormFused:

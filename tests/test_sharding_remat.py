@@ -73,7 +73,7 @@ class TestHybridZero2Lowering(unittest.TestCase):
         placed = engine.shard_batch(batch)
         lowered = engine._jit.lower(engine.params, engine.opt_state, placed,
                                     jax.random.PRNGKey(0),
-                                    np.float32(1e-4))
+                                    np.float32(1e-4), engine.step_state)
         with _CaptureFd2() as cap:
             compiled = lowered.compile()
         self.assertNotIn("Involuntary full rematerialization", cap.text,

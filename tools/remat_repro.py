@@ -46,7 +46,7 @@ def main():
     placed = engine.shard_batch(batch)
     import jax.random as jrandom
     lowered = engine._jit.lower(engine.params, engine.opt_state, placed,
-                                jrandom.PRNGKey(0), 1e-4)
+                                jrandom.PRNGKey(0), 1e-4, engine.step_state)
     compiled = lowered.compile()
     hlo = compiled.as_text()
     lines = [ln for ln in hlo.splitlines() if "f32[2,32,64]" in ln]
