@@ -11,12 +11,19 @@ wrapping the segment's pure function. RNG parity (reference saves/restores
 CUDA seeds, recompute.py:88-114) comes for free: the segment's dropout keys
 are explicit inputs, so the re-forward reuses identical keys.
 
-One thing inside a segment is kept beside its inputs: what the blockwise
-attention kernel made (``ops/pallas/flash_attention.py`` names its ``out``
-and ``lse``, the two things its backward kernels need of it). Running
-that kernel again costs more a byte kept than anything else in a decoder
-block (0.042 ms a MB on a v5e against 0.011-0.015 for q / k / v, the
-SwiGLU inputs or a whole block: PERF.md, PR 30).
+Beside its inputs a segment keeps what costs more to make again than to
+hold, by name: the op or layer that makes such a value names it where it
+makes it (``core/recompute_keeps.py::keep_in_recompute``: the rule, 0.02
+ms a MB kept on a v5e, and the registry of names), and the segment's
+policy keeps every named value that its backward pass reads. Named so
+far, each with its measured ms a MB beside it: the blockwise attention
+kernel's ``out`` and ``lse`` (``ops/pallas/flash_attention.py``), the
+output of a gated feed-forward's narrowing ``down_proj``
+(``nn/layer_transformer.py``), the routed-expert layer's picks, pick
+scores, sorted order and sorted rows' weights (``nn/layer_moe.py``), the
+stream after attention in Kanana-2's and SDAR's pre-norm layers
+(``text/models``). Nothing here chooses among them: no flag, no argument,
+no budget.
 
 State that the segment's forward writes (batch norm's running
 statistics, the expert layer's load counts) cannot stay in the side list
@@ -34,30 +41,28 @@ from typing import Any, Callable
 import jax
 
 from ....autograd.engine import apply, no_grad
+from ....core import recompute_keeps
 from ....core.generator import next_key, rng_scope
 from ....core.tensor import Tensor
 from ....nn.functional import norm as fnorm
 from ....nn.layer_base import Layer
-from ....ops.pallas.flash_attention import KEPT_RESIDUALS
 
 __all__ = ["recompute", "recompute_sequential"]
 
-# what a recomputed segment keeps beside its inputs
-_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
-
 
 def recompute(function: Callable, *args, **kwargs):
-    """Run ``function(*args)`` keeping its inputs and, where its attention
-    took the Pallas kernels (``use_flash_for``: both sequences >= 1024 on
-    a TPU), each kernel call's ``out`` and ``lse``; backward re-executes
-    the rest (reference recompute.py:162 recompute()) and does not run
-    the forward kernel a second time. A segment with XLA's dense
-    attention, or with none, holds no such name and keeps its inputs
-    alone. What the kernel's outputs cost: 2 bytes x tokens x heads x
-    head_dim a call (``lse`` is 4 bytes x tokens x heads), so a
-    transformer block at sequence >= 1024 under recomputation keeps about
-    twice what it kept before, its input and one more tensor of that
-    size."""
+    """Run ``function(*args)`` keeping its inputs and every value inside
+    that carries a name of ``core/recompute_keeps.py`` and is read by the
+    backward pass; backward re-executes the rest (reference
+    recompute.py:162 recompute()). A segment that holds no named value
+    (a block with XLA's dense attention and a plain feed-forward) keeps
+    its inputs alone. What is kept is the value the forward pass made,
+    to the bit. What it costs follows from the names inside: the
+    attention kernel's ``out`` is one more tensor of the input's size a
+    call at sequence >= 1024 (``lse`` 4 bytes x tokens x heads), a
+    narrowing projection's output another;
+    ``recompute_kept_bytes_total{name}`` in the process registry sums
+    what each traced segment was given."""
     preserve = kwargs.pop("preserve_rng_state", True)
     use_reentrant = kwargs.pop("use_reentrant", True)
     if kwargs:
@@ -97,7 +102,7 @@ def recompute(function: Callable, *args, **kwargs):
         # whether it returned a tuple
         made: dict = {}
 
-        @functools.partial(jax.checkpoint, policy=_KEEP)
+        @functools.partial(jax.checkpoint, policy=recompute_keeps.keeps)
         def seg(key, param_arrays, *input_arrays):
             # tape off: the segment is differentiated as a whole, by
             # jax, and an op's own vjp rule has to reach it unopened.
@@ -105,7 +110,8 @@ def recompute(function: Callable, *args, **kwargs):
             # is a tracer of this trace and leaves it as an output
             with rng_scope(key), no_grad(), layer.load_functional_state(
                     dict(zip(names, param_arrays))), \
-                    fnorm.collect_stat_updates() as sink:
+                    fnorm.collect_stat_updates() as sink, \
+                    recompute_keeps.segment():
                 out = fwd_callable(*_rebuild_args(input_arrays))
             made["tuple"] = isinstance(out, (tuple, list))
             outs = tuple(t.data for t in out) if made["tuple"] \

@@ -66,6 +66,15 @@ nothing. 32 bits: a raw read wraps to negative after 2^31 of a field
 differences modulo 2^32 at least that often stays exact, as the process
 registry's ``moe_*`` series do.
 
+**Under ``fleet.utils.recompute``** the small things that are dear to
+make again carry a name (``core/recompute_keeps.py``) and are kept: a
+token's picked scores and experts (:func:`route`), the sorted order, each
+pick's row, the rows an expert and the late picks (:func:`sort_picks`),
+and the pick weights in sorted order: 1.6 MB a layer at Kanana-2's size
+against a ``top_k``, two sorts and two gathers of scalars (3 ms a layer;
+PERF.md, PR 37). The gathers of whole rows and the grouped products run
+again: they are cheap for their bytes.
+
 Names in a traced step: everything under the scope ``moe``; ops
 ``moe_router`` (float32 whatever the autocast), ``moe_dispatch`` (sort,
 gather; backward, the kernel ``p1t_sum_picks_fwd``), ``routed_experts``
@@ -84,6 +93,7 @@ from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..autograd.engine import apply, scope
 from ..core.flags import in_auto_partitioned_region
+from ..core.recompute_keeps import keep_in_recompute
 from ..core.tensor import Tensor
 from ..obs.costmodel import SCOPE_ATTRIBUTE
 from ..ops.pallas import sum_picks as sum_picks_kernel
@@ -111,14 +121,61 @@ def route(x, w_gate, bias, top_k, scale, scoring="sigmoid"):
     logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax":
-        picked, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        picked, chosen = top_scores(jax.nn.softmax(logits, axis=-1), top_k)
         weights = picked / jnp.sum(picked, -1, keepdims=True) * scale
-        return weights, chosen.astype(jnp.int32)
+        return weights, chosen
     s = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
-    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    chosen = _named_chosen(
+        jax.lax.top_k(s + bias.astype(jnp.float32), top_k)[1])
+    picked = _named_scores(jnp.take_along_axis(s, chosen, axis=-1))
     weights = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) * scale
-    return weights, chosen.astype(jnp.int32)
+    return weights, chosen
+
+
+# A token's picked scores (float32) and experts (int32), each under its
+# name: a recomputed segment keeps 8 bytes a pick and runs no second
+# ``top_k`` over every token's scores (0.20 ms for 0.39 MB of Kanana-2's
+# picks, 0.5 ms a MB on a v5e) nor the gather of the picked ones (0.78
+# ms, 2.0 ms a MB; PERF.md PR 37). Every later reader takes the named
+# value: one that reads what ``top_k`` itself returned makes the segment
+# run it again.
+def _named_scores(picked):
+    return keep_in_recompute(picked, "routed_scores")
+
+
+def _named_chosen(chosen):
+    return keep_in_recompute(chosen.astype(jnp.int32), "routed_chosen")
+
+
+def _top_scores(scores, k):
+    values, chosen = jax.lax.top_k(scores, k)
+    return _named_scores(values), _named_chosen(chosen)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def top_scores(scores, k):
+    """``jax.lax.top_k`` over the last axis -> (values, int32 indices),
+    both named, with a backward pass that reads the named indices
+    (``jax.lax.top_k``'s own reads the ones it made, so a recomputed
+    segment would make them again): a value's gradient goes to the score
+    it was picked from."""
+    return _top_scores(scores, k)
+
+
+def _top_scores_fwd(scores, k):
+    # not through ``top_scores``: a segment's policy has to see the names
+    values, chosen = _top_scores(scores, k)
+    return (values, chosen), (chosen, jax.ShapeDtypeStruct(scores.shape,
+                                                           scores.dtype))
+
+
+def _top_scores_bwd(k, res, d):
+    chosen, scores = res
+    return jax.linear_transpose(
+        lambda s: jnp.take_along_axis(s, chosen, axis=-1), scores)(d[0])
+
+
+top_scores.defvjp(_top_scores_fwd, _top_scores_bwd)
 
 
 def sort_picks(chosen, first, held, capacity):
@@ -139,7 +196,14 @@ def sort_picks(chosen, first, held, capacity):
     ends = jnp.cumsum(counts)
     sizes = jnp.minimum(ends, capacity) - jnp.minimum(ends - counts, capacity)
     where = jnp.where(here & (row < capacity), row, capacity)
-    return order[:capacity], where, sizes, ends[-1] - jnp.sum(sizes)
+    # a recomputed segment keeps the four: 4 bytes a pick and a row
+    # against two ``argsort``s over every pick (0.16 ms for 0.54 MB at
+    # Kanana-2's size, 0.29 ms a MB on a v5e, PERF.md PR 37); integers,
+    # no gradient
+    return (keep_in_recompute(order[:capacity], "routed_order"),
+            keep_in_recompute(where, "routed_where"),
+            keep_in_recompute(sizes, "routed_sizes"),
+            keep_in_recompute(ends[-1] - jnp.sum(sizes), "routed_overflow"))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -308,8 +372,12 @@ class RoutedExperts(Layer):
             load = jnp.concatenate([sizes, jnp.stack([
                 jnp.sum(sizes) + overflow, overflow,
                 (overflow > 0).astype(jnp.int32), jnp.int32(1)])])
+            # the pick weights in sorted order: a gather of scalars, 0.32
+            # ms for 0.15 MB (2.2 ms a MB on a v5e, PERF.md PR 37)
             return (rows_in_order(x, order, where, k),
-                    rows_in_order(weights.reshape(-1), order, where, 1),
+                    keep_in_recompute(rows_in_order(
+                        weights.reshape(-1), order, where, 1),
+                        "routed_row_weights"),
                     order, where, sizes, overflow, load)
         xs, ws, order, where, sizes, overflow, load = apply(
             "moe_dispatch", dispatch, (x, weights, chosen))

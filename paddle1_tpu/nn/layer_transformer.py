@@ -21,6 +21,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..core.errors import InvalidArgumentError
+from ..core.recompute_keeps import keep_in_recompute
 from . import functional as F
 from .layer_base import Layer
 from .layer_common import Dropout, Linear
@@ -257,6 +258,17 @@ class MultiHeadAttention(Layer):
         return out if len(outs) == 1 else tuple(outs)
 
 
+@jax.custom_vjp
+def _made_once(s):
+    """``s`` behind an optimization barrier in the forward pass (none on
+    its gradient): :class:`GatedFeedForward`'s note."""
+    return jax.lax.optimization_barrier(s)
+
+
+_made_once.defvjp(lambda s: (jax.lax.optimization_barrier(s), None),
+                  lambda _, d: (d,))
+
+
 class GatedFeedForward(Layer):
     """``down(silu(gate(x)) * up(x))``, the SwiGLU feed-forward of the
     decoder families after 2020; no reference analog. Three ``linear``
@@ -273,7 +285,23 @@ class GatedFeedForward(Layer):
                                 bias_attr)
 
     def forward(self, x):
-        return self.down_proj(F.swiglu(self.gate_proj(x), self.up_proj(x)))
+        from ..autograd.engine import apply
+        # the gated product is made once, as a value of its own: where a
+        # recomputed segment keeps the output below, XLA otherwise makes
+        # the product again inside the weight-gradient fusion of
+        # ``down_proj``, which it defers, and holds the gate and up
+        # outputs of every layer of a loop step until then (Ouro's step
+        # compiled for a v5e: 7.99 GiB of temporaries against 6.59, and
+        # the chip ran out; PERF.md, PR 37)
+        s = apply("made_once", _made_once,
+                  (F.swiglu(self.gate_proj(x), self.up_proj(x)),))
+        # a recomputed segment keeps the output where its backward reads
+        # it (a norm behind the feed-forward, Ouro's sandwich): the
+        # product contracts over the wide side (K 5632 -> 2048: 1.03 ms
+        # for 33.5 MB, 0.031 ms a MB on a v5e, PERF.md PR 37). Where
+        # nothing reads it (a pre-norm block adds it to the stream) it
+        # is not held
+        return keep_in_recompute(self.down_proj(s), "gated_ffn_out")
 
 
 class TransformerEncoderLayer(Layer):

@@ -505,9 +505,13 @@ def process_group(label_key) -> MetricsGroup:
     ``process_group(("layer", "expert")).child((path, 3))`` carries both
     labels. The labeled series of the training side: ``attention_arm_
     total{arm}``, ``flash_tiles_total{kind}`` (counted when a kernel call
-    is traced), and what the routed-expert layers count on the device in
-    every compiled step, fed by differences at a loss readback under
-    ``obs_metrics`` (``ParallelEngine._publish_load``), each beside the
+    is traced), ``recompute_kept_bytes_total{name}`` and ``recompute_
+    kept_values_total{name}`` (what each traced ``fleet.utils.recompute``
+    segment was given to keep, by the shapes of the values named inside:
+    ``core/recompute_keeps.py``), and what the routed-expert layers count
+    on the device in every compiled step, fed by differences at a loss
+    readback under ``obs_metrics`` (``ParallelEngine._publish_load``),
+    each beside the
     benchmark metric that reads the same counter: ``moe_picks_held_
     total{layer}`` (``moe_held_picks_pct``), ``moe_expert_rows_total
     {layer,expert}`` (``moe_expert_rows_max``), ``moe_picks_late_total

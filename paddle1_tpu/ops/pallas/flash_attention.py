@@ -60,10 +60,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.recompute_keeps import keep_in_recompute
 from . import _common
 from .mask_rules import CAUSAL, NO_MASK
 
@@ -72,10 +72,6 @@ _LANES = 128  # Mosaic minor-dim tile: per-row statistics are kept
 _NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
-# ``jax.ad_checkpoint.checkpoint_name``s of the forward kernel's two
-# outputs: a recomputation whose policy keeps both
-# (distributed/fleet/utils/recompute.py) does not run the kernel again
-KEPT_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
 
 
 def rule_of(causal: bool, mask):
@@ -302,21 +298,29 @@ def _flash_fwd(q, k, v, scale, rule, padding_mask=None, blocks=None):
     the step's trace and lowering then take the kernel once a shape
     (Ouro's 48 calls cost its set-up 11 s otherwise).
 
-    The kernel's two outputs carry names, outside that ``jit``: inert
-    anywhere but under a ``jax.checkpoint`` whose policy keeps both
+    The kernel's two outputs carry names (``core/recompute_keeps.py``),
+    outside that ``jit``: inert anywhere but in a recomputed segment
     (``fleet.utils.recompute``), where they spare the kernel's second
-    run in the backward pass; one alone spares nothing, the kernel makes
-    both or neither. ``out`` is named as the kernel wrote it, before
-    :func:`_unlayout`: named as [B, Nq, H, D] the same bytes cost Ouro's
-    step 48 more layout copies and a compile-cache entry of 131.8 MiB
-    against 126.9 (the step without the names: 129.7; PERF.md, PR 30).
-    q, k and v are not named: a block's projections and rotary run again
-    for 0.015 ms a MB kept, the kernel for 0.042."""
+    run in the backward pass: 0.042 ms a MB kept on a v5e (PERF.md, PR
+    30). One alone spares nothing, the kernel makes both or neither.
+    ``out`` is named as the kernel wrote it, before :func:`_unlayout`:
+    named as [B, Nq, H, D] the same bytes cost Ouro's step 48 more
+    layout copies and a compile-cache entry of 131.8 MiB against 126.9
+    (the step without the names: 129.7; PERF.md, PR 30).
+    q, k and v are not named here: by the rule of that module a value is
+    named where making it again costs 0.02 ms a MB, and what makes these
+    is the caller's. Widening projections (K 2048) run again for 0.010
+    to 0.012 ms a MB in all three cells; with a norm a head in float32
+    behind them (SDAR) q still reads 0.012, because the norm's backward
+    wants the product again whatever is kept; with rotary's float32
+    passes Ouro's q and k read 0.025, and their 24 applications would
+    hold 1.57 GiB, more than that chip has free (PERF.md, PR 37)."""
     (b, _, h, _), dv = q.shape, v.shape[3]
     out, lse = _fwd_call(q, k, v, padding_mask, scale=scale, rule=rule,
                          blocks=blocks, interpret=_common.interpret())
-    out = checkpoint_name(out, KEPT_RESIDUALS[0])
-    return _unlayout(out, b, h, dv), checkpoint_name(lse, KEPT_RESIDUALS[1])
+    out = keep_in_recompute(out, "flash_attention_out")
+    return (_unlayout(out, b, h, dv),
+            keep_in_recompute(lse, "flash_attention_lse"))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "rule", "blocks",

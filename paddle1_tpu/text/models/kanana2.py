@@ -28,9 +28,12 @@ projections and the latent's norm: everything there but the op
 feed-forward under ``mlp`` (and ``mlp/moe`` for an expert layer), the
 head under ``lm_head``. With ``enable_recompute``
 (``ParallelEngine(recompute=True)``) every layer application and the head
-with its cross-entropy run again in the backward pass; the attention
-kernel's ``out`` and ``lse`` are kept (``fleet.utils.recompute``), the
-sort, the gathers and the grouped products of an expert layer are not.
+with its cross-entropy run again in the backward pass, but for what
+carries a name of ``core/recompute_keeps.py``: the attention kernel's
+``out`` and ``lse``, the stream after attention (so ``o_proj`` does not
+run again), an expert layer's picks, their scores, the sorted order and
+the sorted rows' weights (so neither ``top_k`` nor the sorts do); its
+gathers of rows and its grouped products run again.
 """
 
 from __future__ import annotations
@@ -38,6 +41,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ...autograd.engine import apply
+from ...core.recompute_keeps import keep_in_recompute
 from ...framework.param_attr import ParamAttr
 from ...nn import functional as F
 from ...nn.initializer import Normal
@@ -121,7 +125,11 @@ class Kanana2DecoderLayer(Layer):
                                   **experts))
 
     def forward(self, x):
-        a = x + self.self_attn(self.input_layernorm(x))
+        # ``N_2``'s backward reads ``a``: a recomputed segment keeps it
+        # and does not run ``o_proj`` (K 4096 -> 2048) again: 1.53 ms for
+        # 67.1 MB, 0.023 ms a MB on a v5e (PERF.md, PR 37)
+        a = keep_in_recompute(
+            x + self.self_attn(self.input_layernorm(x)), "stream_after_attn")
         return a + self.mlp(self.post_attention_layernorm(a))
 
 

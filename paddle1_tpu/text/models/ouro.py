@@ -37,7 +37,10 @@ application but its attention kernel, that is: where attention takes the
 Pallas kernels (sequences >= 1024 on a TPU) ``fleet.utils.recompute``
 keeps the kernel's ``out`` and ``lse`` beside the layer's input, one more
 hidden-sized tensor an application, and the backward pass does not run the
-forward kernel again (PERF.md, PR 30).
+forward kernel again (PERF.md, PR 30); and the feed-forward's output,
+which the sandwich's last norm reads in the backward pass, one more, so
+``down_proj`` does not run again either (``nn.GatedFeedForward``; PERF.md,
+PR 37).
 """
 
 from __future__ import annotations

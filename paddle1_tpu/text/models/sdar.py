@@ -36,8 +36,10 @@ labels, the weights ``m / t``), a layer under ``layers/<i>``
 the head under ``lm_head`` (``head_cross_entropy``), the weighting and the
 mean as the op ``diffusion_loss``. With ``enable_recompute``
 (``ParallelEngine(recompute=True)``) every layer application and the head
-with its cross-entropy run again in the backward pass; the attention
-kernel's ``out`` and ``lse`` are kept (``fleet.utils.recompute``).
+with its cross-entropy run again in the backward pass, but for what
+carries a name of ``core/recompute_keeps.py``: the attention kernel's
+``out`` and ``lse``, the stream after attention, the expert layer's picks,
+their scores, the sorted order and the sorted rows' weights.
 """
 
 from __future__ import annotations
@@ -45,6 +47,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ...autograd.engine import apply
+from ...core.recompute_keeps import keep_in_recompute
 from ...framework.param_attr import ParamAttr
 from ...nn import functional as F
 from ...nn.initializer import Normal
@@ -89,6 +92,12 @@ class SdarAttention(Layer):
 
         def heads(y, n):
             return manip_ops.reshape(y, [b, s, n, d])
+        # q and k carry no name for a recomputed segment: the norms'
+        # backward reads ``q_proj``'s and ``k_proj``'s outputs, so the
+        # products run again whatever is kept, and what a kept q would
+        # spare (the normed value's float32 passes and rotary) is 1.59 ms
+        # for 134 MB, 0.012 ms a MB on a v5e, k 0.010: under the rule's
+        # 0.02 (``core/recompute_keeps.py``; PERF.md, PR 37)
         q = F.rotary_embedding(
             self.q_norm(heads(self.q_proj(x), self.num_heads)),
             self.rope_theta, positions)
@@ -118,7 +127,12 @@ class SdarDecoderLayer(Layer):
                                  scoring="softmax", **experts)
 
     def forward(self, x, positions):
-        a = x + self.self_attn(self.input_layernorm(x), positions)
+        # ``N_2``'s backward reads ``a``: a recomputed segment keeps it
+        # and does not run ``o_proj`` (K 4096 -> 2048) again: 1.62 ms for
+        # 67.1 MB, 0.024 ms a MB on a v5e (PERF.md, PR 37)
+        a = keep_in_recompute(
+            x + self.self_attn(self.input_layernorm(x), positions),
+            "stream_after_attn")
         return a + self.mlp(self.post_attention_layernorm(a))
 
 

@@ -604,6 +604,11 @@ def test_recomputation_changes_neither_loss_nor_gradients(attention):
 
 
 def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(capsys):
+    """Beside its inputs: the kernel's two outputs (ISSUE 30) and, by
+    name (ISSUE 37), the stream after attention, the picks' scores and
+    experts, the sort's four and the sorted rows' weights (the capacity
+    is every pick at this size); not the shared experts' output, which
+    nothing in the backward pass reads."""
     from jax.ad_checkpoint import print_saved_residuals
     from paddle1_tpu.autograd.engine import no_grad
     from paddle1_tpu.distributed.fleet.utils.recompute import recompute
@@ -620,9 +625,24 @@ def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(capsys):
         print_saved_residuals(loss, state, h)
     lines = capsys.readouterr().out.strip().splitlines()
     beside = [l for l in lines if " from the argument " not in l]
-    assert len(lines) - len(beside) == len(state) + 1   # + the hidden input
-    assert len(beside) == 2 and all("flash_attention.py" in l
-                                    for l in beside), beside
+    # + the hidden input, - the selection bias: the ``top_k`` that read it
+    # does not run again
+    assert len(lines) - len(beside) == len(state)
+    assert not any("e_score_correction_bias" in l for l in lines)
+    tokens, k = 2 * 128, CFG["num_experts_per_tok"]
+    floats = sorted(l.split()[0] for l in beside if l.startswith("f32"))
+    assert floats == sorted([
+        "f32[4,128,16]", "f32[4,128]",                 # the kernel's two
+        f"f32[2,128,{CFG['hidden_size']}]",            # the stream, once
+        f"f32[{tokens},{k}]",                          # the picks' scores
+        f"f32[{tokens * k}]"]), beside                 # the rows' weights
+    # the rest: the picks' experts and the sort's integers (or an index
+    # made of them), a pick or a row each at most
+    rest = [l for l in beside if not l.startswith("f32")]
+    assert len(rest) >= 4 and all(
+        l.split()[0] in (f"i32[{tokens},{k}]", f"i32[{tokens * k}]",
+                         f"i32[{CFG['n_routed_experts']}]", "i32[]")
+        for l in rest), rest
 
 
 def _engine(recompute=True, amp=None, bias=None):

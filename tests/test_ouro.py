@@ -288,12 +288,15 @@ def _kernels(jaxpr):
 
 
 # (the segment, the flag ``flash_attention``) -> forward kernels in the
-# segment's gradient, and what it keeps beside its inputs
+# segment's gradient, and what it keeps beside its inputs: the kernel's
+# ``out`` and ``lse`` (ISSUE 30) and the feed-forward's output, which the
+# sandwich's last norm reads (ISSUE 37)
+FFN_OUT = "f32[2,128,32] output of reduce_precision"
 KEPT = {
     "kernel_attention": ("block", "always", 1,
                          ["f32[4,128,16]", "f32[4,128] named "
-                          "'flash_attention_lse'"]),
-    "dense_attention": ("block", "never", 0, []),
+                          "'flash_attention_lse'", FFN_OUT]),
+    "dense_attention": ("block", "never", 0, [FFN_OUT]),
     "exit_head": ("exit_head", "always", 0, []),
 }
 
@@ -301,10 +304,11 @@ KEPT = {
 @pytest.mark.parametrize("case", sorted(KEPT))
 def test_a_recomputed_segment_keeps_the_kernels_outputs_and_no_more(
         case, capsys):
-    """``recompute`` keeps a segment's inputs and what the attention
-    kernel made (``out``, ``lse``), so that the backward pass does not run
-    the forward kernel again; a segment that took dense attention, or has
-    no attention, keeps its inputs alone (ISSUE 30)."""
+    """``recompute`` keeps a segment's inputs and what carries a name
+    inside: what the attention kernel made (``out``, ``lse``), so that
+    the backward pass does not run the forward kernel again, and the
+    feed-forward's output; a segment that took dense attention keeps the
+    last alone, one with neither (the exit head) its inputs alone."""
     from paddle1_tpu.distributed.fleet.utils.recompute import recompute
     segment, flag, forward_kernels, kept = KEPT[case]
     model, _, _ = _model()
@@ -334,7 +338,8 @@ def test_a_recomputed_segment_keeps_the_kernels_outputs_and_no_more(
     assert len(beside) == len(kept), beside
     for line, what in zip(beside, kept):
         assert line.startswith(what), line
-        assert "flash_attention.py" in line
+        assert ("recompute_keeps.py" if what == FFN_OUT
+                else "flash_attention.py") in line
 
 
 # -- through the engine -----------------------------------------------------
