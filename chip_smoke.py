@@ -10,9 +10,12 @@ and one ``step_many`` of 2 through ``ParallelEngine``, each main-path
 Pallas kernel runs once against its XLA reference at a real width, the
 blockwise attention kernels run block diffusion's mask rule with grouped
 key/value heads at SDAR's size against a float32 dense-mask reference in
-blocks, and the routed-expert layer takes more held picks than its grouped
-products have rows and counts the late ones on the device, eagerly and in
-two compiled steps (``ParallelEngine.expert_load()``). Every
+blocks, the causal rule runs with 32 / 8 heads at head width 64 over LFM2's
+16,384 positions and the two gated short-convolution kernels run at its
+size against float32 shifted sums, and the routed-expert layer takes more
+held picks than its grouped products have rows and counts the late ones
+on the device, eagerly and in two compiled steps
+(``ParallelEngine.expert_load()``). Every
 check that fails raises: no phase may fail and the script still exit 0,
 and no kernel gives way to its reference. One process, no child that
 needs the chip.
@@ -387,6 +390,94 @@ def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
               "(smoke reading, not a metric)", flush=True)
 
 
+def lfm2_phase(seq=16384, heads=32, kv_heads=8, dim=64, channels=2048,
+               taps=3):
+    """LFM2-24B-A2B's two operators at its size: the blockwise kernels
+    under the causal rule with 32 / 8 heads at head width 64 ([1, 16384,
+    32 / 8, 64] bf16: no multiple of the 128-lane tile, so q, k, dq and dk
+    take the transposed layout) against a float32 reference a block of
+    queries at a time, and the two gated short-convolution kernels ([1,
+    16384, 3 x 2048] bf16) against the shifted sums in float32."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.nn.functional import short_conv as op
+    from paddle1_tpu.ops.pallas import flash_attention
+    from paddle1_tpu.ops.pallas import short_conv as kernels
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    group = heads // kv_heads
+    keys = jax.random.split(jax.random.key(38), 7)
+    q = jax.random.normal(keys[0], (1, seq, heads, dim), bf16)
+    k = jax.random.normal(keys[1], (1, seq, kv_heads, dim), bf16)
+    v = jax.random.normal(keys[2], (1, seq, kv_heads, dim), bf16)
+    dout = jax.random.normal(keys[3], (1, seq, heads, dim), bf16)
+    at = f"[1, {seq}, {heads}/{kv_heads}, {dim}] causal"
+    rows = 512
+
+    def plain(q, k, v):
+        qg = q[0].astype(f32).reshape(seq // rows, rows, kv_heads, group, dim)
+        kf, vf = k[0].astype(f32), v[0].astype(f32)
+
+        @jax.checkpoint
+        def some(args):
+            qb, at = args
+            scores = jnp.einsum("qngd,knd->ngqk", qb, kf) / dim ** 0.5
+            seen = jnp.arange(seq)[None] <= at[:, None]
+            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            return jnp.einsum("ngqk,knd->qngd", probs, vf)
+        out = jax.lax.map(some, (qg, jnp.arange(seq).reshape(-1, rows)))
+        return out.reshape(1, seq, heads, dim)
+
+    def vjp_of(fn):
+        def f(*args):
+            out, pull = jax.vjp(fn, *args[:-1])
+            return (out,) + pull(args[-1].astype(out.dtype))
+        return jax.jit(f)
+
+    got = vjp_of(lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, causal=True))
+    with jax.default_matmul_precision("highest"):
+        want = vjp_of(plain)(q, k, v, dout.astype(f32))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got(q, k, v, dout),
+                          want):
+        err = max_err(g, w) / float(np.max(np.abs(np.asarray(w))))
+        check(err <= 5e-2, f"flash {at} {name}: max abs err / max |ref| = "
+                           f"{err:.2e} <= 5e-2")
+
+    bcx = jax.random.normal(keys[4], (1, seq, 3 * channels), bf16)
+    w = (0.5 * jax.random.normal(keys[5], (channels, taps))).astype(bf16)
+    g = jax.random.normal(keys[6], (1, seq, channels), bf16)
+    at = f"[1, {seq}, 3 x {channels}] {taps} taps"
+    check(kernels.supported(bcx.shape, taps) and op._use_kernels(bcx, w),
+          f"gated_short_conv {at} takes the kernels")
+
+    def shifted_sums(bcx, w, g):
+        """The op's XLA form, in float32."""
+        b, c, x = op._parts(bcx)
+        y = op._taps_sum(b * x, w, op._earlier)
+        dy = g.astype(f32) * c
+        ds = op._taps_sum(dy, w, op._later)
+        dw = jnp.stack([jnp.sum(dy * op._earlier(b * x, taps - 1 - j), (0, 1))
+                        for j in range(taps)], 1)
+        return c * y, jnp.concatenate([ds * x, g.astype(f32) * y, ds * b],
+                                      -1), dw
+    want = jax.jit(shifted_sums)(bcx, w, g)
+    fwd, bwd = jax.jit(kernels.forward), jax.jit(kernels.backward)
+    have = (fwd(bcx, w),) + bwd(bcx, w, g)
+    for name, a, b in zip(("out", "d bcx", "d taps"), have, want):
+        err = max_err(a, b) / float(np.max(np.abs(np.asarray(b))))
+        check(err <= 2e-2, f"gated_short_conv {at} {name}: max abs err / "
+                           f"max |ref| = {err:.2e} <= 2e-2")
+    for name, fn, args in (("p1t_gated_short_conv_fwd", fwd, (bcx, w)),
+                           ("p1t_gated_short_conv_bwd", bwd, (bcx, w, g))):
+        t = time.perf_counter()
+        for _ in range(10):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        print(f"chip_smoke: {name} {at}: "
+              f"{100 * (time.perf_counter() - t):.3f} ms a call (smoke "
+              "reading on the host's clock, not a metric)", flush=True)
+
+
 def experts_phase(tokens=4096, hidden=512, width=256):
     """``nn.RoutedExperts`` with more held picks than its grouped products
     have rows: every token picks the same six held experts, so five
@@ -544,6 +635,7 @@ def main():
         trainer_phase(devs[0])
         kernel_phase()
         block_diffusion_phase()
+        lfm2_phase()
         experts_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {

@@ -12,13 +12,15 @@ import numpy as np
 from ..core.errors import InvalidArgumentError
 from .initializer import Constant, Uniform
 from .layer_base import Layer
+from .layer_common import Linear
 from . import functional as F
 
 __all__ = ["Conv1D", "Conv2D", "Conv3D", "Conv1DTranspose", "Conv2DTranspose",
            "Conv3DTranspose", "AvgPool1D", "AvgPool2D", "AvgPool3D",
            "MaxPool1D", "MaxPool2D", "MaxPool3D", "AdaptiveAvgPool1D",
            "AdaptiveAvgPool2D", "AdaptiveAvgPool3D", "AdaptiveMaxPool1D",
-           "AdaptiveMaxPool2D", "AdaptiveMaxPool3D", "MaxUnPool2D"]
+           "AdaptiveMaxPool2D", "AdaptiveMaxPool3D", "MaxUnPool2D",
+           "ShortConv"]
 
 
 def _ntuple(v, n):
@@ -260,3 +262,26 @@ class MaxUnPool2D(Layer):
         return F.max_unpool2d(x, indices, self.kernel_size, self.stride,
                               self.padding, self.data_format,
                               self.output_size)
+
+
+class ShortConv(Layer):
+    """The gated short-convolution operator of the LFM2 family; no
+    reference analog. ``[b ; c ; x] = in_proj(u)`` (three equal parts
+    along the features, in that order), ``F.gated_short_conv`` over the
+    three, unsplit, with ``taps`` taps a channel (``conv_weight`` [d_model, taps]: the
+    ``[d_model, 1, taps]`` kernel of the family's depthwise ``Conv1d``
+    without its middle axis), ``out_proj``. No bias. ``forward``:
+    [batch, seq, d_model] -> the same shape. In a traced step: two
+    ``linear`` ops under ``in_proj`` and ``out_proj`` and the op
+    ``gated_short_conv`` between them."""
+
+    def __init__(self, d_model, taps=3, weight_attr=None):
+        super().__init__()
+        self.in_proj = Linear(d_model, 3 * d_model, weight_attr, False)
+        self.conv_weight = self.create_parameter([d_model, taps],
+                                                 attr=weight_attr)
+        self.out_proj = Linear(d_model, d_model, weight_attr, False)
+
+    def forward(self, u):
+        return self.out_proj(F.gated_short_conv(self.in_proj(u),
+                                                self.conv_weight))

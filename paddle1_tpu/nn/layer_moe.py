@@ -16,7 +16,8 @@ selection bias):
   ``s = sigmoid(float32(u) W_g)`` over all the experts;
   chosen = the ``top_k`` of ``s + b``: the selection bias ``b`` takes part
   in the choice and not in the weight, is a buffer and has no gradient;
-  ``w = s[chosen] / (sum s[chosen] + 1e-20) * routed_scaling_factor``;
+  ``w = s[chosen] / (sum s[chosen] + norm_eps) * routed_scaling_factor``
+  (``norm_eps`` 1e-20 unless the constructor is told another);
   ``y = sum_{e chosen and held} w_e E_e(u) + S(u)``, ``E_e`` and ``S``
   SwiGLU feed-forwards.
 
@@ -110,14 +111,17 @@ CAPACITY_FACTOR = 3  # rows of the grouped products over even routing's picks
 LOAD_TAIL = ("held_picks", "late_picks", "late_steps", "steps")
 
 
-def route(x, w_gate, bias, top_k, scale, scoring="sigmoid"):
+def route(x, w_gate, bias, top_k, scale, scoring="sigmoid",
+          norm_eps=1e-20):
     """[tokens, hidden] -> (weights float32 [tokens, top_k], experts int32
     [tokens, top_k]). Float32 products whatever x and w_gate arrive in:
     a score rounded to bf16 moves the choice. ``scoring``: ``sigmoid``
     (DeepSeek-V3's rule, the module's docstring) or ``softmax`` (the
     Qwen3-MoE family's, ``norm_topk_prob`` true: probabilities over all
     the experts, the ``top_k`` largest, renormalised to sum ``scale``;
-    ``bias`` takes no part)."""
+    ``bias`` takes no part). ``norm_eps``: what the sigmoid rule adds to
+    the picked scores' sum before it divides (DeepSeek-V3's file 1e-20,
+    the LFM2 family's 1e-6)."""
     logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax":
@@ -128,7 +132,8 @@ def route(x, w_gate, bias, top_k, scale, scoring="sigmoid"):
     chosen = _named_chosen(
         jax.lax.top_k(s + bias.astype(jnp.float32), top_k)[1])
     picked = _named_scores(jnp.take_along_axis(s, chosen, axis=-1))
-    weights = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-20) * scale
+    weights = picked / (jnp.sum(picked, -1, keepdims=True)
+                        + norm_eps) * scale
     return weights, chosen
 
 
@@ -300,12 +305,12 @@ class RoutedExperts(Layer):
 
     def __init__(self, d_model, expert_width, num_experts, top_k,
                  held=None, shared_width=0, routed_scaling_factor=1.0,
-                 weight_attr=None, scoring="sigmoid"):
+                 weight_attr=None, scoring="sigmoid", norm_eps=1e-20):
         super().__init__()
         if scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring={scoring!r}")
         self.num_experts, self.top_k = num_experts, top_k
-        self.scoring = scoring
+        self.scoring, self.norm_eps = scoring, norm_eps
         self.first, self.held = held if held is not None else (0, num_experts)
         if not 0 <= self.first <= self.first + self.held <= num_experts:
             raise ValueError(f"held={held} of {num_experts} experts")
@@ -363,7 +368,8 @@ class RoutedExperts(Layer):
                 if self.scoring == "sigmoid" else ())
         weights, chosen = apply(
             "moe_router", lambda x, w, b=None: route(
-                x, w, b, k, self.routed_scaling_factor, self.scoring),
+                x, w, b, k, self.routed_scaling_factor, self.scoring,
+                self.norm_eps),
             (x, self.router) + bias)
 
         def dispatch(x, weights, chosen):

@@ -7,6 +7,8 @@ from .bert import (BertForPretraining, BertForSequenceClassification,
 from .kanana2 import (Kanana2DecoderLayer, Kanana2ForPretraining,
                       Kanana2Head, Kanana2PretrainingCriterion, Kanana2Stack,
                       LatentAttention)
+from .lfm2 import (Lfm2Attention, Lfm2DecoderLayer, Lfm2ForPretraining,
+                   Lfm2Head, Lfm2PretrainingCriterion, Lfm2Stack)
 from .ouro import (OuroDecoderLayer, OuroExitHead, OuroForPretraining,
                    OuroPretrainingCriterion, OuroStack)
 from .sdar import (SdarAttention, SdarBlockDiffusionCriterion,
@@ -21,4 +23,6 @@ __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "Kanana2Head", "Kanana2ForPretraining",
            "Kanana2PretrainingCriterion", "SdarAttention",
            "SdarDecoderLayer", "SdarStack", "SdarForBlockDiffusion",
-           "SdarBlockDiffusionCriterion"]
+           "SdarBlockDiffusionCriterion", "Lfm2Attention",
+           "Lfm2DecoderLayer", "Lfm2Stack", "Lfm2Head", "Lfm2ForPretraining",
+           "Lfm2PretrainingCriterion"]

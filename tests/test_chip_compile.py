@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle1_tpu.core.flags import flags_guard
 from paddle1_tpu.ops.pallas import (_common, flash_attention, fused_bn,
                                     layer_norm, mask_rules, paged_attention,
-                                    softmax, sum_picks)
+                                    short_conv, softmax, sum_picks)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -129,6 +129,17 @@ def _sum_picks(rows, hidden, tokens, fan, dtype=BF16):
             [((rows, hidden), dtype), ((tokens * fan,), I32)])
 
 
+def _short_conv(grad=False, dtype=BF16):
+    def build(batch, seq, channels, taps=3):
+        bcx = ((batch, seq, 3 * channels), dtype)
+        w = ((channels, taps), dtype)
+        if grad:
+            return short_conv.backward, [bcx, w,
+                                         ((batch, seq, channels), dtype)]
+        return short_conv.forward, [bcx, w]
+    return build
+
+
 B32_S128 = (32, 128, 12, 64)
 B8_S512 = (8, 512, 12, 64)
 OURO = (2, 4096, 16, 128)   # ouro_2p6b.pretrain_s4096's attention call
@@ -138,6 +149,11 @@ KANANA2 = (2, 8192, 32, 192, 128)
 
 SDAR = (1, 16384, 32, 128)
 SDAR_RULE = mask_rules.BlockDiffusion(8192, 4)
+
+# lfm2_24b_a2b.pretrain_s16384's attention call: head width 64 (no
+# multiple of the 128-lane tile: the [B*H, N, D] layout for q, k, dq, dk),
+# 32 query heads over 8, twice the longest causal row of any other cell
+LFM2 = (1, 16384, 32, 64)
 
 CASES = {
     "flash_fwd_b32_s128": lambda: _flash()(*B32_S128),
@@ -178,6 +194,17 @@ CASES = {
     # range at a time (two ranges of 32,768; ISSUE 35)
     "flash_causal_grad_s65536_two_key_ranges":
         lambda: _flash(causal=True, grad=True)(1, 65536, 1, 128),
+    "flash_causal_lfm2_s16384_h32_kv8_d64":
+        lambda: _flash(causal=True, kv_heads=8)(*LFM2),
+    "flash_causal_grad_lfm2_s16384_h32_kv8_d64":
+        lambda: _flash(causal=True, kv_heads=8, grad=True)(*LFM2),
+    # LFM2's gated short convolution: one 16k row of 2048 channels, 3 taps
+    "short_conv_fwd_lfm2_16384x2048": lambda: _short_conv()(1, 16384, 2048),
+    "short_conv_bwd_lfm2_16384x2048":
+        lambda: _short_conv(grad=True)(1, 16384, 2048),
+    # float32 operands, several rows, four taps
+    "short_conv_bwd_f32_4x4096x1024_4taps":
+        lambda: _short_conv(grad=True, dtype=F32)(4, 4096, 1024, 4),
     "layer_norm_4096x768": _layer_norm,
     "softmax_49152x128": _softmax,
     "bn_norm_fwd_25088x256": _bn_norm,
@@ -229,6 +256,7 @@ BACKWARD_CALLS = {
     "ouro_2p6b": (OURO, 16, 128, mask_rules.CAUSAL, True),
     "kanana2_30b_a3b": (KANANA2[:4], 32, 128, mask_rules.CAUSAL, False),
     "sdar_30b_a3b": (SDAR, 4, 128, SDAR_RULE, False),
+    "lfm2_24b_a2b": (LFM2, 8, 64, mask_rules.CAUSAL, False),
 }
 
 
