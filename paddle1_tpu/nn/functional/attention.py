@@ -117,21 +117,30 @@ def use_flash_for(q, k) -> bool:
     sequences alone.
 
     The sweep (tools/tpu_flash_crossover.py on a TPU v5 lite, re-run
-    2026-09-30 with PR 35's one backward kernel and the shipped 1024-row
-    fetched blocks: one call's forward + backward in isolation, bf16,
-    8192 tokens a call, ms dense / kernel; PR 28's table, with two
-    backward kernels, read 2.10, 2.88, 3.81, 5.92 and 10.24 for the
-    kernels in the first column):
+    2026-10-01 with PR 39's kernels, which walk the needed (query block,
+    key block) pairs alone by a scalar-prefetched table: one call's
+    forward + backward in isolation, bf16, 8192 tokens a call, ms dense /
+    kernel; on the rectangular grid they had before, PR 35's table of
+    2026-09-30, the kernels read 1.74, 2.29, 3.16, 4.91 and 8.41 in the
+    first column, 1.75, 2.46, 4.01, 6.85 and 12.91 in the second, 6.89
+    and 11.89 in the last, and dense the same to 0.1%):
 
     ====== ============= ============= ============= ============= ==================
     seq    d128 causal   d128 full     d64 causal    d64 full      d192 / v128 causal
     ====== ============= ============= ============= ============= ==================
-    512    2.00 / 1.74   2.01 / 1.75   0.75 / 1.07   0.74 / 1.08   not measured
-    1024   4.10 / 2.29   4.09 / 2.46   2.93 / 1.36   2.85 / 1.54   not measured
-    2048   7.47 / 3.16   7.45 / 4.01   5.28 / 1.97   5.26 / 2.60   not measured
-    4096   13.99 / 4.91  13.86 / 6.85  10.25 / 3.15  10.21 / 4.66  14.31 / 6.89
-    8192   30.34 / 8.41  28.38 / 12.91 20.90 / 5.36  20.65 / 8.68  29.39 / 11.89
+    512    2.00 / 1.92   2.00 / 1.91   0.75 / 1.10   0.75 / 1.09   not measured
+    1024   4.11 / 2.33   4.09 / 2.61   2.94 / 1.38   2.85 / 1.59   not measured
+    2048   7.47 / 3.08   7.45 / 4.06   5.29 / 1.91   5.27 / 2.62   not measured
+    4096   13.98 / 4.59  13.88 / 6.96  10.25 / 3.00  10.20 / 4.69  14.31 / 6.48
+    8192   30.35 / 7.88  28.39 / 13.06 20.89 / 5.11  20.64 / 8.78  29.39 / 10.99
     ====== ============= ============= ============= ============= ==================
+
+    What the table buys grows with the steps a mask lets it drop (6 to 8%
+    of a causal call at 4096 and 8192); where it drops none it costs: a
+    call without a mask reads 1 to 2% more from 2048 up and 6 to 9% more
+    at 512 and 1024 at d 128, where a head has one or two grid steps and
+    the table's trip to SMEM before each kernel has little to hide behind
+    (no cell runs such a call: PERF.md section 7).
 
     The last column (16 heads): keys 192 and values 128 wide, latent
     attention's shape. 192 is no multiple of the 128-lane tile, so q, k,
@@ -142,10 +151,10 @@ def use_flash_for(q, k) -> bool:
     does not fit at all.
 
     Dense wins at 512 at d 64 whatever the mask, and at BERT's shapes
-    ([64,512,12,64] 5.47 / 5.44, level; [256,128,12,64] 1.62 / 6.50:
+    ([64,512,12,64] 5.46 / 5.50, level; [256,128,12,64] 1.61 / 6.69:
     one or no key block to skip, and at d 64 the kernels pay XLA
-    transposes); at d 128 the kernels are now ahead at 512 too (by 13%;
-    PR 28 had dense ahead by 5%), and from 1024 up, causal or not, by
+    transposes); at d 128 the kernels are ahead at 512 too (by 4%; by 13%
+    on the rectangular grid), and from 1024 up, causal or not, by
     more the longer the sequence. **The rule stays where PR 28 set it**:
     no cell runs d 128 at 512, a crossing moves on a step's evidence, and
     ``bert_base.pretrain_s512`` forced onto the kernels gained 0.27%

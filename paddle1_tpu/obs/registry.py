@@ -504,8 +504,10 @@ def process_group(label_key) -> MetricsGroup:
     ``p1t_attention_arm_total{arm="flash"}`` on the ``/metrics`` page;
     ``process_group(("layer", "expert")).child((path, 3))`` carries both
     labels. The labeled series of the training side: ``attention_arm_
-    total{arm}``, ``flash_tiles_total{kind}`` (counted when a kernel call
-    is traced), ``recompute_kept_bytes_total{name}`` and ``recompute_
+    total{arm}``, ``flash_tiles_total{kind}`` and ``flash_grid_steps_
+    total{kind}`` (counted when a kernel call is traced: its score tiles
+    plain, masked and skipped; its grid's steps working and held),
+    ``recompute_kept_bytes_total{name}`` and ``recompute_
     kept_values_total{name}`` (what each traced ``fleet.utils.recompute``
     segment was given to keep, by the shapes of the values named inside:
     ``core/recompute_keeps.py``), and what the routed-expert layers count

@@ -7,33 +7,42 @@ TPU idiom: online-softmax blocking in VMEM, logits never in HBM.
 Layout: [B, N, H, D] (paddle layout, matching nn.functional.attention).
 This file holds the forward kernel and the entry point; the one
 backward kernel (dQ, dK and dV from a score tile made once) is in
-flash_attention_bwd.py. Both walk one blocked grid, query-major: a query
-block resident while the key blocks it sees pass along the innermost,
-sequential axis, accumulators in VMEM scratch. **Resident**: the forward
-keeps a query block with its running max, sum and output ([BQ, D]); the
-backward a query block with its dout, LSE, delta and dQ, and besides,
-for every query block and query head of its group, one key head's whole
-dK and dV in float32 ([Nk, D] + [Nk, Dv]: 8 MiB at 4096 keys of 128, 32
-at SDAR's 16,384, with the blocks they are written back through), for
-which it raises its own scoped-VMEM limit. A grid step
+flash_attention_bwd.py. Both walk, a head, **the needed (query block,
+key block) pairs and no other step**, query-major: one sequential grid
+axis over a small int32 table (``mask_rules.pair_table``, made with
+numpy from the mask rule when a call is traced) that reaches the index
+maps and the bodies by scalar prefetch. A step's q / out windows follow
+the table's query block, its k / v windows the key block, and two marks
+say where a query block opens (set up what is resident with it) and
+closes (write it out). A query block stays resident while the key blocks
+it sees pass, accumulators in VMEM scratch, and while its last pair runs
+the pipeline already fetches the next query block's operands: no step
+runs nothing, so none leaves that fetch with nothing to hide behind (the
+rectangular grid this replaced, query blocks x the hungriest one's key
+steps, spent 38-47% of its steps so: PERF.md, PR 39). **Resident**: the
+forward keeps a query block with its running max, sum and output
+([BQ, D]); the backward a query block with its dout, LSE, delta and dQ,
+and besides, for every query block and query head of its group, one key
+head's whole dK and dV in float32 ([Nk, D] + [Nk, Dv]: 8 MiB at 4096
+keys of 128, 32 at SDAR's 16,384, with the blocks they are written back
+through), for which it raises its own scoped-VMEM limit. A grid step
 takes its fetched block a chunk at a time, and under a mask rule
 (``mask_rules.py``: bottom-right causal, query ``r`` sees keys ``<= r +
 nk - nq``, or block diffusion's mask over a noisy and a clean copy of a
-row) a score tile (resident block x chunk) is one of three kinds:
+row) a score tile (resident block x chunk) is one of three kinds, by
+``rule.tile`` on the table's scalars:
 
-* wholly hidden: no work, and the index map fetches nothing for a
-  block of such (under the causal rule it is clamped to the last needed
-  block; under block diffusion the inner axis counts the needed blocks
-  alone, and holds the last one where a resident block needs fewer);
+* wholly hidden (a chunk of a needed block that the query block does not
+  see): no work; a block of such is not in the table;
 * wholly visible: the plain body, no iota, no select;
 * crossed: the body with the rule's element-wise keep.
 
 k and v may have fewer heads than q (grouped-query attention: ``H_kv``
 divides ``H``): the forward kernel reads key/value head ``h // (H /
 H_kv)`` through the index map, the backward kernel's grid runs over the
-key heads and passes a group's query heads one after the other along
-its query axis while that key head's dK and dV stay resident, and writes
-``H_kv`` heads. No copy of k or v per query head is made anywhere.
+key heads and passes a group's query heads one after the other, each
+over the table, while that key head's dK and dV stay resident, and
+writes ``H_kv`` heads. No copy of k or v per query head is made anywhere.
 
 Precision is the caller's: the products take q/k/v/dout in the dtype
 they arrive in (bf16 under AMP) and accumulate in float32; max, sum and
@@ -65,7 +74,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core.recompute_keeps import keep_in_recompute
 from . import _common
-from .mask_rules import CAUSAL, NO_MASK
+from .mask_rules import (CAUSAL, FIRST, LAST, NO_MASK, pair_table,
+                         tile_counts)
 
 _LANES = 128  # Mosaic minor-dim tile: per-row statistics are kept
               # replicated across one 128-lane register row
@@ -116,9 +126,9 @@ def supported(q_shape, k_shape, causal: bool = False,
 
 
 def block_sizes(nq: int, nk: int, d: int, dtype) -> tuple:
-    """(block_q, block_k, chunk): the resident block of the outer axis,
-    the block fetched a grid step along the inner axis, and the slice of
-    it that one pass of the body takes. ``d`` is the wider of the key and
+    """(block_q, block_k, chunk): the resident query block, the key
+    block fetched a grid step, and the slice of it that one pass of the
+    body takes. ``d`` is the wider of the key and
     the value head widths: the VMEM budget below is the widest operand's.
     The largest rungs that divide
     the lengths, of ladders set from the sweep on the v5e
@@ -200,48 +210,58 @@ def _kv_head(h, h_kv):
     return lambda g: g // h * h_kv + g % h // (h // h_kv)
 
 
-def _run_tile(body, rule, q0, bq, k0, bk, off, live=None):
+def _run_tile(body, rule, q0, bq, k0, bk, off):
     """Call ``body(masked)`` as the kind of the score tile of queries
-    [q0, q0+bq) x keys [k0, k0+bk) asks, or not at all. ``live``: whether
-    the grid step fetched a block this resident block needs (None: the
-    rule's grid has no other)."""
+    [q0, q0+bq) x keys [k0, k0+bk) asks, or not at all."""
     needed, full = rule.tile(q0, bq, k0, bk, off)   # some, every pair seen
     if full is True:        # the trace knows: a rule that hides nothing
-        if live is None:
-            body(False)
-        else:
-            pl.when(live)(lambda: body(False))
+        body(False)
         return
-    if live is not None:
-        needed, full = live & needed, live & full
     pl.when(full)(lambda: body(False))
     pl.when(jnp.logical_and(needed, jnp.logical_not(full)))(
         lambda: body(True))
+
+
+def _count_kinds(name, counts, calls):
+    """Add ``counts`` ({kind: a number a batch x head}) x ``calls`` to the
+    process's counters ``name{kind}``."""
+    from ...obs.registry import process_group
+    for kind, n in counts.items():
+        process_group("kind").child(kind).counter(name).inc(n * calls)
 
 
 def _count_tiles(rule, nq, nk, bq, bk, calls):
     """``flash_tiles_total{kind}``: the score tiles of one lowered kernel
     call over its ``calls`` (batch x heads) rows of the grid, by what the
     rule makes of them. A wrong rule shows as a count, not as a time."""
-    from ...obs.registry import process_group
-    from .mask_rules import tile_counts
-    for kind, n in tile_counts(rule, nq, nk, bq, bk).items():
-        process_group("kind").child(kind).counter(
-            "flash_tiles_total").inc(n * calls)
+    _count_kinds("flash_tiles_total", tile_counts(rule, nq, nk, bq, bk),
+                 calls)
+
+
+def _count_steps(table, calls):
+    """``flash_grid_steps_total{kind}``: the steps of one lowered kernel
+    call's sequential axis over the ``calls`` times the grid walks it,
+    ``working`` where a step runs its pair's tiles and ``held`` where it
+    runs nothing (the padding of a backward call in key ranges: 0 for
+    every shape a model here runs)."""
+    _count_kinds("flash_grid_steps_total",
+                 {"working": table.q.size - table.held, "held": table.held},
+                 calls)
 
 
 def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [BK, D]; mask_ref: [1, BK] f32,
     # 1.0 = attend / 0.0 = padding; lse_ref: [BQ, 128]
-    q_ref, k_ref, v_ref = refs[:3]
-    mask_ref = refs[3] if has_mask else None
-    o_ref, lse_ref, qs_ref, m_ref, l_ref, acc_ref = refs[3 + has_mask:]
-    i, j = pl.program_id(1), pl.program_id(2)
+    # qb_ref/kb_ref/mark_ref: the step's pair and marks (``pair_table``)
+    qb_ref, kb_ref, mark_ref, q_ref, k_ref, v_ref = refs[:6]
+    mask_ref = refs[6] if has_mask else None
+    o_ref, lse_ref, qs_ref, m_ref, l_ref, acc_ref = refs[6 + has_mask:]
+    step = pl.program_id(1)
+    i, at, mark = qb_ref[step], kb_ref[step], mark_ref[step]
     bq, dv = q_ref.shape[0], v_ref.shape[1]     # out is as wide as v
     bk = k_ref.shape[0]
-    at, live = rule.key_blocks(i, j, bq, bk)
 
-    @pl.when(j == 0)
+    @pl.when(mark & FIRST != 0)
     def _():
         qs_ref[...] = (q_ref[...] * scale).astype(qs_ref.dtype)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -268,9 +288,9 @@ def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
 
     for c in range(bk // chunk):
         _run_tile(functools.partial(one, c), rule, i * bq, bq,
-                  at * bk + c * chunk, chunk, off, live)
+                  at * bk + c * chunk, chunk, off)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(mark & LAST != 0)
     def _():
         # Rows with zero visible keys (fully-padded batch entry): m is
         # still the sentinel and p degenerated to exp(0)=1 per key. Gate
@@ -337,44 +357,54 @@ def _fwd_call(q, k, v, padding_mask, *, scale, rule, blocks, interpret):
     at_o = _window(h, dv)       # out: q's heads, v's width
     kv = _kv_head(h, h_kv)
 
-    steps, kj = rule.key_map(nq, nk, bq, bk)
+    # a head walks the needed (query block, key block) pairs and no
+    # other step: the windows follow the table's columns
+    table = pair_table(rule, nq, nk, bq, bk)
     _count_tiles(rule, nq, nk, bq, chunk, b * h)
+    _count_steps(table, b * h)
+
+    def spec(shape, index):
+        """A window placed by (batch x head, query block, key block) of
+        a grid step."""
+        return pl.BlockSpec(shape, lambda g, s, qb, kb, _: index(
+            g, qb[s], kb[s]))
     in_specs = [
-        pl.BlockSpec((None, bq, d), lambda g, i, j: at(g, i)),
-        pl.BlockSpec((None, bk, d), lambda g, i, j: at_k(kv(g), kj(i, j))),
-        pl.BlockSpec((None, bk, dv), lambda g, i, j: at_v(kv(g), kj(i, j))),
+        spec((None, bq, d), lambda g, i, j: at(g, i)),
+        spec((None, bk, d), lambda g, i, j: at_k(kv(g), j)),
+        spec((None, bk, dv), lambda g, i, j: at_v(kv(g), j)),
     ]
     args = [qa, ka, va]
     if padding_mask is not None:
         # [B, Nk] keep-mask as f32; each (batch, head) program reads its
         # batch row (index map folds bh → b).
-        in_specs.append(pl.BlockSpec(
-            (None, 1, bk), lambda g, i, j: (g // h, 0, kj(i, j))))
+        in_specs.append(spec((None, 1, bk), lambda g, i, j: (g // h, 0, j)))
         args.append(padding_mask.astype(jnp.float32).reshape(b, 1, nk))
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, rule=rule, off=off,
                           chunk=chunk, has_mask=padding_mask is not None),
-        grid=(b * h, nq // bq, steps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, bq, dv), lambda g, i, j: at_o(g, i)),
-            pl.BlockSpec((None, bq, _LANES), lambda g, i, j: (g, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b * h, table.steps),
+            in_specs=in_specs,
+            out_specs=[
+                spec((None, bq, dv), lambda g, i, j: at_o(g, i)),
+                spec((None, bq, _LANES), lambda g, i, j: (g, i, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, d), q.dtype),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(_layout_shape(b, nq, h, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, nq, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), q.dtype),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, dv), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         name="p1t_flash_attention_fwd",
         interpret=interpret,
-    )(*args)
+    )(table.q, table.k, table.mark, *args)
     return out, lse[:, :, 0]
 
 

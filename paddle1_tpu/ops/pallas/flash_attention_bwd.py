@@ -5,7 +5,9 @@ and accumulates dQ, dK and dV from them (five score-shaped products and
 one exponent a tile). Logits and probabilities never touch HBM, and the
 blocks a mask rule hides cost nothing.
 
-Grid (batch·key head, key range, group·q-block, k-block), query-major: a
+Grid (batch·key head, key range, query head of the group, needed pair):
+the last axis walks the forward's table of needed (q-block, k-block)
+pairs (``mask_rules.pair_table``, by scalar prefetch), query-major: a
 q-block (with its dout, LSE and delta) stays resident while the k-blocks
 it sees pass, as in the forward kernel, and dQ accumulates in a [BQ, D]
 float32 scratch. **dK and dV of one key head stay resident in VMEM for
@@ -27,7 +29,11 @@ x (192 + 128); 23.2 and 11.4 at Ouro's 4,096 (compiled for a described
 v5e: ``tests/test_chip_compile.py``). A sequence whose gradients do not
 fit :data:`_VMEM_CAP` takes the same kernel a key range at a time along
 the grid's second axis: the range's rows resident, every q-block passing
-once a range, and dQ a float32 partial a range, summed in XLA.
+once a range over a table of the range's own pairs, and dQ a float32
+partial a range, summed in XLA. The ranges' tables are one length, so
+the shorter end in steps that hold their last pair and run nothing
+(``flash_grid_steps_total{kind="held"}``: 0 for every shape a model here
+runs, which all take one range).
 
 ``delta = rowsum(dout * out)`` is computed in XLA (one fused reduction)
 and arrives with the forward's LSE as [batch·head, 1, Nq] rows.
@@ -43,9 +49,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
-from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_tiles,
-                              _dot, _layout, _run_tile, _unlayout,
-                              block_sizes, split_blocks)
+from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_steps,
+                              _count_tiles, _dot, _layout, _run_tile,
+                              _unlayout, block_sizes, split_blocks)
+from .mask_rules import FIRST, HELD, LAST, pair_table
 
 __all__ = ["flash_attention_bwd", "block_sizes"]
 
@@ -89,33 +96,31 @@ def key_span(nk, bq, bk, chunk, d, dv, dtype) -> int:
     return span
 
 
-def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, blocks_q, span,
-                ranges):
+def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, span, held):
+    # qb_ref/kb_ref/mark_ref: a range's pairs and marks (``pair_table``);
     # q_ref [BQ, D], do_ref [BQ, Dv], lse_ref/delta_ref [1, BQ] (resident);
     # k_ref [BK, D], v_ref [BK, Dv]; mask_ref [BK, 1]; dk_ref [span, D],
     # dv_ref [span, Dv] with their float32 accumulators
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    mask_ref = refs[6] if has_mask else None
+    qb_ref, kb_ref, mark_ref = refs[:3]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[3:9]
+    mask_ref = refs[9] if has_mask else None
     dq_ref, dk_ref, dv_ref, qs_ref, dq_acc, dk_acc, dv_acc = \
-        refs[6 + has_mask:]
-    r, t, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+        refs[9 + has_mask:]
+    # a group's query heads pass one after the other, each over the pairs
+    r, head, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    steps = pl.num_programs(3)
     bq = q_ref.shape[0]
     bk = k_ref.shape[0]
-    # a group's query heads pass one after the other, ``blocks_q`` each
-    i = t if blocks_q is None else t % blocks_q
-    at, live = rule.key_blocks(i, j, bq, bk)
-    base = 0                    # the first key of this step's range
-    if ranges > 1:
-        base = r * span
-        mine = (at * bk >= base) & (at * bk < base + span)
-        live = mine if live is None else live & mine
-    first = j == 0
-    last = j == pl.num_programs(3) - 1
+    entry = r * steps + step
+    i, at, mark = qb_ref[entry], kb_ref[entry], mark_ref[entry]
+    base = r * span             # the first key of this step's range
+    first = mark & FIRST != 0
+    last = mark & LAST != 0
 
     def rows_of(n):
         return pl.ds(pl.multiple_of(n * chunk, chunk), chunk)
 
-    @pl.when(first & (t == 0))
+    @pl.when((step == 0) & (head == 0))
     def _():
         def zero(n, _):
             dk_acc[rows_of(n), :] = jnp.zeros((chunk, dk_acc.shape[1]),
@@ -151,15 +156,20 @@ def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, blocks_q, span,
         dk_acc[here, :] += _dot(ds, q, _NN)
         dq_acc[...] += _dot(ds, k, _TN)
 
-    for c in range(bk // chunk):
-        _run_tile(functools.partial(one, c), rule, i * bq, bq,
-                  at * bk + c * chunk, chunk, off, live)
+    def tiles():
+        for c in range(bk // chunk):
+            _run_tile(functools.partial(one, c), rule, i * bq, bq,
+                      at * bk + c * chunk, chunk, off)
+    if held:        # only a table in key ranges has steps that run nothing
+        pl.when(mark & HELD == 0)(tiles)
+    else:
+        tiles()
 
     @pl.when(last)
     def _():
         dq_ref[...] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
-    @pl.when(last & (t == pl.num_programs(2) - 1))
+    @pl.when((step == steps - 1) & (head == pl.num_programs(2) - 1))
     def _():
         def store(n, _):
             dk_ref[rows_of(n), :] = (dk_acc[rows_of(n), :]
@@ -210,53 +220,53 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
     lse = lse.reshape(b * h, 1, nq).astype(jnp.float32)
     lse = jnp.where(lse > _NEG_INF * 0.1, lse, jnp.inf)
 
-    # grid step t of key head g: q-block t % blocks_q of query head
-    # t // blocks_q of its group; the key blocks that q-block sees, held
-    # inside the range where there is more than one
-    blocks_q = nq // bq
-    steps, kj = rule.key_map(nq, nk, bq, bk)
+    # key head g of the grid takes query head t of its group over the
+    # range's needed (query block, key block) pairs: the windows follow
+    # the table's columns
+    table = pair_table(rule, nq, nk, bq, bk, ranges)
+    steps = table.steps
     if group == 1:
-        head, qi = (lambda g, t: g), (lambda t: t)
+        head = lambda g, t: g
     else:
-        head = lambda g, t: g // h_kv * h + g % h_kv * group + t // blocks_q
-        qi = lambda t: t % blocks_q
-    if ranges == 1:
-        key = lambda r, t, j: kj(qi(t), j)
-    else:
-        key = lambda r, t, j: jnp.clip(kj(qi(t), j), r * (span // bk),
-                                       (r + 1) * (span // bk) - 1)
+        head = lambda g, t: g // h_kv * h + g % h_kv * group + t
     _count_tiles(rule, nq, nk, bq, chunk, b * h)
+    _count_steps(table, b * h)
     _count_ranges(ranges)
 
-    rows = pl.BlockSpec((None, bq, d),
-                        lambda g, r, t, j: at(head(g, t), qi(t)))
-    douts = pl.BlockSpec((None, bq, dv),
-                         lambda g, r, t, j: at_do(head(g, t), qi(t)))
-    keys = pl.BlockSpec((None, bk, d),
-                        lambda g, r, t, j: at_k(g, key(r, t, j)))
-    values = pl.BlockSpec((None, bk, dv),
-                          lambda g, r, t, j: at_v(g, key(r, t, j)))
-    stat = pl.BlockSpec((None, 1, bq),
-                        lambda g, r, t, j: (head(g, t), 0, qi(t)))
+    def spec(shape, index):
+        """A window placed by (key head, query head, query block, key
+        block, key range) of a grid step."""
+        return pl.BlockSpec(shape, lambda g, r, t, s, qb, kb, _: index(
+            g, head(g, t), qb[r * steps + s], kb[r * steps + s], r))
+    rows = spec((None, bq, d), lambda g, hq, i, j, r: at(hq, i))
+    douts = spec((None, bq, dv), lambda g, hq, i, j, r: at_do(hq, i))
+    keys = spec((None, bk, d), lambda g, hq, i, j, r: at_k(g, j))
+    values = spec((None, bk, dv), lambda g, hq, i, j, r: at_v(g, j))
+    stat = spec((None, 1, bq), lambda g, hq, i, j, r: (hq, 0, i))
     in_specs = [rows, keys, values, douts, stat, stat]
     args = [qa, ka, va, doa, lse, delta]
     if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (None, bk, 1), lambda g, r, t, j: (g // h_kv, key(r, t, j), 0)))
+        in_specs.append(spec(
+            (None, bk, 1), lambda g, hq, i, j, r: (g // h_kv, j, 0)))
         args.append(padding_mask.astype(jnp.float32).reshape(b, nk, 1))
     dq, dk, dv_out = pl.pallas_call(
         functools.partial(
             _bwd_kernel, scale=scale, rule=rule, off=off, chunk=chunk,
-            has_mask=has_mask, blocks_q=None if group == 1 else blocks_q,
-            span=span, ranges=ranges),
-        grid=(b * h_kv, ranges, group * blocks_q, steps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, None, bq, d), lambda g, r, t, j: (
-                r,) + at(head(g, t), qi(t))),
-            pl.BlockSpec((None, span, d), lambda g, r, t, j: at_k(g, r)),
-            pl.BlockSpec((None, span, dv), lambda g, r, t, j: at_v(g, r)),
-        ],
+            has_mask=has_mask, span=span, held=table.held > 0),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b * h_kv, ranges, group, steps),
+            in_specs=in_specs,
+            out_specs=[
+                spec((None, None, bq, d),
+                     lambda g, hq, i, j, r: (r,) + at(hq, i)),
+                spec((None, span, d), lambda g, hq, i, j, r: at_k(g, r)),
+                spec((None, span, dv), lambda g, hq, i, j, r: at_v(g, r)),
+            ],
+            scratch_shapes=[pltpu.VMEM((bq, d), q.dtype),
+                            pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((span, d), jnp.float32),
+                            pltpu.VMEM((span, dv), jnp.float32)]),
         out_shape=[
             # a partial a range is summed below: float32 until then
             jax.ShapeDtypeStruct((ranges,) + qa.shape,
@@ -264,10 +274,6 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
             jax.ShapeDtypeStruct(ka.shape, k.dtype),
             jax.ShapeDtypeStruct(va.shape, v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bq, d), q.dtype),
-                        pltpu.VMEM((bq, d), jnp.float32),
-                        pltpu.VMEM((span, d), jnp.float32),
-                        pltpu.VMEM((span, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
@@ -277,7 +283,7 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
         # backward's time by it (PERF.md section 7)
         name="p1t_flash_attention_bwd_dkv",
         interpret=interpret,
-    )(*args)
+    )(table.q, table.k, table.mark, *args)
     dq = dq[0] if ranges == 1 else jnp.sum(dq, axis=0).astype(q.dtype)
     return (_unlayout(dq, b, h, d), _unlayout(dk, b, h_kv, d),
             _unlayout(dv_out, b, h_kv, dv))

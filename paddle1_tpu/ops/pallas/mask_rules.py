@@ -3,50 +3,47 @@ by tile (``flash_attention.py``, ``flash_attention_bwd.py``) and
 ``attention_ref`` builds a dense mask from.
 
 A rule is a small hashable value (it is a static argument of the kernels'
-``jit``) that depends on positions alone, never on data, and answers three
+``jit``) that depends on positions alone, never on data, and answers two
 questions for the forward and the backward kernel alike (both keep a
 query block resident while key blocks pass):
 
-* which fetched key blocks a resident query block needs at all
-  (``key_blocks``): the index maps fetch nothing for the others;
 * whether a score tile is wholly visible, crossed or hidden (``tile``);
 * the element-wise keep of a crossed tile (``keep``).
 
-A rule also gives the grid's inner axis its length and index map
-(``key_map``), the lengths the kernels' block sizes have
-to divide (``sizes``), whether it describes given lengths at all
-(``lengths_ok``), its dense mask and its count of visible pairs.
+A rule also gives the lengths the kernels' block sizes have to divide
+(``sizes``), whether it describes given lengths at all (``lengths_ok``),
+its dense mask and its count of visible pairs. It says nothing about a
+grid: :func:`pair_table` asks ``tile`` of every (query block, fetched key
+block) once, at trace time, and lists the needed pairs in the order the
+kernels walk them; the table reaches their index maps and bodies by
+scalar prefetch, so a grid has no step that fetches or runs nothing.
 
 :data:`NO_MASK` hides nothing (every tile is plain: the kernels emit the
 unmasked body alone), :data:`CAUSAL` is the bottom-right causal mask the
 kernels have always had, :class:`BlockDiffusion` the training mask of
 block diffusion (BD3-LMs, arXiv:2503.09573; SDAR, arXiv:2510.06303).
 Every function takes and gives arrays (numpy or jax, scalars included),
-so the kernels call them on program ids, and :func:`tile_counts` and the
-tests on ``numpy.arange``s: one definition for what runs and what is
-counted.
+so the kernels call them on the table's scalars, and :func:`pair_table`,
+:func:`tile_counts` and the tests on ``numpy.arange``s: one definition
+for what runs and what is counted.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["NO_MASK", "NoMask", "CAUSAL", "Causal", "BlockDiffusion",
-           "dense_mask", "tile_counts", "visible_pairs"]
+           "dense_mask", "tile_counts", "visible_pairs", "pair_table",
+           "PairTable", "FIRST", "LAST", "HELD"]
 
 
 def _iota(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
-
-
-def _xp(x):
-    """numpy for numpy's arrays (the counts), jax.numpy for the rest (the
-    program ids of a kernel or of an index map)."""
-    return np if isinstance(x, (np.ndarray, np.generic)) else jnp
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,12 +56,6 @@ class NoMask:
 
     def sizes(self, nq, nk):
         return nq, nk
-
-    def key_map(self, nq, nk, bq, bk):
-        return nk // bk, lambda i, j: j
-
-    def key_blocks(self, i, step, bq, bk):
-        return step, None
 
     def tile(self, q0, bq, k0, bk, off=0):
         return True, True
@@ -92,21 +83,6 @@ class Causal:
     def sizes(self, nq, nk):
         """The lengths a kernel's block sizes have to divide."""
         return nq, nk
-
-    def key_map(self, nq, nk, bq, bk):
-        """-> (steps of the inner axis of the kernels' grids,
-        (query block i, grid step j) -> the key block to fetch): j, held
-        at the last block that query block i sees, so that a step above
-        the diagonal fetches nothing new."""
-        off = nk - nq
-        return nk // bk, lambda i, j: jnp.minimum(
-            j, jnp.minimum((i * bq + bq - 1 + off) // bk, nk // bk - 1))
-
-    def key_blocks(self, i, step, bq, bk):
-        """-> (the block a grid step fetched as the kernels place it,
-        whether the resident block needs it: None, the grid counts every
-        block and the tiles are skipped by position)."""
-        return step, None
 
     def tile(self, q0, bq, k0, bk, off):
         """(some pair visible, every pair visible) of the score tile of
@@ -163,12 +139,6 @@ class BlockDiffusion:
         """A copy's length: no tile may straddle two copies."""
         return self.length, self.length
 
-    def key_map(self, nq, nk, bq, bk):
-        """The inner axis counts the blocks the hungriest resident block
-        needs, and no more."""
-        return (self.key_steps(bq, bk),
-                lambda i, j: self.key_blocks(i, j, bq, bk)[0])
-
     # -- where a tile lies
 
     def _place(self, r0):
@@ -180,10 +150,6 @@ class BlockDiffusion:
     def _floor(self, p):
         """The first position of ``p``'s block."""
         return p & -self.block
-
-    def _base(self, noisy, blocks_a_copy):
-        """The first block (of ``blocks_a_copy`` a copy) of a copy."""
-        return 0 if noisy == self.noisy_first else blocks_a_copy
 
     def tile(self, q0, bq, k0, bk, off=0):
         qn, qp = self._place(q0)
@@ -208,44 +174,6 @@ class BlockDiffusion:
         least = jnp.where(qn & kn, 0, -2 * self.length)
         most = jnp.where(qn & ~kn, -1, 0)
         return (apart >= least) & (apart <= most)
-
-    # -- which key blocks a resident query block needs: two runs of
-    # consecutive blocks, [a0, a0 + na) then [b0, b0 + nb)
-
-    def _key_runs(self, i, bq, bk):
-        per = self.length // bk
-        qn, qp = self._place(i * bq)
-        last = self._floor(qp + bq - 1)          # its last query's block
-        clean, noisy = self._base(False, per), self._base(True, per)
-        upto = (last + self.block - 1) // bk     # that block's last key's
-        # a noisy query block: the clean keys before its last query's
-        # block, then the noisy keys of its own blocks. A clean one: the
-        # clean keys up to its last query's block
-        first = self._floor(qp) // bk
-        na = _xp(qn).where(qn, (last + bk - 1) // bk, upto + 1)
-        nb = _xp(qn).where(qn, upto - first + 1, 0)
-        return clean, na, noisy + first, nb
-
-    @staticmethod
-    def _pick(step, a0, na, b0, nb):
-        """Grid step -> (the block to fetch, whether the step is one of
-        the ``na + nb`` needed). A step past them holds the last block, so
-        that nothing new is fetched."""
-        xp = _xp(na)
-        at = xp.minimum(step, na + nb - 1)
-        return xp.where(at < na, a0 + at, b0 + at - na), step < na + nb
-
-    def key_blocks(self, i, step, bq, bk):
-        """Of query block ``i`` (of ``bq`` rows): the key block (of ``bk``)
-        its ``step``-th grid step fetches, and whether it needs one."""
-        return self._pick(step, *self._key_runs(i, bq, bk))
-
-    def key_steps(self, bq, bk):
-        """Grid steps along the keys that the hungriest query block
-        needs: the inner axis of the kernels' grids."""
-        _, na, _, nb = self._key_runs(
-            np.arange(2 * self.length // bq, dtype=np.int32), bq, bk)
-        return int(np.max(na + nb))
 
     def dense(self, nq, nk):
         r = np.arange(2 * self.length)
@@ -272,17 +200,78 @@ def visible_pairs(rule, nq, nk):
     return rule.pairs(nq, nk)
 
 
+def _tiles(rule, nq, nk, bq, bk):
+    """(needed, full) of :meth:`tile` for every one of the (nq / bq) x
+    (nk / bk) score tiles, as boolean matrices."""
+    shape = nq // bq, nk // bk
+    q0 = (np.arange(shape[0], dtype=np.int32) * bq)[:, None]
+    k0 = (np.arange(shape[1], dtype=np.int32) * bk)[None]
+    return tuple(np.broadcast_to(np.asarray(kind, bool), shape)
+                 for kind in rule.tile(q0, bq, k0, bk, nk - nq))
+
+
 def tile_counts(rule, nq, nk, bq, bk):
     """{"plain", "masked", "skipped"}: how many of the (nq / bq) x
     (nk / bk) score tiles a kernel runs without a mask, runs under the
     rule's element-wise keep, and does not run."""
-    total = (nq // bq) * (nk // bk)
-    q0 = (np.arange(nq // bq, dtype=np.int32) * bq)[:, None]
-    k0 = (np.arange(nk // bk, dtype=np.int32) * bk)[None]
-    needed, full = rule.tile(q0, bq, k0, bk, nk - nq)
-    needed, full = np.broadcast_to(needed, (nq // bq, nk // bk)), \
-        np.broadcast_to(full, (nq // bq, nk // bk))
+    needed, full = _tiles(rule, nq, nk, bq, bk)
     plain = int(np.sum(full))
     masked = int(np.sum(needed & ~full))
     return {"plain": plain, "masked": masked,
-            "skipped": total - plain - masked}
+            "skipped": needed.size - plain - masked}
+
+
+# marks of a step of :func:`pair_table`: the first / the last step of its
+# query block (set up / write out what is resident with the block); a
+# step that runs no tile
+FIRST, LAST, HELD = 1, 2, 4
+
+
+class PairTable(NamedTuple):
+    """A step ``s`` of key range ``r`` is entry ``r * steps + s`` of the
+    three int32 columns."""
+    q: np.ndarray       # the query block
+    k: np.ndarray       # the fetched key block
+    mark: np.ndarray    # FIRST | LAST | HELD
+    steps: int          # a range's steps
+
+    @property
+    def held(self) -> int:
+        return int(np.sum(self.mark & HELD != 0))
+
+
+def pair_table(rule, nq, nk, bq, bk, ranges=1) -> PairTable:
+    """The (query block of ``bq``, key block of ``bk``) pairs that
+    ``rule.tile`` calls needed, query-major with the key blocks ascending:
+    the one sequential axis of the kernels' grids, made with numpy when a
+    kernel call is traced. Every query block has a FIRST and a LAST step.
+
+    With the keys in ``ranges`` equal ranges (the backward kernel where a
+    head's dK and dV do not fit VMEM: no shape a model here runs), a table
+    a range, of that range's key blocks alone and as long as the longest;
+    the others end in steps that hold their last pair, and a query block
+    that needs no key of a range has one step there, FIRST | LAST | HELD,
+    so that its partial dQ of the range is written (zero)."""
+    needed, _ = _tiles(rule, nq, nk, bq, bk)
+    per = needed.shape[1] // ranges
+    columns = []
+    for lo in range(0, needed.shape[1], per):
+        mine = needed[:, lo:lo + per]
+        q, k = np.nonzero(mine)                 # row-major: query-major
+        none = np.flatnonzero(~mine.any(axis=1))
+        q, k = np.concatenate([q, none]), np.concatenate([k, 0 * none]) + lo
+        mark = np.repeat([0, HELD], [q.size - none.size, none.size])
+        order = np.lexsort((k, q))
+        q, k, mark = q[order], k[order], mark[order]
+        edge = np.flatnonzero(np.diff(q)) + 1   # where a query block begins
+        mark[np.concatenate([[0], edge])] |= FIRST
+        mark[np.concatenate([edge - 1, [q.size - 1]])] |= LAST
+        columns.append((q, k, mark))
+    steps = max(q.size for q, _, _ in columns)
+
+    def column(parts, **how):       # every range padded to the longest
+        return np.concatenate([np.pad(x, (0, steps - x.size), **how)
+                               for x in parts]).astype(np.int32)
+    qs, ks, marks = zip(*columns)
+    return PairTable(column(qs, mode="edge"), column(ks, mode="edge"),
+                     column(marks, constant_values=HELD), steps)

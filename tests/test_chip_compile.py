@@ -260,6 +260,41 @@ BACKWARD_CALLS = {
 }
 
 
+# the steps of a head of each cell's table at the shipped (512, 1024): the
+# parent's rectangles had 288, 128, 512 and 32
+TABLE_STEPS = {"ouro_2p6b": 20, "kanana2_30b_a3b": 72, "sdar_30b_a3b": 160,
+               "lfm2_24b_a2b": 272}
+
+
+@pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
+def test_a_cells_forward_call_walks_the_table_in_the_default_vmem(
+        cell, one_chip, for_the_chip):
+    """The forward kernel at each cell's shape compiles for the v5e with
+    the table of needed pairs as its three scalar-prefetched columns, a
+    grid of batch x heads by the table's steps, and keeps no more than a
+    block of any operand: Mosaic uses under the default 16 MiB."""
+    import functools
+    (b, s, h, d), h_kv, dv, rule, _ = BACKWARD_CALLS[cell]
+    struct = lambda *dims: jax.ShapeDtypeStruct(dims, BF16,
+                                                sharding=one_chip)
+    text = jax.jit(functools.partial(
+        flash_attention._fwd_call.__wrapped__, scale=d ** -0.5, rule=rule,
+        blocks=None, interpret=False)).lower(
+        struct(b, s, h, d), struct(b, s, h_kv, d), struct(b, s, h_kv, dv),
+        None).compile().as_text()
+    call, = re.findall(r"^.*%p1t_flash_attention_fwd\S* = .*$", text, re.M)
+    assert call.count("s32[%d]{0}" % TABLE_STEPS[cell]) >= 3
+    assert 0 < _scoped_vmem(call, "used_") <= 16 << 20
+
+
+def _scoped_vmem(call, which=""):
+    """The scoped VMEM a compiled kernel instruction was allowed
+    (``which`` ""), or what Mosaic used of it ("used_")."""
+    return int(re.search(
+        r'"%sscoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"' % which, call).group(1))
+
+
 @pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
 def test_a_cells_backward_call_asks_for_less_vmem_than_the_limit_it_sets(
         cell, one_chip, for_the_chip, monkeypatch):
@@ -294,11 +329,14 @@ def test_a_cells_backward_call_asks_for_less_vmem_than_the_limit_it_sets(
     # the instruction says what it was allowed and what Mosaic used
     call, = re.findall(r"^.*%p1t_flash_attention_bwd_dkv\S* = .*$", text,
                        re.M)
-    allowed, used = (int(re.search(
-        r'"%sscoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
-        r'"size":"(\d+)"' % which, call).group(1)) for which in ("", "used_"))
+    allowed, used = _scoped_vmem(call), _scoped_vmem(call, "used_")
     assert allowed == limit and 0 < used < allowed
     assert (used <= 16 << 20) == default_does
+    # and it walks the table of needed pairs: three scalar-prefetched
+    # columns, a step a pair (ISSUE 39)
+    steps = mask_rules.pair_table(rule, s, s, *blocks[:2]).steps
+    assert steps == TABLE_STEPS[cell]
+    assert call.count("s32[%d]{0}" % steps) >= 3
     monkeypatch.setattr(fb, "_vmem_bytes", lambda *a: 16 << 20)
     if default_does:
         compiled()

@@ -398,8 +398,8 @@ FLASH_CASES = [
     (False, 256, 256, 64, "float32", "padded_row", _B128),
     (True, 256, 256, 128, "float32", "padded_row", _B128),
     (True, 128, 384, 64, "bfloat16", "padded_row", _B128),
-    # some key blocks wholly above the diagonal: skipped, index map
-    # clamped; fetched block of two chunks: the chunk-level skip too
+    # some key blocks wholly above the diagonal: not in the table of
+    # pairs; fetched block of two chunks: the chunk-level skip too
     (True, 512, 512, 128, "float32", None, (128, 256, 128)),
     (True, 512, 512, 64, "float32", "padding", (128, 256, 128)),
     (True, 512, 512, 128, "bfloat16", None, (256, 128, 128)),

@@ -2,8 +2,8 @@
 skip by tile, grouped key/value heads in those kernels, the softmax rule
 of the routed-expert layer, and the model against the plain reference
 (``benchmarks/reference/sdar_30b_a3b.py``); recomputation, the names and
-counters a traced step carries, and the causal kernels' jaxpr held to the
-one they had before the rule. CPU, tiny sizes, seeded weights; the kernels
+counters a traced step carries, and the kernels' jaxprs held to the ones
+last taken on purpose. CPU, tiny sizes, seeded weights; the kernels
 in interpreter mode at tile-aligned sizes."""
 
 import hashlib
@@ -190,10 +190,15 @@ def test_one_block_and_no_mask_count_their_tiles():
         "plain": 8, "masked": 0, "skipped": 8}
     assert mask_rules.tile_counts(mask_rules.NO_MASK, 512, 256, 128, 128) == {
         "plain": 8, "masked": 0, "skipped": 0}
-    # the inner axis of both grids counts the needed blocks alone
+    # the kernels' sequential axis counts the needed pairs alone: the
+    # table's length, and the most steps any one query block has (what
+    # every query block had on the rectangle the kernels walked before)
     cell = BlockDiffusion(8192, 4)
-    assert cell.key_steps(512, 1024) == 9       # of the 16 there are
-    assert cell.key_steps(512, 512) == 17       # of 32
+    for fetched, steps, most in ((1024, 160, 9),    # of the 16 there are
+                                 (512, 288, 17)):   # of 32
+        table = mask_rules.pair_table(cell, 16384, 16384, 512, fetched)
+        assert (table.steps, table.held) == (steps, 0)
+        assert np.bincount(table.q).max() == most
 
 
 @pytest.fixture
@@ -288,27 +293,28 @@ def test_one_block_is_full_attention_and_blocks_of_one_are_causal():
     np.testing.assert_allclose(first, v[0, 0], atol=2e-5)
 
 
-# -- the kernels the parent had are the ones they were ----------------------
+# -- the kernels lower to the text they had ---------------------------------
 
 # sha256 of the jaxpr (kernel body, index maps and grid included) of one
-# attention call's forward kernel at Ouro's and Kanana-2's shapes under the
-# causal mask and at one shape under none, taken at the parent commit of
-# ISSUE 35 (3b9b390) under jax 0.9.0, where they were what ISSUE 33's
-# parent (52e487e) had: a rule in place of the boolean left the kernel's
-# Mosaic body as it was, and one backward kernel in place of two leaves
-# the forward's so. The backward call's (``flash_attention_bwd``: delta,
-# the remapped LSE and the one kernel) are this tree's own, re-taken by
-# whoever changes that kernel on purpose.
+# attention call's forward kernel and of its backward call
+# (``flash_attention_bwd``: delta, the remapped LSE and the one kernel) at
+# Ouro's and Kanana-2's shapes under the causal mask and at one shape
+# under none, under jax 0.9.0. The forward's stood from ISSUE 33's parent
+# (52e487e) to ISSUE 39's (89d7fd5): a rule in place of the boolean and
+# one backward kernel in place of two left the forward's Mosaic body as
+# it was. ISSUE 39 changed both kernels' grids on purpose (one axis over
+# the table of needed pairs, by scalar prefetch) and re-took all six;
+# whoever changes a kernel on purpose re-takes them again.
 KERNEL_JAXPRS = {
     (2, 4096, 16, 128, 128, True): (
-        "14110a26161ded98f712f85581f71cf76b6fd42829d7b7a0898628dc21d08f63",
-        "431ef5f5d38768c585ad93179fa360bede79048f89a10f1b28b992de09699815"),
+        "c5e085e95f7cf1fd316380d9b67c714589ec67a0d641e65c31addf8141dea6b8",
+        "20fc72d2232c3315c4cd6f336b06e88f2942b385f21718ea637746f62beaf8ba"),
     (2, 8192, 32, 192, 128, True): (
-        "fb7d987e0e9d5a06e6c7dab7ccf3ba78ea4bdbe0358ebfd30996d561be916e0c",
-        "25d6dc6e8a9b25a9204c0f49f9e1af07a69c3fb226f246ce0a5489578f77b4bc"),
+        "81ef3b17677bbf2500061bb14db8bde1db5e2a602fbd86c1ae5185c3e295f8a2",
+        "485de6b9047e677599e5588bec7e510b6dad99aee9c8695391f9e350f42a8088"),
     (2, 2048, 8, 128, 128, False): (
-        "bc578bad6ae7c8cd023bcd3c0a478f4a102b769c8e178bac6e0084fb3d510af8",
-        "7fdb2946973bd4fe2da6e432e1fa90d44ff78bfd0d03d8bdcb0d6d04d3263d0e"),
+        "9c7b5f806a6cc60f5be5783f5db9da45d13bc2ef9eee0aa8dde997f93c861836",
+        "c00e1b90afce08e0bd1eb984a983e22dcbad8dd7ffdc1ccc6e3d19eda21a783c"),
 }
 
 
