@@ -8,9 +8,23 @@ The routed part is an expectation: under even routing a token's
 that lands costs one expert. The router's product over all the experts is
 counted; what recomputation runs again is not."""
 
+from . import attention_kernels
+
 
 def picks_here_a_token(cfg):
     return cfg["num_experts_per_tok"] / cfg["expert_parallel"]
+
+
+def attention_kernel_flops(cfg, env):
+    """{kernel: FLOPs of its calls in one step}, a call a layer: the
+    causal half's pairs alone, keys ``qk_nope + qk_rope`` wide in the
+    score, dQ and dK, values ``v_head_dim`` wide in PV, dV and dP
+    (``attention_kernels``)."""
+    calls = cfg["num_hidden_layers"] * env["batch"]
+    return attention_kernels.flops(
+        calls * (env["seq"] * (env["seq"] + 1) // 2),
+        cfg["num_attention_heads"],
+        cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"], cfg["v_head_dim"])
 
 
 def forward_matmul_flops(cfg, env):

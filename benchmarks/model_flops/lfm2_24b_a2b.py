@@ -14,18 +14,14 @@ Beside the FLOPs, the bytes of the one op that is bound by memory:
 :func:`short_conv_bytes`, what the ``gated_short_conv`` calls of a step
 have to read and write: over the op's device time (scope
 ``gated_short_conv``) and the chip's HBM peak (``peaks.json``) they give
-the op's share of its roofline. No metric file reads them yet (PERF.md
-section 7: a ``benchmark`` PR's). The program counts the same
-closed form where it traces the op (``short_conv_bytes_total{pass}``,
+the op's share of its roofline (``short_conv_op_hbm_roofline``, reducer
+``op_hbm_pct``). The program counts the same closed form where it traces
+the op (``short_conv_bytes_total{pass}``,
 ``paddle1_tpu/nn/functional/short_conv.py::traffic_bytes``): written
 twice, once on either side, and ``test_lfm2_yardstick.py`` holds the two
 equal on a traced step."""
 
-KERNELS = ("p1t_flash_attention_fwd", "p1t_flash_attention_bwd_dkv")
-# score-shaped products a visible pair costs in each kernel, each 2 x
-# head_dim FLOPs: forward QK^T and PV; the one backward kernel the scores
-# again, dV, dP, dQ and dK
-PRODUCTS = dict(zip(KERNELS, (2, 5)))
+from . import attention_kernels
 
 
 def layer_kinds(cfg):
@@ -49,11 +45,10 @@ def causal_pairs(env):
 
 def attention_kernel_flops(cfg, env):
     """{kernel: FLOPs of its calls in one step}, a call an attention
-    layer: the visible pairs' alone."""
-    pair = 2 * head_dim(cfg) * cfg["num_attention_heads"]
+    layer: the visible pairs' alone (``attention_kernels``)."""
     calls = layer_kinds(cfg).count("full_attention") * env["batch"]
-    return {k: n * pair * causal_pairs(env) * calls
-            for k, n in PRODUCTS.items()}
+    return attention_kernels.flops(calls * causal_pairs(env),
+                                   cfg["num_attention_heads"], head_dim(cfg))
 
 
 def short_conv_bytes(cfg, env, itemsize=2):

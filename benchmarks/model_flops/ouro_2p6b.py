@@ -4,6 +4,18 @@ three (one product forward, two backward). A layer's weights are used
 scores the causal half (a query with the keys up to itself) is counted, once;
 what recomputation runs again is not counted."""
 
+from . import attention_kernels
+
+
+def attention_kernel_flops(cfg, env):
+    """{kernel: FLOPs of its calls in one step}, a call a layer a loop
+    step: the causal half's pairs alone (``attention_kernels``)."""
+    calls = (cfg["total_ut_steps"] * cfg["num_hidden_layers"]
+             * env["batch"])
+    return attention_kernels.flops(
+        calls * (env["seq"] * (env["seq"] + 1) // 2),
+        cfg["num_attention_heads"], cfg["head_dim"])
+
 
 def forward_matmul_flops(cfg, env):
     tokens, s = env["batch"] * env["seq"], env["seq"]

@@ -10,12 +10,7 @@ chip's ``num_experts`` of ``num_experts * expert_parallel`` experts that
 often. The head runs over the ``seq`` noisy rows. What recomputation runs
 again is not counted."""
 
-KERNELS = ("p1t_flash_attention_fwd", "p1t_flash_attention_bwd_dkv",
-           "p1t_flash_attention_bwd_dq")
-# score-shaped products a visible pair costs in each kernel, each 2 x
-# head_dim FLOPs: forward QK^T and PV; dK/dV the scores again, dV, dP and
-# dK; dQ the scores again, dP and dQ
-PRODUCTS = dict(zip(KERNELS, (2, 4, 3)))
+from . import attention_kernels
 
 
 def picks_here_a_token(cfg):
@@ -31,11 +26,12 @@ def visible_pairs(cfg, env):
 
 def attention_kernel_flops(cfg, env):
     """{kernel: FLOPs of its calls in one step}, a call a layer: the
-    visible pairs' alone."""
-    pair = 2 * cfg["head_dim"] * cfg["num_attention_heads"]
+    visible pairs' alone (``attention_kernels``: 2 products forward, 5 in
+    the one backward kernel)."""
     calls = cfg["num_hidden_layers"] * env["batch"]
-    return {k: n * pair * visible_pairs(cfg, env) * calls
-            for k, n in PRODUCTS.items()}
+    return attention_kernels.flops(calls * visible_pairs(cfg, env),
+                                   cfg["num_attention_heads"],
+                                   cfg["head_dim"])
 
 
 def forward_matmul_flops(cfg, env):
@@ -47,7 +43,7 @@ def forward_matmul_flops(cfg, env):
     routed_total = cfg["num_experts"] * cfg["expert_parallel"]
     layer = (
         2 * positions * (2 * h * heads * d + 2 * h * kv * d)
-        + attention_kernel_flops(cfg, env)[KERNELS[0]]
+        + attention_kernel_flops(cfg, env)[attention_kernels.FORWARD]
         / cfg["num_hidden_layers"]
         + 2 * positions * (h * routed_total
                            + 3 * h * width * picks_here_a_token(cfg)))

@@ -202,7 +202,8 @@ def run(args):
     from . import check, peaks, trace_reduce
     flops = spec.module("model_flops", cfg).train_step_flops(cfg, env)
     kind = devices[0].device_kind
-    peak_flops = None if rehearsal else peaks.of(kind)["bf16_flops_per_s"]
+    peak = None if rehearsal else peaks.of(kind)
+    peak_flops = peak and peak["bf16_flops_per_s"]
     say(f"cell {cell['name']} of {spec.ROOT} on {len(devices)} x {kind}; batch "
         f"{env['batch']}, dims {cell['rehearsal']['dims'] if rehearsal else cell['dims']}; "
         f"model FLOPs a step {flops:.6g}")
@@ -297,7 +298,8 @@ def run(args):
                "counters": {"compiles_in_window": misses1 - misses0},
                "peak_bytes": [b for b in peak_bytes if b],
                "flops_per_step": flops, "peak_flops_per_s": peak_flops,
-               "n_devices": len(devices)}
+               "n_devices": len(devices), "cell": cell, "config": cfg,
+               "peaks": peak}
         files = spec.layer_metrics()
         metrics = {}
         for entry in spec.per_layer_for(cell["name"]):
@@ -334,6 +336,10 @@ def run(args):
         say(f"compare {what}: {value:.6g} (limit {limit:g}) "
             f"{'ok' if good else 'NOT OK'}; {note}")
     say(f"reference and comparison took {time.perf_counter() - t:.2f}s")
+    # a gap that is no number (a loss that is none) stays valid JSON
+    result["compared"] = {
+        what: {"value": value if np.isfinite(value) else None, "limit": limit}
+        for what, value, limit, _, _ in rows}
 
     tag = REHEARSAL_TAG if rehearsal else ""
     result.update({
@@ -353,7 +359,13 @@ def main(argv=None):
     ap.add_argument("--rehearsal", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     result = run(args)
-    keys = ("correct", "attempted", "failed", "metrics", "device", "breakdown")
+    # each number compared beside its limit: the last lines of standard
+    # error, and the last key of the result's line
+    for what, row in result["compared"].items():
+        print(f"benchmarks: compared {what}: {row['value']} "
+              f"(limit {row['limit']})", file=sys.stderr, flush=True)
+    keys = ("correct", "attempted", "failed", "metrics", "device", "breakdown",
+            "compared")
     print(json.dumps({k: result[k] for k in keys if k in result}), flush=True)
 
 

@@ -14,7 +14,6 @@ import os
 import re
 
 MODULES, OPS = "XLA Modules", "XLA Ops"
-PALLAS_MARK = "tpu_custom_call"
 
 
 def find_xplane(trace_dir):
@@ -88,8 +87,8 @@ def device_view(dev):
 
     -> None when the trace holds fewer than two step programs, else a dict:
     window_s, busy_s, step_s (list), gap_s (list of (start, end) between
-    step programs), pallas_s, ops (name -> seconds), idle (disjoint idle
-    intervals inside the window).
+    step programs), ops (name -> seconds), idle (disjoint idle intervals
+    inside the window).
     """
     steps = step_events(dev["modules"])
     if len(steps) < 2:
@@ -104,17 +103,15 @@ def device_view(dev):
         at = max(at, e)
     if hi > at:
         idle.append((at, hi))
-    ops, pallas_s = {}, 0.0
+    ops = {}
     for name, s, e in dev["ops"]:
         if e <= lo or s >= hi:
             continue
         ops[name] = ops.get(name, 0.0) + (e - s)
-        if PALLAS_MARK in name:
-            pallas_s += e - s
     return {"window_s": hi - lo, "busy_s": busy_s,
             "step_s": [e - s for _, s, e in steps],
             "gaps": [(a[2], b[1]) for a, b in zip(steps, steps[1:])],
-            "pallas_s": pallas_s, "ops": ops, "idle": idle}
+            "ops": ops, "idle": idle}
 
 
 def views(trace):

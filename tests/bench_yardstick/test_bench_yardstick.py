@@ -182,7 +182,6 @@ def test_device_view_on_hand_made_events():
     assert v["window_s"] == 4.0 and v["busy_s"] == pytest.approx(2.8)
     assert v["step_s"] == [1.0, 1.0, 1.0]
     assert v["gaps"] == [(1.0, 1.5), (2.5, 3.0)]
-    assert v["pallas_s"] == 0.5
     assert v["idle"] == [(1.0, 1.5), (2.5, 3.2)]
     trace = {"devices": {"/device:TPU:0": dev},
              "spans": [("bench/dispatch", 0.9, 1.6), ("bench/readback", 2.4, 3.1)]}
@@ -194,13 +193,12 @@ def test_device_view_on_hand_made_events():
     assert trace_reduce.op_family("%fusion.12 = bf16[8]{0} fusion(%p.3)") \
         == trace_reduce.op_family("%fusion.7 = bf16[8]{0} fusion(%p.3)")
     from benchmarks.reducers import (device_idle_pct, device_step_ms,
-                                     pallas_time_pct, step_gap_ms, step_mfu_pct)
+                                     step_gap_ms, step_mfu_pct)
     ctx = {"views": [v], "flops_per_step": 9.85e13, "peak_flops_per_s": 197e12,
            "n_devices": 1}
     assert device_step_ms.reduce(ctx, {}) == 1000.0
     assert step_gap_ms.reduce(ctx, {}) == 500.0
     assert device_idle_pct.reduce(ctx, {}) == pytest.approx(30.0)
-    assert pallas_time_pct.reduce(ctx, {}) == pytest.approx(100 * 0.5 / 3)
     assert step_mfu_pct.reduce(ctx, {}) == pytest.approx(50.0)
     assert device_step_ms.reduce({**ctx, "views": []}, {}) is None
 
@@ -230,7 +228,6 @@ def test_recorded_trace(recorded):
     assert 0 < v["busy_s"] < v["window_s"]
     idle = sum(e - s for s, e in v["idle"])
     assert idle + v["busy_s"] == pytest.approx(v["window_s"], rel=1e-9)
-    assert 0 < v["pallas_s"] < sum(v["step_s"])
     assert all(e > s for s, e in v["gaps"])
     br = trace_reduce.breakdown(recorded)
     assert 1 <= len(br["device_ops"]) <= 10 and 1 <= len(br["idle_gaps"]) <= 10
@@ -283,7 +280,7 @@ def test_flops_against_xla_cost_analysis(name):
     import jax
     from benchmarks.reference.numerics import Numerics
     cfg = spec.config(name, rehearsal=True)
-    if name == "bert_base":
+    if cfg.get("reference", name) == "bert_base":
         cfg = {**cfg, "hidden_size": 256, "intermediate_size": 1024,
                "num_attention_heads": 4, "vocab_size": 2048,
                "num_hidden_layers": 1}   # XLA counts a scan body once
@@ -321,6 +318,7 @@ def one_cell_per_config():
     return out
 
 
+@pytest.mark.drives_a_run
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_follows_the_framework_in_float32(name, one_cell_per_config,
                                                     capsys):
@@ -338,6 +336,7 @@ def test_reference_follows_the_framework_in_float32(name, one_cell_per_config,
     assert all(k.endswith("@cpu_rehearsal") for k in result["metrics"])
 
 
+@pytest.mark.drives_a_run
 @pytest.mark.parametrize("broken", ["state_unchanged", "half_the_batch"])
 def test_a_broken_timed_path_is_not_correct(broken, monkeypatch, capsys):
     """Drive a run with the timed path broken underneath: a step that
@@ -371,6 +370,7 @@ def _no_donation(pe):
     return lambda name: False if name == "jit_donate_params" else real(name)
 
 
+@pytest.mark.drives_a_run
 @pytest.mark.parametrize("name", CONFIGS)
 def test_lower_precision_control_is_not_correct(name, one_cell_per_config):
     """The reference in the program's place, one precision below what the
@@ -422,6 +422,7 @@ def _cli(*extra):
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
 
 
+@pytest.mark.drives_a_run
 def test_command_rehearsal_prints_the_contracts_last_line():
     done = _cli("--trace", "1", "--rehearsal", "1")
     assert done.returncode == 0, done.stderr[-2000:]
@@ -436,44 +437,28 @@ def test_command_rehearsal_prints_the_contracts_last_line():
     assert {k.split("@")[0] for k in last["metrics"]} <= listed
     assert "compiles_in_window@cpu_rehearsal" in last["metrics"]
     assert last["metrics"]["compiles_in_window@cpu_rehearsal"]["value"] == 0
+    # each number compared beside its limit: the line's last key, and the
+    # last lines of standard error
+    assert list(last)[-1] == "compared" and len(last["compared"]) == 7
+    assert all(0 <= row["value"] <= row["limit"]
+               for row in last["compared"].values())
+    told = done.stderr.strip().splitlines()[-len(last["compared"]):]
+    assert [line.split("compared ")[1].split(":")[0] for line in told] \
+        == list(last["compared"])
 
 
+@pytest.mark.drives_a_run
 def test_a_new_configuration_and_cell_add_files_and_edit_none(tmp_path):
-    """What a later PR does: in a copy of the benchmark, a configuration
-    with limits of its own, a cell, a schedule and a draw are added as new
-    files plus entries in BENCHMARK.json, no file that was there is
-    touched, and the command runs the new cell."""
-    import shutil
-    src = os.path.join(ROOT, "benchmarks")
-    shutil.copytree(src, tmp_path / "benchmarks",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    cfg = spec.config("bert_base")
-    cfg.update(name="probe", optimizer={
-        **cfg["optimizer"], "lr_schedule": {"kind": "probe_flat", "lr": 1e-5}})
-    cell = spec.cell("bert_base.pretrain_s128")
-    cell.update(name="probe.pretrain", config="probe", traffic="pretrain")
-    cell["fields"]["nsp"] = {"draw": "probe_ones", "shape": ["batch"]}
-    new = {
-        "configs/probe.json": json.dumps(cfg),
-        "limits/probe.json": json.dumps(spec.load_json("limits",
-                                                       "bert_base.json")),
-        "workloads/probe.pretrain.json": json.dumps(cell),
-        "schedules/probe_flat.py":
-            "def lr_at(schedule, step):\n    return schedule['lr']\n",
-        "draws/probe_ones.py":
-            "import numpy as np\n\n\ndef draw(rng, field, resolve):\n"
-            "    return np.ones([resolve(s) for s in field['shape']], "
-            "np.int32)\n"}
-    for rel, text in new.items():
-        assert not os.path.exists(os.path.join(src, rel))
-        (tmp_path / "benchmarks" / rel).write_text(text)
-    bench = spec.benchmark()
-    bench["configs"].append({**bench["configs"][0], "name": "probe",
-                             "file": "benchmarks/configs/probe.json"})
-    bench["workloads"].append({**bench["workloads"][0],
-                               "name": "probe.pretrain", "config": "probe",
-                               "traffic": "pretrain"})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    """What a later PR does: in a copy of the benchmark
+    (``probe_copy.py``), a configuration with limits of its own, a cell, a
+    schedule, a draw and a per-layer metric are added as new files plus
+    entries at the ends of BENCHMARK.json's lists, no file that was there
+    is touched, and the command runs the new cell."""
+    import probe_copy
+    assert probe_copy.make(tmp_path) == [
+        "configs/probe.json", "draws/probe_ones.py",
+        "layer_metrics/probe_ms.json", "limits/probe.json",
+        "schedules/probe_flat.py", "workloads/probe.pretrain.json"]
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": ROOT}
     env.pop("XLA_FLAGS", None)
     done = subprocess.run(
@@ -488,6 +473,7 @@ def test_a_new_configuration_and_cell_add_files_and_edit_none(tmp_path):
     assert all(k.endswith("@cpu_rehearsal") for k in last["metrics"])
 
 
+@pytest.mark.drives_a_run
 def test_command_refuses_a_backend_that_is_not_a_tpu():
     done = _cli("--trace", "0")
     assert done.returncode != 0 and "found no TPU" in done.stderr

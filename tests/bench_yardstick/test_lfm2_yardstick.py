@@ -1,17 +1,15 @@
-"""CPU tests of what PR 38 added to the yardstick: the FLOP count of a step
-whose layers are of two kinds, the kernels' FLOPs in the form the accepted
-reducer of their share takes, the bytes of the one op that memory bounds,
-the scopes of the step under the accepted scope metrics the cell is listed
-in, the parameter count of the cut, and the new configuration's and cell's
-files. (That the rehearsal passes ``correct`` in float32 and the bfloat16
-control fails it: ``test_bench_yardstick.py`` runs both for every
-configuration there is.) Nothing here is a device metric.
-
-The cell brings no per-layer metric of its own: a program PR may only
-append to ``BENCHMARK.json``'s ``per_layer``, and
-``test_expert_load_yardstick.py`` pins that list's last three entries, so
-the readers of this model's scopes wait for a ``benchmark`` PR (PERF.md
-section 7 has each of them, written out)."""
+"""CPU tests of what PR 38 added to the yardstick, and of the readers of
+its scopes that PR 41 filed: the FLOP count of a step whose layers are of
+two kinds, the bytes of the one op that memory bounds, the scopes of the
+step under the scope metrics the cell is listed in (the accepted ones, the
+expert layer's and the attention projections' under the names the other
+cells read them by, and four of its own: the convolution operator, the op
+inside it, the op's share of the HBM peak and the dense feed-forward), the
+parameter count of the cut, and the configuration's and cell's files. (That
+the rehearsal passes ``correct`` in float32 and the bfloat16 control fails
+it: ``test_bench_yardstick.py`` runs both for every configuration there
+is; the kernels' share of the matrix unit: ``test_kernel_rooflines.py``.)
+Nothing here is a device metric."""
 
 import os
 import re
@@ -28,14 +26,20 @@ if ROOT not in sys.path:
 
 from benchmarks import spec, traffic  # noqa: E402
 from benchmarks.model_flops import lfm2_24b_a2b as mf  # noqa: E402
-from benchmarks.reducers import kernel_mxu_pct  # noqa: E402
+from benchmarks.reducers import op_hbm_pct  # noqa: E402
 
 FILES = spec.layer_metrics()
 CELL = "lfm2_24b_a2b.pretrain_s16384"
 CONFIG = "lfm2_24b_a2b"
-# the accepted scope metrics whose lists of cells gained this one
+# the accepted metrics whose lists of cells gained this one (PR 38, and
+# PR 41 from ``moe_ms`` on), and the four PR 41 filed for it
 APPENDED = ("forward_ms", "backward_ms", "optimizer_ms", "attention_ms",
-            "unscoped_ms", "host_step_ms", "recompute_ms", "rms_norm_ms")
+            "unscoped_ms", "host_step_ms", "recompute_ms", "rms_norm_ms",
+            "moe_ms", "moe_route_ms", "routed_experts_ms", "attn_proj_ms",
+            "moe_held_picks_pct", "moe_expert_rows_max", "moe_late_picks",
+            "attention_kernel_mxu_roofline")
+OWN = ("short_conv_ms", "short_conv_op_ms", "short_conv_op_hbm_roofline",
+       "dense_ffn_ms")
 
 # scopes as the LFM2 step compiled for a v5e carries them (PR 38)
 J = "jit(counted_step)/"
@@ -84,14 +88,29 @@ SCOPES = {
     "fusion.29": J + "optimizer/add",
     # another model's convolution is no layer of this stack
     "fusion.30": J + "jvp(loss)/ResNet/conv/conv2d/conv_general_dilated",
+    "fusion.31": FWD + "0/mlp/swiglu/mul",
+    "fusion.32": BACK + "0/mlp/down_proj/linear/transpose",
 }
 KERNELS = {"p1t_flash_attention_fwd.16", "p1t_flash_attention_bwd_dkv.17"}
-# what each accepted scope metric of the cell holds of the scopes above
+OP = {"p1t_gated_short_conv_fwd.4", "p1t_gated_short_conv_fwd.7",
+      "p1t_gated_short_conv_bwd.8", "fusion.10"}
+ROUTE = {"fusion.19", "sort.20", "p1t_sum_picks_fwd.21", "conditional.22"}
+PRODUCTS = {"ragged-dot-none.23", "ragged-dot-none.24", "fusion.25"}
+# what each scope metric of the cell holds of the scopes above
 EXPECT = {
     "attention_ms": KERNELS | {"fusion.18"},
     "recompute_ms": {"fusion.6", "p1t_gated_short_conv_fwd.7", "fusion.13",
                      "conditional.22"},
     "rms_norm_ms": {"fusion.2", "fusion.13", "fusion.26"},
+    "moe_ms": ROUTE | PRODUCTS,
+    "moe_route_ms": ROUTE,
+    "routed_experts_ms": PRODUCTS,
+    "attn_proj_ms": {"fusion.12", "fusion.13", "fusion.14", "fusion.15"},
+    "short_conv_ms": OP | {"fusion.3", "fusion.5", "fusion.6", "fusion.9"},
+    "short_conv_op_ms": OP,
+    # the leading dense layer's feed-forward; an expert layer's products
+    # lie under ``mlp/moe``
+    "dense_ffn_ms": {"fusion.11", "fusion.31", "fusion.32"},
 }
 
 
@@ -102,19 +121,19 @@ def _view(steps=4, ms=1.0):
             "busy_s": len(SCOPES) * 1e-3 * ms * steps}
 
 
-def test_the_cell_is_appended_to_eight_accepted_lists_and_adds_no_metric():
+def test_the_cell_is_in_the_lists_of_the_metrics_its_scopes_give():
     entries = {m["name"]: m for m in spec.benchmark()["per_layer"]}
-    for name in APPENDED:
-        assert entries[name]["workloads"][-1] == CELL, name
-    assert not [n for n in entries if "lfm2" in n or "short_conv" in n]
-    assert not [n for n in FILES if "lfm2" in n or "short_conv" in n]
-    # the one cell its configuration has
-    assert [w["name"] for w in spec.benchmark()["workloads"]
-            if w["config"] == CONFIG] == [CELL]
+    for name in APPENDED + OWN:
+        assert CELL in entries[name]["workloads"], name
+    for name in OWN:
+        assert FILES[name]["moves"] == "samples_per_s"
+        assert FILES[name]["source"] == "device_trace"
+    assert CELL in [w["name"] for w in spec.benchmark()["workloads"]
+                    if w["config"] == CONFIG]
 
 
 @pytest.mark.parametrize("name", sorted(EXPECT))
-def test_accepted_scope_metric_reads_this_steps_scopes(name):
+def test_scope_metric_reads_this_steps_scopes(name):
     metric = FILES[name]
     match = re.compile(metric["match"])
     exclude = re.compile(metric["exclude"]) if "exclude" in metric else None
@@ -127,49 +146,54 @@ def test_accepted_scope_metric_reads_this_steps_scopes(name):
 
 
 def test_the_operators_scopes_stand_apart():
-    """The scopes ISSUE 38 names, as patterns over the compiled step's
-    paths: the operator blocks share no instruction, the op lies inside
-    its block, the attention op holds the kernels and no projection."""
-    def under(pattern, exclude=None):
-        return {n for n, p in SCOPES.items() if re.search(pattern, p)
-                and not (exclude and re.search(exclude, p))}
-    conv = under("/layers/.*/conv(/|$)")
-    op = under("/gated_short_conv(/|$)")
-    proj = under("/self_attn(/|$)", "/scaled_dot_product_attention(/|$)")
-    assert op == {"p1t_gated_short_conv_fwd.4", "p1t_gated_short_conv_fwd.7",
-                  "p1t_gated_short_conv_bwd.8", "fusion.10"} < conv
-    assert conv - op == {"fusion.3", "fusion.5", "fusion.6", "fusion.9"}
-    assert proj == {"fusion.12", "fusion.13", "fusion.14", "fusion.15"}
+    """The operator blocks share no instruction, the op lies inside its
+    block, the attention op holds the kernels and no projection, and the
+    expert layer's two parts make up its whole."""
+    conv, proj = EXPECT["short_conv_ms"], EXPECT["attn_proj_ms"]
+    assert EXPECT["short_conv_op_ms"] < conv
     assert not conv & proj and not EXPECT["attention_ms"] & (conv | proj)
-    # the expert layer's scopes are kanana2's: its metrics' patterns, which
-    # this cell is not listed under, find them
-    assert under(FILES["moe_route_ms"]["match"]) \
-        == {"fusion.19", "sort.20", "p1t_sum_picks_fwd.21", "conditional.22"}
-    assert under(FILES["routed_experts_ms"]["match"]) \
-        == {"ragged-dot-none.23", "ragged-dot-none.24", "fusion.25"}
-    assert under(FILES["moe_ms"]["match"]) \
-        == under(FILES["moe_route_ms"]["match"]) \
-        | under(FILES["routed_experts_ms"]["match"])
+    assert not EXPECT["dense_ffn_ms"] & (conv | proj | EXPECT["moe_ms"])
+    assert EXPECT["moe_ms"] \
+        == EXPECT["moe_route_ms"] | EXPECT["routed_experts_ms"]
+    assert not EXPECT["moe_route_ms"] & EXPECT["routed_experts_ms"]
 
 
-def test_the_kernels_flops_in_the_form_the_accepted_reducer_takes():
-    """``reducers/kernel_mxu_pct.py`` with a metric that names this cell,
-    as a ``benchmark`` PR would file it: {kernel name: FLOPs a step} over
-    the two instructions' time."""
-    metric = {**FILES["attention_kernel_mxu_pct"], "cell": CELL}
+def test_the_ops_share_of_the_hbm_peak_from_a_hand_made_view():
+    """``reducers/op_hbm_pct.py``: the bytes the op's calls of a step have
+    to move (``model_flops.short_conv_bytes``, at the running cell's size)
+    over the time under the op's scope and the chip's HBM peak."""
+    metric = FILES["short_conv_op_hbm_roofline"]
+    assert metric["reducer"] == "op_hbm_pct" and metric["unit"] == "%"
+    assert metric["match"] == FILES["short_conv_op_ms"]["match"]
     cell, cfg = spec.cell(CELL), spec.config(CONFIG)
-    flops = mf.attention_kernel_flops(cfg, traffic.environment(cfg, cell))
-    assert set(flops) == set(mf.KERNELS)
-    assert {k + s for k, s in zip(mf.KERNELS, (".16", ".17"))} == KERNELS
-    peak = 197e12
-    seconds = sum(flops.values()) / (0.5 * peak)
-    view = _view(ms=1e3 * seconds / 2)
-    ctx = {"views": [view, view], "peak_flops_per_s": peak}
-    assert kernel_mxu_pct.reduce(ctx, metric) == pytest.approx(50.0)
-    bare = {**view, "ops": {k: v for k, v in view["ops"].items()
-                            if "p1t_flash" not in k}}
-    assert kernel_mxu_pct.reduce({"views": [bare], "peak_flops_per_s": peak},
-                                 metric) is None
+    moved = sum(mf.short_conv_bytes(
+        cfg, traffic.environment(cfg, cell)).values())
+    peak = 819e9
+    # the time the op would take at half the peak, spread over the four
+    # instructions under its scope
+    view = _view(ms=1e3 * moved / (0.5 * peak) / len(OP))
+    ctx = {"views": [view, view], "peaks": {"hbm_bytes_per_s": peak},
+           "cell": cell, "config": cfg}
+    maps = (SCOPES, {})
+    assert op_hbm_pct.reduce(ctx, metric, maps=maps) == pytest.approx(50.0)
+    # the bytes are the op's whatever the trace holds: with the forward
+    # that a recomputed segment runs again out of the map, they go over
+    # three quarters of the time
+    once = {k: v for k, v in SCOPES.items()
+            if k != "p1t_gated_short_conv_fwd.7"}
+    assert op_hbm_pct.reduce(ctx, metric, maps=(once, {})) \
+        == pytest.approx(50.0 * 4 / 3)
+    # a rehearsal has no peaks, a run without a trace no view, the parent of
+    # the PR that named the scopes no map, another configuration no such
+    # function, a step without the op no instruction: nothing, no raise
+    bert = {**ctx, "config": spec.config("bert_base"),
+            "cell": spec.cell("bert_base.pretrain_s128")}
+    bare = {k: v for k, v in SCOPES.items() if k not in OP}
+    for other, given in ((({**ctx, "peaks": None}), maps),
+                         ({**ctx, "views": []}, maps),
+                         (ctx, (None, None)), (ctx, ({}, {})),
+                         (bert, maps), (ctx, (bare, {}))):
+        assert op_hbm_pct.reduce(other, metric, maps=given) is None
 
 
 def test_lfm2_flops_hand_count():
@@ -202,10 +226,10 @@ def test_lfm2_flops_hand_count():
     per_pair = 2 * 64 * 32
     assert mf.attention_kernel_flops(cfg, env) == {
         "p1t_flash_attention_fwd": 2 * per_pair * pairs,
-        "p1t_flash_attention_bwd_dkv": 5 * per_pair * pairs}
+        "p1t_flash_attention_bwd": 5 * per_pair * pairs}
     assert mf.attention_kernel_flops(cfg, {"batch": 3, "seq": 64}) == {
         "p1t_flash_attention_fwd": 2 * per_pair * 64 * 65 // 2 * 3,
-        "p1t_flash_attention_bwd_dkv": 5 * per_pair * 64 * 65 // 2 * 3}
+        "p1t_flash_attention_bwd": 5 * per_pair * 64 * 65 // 2 * 3}
     # a brute-force count of the causal pairs
     assert sum(k <= q for q in range(64) for k in range(64)) \
         == mf.causal_pairs({"seq": 64})
@@ -330,16 +354,11 @@ def test_the_lfm2_cell():
     entry = {w["name"]: w for w in spec.benchmark()["workloads"]}[CELL]
     assert entry == {k: cell[k] for k in ("name", "config", "traffic",
                                           "chips", "why")}
-    assert [w["name"] for w in spec.benchmark()["workloads"]][-1] == CELL
-    assert [c["name"] for c in spec.benchmark()["configs"]][-1] == CONFIG
+    assert CONFIG in [c["name"] for c in spec.benchmark()["configs"]]
     listed = {m["name"] for m in spec.per_layer_for(CELL)}
-    assert set(APPENDED) | {"device_step_ms", "step_mfu_pct",
-                            "pallas_time_pct", "peak_hbm_gib",
-                            "compiles_in_window"} <= listed
-    assert not listed & {"norm_ms", "loop_layers_ms", "exit_head_ms",
-                         "shared_experts_ms", "mla_proj_ms", "moe_ms",
-                         "attn_proj_ms", "attention_kernel_mxu_pct",
-                         "moe_late_picks"}
+    assert set(APPENDED + OWN) | {"device_step_ms", "step_mfu_pct",
+                                  "peak_hbm_gib",
+                                  "compiles_in_window"} <= listed
     # every id lies in the slice
     env = traffic.environment(cfg, cell)
     small = {**env, "batch": 2, "seq": 64}

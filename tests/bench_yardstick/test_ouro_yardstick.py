@@ -15,11 +15,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmarks import spec  # noqa: E402
-from benchmarks.reducers import scope_ms, scope_ms_later  # noqa: E402
 
 FILES = spec.layer_metrics()
-LATER = sorted(n for n, m in FILES.items()
-               if m["reducer"] == "scope_ms_later")
 
 # scopes as the Ouro step compiled for a v5e carries them (PR 27)
 J = "jit(counted_step)/"
@@ -70,11 +67,6 @@ def _view(steps=4):
             "busy_s": len(SCOPES) * 1e-3 * steps}
 
 
-def test_the_later_reducer_is_scope_ms():
-    assert scope_ms_later.reduce is scope_ms.reduce
-    assert set(LATER) == set(EXPECT)
-
-
 @pytest.mark.parametrize("name", sorted(EXPECT))
 def test_later_scope_metric_reads_its_scope(name):
     metric = FILES[name]
@@ -123,6 +115,29 @@ def test_ouro_flops_hand_count():
     assert mf.forward_matmul_flops(one, env) == layer + head
 
 
+def test_ouro_attention_kernel_flops_hand_count():
+    """The two attention kernels, a call a layer a loop step (24 each a
+    step), 128 wide throughout: 2 and 5 score-shaped products a causal
+    pair (PERF.md section 5 reads the kernels against 0.1375 and 0.3437
+    TFLOP a call)."""
+    from benchmarks.model_flops import ouro_2p6b as mf
+    cfg = spec.config("ouro_2p6b")
+    pairs = 4096 * 4097 // 2
+    per_pair = 2 * 128 * 16             # one score-shaped product, all heads
+    kernels = mf.attention_kernel_flops(cfg, {"batch": 2, "seq": 4096})
+    assert kernels == {
+        "p1t_flash_attention_fwd": 24 * 2 * 2 * per_pair * pairs,
+        "p1t_flash_attention_bwd": 24 * 2 * 5 * per_pair * pairs}
+    assert kernels["p1t_flash_attention_fwd"] / 24 \
+        == pytest.approx(0.1375e12, rel=1e-3)
+    assert kernels["p1t_flash_attention_bwd"] / 24 \
+        == pytest.approx(0.3437e12, rel=1e-3)
+    # the forward kernel's two products are the model's scores and values
+    one = {**cfg, "total_ut_steps": 1, "num_hidden_layers": 1}
+    assert mf.attention_kernel_flops(one, {"batch": 2, "seq": 4096})[
+        "p1t_flash_attention_fwd"] == 2 * 2 * 2 * pairs * 2048
+
+
 # ByteDance/Ouro-2.6B config.json, the keys that say something of its shape
 PUBLISHED = {
     "head_dim": 128, "hidden_act": "silu", "hidden_size": 2048,
@@ -167,8 +182,5 @@ def test_the_ouro_cell_reads_back_every_fifth_step():
     listed = {m["name"] for m in spec.per_layer_for(cell["name"])}
     assert set(EXPECT) | {"attention_ms", "forward_ms", "backward_ms",
                           "optimizer_ms", "unscoped_ms", "host_step_ms",
-                          "device_step_ms", "step_mfu_pct"} <= listed
-    assert "norm_ms" not in listed
-    for other in ("bert_base.pretrain_s128", "resnet50.train_b128"):
-        assert not set(EXPECT) & {m["name"]
-                                  for m in spec.per_layer_for(other)}
+                          "device_step_ms", "step_mfu_pct",
+                          "attention_kernel_mxu_roofline"} <= listed

@@ -22,6 +22,7 @@ from benchmarks import spec  # noqa: E402
 from benchmarks.reducers import engine_phase_ms, scope_ms  # noqa: E402
 
 FILES = spec.layer_metrics()
+# every metric that reads scopes, whichever PR filed it: one reducer
 SCOPED = sorted(n for n, m in FILES.items() if m["reducer"] == "scope_ms")
 PARTITION = ["forward_ms", "backward_ms", "optimizer_ms", "unscoped_ms"]
 
@@ -73,7 +74,7 @@ def _ctx(views):
     return {"views": views}
 
 
-@pytest.mark.parametrize("name", SCOPED)
+@pytest.mark.parametrize("name", sorted(EXPECT))
 def test_scope_metric_reads_its_region(name):
     metric = FILES[name]
     re.compile(metric["match"])
@@ -110,12 +111,18 @@ def test_an_instruction_the_map_lacks_is_unscoped():
             1.0 if name == "unscoped_ms" else 0.0)
 
 
-@pytest.mark.parametrize("maps", [(None, None), ({}, {})])
-def test_no_map_or_no_view_is_none(maps):
-    for name in SCOPED:
-        assert scope_ms.reduce(_ctx([_view()]), FILES[name], maps=maps) is None
-        assert scope_ms.reduce(_ctx([]), FILES[name],
-                               maps=(SCOPES, {})) is None
+@pytest.mark.parametrize("name", SCOPED)
+def test_any_scope_metric_without_a_map_or_a_view_is_none(name):
+    """Every file that names ``scope_ms``, a later PR's too: its patterns
+    compile, and with no map (the parent of the PR that named the scopes)
+    or no view it reads nothing and raises nothing."""
+    metric = FILES[name]
+    assert metric["unit"] == "ms" and metric["source"] == "device_trace"
+    re.compile(metric["match"])
+    re.compile(metric.get("exclude", ""))
+    for maps in ((None, None), ({}, {})):
+        assert scope_ms.reduce(_ctx([_view()]), metric, maps=maps) is None
+    assert scope_ms.reduce(_ctx([]), metric, maps=(SCOPES, {})) is None
 
 
 def test_report_lists_the_fusions_that_span_regions(capsys):
@@ -160,6 +167,7 @@ def test_engine_phase_ms_on_hand_made_records(capsys):
     assert "medians over 6 dispatches, ms: shard 2.000, guard 0.100" in out
 
 
+@pytest.mark.drives_a_run
 def test_command_rehearsal_prints_host_step_ms():
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env.pop("XLA_FLAGS", None)

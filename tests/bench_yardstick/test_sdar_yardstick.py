@@ -1,8 +1,10 @@
 """CPU tests of what PR 33 added to the yardstick: the FLOP count of a step
 whose attention is counted by the pairs block diffusion's mask lets
-through, the three scope metrics and the kernels' share of the matrix unit
-that read the new names, the uniform draw, and the new configuration's and
-cell's files. (That the rehearsal passes ``correct`` in float32 and the
+through, the scope metrics that read the new names (the expert layer's
+three under the names kanana2's cell reads them by, since PR 41), the
+uniform draw, and the new configuration's and cell's files. (The kernels'
+share of the matrix unit: ``test_kernel_rooflines.py``, for every cell
+that lists it.) (That the rehearsal passes ``correct`` in float32 and the
 bfloat16 control fails it: ``test_bench_yardstick.py`` runs both for every
 configuration there is.) Nothing here is a device metric."""
 
@@ -20,16 +22,15 @@ if ROOT not in sys.path:
 
 from benchmarks import spec, traffic  # noqa: E402
 from benchmarks.model_flops import sdar_30b_a3b as mf  # noqa: E402
-from benchmarks.reducers import (kernel_mxu_pct, scope_ms,  # noqa: E402
-                                 scope_ms_experts)
 
 FILES = spec.layer_metrics()
 CELL = "sdar_30b_a3b.blockdiff_s8192"
 NEW = ("attn_proj_ms", "diffusion_input_ms", "diffusion_head_ms",
-       "attention_kernel_mxu_pct", "sdar_moe_ms", "sdar_moe_route_ms",
-       "sdar_routed_experts_ms")
+       "attention_kernel_mxu_roofline", "moe_ms", "moe_route_ms",
+       "routed_experts_ms")
 
-# scopes as the SDAR step compiled for a v5e carries them (PR 33)
+# scopes as the SDAR step compiled for a v5e carries them (PR 33; one
+# backward kernel since PR 35)
 J = "jit(counted_step)/"
 M = "SdarForBlockDiffusion/"
 FWD = J + "jvp(loss)/" + M + "layers/recompute/"
@@ -51,8 +52,6 @@ SCOPES = {
     + "jit(_fwd_call)/p1t_flash_attention_fwd/pallas_call",
     "p1t_flash_attention_bwd_dkv.11": BACK + "2/" + SDPA
     + "jit(_bwd_call)/p1t_flash_attention_bwd_dkv/pallas_call",
-    "p1t_flash_attention_bwd_dq.12": BACK + "2/" + SDPA
-    + "jit(_bwd_call)/p1t_flash_attention_bwd_dq/pallas_call",
     "fusion.13": BACK + "2/" + SDPA + "jit(_bwd_call)/reduce_sum",
     "fusion.14": FWD + "1/mlp/moe/moe_router/dot_general",
     "ragged-dot-none.15": J + "transpose(jvp(loss))/moe/routed_experts",
@@ -66,11 +65,10 @@ SCOPES = {
     "fusion.20": J + "transpose(jvp(loss))/diffusion_loss/mul",
     "fusion.21": J + "optimizer/add",
     # Kanana-2's attention layer has the same name and its head the same
-    # scope: its cell does not list these metrics
+    # scope
     "fusion.22": J + "jvp(loss)/Kanana2ForPretraining/layers/recompute/1/"
     "self_attn/kv_b_proj/linear/dot_general",
-    # the expert layer under Kanana-2's names, which its own cell's metrics
-    # keep to themselves (``test_kanana2_yardstick.py``)
+    # the expert layer under Kanana-2's names
     "sort.23": FWD + "1/mlp/moe/moe_dispatch/sort",
     "p1t_sum_picks_fwd.24": FWD + "1/mlp/moe/moe_combine/"
     "p1t_sum_picks_fwd/pallas_call",
@@ -84,16 +82,15 @@ EXPECT = {
     "diffusion_input_ms": {"fusion.1", "fusion.2"},
     "diffusion_head_ms": {"fusion.16", "fusion.17", "fusion.18", "fusion.19",
                           "fusion.20"},
-    "sdar_moe_ms": {"fusion.14", "ragged-dot-none.15", "sort.23",
-                    "p1t_sum_picks_fwd.24", "conditional.25",
-                    "ragged-dot-none.26", "fusion.27"},
-    "sdar_moe_route_ms": {"fusion.14", "sort.23", "p1t_sum_picks_fwd.24",
-                          "conditional.25"},
-    "sdar_routed_experts_ms": {"ragged-dot-none.15", "ragged-dot-none.26",
-                               "fusion.27"},
+    "moe_ms": {"fusion.14", "ragged-dot-none.15", "sort.23",
+               "p1t_sum_picks_fwd.24", "conditional.25",
+               "ragged-dot-none.26", "fusion.27"},
+    "moe_route_ms": {"fusion.14", "sort.23", "p1t_sum_picks_fwd.24",
+                     "conditional.25"},
+    "routed_experts_ms": {"ragged-dot-none.15", "ragged-dot-none.26",
+                          "fusion.27"},
 }
-KERNELS = {"p1t_flash_attention_fwd.10", "p1t_flash_attention_bwd_dkv.11",
-           "p1t_flash_attention_bwd_dq.12"}
+KERNELS = {"p1t_flash_attention_fwd.10", "p1t_flash_attention_bwd_dkv.11"}
 
 
 def _view(steps=4, ms=1.0):
@@ -101,12 +98,6 @@ def _view(steps=4, ms=1.0):
            1e-3 * ms * steps for i, n in enumerate(SCOPES)}
     return {"ops": ops, "step_s": [len(SCOPES) * 1e-3 * ms] * steps,
             "busy_s": len(SCOPES) * 1e-3 * ms * steps}
-
-
-def test_the_new_scope_metrics_name_the_experts_reducer():
-    assert scope_ms_experts.reduce is scope_ms.reduce
-    assert all(FILES[n]["reducer"] == "scope_ms_experts" for n in EXPECT)
-    assert FILES["attention_kernel_mxu_pct"]["reducer"] == "kernel_mxu_pct"
 
 
 @pytest.mark.parametrize("name", sorted(EXPECT))
@@ -138,33 +129,6 @@ def test_the_attention_op_holds_the_kernels_and_stands_apart():
         == {"fusion.4", "fusion.7", "fusion.18", "conditional.25"}
 
 
-def test_the_kernels_share_of_the_matrix_unit_from_a_hand_made_view():
-    metric = FILES["attention_kernel_mxu_pct"]
-    assert metric["cell"] == CELL and metric["unit"] == "%"
-    cell, cfg = spec.cell(CELL), spec.config("sdar_30b_a3b")
-    flops = mf.attention_kernel_flops(cfg, traffic.environment(cfg, cell))
-    assert set(flops) == set(mf.KERNELS)
-    peak = 197e12
-    # the time the three kernels would take at half the peak, spread over
-    # the three instructions of the view
-    seconds = sum(flops.values()) / (0.5 * peak)
-    view = _view(ms=1e3 * seconds / 3)
-    ctx = {"views": [view, view], "peak_flops_per_s": peak}
-    assert kernel_mxu_pct.reduce(ctx, metric) == pytest.approx(50.0)
-    per = kernel_mxu_pct.seconds_a_step(view, list(mf.KERNELS))
-    assert all(s == pytest.approx(seconds / 3) for s in per.values())
-    # a rehearsal has no peak, the parent no such instruction, a run with
-    # no trace no view: nothing, no raise
-    assert kernel_mxu_pct.reduce({**ctx, "peak_flops_per_s": None},
-                                 metric) is None
-    assert kernel_mxu_pct.reduce({"views": [], "peak_flops_per_s": peak},
-                                 metric) is None
-    bare = {**view, "ops": {k: v for k, v in view["ops"].items()
-                            if "p1t_flash" not in k}}
-    assert kernel_mxu_pct.reduce({"views": [bare], "peak_flops_per_s": peak},
-                                 metric) is None
-
-
 def _brute_force_pairs(length, block):
     """Visible (query, key) pairs of a doubled row, pair by pair."""
     count = 0
@@ -185,10 +149,12 @@ def test_the_flop_count_is_a_brute_force_count_of_visible_pairs(block):
     pairs = _brute_force_pairs(64, block)
     assert mf.visible_pairs(cfg, env) == pairs == 64 * 64 + 64 * block
     per_pair = 2 * 128 * 32             # one score-shaped product, all heads
+    # the forward kernel's scores and values; the one backward kernel's
+    # scores again, dV, dP, dQ and dK (PR 35; 2 + 4 + 3 over three names
+    # before it, which PR 41 took out as stale)
     assert mf.attention_kernel_flops(cfg, env) == {
         "p1t_flash_attention_fwd": 2 * per_pair * pairs * 3 * 5,
-        "p1t_flash_attention_bwd_dkv": 4 * per_pair * pairs * 3 * 5,
-        "p1t_flash_attention_bwd_dq": 3 * per_pair * pairs * 3 * 5}
+        "p1t_flash_attention_bwd": 5 * per_pair * pairs * 3 * 5}
 
 
 def test_sdar_flops_hand_count():
@@ -291,20 +257,8 @@ def test_the_sdar_cell():
     assert set(NEW) | {"attention_ms", "recompute_ms", "rms_norm_ms",
                        "forward_ms", "backward_ms", "optimizer_ms",
                        "unscoped_ms", "host_step_ms", "device_step_ms",
-                       "step_mfu_pct"} <= listed
-    assert not listed & {"norm_ms", "loop_layers_ms", "exit_head_ms",
-                         "shared_experts_ms", "mla_proj_ms"}
-    # the kernels' share takes its FLOPs from this cell's size (the
-    # metric's file names the cell: a reducer is not told which cell runs),
-    # so no other cell may list it; the scope metrics are any cell's
-    for other in spec.names_in("workloads"):
-        if other != CELL:
-            assert "attention_kernel_mxu_pct" not in {
-                m["name"] for m in spec.per_layer_for(other)}
-    # the expert layer's three scopes under names of this cell's own, the
-    # patterns Kanana-2's three have
-    for name in ("moe_ms", "moe_route_ms", "routed_experts_ms"):
-        assert FILES["sdar_" + name]["match"] == FILES[name]["match"]
+                       "step_mfu_pct", "moe_held_picks_pct",
+                       "moe_expert_rows_max", "moe_late_picks"} <= listed
     # ids below the id that stands for [MASK]
     env = traffic.environment(cfg, cell)
     small = {**env, "batch": 2, "seq": 64, "blocks": 16}
