@@ -12,7 +12,9 @@ blockwise attention kernels run block diffusion's mask rule with grouped
 key/value heads at SDAR's size against a float32 dense-mask reference in
 blocks, the causal rule runs with 32 / 8 heads at head width 64 over LFM2's
 16,384 positions and the two gated short-convolution kernels run at its
-size against float32 shifted sums, and the routed-expert layer takes more
+size against float32 shifted sums, the sliding-window rule (4,096 keys)
+runs with 28 / 4 heads at SmallThinker's size against a float32 dense
+band in blocks, and the routed-expert layer takes more
 held picks than its grouped products have rows and counts the late ones
 on the device, eagerly and in two compiled steps
 (``ParallelEngine.expert_load()``). Every
@@ -348,12 +350,6 @@ def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
                                  blk.reshape(-1, rows)))
         return out.reshape(1, s, heads, dim)
 
-    def vjp_of(fn):
-        def f(q, k, v, dout):
-            out, pull = jax.vjp(fn, q, k, v)
-            return (out,) + pull(dout.astype(out.dtype))
-        return jax.jit(f)
-
     got = vjp_of(kernels)
     text = got.lower(q, k, v, dout).compile().as_text()
     n_calls = text.count('custom_call_target="tpu_custom_call"')
@@ -390,6 +386,69 @@ def block_diffusion_phase(length=8192, block=4, heads=32, kv_heads=4,
               "(smoke reading, not a metric)", flush=True)
 
 
+def in_blocks_reference(window=None, rows=512):
+    """-> ``plain(q, k, v)``: causal grouped-query attention on [1, s, H,
+    d] in float32, one block of ``rows`` queries of every head at a time
+    against every key under a dense mask made from the two positions
+    (key ``j`` up to query ``i`` and, under a ``window``, fewer than
+    ``window`` behind it); not from ``mask_rules``."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+
+    def plain(q, k, v):
+        (_, seq, heads, dim), kv_heads = q.shape, k.shape[2]
+        qg = q[0].astype(f32).reshape(seq // rows, rows, kv_heads,
+                                      heads // kv_heads, dim)
+        kf, vf = k[0].astype(f32), v[0].astype(f32)
+
+        @jax.checkpoint
+        def some(args):
+            qb, at = args
+            scores = jnp.einsum("qngd,knd->ngqk", qb, kf) / dim ** 0.5
+            behind = at[:, None] - jnp.arange(seq)[None]
+            seen = (behind >= 0) & ((behind < window) if window else True)
+            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            return jnp.einsum("ngqk,knd->qngd", probs, vf)
+        out = jax.lax.map(some, (qg, jnp.arange(seq).reshape(-1, rows)))
+        return out.reshape(1, seq, heads, dim)
+    return plain
+
+
+def vjp_of(fn):
+    """``fn(*args[:-1])`` and its pull-back of ``args[-1]``, jitted."""
+    import jax
+
+    def f(*args):
+        out, pull = jax.vjp(fn, *args[:-1])
+        return (out,) + pull(args[-1].astype(out.dtype))
+    return jax.jit(f)
+
+
+def kernels_against_blocks(at, q, k, v, dout, window=None):
+    """The two blockwise kernels (causal, or the sliding window's rule)
+    on bf16 operands against :func:`in_blocks_reference` in float32: out
+    and the three gradients to 5e-2 of the reference's largest value.
+    -> the jitted forward + backward of the kernels."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.ops.pallas import flash_attention
+    from paddle1_tpu.ops.pallas.mask_rules import SlidingWindow
+    how = (dict(causal=True) if window is None
+           else dict(mask=SlidingWindow(window)))
+    got = vjp_of(lambda q, k, v: flash_attention.flash_attention(
+        q, k, v, **how))
+    with jax.default_matmul_precision("highest"):
+        want = vjp_of(in_blocks_reference(window))(
+            q, k, v, dout.astype(jnp.float32))
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got(q, k, v, dout),
+                          want):
+        err = max_err(g, w) / float(np.max(np.abs(np.asarray(w))))
+        check(err <= 5e-2, f"flash {at} {name}: max abs err / max |ref| = "
+                           f"{err:.2e} <= 5e-2")
+    return got
+
+
 def lfm2_phase(seq=16384, heads=32, kv_heads=8, dim=64, channels=2048,
                taps=3):
     """LFM2-24B-A2B's two operators at its size: the blockwise kernels
@@ -401,47 +460,15 @@ def lfm2_phase(seq=16384, heads=32, kv_heads=8, dim=64, channels=2048,
     import jax
     import jax.numpy as jnp
     from paddle1_tpu.nn.functional import short_conv as op
-    from paddle1_tpu.ops.pallas import flash_attention
     from paddle1_tpu.ops.pallas import short_conv as kernels
     bf16, f32 = jnp.bfloat16, jnp.float32
-    group = heads // kv_heads
     keys = jax.random.split(jax.random.key(38), 7)
     q = jax.random.normal(keys[0], (1, seq, heads, dim), bf16)
     k = jax.random.normal(keys[1], (1, seq, kv_heads, dim), bf16)
     v = jax.random.normal(keys[2], (1, seq, kv_heads, dim), bf16)
     dout = jax.random.normal(keys[3], (1, seq, heads, dim), bf16)
-    at = f"[1, {seq}, {heads}/{kv_heads}, {dim}] causal"
-    rows = 512
-
-    def plain(q, k, v):
-        qg = q[0].astype(f32).reshape(seq // rows, rows, kv_heads, group, dim)
-        kf, vf = k[0].astype(f32), v[0].astype(f32)
-
-        @jax.checkpoint
-        def some(args):
-            qb, at = args
-            scores = jnp.einsum("qngd,knd->ngqk", qb, kf) / dim ** 0.5
-            seen = jnp.arange(seq)[None] <= at[:, None]
-            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
-            return jnp.einsum("ngqk,knd->qngd", probs, vf)
-        out = jax.lax.map(some, (qg, jnp.arange(seq).reshape(-1, rows)))
-        return out.reshape(1, seq, heads, dim)
-
-    def vjp_of(fn):
-        def f(*args):
-            out, pull = jax.vjp(fn, *args[:-1])
-            return (out,) + pull(args[-1].astype(out.dtype))
-        return jax.jit(f)
-
-    got = vjp_of(lambda q, k, v: flash_attention.flash_attention(
-        q, k, v, causal=True))
-    with jax.default_matmul_precision("highest"):
-        want = vjp_of(plain)(q, k, v, dout.astype(f32))
-    for name, g, w in zip(("out", "dq", "dk", "dv"), got(q, k, v, dout),
-                          want):
-        err = max_err(g, w) / float(np.max(np.abs(np.asarray(w))))
-        check(err <= 5e-2, f"flash {at} {name}: max abs err / max |ref| = "
-                           f"{err:.2e} <= 5e-2")
+    kernels_against_blocks(f"[1, {seq}, {heads}/{kv_heads}, {dim}] causal",
+                           q, k, v, dout)
 
     bcx = jax.random.normal(keys[4], (1, seq, 3 * channels), bf16)
     w = (0.5 * jax.random.normal(keys[5], (channels, taps))).astype(bf16)
@@ -475,6 +502,33 @@ def lfm2_phase(seq=16384, heads=32, kv_heads=8, dim=64, channels=2048,
         jax.block_until_ready(out)
         print(f"chip_smoke: {name} {at}: "
               f"{100 * (time.perf_counter() - t):.3f} ms a call (smoke "
+              "reading on the host's clock, not a metric)", flush=True)
+
+
+def sliding_window_phase(seq=16384, heads=28, kv_heads=4, dim=128,
+                         window=4096):
+    """The blockwise kernels under the sliding window's rule, a band of
+    4,096 keys under the diagonal, with query heads in groups of 7 at
+    SmallThinker-21BA3B's size ([1, 16384, 28 / 4, 128] bf16) against a
+    float32 dense band in blocks; then a call's forward + backward beside
+    the causal rule's at that shape, on the host's clock."""
+    import jax
+    import jax.numpy as jnp
+    bf16 = jnp.bfloat16
+    keys = jax.random.split(jax.random.key(43), 4)
+    q, k, v, dout = (jax.random.normal(kk, (1, seq, h, dim), bf16)
+                     for kk, h in zip(keys, (heads, kv_heads, kv_heads,
+                                             heads)))
+    shape = f"[1, {seq}, {heads}/{kv_heads}, {dim}]"
+    for at, w in ((f"{shape} window {window}", window),
+                  (f"{shape} causal", None)):
+        got = kernels_against_blocks(at, q, k, v, dout, w)
+        t = time.perf_counter()
+        for _ in range(5):
+            out = got(q, k, v, dout)
+        jax.block_until_ready(out)
+        print(f"chip_smoke: flash forward + backward {at}: "
+              f"{200 * (time.perf_counter() - t):.3f} ms a call (smoke "
               "reading on the host's clock, not a metric)", flush=True)
 
 
@@ -636,6 +690,7 @@ def main():
         kernel_phase()
         block_diffusion_phase()
         lfm2_phase()
+        sliding_window_phase()
         experts_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {

@@ -219,9 +219,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     ``use_flash_for`` says they win and the shapes are tile-aligned, else
     the XLA composition. ``key`` and ``value`` may have fewer heads than
     ``query`` (grouped-query attention). ``mask_rule``: a structured mask
-    as a description (``ops.pallas.mask_rules``: block diffusion's), not
-    a dense array: the kernels skip its hidden tiles, the composition
-    builds the dense mask from it."""
+    as a description (``ops.pallas.mask_rules``: block diffusion's, or
+    ``SlidingWindow(window)``, the causal mask cut to a band; given in
+    ``is_causal``'s place, not beside it), not a dense array: the kernels
+    skip its hidden tiles, the composition builds the dense mask from
+    it."""
     q, k, v = _t(query), _t(key), _t(value)
     drop = dropout_p if training else 0.0
     dropout_key = None

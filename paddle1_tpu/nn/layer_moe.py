@@ -21,6 +21,18 @@ selection bias):
   ``y = sum_{e chosen and held} w_e E_e(u) + S(u)``, ``E_e`` and ``S``
   SwiGLU feed-forwards.
 
+Two things a caller may say beside that (SmallThinker says both). **What
+the router reads**: ``forward(x, router_input=r)`` scores and chooses from
+``r`` and computes the experts from ``x`` (``s = score(float32(r) W_g)``,
+``y = sum w_e E_e(x)``): a model whose router stands before its attention
+block routes on that block's input and feeds the experts the stream after
+it, so the choice, the sorts and the counts wait for ``r`` alone. ``r``
+has ``x``'s leading shape; left out, it is ``x``. **The gate's
+activation**: ``E_e(u) = (act(u W_gate_e) * (u W_up_e)) W_down_e`` with
+``act`` the constructor's ``gate_activation``, ``"silu"`` (SwiGLU) unless
+told ``"relu"`` (ReGLU); the overflow path takes the same one. The shared
+experts stay SwiGLU.
+
 **No token is dropped, shapes are static, and the work follows the picks
 that land here.** The ``tokens x top_k`` picks are sorted by held expert
 (the picks of absent experts last); the first ``capacity`` rows of that
@@ -279,14 +291,17 @@ def grouped_matmul(rows, weights, sizes):
         return jax.lax.ragged_dot(rows, weights, sizes)
 
 
-def expert_ffn(xs, sizes, gate_up, down):
-    """Held experts' SwiGLU over their own rows: ``gate_up`` [held,
-    hidden, 2 * width] (gate columns first), ``down`` [held, width,
-    hidden]."""
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def expert_ffn(xs, sizes, gate_up, down, act=jax.nn.silu):
+    """Held experts' gated feed-forward over their own rows: ``gate_up``
+    [held, hidden, 2 * width] (gate columns first), ``down`` [held, width,
+    hidden]; ``act`` on the gate (SwiGLU unless told another)."""
     both = grouped_matmul(xs, gate_up, sizes)
     width = both.shape[-1] // 2
-    return grouped_matmul(jax.nn.silu(both[:, :width]) * both[:, width:],
-                          down, sizes)
+    return grouped_matmul(act(both[:, :width]) * both[:, width:], down,
+                          sizes)
 
 
 def capacity_rows(tokens, top_k, held, num_experts):
@@ -300,15 +315,22 @@ def capacity_rows(tokens, top_k, held, num_experts):
 
 class RoutedExperts(Layer):
     """See the module's docstring. ``held = (first, count)``: the experts
-    this layer holds, all of them when None. ``forward``: [..., d_model]
-    -> the same shape."""
+    this layer holds, all of them when None. ``gate_activation``: what an
+    expert puts on its gate, ``"silu"`` or ``"relu"``. ``forward(x,
+    router_input=None)``: [..., d_model] -> the same shape; the router
+    scores ``router_input`` (``x`` where None), the experts compute from
+    ``x``."""
 
     def __init__(self, d_model, expert_width, num_experts, top_k,
                  held=None, shared_width=0, routed_scaling_factor=1.0,
-                 weight_attr=None, scoring="sigmoid", norm_eps=1e-20):
+                 weight_attr=None, scoring="sigmoid", norm_eps=1e-20,
+                 gate_activation="silu"):
         super().__init__()
         if scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring={scoring!r}")
+        if gate_activation not in GATE_ACTIVATIONS:
+            raise ValueError(f"gate_activation={gate_activation!r}")
+        self.gate_activation = gate_activation
         self.num_experts, self.top_k = num_experts, top_k
         self.scoring, self.norm_eps = scoring, norm_eps
         self.first, self.held = held if held is not None else (0, num_experts)
@@ -349,18 +371,23 @@ class RoutedExperts(Layer):
                 "picks_made_a_step": made, "capacity_rows": capacity,
                 "held": self.held, "num_experts": self.num_experts}
 
-    def forward(self, x):
+    def forward(self, x, router_input=None):
         from ..ops import manip_ops
         shape = list(x.shape)
         with scope("moe"):
             flat = manip_ops.reshape(x, [-1, shape[-1]])
-            y = self._routed(flat)
+            y = self._routed(flat, flat if router_input is None else
+                             manip_ops.reshape(router_input,
+                                               [-1, shape[-1]]))
             if self.shared_experts is not None:
                 y = y + self.shared_experts(flat)
             return manip_ops.reshape(y, shape)
 
-    def _routed(self, x):
+    def _routed(self, x, scored):
+        """``x`` [tokens, d_model] through the held experts that the
+        router picks from ``scored`` [tokens, d_model]."""
         k, first, held = self.top_k, self.first, self.held
+        act = GATE_ACTIVATIONS[self.gate_activation]
         tokens = x.shape[0]
         capacity = capacity_rows(tokens, k, held, self.num_experts)
         # the softmax rule has no selection bias to hand in
@@ -370,7 +397,7 @@ class RoutedExperts(Layer):
             "moe_router", lambda x, w, b=None: route(
                 x, w, b, k, self.routed_scaling_factor, self.scoring,
                 self.norm_eps),
-            (x, self.router) + bias)
+            (scored, self.router) + bias)
 
         def dispatch(x, weights, chosen):
             order, where, sizes, overflow = sort_picks(chosen, first, held,
@@ -390,7 +417,7 @@ class RoutedExperts(Layer):
         self._load_shape = (tokens * k, capacity)
         record_state_update(self.expert_load, load.data, "add")
         out = apply("routed_experts", expert_ffn,
-                    (xs, sizes, self.gate_up_proj, self.down_proj))
+                    (xs, sizes, self.gate_up_proj, self.down_proj), act=act)
 
         def combine(out, ws, order, where, sizes):
             # a row past the groups holds whatever the product left there
@@ -410,7 +437,7 @@ class RoutedExperts(Layer):
                 mine = jnp.sum(jnp.where(late == e, weights, 0.0), -1)
                 both = jnp.dot(x, gate_up[e])
                 width = both.shape[-1] // 2
-                out = jnp.dot(jax.nn.silu(both[:, :width]) * both[:, width:],
+                out = jnp.dot(act(both[:, :width]) * both[:, width:],
                               down[e])
                 y = y + (mine[:, None] * out).astype(y.dtype)
             return y

@@ -507,6 +507,8 @@ def process_group(label_key) -> MetricsGroup:
     total{arm}``, ``flash_tiles_total{kind}`` and ``flash_grid_steps_
     total{kind}`` (counted when a kernel call is traced: its score tiles
     plain, masked and skipped; its grid's steps working and held),
+    ``flash_pairs_total{rule}`` (the pairs its mask rule lets through, by
+    the rule's name),
     ``recompute_kept_bytes_total{name}`` and ``recompute_
     kept_values_total{name}`` (what each traced ``fleet.utils.recompute``
     segment was given to keep, by the shapes of the values named inside:

@@ -249,6 +249,16 @@ def _count_steps(table, calls):
                  calls)
 
 
+def _count_pairs(rule, nq, nk, calls):
+    """``flash_pairs_total{rule}``: the (query, key) pairs the rule lets
+    through (``rule.pairs``, a closed form) of one lowered kernel call x
+    its ``calls`` (batch x heads), by the rule's name: the work a kernel
+    is there to do, whatever tiles it takes to do it."""
+    from ...obs.registry import process_group
+    process_group("rule").child(rule.name).counter(
+        "flash_pairs_total").inc(rule.pairs(nq, nk) * calls)
+
+
 def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [BK, D]; mask_ref: [1, BK] f32,
     # 1.0 = attend / 0.0 = padding; lse_ref: [BQ, 128]
@@ -362,6 +372,7 @@ def _fwd_call(q, k, v, padding_mask, *, scale, rule, blocks, interpret):
     table = pair_table(rule, nq, nk, bq, bk)
     _count_tiles(rule, nq, nk, bq, chunk, b * h)
     _count_steps(table, b * h)
+    _count_pairs(rule, nq, nk, b * h)
 
     def spec(shape, index):
         """A window placed by (batch x head, query block, key block) of

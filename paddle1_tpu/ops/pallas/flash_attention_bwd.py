@@ -49,9 +49,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
-from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_steps,
-                              _count_tiles, _dot, _layout, _run_tile,
-                              _unlayout, block_sizes, split_blocks)
+from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_pairs,
+                              _count_steps, _count_tiles, _dot, _layout,
+                              _run_tile, _unlayout, block_sizes,
+                              split_blocks)
 from .mask_rules import FIRST, HELD, LAST, pair_table
 
 __all__ = ["flash_attention_bwd", "block_sizes"]
@@ -231,6 +232,7 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
         head = lambda g, t: g // h_kv * h + g % h_kv * group + t
     _count_tiles(rule, nq, nk, bq, chunk, b * h)
     _count_steps(table, b * h)
+    _count_pairs(rule, nq, nk, b * h)
     _count_ranges(ranges)
 
     def spec(shape, index):

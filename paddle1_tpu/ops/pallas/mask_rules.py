@@ -20,8 +20,12 @@ scalar prefetch, so a grid has no step that fetches or runs nothing.
 
 :data:`NO_MASK` hides nothing (every tile is plain: the kernels emit the
 unmasked body alone), :data:`CAUSAL` is the bottom-right causal mask the
-kernels have always had, :class:`BlockDiffusion` the training mask of
-block diffusion (BD3-LMs, arXiv:2503.09573; SDAR, arXiv:2510.06303).
+kernels have always had, :class:`SlidingWindow` the causal mask cut to a
+band (a query sees itself and the ``window - 1`` keys before it: the
+local layers of a model that mixes them with global ones),
+:class:`BlockDiffusion` the training mask of block diffusion (BD3-LMs,
+arXiv:2503.09573; SDAR, arXiv:2510.06303). A rule's ``name`` labels what
+is counted by rule (``flash_pairs_total{rule}``).
 Every function takes and gives arrays (numpy or jax, scalars included),
 so the kernels call them on the table's scalars, and :func:`pair_table`,
 :func:`tile_counts` and the tests on ``numpy.arange``s: one definition
@@ -37,7 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["NO_MASK", "NoMask", "CAUSAL", "Causal", "BlockDiffusion",
+__all__ = ["NO_MASK", "NoMask", "CAUSAL", "Causal", "SlidingWindow",
+           "BlockDiffusion",
            "dense_mask", "tile_counts", "visible_pairs", "pair_table",
            "PairTable", "FIRST", "LAST", "HELD"]
 
@@ -50,6 +55,7 @@ def _iota(shape, axis):
 class NoMask:
     """Every query sees every key. The answers are Python's own ``True``,
     so that a kernel's trace decides them and holds no branch."""
+    name = "none"
 
     def lengths_ok(self, nq, nk):
         return True
@@ -74,6 +80,7 @@ NO_MASK = NoMask()
 class Causal:
     """Bottom-right aligned: query ``r`` sees keys ``<= r + nk - nq``
     (``off`` below is ``nk - nq``)."""
+    name = "causal"
 
     def lengths_ok(self, nq, nk):
         # nq > nk leaves leading queries with ZERO visible keys; the
@@ -107,6 +114,52 @@ CAUSAL = Causal()
 
 
 @dataclasses.dataclass(frozen=True)
+class SlidingWindow:
+    """Causal, cut to a band: with ``d = r + nk - nq - c`` the distance of
+    key ``c`` behind query ``r`` (bottom-right aligned as :class:`Causal`),
+    the query sees the key iff ``0 <= d < window``: itself and the
+    ``window - 1`` keys before it. A score tile holds every distance from
+    its top-right corner's to its bottom-left corner's, so it can be
+    crossed by the diagonal, by the band's far edge, or by both (a window
+    shorter than a block); a window of ``nk`` or more is the causal
+    mask."""
+    window: int
+    name = "window"
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"window {self.window}: a query sees itself")
+
+    def lengths_ok(self, nq, nk):
+        return nq <= nk         # as the causal rule: no query without a key
+
+    def sizes(self, nq, nk):
+        return nq, nk
+
+    def tile(self, q0, bq, k0, bk, off):
+        nearest = q0 + off - (k0 + bk - 1)      # the top-right corner's d
+        farthest = q0 + bq - 1 + off - k0       # the bottom-left corner's
+        return ((farthest >= 0) & (nearest < self.window),
+                (nearest >= 0) & (farthest < self.window))
+
+    def keep(self, shape, q0, k0, off, q_axis):
+        d = (q0 + off + _iota(shape, q_axis)) - (k0 + _iota(shape,
+                                                            1 - q_axis))
+        return (d >= 0) & (d < self.window)
+
+    def dense(self, nq, nk):
+        d = np.arange(nq)[:, None] + (nk - nq) - np.arange(nk)[None]
+        return (d >= 0) & (d < self.window)
+
+    def pairs(self, nq, nk):
+        # the first queries see fewer than ``window`` keys: off + 1, ...
+        off = nk - nq
+        short = min(max(self.window - 1 - off, 0), nq)
+        return (short * (off + 1) + short * (short - 1) // 2
+                + (nq - short) * self.window)
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockDiffusion:
     """A row of ``2 * length`` positions: a noisy copy and a clean copy of
     one sequence, the noisy one first where ``noisy_first``; blocks of
@@ -124,6 +177,7 @@ class BlockDiffusion:
     length: int
     block: int
     noisy_first: bool = True
+    name = "block_diffusion"
 
     def __post_init__(self):
         if self.block < 1 or self.block & (self.block - 1) \

@@ -13,6 +13,9 @@ from .ouro import (OuroDecoderLayer, OuroExitHead, OuroForPretraining,
                    OuroPretrainingCriterion, OuroStack)
 from .sdar import (SdarAttention, SdarBlockDiffusionCriterion,
                    SdarDecoderLayer, SdarForBlockDiffusion, SdarStack)
+from .smallthinker import (SmallThinkerAttention, SmallThinkerDecoderLayer,
+                           SmallThinkerForPretraining,
+                           SmallThinkerPretrainingCriterion)
 
 __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "BertForSequenceClassification", "ErnieModel",
@@ -25,4 +28,6 @@ __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "SdarDecoderLayer", "SdarStack", "SdarForBlockDiffusion",
            "SdarBlockDiffusionCriterion", "Lfm2Attention",
            "Lfm2DecoderLayer", "Lfm2Stack", "Lfm2Head", "Lfm2ForPretraining",
-           "Lfm2PretrainingCriterion"]
+           "Lfm2PretrainingCriterion", "SmallThinkerAttention",
+           "SmallThinkerDecoderLayer", "SmallThinkerForPretraining",
+           "SmallThinkerPretrainingCriterion"]

@@ -155,6 +155,12 @@ SDAR_RULE = mask_rules.BlockDiffusion(8192, 4)
 # 32 query heads over 8, twice the longest causal row of any other cell
 LFM2 = (1, 16384, 32, 64)
 
+# smallthinker_21b_a3b.pretrain_s16384's attention calls: 28 query heads
+# over 4 (groups of 7, the first that is no power of two), under the
+# window's rule on three layers of four and the causal one on the fourth
+SMALLTHINKER = (1, 16384, 28, 128)
+WINDOW = mask_rules.SlidingWindow(4096)
+
 CASES = {
     "flash_fwd_b32_s128": lambda: _flash()(*B32_S128),
     "flash_fwd_b8_s512": lambda: _flash()(*B8_S512),
@@ -198,6 +204,15 @@ CASES = {
         lambda: _flash(causal=True, kv_heads=8)(*LFM2),
     "flash_causal_grad_lfm2_s16384_h32_kv8_d64":
         lambda: _flash(causal=True, kv_heads=8, grad=True)(*LFM2),
+    "flash_window_smallthinker_s16384_h28_kv4":
+        lambda: _flash(mask=WINDOW, kv_heads=4)(*SMALLTHINKER),
+    "flash_window_grad_smallthinker_s16384_h28_kv4":
+        lambda: _flash(mask=WINDOW, kv_heads=4, grad=True)(*SMALLTHINKER),
+    # a window shorter than a block (both edges through one tile) with a
+    # group of 7 and a padding mask
+    "flash_window_masked_grad_s2048_h7_kv1_w300":
+        lambda: _flash(mask=mask_rules.SlidingWindow(300), kv_heads=1,
+                       masked=True, grad=True)(2, 2048, 7, 128),
     # LFM2's gated short convolution: one 16k row of 2048 channels, 3 taps
     "short_conv_fwd_lfm2_16384x2048": lambda: _short_conv()(1, 16384, 2048),
     "short_conv_bwd_lfm2_16384x2048":
@@ -257,13 +272,17 @@ BACKWARD_CALLS = {
     "kanana2_30b_a3b": (KANANA2[:4], 32, 128, mask_rules.CAUSAL, False),
     "sdar_30b_a3b": (SDAR, 4, 128, SDAR_RULE, False),
     "lfm2_24b_a2b": (LFM2, 8, 64, mask_rules.CAUSAL, False),
+    "smallthinker_21b_a3b_window": (SMALLTHINKER, 4, 128, WINDOW, False),
+    "smallthinker_21b_a3b_global": (SMALLTHINKER, 4, 128, mask_rules.CAUSAL,
+                                    False),
 }
 
 
 # the steps of a head of each cell's table at the shipped (512, 1024): the
 # parent's rectangles had 288, 128, 512 and 32
 TABLE_STEPS = {"ouro_2p6b": 20, "kanana2_30b_a3b": 72, "sdar_30b_a3b": 160,
-               "lfm2_24b_a2b": 272}
+               "lfm2_24b_a2b": 272, "smallthinker_21b_a3b_window": 140,
+               "smallthinker_21b_a3b_global": 272}
 
 
 @pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
@@ -589,6 +608,93 @@ def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
     assert not re.search(r"\[(\d+,)*16384,16384\]", text)
     assert not re.search(r"\bwhile\(", text)
     assert "/rematted_computation/" in text
+
+
+def test_recomputed_smallthinker_blocks_name_their_kind_and_route_first(
+        one_chip, for_the_chip, monkeypatch):
+    """A global and a window block of SmallThinker's step at the cell's
+    shape ([1, 16384, 2560] bf16, 28 query heads over 4 of 128, a window
+    of 4,096, 8 of 64 ReLU-gated experts, top-6) under
+    ``fleet.utils.recompute``, loss and gradients (ISSUE 43): each
+    block's attention is the two blockwise kernels under a scope that
+    names its kind, the forward one not run again; Mosaic takes the
+    backward's VMEM request with a key head's dK and dV resident while 7
+    query heads pass; k, v, dK and dV stay 4 heads wide; the router's
+    product reads the block's input and is float32; rotary is on the
+    window block alone; every grouped product lies in a region under the
+    expert layer's scope; nothing is shaped like a dense mask."""
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle1_tpu.obs import costmodel
+    from paddle1_tpu.text.models import SmallThinkerDecoderLayer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    experts = dict(expert_width=768, num_experts=64, top_k=6, held=(0, 8))
+    layers = [SmallThinkerDecoderLayer(
+        2560, dict(num_heads=28, num_kv_heads=4, head_dim=128, window=window,
+                   rotary=window is not None), experts)
+        for window in (None, 4096)]
+    states = [{k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
+               for k, v in layer.state_dict().items()} for layer in layers]
+
+    def loss(states, x):
+        h = Tensor(x)
+        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes():
+            for i, (layer, state) in enumerate(zip(layers, states)):
+                with layer.load_functional_state(state), \
+                        jax.named_scope(str(i)):
+                    h = recompute(layer, h)
+        return (h.data.astype(F32) ** 2).mean()
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            states, jax.ShapeDtypeStruct((1, 16384, 2560), BF16,
+                                         sharding=one_chip)
+        ).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert not re.search(r"\[(\d+,)*16384,16384\]", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted((re.sub(r"\.\d+$", "", n), costmodel.region_of(s),
+                      "global" if "/self_attn/global/" in s else
+                      "window" if "/self_attn/window/" in s else None,
+                      "rematted_computation" in s)
+                     for n, s in scopes.items()
+                     if n.startswith("p1t_flash_attention"))
+    assert kernels == [
+        ("p1t_flash_attention_bwd_dkv", "backward", "global", False),
+        ("p1t_flash_attention_bwd_dkv", "backward", "window", False),
+        ("p1t_flash_attention_fwd", "forward", "global", False),
+        ("p1t_flash_attention_fwd", "forward", "window", False)], kernels
+    # groups of 7: k and v go in, and dK and dV come out, 4 heads wide;
+    # what the instruction uses of VMEM is over the default 16 MiB (the
+    # call's own limit is what lets it compile: the cell's backward call,
+    # above) and, with what XLA adds round a kernel inside a larger
+    # program, under what one call may ask
+    from paddle1_tpu.ops.pallas import flash_attention_bwd as fb
+    narrow = "bf16[1,16384,512]"
+    for call in re.findall(r"^.*%p1t_flash_attention_bwd_dkv\S* = .*$", text,
+                           re.M):
+        assert call.split(" custom-call(")[0].count(narrow) == 2
+        assert 16 << 20 < _scoped_vmem(call, "used_") < fb._VMEM_CAP
+    # positions on the window block alone
+    rotary = {block for s in scopes.values()
+              if "/self_attn/rotary_embedding" in s
+              for block in re.findall(r"/([01])/", s)}
+    assert rotary == {"1"}
+    # every grouped product in a region under the expert layer's scope
+    products = [scopes[n] for n in scopes
+                if re.match(r"ragged-dot-none(\.\d+)?$", n)]
+    assert len(products) == 2 * 8 and all(
+        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
+        for w in products), products
+    # the router: a float32 product over the 64 experts, from the block's
+    # input (2560 wide, not the experts' rows)
+    router = [l for l in text.splitlines() if "/moe/moe_router/" in l
+              and re.search(r"= f32\[16384,64\]\S* (fusion|convolution|dot)\(",
+                            l)]
+    assert router
+    for op in ("moe_dispatch", "moe_combine", "moe_overflow"):
+        assert any(f"/mlp/moe/{op}" in s for s in scopes.values()), op
 
 
 def test_sum_picks_supported_admits_only_what_fits():

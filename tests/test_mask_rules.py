@@ -1,8 +1,10 @@
 """The table of needed (query block, key block) pairs that the blockwise
 attention kernels walk (ISSUE 39; ``ops/pallas/mask_rules.py::pair_table``)
 against what ``rule.tile`` says pair by pair; held steps (a backward call
-in key ranges) change nothing; the counter of a lowered call's grid steps.
-CPU; the kernels in interpreter mode."""
+in key ranges) change nothing; the counters of a lowered call's grid steps
+and visible pairs; the sliding window's rule (ISSUE 43) against its dense
+mask written out by hand, and on the kernels with query heads in groups
+of 7. CPU; the kernels in interpreter mode."""
 
 import os
 import sys
@@ -23,8 +25,10 @@ from paddle1_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 from paddle1_tpu.ops.pallas import flash_attention_bwd as fb  # noqa: E402
 from paddle1_tpu.ops.pallas.mask_rules import (CAUSAL, FIRST,  # noqa: E402
                                                HELD, LAST, NO_MASK,
-                                               BlockDiffusion, pair_table,
-                                               tile_counts)
+                                               BlockDiffusion,
+                                               SlidingWindow, dense_mask,
+                                               pair_table, tile_counts,
+                                               visible_pairs)
 
 # name -> (rule, queries, keys, resident block, fetched block)
 SMALL = {
@@ -33,6 +37,13 @@ SMALL = {
     "causal_fewer_queries": (CAUSAL, 256, 768, 128, 256),
     "no_mask": (NO_MASK, 512, 256, 128, 128),
     "one_block": (BlockDiffusion(256, 256), 512, 512, 128, 128),
+    # a window shorter than a block (the diagonal's tile is crossed by
+    # both edges), a block long, between one and two, and past every key
+    "window_64": (SlidingWindow(64), 512, 512, 128, 128),
+    "window_a_block": (SlidingWindow(128), 512, 512, 128, 256),
+    "window_200": (SlidingWindow(200), 512, 512, 128, 128),
+    "window_300_fewer_queries": (SlidingWindow(300), 256, 768, 128, 256),
+    "window_past_the_keys": (SlidingWindow(4096), 512, 512, 128, 128),
 }
 SMALL.update({
     f"block_diffusion_{block}_{'noisy' if first else 'clean'}_first":
@@ -45,6 +56,11 @@ CELLS = {
     "kanana2": (CAUSAL, 8192, 8192, 72),
     "lfm2": (CAUSAL, 16384, 16384, 272),
     "ouro": (CAUSAL, 4096, 4096, 20),
+    # a window layer of SmallThinker's (its global layer walks lfm2's
+    # 272): a query block of 512 sees 4,096 keys in 5 blocks of 1,024
+    # wherever it stands from the ninth on, the first eight 1, 1, 2, 2, 3,
+    # 3, 4, 4
+    "smallthinker": (SlidingWindow(4096), 16384, 16384, 24 * 5 + 20),
 }
 CASES = dict(SMALL, **{name: case[:3] + (512, 1024)
                        for name, case in CELLS.items()})
@@ -101,7 +117,7 @@ def test_a_cells_head_walks_the_steps_the_issue_counted(cell):
     most = int(np.bincount(pair_table(rule, nq, nk, 512, 1024).q).max())
     idle = (nq // 512) * most - steps
     assert idle == {"sdar": 128, "kanana2": 56, "lfm2": 240,
-                    "ouro": 12}[cell]
+                    "ouro": 12, "smallthinker": 20}[cell]
 
 
 RANGED = {
@@ -112,6 +128,8 @@ RANGED = {
     "block_diffusion": (BlockDiffusion(256, 4), 512, 512, 128, 128, 2),
     "block_diffusion_clean_first": (BlockDiffusion(256, 4, False), 512, 512,
                                     128, 128, 4),
+    # every query block but the first two needs no key of the first range
+    "window": (SlidingWindow(200), 512, 512, 128, 128, 2),
 }
 
 
@@ -153,6 +171,7 @@ HELD_CASES = {
     "block_diffusion": (BlockDiffusion(256, 4), 512, 2, 2, 128, 128),
     "grouped_heads": (CAUSAL, 512, 8, 2, 64, 64),
     "keys_192_values_128": (CAUSAL, 512, 2, 2, 192, 128),
+    "window": (SlidingWindow(200), 512, 2, 1, 128, 128),
 }
 
 
@@ -209,12 +228,22 @@ def _steps():
             for kind in ("working", "held")}
 
 
-@pytest.mark.parametrize("case", ["causal", "block_diffusion", "no_mask"])
+def _pairs():
+    """``flash_pairs_total{rule}`` of every rule that has counted."""
+    rules = process_group("rule")
+    return {name: rules.child(name).counter("flash_pairs_total").value
+            for name in rules.labels()}
+
+
+@pytest.mark.parametrize("case", ["causal", "block_diffusion", "no_mask",
+                                  "window"])
 def test_a_lowered_call_counts_its_grid_steps(case, _fresh_obs):
     """``flash_grid_steps_total{kind}``: a lowered forward and backward
-    call each walk the table once a batch x head; nothing is held."""
+    call each walk the table once a batch x head; nothing is held.
+    ``flash_pairs_total{rule}``: each counts the pairs its rule lets
+    through a batch x head, under the rule's name and no other."""
     rule = {"causal": CAUSAL, "block_diffusion": BlockDiffusion(256, 4),
-            "no_mask": NO_MASK}[case]
+            "no_mask": NO_MASK, "window": SlidingWindow(200)}[case]
     q, k = (jax.ShapeDtypeStruct((2, 512, heads, 64), jnp.float32)
             for heads in (4, 2))
     jax.jit(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
@@ -222,6 +251,11 @@ def test_a_lowered_call_counts_its_grid_steps(case, _fresh_obs):
         argnums=(0, 1, 2))).lower(q, k, k)
     pairs = pair_table(rule, 512, 512, 128, 256).steps
     assert _steps() == {"working": 2 * pairs * 2 * 4, "held": 0}
+    seen = {"causal": 512 * 513 // 2, "block_diffusion": 256 * 260,
+            "no_mask": 512 * 512,
+            "window": 200 * 201 // 2 + 312 * 200}[case]
+    assert _pairs() == {{"no_mask": "none"}.get(case, case):
+                        2 * seen * 2 * 4}
 
 
 def test_a_call_in_key_ranges_counts_its_held_steps(_fresh_obs):
@@ -242,7 +276,8 @@ def test_at_the_cells_sizes_no_step_is_held(_fresh_obs):
     ``held`` reads 0 and ``working`` the table's steps x batch x heads,
     where the parent's rectangle held 4,096 / 3,584 / 7,680 / 384 a call."""
     shapes = {"sdar": (1, 32, 4, 128, 128), "kanana2": (2, 32, 32, 192, 128),
-              "lfm2": (1, 32, 8, 64, 64), "ouro": (2, 16, 16, 128, 128)}
+              "lfm2": (1, 32, 8, 64, 64), "ouro": (2, 16, 16, 128, 128),
+              "smallthinker": (1, 28, 4, 128, 128)}
     parents_idle = {}
     for cell, (b, h, h_kv, d, dv) in shapes.items():
         obs.reset_process_registry()
@@ -253,7 +288,131 @@ def test_at_the_cells_sizes_no_step_is_held(_fresh_obs):
             q, k, v, mask=rule).astype(jnp.float32)),
             argnums=(0, 1, 2)))(q, k, v)
         assert _steps() == {"working": 2 * steps * b * h, "held": 0}, cell
+        assert _pairs() == {rule.name: 2 * rule.pairs(s, s) * b * h}, cell
         most = int(np.bincount(pair_table(rule, s, s, 512, 1024).q).max())
         parents_idle[cell] = (s // 512 * most - steps) * b * h
     assert parents_idle == {"sdar": 4096, "kanana2": 3584, "lfm2": 7680,
-                            "ouro": 384}
+                            "ouro": 384, "smallthinker": 560}
+
+
+# -- the sliding window's rule ------------------------------------------------
+
+def _band_by_hand(nq, nk, window):
+    """Query ``r`` sees key ``c`` iff ``c`` is up to ``r``'s place among
+    the keys (bottom-right aligned) and fewer than ``window`` behind it."""
+    return np.array([[0 <= r + nk - nq - c < window for c in range(nk)]
+                     for r in range(nq)])
+
+
+# window, queries, keys, query block, key block: shorter than, as long as
+# and longer than a block, more keys than queries, blocks of two sizes
+WINDOWS = [(1, 64, 64, 16, 16), (5, 64, 64, 16, 16), (16, 64, 64, 16, 16),
+           (17, 64, 64, 16, 32), (40, 64, 96, 16, 16), (64, 64, 64, 32, 16),
+           (1000, 64, 128, 16, 16)]
+
+
+@pytest.mark.parametrize("window,nq,nk,bq,bk", WINDOWS)
+def test_the_window_rule_is_its_dense_band(window, nq, nk, bq, bk):
+    """``dense``, ``pairs``, ``tile`` and ``keep`` of every tile against
+    the band written out position by position; ``sizes`` and
+    ``lengths_ok`` as the causal rule's."""
+    rule = SlidingWindow(window)
+    band = _band_by_hand(nq, nk, window)
+    np.testing.assert_array_equal(dense_mask(rule, nq, nk), band)
+    assert visible_pairs(rule, nq, nk) == band.sum()
+    assert rule.sizes(nq, nk) == (nq, nk)
+    assert rule.lengths_ok(nq, nk) and not rule.lengths_ok(nk + 16, nk)
+    off, crossed_twice = nk - nq, 0
+    by_hand = {"plain": 0, "masked": 0, "skipped": 0}
+    for q0 in range(0, nq, bq):
+        for k0 in range(0, nk, bk):
+            tile = band[q0:q0 + bq, k0:k0 + bk]
+            needed, full = rule.tile(np.int32(q0), bq, np.int32(k0), bk, off)
+            assert (bool(needed), bool(full)) == (tile.any(), tile.all())
+            by_hand["plain" if tile.all() else
+                    "masked" if tile.any() else "skipped"] += 1
+            for q_axis in (0, 1):       # the forward's tile, the backward's
+                shape = (bq, bk) if q_axis == 0 else (bk, bq)
+                keep = np.asarray(rule.keep(shape, q0, k0, off, q_axis))
+                np.testing.assert_array_equal(
+                    keep, tile if q_axis == 0 else tile.T)
+            # the diagonal and the band's far edge through one tile
+            crossed_twice += bool(tile[-1, 0] == 0 and tile[0, -1] == 0
+                                  and tile.any())
+    # a tile holds bq + bk - 1 distances: both edges fit in one whose
+    # window is shorter than a block, in none where it is longer than that
+    if window < min(bq, bk):
+        assert crossed_twice > 0
+    if window >= bq + bk - 1:
+        assert crossed_twice == 0
+    assert tile_counts(rule, nq, nk, bq, bk) == by_hand
+
+
+def test_a_window_past_every_key_is_the_causal_rule():
+    wide, nq, nk = SlidingWindow(768), 256, 768
+    np.testing.assert_array_equal(wide.dense(nq, nk), CAUSAL.dense(nq, nk))
+    assert wide.pairs(nq, nk) == CAUSAL.pairs(nq, nk)
+    a, b = (pair_table(r, nq, nk, 128, 256) for r in (wide, CAUSAL))
+    for x, y in zip(a[:3], b[:3]):
+        np.testing.assert_array_equal(x, y)
+    assert tile_counts(wide, nq, nk, 128, 128) \
+        == tile_counts(CAUSAL, nq, nk, 128, 128)
+    with pytest.raises(ValueError):
+        SlidingWindow(0)
+    with pytest.raises(ValueError):         # one rule a call
+        fa.rule_of(True, SlidingWindow(8))
+
+
+def test_the_cells_band_in_closed_form():
+    """At SmallThinker's shape the table lists the band alone: 44% of a
+    causal call's pairs in 51% of its steps, none held."""
+    rule, s = SlidingWindow(4096), 16384
+    assert rule.pairs(s, s) == 4096 * 4097 // 2 + 12288 * 4096 == 58722304
+    assert CAUSAL.pairs(s, s) == 134225920
+    table = pair_table(rule, s, s, 512, 1024)
+    assert (table.steps, table.held) == (140, 0)
+    # a query block's key blocks: those its first query's window reaches
+    # back to, up to its last query's own
+    for i in range(s // 512):
+        mine = table.k[table.q == i]
+        assert mine.tolist() == list(range(
+            max(0, (512 * i - 4095) // 1024), (512 * i + 511) // 1024 + 1))
+    counts = tile_counts(rule, s, s, 512, 512)
+    # a query block from the ninth on: 7 whole tiles between 2 crossed
+    assert counts == {"plain": 7 * 24 + sum(range(8)),
+                      "masked": 2 * 24 + 8,
+                      "skipped": 1024 - 9 * 24 - sum(range(1, 9))}
+
+
+# heads, key/value heads: SmallThinker's 28 / 4 and one group of 7
+@pytest.mark.parametrize("heads,kv_heads", [(28, 4), (7, 1)])
+def test_the_kernels_under_the_window_with_heads_in_groups_of_seven(
+        heads, kv_heads):
+    """Forward and backward kernels (interpreter mode) under a window that
+    is no multiple of a block, against ``attention_ref`` with the key and
+    value heads repeated: out and all three gradients; dK and dV sum a
+    group's 7 query heads."""
+    rule, s, d = SlidingWindow(200), 512, 128
+    keys = jax.random.split(jax.random.key(43), 4)
+    q, k, v, dout = (jax.random.normal(kk, (1, s, h, d), jnp.float32)
+                     for kk, h in zip(keys, (heads, kv_heads, kv_heads,
+                                             heads)))
+    assert fa.supported(q.shape, k.shape, v_shape=v.shape, mask=rule)
+    out, pull = jax.vjp(lambda q, k, v: fa.flash_attention(
+        q, k, v, mask=rule, blocks=(128, 256, 128)), q, k, v)
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (k, v))
+        return attention_ref(q, k, v, mask_rule=rule)
+    want, want_pull = jax.vjp(dense, q, k, v)
+    # a dense band of its own beside the rule's: head 0 by hand
+    band = jnp.asarray(_band_by_hand(s, s, 200))
+    scores = jnp.einsum("qd,kd->qk", q[0, :, 0], k[0, :, 0]) / d ** 0.5
+    by_hand = jax.nn.softmax(jnp.where(band, scores, -jnp.inf), -1) \
+        @ v[0, :, 0]
+    np.testing.assert_allclose(out[0, :, 0], by_hand, rtol=2e-5, atol=2e-5)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), (out,) + pull(dout),
+                          (want,) + want_pull(dout)):
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5 * float(
+            jnp.max(jnp.abs(w))), err_msg=name)
