@@ -52,13 +52,18 @@ main path holds them all. No data movement is a scatter, forward or
 backward: the transpose of "rows in sorted order" (a gather of
 ``capacity`` rows from the tokens) is "sum a token's picks", and that
 reads the rows that hold a pick and no other: ``capacity`` rows of the
-hidden width relaid as whole tiles, each held row fetched once into a
-zeroed ``[top_k, block, hidden]`` buffer in VMEM, ``tokens`` rows written
-(``ops/pallas/sum_picks.py``; the pick weights' vector and rows that are
-no whole tiles take a gather with an index for every pick). A gather
+hidden width relaid so that a row is whole tiles, each held row fetched
+once into a zeroed ``[top_k, block, hidden]`` buffer in VMEM, ``tokens``
+rows written (``ops/pallas/sum_picks.py``: any hidden width of a whole
+number of 32-bit lane rows, a multiple of 256 two-byte or 128 four-byte
+elements; whole ``(8, 128)`` tiles as ``[hidden / 128, 128]``, any other
+as ``[depth, hidden / depth]``, PR 44; the pick weights' vector and rows
+of another width take a gather with an index for every pick). A gather
 over every pick paid 36.7 ns for each of the 98,304 rows it fetched from
 HBM at Kanana-2's size, 87% of them a row of zeros (PERF.md section 6,
-PR 32).
+PR 32), and 46.8 ns a row at SmallThinker's 2,560 (PR 43). Which arm a
+traced sum over rows took is counted in the process registry:
+``p1t_moe_sum_picks_arm_total{arm="kernel"|"gather"}``.
 
 **The load is counted where it falls** (PR 36): every forward adds to
 the buffer ``expert_load`` (int32 ``[held + 4]``, non-persistable: state
@@ -109,6 +114,7 @@ from ..core.flags import in_auto_partitioned_region
 from ..core.recompute_keeps import keep_in_recompute
 from ..core.tensor import Tensor
 from ..obs.costmodel import SCOPE_ATTRIBUTE
+from ..obs.registry import process_group
 from ..ops.pallas import sum_picks as sum_picks_kernel
 from .functional.norm import record_state_update
 from .initializer import Constant
@@ -247,15 +253,23 @@ def _sum_picks(o, where, fan):
     nothing, where ``where`` says ``capacity``), summed over the ``fan``
     picks of a row in float32 and cast once.
 
-    Rows of whole tiles go through ``ops/pallas/sum_picks.py``, which
-    reads the rows that hold a pick and no other (its docstring: a gather
-    pays for every pick, held or not). A vector (the pick weights'
-    gradient), rows of another width and a step that XLA partitions by
-    itself take the gather: indexed ``[fan, picks / fan]`` so that the
-    sum runs over the leading axis, an index past the rows filled with
-    zeros."""
-    if sum_picks_kernel.supported(o, where, fan) \
-            and not in_auto_partitioned_region():
+    Rows of a whole number of 32-bit lane rows (hidden a multiple of
+    256 two-byte or 128 four-byte elements) go through
+    ``ops/pallas/sum_picks.py``, which reads the rows that hold a pick
+    and no other (its docstring: a gather pays for every pick, held or
+    not; ``_row_shape`` there has the layout a width travels in). A
+    vector (the pick weights' gradient), rows of another width and a
+    step that XLA partitions by itself take the gather: indexed ``[fan,
+    picks / fan]`` so that the sum runs over the leading axis, an index
+    past the rows filled with zeros. Which arm a traced call on rows
+    took is counted: ``p1t_moe_sum_picks_arm_total{arm}``, ``kernel`` or
+    ``gather``."""
+    kernel = (sum_picks_kernel.supported(o, where, fan)
+              and not in_auto_partitioned_region())
+    if o.ndim == 2:
+        process_group("arm").child("kernel" if kernel else "gather") \
+            .counter("moe_sum_picks_arm_total").inc()
+    if kernel:
         return sum_picks_kernel.sum_picks(o, where, fan)
     picked = jnp.take(o, where.reshape(-1, fan).T, axis=0, mode="fill",
                       fill_value=0)

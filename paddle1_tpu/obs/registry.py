@@ -508,7 +508,10 @@ def process_group(label_key) -> MetricsGroup:
     total{kind}`` (counted when a kernel call is traced: its score tiles
     plain, masked and skipped; its grid's steps working and held),
     ``flash_pairs_total{rule}`` (the pairs its mask rule lets through, by
-    the rule's name),
+    the rule's name), ``moe_sum_picks_arm_total{arm}`` (counted when the
+    expert layer's sum of a token's picks is traced on rows: ``kernel``
+    where ``ops/pallas/sum_picks.py`` takes the rows' width, ``gather``
+    where an index for every pick does; ``nn/layer_moe.py::_sum_picks``),
     ``recompute_kept_bytes_total{name}`` and ``recompute_
     kept_values_total{name}`` (what each traced ``fleet.utils.recompute``
     segment was given to keep, by the shapes of the values named inside:

@@ -232,6 +232,17 @@ CASES = {
         lambda: _sum_picks(36864, 2048, 16384, 6),
     "sum_picks_f32_4096x1024_t2048_top8":
         lambda: _sum_picks(4096, 1024, 2048, 8, F32),
+    # smallthinker's: rows of 10 lane rows of 32 bits, [4, 640] a row
+    "sum_picks_smallthinker_36864x2560_t16384_top6":
+        lambda: _sum_picks(36864, 2560, 16384, 6),
+    # chip_smoke.py's expert phase: two lane rows, [4, 128] a row
+    "sum_picks_chip_smoke_9216x512_t4096_top6":
+        lambda: _sum_picks(9216, 512, 4096, 6),
+    # the shallowest rows: one lane row of 32 bits, bf16 and float32
+    "sum_picks_4096x256_t2048_top6":
+        lambda: _sum_picks(4096, 256, 2048, 6),
+    "sum_picks_f32_4096x640_t2048_top6":
+        lambda: _sum_picks(4096, 640, 2048, 6, F32),
     # the most picks supported() admits: SMEM's worst case
     "sum_picks_8192x2048_t32768_top6":
         lambda: _sum_picks(8192, 2048, 32768, 6),
@@ -704,9 +715,13 @@ def test_sum_picks_supported_admits_only_what_fits():
                                    fan)
     assert ok(36864, 2048, 16384, 6) and ok(4096, 1024, 2048, 8, F32)
     assert ok(8192, 2048, 32768, 6)
+    # any whole number of 32-bit lane rows (ISSUE 44): SmallThinker's 10,
+    # bf16 rows of half a packed tile, one lane row
+    assert ok(36864, 2560, 16384, 6) and ok(36864, 1024, 16384, 6)
+    assert ok(4096, 256, 2048, 6) and ok(4096, 128, 2048, 6, F32)
     assert not ok(8192, 2048, 32768, 8)     # 1 MiB of picks: SMEM's whole
-    assert not ok(36864, 1024, 16384, 6)    # bf16 rows of half a tile
-    assert not ok(36864, 2048 + 128, 16384, 6)
+    assert not ok(36864, 2048 + 128, 16384, 6)  # half a lane row over
+    assert not ok(36864, 128, 16384, 6) and not ok(4096, 64, 2048, 6, F32)
     assert not ok(1 << 20, 2048, 16384, 6)  # a row past its 20 bits
     assert not ok(4096, 2048, 2048, 9)      # a slot past its 3 bits
     assert not ok(4096, 2048, 2044, 6)      # tokens in no block of eight
@@ -811,6 +826,61 @@ def test_the_expert_layer_gathers_no_row_for_a_pick_it_does_not_hold(
              "rematted_computation" in k) for k in kernels] == [
         ("forward", "moe_combine", False),
         ("backward", "moe_dispatch", False)], kernels
+    products = sorted(scopes[n] for n in scopes
+                      if re.match(r"ragged-dot-none(\.\d+)?$", n))
+    assert len(products) == 8 and all(
+        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
+        for w in products), products
+
+
+def test_smallthinkers_expert_layer_gathers_no_row_it_does_not_hold(
+        one_chip, for_the_chip, monkeypatch):
+    """SmallThinker's expert layer at the cell's shape ([1, 16384, 2560]
+    bf16, 8 of 64 ReLU-gated experts, top-6: 98,304 picks over 36,864
+    rows of 10 lane rows of 32 bits, a packed tile and a quarter) under
+    ``fleet.utils.recompute``, loss and gradients (ISSUE 44): the twin of
+    Kanana-2's case above at a width that is no whole tile. The kernel
+    once forward under ``moe_combine`` and once backward under
+    ``moe_dispatch``, its rows ``[4, 640]`` deep as their lane rows
+    allow, and nothing of what the gather over every pick made."""
+    from paddle1_tpu import nn
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    from paddle1_tpu.obs import costmodel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = nn.RoutedExperts(2560, 768, 64, 6, held=(0, 8),
+                             scoring="softmax", gate_activation="relu")
+    state = {k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
+             for k, v in layer.state_dict().items()}
+
+    def loss(state, x):
+        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes(), \
+                layer.load_functional_state(state):
+            out = recompute(layer, Tensor(x))
+        return (out.data.astype(F32) ** 2).mean()
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            state, jax.ShapeDtypeStruct((1, 16384, 2560), BF16,
+                                        sharding=one_chip)
+        ).compile().as_text()
+    for gone in (r"\[36865,2560\]", r"\[98304,2560\]", r"\[98304,4,640\]",
+                 r"\[6,16384,2560\]", r"\[6,16384,4,640\]",
+                 r"\[16384,6,2560\]", r"\[16384,6,4,640\]"):
+        assert not re.search(gone, text), gone
+    assert not re.search(r"\bwhile\(", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted(scopes[n] for n in scopes
+                     if re.match(r"p1t_sum_picks_fwd(\.\d+)?$", n))
+    assert [(costmodel.region_of(k), k.split("/moe/")[1].split("/")[0],
+             "rematted_computation" in k) for k in kernels] == [
+        ("forward", "moe_combine", False),
+        ("backward", "moe_dispatch", False)], kernels
+    for call in re.findall(r"^.*%p1t_sum_picks_fwd\S* = .*$", text, re.M):
+        assert call.split(" custom-call(")[0].count(
+            "bf16[16384,4,640]{2,1,0:T(4,128)(2,1)") == 1, call
+        assert "bf16[36864,4,640]" in call
     products = sorted(scopes[n] for n in scopes
                       if re.match(r"ragged-dot-none(\.\d+)?$", n))
     assert len(products) == 8 and all(

@@ -5,7 +5,9 @@ against the plain reference (``benchmarks/reference/kanana2_30b_a3b.py``);
 recomputation, the selection bias through a step, the names a traced step
 carries. CPU, tiny sizes, seeded weights."""
 
+import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -21,7 +23,8 @@ import paddle1_tpu as paddle  # noqa: E402
 from benchmarks.reference import kanana2_30b_a3b as ref  # noqa: E402
 from benchmarks.reference.numerics import Numerics  # noqa: E402
 from paddle1_tpu import nn, obs  # noqa: E402
-from paddle1_tpu.core.flags import flags_guard  # noqa: E402
+from paddle1_tpu.core.flags import (auto_partitioned_region,  # noqa: E402
+                                    flags_guard)
 from paddle1_tpu.core.tensor import Tensor  # noqa: E402
 from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
 from paddle1_tpu.framework.param_attr import ParamAttr  # noqa: E402
@@ -409,9 +412,13 @@ def _f32(x):
 HELD_PICKS = {"none": (8, 16), "some": (0, 16), "all": (0, 8)}
 # (fan, the operand's trailing shape): the rows of a hidden width that
 # `moe_dispatch` and `moe_combine` move, through the gather (40 wide) and
-# through the kernel (2048 wide: whole tiles in either dtype, interpret
-# mode here), the pick weights' vector, and rows a pick each
+# through the kernel (interpret mode here; 2048 wide: whole tiles in
+# either dtype; 2560 and 1280 wide, SmallThinker's and half of it: 10 and
+# 5 or 20 and 10 lane rows of 32 bits, which travel 4, 2 or 1 deep), the
+# pick weights' vector, and rows a pick each
 OPERANDS = {"fan6-rows": (6, (40,)), "fan6-rows_of_tiles": (6, (2048,)),
+            "fan6-rows_of_2560": (6, (2560,)),
+            "fan6-rows_of_1280": (6, (1280,)),
             "fan1-vector": (1, ()), "fan1-rows": (1, (40,))}
 
 
@@ -450,7 +457,9 @@ def test_the_two_gathers_are_one_hot_products_and_transposes(
 
     a = jnp.asarray(rng.standard_normal((n,) + trailing), dtype)
     o = jnp.asarray(rng.standard_normal((rows,) + trailing), dtype)
-    assert sum_picks.supported(o, where, fan) == (trailing == (2048,))
+    # a whole number of 32-bit lane rows (512 bytes), of rows alone
+    assert sum_picks.supported(o, where, fan) == (
+        len(trailing) == 1 and trailing[0] * o.dtype.itemsize % 512 == 0)
 
     def product(m, v):              # float32, then the operand's rounding
         return (m @ _f32(v).reshape(v.shape[0], -1)).reshape(
@@ -471,7 +480,9 @@ def test_the_two_gathers_are_one_hot_products_and_transposes(
     close(sum_vjp(a)[0], product(R, a))
     # to the last bit, values and the dispatch's backward alike
     before = _sum_picks_before_issue_32(o, where, fan)
-    for got in (got_sum, rows_vjp(o)[0],
+    with auto_partitioned_region():             # the gather, whatever the width
+        gathered = layer_moe._sum_picks(o, where, fan)
+    for got in (got_sum, rows_vjp(o)[0], gathered,
                 jax.jit(layer_moe.sum_of_picks, static_argnums=3)(
                     o, order, where, fan)):
         np.testing.assert_array_equal(_f32(got), _f32(before))
@@ -509,7 +520,6 @@ def test_a_step_partitioned_by_xla_takes_the_gather():
     """Inside ``auto_partitioned_region`` (GSPMD refuses a Mosaic
     kernel) rows of whole tiles go through the gather, to the same bits."""
     import contextlib
-    from paddle1_tpu.core.flags import auto_partitioned_region
     where = jnp.asarray([0, 2, 2, 1, 2, 2, 0, 2], jnp.int32)   # 2: no row
     o = jnp.asarray(np.random.default_rng(0).standard_normal((2, 1024)),
                     jnp.float32)
@@ -527,6 +537,94 @@ def test_a_step_partitioned_by_xla_takes_the_gather():
     np.testing.assert_array_equal(
         gathered, np.where((np.asarray(where) < 2)[:, None],
                            np.asarray(o)[np.minimum(where, 1)], 0))
+
+
+# sha256 of the jaxpr (kernel body, index map, grid and the picks' sort
+# included) of one call on bf16 rows of whole tiles at Kanana-2's and
+# SDAR's shapes and on float32 rows at a test's, under jax 0.9.0, as
+# ISSUE 44's parent (5d4e33b) lowered them: the widths the kernel took
+# before it took any whole number of 32-bit lane rows keep their layout,
+# their block and their text. Whoever changes the kernel on purpose
+# re-takes them.
+SUM_PICKS_JAXPRS = {
+    (36864, 2048, 16384, 6, "bfloat16"):
+        "0cbd06ad64d19074e70b70454a3a5e2fa8c8ea265713558f50a8e5b45d15c2ea",
+    (49152, 2048, 16384, 8, "bfloat16"):
+        "40f8ef7f7871f401c9f022a1f53597d8f8b20a142982d4b6709c81542f9162e5",
+    (4096, 1024, 2048, 8, "float32"):
+        "3474eed7167dd46596b66a5f277550fdd8150947df497aeb5e0b76c30cfd347e",
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SUM_PICKS_JAXPRS))
+def test_rows_of_whole_tiles_lower_to_the_text_they_had(shape):
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests were taken under jax 0.9.0")
+    rows, hidden, tokens, fan, dtype = shape
+    jaxpr = jax.make_jaxpr(lambda o, w: sum_picks.sum_picks(o, w, fan))(
+        jax.ShapeDtypeStruct((rows, hidden), jnp.dtype(dtype)),
+        jax.ShapeDtypeStruct((tokens * fan,), jnp.int32))
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jaxpr))
+    assert text.count("pallas_call") == 1
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == SUM_PICKS_JAXPRS[shape]
+
+
+@pytest.mark.parametrize("hidden,dtype,row", [
+    (2048, "bfloat16", (16, 128)), (4096, "bfloat16", (32, 128)),
+    (1024, "float32", (8, 128)),                # whole tiles, as before
+    (2560, "bfloat16", (4, 640)), (1024, "bfloat16", (8, 128)),
+    (3072, "bfloat16", (8, 384)), (256, "bfloat16", (2, 128)),
+    (1280, "float32", (2, 640)), (640, "float32", (1, 640)),
+    (2048 + 128, "bfloat16", ()), (64, "float32", ())])
+def test_a_row_travels_as_deep_as_its_lane_rows_allow(hidden, dtype, row):
+    """The one rule of the layout, off the width and the dtype alone: a
+    row's tiles are its own and contiguous, so the operand, the buffer
+    and the output block of a call carry that shape behind their
+    leading dimensions."""
+    assert sum_picks._row_shape(hidden, jnp.dtype(dtype).itemsize) == row
+    o = jax.ShapeDtypeStruct((64, hidden), jnp.dtype(dtype))
+    where = jax.ShapeDtypeStruct((16 * 6,), jnp.int32)
+    assert sum_picks.supported(o, where, 6) == bool(row)
+    if row:
+        text = str(jax.make_jaxpr(
+            lambda o, w: sum_picks.sum_picks(o, w, 6))(o, where))
+        dims = ",".join(map(str, row))
+        short = {"bfloat16": "bf16", "float32": "f32"}[dtype]
+        assert f"{short}[64,{dims}]" in text       # the rows, relaid
+        assert f"{short}[2,6,16,{dims}]" in text   # the buffer's two halves
+        assert f"{short}[16,{dims}]" in text       # a block of the output
+
+
+@pytest.mark.parametrize("hidden,arm", [(2560, "kernel"),
+                                        (2048 + 128, "gather")])
+def test_a_traced_layer_counts_the_arm_its_sums_took(_fresh_obs, hidden,
+                                                     arm):
+    """``p1t_moe_sum_picks_arm_total{arm}``: one increment a traced sum
+    over rows, two a layer's forward + backward (``moe_combine``, and the
+    transpose of ``moe_dispatch``'s gather); the pick weights' vector,
+    whose gradient is such a sum too, is counted under neither arm."""
+    layer, x = _experts(16, 8, 3, (0, 4), hidden=hidden)
+    state = {k: v.data.astype(jnp.bfloat16)
+             for k, v in layer.state_dict().items()}
+
+    def loss(state, x):
+        from paddle1_tpu.autograd import engine as ae
+        with ae.no_grad(), layer.load_functional_state(state):
+            return (layer(Tensor(x)).data ** 2).mean()
+    jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        state, jnp.asarray(x, jnp.bfloat16))
+    arms = obs.process_group("arm")
+    other = {"kernel": "gather", "gather": "kernel"}[arm]
+    assert arms.child(arm).counter("moe_sum_picks_arm_total").value == 2
+    assert arms.child(other).counter("moe_sum_picks_arm_total").value == 0
+    assert f'p1t_moe_sum_picks_arm_total{{arm="{arm}"}} 2' \
+        in obs.registry.render_process_groups()
+    # the weights' vector alone: neither
+    obs.reset_process_registry()
+    layer_moe._sum_picks(jnp.ones((24,)), jnp.arange(24, dtype=jnp.int32), 1)
+    assert "moe_sum_picks_arm_total" not in \
+        obs.registry.render_process_groups()
 
 
 # -- the model --------------------------------------------------------------

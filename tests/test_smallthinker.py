@@ -533,6 +533,13 @@ def test_a_step_trains_and_carries_the_scopes_and_the_counters(_fresh_obs):
         arms = process_group("arm")
         assert arms.child("flash").counter("attention_arm_total").value >= 1
         assert arms.child("dense").counter("attention_arm_total").value == 0
+        # the sums of a token's picks, on rows half a 32-bit lane row wide
+        # (128 bf16): 3 a layer, the forward's, the recomputed segment's
+        # (jax traces it again for the backward pass) and the transpose
+        # of the dispatch's gather
+        sums = {arm: arms.child(arm).counter("moe_sum_picks_arm_total").value
+                for arm in ("kernel", "gather")}
+        assert sums == {"kernel": 0, "gather": 3 * len(ref.layer_kinds(CFG))}
         scopes = costmodel.step_op_scopes()
         text = engine.compiled_step_text()
     assert losses[2] < losses[0]
