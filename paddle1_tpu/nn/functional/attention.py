@@ -9,10 +9,12 @@ and to the plain XLA composition otherwise.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...autograd.engine import apply
 from ...core.tensor import Tensor, to_tensor
@@ -64,6 +66,64 @@ def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
     return jnp.swapaxes(out, 1, 2)
 
 
+def _turn(x, at, theta, interleaved, back):
+    """``x * C + (x @ P) * S`` over the last axis, or with ``back`` the
+    same pass with ``S`` negated, which is its transpose."""
+    batch, seq, heads, d = x.shape
+    half = d // 2
+    inv_freq = jnp.float32(theta) ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    angle = at.astype(jnp.float32)[..., None, None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)   # [(batch,) seq, 1, half]
+    if back:
+        sin = -sin
+    lane = jnp.arange(d)
+    if interleaved:
+        cos_d = jnp.repeat(cos, 2, axis=-1)
+        sin_d = jnp.stack([-sin, sin], axis=-1).reshape(cos_d.shape)
+        source = lane ^ 1
+    else:
+        cos_d = jnp.concatenate([cos, cos], axis=-1)
+        sin_d = jnp.concatenate([-sin, sin], axis=-1)
+        source = (lane + half) % d
+    swap = (lane[:, None] == source[None, :]).astype(x.dtype)
+    # ones and zeros: exact in bf16 at the MXU's one bf16 precision, which
+    # is named so that no process-wide default can ask for another; a
+    # float32 x would be rounded to bf16 by a TPU's default
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    # every position a row of [heads, d], the batch folded into the
+    # sequence: with a batch of one as an axis of its own XLA's layout
+    # assignment on the v5e splits the pass round a float32 copy of q
+    # (PERF.md section 6, PR 45: the forms tried, a cell each)
+    rows = x.reshape(batch * seq, heads, d)
+    cos_d, sin_d = (jnp.broadcast_to(t, (batch, seq, 1, d)).reshape(
+        batch * seq, 1, d) for t in (cos_d, sin_d))
+    partner = jax.lax.dot_general(
+        rows, swap, (((2,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+    return (rows.astype(jnp.float32) * cos_d
+            + partner * sin_d).astype(x.dtype).reshape(x.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rotary(x, at, theta, interleaved):
+    return _turn(x, at, theta, interleaved, back=False)
+
+
+def _rotary_fwd(x, at, theta, interleaved):
+    return _turn(x, at, theta, interleaved, back=False), at
+
+
+def _rotary_bwd(theta, interleaved, at, g):
+    dat = (jnp.zeros_like(at) if jnp.issubdtype(at.dtype, jnp.floating)
+           else np.zeros(at.shape, jax.dtypes.float0))
+    return _turn(g, at, theta, interleaved, back=True), dat
+
+
+_rotary.defvjp(_rotary_fwd, _rotary_bwd)
+
+
 def rotary_embedding(x, theta=10000.0, positions=None, interleaved=False,
                      name=None):
     """Rotary positions (Su et al. 2021, arXiv:2104.09864) on a
@@ -72,28 +132,30 @@ def rotary_embedding(x, theta=10000.0, positions=None, interleaved=False,
     channel ``i`` of the first half and channel ``i`` of the second,
     ``interleaved`` (the paper's own, ``rope_interleave`` of the
     DeepSeek-V3 family) of channels ``2i`` and ``2i + 1``. ``positions``:
-    [seq] or [batch, seq] integers, ``0..seq-1`` when None. The angles
-    and the rotation are float32 whatever ``x`` is; the result has
-    ``x``'s dtype."""
-    args = (_t(x),) + ((_t(positions),) if positions is not None else ())
+    [seq] or [batch, seq] integers, ``0..seq-1`` when None; no gradient
+    reaches them. The result has ``x``'s dtype.
 
-    def f(x, *pos):
-        half = x.shape[-1] // 2
-        at = (pos[0] if pos else jnp.arange(x.shape[1])).astype(jnp.float32)
-        inv_freq = jnp.float32(theta) ** (
-            -jnp.arange(half, dtype=jnp.float32) / half)
-        angle = at[..., None, None] * inv_freq       # [(batch,) seq, 1, half]
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
-        xf = x.astype(jnp.float32)
-        if interleaved:
-            pairs = xf.reshape(xf.shape[:-1] + (half, 2))
-            a, b = pairs[..., 0], pairs[..., 1]
-            return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                             axis=-1).reshape(x.shape).astype(x.dtype)
-        a, b = xf[..., :half], xf[..., half:]
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                               axis=-1).astype(x.dtype)
-    return apply("rotary_embedding", f, args)
+    One pass at the full head width: ``y = x * C + (x @ P) * S``, with
+    ``C`` the cosines laid under both channels of a pair, ``S`` the sines
+    with the minus sign on the pair's first channel, and ``P`` the 0 / 1
+    matrix that hands each channel its partner. A slice or a
+    concatenation at half the width is no elementwise op on a TPU (64 of
+    128 lanes) and left float32 halves of q and k in HBM; a product with
+    ``P`` fuses. Float32 whatever ``x`` is: the angles, ``C`` and ``S``,
+    ``x @ P`` (a sum of one value and zeros, so exact), both products
+    and their sum; the one rounding is the last cast. The backward is
+    written by hand: ``P`` is its own inverse and swaps ``S``'s signs,
+    so ``dx = g * C - (g @ P) * S`` is the same pass on ``g``, float32
+    the same way. Autodiff of the forward would send the float32
+    ``g * S`` through a bf16 product and round one term early. ``C`` and
+    ``S`` are made again from the positions, which are all it keeps."""
+    x = _t(x)
+    at = (_t(positions) if positions is not None
+          else jnp.arange(x.shape[1], dtype=jnp.int32))
+
+    def f(x, at):
+        return _rotary(x, at, theta, interleaved)
+    return apply("rotary_embedding", f, (x, at))
 
 
 # The shortest sequence at which the blockwise kernels ran a shorter

@@ -12,6 +12,7 @@ from __future__ import annotations
 import unittest
 from typing import Callable, Dict, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 import paddle1_tpu as paddle
@@ -86,3 +87,24 @@ class OpTest(unittest.TestCase):
             flat[i] = orig
             grad[i] = (f_hi - f_lo) / (2 * delta)
         return grad.reshape(base[idx].shape)
+
+
+def rotary_by_halves(x, theta, at, interleaved):
+    """The plain reference of ``F.rotary_embedding``: the op as it stood
+    until ISSUE 45, a slice at half the head width (or a view in pairs)
+    and a concatenation, in float32, differentiated by ``jax.grad``.
+    ``x`` [batch, seq, heads, dim], ``at`` [seq] or [batch, seq]."""
+    half = x.shape[-1] // 2
+    inv_freq = jnp.float32(theta) ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    angle = at.astype(jnp.float32)[..., None, None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        pairs = xf.reshape(xf.shape[:-1] + (half, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)

@@ -557,6 +557,50 @@ def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
     assert not [c for c in calls if "/rematted_computation/" in c]
 
 
+def _float32_arrays_of_q(text):
+    """Instructions outside fusion bodies whose result is float32 and
+    shaped like q at Ouro's size ([2, 4096, 16, 128]), like its
+    projection ([2, 4096, 2048]) or half a head wide: arrays in HBM."""
+    bodies = _computations(text)
+    fused = set(re.findall(r"\bcalls=%([\w.\-]+)", text))
+    return [line.strip()[:120]
+            for name, lines in bodies.items() if name not in fused
+            for line in lines
+            if re.search(r" = f32\[2,4096,(16,128|2048|\d+,64)\]\S* "
+                         r"(?!bitcast|get-tuple-element|parameter)\w", line)]
+
+
+def test_rotary_behind_a_projection_leaves_no_float32_array_of_q(
+        one_chip, for_the_chip):
+    """``rotary_embedding`` behind a bf16 projection at Ouro's shape,
+    loss and gradients (ISSUE 45): the pass at the full head width and
+    its hand-written backward fuse, and no float32 array of q's shape, of
+    the projection's or half a head wide is written. Sliced at half the
+    width and concatenated, as the op stood, the text holds such arrays
+    (two of 67 MB, and the halves): the census can see them."""
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.nn import functional as F
+    from op_test import rotary_by_halves
+
+    def compiled(rotary):
+        def loss(w, x):
+            q = rotary((x @ w).reshape(2, 4096, 16, 128))
+            return (q.astype(F32) ** 2).mean()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            jax.ShapeDtypeStruct((2048, 2048), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((2, 4096, 2048), BF16, sharding=one_chip)
+        ).compile().as_text()
+
+    def op(q):
+        with ae.no_grad():
+            return F.rotary_embedding(Tensor(q), 1e6).data
+    assert _float32_arrays_of_q(compiled(op)) == []
+    assert len(_float32_arrays_of_q(
+        compiled(lambda q: rotary_by_halves(
+            q, 1e6, jnp.arange(4096), False)))) >= 2
+
+
 def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
         one_chip, for_the_chip, monkeypatch):
     """One decoder block of SDAR's step at the cell's shape ([1, 2 x 8192,

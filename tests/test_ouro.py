@@ -19,6 +19,7 @@ if ROOT not in sys.path:
 
 import paddle1_tpu as paddle  # noqa: E402
 from benchmarks.reference import ouro_2p6b as ref  # noqa: E402
+from op_test import rotary_by_halves  # noqa: E402
 from benchmarks.reference.numerics import Numerics  # noqa: E402
 from paddle1_tpu import obs  # noqa: E402
 from paddle1_tpu.autograd.engine import no_grad  # noqa: E402
@@ -87,6 +88,45 @@ def test_rotary_embedding_keeps_the_dtype_and_differentiates():
     assert y.dtype == x.dtype
     y.sum().backward()
     assert x.grad.shape == x.shape
+
+
+@pytest.mark.parametrize("positions", [None, "row", "batch"])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("interleaved", [False, True],
+                         ids=["halves", "pairs"])
+def test_rotary_in_one_pass_is_the_slices_and_concatenation_bit_for_bit(
+        interleaved, dtype, d, positions):
+    """``x * C + (x @ P) * S`` and its hand-written backward make the
+    float32 products and the one sum the slice-and-concatenate form and
+    its autodiff make, so every bit of the result and of the gradient is
+    the same, at both pairings, dtypes, widths and kinds of positions."""
+    rng = np.random.default_rng(d + interleaved)
+    x = jnp.asarray(rng.standard_normal((2, 9, 3, d)), dtype)
+    g = jnp.asarray(rng.standard_normal((2, 9, 3, d)), dtype)
+    at = {None: np.arange(9), "row": rng.integers(0, 5000, 9),
+          "batch": rng.integers(0, 5000, (2, 9))}[positions].astype(np.int32)
+
+    def op(x):
+        return F.rotary_embedding(
+            Tensor(x), 1e4, None if positions is None else Tensor(at),
+            interleaved=interleaved).data
+
+    def plain(x):
+        return rotary_by_halves(x, 1e4, jnp.asarray(at), interleaved)
+
+    # op by op, as the tape runs it: inside one compiled program the
+    # CPU's code generator may contract a product and a sum into a fused
+    # multiply-add, in either form, and that is not the op's arithmetic
+    with no_grad():
+        got, pull = jax.vjp(op, x)
+    want, pull_plain = jax.vjp(plain, x)
+    (dx,), (dx_plain,) = pull(g), pull_plain(g)
+    assert got.dtype == want.dtype == dx.dtype == dx_plain.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(dx, np.float32),
+                                  np.asarray(dx_plain, np.float32))
 
 
 def test_swiglu_and_the_gated_feed_forward():
