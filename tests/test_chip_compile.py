@@ -1,19 +1,35 @@
 """Compile the main-path Pallas kernels for a described TPU v5e, at real
 widths, without a chip: what Mosaic refuses here it refuses on the chip.
-And one ResNet-50 stage-1 bottleneck, forward and backward, whose text
-shows whether XLA fused batch norm into the convolutions (ISSUE 26), and
-one recomputed Ouro decoder block, whose text shows how often the
-attention forward kernel runs (ISSUE 30).
+And the cells' whole blocks: one ResNet-50 stage-1 bottleneck, forward and
+backward, whose text shows whether XLA fused batch norm into the
+convolutions (ISSUE 26); one recomputed decoder block of Ouro, of SDAR and
+two of SmallThinker, and Kanana-2's and SmallThinker's expert layers,
+whose texts show which kernels run, how often and on what (ISSUE 30, 32,
+33, 43, 44); rotary positions behind a projection (ISSUE 45); the grouped
+products' scopes.
+
+A cell's recomputed block is compiled through one helper
+(``_recomputed``). No two cases share a compile: each of the five texts
+has one reader. ``test_smallthinkers_expert_layer_gathers_no_row_it_does_
+not_hold`` could read all but one of its lines out of the two blocks of
+``test_recomputed_smallthinker_blocks_name_their_kind_and_route_first``
+(the same 8 of 64 experts, top-6, hidden 2560, ``[1, 16384, 2560]``), but
+it counts the layer's 8 grouped products, the blocks' text holds 16, and
+the scope the TPU's compiler leaves a grouped product carries no block's
+index to tell them apart by: it keeps the layer alone (ISSUE 46).
 
 The only file that describes the chip. The topology is described inside
 a module-scoped fixture — never at import, in a ``skipif`` or in
 ``parametrize`` arguments — because only one process may load the TPU's
 library: under several test workers every worker imports this file, and
-only the one that is handed it may make the call. Nothing runs: there is
-no device to hold an array, so every case lowers ``ShapeDtypeStruct``s.
-A compile that passes is not a chip run.
+only the one that is handed it may make the call. (A second file of such
+cases would go to a second worker, whose load the library refuses and
+whose fixture then skips every case in silence: ISSUE 46 kept the file
+whole.) Nothing runs: there is no device to hold an array, so every case
+lowers ``ShapeDtypeStruct``s. A compile that passes is not a chip run.
 """
 
+import contextlib
 import os
 import re
 
@@ -396,6 +412,52 @@ def test_every_kernel_in_the_tree_is_compiled_here():
     assert in_tree == compiled
 
 
+def _recomputed(one_chip, layers, shape, more=lambda: (), scope="loss",
+                precision="default", f32=()):
+    """Compiled text of loss and gradients of ``layers``, one after the
+    other on a bf16 input of ``shape``, each under
+    ``fleet.utils.recompute`` (and, where there are several, under a scope
+    of its index), traced as ``make_train_step`` traces a model: tape
+    off, ``jax.value_and_grad`` outside. The state is bf16
+    ``ShapeDtypeStruct``s but for the names in ``f32``; ``more()`` makes a
+    layer's inputs beside the stream inside the trace."""
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
+    states = [{k: jax.ShapeDtypeStruct(v.shape, F32 if k in f32 else BF16,
+                                       sharding=one_chip)
+               for k, v in layer.state_dict().items()} for layer in layers]
+
+    def loss(states, x):
+        h = Tensor(x)
+        with contextlib.ExitStack() as stack:
+            if scope:
+                stack.enter_context(jax.named_scope(scope))
+            stack.enter_context(ae.no_grad())
+            stack.enter_context(ae.traced_scopes())
+            for i, (layer, state) in enumerate(zip(layers, states)):
+                with layer.load_functional_state(state), (
+                        jax.named_scope(str(i)) if len(layers) > 1
+                        else contextlib.nullcontext()):
+                    h = recompute(layer, h, *map(Tensor, more()))
+        return (h.data.astype(F32) ** 2).mean()
+
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            states, jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+        ).compile().as_text()
+
+
+def _kernel_calls(text):
+    """[(the kernel's name, its line)] of the text's ``tpu_custom_call``s
+    that a ``p1t_*`` kernel of ours names, in the text's order."""
+    return [(re.search(r"%\w*?(p1t_[a-z_]*[a-z])", c).group(1), c)
+            for c in re.findall(
+                r'^.*custom_call_target="tpu_custom_call".*$', text, re.M)
+            if "p1t_" in c.split(" = ")[0]]
+
+
 def _computations(text):
     """{computation name: its instruction lines} of an HLO module, the
     entry computation under ``"ENTRY"`` too."""
@@ -528,28 +590,15 @@ def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
     name and writes dQ too: ISSUE 35) and no second forward kernel
     (ISSUE 30: the parent's text has two). Traced as
     ``make_train_step`` traces a model: tape off, ``jax.grad`` outside."""
-    from paddle1_tpu.autograd import engine as ae
-    from paddle1_tpu.core.tensor import Tensor
-    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
     from paddle1_tpu.text.models import OuroDecoderLayer
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = OuroDecoderLayer(2048, 16, 128, 5632)
-    state = {k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
-             for k, v in layer.state_dict().items()}
-
-    def loss(state, x):
-        with ae.no_grad(), ae.traced_scopes(), \
-                layer.load_functional_state(state):
-            out = recompute(layer, Tensor(x))
-        return (out.data.astype(F32) ** 2).mean()
-
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-        state, jax.ShapeDtypeStruct((2, 4096, 2048), BF16,
-                                    sharding=one_chip)).compile().as_text()
+    text = _recomputed(one_chip, [OuroDecoderLayer(2048, 16, 128, 5632)],
+                       (2, 4096, 2048), scope=None, precision=None)
     calls = re.findall(r'^.*custom_call_target="tpu_custom_call".*$', text,
                        re.M)
-    kernels = sorted(re.search(r"%\w*?(p1t_[a-z_]*[a-z])", c).group(1)
-                     for c in calls)
+    ours = _kernel_calls(text)
+    assert len(calls) == len(ours)
+    kernels = sorted(name for name, _ in ours)
     assert kernels == ["p1t_flash_attention_bwd_dkv",
                        "p1t_flash_attention_fwd"]
     # the rest of the block is still run again in the backward pass
@@ -613,9 +662,6 @@ def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
     gradient); nothing in the text is shaped like the dense mask or
     the scores of the doubled row; the sum of a token's 8 picks is the
     kernel."""
-    from paddle1_tpu.autograd import engine as ae
-    from paddle1_tpu.core.tensor import Tensor
-    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
     from paddle1_tpu.text.models import SdarDecoderLayer
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     length = 8192
@@ -623,25 +669,10 @@ def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
         2048, dict(num_heads=32, num_kv_heads=4, head_dim=128,
                    block_length=4),
         dict(expert_width=768, num_experts=128, top_k=8, held=(0, 16)))
-    state = {k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
-             for k, v in layer.state_dict().items()}
-
-    def loss(state, x):
-        at = jnp.tile(jnp.arange(length, dtype=I32), 2)
-        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes(), \
-                layer.load_functional_state(state):
-            out = recompute(layer, Tensor(x), Tensor(at))
-        return (out.data.astype(F32) ** 2).mean()
-
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            state, jax.ShapeDtypeStruct((1, 2 * length, 2048), BF16,
-                                        sharding=one_chip)
-        ).compile().as_text()
-    calls = {re.search(r"%\w*?(p1t_[a-z_]*[a-z])", c).group(1): c
-             for c in re.findall(
-                 r'^.*custom_call_target="tpu_custom_call".*$', text, re.M)
-             if "p1t_" in c.split(" = ")[0]}
+    text = _recomputed(
+        one_chip, [layer], (1, 2 * length, 2048),
+        more=lambda: (jnp.tile(jnp.arange(length, dtype=I32), 2),))
+    calls = dict(_kernel_calls(text))
     assert sorted(calls) == ["p1t_flash_attention_bwd_dkv",
                              "p1t_flash_attention_fwd", "p1t_sum_picks_fwd"]
     assert len(re.findall(r"%\w*p1t_flash_attention_fwd[.\d]* = ", text)) == 1
@@ -678,34 +709,14 @@ def test_recomputed_smallthinker_blocks_name_their_kind_and_route_first(
     product reads the block's input and is float32; rotary is on the
     window block alone; every grouped product lies in a region under the
     expert layer's scope; nothing is shaped like a dense mask."""
-    from paddle1_tpu.autograd import engine as ae
-    from paddle1_tpu.core.tensor import Tensor
-    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
     from paddle1_tpu.obs import costmodel
     from paddle1_tpu.text.models import SmallThinkerDecoderLayer
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     experts = dict(expert_width=768, num_experts=64, top_k=6, held=(0, 8))
-    layers = [SmallThinkerDecoderLayer(
+    text = _recomputed(one_chip, [SmallThinkerDecoderLayer(
         2560, dict(num_heads=28, num_kv_heads=4, head_dim=128, window=window,
                    rotary=window is not None), experts)
-        for window in (None, 4096)]
-    states = [{k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
-               for k, v in layer.state_dict().items()} for layer in layers]
-
-    def loss(states, x):
-        h = Tensor(x)
-        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes():
-            for i, (layer, state) in enumerate(zip(layers, states)):
-                with layer.load_functional_state(state), \
-                        jax.named_scope(str(i)):
-                    h = recompute(layer, h)
-        return (h.data.astype(F32) ** 2).mean()
-
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            states, jax.ShapeDtypeStruct((1, 16384, 2560), BF16,
-                                         sharding=one_chip)
-        ).compile().as_text()
+        for window in (None, 4096)], (1, 16384, 2560))
     assert not re.search(r"\bwhile\(", text)
     assert not re.search(r"\[(\d+,)*16384,16384\]", text)
     scopes, _ = costmodel.parse_op_scopes(text)
@@ -750,6 +761,130 @@ def test_recomputed_smallthinker_blocks_name_their_kind_and_route_first(
     assert router
     for op in ("moe_dispatch", "moe_combine", "moe_overflow"):
         assert any(f"/mlp/moe/{op}" in s for s in scopes.values()), op
+
+
+def test_the_expert_layer_gathers_no_row_for_a_pick_it_does_not_hold(
+        one_chip, for_the_chip, monkeypatch):
+    """Kanana-2's expert layer at the cell's shape ([2, 8192, 2048] bf16,
+    16 of 128 experts, top-6: 98,304 picks over 36,864 rows) under
+    ``fleet.utils.recompute``, loss and gradients (ISSUE 32). The sum of
+    a token's picks is the kernel, once forward under ``moe_combine`` and
+    once backward as the transpose of ``moe_dispatch``'s gather, and not
+    again in the recomputed segment; the text holds none of what the
+    gather over every pick made: no ``[36865, 2048]`` rows with a row of
+    zeros behind them, no ``[98304, 2048]`` or ``[6, 16384, 2048]`` buffer
+    of every pick's row (402 MB), no ``[16384, 6, 2048]`` relayout, in
+    either layout of a row. The grouped products are all still under the
+    layer's scope in their pass."""
+    from paddle1_tpu import nn
+    from paddle1_tpu.obs import costmodel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = nn.RoutedExperts(2048, 768, 128, 6, held=(0, 16),
+                             shared_width=1536, routed_scaling_factor=2.448)
+    text = _recomputed(one_chip, [layer], (2, 8192, 2048),
+                       f32=("e_score_correction_bias",))
+    for gone in (r"\[36865,2048\]", r"\[98304,2048\]", r"\[98304,16,128\]",
+                 r"\[6,16384,2048\]", r"\[6,16384,16,128\]",
+                 r"\[16384,6,2048\]", r"\[16384,6,16,128\]"):
+        assert not re.search(gone, text), gone
+    assert not re.search(r"\bwhile\(", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted(scopes[n] for n in scopes
+                     if re.match(r"p1t_sum_picks_fwd(\.\d+)?$", n))
+    assert [(costmodel.region_of(k), k.split("/moe/")[1].split("/")[0],
+             "rematted_computation" in k) for k in kernels] == [
+        ("forward", "moe_combine", False),
+        ("backward", "moe_dispatch", False)], kernels
+    products = sorted(scopes[n] for n in scopes
+                      if re.match(r"ragged-dot-none(\.\d+)?$", n))
+    assert len(products) == 8 and all(
+        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
+        for w in products), products
+
+
+def test_smallthinkers_expert_layer_gathers_no_row_it_does_not_hold(
+        one_chip, for_the_chip, monkeypatch):
+    """SmallThinker's expert layer at the cell's shape ([1, 16384, 2560]
+    bf16, 8 of 64 ReLU-gated experts, top-6: 98,304 picks over 36,864
+    rows of 10 lane rows of 32 bits, a packed tile and a quarter) under
+    ``fleet.utils.recompute``, loss and gradients (ISSUE 44): the twin of
+    Kanana-2's case above at a width that is no whole tile. The kernel
+    once forward under ``moe_combine`` and once backward under
+    ``moe_dispatch``, its rows ``[4, 640]`` deep as their lane rows
+    allow, and nothing of what the gather over every pick made."""
+    from paddle1_tpu import nn
+    from paddle1_tpu.obs import costmodel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = nn.RoutedExperts(2560, 768, 64, 6, held=(0, 8),
+                             scoring="softmax", gate_activation="relu")
+    text = _recomputed(one_chip, [layer], (1, 16384, 2560))
+    for gone in (r"\[36865,2560\]", r"\[98304,2560\]", r"\[98304,4,640\]",
+                 r"\[6,16384,2560\]", r"\[6,16384,4,640\]",
+                 r"\[16384,6,2560\]", r"\[16384,6,4,640\]"):
+        assert not re.search(gone, text), gone
+    assert not re.search(r"\bwhile\(", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted(scopes[n] for n in scopes
+                     if re.match(r"p1t_sum_picks_fwd(\.\d+)?$", n))
+    assert [(costmodel.region_of(k), k.split("/moe/")[1].split("/")[0],
+             "rematted_computation" in k) for k in kernels] == [
+        ("forward", "moe_combine", False),
+        ("backward", "moe_dispatch", False)], kernels
+    for call in re.findall(r"^.*%p1t_sum_picks_fwd\S* = .*$", text, re.M):
+        assert call.split(" custom-call(")[0].count(
+            "bf16[16384,4,640]{2,1,0:T(4,128)(2,1)") == 1, call
+        assert "bf16[36864,4,640]" in call
+    products = sorted(scopes[n] for n in scopes
+                      if re.match(r"ragged-dot-none(\.\d+)?$", n))
+    assert len(products) == 8 and all(
+        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
+        for w in products), products
+
+
+def test_the_grouped_products_keep_their_scope(one_chip, for_the_chip):
+    """The TPU's compiler lowers ``lax.ragged_dot`` and its two transposes
+    to grouped kernels it names ``ragged-dot-none`` itself, and drops the
+    ``op_name``; the frontend attribute that ``grouped_matmul`` hands it
+    survives, and ``parse_op_scopes`` puts each under the expert layer's
+    scope in the pass its operands were made in: two products forward,
+    one of them recomputed (the second's output is not needed again
+    here) and four backward, named as a recomputed segment of
+    ``make_train_step`` names its instructions."""
+    from paddle1_tpu.nn import layer_moe
+    from paddle1_tpu.obs import costmodel
+
+    @jax.checkpoint
+    def segment(xs, gate_up, down, sizes):
+        with jax.named_scope("moe"):
+            with jax.named_scope("moe_dispatch"):
+                xs = xs * 2
+            with jax.named_scope("routed_experts"):
+                out = layer_moe.expert_ffn(xs, sizes, gate_up, down)
+            with jax.named_scope("moe_combine"):
+                return out * 3
+
+    def loss(xs, gate_up, down, sizes):
+        with jax.named_scope("loss"), jax.named_scope("Model"):
+            return jnp.sum(segment(xs, gate_up, down, sizes).astype(F32))
+
+    def s(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    # the cell's: 36864 rows, 16 held experts, 2048 -> 2 x 768 -> 2048;
+    # the chip's default precision (conftest asks float32 products of the
+    # CPU, which the grouped kernel refuses of bf16 operands)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            s((36864, 2048)), s((16, 2048, 1536)), s((16, 768, 2048)),
+            s((16,), I32)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    where = sorted(scopes[n] for n in scopes
+                   if re.match(r"ragged-dot-none(\.\d+)?$", n))
+    assert all(w.endswith("/moe/routed_experts") for w in where), where
+    passes = sorted((costmodel.region_of(w), "rematted_computation" in w)
+                    for w in where)
+    assert passes == ([("backward", False)] * 4 + [("backward", True)]
+                      + [("forward", False)] * 2), where
 
 
 def test_sum_picks_supported_admits_only_what_fits():
@@ -820,159 +955,3 @@ def test_gspmd_step_takes_the_xla_composition(topo, for_the_chip,
         jax.jit(ln).lower(x, wb, wb)
     text = jax.jit(ln_gspmd).lower(x, wb, wb).compile().as_text()
     assert "tpu_custom_call" not in text
-
-
-def test_the_expert_layer_gathers_no_row_for_a_pick_it_does_not_hold(
-        one_chip, for_the_chip, monkeypatch):
-    """Kanana-2's expert layer at the cell's shape ([2, 8192, 2048] bf16,
-    16 of 128 experts, top-6: 98,304 picks over 36,864 rows) under
-    ``fleet.utils.recompute``, loss and gradients (ISSUE 32). The sum of
-    a token's picks is the kernel, once forward under ``moe_combine`` and
-    once backward as the transpose of ``moe_dispatch``'s gather, and not
-    again in the recomputed segment; the text holds none of what the
-    gather over every pick made: no ``[36865, 2048]`` rows with a row of
-    zeros behind them, no ``[98304, 2048]`` or ``[6, 16384, 2048]`` buffer
-    of every pick's row (402 MB), no ``[16384, 6, 2048]`` relayout, in
-    either layout of a row. The grouped products are all still under the
-    layer's scope in their pass."""
-    from paddle1_tpu import nn
-    from paddle1_tpu.autograd import engine as ae
-    from paddle1_tpu.core.tensor import Tensor
-    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
-    from paddle1_tpu.obs import costmodel
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = nn.RoutedExperts(2048, 768, 128, 6, held=(0, 16),
-                             shared_width=1536, routed_scaling_factor=2.448)
-    state = {k: jax.ShapeDtypeStruct(
-        v.shape, F32 if k == "e_score_correction_bias" else BF16,
-        sharding=one_chip) for k, v in layer.state_dict().items()}
-
-    def loss(state, x):
-        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes(), \
-                layer.load_functional_state(state):
-            out = recompute(layer, Tensor(x))
-        return (out.data.astype(F32) ** 2).mean()
-
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            state, jax.ShapeDtypeStruct((2, 8192, 2048), BF16,
-                                        sharding=one_chip)
-        ).compile().as_text()
-    for gone in (r"\[36865,2048\]", r"\[98304,2048\]", r"\[98304,16,128\]",
-                 r"\[6,16384,2048\]", r"\[6,16384,16,128\]",
-                 r"\[16384,6,2048\]", r"\[16384,6,16,128\]"):
-        assert not re.search(gone, text), gone
-    assert not re.search(r"\bwhile\(", text)
-    scopes, _ = costmodel.parse_op_scopes(text)
-    kernels = sorted(scopes[n] for n in scopes
-                     if re.match(r"p1t_sum_picks_fwd(\.\d+)?$", n))
-    assert [(costmodel.region_of(k), k.split("/moe/")[1].split("/")[0],
-             "rematted_computation" in k) for k in kernels] == [
-        ("forward", "moe_combine", False),
-        ("backward", "moe_dispatch", False)], kernels
-    products = sorted(scopes[n] for n in scopes
-                      if re.match(r"ragged-dot-none(\.\d+)?$", n))
-    assert len(products) == 8 and all(
-        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
-        for w in products), products
-
-
-def test_smallthinkers_expert_layer_gathers_no_row_it_does_not_hold(
-        one_chip, for_the_chip, monkeypatch):
-    """SmallThinker's expert layer at the cell's shape ([1, 16384, 2560]
-    bf16, 8 of 64 ReLU-gated experts, top-6: 98,304 picks over 36,864
-    rows of 10 lane rows of 32 bits, a packed tile and a quarter) under
-    ``fleet.utils.recompute``, loss and gradients (ISSUE 44): the twin of
-    Kanana-2's case above at a width that is no whole tile. The kernel
-    once forward under ``moe_combine`` and once backward under
-    ``moe_dispatch``, its rows ``[4, 640]`` deep as their lane rows
-    allow, and nothing of what the gather over every pick made."""
-    from paddle1_tpu import nn
-    from paddle1_tpu.autograd import engine as ae
-    from paddle1_tpu.core.tensor import Tensor
-    from paddle1_tpu.distributed.fleet.utils.recompute import recompute
-    from paddle1_tpu.obs import costmodel
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = nn.RoutedExperts(2560, 768, 64, 6, held=(0, 8),
-                             scoring="softmax", gate_activation="relu")
-    state = {k: jax.ShapeDtypeStruct(v.shape, BF16, sharding=one_chip)
-             for k, v in layer.state_dict().items()}
-
-    def loss(state, x):
-        with jax.named_scope("loss"), ae.no_grad(), ae.traced_scopes(), \
-                layer.load_functional_state(state):
-            out = recompute(layer, Tensor(x))
-        return (out.data.astype(F32) ** 2).mean()
-
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-            state, jax.ShapeDtypeStruct((1, 16384, 2560), BF16,
-                                        sharding=one_chip)
-        ).compile().as_text()
-    for gone in (r"\[36865,2560\]", r"\[98304,2560\]", r"\[98304,4,640\]",
-                 r"\[6,16384,2560\]", r"\[6,16384,4,640\]",
-                 r"\[16384,6,2560\]", r"\[16384,6,4,640\]"):
-        assert not re.search(gone, text), gone
-    assert not re.search(r"\bwhile\(", text)
-    scopes, _ = costmodel.parse_op_scopes(text)
-    kernels = sorted(scopes[n] for n in scopes
-                     if re.match(r"p1t_sum_picks_fwd(\.\d+)?$", n))
-    assert [(costmodel.region_of(k), k.split("/moe/")[1].split("/")[0],
-             "rematted_computation" in k) for k in kernels] == [
-        ("forward", "moe_combine", False),
-        ("backward", "moe_dispatch", False)], kernels
-    for call in re.findall(r"^.*%p1t_sum_picks_fwd\S* = .*$", text, re.M):
-        assert call.split(" custom-call(")[0].count(
-            "bf16[16384,4,640]{2,1,0:T(4,128)(2,1)") == 1, call
-        assert "bf16[36864,4,640]" in call
-    products = sorted(scopes[n] for n in scopes
-                      if re.match(r"ragged-dot-none(\.\d+)?$", n))
-    assert len(products) == 8 and all(
-        w.endswith("/moe/routed_experts") and costmodel.region_of(w)
-        for w in products), products
-
-
-def test_the_grouped_products_keep_their_scope(one_chip, for_the_chip):
-    """The TPU's compiler lowers ``lax.ragged_dot`` and its two transposes
-    to grouped kernels it names ``ragged-dot-none`` itself, and drops the
-    ``op_name``; the frontend attribute that ``grouped_matmul`` hands it
-    survives, and ``parse_op_scopes`` puts each under the expert layer's
-    scope in the pass its operands were made in: two products forward,
-    one of them recomputed (the second's output is not needed again
-    here) and four backward, named as a recomputed segment of
-    ``make_train_step`` names its instructions."""
-    from paddle1_tpu.nn import layer_moe
-    from paddle1_tpu.obs import costmodel
-
-    @jax.checkpoint
-    def segment(xs, gate_up, down, sizes):
-        with jax.named_scope("moe"):
-            with jax.named_scope("moe_dispatch"):
-                xs = xs * 2
-            with jax.named_scope("routed_experts"):
-                out = layer_moe.expert_ffn(xs, sizes, gate_up, down)
-            with jax.named_scope("moe_combine"):
-                return out * 3
-
-    def loss(xs, gate_up, down, sizes):
-        with jax.named_scope("loss"), jax.named_scope("Model"):
-            return jnp.sum(segment(xs, gate_up, down, sizes).astype(F32))
-
-    def s(shape, dtype=BF16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    # the cell's: 36864 rows, 16 held experts, 2048 -> 2 x 768 -> 2048;
-    # the chip's default precision (conftest asks float32 products of the
-    # CPU, which the grouped kernel refuses of bf16 operands)
-    with jax.default_matmul_precision("default"):
-        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-            s((36864, 2048)), s((16, 2048, 1536)), s((16, 768, 2048)),
-            s((16,), I32)).compile().as_text()
-    assert not re.search(r"\bwhile\(", text)
-    scopes, _ = costmodel.parse_op_scopes(text)
-    where = sorted(scopes[n] for n in scopes
-                   if re.match(r"ragged-dot-none(\.\d+)?$", n))
-    assert all(w.endswith("/moe/routed_experts") for w in where), where
-    passes = sorted((costmodel.region_of(w), "rematted_computation" in w)
-                    for w in where)
-    assert passes == ([("backward", False)] * 4 + [("backward", True)]
-                      + [("forward", False)] * 2), where

@@ -379,9 +379,15 @@ def _flash_and_ref(q, k, v, dout, keep, causal, blocks, rule=None):
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
         return attention_ref(q, k, v, mask=m4, is_causal=causal) * alive
 
-    out, vjp = jax.vjp(kernel, q, k, v)
-    want, vjp_ref = jax.vjp(plain, f32(q), f32(k), f32(v))
-    return ((out,) + vjp(dout), (want,) + vjp_ref(f32(dout)), dead)
+    # each side one compiled program, as a step runs the kernels: op by
+    # op the two ``jax.vjp``s are 14 programs a case
+    def out_and_grads(attention):
+        def run(q, k, v, dout):
+            out, vjp = jax.vjp(attention, q, k, v)
+            return (out,) + vjp(dout)
+        return jax.jit(run)
+    return (out_and_grads(kernel)(q, k, v, dout),
+            out_and_grads(plain)(f32(q), f32(k), f32(v), f32(dout)), dead)
 
 
 # (causal, nq, nk, d, dtype, mask, blocks): every pairing the kernels

@@ -97,6 +97,47 @@ class TestTools:
         rec = json.loads(r.stdout.strip().splitlines()[-1])
         assert rec["op"] == "add" and rec["jit_us_median"] > 0
 
+    def test_suite_times_reads_a_junit_file_and_compares_two(self, tmp_path):
+        """Six cases over two files, a class's method and two parametrised
+        cases of one function among them: the wall, the sum, the table by
+        file, the functions with their cases summed; and with a second file
+        the differences."""
+        from tools import suite_times
+
+        def junit(wall, times):
+            cases = "".join(
+                f'<testcase classname="{c}" name="{n}" time="{t}" />'
+                for (c, n), t in zip([
+                    ("tests.test_a", "test_x[1]"), ("tests.test_a", "test_x[2]"),
+                    ("tests.test_a", "test_y"),
+                    ("tests.test_inGraph.TestK", "test_m"),
+                    ("tests.sub.test_b", "test_z"),
+                    ("tests.sub.test_b", "test_w")], times))
+            path = tmp_path / f"{wall}.xml"
+            path.write_text(
+                '<?xml version="1.0"?><testsuites><testsuite name="pytest" '
+                f'errors="0" failures="0" skipped="0" tests="6" '
+                f'time="{wall}">{cases}</testsuite></testsuites>')
+            return str(path)
+        one = junit(10.0, [1.0, 2.0, 4.0, 8.0, 0.5, 0.25])
+        wall, cases, files, funcs = suite_times.read(one)
+        assert wall == 10.0 and sum(cases.values()) == 6
+        assert files == {"tests/test_a.py": 7.0, "tests/test_inGraph.py": 8.0,
+                         "tests/sub/test_b.py": 0.75}
+        assert funcs["tests/test_a.py::test_x"] == 3.0
+        text = suite_times.report(one)
+        assert "wall 10.0 s, 6 cases, sum 15.8 s" in text
+        by_file = text.split("file\n")[1].split("\n\n")[0].splitlines()
+        assert [l.split()[-1] for l in by_file] == [
+            "tests/test_inGraph.py", "tests/test_a.py", "tests/sub/test_b.py"]
+        assert by_file[1].split()[:2] == ["7.0", "3"]
+        two = junit(5.0, [0.5, 0.5, 4.0, 2.0, 0.5, 0.25])
+        both = suite_times.report(one, two)
+        assert "wall x0.500, sum x0.492" in both
+        line, = [l for l in both.splitlines()
+                 if l.endswith("tests/test_a.py::test_x")]
+        assert line.split()[:3] == ["3.0", "1.0", "-2.0"]
+
     def test_check_style_passes(self):
         r = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "check_style.py")],

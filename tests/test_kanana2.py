@@ -20,13 +20,18 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle1_tpu as paddle  # noqa: E402
+from benchmarks.programs import kanana2_30b_a3b as program  # noqa: E402
 from benchmarks.reference import kanana2_30b_a3b as ref  # noqa: E402
 from benchmarks.reference.numerics import Numerics  # noqa: E402
+from decoder_cases import (  # noqa: E402,F401
+    NM, Decoder, decoder, eager_run, fresh_obs, ids_batch, next_token_loss,
+    reference,
+    test_recomputation_changes_neither_loss_nor_gradients,
+    test_the_model_follows_the_reference)
 from paddle1_tpu import nn, obs  # noqa: E402
 from paddle1_tpu.core.flags import (auto_partitioned_region,  # noqa: E402
                                     flags_guard)
 from paddle1_tpu.core.tensor import Tensor  # noqa: E402
-from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
 from paddle1_tpu.framework.param_attr import ParamAttr  # noqa: E402
 from paddle1_tpu.nn import functional as F  # noqa: E402
 from paddle1_tpu.nn import layer_moe  # noqa: E402
@@ -49,16 +54,11 @@ CFG = {"vocab_size": 96, "hidden_size": 32, "num_hidden_layers": 3,
        "num_experts_per_tok": 3, "n_shared_experts": 2,
        "first_k_dense_replace": 1, "routed_scaling_factor": 2.448,
        "rope_theta": 1e4, "rms_norm_eps": 1e-6, "initializer_range": 0.2}
-NM = Numerics()
 
 
-def _model(cfg=CFG):
-    """(the Layer, the reference's weights it was loaded with)."""
-    from benchmarks.programs import kanana2_30b_a3b as program
-    from benchmarks.programs import load_weights
-    weights = ref.init_params(cfg, jax.random.key(4))
+def _build(cfg):
     held = cfg["n_routed_experts"]
-    model = Kanana2ForPretraining(
+    return Kanana2ForPretraining(
         n_routed_experts=held * cfg["expert_parallel"],
         held_experts=(cfg["expert_rank"] * held, held),
         **{k: cfg[k] for k in (
@@ -69,19 +69,10 @@ def _model(cfg=CFG):
             "n_shared_experts", "first_k_dense_replace",
             "routed_scaling_factor", "rope_theta", "rms_norm_eps",
             "initializer_range")})
-    load_weights(model, {p: weights[r] for p, r, _ in program.leaves(cfg)})
-    return model, weights
 
 
-def _ids(batch=2, seq=12, seed=0):
-    return np.random.default_rng(seed).integers(
-        0, CFG["vocab_size"], (batch, seq)).astype(np.int32)
-
-
-def _loss(model, ids):
-    ids = Tensor(ids)
-    labels = model.next_token_labels(ids)
-    return Kanana2PretrainingCriterion()(model(ids, labels), labels)
+_batch = ids_batch(CFG["vocab_size"], row=12)
+_loss = next_token_loss(Kanana2PretrainingCriterion)
 
 
 # -- rotary positions, pairs (2i, 2i + 1) -----------------------------------
@@ -167,8 +158,8 @@ def _attention_weights(weights, i=0):
 
 
 @pytest.mark.parametrize("attention", ["dense", "kernel"])
-def test_latent_attention_follows_the_reference(attention):
-    model, weights = _model()
+def test_latent_attention_follows_the_reference(reference, attention):
+    model, weights = reference.model(), reference.weights
     seq = 128 if attention == "kernel" else 12
     u = np.random.default_rng(5).standard_normal(
         (2, seq, CFG["hidden_size"])).astype(np.float32)
@@ -176,15 +167,16 @@ def test_latent_attention_follows_the_reference(attention):
             flash_attention="always" if attention == "kernel" else "never"):
         got = model.layers.blocks[0].self_attn(Tensor(u)).numpy()
     lp = _attention_weights(weights)
-    want = np.stack([ref.attend(jnp.asarray(row), lp, CFG, NM) @ lp["wo"]
-                     for row in u])
+    want = jax.jit(jax.vmap(
+        lambda row: ref.attend(row, lp, CFG, NM) @ lp["wo"]))(jnp.asarray(u))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
-def test_latent_attention_is_multi_head_attention_over_expanded_k_and_v():
+def test_latent_attention_is_multi_head_attention_over_expanded_k_and_v(
+        reference):
     """The latent expanded to every head's key and value, the one rotary
     key copied to every head, then plain multi-head attention."""
-    model, weights = _model()
+    model, weights = reference.model(), reference.weights
     attn = model.layers.blocks[0].self_attn
     assert isinstance(attn, LatentAttention)
     lp = _attention_weights(weights)
@@ -363,15 +355,6 @@ def test_the_eight_shares_add_up_to_the_whole_layer():
     np.testing.assert_allclose(parts + shared, want, rtol=1e-4, atol=1e-5)
 
 
-@pytest.fixture
-def _fresh_obs():
-    obs.reset_process_registry()
-    obs.hbm.reset()
-    yield
-    obs.reset_process_registry()
-    obs.hbm.reset()
-
-
 @pytest.mark.parametrize("capacity", [4, 8, 64])
 def test_sort_picks_by_hand(capacity):
     """12 picks (4 tokens x top-3) over 8 experts of which 2, 3 and 4 are
@@ -474,15 +457,16 @@ def test_the_two_gathers_are_one_hot_products_and_transposes(
         lambda a: layer_moe.rows_in_order(a, order, where, fan), a)
     got_sum, sum_vjp = jax.vjp(
         lambda o: layer_moe.sum_of_picks(o, order, where, fan), o)
+    pulled, = rows_vjp(o)       # once: a call lowers the kernel anew
     close(got_rows, product(R, a))
     close(got_sum, product(S, o))
-    close(rows_vjp(o)[0], product(S, o))
+    close(pulled, product(S, o))
     close(sum_vjp(a)[0], product(R, a))
     # to the last bit, values and the dispatch's backward alike
     before = _sum_picks_before_issue_32(o, where, fan)
     with auto_partitioned_region():             # the gather, whatever the width
         gathered = layer_moe._sum_picks(o, where, fan)
-    for got in (got_sum, rows_vjp(o)[0], gathered,
+    for got in (got_sum, pulled, gathered,
                 jax.jit(layer_moe.sum_of_picks, static_argnums=3)(
                     o, order, where, fan)):
         np.testing.assert_array_equal(_f32(got), _f32(before))
@@ -598,7 +582,7 @@ def test_a_row_travels_as_deep_as_its_lane_rows_allow(hidden, dtype, row):
 
 @pytest.mark.parametrize("hidden,arm", [(2560, "kernel"),
                                         (2048 + 128, "gather")])
-def test_a_traced_layer_counts_the_arm_its_sums_took(_fresh_obs, hidden,
+def test_a_traced_layer_counts_the_arm_its_sums_took(fresh_obs, hidden,
                                                      arm):
     """``p1t_moe_sum_picks_arm_total{arm}``: one increment a traced sum
     over rows, two a layer's forward + backward (``moe_combine``, and the
@@ -629,43 +613,46 @@ def test_a_traced_layer_counts_the_arm_its_sums_took(_fresh_obs, hidden,
 
 # -- the model --------------------------------------------------------------
 
-def test_the_model_follows_the_reference():
-    model, weights = _model()
-    ids = _ids()
-    got = float(_loss(model, ids))
-    want, state = ref.loss(weights, {"ids": jnp.asarray(ids)}, CFG, NM)
-    assert got == pytest.approx(float(want), rel=2e-5)
+def _the_kinds_of_layers_and_the_logits(model, weights, batch):
+    state = jax.eval_shape(lambda w: ref.loss(
+        w, {"ids": jnp.asarray(batch["ids"])}, CFG, NM)[1], weights)
     assert sorted(state) == ["e_bias.1", "e_bias.2"]
     # a leading dense layer, then expert layers
     kinds = [type(b.mlp).__name__ for b in model.layers.blocks]
     assert kinds == ["GatedFeedForward", "RoutedExperts", "RoutedExperts"]
-    logits = model(Tensor(ids)).numpy()
+    logits = model(Tensor(batch["ids"])).numpy()
     assert logits.shape == (2, 12, CFG["vocab_size"])
 
 
+# the shared cases' model (decoder_cases.py)
+DECODER = Decoder(
+    cfg=CFG, ref=ref, program=program, build=_build, criterion=_loss,
+    batch=_batch, dense_seq=12,
+    follows_also=_the_kinds_of_layers_and_the_logits,
+    buffers=frozenset(f"layers.blocks.{i}.mlp.e_score_correction_bias"
+                      for i in (1, 2)))
+
+
 @pytest.mark.parametrize("precision", ["float32", "float8_matmul"])
-def test_the_reference_in_blocks_is_the_reference(monkeypatch, precision):
+def test_the_reference_in_blocks_is_the_reference(reference, monkeypatch,
+                                                  precision):
     """At the cell's size the reference takes the rows, the heads and
     groups of the held experts one at a time through ``lax.map``, and a
     head's queries in blocks; at a test's size it takes each whole. The same loss and
     gradients either way (the control's float8 scale is a block's own, so
     there only the loss is held, loosely)."""
-    weights = ref.init_params(CFG, jax.random.key(4))
-    batch = {"ids": jnp.asarray(_ids(seq=16))}
+    batch = _batch(seq=16)
     nm = Numerics(precision)
-
-    def run():
-        return jax.value_and_grad(
-            lambda w: ref.loss(w, batch, CFG, nm)[0])(weights)
-    whole, g_whole = run()
+    whole, g_whole = reference.compiled(nm)(batch)
     # one head and 8 of its 16 queries a block of scores; one expert a group
     monkeypatch.setattr(ref, "SCORE_BLOCK_BYTES", 4 * 16 * 8)
     monkeypatch.setattr(ref, "EXPERTS_BLOCK_BYTES", 4 * 16 * 32)
     monkeypatch.setattr(ref, "BLOCK_TOKENS", 16)     # and a row a block
-    text = str(jax.make_jaxpr(lambda w: ref.loss(w, batch, CFG, nm)[0])(
-        weights))
+    text = str(jax.make_jaxpr(lambda w: ref.loss(
+        w, {"ids": jnp.asarray(batch["ids"])}, CFG, nm)[0])(
+            reference.weights))
     assert text.count("scan") >= 3
-    blocks, g_blocks = run()
+    blocks, g_blocks = reference.compiled(nm)(batch)
     if precision != "float32":
         # other blocks, other scales: the control's noise is drawn anew
         assert float(blocks) == pytest.approx(float(whole), rel=0.05)
@@ -680,28 +667,8 @@ def test_the_reference_in_blocks_is_the_reference(monkeypatch, precision):
                 np.linalg.norm(b), 1e-3), k
 
 
-@pytest.mark.parametrize("attention", ["dense", "kernel"])
-def test_recomputation_changes_neither_loss_nor_gradients(attention):
-    """A segment now holds a sort, gathers and grouped products with
-    integer residuals beside the kernel's kept ``out`` and ``lse``."""
-    ids = _ids(seq=128 if attention == "kernel" else 12)
-    got = {}
-    with flags_guard(
-            flash_attention="always" if attention == "kernel" else "never"):
-        for remat in (False, True):
-            model, _ = _model()
-            model.layers.enable_recompute = remat
-            loss = _loss(model, ids)
-            loss.backward()
-            got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
-                                        model.named_parameters()})
-    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
-    for k, g in got[False][1].items():
-        np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
-                                   atol=1e-6 * np.abs(g).max())
-
-
-def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(capsys):
+def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(reference,
+                                                                   capsys):
     """Beside its inputs: the kernel's two outputs (ISSUE 30) and, by
     name (ISSUE 37), the stream after attention, the picks' scores and
     experts, the sort's four and the sorted rows' weights (the capacity
@@ -710,8 +677,7 @@ def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(capsys):
     from jax.ad_checkpoint import print_saved_residuals
     from paddle1_tpu.autograd.engine import no_grad
     from paddle1_tpu.distributed.fleet.utils.recompute import recompute
-    model, _ = _model()
-    layer = model.layers.blocks[1]
+    layer = reference.model().layers.blocks[1]
     state = {k: v.data for k, v in layer.state_dict().items()}
     h = jnp.asarray(np.random.default_rng(2).standard_normal(
         (2, 128, CFG["hidden_size"])), jnp.float32)
@@ -743,31 +709,16 @@ def test_a_recomputed_expert_layer_keeps_the_kernels_outputs_alone(capsys):
         for l in rest), rest
 
 
-def _engine(recompute=True, amp=None, bias=None):
-    model, _ = _model()
-    if bias is not None:
-        for _, b in model.named_buffers():
-            b.data = jnp.asarray(bias, jnp.float32)
-    opt = paddle.optimizer.AdamW(learning_rate=1e-2, weight_decay=0.1,
-                                 parameters=model.parameters())
-    crit = Kanana2PretrainingCriterion()
-
-    def loss_fn(m, b):
-        ids = Tensor(b["ids"])
-        labels = m.next_token_labels(ids)
-        return crit(m(ids, labels), labels)
-    return ParallelEngine(model, opt, loss_fn, amp_dtype=amp,
-                          mesh=build_mesh(dp=1, devices=jax.devices()[:1]),
-                          recompute=recompute)
-
-
-def test_the_selection_bias_passes_through_a_step_untouched():
+def test_the_selection_bias_passes_through_a_step_untouched(reference):
     """A buffer with no gradient: AdamW's decay would shrink it."""
     bias = np.linspace(-0.1, 0.1, 8)
-    engine = _engine(bias=bias)
+    model = reference.model()
+    for _, b in model.named_buffers():
+        b.data = jnp.asarray(bias, jnp.float32)
+    engine = reference.engine(model=model)
     names = [k for k in engine.params if k.endswith("e_score_correction_bias")]
     assert len(names) == 2
-    batch = engine.shard_batch({"ids": _ids()})
+    batch = engine.shard_batch(_batch())
     losses = [float(engine.step(batch)) for _ in range(3)]
     assert losses[2] < losses[0]
     for k in names:
@@ -778,9 +729,10 @@ def test_the_selection_bias_passes_through_a_step_untouched():
         engine.model.layers.blocks[1].mlp.router.data))
 
 
-def test_the_expert_layer_and_latent_attention_have_scopes(_fresh_obs):
-    engine = _engine(amp="bfloat16")
-    float(engine.step(engine.shard_batch({"ids": _ids()}), lr=1e-3))
+def test_the_expert_layer_and_latent_attention_have_scopes(reference,
+                                                           fresh_obs):
+    engine = reference.engine(amp="bfloat16")
+    float(engine.step(engine.shard_batch(_batch()), lr=1e-3))
     scopes = costmodel.step_op_scopes()
     named = [s for s in scopes.values() if "jvp(loss)" in s]
     assert not [s for s in named if "/while/" in s]

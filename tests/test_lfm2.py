@@ -20,19 +20,26 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle1_tpu as paddle  # noqa: E402
+from benchmarks.programs import lfm2_24b_a2b as program  # noqa: E402
+from benchmarks.reference import kanana2_30b_a3b as blocks_of  # noqa: E402
 from benchmarks.reference import lfm2_24b_a2b as ref  # noqa: E402
-from benchmarks.reference.numerics import Numerics  # noqa: E402
-from paddle1_tpu import nn, obs  # noqa: E402
+from decoder_cases import (  # noqa: E402,F401
+    NM, Decoder, decoder, eager_kernel_run, eager_run, fresh_obs, ids_batch,
+    logits_follow, next_token_loss, reference,
+    test_a_step_trains_and_carries_the_scopes_and_the_counters,
+    test_recomputation_changes_neither_loss_nor_gradients,
+    test_the_eight_shares_add_up_to_the_whole_layer,
+    test_the_model_follows_the_reference,
+    test_the_reference_in_blocks_is_the_reference)
+from paddle1_tpu import nn  # noqa: E402
 from paddle1_tpu.core.flags import flags_guard  # noqa: E402
 from paddle1_tpu.core.tensor import Tensor  # noqa: E402
-from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
 from paddle1_tpu.framework.param_attr import ParamAttr  # noqa: E402
 from paddle1_tpu.nn import functional as F  # noqa: E402
 from paddle1_tpu.nn import layer_moe  # noqa: E402
 from paddle1_tpu.nn.functional import short_conv  # noqa: E402
 from paddle1_tpu.nn.functional.attention import attention_ref  # noqa: E402
 from paddle1_tpu.nn.initializer import Normal  # noqa: E402
-from paddle1_tpu.obs import costmodel  # noqa: E402
 from paddle1_tpu.obs.registry import process_group  # noqa: E402
 from paddle1_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 from paddle1_tpu.text.models import (Lfm2ForPretraining,  # noqa: E402
@@ -53,16 +60,6 @@ CFG = {"vocab_size": 96, "hidden_size": 128, "num_hidden_layers": 4,
        "routed_scaling_factor": 1, "norm_eps": 1e-5,
        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
        "initializer_range": 0.2}
-NM = Numerics()
-
-
-@pytest.fixture
-def _fresh_obs():
-    obs.reset_process_registry()
-    obs.hbm.reset()
-    yield
-    obs.reset_process_registry()
-    obs.hbm.reset()
 
 
 # -- the op -----------------------------------------------------------------
@@ -147,7 +144,7 @@ def test_the_op_in_bfloat16_accumulates_in_float32():
     assert [g.dtype for g in grads] == [jnp.bfloat16] * 2
 
 
-def test_a_traced_call_counts_its_bytes_by_the_closed_form(_fresh_obs):
+def test_a_traced_call_counts_its_bytes_by_the_closed_form(fresh_obs):
     """``short_conv_bytes_total{pass}`` is a whole multiple (jax may trace
     a call's forward more than once) of :func:`traffic_bytes` of the
     call's shapes, which is the benchmark's own closed form for one
@@ -252,13 +249,9 @@ def test_the_sigmoid_rule_takes_its_constant_from_the_constructor():
 
 # -- the model against the reference ----------------------------------------
 
-def _model(cfg=CFG):
-    """(the Layer, the reference's weights it was loaded with)."""
-    from benchmarks.programs import load_weights
-    from benchmarks.programs import lfm2_24b_a2b as program
-    weights = ref.init_params(cfg, jax.random.key(4))
+def _build(cfg):
     held = cfg["num_experts"]
-    model = Lfm2ForPretraining(
+    return Lfm2ForPretraining(
         layer_types=ref.layer_kinds(cfg),
         num_experts=held * cfg["expert_parallel"],
         held_experts=(cfg["expert_rank"] * held, held),
@@ -269,61 +262,26 @@ def _model(cfg=CFG):
             "conv_L_cache", "intermediate_size", "moe_intermediate_size",
             "num_experts_per_tok", "routed_scaling_factor", "norm_eps",
             "initializer_range")})
-    load_weights(model, {p: weights[r] for p, r, _ in program.leaves(cfg)})
-    return model, weights
 
 
-def _batch(batch=2, seq=16, seed=0):
-    rng = np.random.default_rng(seed)
-    return {"ids": rng.integers(0, CFG["vocab_size"],
-                                (batch, seq)).astype(np.int32)}
+# one row length for every case of the model on the eager tape, the
+# kernels' tile: an op's programs are compiled once a shape (ISSUE 46)
+_batch = ids_batch(CFG["vocab_size"], row=128)
+_loss = next_token_loss(Lfm2PretrainingCriterion)
 
 
-def _loss(model, batch):
-    ids = Tensor(batch["ids"])
-    labels = model.next_token_labels(ids)
-    return Lfm2PretrainingCriterion()(model(ids, labels), labels)
-
-
-def test_the_model_follows_the_reference():
-    """Logits, loss and every gradient leaf in float32. Tolerances: the
-    two sides sum the same float32 products in another order (2e-5 of a
-    loss, 2e-4 of a leaf's gradient norm, 1e-4 of the largest logit)."""
-    from benchmarks.programs import lfm2_24b_a2b as program
-    model, weights = _model()
+def _the_kinds_of_layers_and_the_logits(model, weights, batch):
     kinds = [b.kind for b in model.layers.blocks]
     assert kinds == ["conv", "full_attention", "conv", "conv"]
     assert [type(b.mlp).__name__ for b in model.layers.blocks] == [
         "GatedFeedForward"] + ["RoutedExperts"] * 3
-    batch = _batch()
-    ids = jnp.asarray(batch["ids"])
-    logits = model(Tensor(batch["ids"])).numpy()
-    want_logits = np.asarray(ref.head_logits(
-        ref.hidden(weights, ids, CFG, NM), weights, CFG, NM))
-    assert logits.shape == (2, 16, CFG["vocab_size"])
-    np.testing.assert_allclose(logits, want_logits, rtol=0,
-                               atol=1e-4 * np.abs(want_logits).max())
-    loss = _loss(model, batch)
-    want, grads = jax.value_and_grad(
-        lambda w: ref.loss(w, {"ids": ids}, CFG, NM)[0])(weights)
-    assert float(loss) == pytest.approx(float(want), rel=2e-5)
-    loss.backward()
-    named = dict(model.named_parameters())
-    leaves = program.leaves(CFG)
-    # every parameter is a leaf of the map, and the expert bias a buffer
-    assert {p for p, _, _ in leaves} - set(named) == {
-        f"layers.blocks.{i}.mlp.e_score_correction_bias" for i in (1, 2, 3)}
-    for p, r, _ in leaves:
-        if p not in named:
-            continue
-        g, w = named[p].grad.numpy(), np.asarray(grads[r])
-        assert np.linalg.norm(g - w) <= 2e-4 * max(np.linalg.norm(w), 1e-4), p
+    logits_follow(DECODER, model, weights, batch)
 
 
-def test_the_tied_head_is_one_leaf_with_both_gradients():
+def test_the_tied_head_is_one_leaf_with_both_gradients(reference):
     """No second [vocab, hidden] parameter; the leaf's gradient is the
     lookup's plus the head's, each of which the reference gives alone."""
-    model, weights = _model()
+    model, weights = reference.model(), reference.weights
     names = [n for n, p in model.named_parameters()
              if tuple(p.shape) == (CFG["vocab_size"], CFG["hidden_size"])]
     assert names == ["embed_tokens.weight"]
@@ -339,8 +297,8 @@ def test_the_tied_head_is_one_leaf_with_both_gradients():
         picked = jnp.take_along_axis(logits[:, :-1], ids[:, 1:, None],
                                      -1)[..., 0]
         return jnp.mean(jax.nn.logsumexp(logits[:, :-1], -1) - picked)
-    g_lookup, g_head = jax.grad(split, (0, 1))(weights["embed"],
-                                               weights["embed"])
+    g_lookup, g_head = jax.jit(jax.grad(split, (0, 1)))(weights["embed"],
+                                                        weights["embed"])
     assert float(jnp.linalg.norm(g_lookup)) > 0
     assert float(jnp.linalg.norm(g_head)) > 0
     both = np.asarray(g_lookup + g_head)
@@ -350,56 +308,10 @@ def test_the_tied_head_is_one_leaf_with_both_gradients():
         > 1e-2 * np.linalg.norm(both)
 
 
-def test_the_reference_in_blocks_is_the_reference(monkeypatch):
-    """At the cell's size the reference takes a row's positions through a
-    feed-forward in blocks and a block of one key/value head's queries
-    against the keys at a time, through ``lax.map``; at a test's size it
-    takes each whole."""
-    from benchmarks.reference import kanana2_30b_a3b as blocks_of
-    weights = ref.init_params(CFG, jax.random.key(4))
-    batch = {"ids": jnp.asarray(_batch(seq=32)["ids"])}
-
-    def run():
-        return jax.value_and_grad(
-            lambda w: ref.loss(w, batch, CFG, NM)[0])(weights)
-    whole, g_whole = run()
-    monkeypatch.setattr(ref, "ROW_BLOCK_POSITIONS", 8)
-    monkeypatch.setattr(ref, "SCORE_BLOCK_BYTES", 4 * 32 * 2 * 8)
-    monkeypatch.setattr(ref, "EXPERTS_BLOCK_BYTES", 4 * 8 * 128 * 2)
-    monkeypatch.setattr(blocks_of, "BLOCK_TOKENS", 32)
-    text = str(jax.make_jaxpr(lambda w: ref.loss(w, batch, CFG, NM)[0])(
-        weights))
-    assert text.count("scan") >= 5
-    blocks, g_blocks = run()
-    assert float(blocks) == pytest.approx(float(whole), rel=1e-5)
-    for k in g_whole:
-        a, b = np.asarray(g_blocks[k]), np.asarray(g_whole[k])
-        assert np.linalg.norm(a - b) <= 1e-5 * max(np.linalg.norm(b), 1e-3), k
-
-
-@pytest.mark.parametrize("attention", ["dense", "kernel"])
-def test_recomputation_changes_neither_loss_nor_gradients(attention):
-    batch = _batch(seq=128 if attention == "kernel" else 16)
-    got = {}
-    with flags_guard(
-            flash_attention="always" if attention == "kernel" else "never"):
-        for remat in (False, True):
-            model, _ = _model()
-            model.layers.enable_recompute = remat
-            loss = _loss(model, batch)
-            loss.backward()
-            got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
-                                        model.named_parameters()})
-    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
-    for k, g in got[False][1].items():
-        np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
-                                   atol=1e-6 * np.abs(g).max())
-
-
 # -- the kernels at this model's shape ---------------------------------------
 
 def test_the_kernels_arm_is_taken_at_head_width_64_with_32_over_8_heads(
-        _fresh_obs):
+        fresh_obs):
     """[1, 256, 32 / 8, 64] causal under ``flash_attention=always``
     (interpreter mode): the kernels' arm is counted, and out and the three
     gradients agree with ``attention_ref``."""
@@ -439,38 +351,6 @@ def _experts(tokens, num_experts, top_k, held, hidden=32, width=8, seed=0):
     return layer, x
 
 
-def test_the_eight_shares_add_up_to_the_whole_layer():
-    """The share test: with the same weights, the routed outputs of the
-    eight shares (8 experts of 64 each, top-4) are the uncut layer's, which
-    is the reference's uncut expert layer."""
-    tokens, width, total = 48, 8, 64
-    whole, x = _experts(tokens, total, 4, None)
-    want = whole(Tensor(x)).numpy()
-    cfg = {"num_experts": total, "expert_parallel": 1, "expert_rank": 0,
-           "num_experts_per_tok": 4, "moe_intermediate_size": width,
-           "routed_scaling_factor": 1}
-    lp = {"router": whole.router.data, "e_gate_up": whole.gate_up_proj.data,
-          "e_down": whole.down_proj.data,
-          "e_bias": jnp.zeros((total,), jnp.float32)}
-    np.testing.assert_allclose(want, ref.experts(jnp.asarray(x), lp, cfg, NM),
-                               rtol=1e-4, atol=1e-5)
-    parts = np.zeros_like(want)
-    for rank in range(8):
-        share, _ = _experts(tokens, total, 4, (8 * rank, 8))
-        share.router.data = whole.router.data
-        share.gate_up_proj.data = whole.gate_up_proj.data[8 * rank:][:8]
-        share.down_proj.data = whole.down_proj.data[8 * rank:][:8]
-        part = share(Tensor(x)).numpy()
-        # what a share computes is what the reference gives that share
-        np.testing.assert_allclose(part, ref.experts(
-            jnp.asarray(x), {**lp, "e_gate_up": share.gate_up_proj.data,
-                             "e_down": share.down_proj.data},
-            {**cfg, "num_experts": 8, "expert_parallel": 8,
-             "expert_rank": rank}, NM), rtol=1e-4, atol=1e-5)
-        parts += part
-    np.testing.assert_allclose(parts, want, rtol=1e-4, atol=1e-5)
-
-
 def test_the_eight_vocabulary_slices_concatenate_to_the_whole_head():
     """Eight models, each with an eighth of the embedding's rows, give
     logits that side by side are the whole tied head's over the same
@@ -496,41 +376,15 @@ def test_the_eight_vocabulary_slices_concatenate_to_the_whole_head():
 
 # -- a traced step ------------------------------------------------------------
 
-def _engine(amp=None):
-    model, _ = _model()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-2, weight_decay=0.1,
-                                 parameters=model.parameters())
-    crit = Lfm2PretrainingCriterion()
-
-    def loss_fn(m, b):
-        ids = Tensor(b["ids"])
-        labels = m.next_token_labels(ids)
-        return crit(m(ids, labels), labels)
-    return ParallelEngine(model, opt, loss_fn, amp_dtype=amp,
-                          mesh=build_mesh(dp=1, devices=jax.devices()[:1]),
-                          recompute=True)
-
-
-def test_a_step_trains_and_carries_the_scopes_and_the_counters(_fresh_obs):
-    engine = _engine(amp="bfloat16")
-    assert engine.model.layers.enable_recompute
-    batch = engine.shard_batch(_batch(seq=128))
-    with flags_guard(flash_attention="always"):
-        losses = [float(engine.step(batch, lr=1e-2)) for _ in range(3)]
-        arms, passes = process_group("arm"), process_group("pass")
-        assert arms.child("flash").counter("attention_arm_total").value >= 1
-        assert arms.child("dense").counter("attention_arm_total").value == 0
-        scopes = costmodel.step_op_scopes()
-        text = engine.compiled_step_text()
-    assert losses[2] < losses[0]
+def _the_steps_own_scopes_and_counters(engine, named):
     # three convolution layers: each traced forward and backward counted
     # by the closed form of [2, 128, 128] bfloat16 with 3 taps
+    passes = process_group("pass")
     want = short_conv.traffic_bytes((2, 128, 3 * 128), 2, 3)
     for which in ("forward", "backward"):
         counted = passes.child(which).counter("short_conv_bytes_total").value
         assert counted >= 3 * want[which] \
             and counted % (3 * want[which]) == 0
-    named = [s for s in scopes.values() if "jvp(loss)" in s]
     for i, kind in enumerate(ref.layer_kinds(CFG)):
         at = f"/layers/recompute/{i}/"
         if kind == "conv":
@@ -553,26 +407,35 @@ def test_a_step_trains_and_carries_the_scopes_and_the_counters(_fresh_obs):
             assert any(at + op in s or at + op.replace(
                 "mlp/linear", "mlp/gate_proj/linear") in s
                 for s in named), (i, op)
-    assert not [s for s in named if "shared_experts" in s]
-    assert any("/lm_head/head_cross_entropy" in s for s in named)
     assert any("/lm_head/norm/rms_norm" in s for s in named)
     assert any("/next_token_loss" in s for s in named)
-    # the two kernels under the attention op, the forward not run again
-    kernels = [s for s in named if "p1t_flash_attention" in s]
-    assert kernels and all("/scaled_dot_product_attention/" in s
-                           for s in kernels)
-    assert not [s for s in kernels if "/rematted_computation/" in s
-                and "p1t_flash_attention_fwd" in s]
     # the op runs again inside a recomputed segment: it keeps nothing
     again = [s for s in named if "/rematted_computation/" in s
              and "/gated_short_conv" in s]
     assert again
-    # the router is a float32 island under the bf16 autocast
-    router = [l for l in text.splitlines()
-              if "moe_router" in l and " dot(" in l]
-    assert router and all(" f32[" in l.split(" dot(")[0] for l in router)
     # the expert layers count their load: three of them
     load = engine.expert_load()
     assert len(load) == 3 and all(c["steps"] == 3 for c in load.values())
     assert all(c["num_experts"] == 16 and c["held"] == 8
                for c in load.values())
+
+
+# the shared cases' model (decoder_cases.py). The reference in blocks: a
+# row's positions through a feed-forward 8 at a time, one key/value head
+# and 8 of its queries a block of scores, two experts a group, a row a
+# block. The share test: 8 experts of 64 each, top-4, the sigmoid rule.
+DECODER = Decoder(
+    cfg=CFG, ref=ref, program=program, build=_build, criterion=_loss,
+    batch=_batch, dense_seq=128, kernel_sides_on_the_tape=True,
+    follows_also=_the_kinds_of_layers_and_the_logits,
+    buffers=frozenset(f"layers.blocks.{i}.mlp.e_score_correction_bias"
+                      for i in (1, 2, 3)),
+    blocks_seq=32, scans=5, blocks=(
+        (ref, "ROW_BLOCK_POSITIONS", 8),
+        (ref, "SCORE_BLOCK_BYTES", 4 * 32 * 2 * 8),
+        (ref, "EXPERTS_BLOCK_BYTES", 4 * 8 * 128 * 2),
+        (blocks_of, "BLOCK_TOKENS", 32)),
+    step_scopes=_the_steps_own_scopes_and_counters,
+    shares={"experts": _experts, "total": 64, "top_k": 4,
+            "cfg": {"routed_scaling_factor": 1},
+            "weights": {"e_bias": jnp.zeros((64,), jnp.float32)}})

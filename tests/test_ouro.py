@@ -4,6 +4,7 @@ reference (``benchmarks/reference/ouro_2p6b.py``), the exit distribution and
 its loss, recomputation, and the names a traced step carries. CPU, tiny
 sizes, seeded weights."""
 
+import functools
 import os
 import sys
 
@@ -18,10 +19,12 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle1_tpu as paddle  # noqa: E402
+from benchmarks.programs import ouro_2p6b as program  # noqa: E402
 from benchmarks.reference import ouro_2p6b as ref  # noqa: E402
+from decoder_cases import (  # noqa: E402,F401
+    NM, Decoder, Reference, decoder, eager_kernel_run, eager_run, fresh_obs,
+    test_recomputation_changes_neither_loss_nor_gradients)
 from op_test import rotary_by_halves  # noqa: E402
-from benchmarks.reference.numerics import Numerics  # noqa: E402
-from paddle1_tpu import obs  # noqa: E402
 from paddle1_tpu.autograd.engine import no_grad  # noqa: E402
 from paddle1_tpu.core.flags import flags_guard  # noqa: E402
 from paddle1_tpu.core.tensor import Tensor  # noqa: E402
@@ -35,30 +38,69 @@ CFG = {"vocab_size": 96, "hidden_size": 32, "num_hidden_layers": 2,
        "num_attention_heads": 2, "head_dim": 16, "intermediate_size": 48,
        "total_ut_steps": 4, "rope_theta": 1e4, "rms_norm_eps": 1e-6,
        "initializer_range": 0.2, "exit_entropy_beta": 0.1}
-NM = Numerics()
 
 
-def _model(**over):
-    cfg = {**CFG, **over}
-    model = OuroForPretraining(**{k: cfg[k] for k in cfg
-                                  if k != "exit_entropy_beta"})
-    weights = jax.device_get(ref.init_params(cfg, jax.random.key(3)))
+def _draw(cfg):
+    weights = jax.device_get(jax.jit(lambda key: ref.init_params(cfg, key))(
+        jax.random.key(3)))
     # the norm scales and the gate's bias away from their 1 and 0
     rng = np.random.default_rng(0)
     for k in sorted(weights):
         if k[0] == "n" or k == "gate_b":
             weights[k] = (weights[k] + 0.3 * rng.standard_normal(
                 weights[k].shape)).astype(np.float32)
-    from benchmarks.programs import load_weights, ouro_2p6b as program
-    load_weights(model, {p: jnp.asarray(weights[r] if i is None
-                                        else weights[r][i])
-                         for p, r, i in program.leaves(cfg)})
-    return model, weights, cfg
+    return weights
 
 
 def _ids(rows=2, seq=12, seed=1):
     return np.random.default_rng(seed).integers(
         0, CFG["vocab_size"], (rows, seq)).astype(np.int32)
+
+
+def _loss(model, ids, beta=0.1):
+    t = Tensor(ids)
+    labels = model.next_token_labels(t)
+    return OuroPretrainingCriterion(beta)(*model(t, labels), labels)
+
+
+def _nothing_is_recomputed_in_evaluation(model, batch, loss):
+    model.eval()
+    assert float(_loss(model, batch["ids"])) == pytest.approx(loss, rel=1e-6)
+
+
+# the shared cases' model (decoder_cases.py): this file keeps both sides
+# of the kernels' recomputation case op by op on the eager tape
+DECODER = Decoder(
+    cfg=CFG, ref=ref, program=program, draw=_draw, dense_seq=12,
+    build=lambda cfg: OuroForPretraining(
+        **{k: cfg[k] for k in cfg if k != "exit_entropy_beta"}),
+    criterion=lambda model, batch: _loss(model, batch["ids"]),
+    batch=lambda seq: {"ids": _ids(seq=seq)},
+    recomputed_also=_nothing_is_recomputed_in_evaluation,
+    kernel_sides_on_the_tape=True,
+    optimizer=lambda parameters: paddle.optimizer.AdamW(
+        learning_rate=1e-3, parameters=parameters))
+
+
+@functools.cache
+def _reference(**over):
+    """The reference's side with ``over`` laid over ``CFG``: a
+    configuration's weights drawn once a file."""
+    return Reference(DECODER, {**CFG, **over})
+
+
+@pytest.fixture(scope="module")
+def reference():
+    """The shared cases' (in ``decoder_cases.reference``'s place) and
+    ``_model()``'s: one draw."""
+    return _reference()
+
+
+def _model(**over):
+    """(a fresh Layer, the reference's weights it holds, the configuration)
+    with ``over`` laid over ``CFG``."""
+    r = _reference(**over)
+    return r.model(), r.weights, r.cfg
 
 
 @pytest.mark.parametrize("positions", [None, "row", "batch"])
@@ -166,12 +208,6 @@ def test_the_block_follows_the_reference_layer_by_layer():
     assert np.abs(out[:, 8:] - base[:, 8:]).max() > 0.1
 
 
-def _loss(model, ids, beta=0.1):
-    t = Tensor(ids)
-    labels = model.next_token_labels(t)
-    return OuroPretrainingCriterion(beta)(*model(t, labels), labels)
-
-
 @pytest.mark.parametrize("steps", [1, 2, 4])
 def test_the_model_follows_the_reference(steps):
     model, weights, cfg = _model(total_ut_steps=steps)
@@ -196,14 +232,12 @@ def test_one_loop_step_is_one_pass():
                                atol=1e-5)
 
 
-def test_a_shared_weights_gradient_is_the_sum_over_its_uses():
+def test_a_shared_weights_gradient_is_the_sum_over_its_uses(eager_run):
     """At T = 4 the gradient of each weight of the stack and of the head
-    equals the sum of the four per-use gradients of an unrolled copy that
-    has separate weights for every loop step."""
-    model, _, cfg = _model()
-    ids = _ids()
-    loss = _loss(model, ids)
-    loss.backward()
+    (the file's one eager run) equals the sum of the four per-use
+    gradients of an unrolled copy that has separate weights for every
+    loop step."""
+    model, ids, loss = eager_run.model, eager_run.batch["ids"], eager_run.loss
     shared = {"layers." + k: v for k, v in
               model.layers.functional_state().items()}
     shared.update({"exit_head." + k: v for k, v in
@@ -224,14 +258,13 @@ def test_a_shared_weights_gradient_is_the_sum_over_its_uses():
                     labels).data
 
     with no_grad():
-        value, grads = jax.value_and_grad(unrolled)([dict(shared)
-                                                     for _ in range(4)])
-    assert float(value) == pytest.approx(float(loss), rel=1e-6)
-    params = dict(model.named_parameters())
+        value, grads = jax.jit(jax.value_and_grad(unrolled))(
+            [dict(shared) for _ in range(4)])
+    assert float(value) == pytest.approx(loss, rel=1e-6)
     for name in shared:
         per_use = [np.asarray(g[name]) for g in grads]
         total = sum(per_use)
-        np.testing.assert_allclose(params[name].grad.numpy(), total,
+        np.testing.assert_allclose(eager_run.grads[name], total,
                                    rtol=2e-4, atol=1e-5 * np.abs(total).max())
         # and every use takes part, but the last use of the gate: the
         # last exit takes what is left, whatever its gate says
@@ -283,32 +316,6 @@ def test_the_exit_arithmetic_is_float32_under_autocast():
         Tensor(jnp.ones((4, 1, 4), jnp.bfloat16)),
         Tensor(jnp.zeros((4, 1, 4), jnp.bfloat16)), labels)
     assert out.data.dtype == jnp.float32
-
-
-@pytest.mark.parametrize("attention", ["dense", "kernel"])
-def test_recomputation_changes_neither_loss_nor_gradients(attention):
-    """Whatever a recomputed segment keeps: with XLA's dense attention
-    its inputs alone, with the kernels (forced: 128 is their tile, and
-    off a TPU they run in interpret mode) their ``out`` and ``lse`` too."""
-    ids = _ids(seq=128 if attention == "kernel" else 12)
-    got = {}
-    with flags_guard(
-            flash_attention="always" if attention == "kernel" else "never"):
-        for remat in (False, True):
-            model, _, _ = _model()
-            model.layers.enable_recompute = remat
-            loss = _loss(model, ids)
-            loss.backward()
-            got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
-                                        model.named_parameters()})
-        assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
-        for k, g in got[False][1].items():
-            np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
-                                       atol=1e-6 * np.abs(g).max())
-        # and in evaluation nothing is recomputed
-        model.eval()
-        assert float(_loss(model, ids)) == pytest.approx(got[False][0],
-                                                         rel=1e-6)
 
 
 def _kernels(jaxpr):
@@ -384,35 +391,13 @@ def test_a_recomputed_segment_keeps_the_kernels_outputs_and_no_more(
 
 # -- through the engine -----------------------------------------------------
 
-@pytest.fixture
-def _fresh_obs():
-    obs.reset_process_registry()
-    obs.hbm.reset()
-    yield
-    obs.reset_process_registry()
-    obs.hbm.reset()
-
-
-def _engine(recompute, amp=None):
-    model, _, _ = _model()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                 parameters=model.parameters())
-    crit = OuroPretrainingCriterion(0.1)
-
-    def loss_fn(m, b):
-        ids = Tensor(b["ids"])
-        labels = m.next_token_labels(ids)
-        return crit(*m(ids, labels), labels)
-    return ParallelEngine(model, opt, loss_fn, amp_dtype=amp,
-                          mesh=build_mesh(dp=1, devices=jax.devices()[:1]),
-                          recompute=recompute)
-
-
 def test_the_engine_switches_recomputation_on_and_the_step_is_the_same():
+    """Two loop steps: the switch is what the case is about, and each
+    side's step is compiled twice (ISSUE 46)."""
     batch = {"ids": _ids()}
     seen = {}
     for remat in (False, True):
-        engine = _engine(remat)
+        engine = _reference(total_ut_steps=2).engine(recompute=remat)
         assert engine.model.layers.enable_recompute is remat
         loss = float(engine.step(engine.shard_batch(batch), lr=1e-3))
         first = {k: np.asarray(v["moment1"])
@@ -426,10 +411,10 @@ def test_the_engine_switches_recomputation_on_and_the_step_is_the_same():
     assert "rematted_computation" not in seen[False][2]
 
 
-def test_the_loop_and_the_heads_have_scopes_of_their_own(_fresh_obs):
+def test_the_loop_and_the_heads_have_scopes_of_their_own(reference, fresh_obs):
     """Loop step t's copy of the stack under ``ut_step/<t>``; the heads,
     the gate and the exit loss under ``exit_head``; nothing under both."""
-    engine = _engine(True, amp="bfloat16")
+    engine = reference.engine(amp="bfloat16")
     float(engine.step(engine.shard_batch({"ids": _ids()}), lr=1e-3))
     scopes = costmodel.step_op_scopes()
     named = [s for s in scopes.values() if "jvp(loss)" in s]

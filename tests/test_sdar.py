@@ -21,18 +21,24 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 import paddle1_tpu as paddle  # noqa: E402
+from benchmarks.programs import sdar_30b_a3b as program  # noqa: E402
+from benchmarks.reference import kanana2_30b_a3b as blocks_of  # noqa: E402
 from benchmarks.reference import sdar_30b_a3b as ref  # noqa: E402
-from benchmarks.reference.numerics import Numerics  # noqa: E402
+from decoder_cases import (  # noqa: E402,F401
+    Decoder, decoder, eager_run, fresh_obs, reference,
+    test_a_step_trains_and_carries_the_scopes_and_the_counters,
+    test_recomputation_changes_neither_loss_nor_gradients,
+    test_the_eight_shares_add_up_to_the_whole_layer,
+    test_the_model_follows_the_reference,
+    test_the_reference_in_blocks_is_the_reference)
 from paddle1_tpu import nn, obs  # noqa: E402
 from paddle1_tpu.core.flags import flags_guard  # noqa: E402
 from paddle1_tpu.core.tensor import Tensor  # noqa: E402
-from paddle1_tpu.distributed import ParallelEngine, build_mesh  # noqa: E402
 from paddle1_tpu.framework.param_attr import ParamAttr  # noqa: E402
 from paddle1_tpu.nn import functional as F  # noqa: E402
 from paddle1_tpu.nn import layer_moe  # noqa: E402
 from paddle1_tpu.nn.functional.attention import attention_ref  # noqa: E402
 from paddle1_tpu.nn.initializer import Normal  # noqa: E402
-from paddle1_tpu.obs import costmodel  # noqa: E402
 from paddle1_tpu.obs.registry import process_group  # noqa: E402
 from paddle1_tpu.ops.pallas import flash_attention as fa  # noqa: E402
 from paddle1_tpu.ops.pallas import mask_rules, sum_picks  # noqa: E402
@@ -51,7 +57,6 @@ CFG = {"vocab_size": 96, "hidden_size": 128, "num_hidden_layers": 2,
        "rms_norm_eps": 1e-6, "initializer_range": 0.2,
        "residual_initializer_range": 0.2,
        "embedding_initializer_range": 0.2, "mask_route_logit": 8.0}
-NM = Numerics()
 
 
 # -- the mask rule ----------------------------------------------------------
@@ -201,16 +206,7 @@ def test_one_block_and_no_mask_count_their_tiles():
         assert np.bincount(table.q).max() == most
 
 
-@pytest.fixture
-def _fresh_obs():
-    obs.reset_process_registry()
-    obs.hbm.reset()
-    yield
-    obs.reset_process_registry()
-    obs.hbm.reset()
-
-
-def test_a_lowered_kernel_call_counts_its_tiles(_fresh_obs):
+def test_a_lowered_kernel_call_counts_its_tiles(fresh_obs):
     """``flash_tiles_total{kind}``: the forward kernel's tiles of one
     lowered call, times its batch x heads; a wrong rule shows here."""
     length = 256
@@ -246,7 +242,7 @@ def test_grouped_heads_are_keys_and_values_repeated_eight_times(mask):
     _close(got, want, tol=1e-5)
 
 
-def test_sdpa_hands_the_rule_and_the_grouped_heads_to_the_kernels(_fresh_obs):
+def test_sdpa_hands_the_rule_and_the_grouped_heads_to_the_kernels(fresh_obs):
     length = 128
     rule = BlockDiffusion(length, 4)
     q, k, v, _ = _qkv(2 * length, 4, 2, 64, seed=4)
@@ -345,7 +341,7 @@ def test_the_parents_kernels_lower_to_the_text_they_had(shape, which):
 
 
 def test_a_traced_call_at_the_cells_size_counts_two_kernels_tiles(
-        _fresh_obs):
+        fresh_obs):
     """One forward + backward call at the cell's size ([1, 2 x 8192, 32 /
     4, 128], blocks of 4), traced alone: ``flash_tiles_total`` holds two
     kernels' tiles by the closed form, 240 plain + 48 masked of 1,024 a
@@ -491,36 +487,6 @@ def test_the_kernel_that_sums_picks_takes_a_fan_of_eight(dtype):
                                atol=1e-6)
 
 
-def test_the_eight_shares_add_up_to_the_whole_layer():
-    """The share test under the softmax rule: with the same weights, the
-    routed outputs of the eight shares (2 experts of 16 each) are the
-    uncut layer's, which is the reference's uncut expert layer."""
-    tokens, width, total = 48, 8, 16
-    whole, x = _experts(tokens, total, 8, None)
-    want = whole(Tensor(x)).numpy()
-    cfg = {"num_experts": total, "expert_parallel": 1, "expert_rank": 0,
-           "num_experts_per_tok": 8, "moe_intermediate_size": width}
-    lp = {"router": whole.router.data, "e_gate_up": whole.gate_up_proj.data,
-          "e_down": whole.down_proj.data}
-    np.testing.assert_allclose(want, ref.experts(jnp.asarray(x), lp, cfg, NM),
-                               rtol=1e-4, atol=1e-5)
-    parts = np.zeros_like(want)
-    for rank in range(8):
-        share, _ = _experts(tokens, total, 8, (2 * rank, 2))
-        share.router.data = whole.router.data
-        share.gate_up_proj.data = whole.gate_up_proj.data[2 * rank:][:2]
-        share.down_proj.data = whole.down_proj.data[2 * rank:][:2]
-        part = share(Tensor(x)).numpy()
-        # what a share computes is what the reference gives that share
-        np.testing.assert_allclose(part, ref.experts(
-            jnp.asarray(x), {**lp, "e_gate_up": share.gate_up_proj.data,
-                             "e_down": share.down_proj.data},
-            {**cfg, "num_experts": 2, "expert_parallel": 8,
-             "expert_rank": rank}, NM), rtol=1e-4, atol=1e-5)
-        parts += part
-    np.testing.assert_allclose(parts, want, rtol=1e-4, atol=1e-5)
-
-
 def test_the_mask_row_is_spread_over_the_shares():
     """Half the noisy copy is one row, so the reference's weights send it
     to ``top_k / expert_parallel`` experts of every share in every layer,
@@ -554,13 +520,9 @@ def test_the_mask_row_is_spread_over_the_shares():
 
 # -- the model against the reference ----------------------------------------
 
-def _model(cfg=CFG):
-    """(the Layer, the reference's weights it was loaded with)."""
-    from benchmarks.programs import load_weights
-    from benchmarks.programs import sdar_30b_a3b as program
-    weights = ref.init_params(cfg, jax.random.key(4))
+def _build(cfg):
     held = cfg["num_experts"]
-    model = SdarForBlockDiffusion(
+    return SdarForBlockDiffusion(
         num_experts=held * cfg["expert_parallel"],
         held_experts=(cfg["expert_rank"] * held, held),
         **{k: cfg[k] for k in (
@@ -569,8 +531,6 @@ def _model(cfg=CFG):
             "moe_intermediate_size", "num_experts_per_tok", "block_length",
             "mask_token_id", "noise_eps", "rope_theta", "rms_norm_eps",
             "initializer_range")})
-    load_weights(model, {p: weights[r] for p, r, _ in program.leaves(cfg)})
-    return model, weights
 
 
 def _batch(batch=2, seq=16, seed=0):
@@ -587,8 +547,58 @@ def _loss(model, batch):
         Tensor(batch["ids"]), Tensor(batch["level"]), Tensor(batch["draw"])))
 
 
-def test_the_noisy_copy_and_the_weights_by_hand():
-    model, _ = _model()
+def _two_rows_of_losses_and_weights(model, weights, batch):
+    token_losses, weights_ = model(*(Tensor(batch[k])
+                                     for k in ("ids", "level", "draw")))
+    assert token_losses.shape == [2, 16] and weights_.shape == [2, 16]
+
+
+def _the_steps_own_counters(engine):
+    # 2 x 128 positions, one 128 x 128 tile a quadrant: noisy-noisy,
+    # noisy-clean and clean-clean crossed, clean-noisy skipped; 2 rows x 4
+    # heads a traced kernel call (the two kernels, and the forward once
+    # more inside the recomputed segment, where its kept outputs spare it)
+    kinds = process_group("kind")
+    tiles = {kind: kinds.child(kind).counter("flash_tiles_total").value
+             for kind in ("plain", "masked", "skipped")}
+    assert tiles["plain"] == 0 and tiles["skipped"] % 8 == 0
+    assert tiles["masked"] == 3 * tiles["skipped"] >= 3 * 24
+
+
+def _the_steps_own_scopes(engine, named):
+    assert any(s.endswith("SdarForBlockDiffusion/block_noise")
+               or "/SdarForBlockDiffusion/block_noise/" in s for s in named)
+    for i in range(CFG["num_hidden_layers"]):
+        at = f"/layers/recompute/{i}/self_attn/"
+        for op in ("q_proj/linear", "k_proj/linear", "v_proj/linear",
+                   "q_norm/rms_norm", "k_norm/rms_norm", "rotary_embedding",
+                   "scaled_dot_product_attention", "o_proj/linear"):
+            assert any(at + op in s for s in named), (i, op)
+        at = f"/layers/recompute/{i}/mlp/moe/"
+        for op in ("moe_router", "moe_dispatch", "routed_experts",
+                   "moe_combine"):
+            assert any(at + op in s for s in named), (i, op)
+    assert any("/diffusion_loss" in s for s in named)
+
+
+# the shared cases' model (decoder_cases.py). The reference in blocks: 16
+# of a doubled row's 32 positions through a layer at a time; one key/value
+# head and 8 of those queries a block of scores; two experts a group; a row
+# a block. The share test: 2 experts of 16 each, top-8, the softmax rule.
+DECODER = Decoder(
+    cfg=CFG, ref=ref, program=program, build=_build, criterion=_loss,
+    batch=_batch, follows_also=_two_rows_of_losses_and_weights,
+    blocks_seq=16, scans=5, blocks=(
+        (ref, "ROW_BLOCK_POSITIONS", 16),
+        (ref, "SCORE_BLOCK_BYTES", 4 * 32 * 2 * 8),
+        (ref, "EXPERTS_BLOCK_BYTES", 4 * 16 * 128 * 2),
+        (blocks_of, "BLOCK_TOKENS", 16)),
+    step_counters=_the_steps_own_counters, step_scopes=_the_steps_own_scopes,
+    shares={"experts": _experts, "total": 16, "top_k": 8})
+
+
+def test_the_noisy_copy_and_the_weights_by_hand(reference):
+    model = reference.model()
     ids = np.arange(8, dtype=np.int32)[None] + 10
     level = np.array([[0.0, 1.0]], np.float32)      # t = 0.001 and 1
     draw = np.array([[0.5, 0.0005, 0.5, 0.5, 0.9, 0.0, 0.5, 0.999]],
@@ -602,134 +612,3 @@ def test_the_noisy_copy_and_the_weights_by_hand():
     assert labels.tolist() == [[-100, 11, -100, -100, 14, 15, 16, 17]]
     np.testing.assert_allclose(weights, [[0, 1000, 0, 0, 1, 1, 1, 1]],
                                rtol=1e-5)
-
-
-def test_the_model_follows_the_reference():
-    model, weights = _model()
-    batch = _batch()
-    loss = _loss(model, batch)
-    want, grads = jax.value_and_grad(
-        lambda w: ref.loss(w, {k: jnp.asarray(v) for k, v in batch.items()},
-                           CFG, NM)[0])(weights)
-    assert float(loss) == pytest.approx(float(want), rel=2e-5)
-    loss.backward()
-    from benchmarks.programs import sdar_30b_a3b as program
-    named = dict(model.named_parameters())
-    for p, r, _ in program.leaves(CFG):
-        g, w = named[p].grad.numpy(), np.asarray(grads[r])
-        assert np.linalg.norm(g - w) <= 2e-4 * max(np.linalg.norm(w), 1e-4), p
-    token_losses, weights_ = model(*(Tensor(batch[k])
-                                     for k in ("ids", "level", "draw")))
-    assert token_losses.shape == [2, 16] and weights_.shape == [2, 16]
-
-
-def test_the_reference_in_blocks_is_the_reference(monkeypatch):
-    """At the cell's size the reference takes a row's positions through a
-    layer in blocks, and within one the key/value heads, blocks of a
-    group's queries and groups of the held experts one at a time through
-    ``lax.map``; at a test's size it takes each whole."""
-    from benchmarks.reference import kanana2_30b_a3b as blocks_of
-    weights = ref.init_params(CFG, jax.random.key(4))
-    batch = {k: jnp.asarray(v) for k, v in _batch().items()}
-
-    def run():
-        return jax.value_and_grad(
-            lambda w: ref.loss(w, batch, CFG, NM)[0])(weights)
-    whole, g_whole = run()
-    # 16 of a doubled row's 32 positions through a layer at a time; one
-    # key/value head and 8 of those queries a block of scores; two experts
-    # a group; a row a block
-    monkeypatch.setattr(ref, "ROW_BLOCK_POSITIONS", 16)
-    monkeypatch.setattr(ref, "SCORE_BLOCK_BYTES", 4 * 32 * 2 * 8)
-    monkeypatch.setattr(ref, "EXPERTS_BLOCK_BYTES", 4 * 16 * 128 * 2)
-    monkeypatch.setattr(blocks_of, "BLOCK_TOKENS", 16)
-    text = str(jax.make_jaxpr(lambda w: ref.loss(w, batch, CFG, NM)[0])(
-        weights))
-    assert text.count("scan") >= 5
-    blocks, g_blocks = run()
-    assert float(blocks) == pytest.approx(float(whole), rel=1e-5)
-    for k in g_whole:
-        a, b = np.asarray(g_blocks[k]), np.asarray(g_whole[k])
-        assert np.linalg.norm(a - b) <= 1e-5 * max(np.linalg.norm(b), 1e-3), k
-
-
-@pytest.mark.parametrize("attention", ["dense", "kernel"])
-def test_recomputation_changes_neither_loss_nor_gradients(attention):
-    batch = _batch(seq=128 if attention == "kernel" else 16)
-    got = {}
-    with flags_guard(
-            flash_attention="always" if attention == "kernel" else "never"):
-        for remat in (False, True):
-            model, _ = _model()
-            model.layers.enable_recompute = remat
-            loss = _loss(model, batch)
-            loss.backward()
-            got[remat] = (float(loss), {k: p.grad.numpy() for k, p in
-                                        model.named_parameters()})
-    assert got[True][0] == pytest.approx(got[False][0], rel=1e-6)
-    for k, g in got[False][1].items():
-        np.testing.assert_allclose(got[True][1][k], g, rtol=1e-4,
-                                   atol=1e-6 * np.abs(g).max())
-
-
-def _engine(amp=None):
-    model, _ = _model()
-    opt = paddle.optimizer.AdamW(learning_rate=1e-2, weight_decay=0.1,
-                                 parameters=model.parameters())
-    crit = SdarBlockDiffusionCriterion()
-
-    def loss_fn(m, b):
-        return crit(*m(Tensor(b["ids"]), Tensor(b["level"]),
-                       Tensor(b["draw"])))
-    return ParallelEngine(model, opt, loss_fn, amp_dtype=amp,
-                          mesh=build_mesh(dp=1, devices=jax.devices()[:1]),
-                          recompute=True)
-
-
-def test_a_step_trains_and_carries_the_scopes_and_the_counters(_fresh_obs):
-    engine = _engine(amp="bfloat16")
-    assert engine.model.layers.enable_recompute
-    batch = engine.shard_batch(_batch(seq=128))
-    with flags_guard(flash_attention="always"):
-        losses = [float(engine.step(batch, lr=1e-2)) for _ in range(3)]
-        # the counters of the one lowering: the arm, and the tiles of each
-        # lowered kernel
-        arms, kinds = process_group("arm"), process_group("kind")
-        assert arms.child("flash").counter("attention_arm_total").value >= 1
-        assert arms.child("dense").counter("attention_arm_total").value == 0
-        tiles = {kind: kinds.child(kind).counter("flash_tiles_total").value
-                 for kind in ("plain", "masked", "skipped")}
-        scopes = costmodel.step_op_scopes()
-        text = engine.compiled_step_text()
-    assert losses[2] < losses[0]
-    # 2 x 128 positions, one 128 x 128 tile a quadrant: noisy-noisy,
-    # noisy-clean and clean-clean crossed, clean-noisy skipped; 2 rows x 4
-    # heads a traced kernel call (the two kernels, and the forward once
-    # more inside the recomputed segment, where its kept outputs spare it)
-    assert tiles["plain"] == 0 and tiles["skipped"] % 8 == 0
-    assert tiles["masked"] == 3 * tiles["skipped"] >= 3 * 24
-    named = [s for s in scopes.values() if "jvp(loss)" in s]
-    assert any(s.endswith("SdarForBlockDiffusion/block_noise")
-               or "/SdarForBlockDiffusion/block_noise/" in s for s in named)
-    for i in range(CFG["num_hidden_layers"]):
-        at = f"/layers/recompute/{i}/self_attn/"
-        for op in ("q_proj/linear", "k_proj/linear", "v_proj/linear",
-                   "q_norm/rms_norm", "k_norm/rms_norm", "rotary_embedding",
-                   "scaled_dot_product_attention", "o_proj/linear"):
-            assert any(at + op in s for s in named), (i, op)
-        at = f"/layers/recompute/{i}/mlp/moe/"
-        for op in ("moe_router", "moe_dispatch", "routed_experts",
-                   "moe_combine"):
-            assert any(at + op in s for s in named), (i, op)
-    assert not [s for s in named if "shared_experts" in s]
-    assert any("/lm_head/head_cross_entropy" in s for s in named)
-    assert any("/diffusion_loss" in s for s in named)
-    # the two kernels under the attention op, the forward not run again
-    kernels = [s for s in named if "p1t_flash_attention" in s]
-    assert kernels and all("/scaled_dot_product_attention/" in s for s in kernels)
-    assert not [s for s in kernels if "/rematted_computation/" in s
-                and "p1t_flash_attention_fwd" in s]
-    # the router is a float32 island under the bf16 autocast
-    router = [l for l in text.splitlines()
-              if "moe_router" in l and " dot(" in l]
-    assert router and all(" f32[" in l.split(" dot(")[0] for l in router)
