@@ -14,7 +14,10 @@ blocks, the causal rule runs with 32 / 8 heads at head width 64 over LFM2's
 16,384 positions and the two gated short-convolution kernels run at its
 size against float32 shifted sums, the sliding-window rule (4,096 keys)
 runs with 28 / 4 heads at SmallThinker's size against a float32 dense
-band in blocks, and the routed-expert layer takes more
+band in blocks and again with a window of one query block (512 keys) and
+64 / 8 heads beside the causal rule's 48 / 8 at Laguna-XS.2's, where
+rotary positions over half a head under YaRN's table are held to their
+slices in float32, and the routed-expert layer takes more
 held picks than its grouped products have rows and counts the late ones
 on the device, eagerly and in two compiled steps
 (``ParallelEngine.expert_load()``). Every
@@ -506,22 +509,23 @@ def lfm2_phase(seq=16384, heads=32, kv_heads=8, dim=64, channels=2048,
 
 
 def sliding_window_phase(seq=16384, heads=28, kv_heads=4, dim=128,
-                         window=4096):
+                         window=4096, causal_heads=None):
     """The blockwise kernels under the sliding window's rule, a band of
-    4,096 keys under the diagonal, with query heads in groups of 7 at
-    SmallThinker-21BA3B's size ([1, 16384, 28 / 4, 128] bf16) against a
-    float32 dense band in blocks; then a call's forward + backward beside
-    the causal rule's at that shape, on the host's clock."""
+    ``window`` keys under the diagonal, with query heads in groups at a
+    model's size against a float32 dense band in blocks, then the causal
+    rule (with ``causal_heads`` query heads where a model's global layers
+    have a count of their own); a call's forward + backward of each on
+    the host's clock. The defaults are SmallThinker-21BA3B's ([1, 16384,
+    28 / 4, 128] bf16, groups of 7, 4,096 keys)."""
     import jax
     import jax.numpy as jnp
     bf16 = jnp.bfloat16
-    keys = jax.random.split(jax.random.key(43), 4)
-    q, k, v, dout = (jax.random.normal(kk, (1, seq, h, dim), bf16)
-                     for kk, h in zip(keys, (heads, kv_heads, kv_heads,
-                                             heads)))
-    shape = f"[1, {seq}, {heads}/{kv_heads}, {dim}]"
-    for at, w in ((f"{shape} window {window}", window),
-                  (f"{shape} causal", None)):
+    for n, w in ((heads, window), (causal_heads or heads, None)):
+        keys = jax.random.split(jax.random.key(43), 4)
+        q, k, v, dout = (jax.random.normal(kk, (1, seq, h, dim), bf16)
+                         for kk, h in zip(keys, (n, kv_heads, kv_heads, n)))
+        at = f"[1, {seq}, {n}/{kv_heads}, {dim}] " + (
+            f"window {w}" if w else "causal")
         got = kernels_against_blocks(at, q, k, v, dout, w)
         t = time.perf_counter()
         for _ in range(5):
@@ -530,6 +534,42 @@ def sliding_window_phase(seq=16384, heads=28, kv_heads=4, dim=128,
         print(f"chip_smoke: flash forward + backward {at}: "
               f"{200 * (time.perf_counter() - t):.3f} ms a call (smoke "
               "reading on the host's clock, not a metric)", flush=True)
+
+
+def partial_rotary_phase(seq=16384, heads=48, dim=128):
+    """``F.rotary_embedding`` over half a head under YaRN's table and its
+    factor at Laguna-XS.2's size ([1, 16384, 48, 128] bf16), result and
+    gradient against the slices and the concatenation in float32."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.autograd import engine as ae
+    from paddle1_tpu.core.tensor import Tensor
+    from paddle1_tpu.nn import functional as F
+    table = F.yarn_frequencies(dim // 2, 500000.0, 64.0, 4096, 64.0, 1.0)
+    scale = 1.4158883083359672
+    x, g = (jax.random.normal(k, (1, seq, heads, dim), jnp.bfloat16)
+            for k in jax.random.split(jax.random.key(47)))
+
+    def op(x):
+        with ae.no_grad():
+            return F.rotary_embedding(Tensor(x), frequencies=table,
+                                      scale=scale).data
+
+    def plain(x):
+        half = dim // 4
+        angle = jnp.arange(seq, dtype=jnp.float32)[:, None, None] \
+            * jnp.asarray(table, jnp.float32)
+        cos, sin = scale * jnp.cos(angle), scale * jnp.sin(angle)
+        a, b, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest],
+                               -1)
+    at = f"[1, {seq}, {heads}, {dim}] span {dim // 2}, YaRN"
+    got, want = vjp_of(op)(x, g), vjp_of(plain)(
+        x.astype(jnp.float32), g.astype(jnp.float32))
+    for name, a, b in zip(("out", "dx"), got, want):
+        err = max_err(a, b) / float(np.max(np.abs(np.asarray(b))))
+        check(err <= 1e-2, f"rotary {at} {name}: max abs err / max |ref| = "
+                           f"{err:.2e} <= 1e-2")
 
 
 def experts_phase(tokens=4096, hidden=512, width=256):
@@ -691,6 +731,11 @@ def main():
         block_diffusion_phase()
         lfm2_phase()
         sliding_window_phase()
+        # Laguna-XS.2: a window of one query block with heads in groups
+        # of 8, the causal rule in groups of 6, rotary over half a head
+        sliding_window_phase(heads=64, kv_heads=8, window=512,
+                             causal_heads=48)
+        partial_rotary_phase()
         experts_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {

@@ -20,7 +20,7 @@ from ...autograd.engine import apply
 from ...core.tensor import Tensor, to_tensor
 
 __all__ = ["scaled_dot_product_attention", "attention_ref",
-           "paged_attention", "rotary_embedding"]
+           "paged_attention", "rotary_embedding", "yarn_frequencies"]
 
 
 def _t(x):
@@ -66,15 +66,24 @@ def attention_ref(q, k, v, mask=None, dropout_p=0.0, scale=None,
     return jnp.swapaxes(out, 1, 2)
 
 
-def _turn(x, at, theta, interleaved, back):
+def _turn(x, at, theta, interleaved, frequencies, scale, back):
     """``x * C + (x @ P) * S`` over the last axis, or with ``back`` the
-    same pass with ``S`` negated, which is its transpose."""
+    same pass with ``S`` negated, which is its transpose. The turned span
+    is twice the table's length (the whole head from ``theta``); on the
+    channels behind it ``C`` is 1, ``S`` is 0 and ``P`` has no entry."""
     batch, seq, heads, d = x.shape
-    half = d // 2
-    inv_freq = jnp.float32(theta) ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
+    if frequencies is None:
+        half = d // 2
+        inv_freq = jnp.float32(theta) ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        half = len(frequencies)
+        inv_freq = jnp.asarray(frequencies, jnp.float32)
+    span = 2 * half
     angle = at.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)   # [(batch,) seq, 1, half]
+    if scale != 1.0:
+        cos, sin = jnp.float32(scale) * cos, jnp.float32(scale) * sin
     if back:
         sin = -sin
     lane = jnp.arange(d)
@@ -85,8 +94,14 @@ def _turn(x, at, theta, interleaved, back):
     else:
         cos_d = jnp.concatenate([cos, cos], axis=-1)
         sin_d = jnp.concatenate([-sin, sin], axis=-1)
-        source = (lane + half) % d
-    swap = (lane[:, None] == source[None, :]).astype(x.dtype)
+        source = (lane + half) % span
+    swap = lane[:, None] == source[None, :]
+    if span < d:    # the channels that pass: a one, a zero, no partner
+        rest = cos_d.shape[:-1] + (d - span,)
+        cos_d = jnp.concatenate([cos_d, jnp.ones(rest, jnp.float32)], -1)
+        sin_d = jnp.concatenate([sin_d, jnp.zeros(rest, jnp.float32)], -1)
+        swap = swap & (lane < span)
+    swap = swap.astype(x.dtype)
     # ones and zeros: exact in bf16 at the MXU's one bf16 precision, which
     # is named so that no process-wide default can ask for another; a
     # float32 x would be rounded to bf16 by a TPU's default
@@ -106,26 +121,54 @@ def _turn(x, at, theta, interleaved, back):
             + partner * sin_d).astype(x.dtype).reshape(x.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _rotary(x, at, theta, interleaved):
-    return _turn(x, at, theta, interleaved, back=False)
+# (theta, interleaved, frequencies, scale): static, so hashable
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rotary(x, at, how):
+    return _turn(x, at, *how, back=False)
 
 
-def _rotary_fwd(x, at, theta, interleaved):
-    return _turn(x, at, theta, interleaved, back=False), at
+def _rotary_fwd(x, at, how):
+    return _turn(x, at, *how, back=False), at
 
 
-def _rotary_bwd(theta, interleaved, at, g):
+def _rotary_bwd(how, at, g):
     dat = (jnp.zeros_like(at) if jnp.issubdtype(at.dtype, jnp.floating)
            else np.zeros(at.shape, jax.dtypes.float0))
-    return _turn(g, at, theta, interleaved, back=True), dat
+    return _turn(g, at, *how, back=True), dat
 
 
 _rotary.defvjp(_rotary_fwd, _rotary_bwd)
 
 
+def yarn_frequencies(dim, theta, factor, original_max_position_embeddings,
+                     beta_fast=32.0, beta_slow=1.0):
+    """YaRN's table (Peng et al. 2023, arXiv:2309.00071) for a turned span
+    of ``dim`` channels, ``dim / 2`` frequencies for
+    :func:`rotary_embedding`'s ``frequencies``: pair ``i`` keeps ``b_i =
+    theta ** (-2 i / dim)`` where it turns more than ``beta_fast`` times
+    over the original context, is slowed ``factor`` times where it turns
+    less than ``beta_slow`` times, and is blended linearly in ``i``
+    between: ``f_i = (1 - m_i) b_i / factor + m_i b_i``, ``m_i = 1 -
+    clip((i - lo) / (hi - lo), 0, 1)``, ``lo = floor(c(beta_fast))``,
+    ``hi = ceil(c(beta_slow))`` clipped to ``[0, dim - 1]``, ``c(r) = dim
+    ln(original / (2 pi r)) / (2 ln theta)``. Host arithmetic in float64;
+    the ``attention_factor`` a configuration publishes beside them goes
+    in as ``scale``."""
+    half = dim // 2
+    base = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
+
+    def turns_at(r):
+        return dim * np.log(original_max_position_embeddings
+                            / (2 * np.pi * r)) / (2 * np.log(float(theta)))
+    lo = max(int(np.floor(turns_at(beta_fast))), 0)
+    hi = min(int(np.ceil(turns_at(beta_slow))), dim - 1)
+    # a ramp of no length is a step
+    keeps = 1 - np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return (1 - keeps) * base / factor + keeps * base
+
+
 def rotary_embedding(x, theta=10000.0, positions=None, interleaved=False,
-                     name=None):
+                     frequencies=None, scale=1.0, name=None):
     """Rotary positions (Su et al. 2021, arXiv:2104.09864) on a
     [batch, seq, heads, dim] query or key. Pair ``i`` turns by the angle
     ``position * theta ** (-2 i / dim)``; rotate-half pairing makes it of
@@ -135,13 +178,24 @@ def rotary_embedding(x, theta=10000.0, positions=None, interleaved=False,
     [seq] or [batch, seq] integers, ``0..seq-1`` when None; no gradient
     reaches them. The result has ``x``'s dtype.
 
+    ``frequencies``: a table of its own in ``theta``'s place, one angle a
+    position a pair (:func:`yarn_frequencies` makes YaRN's); **its length
+    is the span that turns**, the first ``2 * len(frequencies)`` channels
+    paired among themselves (halves of the span, or ``2i`` and ``2i +
+    1``), and the channels behind it pass as they are
+    (``partial_rotary_factor``). ``scale`` multiplies cos and sin alike
+    (YaRN's ``attention_factor``), so a turned channel grows by it and a
+    passing one does not.
+
     One pass at the full head width: ``y = x * C + (x @ P) * S``, with
     ``C`` the cosines laid under both channels of a pair, ``S`` the sines
     with the minus sign on the pair's first channel, and ``P`` the 0 / 1
     matrix that hands each channel its partner. A slice or a
     concatenation at half the width is no elementwise op on a TPU (64 of
     128 lanes) and left float32 halves of q and k in HBM; a product with
-    ``P`` fuses. Float32 whatever ``x`` is: the angles, ``C`` and ``S``,
+    ``P`` fuses. A span narrower than the head is the same pass: ``C`` 1
+    and ``S`` 0 on the channels that pass, ``P`` pairing inside the span
+    alone. Float32 whatever ``x`` is: the angles, ``C`` and ``S``,
     ``x @ P`` (a sum of one value and zeros, so exact), both products
     and their sum; the one rounding is the last cast. The backward is
     written by hand: ``P`` is its own inverse and swaps ``S``'s signs,
@@ -152,9 +206,16 @@ def rotary_embedding(x, theta=10000.0, positions=None, interleaved=False,
     x = _t(x)
     at = (_t(positions) if positions is not None
           else jnp.arange(x.shape[1], dtype=jnp.int32))
+    if frequencies is not None:
+        frequencies = tuple(float(f) for f in frequencies)
+        if not 0 < 2 * len(frequencies) <= x.shape[-1]:
+            raise ValueError(f"{len(frequencies)} frequencies turn "
+                             f"{2 * len(frequencies)} channels of a head "
+                             f"of {x.shape[-1]}")
+    how = (theta, interleaved, frequencies, float(scale))
 
     def f(x, at):
-        return _rotary(x, at, theta, interleaved)
+        return _rotary(x, at, how)
     return apply("rotary_embedding", f, (x, at))
 
 

@@ -7,6 +7,8 @@ from .bert import (BertForPretraining, BertForSequenceClassification,
 from .kanana2 import (Kanana2DecoderLayer, Kanana2ForPretraining,
                       Kanana2Head, Kanana2PretrainingCriterion, Kanana2Stack,
                       LatentAttention)
+from .laguna import (LagunaAttention, LagunaDecoderLayer,
+                     LagunaForPretraining, LagunaPretrainingCriterion)
 from .lfm2 import (Lfm2Attention, Lfm2DecoderLayer, Lfm2ForPretraining,
                    Lfm2Head, Lfm2PretrainingCriterion, Lfm2Stack)
 from .ouro import (OuroDecoderLayer, OuroExitHead, OuroForPretraining,
@@ -30,4 +32,6 @@ __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "Lfm2DecoderLayer", "Lfm2Stack", "Lfm2Head", "Lfm2ForPretraining",
            "Lfm2PretrainingCriterion", "SmallThinkerAttention",
            "SmallThinkerDecoderLayer", "SmallThinkerForPretraining",
-           "SmallThinkerPretrainingCriterion"]
+           "SmallThinkerPretrainingCriterion", "LagunaAttention",
+           "LagunaDecoderLayer", "LagunaForPretraining",
+           "LagunaPretrainingCriterion"]

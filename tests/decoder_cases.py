@@ -100,6 +100,8 @@ class Decoder:
     step_scopes: Callable = _nothing_more
     # the share test: see test_the_eight_shares_add_up_to_the_whole_layer
     shares: dict = None
+    # an expert layer of the model has a shared expert beside the routed
+    shared_experts: bool = False
 
     def weights(self, cfg):
         if self.draw is not None:
@@ -311,7 +313,8 @@ def test_the_reference_in_blocks_is_the_reference(decoder, reference,
     assert float(blocks) == pytest.approx(float(whole), rel=1e-5)
     for k in g_whole:
         a, b = np.asarray(g_blocks[k]), np.asarray(g_whole[k])
-        assert np.linalg.norm(a - b) <= 1e-5 * max(np.linalg.norm(b), 1e-3), k
+        assert np.linalg.norm(a - b) <= 1e-5 * max(np.linalg.norm(b), 1e-3), (
+            k, np.linalg.norm(a - b), np.linalg.norm(b))
 
 
 @pytest.mark.parametrize("attention", ["dense", "kernel"])
@@ -411,7 +414,8 @@ def test_a_step_trains_and_carries_the_scopes_and_the_counters(
         text = engine.compiled_step_text()
     assert losses[2] < losses[0]
     decoder.step_scopes(engine, named)
-    assert not [s for s in named if "shared_experts" in s]
+    assert bool([s for s in named if "/moe/shared_experts/" in s]) \
+        == decoder.shared_experts
     assert any("/lm_head/head_cross_entropy" in s for s in named)
     # the two kernels under the attention op, the forward not run again
     kernels = [s for s in named if "p1t_flash_attention" in s]

@@ -89,22 +89,36 @@ class OpTest(unittest.TestCase):
         return grad.reshape(base[idx].shape)
 
 
-def rotary_by_halves(x, theta, at, interleaved):
+def rotary_by_halves(x, theta, at, interleaved, frequencies=None, scale=1.0):
     """The plain reference of ``F.rotary_embedding``: the op as it stood
     until ISSUE 45, a slice at half the head width (or a view in pairs)
     and a concatenation, in float32, differentiated by ``jax.grad``.
-    ``x`` [batch, seq, heads, dim], ``at`` [seq] or [batch, seq]."""
-    half = x.shape[-1] // 2
-    inv_freq = jnp.float32(theta) ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
+    ``x`` [batch, seq, heads, dim], ``at`` [seq] or [batch, seq].
+    ``frequencies`` in ``theta``'s place turn the first ``2 *
+    len(frequencies)`` channels, paired among themselves, and the others
+    pass; ``scale`` multiplies cos and sin (ISSUE 47)."""
+    if frequencies is None:
+        half = x.shape[-1] // 2
+        inv_freq = jnp.float32(theta) ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = jnp.asarray(frequencies, jnp.float32)
+        half = inv_freq.shape[0]
     angle = at.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scale != 1.0:
+        cos, sin = jnp.float32(scale) * cos, jnp.float32(scale) * sin
     xf = x.astype(jnp.float32)
+    turned, rest = xf[..., :2 * half], xf[..., 2 * half:]
     if interleaved:
-        pairs = xf.reshape(xf.shape[:-1] + (half, 2))
+        pairs = turned.reshape(turned.shape[:-1] + (half, 2))
         a, b = pairs[..., 0], pairs[..., 1]
-        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                         axis=-1).reshape(x.shape).astype(x.dtype)
-    a, b = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                        axis=-1).reshape(turned.shape)
+    else:
+        a, b = turned[..., :half], turned[..., half:]
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                              axis=-1)
+    if rest.shape[-1]:
+        out = jnp.concatenate([out, rest], axis=-1)
+    return out.astype(x.dtype)

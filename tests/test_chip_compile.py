@@ -302,6 +302,11 @@ BACKWARD_CALLS = {
     "smallthinker_21b_a3b_window": (SMALLTHINKER, 4, 128, WINDOW, False),
     "smallthinker_21b_a3b_global": (SMALLTHINKER, 4, 128, mask_rules.CAUSAL,
                                     False),
+    # Laguna-XS.2 (ISSUE 47): 64 query heads in groups of 8 under a window
+    # of one query block (its 48 in groups of 6 under the causal rule walk
+    # lfm2's and smallthinker's 272 steps)
+    "laguna_xs2_33b_a3b_window": ((1, 16384, 64, 128), 8, 128,
+                                  mask_rules.SlidingWindow(512), False),
 }
 
 
@@ -309,7 +314,8 @@ BACKWARD_CALLS = {
 # parent's rectangles had 288, 128, 512 and 32
 TABLE_STEPS = {"ouro_2p6b": 20, "kanana2_30b_a3b": 72, "sdar_30b_a3b": 160,
                "lfm2_24b_a2b": 272, "smallthinker_21b_a3b_window": 140,
-               "smallthinker_21b_a3b_global": 272}
+               "smallthinker_21b_a3b_global": 272,
+               "laguna_xs2_33b_a3b_window": 47}
 
 
 @pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
@@ -619,18 +625,30 @@ def _float32_arrays_of_q(text):
                          r"(?!bitcast|get-tuple-element|parameter)\w", line)]
 
 
+# how q turns: one theta over the whole head (ISSUE 45), YaRN's table and
+# factor over half of it (ISSUE 47: Laguna-XS.2's full-attention layers)
+TURNS = {"theta_whole_head": dict(theta=1e6),
+         "yarn_half_a_head": dict(
+             frequencies=[500000.0 ** (-i / 32) / (1 if i < 8 else 64)
+                          for i in range(32)], scale=1.4158883083359672)}
+
+
+@pytest.mark.parametrize("turn", sorted(TURNS))
 def test_rotary_behind_a_projection_leaves_no_float32_array_of_q(
-        one_chip, for_the_chip):
+        turn, one_chip, for_the_chip):
     """``rotary_embedding`` behind a bf16 projection at Ouro's shape,
     loss and gradients (ISSUE 45): the pass at the full head width and
     its hand-written backward fuse, and no float32 array of q's shape, of
-    the projection's or half a head wide is written. Sliced at half the
-    width and concatenated, as the op stood, the text holds such arrays
-    (two of 67 MB, and the halves): the census can see them."""
+    the projection's or half a head wide is written; a span of 64 of the
+    128 channels under a table of its own is the same pass and writes
+    none either (ISSUE 47). Sliced at half the width and concatenated, as
+    the op stood, the text holds such arrays (two of 67 MB, and the
+    halves): the census can see them."""
     from paddle1_tpu.autograd import engine as ae
     from paddle1_tpu.core.tensor import Tensor
     from paddle1_tpu.nn import functional as F
     from op_test import rotary_by_halves
+    how = TURNS[turn]
 
     def compiled(rotary):
         def loss(w, x):
@@ -643,11 +661,16 @@ def test_rotary_behind_a_projection_leaves_no_float32_array_of_q(
 
     def op(q):
         with ae.no_grad():
-            return F.rotary_embedding(Tensor(q), 1e6).data
-    assert _float32_arrays_of_q(compiled(op)) == []
+            return F.rotary_embedding(Tensor(q), **how).data
+    text = compiled(op)
+    assert _float32_arrays_of_q(text) == []
+    # nor an array of any type a quarter of a head wide (the halves of a
+    # span of 64)
+    assert not re.search(r"\[2,4096,16,32\]|\[8192,16,32\]", text)
     assert len(_float32_arrays_of_q(
         compiled(lambda q: rotary_by_halves(
-            q, 1e6, jnp.arange(4096), False)))) >= 2
+            q, how.get("theta"), jnp.arange(4096), False,
+            how.get("frequencies"), how.get("scale", 1.0))))) >= 2
 
 
 def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
@@ -761,6 +784,84 @@ def test_recomputed_smallthinker_blocks_name_their_kind_and_route_first(
     assert router
     for op in ("moe_dispatch", "moe_combine", "moe_overflow"):
         assert any(f"/mlp/moe/{op}" in s for s in scopes.values()), op
+
+
+def test_recomputed_laguna_attention_gates_its_heads_by_its_own_count(
+        one_chip, for_the_chip, monkeypatch):
+    """A full and a sliding attention layer of Laguna-XS.2's step at the
+    cell's shape ([1, 16384, 2048] bf16; 48 query heads over 8 of 128
+    under the causal rule with YaRN over half a head; 64 under a window of
+    512 with one theta over the whole head) under
+    ``fleet.utils.recompute``, loss and gradients (ISSUE 47): each layer's
+    attention is the two blockwise kernels under a scope that names its
+    kind, the forward one not run again, at its own head count (q 6,144
+    and 8,192 wide; k, v, dK and dV stay 8 heads wide); the gate's
+    product of one float a position a head lies under ``gate`` in both;
+    rotary on both, and neither leaves a float32 array of q's shape or
+    half a head wide; nothing is shaped like a dense mask. (The whole
+    step with its expert layers: ``benchmarks/tools/aot_compile.py``.)"""
+    from paddle1_tpu.obs import costmodel
+    from paddle1_tpu.text.models import LagunaAttention
+    from paddle1_tpu.text.models.laguna import rotary_arguments
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yarn = rotary_arguments(128, {
+        "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+        "original_max_position_embeddings": 4096, "beta_slow": 1,
+        "beta_fast": 64, "attention_factor": 1.4158883083359672,
+        "partial_rotary_factor": 0.5})
+    assert len(yarn["frequencies"]) == 32
+    text = _recomputed(one_chip, [
+        LagunaAttention(2048, 48, 8, 128, rotary=yarn),
+        LagunaAttention(2048, 64, 8, 128, window=512,
+                        rotary=dict(theta=10000))], (1, 16384, 2048))
+    assert not re.search(r"\bwhile\(", text)
+    assert not re.search(r"\[(\d+,)*16384,16384\]", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted((re.sub(r"\.\d+$", "", n), costmodel.region_of(s),
+                      "global" if "/global/" in s else
+                      "window" if "/window/" in s else None,
+                      "rematted_computation" in s)
+                     for n, s in scopes.items()
+                     if n.startswith("p1t_flash_attention"))
+    assert kernels == [
+        ("p1t_flash_attention_bwd_dkv", "backward", "global", False),
+        ("p1t_flash_attention_bwd_dkv", "backward", "window", False),
+        ("p1t_flash_attention_fwd", "forward", "global", False),
+        ("p1t_flash_attention_fwd", "forward", "window", False)], kernels
+    # groups of 6 and of 8: q goes in 48 and 64 heads wide, k and v go in,
+    # and dK and dV come out, 8 heads wide
+    narrow = "bf16[1,16384,1024]"
+    wide = {"bf16[1,16384,6144]", "bf16[1,16384,8192]"}
+    calls = re.findall(r"^.*%p1t_flash_attention_bwd_dkv\S* = .*$", text,
+                       re.M)
+    assert len(calls) == 2
+    for call in calls:
+        assert call.split(" custom-call(")[0].count(narrow) == 2
+    assert {w for call in calls for w in wide if w in call} == wide
+    # the gate: under its scope in both layers, forward and backward, and
+    # none of it the attention op's
+    gate = [s for s in scopes.values() if "/gate/" in s]
+    assert {costmodel.region_of(s) for s in gate} >= {"forward", "backward"}
+    assert {kind for s in gate for kind in ("/0/", "/1/") if kind in s} \
+        == {"/0/", "/1/"}
+    assert not [s for s in gate if "scaled_dot_product_attention" in s]
+    # positions on both layers; no float32 array of q's shape, and nothing
+    # half or a quarter of a head wide
+    assert {kind for s in scopes.values() if "/rotary_embedding" in s
+            for kind in ("/0/", "/1/") if kind in s} == {"/0/", "/1/"}
+    bodies = _computations(text)
+    fused = set(re.findall(r"\bcalls=%([\w.\-]+)", text))
+    written = [line.strip() for name, lines in bodies.items()
+               if name not in fused for line in lines
+               if re.search(r" = f32\[(1,)?16384,(48,128|64,128|6144|8192|"
+                            r"\d+,64|\d+,32)\]\S* "
+                            r"(?!bitcast|get-tuple-element|parameter)\w", line)]
+    assert not [line[:120] for line in written if "rotary_embedding" in line]
+    # (what is written at that width is the gate's: its backward hands the
+    # attention op's backward its dO as float32, and ``delta``'s reduction
+    # copies it to a layout of its own: PERF.md section 7, "From PR 47")
+    assert all("/scaled_dot_product_attention/jit(_bwd_call)/" in line
+               or "/gate/" in line for line in written), written
 
 
 def test_the_expert_layer_gathers_no_row_for_a_pick_it_does_not_hold(

@@ -384,6 +384,34 @@ def test_the_cells_band_in_closed_form():
                       "skipped": 1024 - 9 * 24 - sum(range(1, 9))}
 
 
+def test_a_window_of_one_query_block_has_no_whole_tile():
+    """Laguna-XS.2's window layers (ISSUE 47): a window of 512 keys at the
+    kernels' 512 resident query rows. A query block's band touches its
+    own key chunk, crossed by the diagonal, and the chunk before, crossed
+    by the window's far edge: two chunks a query block, none whole, so the
+    kernels compute about two tiles' scores for every tile of visible
+    pairs."""
+    rule, s = SlidingWindow(512), 16384
+    assert visible_pairs(rule, s, s) == rule.pairs(s, s) \
+        == 16384 * 512 - 512 * 511 // 2 == 8257792
+    chunks = pair_table(rule, s, s, 512, 512)
+    assert (chunks.steps, chunks.held) == (2 * 32 - 1, 0)
+    for i in range(s // 512):
+        assert chunks.k[chunks.q == i].tolist() == list(
+            range(max(0, i - 1), i + 1))
+    assert tile_counts(rule, s, s, 512, 512) == {
+        "plain": 0, "masked": 63, "skipped": 1024 - 63}
+    # the tiles run hold 63 x 512 x 512 pairs: twice the visible ones
+    assert 63 * 512 * 512 / rule.pairs(s, s) == pytest.approx(2.0, abs=0.001)
+    # at the shipped 1,024 fetched keys: one block where both chunks lie
+    # in it, two where the band crosses a block's edge
+    table = pair_table(rule, s, s, 512, 1024)
+    assert (table.steps, table.held) == (1 + 16 + 2 * 15, 0)
+    for i in range(s // 512):
+        assert table.k[table.q == i].tolist() == list(range(
+            max(0, (512 * i - 511) // 1024), (512 * i + 511) // 1024 + 1))
+
+
 # heads, key/value heads: SmallThinker's 28 / 4 and one group of 7
 @pytest.mark.parametrize("heads,kv_heads", [(28, 4), (7, 1)])
 def test_the_kernels_under_the_window_with_heads_in_groups_of_seven(

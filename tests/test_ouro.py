@@ -171,6 +171,112 @@ def test_rotary_in_one_pass_is_the_slices_and_concatenation_bit_for_bit(
                                   np.asarray(dx_plain, np.float32))
 
 
+# Laguna-XS.2's full-attention layers (ISSUE 47): theta, YaRN's factor over
+# the original context, beta_fast, beta_slow, the published factor on cos
+# and sin, over half a head of 128
+YARN = dict(theta=500000.0, factor=64.0, original=4096, beta_fast=64.0,
+            beta_slow=1.0, attention_factor=1.4158883083359672, span=64)
+
+
+def _yarn_by_the_formulas(span, theta, factor, original, beta_fast,
+                          beta_slow, **_):
+    """(lo, hi, f) written out in numpy float64 from ISSUE 47's
+    equations."""
+    def c(r):
+        return span * np.log(original / (2 * np.pi * r)) \
+            / (2 * np.log(theta))
+    lo = max(int(np.floor(c(beta_fast))), 0)
+    hi = min(int(np.ceil(c(beta_slow))), span - 1)
+    i = np.arange(span // 2)
+    b = theta ** (-i / (span // 2))
+    m = 1 - np.clip((i - lo) / (hi - lo), 0, 1)
+    return lo, hi, (1 - m) * b / factor + m * b
+
+
+def test_yarn_frequencies_at_lagunas_constants_are_pinned():
+    lo, hi, want = _yarn_by_the_formulas(**YARN)
+    assert (lo, hi) == (5, 16)
+    got = F.yarn_frequencies(64, 500000.0, 64.0, 4096, beta_fast=64.0,
+                             beta_slow=1.0)
+    np.testing.assert_allclose(got, want, rtol=1e-12)
+    # pairs 0-5 keep their frequency, pairs 16-31 are slowed 64 times,
+    # and in between the blend is linear in the pair's index
+    base = 500000.0 ** (-np.arange(32) / 32)
+    np.testing.assert_allclose(got[:6], base[:6], rtol=1e-12)
+    np.testing.assert_allclose(got[16:], base[16:] / 64, rtol=1e-12)
+    assert got[0] == 1.0
+    assert got[31] == pytest.approx(500000.0 ** (-31 / 32) / 64, rel=1e-12)
+    assert got[10] == pytest.approx(
+        base[10] * ((1 - 5 / 11) + (5 / 11) / 64), rel=1e-12)
+    assert np.all(np.diff(got) < 0)
+    # the factor on cos and sin that the configuration publishes is YaRN's
+    # own for its factor: 0.1 ln 64 + 1
+    assert YARN["attention_factor"] == pytest.approx(
+        0.1 * np.log(64.0) + 1, rel=1e-15)
+    # no scaling is the plain table, whatever the ramp
+    np.testing.assert_allclose(
+        F.yarn_frequencies(64, 500000.0, 1.0, 4096), base, rtol=1e-12)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("interleaved", [False, True],
+                         ids=["halves", "pairs"])
+def test_rotary_over_part_of_a_head_from_a_table(interleaved, dtype):
+    """Span 64 of a head of 128 under YaRN's table and its factor (ISSUE
+    47): against the formulas written out in numpy; bit for bit the plain
+    form's result, and the hand-written backward bit for bit autodiff of
+    the plain form; the 64 channels behind the span pass untouched,
+    forward and backward."""
+    rng = np.random.default_rng(47 + interleaved)
+    x = jnp.asarray(rng.standard_normal((2, 9, 3, 128)), dtype)
+    g = jnp.asarray(rng.standard_normal((2, 9, 3, 128)), dtype)
+    at = rng.integers(0, 20000, (2, 9)).astype(np.int32)
+    table = F.yarn_frequencies(64, 500000.0, 64.0, 4096, 64.0, 1.0)
+    scale = YARN["attention_factor"]
+
+    def op(x):
+        return F.rotary_embedding(Tensor(x), positions=Tensor(at),
+                                  interleaved=interleaved,
+                                  frequencies=table, scale=scale).data
+
+    def plain(x):
+        return rotary_by_halves(x, None, jnp.asarray(at), interleaved,
+                                table, scale)
+    with no_grad():
+        got, pull = jax.vjp(op, x)
+    want, pull_plain = jax.vjp(plain, x)
+    (dx,), (dx_plain,) = pull(g), pull_plain(g)
+    assert got.dtype == dx.dtype == x.dtype
+    for a, b in ((got, want), (dx, dx_plain)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    # what passes, passes exactly
+    np.testing.assert_array_equal(np.asarray(got[..., 64:], np.float32),
+                                  np.asarray(x[..., 64:], np.float32))
+    np.testing.assert_array_equal(np.asarray(dx[..., 64:], np.float32),
+                                  np.asarray(g[..., 64:], np.float32))
+    # the formulas, in float64: pair i turns by p * f_i, scaled
+    _, _, f = _yarn_by_the_formulas(**YARN)
+    xs = np.asarray(x, np.float64)
+    first, second = ((xs[..., 0:64:2], xs[..., 1:64:2]) if interleaved
+                     else (xs[..., :32], xs[..., 32:64]))
+    turn = scale * np.exp(1j * at[..., None, None] * f)
+    z = (first + 1j * second) * turn
+    out = np.asarray(got, np.float64)
+    mine = ((out[..., 0:64:2], out[..., 1:64:2]) if interleaved
+            else (out[..., :32], out[..., 32:64]))
+    # float32 angles of up to 20,000 radians: 1e-3 of a turn
+    tol = 2e-2 if dtype == "bfloat16" else 3e-3
+    np.testing.assert_allclose(mine[0], z.real, atol=tol * np.abs(z).max())
+    np.testing.assert_allclose(mine[1], z.imag, atol=tol * np.abs(z).max())
+
+
+def test_rotary_refuses_a_table_wider_than_the_head():
+    with pytest.raises(ValueError, match="frequencies"):
+        F.rotary_embedding(Tensor(jnp.ones((1, 2, 1, 8))),
+                           frequencies=[1.0] * 5)
+
+
 def test_swiglu_and_the_gated_feed_forward():
     rng = np.random.default_rng(4)
     g, u = rng.standard_normal((2, 3, 5)).astype(np.float32), \
