@@ -29,13 +29,21 @@ through), for which it raises its own scoped-VMEM limit. A grid step
 takes its fetched block a chunk at a time, and under a mask rule
 (``mask_rules.py``: bottom-right causal, query ``r`` sees keys ``<= r +
 nk - nq``, or block diffusion's mask over a noisy and a clean copy of a
-row) a score tile (resident block x chunk) is one of three kinds, by
-``rule.tile`` on the table's scalars:
+row) a score tile (resident block x chunk) is one of **three kinds, and
+the backward kernel runs a crossed one by sub-tile**, by ``rule.tile`` on
+the table's scalars:
 
 * wholly hidden (a chunk of a needed block that the query block does not
   see): no work; a block of such is not in the table;
 * wholly visible: the plain body, no iota, no select;
-* crossed: the body with the rule's element-wise keep.
+* crossed: the body with the rule's element-wise keep. The backward
+  kernel asks the rule again inside such a tile and runs the sub-tiles it
+  lets through alone (``flash_attention_bwd.crossed_layouts``); this
+  kernel runs it whole, because its time goes with the rows a pass
+  updates (a row's maximum and sum across 128 lanes, its rescaled
+  accumulator), not with the scores it computes: by sub-tile it computed
+  25-37% fewer of a crossed tile's scores in the same time at 256 rows a
+  strip, and was slower at 128 (PERF.md, PR 48).
 
 k and v may have fewer heads than q (grouped-query attention: ``H_kv``
 divides ``H``): the forward kernel reads key/value head ``h // (H /

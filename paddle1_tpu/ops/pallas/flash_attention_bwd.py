@@ -3,7 +3,27 @@ flash_attention.py: one ``pallas_call`` makes a score tile's ``s``,
 ``p = exp(s - lse)``, ``dp = dout v^T`` and ``ds = p (dp - delta)`` once
 and accumulates dQ, dK and dV from them (five score-shaped products and
 one exponent a tile). Logits and probabilities never touch HBM, and the
-blocks a mask rule hides cost nothing.
+blocks a mask rule hides cost nothing: three kinds of a tile, as the
+forward's, **and a crossed one by sub-tile**. When a call is traced the
+rule is asked again, with numpy, at :data:`_SUB` query rows x
+:data:`_SUB` keys over every crossed tile of the call
+(``mask_rules.subtile_patterns``): the sub-tiles' kinds fall into a few
+layouts (the diagonal's under the causal rule; the diagonal's and the far
+edge's under a window; the noisy copy's diagonal and the other
+quadrants' under block diffusion), and each layout is straight code: a
+strip of queries (lanes of the transposed scores, of LSE and of delta;
+rows of q, dout and dQ) runs one pass over the contiguous keys (rows of k,
+v, dK and dV) from its first needed sub-tile to its last, the rule's
+element-wise keep on the sub-tiles it crosses alone, and a sub-tile
+outside them runs nothing. A grid step finds its tile's layout by asking
+``rule.tile`` of the one sub-tile or two that tell the layouts apart, on
+the table's scalars (:func:`_run_tile`). A diagonal or far-edge tile
+spares a quarter of its scores, a noisy-to-noisy tile of block diffusion
+half (``flash_subtiles_total{kind}``, counted from the rule as the tiles
+are). What is resident is sliced, nothing is added. (A loop over strips
+that found its keys step by step was half again slower than the whole
+tile on the v5e, every pass waiting for the one before; strips of 128
+rows were no faster than of 256: PERF.md, PR 48.)
 
 Grid (batch·key head, key range, query head of the group, needed pair):
 the last axis walks the forward's table of needed (q-block, k-block)
@@ -42,18 +62,23 @@ and arrives with the forward's LSE as [batch·head, 1, Nq] rows.
 from __future__ import annotations
 
 import functools
+import itertools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _common
-from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_pairs,
-                              _count_steps, _count_tiles, _dot, _layout,
-                              _run_tile, _unlayout, block_sizes,
+from .flash_attention import (_LANES, _NEG_INF, _NN, _NT, _count_kinds,
+                              _count_pairs, _count_steps, _count_tiles,
+                              _dot, _layout, _unlayout, block_sizes,
                               split_blocks)
-from .mask_rules import FIRST, HELD, LAST, pair_table
+from .flash_attention import _run_tile as _run_whole_tile
+from .mask_rules import (FIRST, HELD, LAST, pair_table, subtile_counts,
+                         subtile_patterns)
 
 __all__ = ["flash_attention_bwd", "block_sizes"]
 
@@ -61,6 +86,10 @@ _TN = (((0,), (0,)), ((), ()))   # a.T @ b
 # what one call may ask of the v5e's 128 MiB of VMEM: past it the keys
 # are taken a range at a time
 _VMEM_CAP = 96 << 20
+_SUB = 256    # a crossed score tile is asked of its rule again in
+              # sub-tiles of this many queries x this many keys
+_LAYOUTS = 4  # a call whose crossed tiles lay their sub-tiles out in more
+              # ways than this runs them whole: a layout is a body of code
 # ``block_sizes`` (block_q, block_k, chunk: the resident query block, the
 # key block fetched a grid step, the slice of it a pass of the body takes)
 # is the forward's, from the same sweep and for its reasons; what it does
@@ -97,7 +126,103 @@ def key_span(nk, bq, bk, chunk, d, dv, dtype) -> int:
     return span
 
 
-def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, span, held):
+def _run_tile(body, rule, q0, bq, k0, bk, off, layouts):
+    """Call ``body(kind)`` as the kind of the score tile of queries [q0,
+    q0+bq) x keys [k0, k0+bk) asks, or not at all: ``False`` for a tile
+    the rule shows whole, and for a crossed one that one of ``layouts``
+    (:func:`crossed_layouts`) which its sub-tiles match, or ``True``
+    where there are none (the forward kernel's three kinds)."""
+    if not layouts:
+        return _run_whole_tile(body, rule, q0, bq, k0, bk, off)
+    needed, full = rule.tile(q0, bq, k0, bk, off)   # some, every pair seen
+    pl.when(full)(lambda: body(False))
+    crossed = jnp.logical_and(needed, jnp.logical_not(full))
+    sq, sk = bq // layouts[0].shape[0], bk // layouts[0].shape[1]
+    probes = _probes(layouts)
+    kinds = {}
+    for j, t in probes:     # the rule again, at the sub-tile's grain
+        some, every = rule.tile(q0 + j * sq, sq, k0 + t * sk, sk, off)
+        kinds[j, t] = jnp.where(some, 1, 0) + jnp.where(every, 1, 0)
+    for layout in layouts:
+        match = crossed
+        for j, t in probes:
+            match = jnp.logical_and(match, kinds[j, t] == int(layout[j, t]))
+        pl.when(match)(lambda layout=layout: body(layout))
+
+
+def _probes(layouts):
+    """The fewest sub-tiles (row, column) whose kinds tell ``layouts``
+    apart (greedy): what a grid step asks its rule to find its own."""
+    flat = np.stack([layout.reshape(-1) for layout in layouts])
+    groups, chosen = [list(range(len(layouts)))], []
+    while any(len(g) > 1 for g in groups):
+        best = max(range(flat.shape[1]), key=lambda c: sum(
+            len(set(flat[g, c])) for g in groups))
+        chosen.append(divmod(best, layouts[0].shape[1]))
+        groups = [[i for i in g if flat[i, best] == kind]
+                  for g in groups for kind in sorted(set(flat[g, best]))]
+    return chosen
+
+
+def sub_grain(bq, chunk):
+    """(query rows, keys) of a sub-tile of a crossed (bq x chunk) score
+    tile; a side that :data:`_SUB` does not divide is taken whole."""
+    return (bq if bq % _SUB else _SUB), (chunk if chunk % _SUB else _SUB)
+
+
+def crossed_layouts(rule, nq, nk, bq, chunk):
+    """How a call runs its crossed (bq x chunk) tiles: None for whole
+    (a tile of one sub-tile; more layouts than :data:`_LAYOUTS`), else
+    the layouts of sub-tile kinds that ``mask_rules.subtile_patterns``
+    finds over them, each of which becomes straight code over the
+    sub-tiles it runs."""
+    sub = sub_grain(bq, chunk)
+    layouts = sub != (bq, chunk) and subtile_patterns(rule, nq, nk, bq,
+                                                      chunk, *sub)
+    return layouts if layouts and len(layouts) <= _LAYOUTS else None
+
+
+def _strips(layout):
+    """(strip, first needed sub-tile, one past the last, their kinds) of
+    every strip of query rows of ``layout`` that runs anything: one pass
+    over the contiguous keys between."""
+    for j, row in enumerate(layout):
+        cols = np.flatnonzero(row)
+        if cols.size:
+            yield (j, int(cols[0]), int(cols[-1]) + 1,
+                   row[cols[0]:cols[-1] + 1])
+
+
+def _keep_crossed(rule, s, kinds, sk, q0, k0, off):
+    """The transposed scores ``s`` of a strip's pass (keys from ``k0`` in
+    sub-tiles of ``sk`` rows, of ``kinds``; queries from ``q0`` along the
+    lanes) with the rule's keep on the sub-tiles it does not show
+    whole."""
+    at, pieces = 0, []
+    for plain, run in itertools.groupby(kinds, lambda kind: kind == 2):
+        n = len(list(run)) * sk
+        piece = s[at:at + n]
+        if not plain:
+            piece = jnp.where(rule.keep(piece.shape, q0, k0 + at, off, 1),
+                              piece, _NEG_INF)
+        pieces.append(piece)
+        at += n
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
+
+
+def _count_subtiles(rule, nq, nk, bq, bk, layouts, calls):
+    """``flash_subtiles_total{kind}``: the sub-tiles of the crossed score
+    tiles alone of one lowered backward call x its ``calls`` (batch x
+    heads), ``spared`` those the kernel does not run (a call that runs
+    its crossed tiles whole, without ``layouts``, has one sub-tile a
+    tile)."""
+    sub = sub_grain(bq, bk) if layouts else (bq, bk)
+    _count_kinds("flash_subtiles_total",
+                 subtile_counts(rule, nq, nk, bq, bk, *sub), calls)
+
+
+def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, span, held,
+                layouts):
     # qb_ref/kb_ref/mark_ref: a range's pairs and marks (``pair_table``);
     # q_ref [BQ, D], do_ref [BQ, Dv], lse_ref/delta_ref [1, BQ] (resident);
     # k_ref [BK, D], v_ref [BK, Dv]; mask_ref [BK, 1]; dk_ref [span, D],
@@ -135,32 +260,54 @@ def _bwd_kernel(*refs, scale, rule, off, chunk, has_mask, span, held):
         qs_ref[...] = (q_ref[...] * scale).astype(qs_ref.dtype)
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def one(c, masked):
-        ks = pl.ds(c * chunk, chunk)
+    def grads(rows, lanes, first, size, keep):
+        # queries ``rows`` of the resident block (``lanes`` of its LSE
+        # and delta) x ``size`` keys from ``first`` of the fetched one;
+        # ``keep(s)``: the scores under the rule, in a crossed tile
+        ks = pl.ds(first, size)
         k, v = k_ref[ks, :], v_ref[ks, :]
-        q, do = q_ref[...], do_ref[...]
-        s = _dot(k, qs_ref[...], _NT)                        # [C, BQ]
+        q, do = q_ref[rows], do_ref[rows]
+        s = _dot(k, qs_ref[rows], _NT)                       # [keys, rows]
         if has_mask:
             s = jnp.where(mask_ref[ks, :] > 0.5, s, _NEG_INF)
-        if masked:
-            s = jnp.where(
-                rule.keep(s.shape, i * bq, at * bk + c * chunk, off, 1),
-                s, _NEG_INF)
+        if keep:
+            s = keep(s)
         # lse is +inf for fully-masked rows (remapped by the wrapper):
         # p underflows to an exact 0 there
-        p = jnp.exp(s - lse_ref[...])
+        p = jnp.exp(s - lse_ref[lanes])
         dp = _dot(v, do, _NT)
-        ds = (p * (dp - delta_ref[...])).astype(q.dtype)
-        here = pl.ds(pl.multiple_of(at * bk + c * chunk - base, chunk),
-                     chunk)
+        ds = (p * (dp - delta_ref[lanes])).astype(q.dtype)
+        here = pl.ds(pl.multiple_of(at * bk + first - base,
+                                    math.gcd(first, size, chunk)), size)
         dv_acc[here, :] += _dot(p.astype(do.dtype), do, _NN)
         dk_acc[here, :] += _dot(ds, q, _NN)
-        dq_acc[...] += _dot(ds, k, _TN)
+        dq_acc[rows] += _dot(ds, k, _TN)
+
+    def one(c, kind):
+        if isinstance(kind, np.ndarray):
+            return by_sub_tile(c, kind)
+        keep = kind and (lambda s: jnp.where(rule.keep(
+            s.shape, i * bq, at * bk + c * chunk, off, 1), s, _NEG_INF))
+        grads(..., ..., c * chunk, chunk, keep)
+
+    def by_sub_tile(c, layout):
+        # a crossed tile: a strip of queries is lanes of the transposed
+        # scores, of LSE and of delta and rows of q, dout and dQ; it runs
+        # one pass over the keys of its needed sub-tiles
+        sq, sk = bq // layout.shape[0], chunk // layout.shape[1]
+        for j, lo, hi, kinds in _strips(layout):
+            first = c * chunk + lo * sk
+            keep = functools.partial(
+                _keep_crossed, rule, kinds=kinds, sk=sk, q0=i * bq + j * sq,
+                k0=at * bk + first, off=off)
+            grads((pl.ds(j * sq, sq), slice(None)),
+                  (slice(None), pl.ds(j * sq, sq)), first, (hi - lo) * sk,
+                  keep)
 
     def tiles():
         for c in range(bk // chunk):
             _run_tile(functools.partial(one, c), rule, i * bq, bq,
-                      at * bk + c * chunk, chunk, off)
+                      at * bk + c * chunk, chunk, off, layouts)
     if held:        # only a table in key ranges has steps that run nothing
         pl.when(mark & HELD == 0)(tiles)
     else:
@@ -230,7 +377,9 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
         head = lambda g, t: g
     else:
         head = lambda g, t: g // h_kv * h + g % h_kv * group + t
+    layouts = crossed_layouts(rule, nq, nk, bq, chunk)
     _count_tiles(rule, nq, nk, bq, chunk, b * h)
+    _count_subtiles(rule, nq, nk, bq, chunk, layouts, b * h)
     _count_steps(table, b * h)
     _count_pairs(rule, nq, nk, b * h)
     _count_ranges(ranges)
@@ -254,7 +403,8 @@ def _bwd_call(q, k, v, out, lse, dout, padding_mask, *, scale, rule,
     dq, dk, dv_out = pl.pallas_call(
         functools.partial(
             _bwd_kernel, scale=scale, rule=rule, off=off, chunk=chunk,
-            has_mask=has_mask, span=span, held=table.held > 0),
+            has_mask=has_mask, span=span, held=table.held > 0,
+            layouts=layouts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b * h_kv, ranges, group, steps),

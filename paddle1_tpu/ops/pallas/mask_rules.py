@@ -43,7 +43,8 @@ import numpy as np
 
 __all__ = ["NO_MASK", "NoMask", "CAUSAL", "Causal", "SlidingWindow",
            "BlockDiffusion",
-           "dense_mask", "tile_counts", "visible_pairs", "pair_table",
+           "dense_mask", "tile_counts", "subtile_counts", "subtile_patterns",
+           "visible_pairs", "pair_table",
            "PairTable", "FIRST", "LAST", "HELD"]
 
 
@@ -273,6 +274,45 @@ def tile_counts(rule, nq, nk, bq, bk):
     masked = int(np.sum(needed & ~full))
     return {"plain": plain, "masked": masked,
             "skipped": needed.size - plain - masked}
+
+
+def _subtiles(rule, nq, nk, bq, bk, sq, sk):
+    """(crossed, needed, full): which (bq x bk) score tiles are crossed,
+    and what ``rule.tile`` makes of each tile's (sq x sk) sub-tiles, as
+    [query tiles, key tiles, sub-tile rows, sub-tile columns]."""
+    needed, full = _tiles(rule, nq, nk, bq, bk)
+    shape = nq // bq, bq // sq, nk // bk, bk // sk
+    return (needed & ~full,) + tuple(
+        kind.reshape(shape).transpose(0, 2, 1, 3)
+        for kind in _tiles(rule, nq, nk, sq, sk))
+
+
+def subtile_counts(rule, nq, nk, bq, bk, sq, sk):
+    """{"plain", "masked", "spared"}: the (sq x sk) sub-tiles of the
+    crossed (bq x bk) score tiles alone, by what the rule makes of them at
+    their own grain: wholly visible, crossed, and hidden (what a kernel
+    that runs a crossed tile by sub-tile does not run)."""
+    crossed, needed, full = _subtiles(rule, nq, nk, bq, bk, sq, sk)
+    needed, full = needed[crossed], full[crossed]
+    return {"plain": int(np.sum(full)), "masked": int(np.sum(needed & ~full)),
+            "spared": int(np.sum(~needed))}
+
+
+def subtile_patterns(rule, nq, nk, bq, bk, sq, sk):
+    """The distinct layouts of sub-tile kinds over the crossed tiles: a
+    tuple of [bq / sq, bk / sk] int8 matrices, 0 where the rule hides the
+    sub-tile, 1 where it crosses it, 2 where it shows every pair. One for
+    the causal rule (the diagonal's), two for a window (the diagonal's
+    and the far edge's), two for block diffusion in blocks shorter than a
+    sub-tile (the noisy copy's own diagonal; the diagonal of the other
+    two quadrants): made with numpy when a kernel call is traced, so that a kernel's
+    crossed tile is straight code over the sub-tiles it runs."""
+    crossed, needed, full = _subtiles(rule, nq, nk, bq, bk, sq, sk)
+    kinds = (needed.astype(np.int8) + full)[crossed]
+    if not len(kinds):
+        return ()
+    return tuple(np.unique(kinds.reshape(len(kinds), -1), axis=0).reshape(
+        (-1,) + kinds.shape[1:]))
 
 
 # marks of a step of :func:`pair_table`: the first / the last step of its

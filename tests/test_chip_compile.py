@@ -224,6 +224,11 @@ CASES = {
         lambda: _flash(mask=WINDOW, kv_heads=4)(*SMALLTHINKER),
     "flash_window_grad_smallthinker_s16384_h28_kv4":
         lambda: _flash(mask=WINDOW, kv_heads=4, grad=True)(*SMALLTHINKER),
+    # Laguna-XS.2's window layers: every tile crossed, the backward's run
+    # by sub-tile in two layouts (ISSUE 48: static slices of rows and lanes)
+    "flash_window_grad_laguna_s16384_h64_kv8_w512":
+        lambda: _flash(mask=mask_rules.SlidingWindow(512), kv_heads=8,
+                       grad=True)(1, 16384, 64, 128),
     # a window shorter than a block (both edges through one tile) with a
     # group of 7 and a padding mask
     "flash_window_masked_grad_s2048_h7_kv1_w300":
@@ -605,6 +610,9 @@ def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
     ours = _kernel_calls(text)
     assert len(calls) == len(ours)
     kernels = sorted(name for name, _ in ours)
+    # no more instances than the parent's two: a crossed tile's sub-tiles
+    # are code inside the backward kernel, not kernels of their own
+    # (ISSUE 48)
     assert kernels == ["p1t_flash_attention_bwd_dkv",
                        "p1t_flash_attention_fwd"]
     # the rest of the block is still run again in the backward pass

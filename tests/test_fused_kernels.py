@@ -451,6 +451,21 @@ BACKWARD_CASES = [
      (_B128, (128, 256, 128, 512))),
     ("causal", 512, 512, 2, 2, 128, 128, "bfloat16", "padding",
      (_B128, (256, 256, 128, 256))),
+    # a crossed tile by sub-tile (ISSUE 48): tiles of 512 rows or keys in
+    # sub-tiles of 256, crossed by the diagonal (a group of 7, keys and
+    # values of two widths), by a window's far edge, by both at once (a
+    # window shorter than a sub-tile, beside a padding mask), by block
+    # diffusion's quadrants, with fewer queries than keys, and at the
+    # sizes the code picks (512 x 512) in bfloat16
+    ("causal", 512, 512, 7, 1, 64, 128, "float32", None, (512, 512, 512)),
+    (("window", 256), 1024, 1024, 2, 2, 128, 128, "float32", None,
+     (512, 512, 512)),
+    (("window", 100), 512, 512, 7, 1, 64, 64, "float32", "padding",
+     (512, 512, 256)),
+    (_BD, 1024, 1024, 1, 1, 128, 128, "float32", None, (512, 512, 512)),
+    (("window", 300), 512, 1024, 2, 1, 192, 128, "float32", "padding",
+     (512, 512, 512)),
+    (("window", 512), 1024, 1024, 1, 1, 128, 128, "bfloat16", None, None),
 ]
 
 
@@ -499,7 +514,9 @@ class TestFlashKernels:
         from paddle1_tpu.ops.pallas import mask_rules
         q, k, v, dout, keep = _attention_problem(
             nq, nk, d, jnp.dtype(dtype), mask, h=h, h_kv=h_kv, dv=dv)
-        if isinstance(rule, tuple):
+        if isinstance(rule, tuple) and rule[0] == "window":
+            rule = mask_rules.SlidingWindow(rule[1])
+        elif isinstance(rule, tuple):
             rule = mask_rules.BlockDiffusion(nq // 2, rule[1])
         else:
             rule = {"causal": mask_rules.CAUSAL, "none": None}[rule]

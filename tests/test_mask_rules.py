@@ -1,6 +1,8 @@
 """The table of needed (query block, key block) pairs that the blockwise
 attention kernels walk (ISSUE 39; ``ops/pallas/mask_rules.py::pair_table``)
-against what ``rule.tile`` says pair by pair; held steps (a backward call
+against what ``rule.tile`` says pair by pair; the sub-tiles of the crossed
+tiles, which the backward kernel runs or spares (ISSUE 48), in closed
+form, on the counter and on the kernel; held steps (a backward call
 in key ranges) change nothing; the counters of a lowered call's grid steps
 and visible pairs; the sliding window's rule (ISSUE 43) against its dense
 mask written out by hand, and on the kernels with query heads in groups
@@ -27,7 +29,8 @@ from paddle1_tpu.ops.pallas.mask_rules import (CAUSAL, FIRST,  # noqa: E402
                                                HELD, LAST, NO_MASK,
                                                BlockDiffusion,
                                                SlidingWindow, dense_mask,
-                                               pair_table, tile_counts,
+                                               pair_table, subtile_counts,
+                                               subtile_patterns, tile_counts,
                                                visible_pairs)
 
 # name -> (rule, queries, keys, resident block, fetched block)
@@ -442,5 +445,164 @@ def test_the_kernels_under_the_window_with_heads_in_groups_of_seven(
     for name, g, w in zip(("out", "dq", "dk", "dv"), (out,) + pull(dout),
                           (want,) + want_pull(dout)):
         assert g.shape == w.shape, name
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5 * float(
+            jnp.max(jnp.abs(w))), err_msg=name)
+
+
+# -- a crossed tile by sub-tile (ISSUE 48) ------------------------------------
+
+# cell -> (rule, positions, crossed 512 x 512 tiles of a head, their sub-
+# tiles plain / masked / spared at 128 x 128 (ISSUE 48's table) and at the
+# backward kernel's 256 x 256; batch, heads, key/value heads)
+SUBTILES = {
+    "laguna_window": (SlidingWindow(512), 16384, 63, (378, 252, 378),
+                      (63, 126, 63), 1, 64, 8),
+    "ouro": (CAUSAL, 4096, 8, (48, 32, 48), (8, 16, 8), 2, 16, 16),
+    "sdar": (BlockDiffusion(8192, 4), 16384, 48, (192, 192, 384),
+             (32, 96, 64), 1, 32, 4),
+    "smallthinker_window": (SlidingWindow(4096), 16384, 56, (336, 224, 336),
+                            (56, 112, 56), 1, 28, 4),
+    "kanana2": (CAUSAL, 8192, 16, (96, 64, 96), (16, 32, 16), 2, 32, 32),
+    "laguna_global": (CAUSAL, 16384, 32, (192, 128, 192), (32, 64, 32),
+                      1, 48, 8),
+}
+SUBTILE_KINDS = ("plain", "masked", "spared")
+
+
+def _kinds(name, kinds):
+    group = process_group("kind")
+    return {kind: group.child(kind).counter(name).value for kind in kinds}
+
+
+@pytest.mark.parametrize("cell", sorted(SUBTILES))
+def test_a_cells_crossed_tiles_in_sub_tiles(cell, _fresh_obs):
+    """From the rule alone: at 128 x 128 a diagonal or far-edge tile
+    spares 6 of its 16 sub-tiles and masks 4, a noisy-to-noisy tile of
+    block diffusion spares 12; at the backward kernel's 256 x 256 they
+    spare 1 of 4 and 2 of 4. The crossed tiles lay their sub-tiles out in
+    one way or two. A lowered forward and backward call add the backward's
+    sub-tiles x batch x heads to ``flash_subtiles_total{kind}`` (the
+    forward runs a crossed tile whole), and ``flash_tiles_total{kind}``
+    reads what it read."""
+    rule, s, crossed, fine, coarse, b, h, h_kv = SUBTILES[cell]
+    tiles = tile_counts(rule, s, s, 512, 512)
+    assert tiles["masked"] == crossed
+    for grain, counts in ((128, fine), (256, coarse)):
+        assert subtile_counts(rule, s, s, 512, 512, grain, grain) == dict(
+            zip(SUBTILE_KINDS, counts)), grain
+        assert sum(counts) == (512 // grain) ** 2 * crossed
+        # the visible pairs lie in the plain tiles and the sub-tiles run
+        whole = tiles["plain"] * 512 * 512 + counts[0] * grain * grain
+        assert whole < rule.pairs(s, s) < whole + counts[1] * grain * grain
+    assert fine[2] / sum(fine) == {"sdar": 0.5}.get(cell, 0.375)
+    assert coarse[2] / sum(coarse) == {"sdar": 1 / 3}.get(cell, 0.25)
+    # 0 hidden, 1 crossed, 2 whole: the diagonal's layout under every
+    # rule, and a window's far edge or block diffusion's noisy copy
+    assert fb.sub_grain(512, 512) == (256, 256)
+    layouts = [layout.tolist()
+               for layout in fb.crossed_layouts(rule, s, s, 512, 512)]
+    other = {"laguna_window": [[1, 2], [0, 1]], "sdar": [[1, 0], [0, 1]],
+             "smallthinker_window": [[1, 2], [0, 1]]}
+    assert sorted(layouts) == sorted([[[1, 0], [2, 1]]] + (
+        [other[cell]] if cell in other else []))
+    q, k = (jax.ShapeDtypeStruct((b, s, heads, 128), jnp.bfloat16)
+            for heads in (h, h_kv))
+    jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, mask=rule).astype(jnp.float32)), argnums=(0, 1, 2)))(q, k, k)
+    assert _kinds("flash_subtiles_total", SUBTILE_KINDS) == {
+        kind: n * b * h for kind, n in zip(SUBTILE_KINDS, coarse)}
+    assert _kinds("flash_tiles_total", tiles) == {
+        kind: 2 * n * b * h for kind, n in tiles.items()}
+
+
+def test_no_rule_no_sub_tile_and_a_tile_of_one_sub_tile_is_itself(
+        _fresh_obs):
+    """``NO_MASK`` crosses no tile; at blocks of 128 a crossed tile is its
+    one sub-tile, masked; a side that the grain does not divide is
+    whole."""
+    assert subtile_counts(NO_MASK, 512, 512, 256, 256, 128, 128) == dict(
+        zip(SUBTILE_KINDS, (0, 0, 0)))
+    assert subtile_patterns(NO_MASK, 512, 512, 256, 256, 128, 128) == ()
+    assert fb.crossed_layouts(NO_MASK, 512, 512, 512, 512) is None
+    assert fb.crossed_layouts(CAUSAL, 512, 512, 128, 128) is None
+    assert fb.sub_grain(128, 128) == (128, 128)
+    assert fb.sub_grain(512, 64) == (256, 64)
+    assert subtile_counts(CAUSAL, 512, 512, 128, 128, 128, 128) == dict(
+        zip(SUBTILE_KINDS, (0, 4, 0)))
+    x = jax.ShapeDtypeStruct((1, 512, 2, 64), jnp.float32)
+    jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v)), argnums=(0, 1, 2)))(x, x, x)
+    assert _kinds("flash_subtiles_total", SUBTILE_KINDS) == dict(
+        zip(SUBTILE_KINDS, (0, 0, 0)))
+    assert _kinds("flash_tiles_total", ("plain",)) == {"plain": 2 * 2}
+
+
+def test_a_step_finds_its_layout_by_the_sub_tiles_that_tell_them_apart(
+        _fresh_obs, monkeypatch):
+    """Three layouts under a window with fewer queries than keys: one
+    sub-tile's kind tells them apart; a rule with more layouts than the
+    kernel holds bodies for runs its crossed tiles whole, and the counter
+    says so."""
+    rule = SlidingWindow(300)
+    monkeypatch.setattr(fb, "_SUB", 128)
+    layouts = fb.crossed_layouts(rule, 256, 768, 256, 256)
+    assert len(layouts) == 3 and len(fb._probes(layouts)) == 1
+    (j, t), = fb._probes(layouts)
+    assert len({int(layout[j, t]) for layout in layouts}) == 3
+    assert fb._probes(fb.crossed_layouts(CAUSAL, 512, 512, 512, 512)) == []
+    monkeypatch.setattr(fb, "_LAYOUTS", 2)
+    assert fb.crossed_layouts(rule, 256, 768, 256, 256) is None
+    fb._count_subtiles(rule, 256, 768, 256, 256, None, 1)
+    assert _kinds("flash_subtiles_total", SUBTILE_KINDS) == dict(
+        zip(SUBTILE_KINDS, (0, 3, 0)))
+
+
+# rule, positions, (resident, fetched, chunk), the sub-tile's side: the
+# diagonal through every strip at the kernel's grain and at 128; a
+# window's two edges through one tile, then through two; block diffusion's
+# quadrants
+STRIPS = {
+    "causal": (CAUSAL, 512, (512, 512, 512), 256),
+    "causal_128": (CAUSAL, 512, (512, 512, 512), 128),
+    "window_both_edges": (SlidingWindow(200), 512, (512, 512, 512), 128),
+    "window_far_edge": (SlidingWindow(256), 1024, (512, 512, 512), 256),
+    "block_diffusion": (BlockDiffusion(256, 32), 512, (256, 256, 256), 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STRIPS))
+def test_a_crossed_tile_by_sub_tile_gives_the_whole_tiles_gradients(
+        case, _fresh_obs, monkeypatch):
+    """The backward kernel's three gradients with a crossed tile run by
+    sub-tile against the same kernel running it whole (the parent's body:
+    no layouts) and the forward's ``out`` and LSE, which both are given,
+    against the dense mask's softmax; some sub-tile is spared in every
+    case."""
+    rule, s, blocks, grain = STRIPS[case]
+    keys = jax.random.split(jax.random.key(48), 4)
+    q, k, v, dout = (jax.random.normal(kk, (1, s, 1, 64), jnp.float32)
+                     for kk in keys)
+    out, lse = fa._flash_fwd(q, k, v, 0.125, rule, blocks=blocks)
+    scores = jnp.einsum("bqhd,bkd->bhqk", q, k[:, :, 0]) * 0.125
+    scores = jnp.where(jnp.asarray(dense_mask(rule, s, s)), scores, -jnp.inf)
+    np.testing.assert_allclose(
+        lse, jax.nn.logsumexp(scores, -1).reshape(1, s), rtol=2e-5,
+        atol=2e-5, err_msg="lse")
+    np.testing.assert_allclose(
+        out, jnp.einsum("bhqk,bkd->bqhd", jax.nn.softmax(scores, -1),
+                        v[:, :, 0]), rtol=2e-5, atol=2e-5, err_msg="out")
+
+    def grads(sub):
+        monkeypatch.setattr(fb, "_SUB", sub)
+        fb._bwd_call.clear_cache()
+        try:
+            return fb.flash_attention_bwd(q, k, v, out, lse, dout, 0.125,
+                                          rule, blocks=blocks)
+        finally:
+            monkeypatch.undo()
+            fb._bwd_call.clear_cache()
+    got = grads(grain)
+    assert _kinds("flash_subtiles_total", ("spared",))["spared"] > 0
+    for name, g, w in zip(("dq", "dk", "dv"), got, grads(1024)):
         np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5 * float(
             jnp.max(jnp.abs(w))), err_msg=name)
