@@ -505,10 +505,12 @@ def process_group(label_key) -> MetricsGroup:
     ``process_group(("layer", "expert")).child((path, 3))`` carries both
     labels. The labeled series of the training side: ``attention_arm_
     total{arm}``, ``flash_tiles_total{kind}``, ``flash_subtiles_total
-    {kind}`` and ``flash_grid_steps_total{kind}`` (counted when a kernel
+    {kind}``, ``flash_grid_steps_total{kind}`` and ``flash_stat_bytes_
+    total{kind}`` (counted when a kernel
     call is traced: its score tiles plain, masked and skipped; its crossed
     tiles' sub-tiles plain, masked and spared; its grid's steps working
-    and held),
+    and held; the float32 bytes of row statistics a forward call writes
+    to HBM, ``lse``),
     ``flash_pairs_total{rule}`` (the pairs its mask rule lets through, by
     the rule's name), ``moe_sum_picks_arm_total{arm}`` (counted when the
     expert layer's sum of a token's picks is traced on rows: ``kernel``

@@ -21,7 +21,8 @@ runs nothing, so none leaves that fetch with nothing to hide behind (the
 rectangular grid this replaced, query blocks x the hungriest one's key
 steps, spent 38-47% of its steps so: PERF.md, PR 39). **Resident**: the
 forward keeps a query block with its running max, sum and output
-([BQ, D]); the backward a query block with its dout, LSE, delta and dQ,
+([BQ, D]; the row statistics below); the backward a query block with its
+dout, LSE, delta and dQ,
 and besides, for every query block and query head of its group, one key
 head's whole dK and dV in float32 ([Nk, D] + [Nk, Dv]: 8 MiB at 4096
 keys of 128, 32 at SDAR's 16,384, with the blocks they are written back
@@ -40,10 +41,32 @@ the table's scalars:
   kernel asks the rule again inside such a tile and runs the sub-tiles it
   lets through alone (``flash_attention_bwd.crossed_layouts``); this
   kernel runs it whole, because its time goes with the rows a pass
-  updates (a row's maximum and sum across 128 lanes, its rescaled
+  updates (a row's maximum across 128 lanes, its rescaled sum and
   accumulator), not with the scores it computes: by sub-tile it computed
   25-37% fewer of a crossed tile's scores in the same time at 256 rows a
   strip, and was slower at 128 (PERF.md, PR 48).
+
+**A row's statistics** live in VMEM across one 128-lane register row,
+``[BQ, 128]``: its running max replicated (a ``[BQ, 1]`` column would be
+masked stores a pass), its running sum as **128 per-lane partial sums**.
+The rescaling factor is equal across a row's lanes, so a pass adds the
+128-lane slices of its probabilities to the lanes (VALU adds) and the
+one reduction across lanes is made once a query block, at its ``LAST``
+mark, where the replicated sum paid one a pass beside the maximum's: 1-2%
+of a forward call at the cells' shapes (where a query block sees one
+chunk, as [32, 128, 12, 64] without a mask, nothing hides the reduction
+at ``LAST`` and a lone call reads 12% more: PERF.md, PR 49). The lanes
+are a register convenience that stops at the edge of VMEM: what leaves
+the kernel is the LSE as the lane-dense ``[B*H, 1, Nq]`` float32 row the
+backward kernel reads (its ``stat`` block, ``[1, BQ]`` a step), 4 bytes a
+(head, query), written once a query block through one transpose of the
+``[BQ, 128]`` tile; ``_flash_fwd`` hands it on as ``[B*H, Nq]``. Written
+as it is held, ``[B*H, Nq, 128]``, it was 512 bytes for every 4 that were
+read and the largest thing the kernel moved under a short window (537 MB
+a call beside q's and out's 268 at Laguna's 64 heads of 16,384 queries),
+and XLA relaid the whole array out to take its first lane: 7.1 ms a
+Laguna step, 0.8 to 4 in the other cells (PERF.md, PR 49;
+``flash_stat_bytes_total{kind="lse"}`` counts the bytes).
 
 k and v may have fewer heads than q (grouped-query attention: ``H_kv``
 divides ``H``): the forward kernel reads key/value head ``h // (H /
@@ -73,6 +96,7 @@ off-TPU so tests exercise the same code path.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -86,7 +110,7 @@ from .mask_rules import (CAUSAL, FIRST, LAST, NO_MASK, pair_table,
                          tile_counts)
 
 _LANES = 128  # Mosaic minor-dim tile: per-row statistics are kept
-              # replicated across one 128-lane register row
+              # across one 128-lane register row
 _NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))   # a @ b.T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -183,6 +207,16 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _lane_sums(p):
+    """[rows, n] as [rows, 128] whose lanes sum to ``p``'s row sums: the
+    128-lane slices added up (VALU adds), no reduction across lanes."""
+    n = p.shape[1]
+    if n % _LANES:
+        p = jnp.pad(p, ((0, 0), (0, -n % _LANES)))
+    return functools.reduce(
+        jnp.add, (p[:, c:c + _LANES] for c in range(0, n, _LANES)))
+
+
 def _layout(x):
     """[B, N, H, D] -> (the array a kernel windows, its index map)."""
     b, n, h, d = x.shape
@@ -267,9 +301,22 @@ def _count_pairs(rule, nq, nk, calls):
         "flash_pairs_total").inc(rule.pairs(nq, nk) * calls)
 
 
+def _count_stat_bytes(shape, calls):
+    """``flash_stat_bytes_total{kind="lse"}``: the float32 bytes of row
+    statistics one lowered forward call writes to HBM (its LSE output, of
+    ``shape`` a batch x head) x its ``calls``: 4 a (head, query), where
+    the lane-replicated ``[Nq, 128]`` it once wrote reads 512."""
+    _count_kinds("flash_stat_bytes_total", {"lse": 4 * math.prod(shape)},
+                 calls)
+
+
 def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [BK, D]; mask_ref: [1, BK] f32,
-    # 1.0 = attend / 0.0 = padding; lse_ref: [BQ, 128]
+    # 1.0 = attend / 0.0 = padding; lse_ref: [1, BQ], the lane-dense row
+    # the backward reads, written at LAST alone; m_ref: [BQ, 128], a
+    # row's max in every lane; l_ref: [BQ, 128], a row's sum a lane's
+    # share at a time (a pass adds its 128-lane slices of p, the lanes
+    # are summed at LAST)
     # qb_ref/kb_ref/mark_ref: the step's pair and marks (``pair_table``)
     qb_ref, kb_ref, mark_ref, q_ref, k_ref, v_ref = refs[:6]
     mask_ref = refs[6] if has_mask else None
@@ -299,7 +346,7 @@ def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - _lanes(m_new, chunk))
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)[:, None]
+        l_ref[...] = alpha * l_ref[...] + _lane_sums(p)
         m_ref[...] = m_new
         acc_ref[...] = acc_ref[...] * _lanes(alpha, dv) + _dot(
             p.astype(v_ref.dtype), v_ref[ks, :], _NN)
@@ -314,12 +361,20 @@ def _fwd_kernel(*refs, scale, rule, off, chunk, has_mask):
         # still the sentinel and p degenerated to exp(0)=1 per key. Gate
         # those rows to zero output and sentinel LSE so the backward
         # (which keys p off the LSE) gives exact zero gradients for them.
-        m, l = m_ref[...], l_ref[...]
+        m = m_ref[...]
+        # l_ref held a lane's share of a row's sum: the one sum across
+        # lanes a query block
+        l = jnp.broadcast_to(jnp.sum(l_ref[...], axis=1)[:, None], m.shape)
         visible = m > _NEG_INF * 0.5
         l_safe = jnp.where(l == 0.0, 1.0, l)
         inv = jnp.where(visible, 1.0 / l_safe, 0.0)
         o_ref[...] = (acc_ref[...] * _lanes(inv, dv)).astype(o_ref.dtype)
-        lse_ref[...] = jnp.where(visible, m + jnp.log(l_safe), _NEG_INF)
+        # every lane of a row holds its LSE: a row of the transposed tile
+        # is the query block's LSE lane-dense (64 vregs through the XLU
+        # once a query block; a one-hot product on the MXU and the
+        # diagonals summed down the sublanes read slower: PERF.md, PR 49)
+        lse = jnp.where(visible, m + jnp.log(l_safe), _NEG_INF)
+        lse_ref[...] = lse.T[:1]
 
 
 def split_blocks(blocks):
@@ -381,6 +436,9 @@ def _fwd_call(q, k, v, padding_mask, *, scale, rule, blocks, interpret):
     _count_tiles(rule, nq, nk, bq, chunk, b * h)
     _count_steps(table, b * h)
     _count_pairs(rule, nq, nk, b * h)
+    # the LSE a head: one lane-dense row, the backward kernel's ``stat``
+    lse_shape = (1, nq)
+    _count_stat_bytes(lse_shape, b * h)
 
     def spec(shape, index):
         """A window placed by (batch x head, query block, key block) of
@@ -407,7 +465,7 @@ def _fwd_call(q, k, v, padding_mask, *, scale, rule, blocks, interpret):
             in_specs=in_specs,
             out_specs=[
                 spec((None, bq, dv), lambda g, i, j: at_o(g, i)),
-                spec((None, bq, _LANES), lambda g, i, j: (g, i, 0)),
+                spec((None, 1, bq), lambda g, i, j: (g, 0, i)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, d), q.dtype),
@@ -417,14 +475,14 @@ def _fwd_call(q, k, v, padding_mask, *, scale, rule, blocks, interpret):
             ]),
         out_shape=[
             jax.ShapeDtypeStruct(_layout_shape(b, nq, h, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * h, nq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b * h,) + lse_shape, jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         name="p1t_flash_attention_fwd",
         interpret=interpret,
     )(table.q, table.k, table.mark, *args)
-    return out, lse[:, :, 0]
+    return out, lse.reshape(b * h, nq)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

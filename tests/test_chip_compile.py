@@ -325,23 +325,81 @@ TABLE_STEPS = {"ouro_2p6b": 20, "kanana2_30b_a3b": 72, "sdar_30b_a3b": 160,
 
 @pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
 def test_a_cells_forward_call_walks_the_table_in_the_default_vmem(
-        cell, one_chip, for_the_chip):
+        cell, forward_text, for_the_chip):
     """The forward kernel at each cell's shape compiles for the v5e with
     the table of needed pairs as its three scalar-prefetched columns, a
     grid of batch x heads by the table's steps, and keeps no more than a
     block of any operand: Mosaic uses under the default 16 MiB."""
-    import functools
-    (b, s, h, d), h_kv, dv, rule, _ = BACKWARD_CALLS[cell]
-    struct = lambda *dims: jax.ShapeDtypeStruct(dims, BF16,
-                                                sharding=one_chip)
-    text = jax.jit(functools.partial(
-        flash_attention._fwd_call.__wrapped__, scale=d ** -0.5, rule=rule,
-        blocks=None, interpret=False)).lower(
-        struct(b, s, h, d), struct(b, s, h_kv, d), struct(b, s, h_kv, dv),
-        None).compile().as_text()
+    text = forward_text(cell)
     call, = re.findall(r"^.*%p1t_flash_attention_fwd\S* = .*$", text, re.M)
     assert call.count("s32[%d]{0}" % TABLE_STEPS[cell]) >= 3
     assert 0 < _scoped_vmem(call, "used_") <= 16 << 20
+
+
+@pytest.fixture(scope="module")
+def forward_text(one_chip):
+    """cell -> the compiled text of one forward call at its shape, made
+    once for the tests that read it."""
+    import functools
+
+    @functools.cache
+    def text(cell):
+        (b, s, h, d), h_kv, dv, rule, _ = BACKWARD_CALLS[cell]
+        struct = lambda *dims: jax.ShapeDtypeStruct(dims, BF16,
+                                                    sharding=one_chip)
+        return jax.jit(functools.partial(
+            flash_attention._fwd_call.__wrapped__, scale=d ** -0.5,
+            rule=rule, blocks=None, interpret=False)).lower(
+            struct(b, s, h, d), struct(b, s, h_kv, d),
+            struct(b, s, h_kv, dv), None).compile().as_text()
+    return text
+
+
+def _replicated_statistics(text):
+    """What a row statistic held across 128 lanes leaves in a compiled
+    text when it is written out so (ISSUE 49; the parent's text holds
+    both at every shape here): a float32 output of the forward attention
+    kernel whose minor dimension is 128, beside ``out``, and XLA's
+    ``copy`` of a float32 ``[heads, n, 128]`` array behind it."""
+    hits = re.findall(r"= f32\[\d+,\d+,128\]\S* copy\(.*?\)", text)
+    for name, call in _kernel_calls(text):
+        if name == "p1t_flash_attention_fwd":
+            hits += re.findall(r"f32\[[\d,]*\b128\]",
+                               call.split(" custom-call(")[0])
+    return hits
+
+
+@pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
+def test_a_cells_forward_call_writes_its_lse_as_one_row_a_head(
+        cell, forward_text, for_the_chip):
+    """The forward kernel at each cell's shape writes ``out`` and one
+    float32 ``[1, Nq]`` row a head, the backward kernel's ``stat`` block,
+    and the ``[B*H, Nq]`` the wrapper returns is a bitcast of it: no
+    128-lane copy of the LSE leaves VMEM, and XLA has nothing to relay
+    out (ISSUE 49: 2.42 GB written and 7.1 ms of copies a laguna step)."""
+    (b, s, h, _), _, _, _, _ = BACKWARD_CALLS[cell]
+    text = forward_text(cell)
+    assert _replicated_statistics(text) == []
+    call, = re.findall(r"^.*%p1t_flash_attention_fwd\S* = .*$", text, re.M)
+    written = call.split(" custom-call(")[0]
+    assert re.findall(r"f32\[[\d,]*\]", written) == [
+        "f32[%d,1,%d]" % (b * h, s)]
+    assert not re.search(r"\bf32\[%d,%d,128\]" % (b * h, s), text)
+    # what XLA still copies of it is the row itself, from the kernel's
+    # rows of one sublane to the [B*H, Nq] array's tiles of eight: 4 bytes
+    # a (head, query)
+    assert set(re.findall(r"= (f32\[[\d,]*\])\S* copy\(", text)) <= {
+        "f32[%d,1,%d]" % (b * h, s)}
+
+
+def test_the_census_of_replicated_statistics_sees_the_parents_form():
+    assert _replicated_statistics(
+        "  %p1t_flash_attention_fwd.1 = (bf16[1,16384,8192]{2,1,0}, "
+        "f32[64,16384,128]{2,1,0}) custom-call(%a), "
+        'custom_call_target="tpu_custom_call"\n'
+        "  %copy.1 = f32[64,16384,128]{1,0,2} copy(%get-tuple-element.2)"
+    ) == ["= f32[64,16384,128]{1,0,2} copy(%get-tuple-element.2)",
+          "f32[64,16384,128]"]
 
 
 def _scoped_vmem(call, which=""):
@@ -618,6 +676,8 @@ def test_a_recomputed_ouro_block_runs_the_forward_kernel_once(
     # the rest of the block is still run again in the backward pass
     assert "/rematted_computation/" in text
     assert not [c for c in calls if "/rematted_computation/" in c]
+    # and the kept LSE is the row the kernel wrote (ISSUE 49)
+    assert _replicated_statistics(text) == []
 
 
 def _float32_arrays_of_q(text):
@@ -725,6 +785,7 @@ def test_a_recomputed_sdar_block_holds_no_dense_mask_and_no_copy_of_k_or_v(
     assert not re.search(r"\[(\d+,)*16384,16384\]", text)
     assert not re.search(r"\bwhile\(", text)
     assert "/rematted_computation/" in text
+    assert _replicated_statistics(text) == []     # ISSUE 49
 
 
 def test_recomputed_smallthinker_blocks_name_their_kind_and_route_first(
@@ -773,6 +834,7 @@ def test_recomputed_smallthinker_blocks_name_their_kind_and_route_first(
                            re.M):
         assert call.split(" custom-call(")[0].count(narrow) == 2
         assert 16 << 20 < _scoped_vmem(call, "used_") < fb._VMEM_CAP
+    assert _replicated_statistics(text) == []     # ISSUE 49
     # positions on the window block alone
     rotary = {block for s in scopes.values()
               if "/self_attn/rotary_embedding" in s
@@ -865,11 +927,15 @@ def test_recomputed_laguna_attention_gates_its_heads_by_its_own_count(
                             r"\d+,64|\d+,32)\]\S* "
                             r"(?!bitcast|get-tuple-element|parameter)\w", line)]
     assert not [line[:120] for line in written if "rotary_embedding" in line]
-    # (what is written at that width is the gate's: its backward hands the
-    # attention op's backward its dO as float32, and ``delta``'s reduction
-    # copies it to a layout of its own: PERF.md section 7, "From PR 47")
+    # (what is written at that width is the gate's, in this text alone:
+    # behind the float32 loss of two lone layers its backward hands the
+    # attention op's backward its dO as float32; in the cell's whole step
+    # dO arrives in bf16 and ``delta``'s reduction fuses with it, on the
+    # chip and compiled here: PERF.md section 7, "From PR 47" (1))
     assert all("/scaled_dot_product_attention/jit(_bwd_call)/" in line
                or "/gate/" in line for line in written), written
+    # both layers' LSE leaves its kernel as one row a head (ISSUE 49)
+    assert _replicated_statistics(text) == []
 
 
 def test_the_expert_layer_gathers_no_row_for_a_pick_it_does_not_hold(

@@ -469,6 +469,38 @@ BACKWARD_CASES = [
 ]
 
 
+# (mask rule, nq, nk, heads, key/value heads, d, dv, dtype, padding): the
+# row statistic the forward kernel hands on (ISSUE 49: its LSE leaves as
+# the lane-dense row the backward reads, summed a lane and reduced once a
+# query block), at head widths 64 / 128 / 192-with-128 values, query
+# heads in groups of 1 / 4 / 8, under every rule and under none, at the
+# sizes the code picks; a fully padded batch entry carries the sentinel
+LSE_CASES = [
+    ("causal", 512, 512, 4, 4, 64, 64, "float32", None),
+    ("causal", 512, 512, 8, 2, 128, 128, "float32", None),
+    ("causal", 256, 256, 8, 1, 192, 128, "bfloat16", None),
+    ("none", 256, 384, 8, 1, 192, 128, "float32", "padding"),
+    ("none", 256, 256, 4, 1, 128, 128, "float32", None),
+    (("window", 512), 1024, 1024, 8, 1, 128, 128, "bfloat16", None),
+    (("window", 512), 1024, 1024, 4, 1, 64, 64, "float32", None),
+    (_BD, 512, 512, 4, 1, 128, 128, "float32", None),
+    (_BD, 256, 256, 2, 2, 192, 128, "float32", None),
+    ("none", 256, 256, 2, 2, 64, 64, "float32", "padded_row"),
+    ("causal", 256, 256, 8, 1, 128, 128, "float32", "padded_row"),
+]
+
+
+def _rule_of_case(rule, nq):
+    """A case's ``"causal"``, ``"none"``, ``("window", keys)`` or
+    ``("block_diffusion", block)`` as its rule of ``mask_rules``."""
+    from paddle1_tpu.ops.pallas import mask_rules
+    if isinstance(rule, tuple) and rule[0] == "window":
+        return mask_rules.SlidingWindow(rule[1])
+    if isinstance(rule, tuple):
+        return mask_rules.BlockDiffusion(nq // 2, rule[1])
+    return {"causal": mask_rules.CAUSAL, "none": mask_rules.NO_MASK}[rule]
+
+
 class TestFlashKernels:
     """The blockwise forward kernel and the one backward kernel
     (ops/pallas/flash_attention.py, flash_attention_bwd.py) against
@@ -511,18 +543,99 @@ class TestFlashKernels:
         """dQ, dK and dV of one ``pallas_call``: grouped heads, the two
         widths of latent attention, block diffusion's rule, and the keys
         a range at a time, within the limits the split kernels had."""
-        from paddle1_tpu.ops.pallas import mask_rules
         q, k, v, dout, keep = _attention_problem(
             nq, nk, d, jnp.dtype(dtype), mask, h=h, h_kv=h_kv, dv=dv)
-        if isinstance(rule, tuple) and rule[0] == "window":
-            rule = mask_rules.SlidingWindow(rule[1])
-        elif isinstance(rule, tuple):
-            rule = mask_rules.BlockDiffusion(nq // 2, rule[1])
-        else:
-            rule = {"causal": mask_rules.CAUSAL, "none": None}[rule]
         self._held_to_the_reference(q, *_flash_and_ref(
-            q, k, v, dout, keep, False, blocks, rule=rule), dtype,
+            q, k, v, dout, keep, False, blocks,
+            rule=_rule_of_case(rule, nq)), dtype, group=h // h_kv)
+
+    @pytest.mark.parametrize(
+        "rule,nq,nk,h,h_kv,d,dv,dtype,mask", LSE_CASES,
+        ids=lambda c: "x".join(map(str, c)) if isinstance(c, tuple)
+        else str(c))
+    def test_the_forward_kernels_lse_is_the_references(
+            self, rule, nq, nk, h, h_kv, d, dv, dtype, mask):
+        """``_flash_fwd``'s second value is ``[B*H, Nq]`` float32, the
+        log-sum-exp of the visible scores as float32 computes it; a row
+        that sees no key carries the sentinel, to the bit."""
+        from paddle1_tpu.ops.pallas import flash_attention as fa
+        from paddle1_tpu.ops.pallas import mask_rules
+        q, k, v, _, keep = _attention_problem(
+            nq, nk, d, jnp.dtype(dtype), mask, h=h, h_kv=h_kv, dv=dv)
+        rule = _rule_of_case(rule, nq)
+        scale = d ** -0.5
+        out, lse = fa._flash_fwd(
+            q, k, v, scale, rule,
+            None if keep is None else jnp.asarray(keep, jnp.float32))
+        b = q.shape[0]
+        assert out.shape == (b, nq, h, dv) and out.dtype == q.dtype
+        assert lse.shape == (b * h, nq) and lse.dtype == jnp.float32
+        seen = np.broadcast_to(
+            mask_rules.dense_mask(rule, nq, nk)[None, None],
+            (b, 1, nq, nk))
+        if keep is not None:
+            seen = seen & keep[:, None, None, :]
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q.astype(jnp.float32),
+            jnp.repeat(k, h // h_kv, axis=2).astype(jnp.float32)) * scale
+        want = np.asarray(jax.nn.logsumexp(
+            jnp.where(seen, scores, -jnp.inf), axis=-1))
+        got = np.asarray(lse).reshape(b, h, nq)
+        dead = ~seen.any(axis=(1, 3))                       # [b, nq]
+        dead = np.broadcast_to(dead[:, None], got.shape)
+        assert dead.any() == (mask == "padded_row")
+        np.testing.assert_array_equal(got[dead], np.float32(fa._NEG_INF))
+        # bf16: q * scale is rounded once before the product
+        tol = 2e-5 if dtype == "float32" else 3e-2
+        np.testing.assert_allclose(got[~dead], want[~dead], rtol=tol,
+                                   atol=tol)
+
+    @pytest.mark.parametrize(
+        "rule,nq,nk,h,h_kv,d,dv,dtype,mask", LSE_CASES,
+        ids=lambda c: "x".join(map(str, c)) if isinstance(c, tuple)
+        else str(c))
+    def test_the_gradients_behind_the_compact_lse_are_the_references(
+            self, rule, nq, nk, h, h_kv, d, dv, dtype, mask):
+        """Forward + backward through the LSE row at ``LSE_CASES``'
+        shapes against ``attention_ref`` in float32, within the limits
+        the file has; exact zeros behind a sentinel."""
+        q, k, v, dout, keep = _attention_problem(
+            nq, nk, d, jnp.dtype(dtype), mask, h=h, h_kv=h_kv, dv=dv)
+        rule = _rule_of_case(rule, nq)
+        self._held_to_the_reference(q, *_flash_and_ref(
+            q, k, v, dout, keep, False, None, rule=rule), dtype,
             group=h // h_kv)
+
+    def test_a_traced_call_counts_the_statistics_it_writes(self):
+        """``flash_stat_bytes_total{kind="lse"}``: 4 bytes a (head,
+        query) of a traced forward call, the one float32 row a head the
+        kernel writes beside ``out`` (lane-replicated it read 128 times
+        that: laguna's 64 heads of 16,384 queries 537 MB a call)."""
+        from paddle1_tpu import obs
+        from paddle1_tpu.ops.pallas import flash_attention as fa
+        from paddle1_tpu.ops.pallas import mask_rules
+        obs.reset_process_registry()
+        count = lambda: obs.registry.process_group("kind").child(
+            "lse").counter("flash_stat_bytes_total").value
+        total = 0
+        for (b, s, h, h_kv, d, dv), rule in [
+                ((1, 16384, 64, 8, 128, 128), mask_rules.SlidingWindow(512)),
+                ((2, 8192, 32, 32, 192, 128), mask_rules.CAUSAL),
+                ((1, 16384, 32, 8, 64, 64), mask_rules.CAUSAL)]:
+            q, k, v = (jax.ShapeDtypeStruct((b, s, heads, w), jnp.bfloat16)
+                       for heads, w in ((h, d), (h_kv, d), (h_kv, dv)))
+            jaxpr = jax.make_jaxpr(lambda q, k, v: fa._flash_fwd(
+                q, k, v, d ** -0.5, rule))(q, k, v)
+            total += b * h * s * 4
+            assert count() == total
+            call, = (e for e in _equations(jaxpr.jaxpr)
+                     if e.primitive.name == "pallas_call")
+            # out, and one float32 row a head: nothing 128 lanes wide
+            # that is not out
+            assert [(o.aval.shape, str(o.aval.dtype))
+                    for o in call.outvars][1:] == [((b * h, 1, s),
+                                                    "float32")]
+        obs.reset_process_registry()
 
     def test_a_call_counts_its_key_ranges(self):
         """``flash_backward_ranges_total``: 1 a traced backward call
@@ -582,20 +695,24 @@ class TestFlashKernels:
         assert need(long) <= fb._VMEM_CAP < need(2 * long)
 
 
-def _primitives(jaxpr, seen=None):
-    """Names of the primitives of a jaxpr and of every jaxpr inside it,
-    a ``pallas_call``'s own body left out (its grid is the kernel's)."""
-    seen = [] if seen is None else seen
+def _equations(jaxpr):
+    """The equations of a jaxpr and of every jaxpr inside it, a
+    ``pallas_call``'s own body left out."""
     for eqn in jaxpr.eqns:
-        seen.append(eqn.primitive.name)
+        yield eqn
         if eqn.primitive.name == "pallas_call":
             continue
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    _primitives(inner, seen)
-    return seen
+                    yield from _equations(inner)
+
+
+def _primitives(jaxpr):
+    """Names of the primitives of a jaxpr and of every jaxpr inside it,
+    a ``pallas_call``'s own body left out (its grid is the kernel's)."""
+    return [eqn.primitive.name for eqn in _equations(jaxpr)]
 
 
 def test_a_causal_models_step_holds_no_while_on_the_kernels_path():
