@@ -17,7 +17,11 @@ runs with 28 / 4 heads at SmallThinker's size against a float32 dense
 band in blocks and again with a window of one query block (512 keys) and
 64 / 8 heads beside the causal rule's 48 / 8 at Laguna-XS.2's, where
 rotary positions over half a head under YaRN's table are held to their
-slices in float32, and the routed-expert layer takes more
+slices in float32, the two state-space scan kernels run at Nemotron 3
+Nano's size ([1, 8192, 64 heads of 64, 8 groups of state 128]) against
+the chunked composition in float32 with each one's share of the HBM
+roofline, beside the causal rule with 16 query heads a key/value head
+([1, 8192, 32 / 2, 128]), and the routed-expert layer takes more
 held picks than its grouped products have rows and counts the late ones
 on the device, eagerly and in two compiled steps
 (``ParallelEngine.expert_load()``). Every
@@ -660,6 +664,68 @@ def experts_phase(tokens=4096, hidden=512, width=256):
 
 # -- four chips -------------------------------------------------------------
 
+def ssd_scan_phase(seq=8192, heads=64, width=64, groups=8, state=128):
+    """Nemotron 3 Nano's two mixers at its size: the two state-space scan
+    kernels ([1, 8192, 64, 64] bf16 over 8 groups of state 128) against
+    the chunked composition in float32, each kernel's ms a call and its
+    share of the HBM roofline by the op's own closed form of its bytes;
+    and the blockwise attention kernels under the causal rule with 16
+    query heads a key/value head ([1, 8192, 32 / 2, 128])."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks import peaks
+    from paddle1_tpu.nn.functional import ssd as op
+    from paddle1_tpu.ops.pallas import ssd_scan as kernels
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    keys = jax.random.split(jax.random.key(50), 10)
+    x = (0.5 * jax.random.normal(keys[0], (1, seq, heads, width))).astype(bf16)
+    d = jax.nn.softplus(jax.random.normal(keys[1], (1, seq, heads)) - 4.0)
+    a = -jnp.arange(1, heads + 1, dtype=f32)
+    b, c = ((0.3 * jax.random.normal(k, (1, seq, groups, state))).astype(bf16)
+            for k in keys[2:4])
+    skip = jnp.ones((heads,), f32)
+    g = jax.random.normal(keys[4], x.shape, bf16)
+    at = f"[1, {seq}, {heads}, {width}] x {groups} groups of {state}"
+    check(kernels.supported(x.shape, b.shape, kernels.CHUNK)
+          and op._use_kernels(x, b, kernels.CHUNK),
+          f"ssd_scan {at} takes the kernels")
+    fwd = jax.jit(lambda *o: kernels.forward(*o, keep_states=True))
+    bwd = jax.jit(kernels.backward)
+    y, starts = fwd(x, d, a, b, c, skip)
+    have = (y,) + bwd(x, d, a, b, c, skip, starts, g)
+    with jax.default_matmul_precision("highest"):
+        want = vjp_of(lambda *o: op.chunked(*o, kernels.CHUNK))(
+            x.astype(f32), d, a, b.astype(f32), c.astype(f32), skip,
+            g.astype(f32))
+    for name, got, ref in zip(("y", "dx", "dd", "dA", "dB", "dC", "dD"),
+                              have, want):
+        err = max_err(got, ref) / float(np.max(np.abs(np.asarray(ref))))
+        check(err <= 5e-2, f"ssd_scan {at} {name}: max abs err / max |ref| "
+                           f"= {err:.2e} <= 5e-2")
+    peak = peaks.of(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+    moved = op.traffic_bytes(x.shape, groups, state, 2)
+    for name, which, fn, args in (
+            ("p1t_ssd_fwd", "forward", fwd, (x, d, a, b, c, skip)),
+            ("p1t_ssd_bwd", "backward", bwd,
+             (x, d, a, b, c, skip, starts, g))):
+        t = time.perf_counter()
+        for _ in range(10):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        ms = 100 * (time.perf_counter() - t)
+        print(f"chip_smoke: {name} {at}: {ms:.3f} ms a call with XLA's "
+              f"layout of the values a head, {moved[which] / 1e6:.1f} MB "
+              f"the op has to move, {100 * moved[which] / (1e-3 * ms) / peak:.1f}"
+              "% of the HBM roofline (smoke reading on the host's clock, "
+              "not a metric)", flush=True)
+
+    q = jax.random.normal(keys[5], (1, seq, 32, 128), bf16)
+    k, v = (jax.random.normal(key, (1, seq, 2, 128), bf16)
+            for key in keys[6:8])
+    dout = jax.random.normal(keys[8], (1, seq, 32, 128), bf16)
+    kernels_against_blocks(f"[1, {seq}, 32/2, 128] causal", q, k, v, dout)
+
+
 def four_chip_phase(devs):
     """dp=2 x mp=2 with Megatron sharding and zero_stage=2 over four
     chips, then the same model, seed and batch on one."""
@@ -737,6 +803,7 @@ def main():
                              causal_heads=48)
         partial_rotary_phase()
         experts_phase()
+        ssd_scan_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,

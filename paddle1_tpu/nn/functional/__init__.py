@@ -13,4 +13,5 @@ from .attention import (scaled_dot_product_attention, attention_ref,  # noqa: F4
                         paged_attention, rotary_embedding,
                         yarn_frequencies)
 from .short_conv import gated_short_conv  # noqa: F401
+from .ssd import ssd_scan  # noqa: F401
 from .crf import crf_decoding, linear_chain_crf  # noqa: F401

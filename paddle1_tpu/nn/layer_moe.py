@@ -31,7 +31,14 @@ has ``x``'s leading shape; left out, it is ``x``. **The gate's
 activation**: ``E_e(u) = (act(u W_gate_e) * (u W_up_e)) W_down_e`` with
 ``act`` the constructor's ``gate_activation``, ``"silu"`` (SwiGLU) unless
 told ``"relu"`` (ReGLU); the overflow path takes the same one. The shared
-experts stay SwiGLU.
+experts stay SwiGLU. **An expert without a gate** (``gated=False``, the
+``nemotron_h`` family's): ``E_e(u) = act(u W_up_e) W_down_e`` with ``act``
+the same constructor argument, which then also takes ``"relu2"``
+(``relu(.)^2``): the first grouped product's operand is ``up_proj``
+``[held, d_model, width]``, not a fused ``gate_up_proj`` of twice the
+width, and the shared expert has the same form at its own width
+(``nn.PlainFeedForward``). The sort, the gathers, the sum over picks and
+the counters are the gated layer's.
 
 **No token is dropped, shapes are static, and the work follows the picks
 that land here.** The ``tokens x top_k`` picks are sorted by held expert
@@ -119,7 +126,7 @@ from ..ops.pallas import sum_picks as sum_picks_kernel
 from .functional.norm import record_state_update
 from .initializer import Constant
 from .layer_base import Layer
-from .layer_transformer import GatedFeedForward
+from .layer_transformer import GatedFeedForward, PlainFeedForward
 
 __all__ = ["RoutedExperts"]
 
@@ -305,7 +312,8 @@ def grouped_matmul(rows, weights, sizes):
         return jax.lax.ragged_dot(rows, weights, sizes)
 
 
-GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# one table for a gate's activation and for an expert's without a gate
+GATE_ACTIVATIONS = PlainFeedForward.ACTIVATIONS
 
 
 def expert_ffn(xs, sizes, gate_up, down, act=jax.nn.silu):
@@ -316,6 +324,12 @@ def expert_ffn(xs, sizes, gate_up, down, act=jax.nn.silu):
     width = both.shape[-1] // 2
     return grouped_matmul(act(both[:, :width]) * both[:, width:], down,
                           sizes)
+
+
+def plain_expert_ffn(xs, sizes, up, down, act):
+    """Held experts without a gate over their own rows: ``up`` [held,
+    hidden, width], ``down`` [held, width, hidden]."""
+    return grouped_matmul(act(grouped_matmul(xs, up, sizes)), down, sizes)
 
 
 def capacity_rows(tokens, top_k, held, num_experts):
@@ -330,7 +344,9 @@ def capacity_rows(tokens, top_k, held, num_experts):
 class RoutedExperts(Layer):
     """See the module's docstring. ``held = (first, count)``: the experts
     this layer holds, all of them when None. ``gate_activation``: what an
-    expert puts on its gate, ``"silu"`` or ``"relu"``. ``forward(x,
+    expert puts on its gate, ``"silu"`` or ``"relu"``; ``gated=False``: an
+    expert is ``down(act(up(x)))`` with that activation (or ``"relu2"``),
+    and so is the shared one. ``forward(x,
     router_input=None)``: [..., d_model] -> the same shape; the router
     scores ``router_input`` (``x`` where None), the experts compute from
     ``x``."""
@@ -338,13 +354,13 @@ class RoutedExperts(Layer):
     def __init__(self, d_model, expert_width, num_experts, top_k,
                  held=None, shared_width=0, routed_scaling_factor=1.0,
                  weight_attr=None, scoring="sigmoid", norm_eps=1e-20,
-                 gate_activation="silu"):
+                 gate_activation="silu", gated=True):
         super().__init__()
         if scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring={scoring!r}")
         if gate_activation not in GATE_ACTIVATIONS:
             raise ValueError(f"gate_activation={gate_activation!r}")
-        self.gate_activation = gate_activation
+        self.gate_activation, self.gated = gate_activation, gated
         self.num_experts, self.top_k = num_experts, top_k
         self.scoring, self.norm_eps = scoring, norm_eps
         self.first, self.held = held if held is not None else (0, num_experts)
@@ -360,13 +376,19 @@ class RoutedExperts(Layer):
             self.register_buffer("e_score_correction_bias", Tensor(
                 Constant(0.0)([num_experts], "float32"),
                 stop_gradient=True))
-        self.gate_up_proj = self.create_parameter(
-            [self.held, d_model, 2 * expert_width], attr=weight_attr)
+        if gated:
+            self.gate_up_proj = self.create_parameter(
+                [self.held, d_model, 2 * expert_width], attr=weight_attr)
+        else:
+            self.up_proj = self.create_parameter(
+                [self.held, d_model, expert_width], attr=weight_attr)
         self.down_proj = self.create_parameter(
             [self.held, expert_width, d_model], attr=weight_attr)
-        self.shared_experts = (GatedFeedForward(d_model, shared_width,
-                                                weight_attr)
-                               if shared_width else None)
+        self.shared_experts = (
+            None if not shared_width else
+            GatedFeedForward(d_model, shared_width, weight_attr) if gated
+            else PlainFeedForward(d_model, shared_width, gate_activation,
+                                  weight_attr))
         # the module's docstring: rows of each held expert, LOAD_TAIL
         self.register_buffer("expert_load", Tensor(
             jnp.zeros((self.held + len(LOAD_TAIL),), jnp.int32),
@@ -430,8 +452,10 @@ class RoutedExperts(Layer):
             "moe_dispatch", dispatch, (x, weights, chosen))
         self._load_shape = (tokens * k, capacity)
         record_state_update(self.expert_load, load.data, "add")
-        out = apply("routed_experts", expert_ffn,
-                    (xs, sizes, self.gate_up_proj, self.down_proj), act=act)
+        first_proj = self.gate_up_proj if self.gated else self.up_proj
+        out = apply("routed_experts",
+                    expert_ffn if self.gated else plain_expert_ffn,
+                    (xs, sizes, first_proj, self.down_proj), act=act)
 
         def combine(out, ws, order, where, sizes):
             # a row past the groups holds whatever the product left there
@@ -451,8 +475,8 @@ class RoutedExperts(Layer):
                 mine = jnp.sum(jnp.where(late == e, weights, 0.0), -1)
                 both = jnp.dot(x, gate_up[e])
                 width = both.shape[-1] // 2
-                out = jnp.dot(act(both[:, :width]) * both[:, width:],
-                              down[e])
+                out = jnp.dot(act(both[:, :width]) * both[:, width:]
+                              if self.gated else act(both), down[e])
                 y = y + (mine[:, None] * out).astype(y.dtype)
             return y
 
@@ -467,4 +491,4 @@ class RoutedExperts(Layer):
                 y, x, weights, late, gate_up, down)
         return apply("moe_overflow", beyond,
                      (y, x, weights, chosen, where, overflow,
-                      self.gate_up_proj, self.down_proj))
+                      first_proj, self.down_proj))

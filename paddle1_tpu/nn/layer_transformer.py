@@ -17,6 +17,7 @@ import collections
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import Tensor
@@ -27,7 +28,7 @@ from .layer_base import Layer
 from .layer_common import Dropout, Linear
 from .layer_norm_act import LayerNorm, LayerList
 
-__all__ = ["MultiHeadAttention", "GatedFeedForward",
+__all__ = ["MultiHeadAttention", "GatedFeedForward", "PlainFeedForward",
            "TransformerEncoderLayer", "TransformerEncoder",
            "TransformerDecoderLayer", "TransformerDecoder", "Transformer"]
 
@@ -302,6 +303,30 @@ class GatedFeedForward(Layer):
         # nothing reads it (a pre-norm block adds it to the stream) it
         # is not held
         return keep_in_recompute(self.down_proj(s), "gated_ffn_out")
+
+
+class PlainFeedForward(Layer):
+    """``down(act(up(x)))``, a feed-forward without a gate; ``activation``
+    ``"relu2"`` (``relu(.)^2``, the ``nemotron_h`` family's), ``"relu"``
+    or ``"silu"``. Two ``linear`` ops and one ``ffn_activation`` in a
+    trace."""
+
+    ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                   "relu2": lambda u: jnp.square(jax.nn.relu(u))}
+
+    def __init__(self, d_model, dim_feedforward, activation="relu2",
+                 weight_attr=None, bias_attr=False):
+        super().__init__()
+        self.act = self.ACTIVATIONS[activation]
+        self.up_proj = Linear(d_model, dim_feedforward, weight_attr,
+                              bias_attr)
+        self.down_proj = Linear(dim_feedforward, d_model, weight_attr,
+                                bias_attr)
+
+    def forward(self, x):
+        from ..autograd.engine import apply
+        return self.down_proj(apply("ffn_activation", self.act,
+                                    (self.up_proj(x),)))
 
 
 class TransformerEncoderLayer(Layer):

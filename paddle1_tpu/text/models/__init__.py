@@ -11,6 +11,8 @@ from .laguna import (LagunaAttention, LagunaDecoderLayer,
                      LagunaForPretraining, LagunaPretrainingCriterion)
 from .lfm2 import (Lfm2Attention, Lfm2DecoderLayer, Lfm2ForPretraining,
                    Lfm2Head, Lfm2PretrainingCriterion, Lfm2Stack)
+from .nemotron_h import (Mamba2Mixer, NemotronHForPretraining,
+                         NemotronHLayer, NemotronHPretrainingCriterion)
 from .ouro import (OuroDecoderLayer, OuroExitHead, OuroForPretraining,
                    OuroPretrainingCriterion, OuroStack)
 from .sdar import (SdarAttention, SdarBlockDiffusionCriterion,
@@ -34,4 +36,5 @@ __all__ = ["BertModel", "BertForPretraining", "BertPretrainingCriterion",
            "SmallThinkerDecoderLayer", "SmallThinkerForPretraining",
            "SmallThinkerPretrainingCriterion", "LagunaAttention",
            "LagunaDecoderLayer", "LagunaForPretraining",
-           "LagunaPretrainingCriterion"]
+           "LagunaPretrainingCriterion", "Mamba2Mixer", "NemotronHLayer",
+           "NemotronHForPretraining", "NemotronHPretrainingCriterion"]

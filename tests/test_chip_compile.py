@@ -41,7 +41,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle1_tpu.core.flags import flags_guard
 from paddle1_tpu.ops.pallas import (_common, flash_attention, fused_bn,
                                     layer_norm, mask_rules, paged_attention,
-                                    short_conv, softmax, sum_picks)
+                                    short_conv, softmax, ssd_scan, sum_picks)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -156,6 +156,22 @@ def _short_conv(grad=False, dtype=BF16):
     return build
 
 
+def _ssd(grad=False, dtype=BF16, keep_states=True):
+    def build(batch, seq, heads, width, groups, state=128):
+        x = ((batch, seq, heads, width), dtype)
+        per_head = ((batch, seq, heads), F32)
+        group = ((batch, seq, groups, state), dtype)
+        head = ((heads,), F32)
+        operands = [x, per_head, head, group, group, head]
+        if grad:
+            starts = ((batch, seq // 128, groups, state,
+                       heads // groups * width), F32)
+            return ssd_scan.backward, operands + [starts, x]
+        return (lambda *o: ssd_scan.forward(*o, keep_states=keep_states),
+                operands)
+    return build
+
+
 B32_S128 = (32, 128, 12, 64)
 B8_S512 = (8, 512, 12, 64)
 OURO = (2, 4096, 16, 128)   # ouro_2p6b.pretrain_s4096's attention call
@@ -170,6 +186,12 @@ SDAR_RULE = mask_rules.BlockDiffusion(8192, 4)
 # multiple of the 128-lane tile: the [B*H, N, D] layout for q, k, dq, dk),
 # 32 query heads over 8, twice the longest causal row of any other cell
 LFM2 = (1, 16384, 32, 64)
+
+# nemotron3_nano_30b_a3b.pretrain_s8192's: the one attention layer's 32
+# query heads over 2 (groups of 16), and the four Mamba-2 layers' scan,
+# 64 heads of 64 over 8 groups of state 128
+NEMOTRON3 = (1, 8192, 32, 128)
+NEMOTRON3_SCAN = (1, 8192, 64, 64, 8)
 
 # smallthinker_21b_a3b.pretrain_s16384's attention calls: 28 query heads
 # over 4 (groups of 7, the first that is no power of two), under the
@@ -234,6 +256,19 @@ CASES = {
     "flash_window_masked_grad_s2048_h7_kv1_w300":
         lambda: _flash(mask=mask_rules.SlidingWindow(300), kv_heads=1,
                        masked=True, grad=True)(2, 2048, 7, 128),
+    "flash_causal_nemotron3_s8192_h32_kv2":
+        lambda: _flash(causal=True, kv_heads=2)(*NEMOTRON3),
+    "flash_causal_grad_nemotron3_s8192_h32_kv2":
+        lambda: _flash(causal=True, kv_heads=2, grad=True)(*NEMOTRON3),
+    # Nemotron 3 Nano's scan: the forward with and without the states it
+    # keeps for the backward, the backward; and float32 operands, two rows
+    "ssd_fwd_nemotron3_8192x64x64_g8": lambda: _ssd()(*NEMOTRON3_SCAN),
+    "ssd_fwd_alone_nemotron3_8192x64x64_g8":
+        lambda: _ssd(keep_states=False)(*NEMOTRON3_SCAN),
+    "ssd_bwd_nemotron3_8192x64x64_g8":
+        lambda: _ssd(grad=True)(*NEMOTRON3_SCAN),
+    "ssd_bwd_f32_2x1024x8x64_g2":
+        lambda: _ssd(grad=True, dtype=F32)(2, 1024, 8, 64, 2),
     # LFM2's gated short convolution: one 16k row of 2048 channels, 3 taps
     "short_conv_fwd_lfm2_16384x2048": lambda: _short_conv()(1, 16384, 2048),
     "short_conv_bwd_lfm2_16384x2048":
@@ -1130,3 +1165,73 @@ def test_gspmd_step_takes_the_xla_composition(topo, for_the_chip,
         jax.jit(ln).lower(x, wb, wb)
     text = jax.jit(ln_gspmd).lower(x, wb, wb).compile().as_text()
     assert "tpu_custom_call" not in text
+
+
+def test_a_recomputed_mamba2_layer_runs_the_scan_kernels_and_writes_no_decays(
+        one_chip, for_the_chip, monkeypatch):
+    """A Mamba-2 layer of Nemotron 3 Nano's step at the cell's shape ([1,
+    8192, 2688] bf16; 64 heads of 64 over 8 groups of state 128, 4 taps)
+    under ``fleet.utils.recompute``, loss and gradients (ISSUE 50): the
+    scan is the two kernels under the op's scope inside the mixer's, the
+    forward one run again in the recomputed segment (nothing of the layer
+    carries a name), no ``[chunks, heads, 128, 128]`` float32 decays are
+    written, and the projections, the convolution and the gated norm lie
+    under their scopes."""
+    from paddle1_tpu.obs import costmodel
+    from paddle1_tpu.text.models.nemotron_h import NemotronHLayer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = NemotronHLayer(2688, "M", dict(
+        num_heads=64, head_dim=64, n_groups=8, state_size=128))
+    text = _recomputed(one_chip, [layer], (1, 8192, 2688))
+    assert not re.search(r"f32\[(\d+,)*64,(\d+,)?128,128\]", text)
+    scopes, _ = costmodel.parse_op_scopes(text)
+    kernels = sorted((re.sub(r"\.\d+$", "", n), costmodel.region_of(s),
+                      "rematted_computation" in s)
+                     for n, s in scopes.items() if n.startswith("p1t_ssd"))
+    assert kernels == [("p1t_ssd_bwd", "backward", False),
+                       ("p1t_ssd_fwd", "backward", True),
+                       ("p1t_ssd_fwd", "forward", False)], kernels
+    assert all("/mamba/ssd_scan/" in s for n, s in scopes.items()
+               if n.startswith("p1t_ssd"))
+    for part in ("norm/rms_norm", "mamba/in_proj/linear",
+                 "mamba/conv/causal_conv_silu", "mamba/ssd_scan",
+                 "mamba/gated_norm/gated_rms_norm", "mamba/out_proj/linear"):
+        assert {costmodel.region_of(s) for s in scopes.values()
+                if "/" + part in s} >= {"forward", "backward"}, part
+
+
+@pytest.mark.slow
+def test_the_nemotron3_cells_step_fits_a_described_v5e(one_chip, for_the_chip,
+                                                       monkeypatch):
+    """The whole step of ``nemotron3_nano_30b_a3b.pretrain_s8192`` as the
+    benchmark builds it, compiled for a described v5e (a minute and a
+    half: ``slow``; ``benchmarks/tools/aot_compile.py`` prints the same):
+    arguments + temporaries + the harness's 4 bytes a parameter stay
+    under the chip's 15.75 GiB, and both scan kernels are in the text."""
+    from benchmarks import spec, traffic
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cell = spec.load_json("workloads",
+                          "nemotron3_nano_30b_a3b.pretrain_s8192.json")
+    cfg = spec.config(cell["config"])
+    env = traffic.environment(cfg, cell)
+    program, reference = (spec.module(k, cfg) for k in ("program",
+                                                        "reference"))
+    w = jax.jit(lambda k: reference.init_params(cfg, k))(jax.random.key(0))
+    engine = program.build(
+        cfg, env, {p: (w[r] if i is None else w[r][i])
+                   for p, r, i in program.leaves(cfg)},
+        jax.devices()[:1])["engine"]
+    batch = traffic.batches(cell, env, 0, 1)[0]
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (engine.params, engine.opt_state,
+         {k: jnp.asarray(v) for k, v in batch.items()},
+         jax.random.key(0), jnp.float32(0)))
+    compiled = jax.jit(engine._step_fn, donate_argnums=(0, 1)).lower(
+        *args).compile()
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + 4 * cfg["parameters"])
+    assert held < 15.75 * 2 ** 30, held / 2 ** 30
+    text = compiled.as_text()
+    assert "p1t_ssd_fwd" in text and "p1t_ssd_bwd" in text

@@ -233,8 +233,9 @@ def test_every_pallas_call_site_has_a_name_of_its_own():
             assert found, f"{os.path.basename(path)}: pallas_call at " \
                 f"offset {at} has no name"
             names.append(found.group(1))
-    # 9 until PR 38, which added the gated short convolution's two
-    assert len(names) == 11 and len(set(names)) == len(names), names
+    # 9 until PR 38, which added the gated short convolution's two; PR 50
+    # the state-space scan's two
+    assert len(names) == 13 and len(set(names)) == len(names), names
     assert all(re.match(r"^p1t_[a-z0-9]+(_[a-z0-9]+)*_(fwd|bwd)", n)
                for n in names), names
 
