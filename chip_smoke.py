@@ -21,10 +21,13 @@ slices in float32, the two state-space scan kernels run at Nemotron 3
 Nano's size ([1, 8192, 64 heads of 64, 8 groups of state 128]) against
 the chunked composition in float32 with each one's share of the HBM
 roofline, beside the causal rule with 16 query heads a key/value head
-([1, 8192, 32 / 2, 128]), and the routed-expert layer takes more
+([1, 8192, 32 / 2, 128]), the routed-expert layer takes more
 held picks than its grouped products have rows and counts the late ones
 on the device, eagerly and in two compiled steps
-(``ParallelEngine.expert_load()``). Every
+(``ParallelEngine.expert_load()``), and the grouped products' three
+kernels run at Nemotron 3 Nano's two shapes ([9216, 2688] x [8, 2688,
+1856] and back) against ``lax.ragged_dot`` and its transposes, each timed
+beside XLA's own at a third of the rows held and at all of them. Every
 check that fails raises: no phase may fail and the script still exit 0,
 and no kernel gives way to its reference. One process, no child that
 needs the chip.
@@ -664,6 +667,69 @@ def experts_phase(tokens=4096, hidden=512, width=256):
 
 # -- four chips -------------------------------------------------------------
 
+def grouped_products_phase(rows=9216, groups=8, hidden=2688, width=1856):
+    """The expert layer's grouped products at Nemotron 3 Nano's two
+    shapes, where XLA's own grouped kernel tiles both widths at 128: the
+    repo's three kernels (``ops/pallas/grouped_matmul.py``) against
+    ``lax.ragged_dot`` and its transposes on the rows that hold a pick,
+    ms a call of each at a third of the rows held and at all of them,
+    and the arm ``layer_moe.grouped_matmul`` takes by the shape."""
+    import jax
+    import jax.numpy as jnp
+    from paddle1_tpu.nn import layer_moe
+    from paddle1_tpu.obs import registry
+    from paddle1_tpu.ops.pallas import grouped_matmul as kernels
+    bf16 = jnp.bfloat16
+    share = np.random.default_rng(0).dirichlet(np.full(groups, 40.0))
+    fills = {held: jnp.asarray(np.diff(np.floor(
+        np.concatenate([[0], np.cumsum(share)]) * held)).astype(np.int32))
+        for held in (rows // 3, rows)}
+
+    def forms(product):
+        def all_three(x, w, d, sizes):
+            y, vjp = jax.vjp(lambda x, w: product(x, w, sizes), x, w)
+            return (y,) + vjp(d)
+        return [jax.jit(lambda *a, i=i: all_three(*a)[i]) for i in range(3)]
+    for k, n in ((hidden, width), (width, hidden)):
+        at = f"[{rows}, {k}] x [{groups}, {k}, {n}]"
+        keys = jax.random.split(jax.random.key(k), 3)
+        x = jax.random.normal(keys[0], (rows, k), bf16)
+        w = (0.02 * jax.random.normal(keys[1], (groups, k, n))).astype(bf16)
+        d = jax.random.normal(keys[2], (rows, n), bf16)
+        check(kernels.supported(x, w), f"grouped product {at} takes the "
+                                       "repo's kernels")
+        ms, rows_held = {}, {}
+        for arm, fns in (("xla", forms(jax.lax.ragged_dot)),
+                         ("kernel", forms(kernels.grouped_matmul))):
+            for held, sizes in fills.items():
+                for name, fn in zip(("product", "dx", "dw"), fns):
+                    out = jax.block_until_ready(fn(x, w, d, sizes))
+                    t = time.perf_counter()
+                    for _ in range(10):
+                        out = fn(x, w, d, sizes)
+                    jax.block_until_ready(out)
+                    ms[arm, held, name] = 100 * (time.perf_counter() - t)
+                    if held == rows // 3:
+                        rows_held[arm, name] = np.asarray(
+                            out if name == "dw" else out[:held], np.float32)
+        for name in ("product", "dx", "dw"):
+            ref = rows_held["xla", name]
+            err = (max_err(rows_held["kernel", name], ref)
+                   / float(np.abs(ref).max()))
+            check(err <= 2e-2, f"grouped {name} {at}: max abs err / max "
+                               f"|ragged_dot's| = {err:.2e} <= 2e-2")
+            print(f"chip_smoke: grouped {name} {at}: " + ", ".join(
+                f"{held} held rows {ms['kernel', held, name]:.3f} ms a call "
+                f"(XLA's own {ms['xla', held, name]:.3f})"
+                for held in fills) + " (smoke readings on the host's "
+                "clock, not a metric)", flush=True)
+        registry.reset_process_registry()
+        jax.make_jaxpr(layer_moe.grouped_matmul)(x, w, fills[rows])
+        check('moe_grouped_matmul_arm_total{arm="kernel"} 1'
+              in registry.render_process_groups(),
+              f"layer_moe.grouped_matmul {at} counts the kernel arm")
+
+
 def ssd_scan_phase(seq=8192, heads=64, width=64, groups=8, state=128):
     """Nemotron 3 Nano's two mixers at its size: the two state-space scan
     kernels ([1, 8192, 64, 64] bf16 over 8 groups of state 128) against
@@ -803,6 +869,7 @@ def main():
                              causal_heads=48)
         partial_rotary_phase()
         experts_phase()
+        grouped_products_phase()
         ssd_scan_phase()
         count = len(devs)
     print(json.dumps({"ok": True, "device": {

@@ -100,12 +100,33 @@ against a ``top_k``, two sorts and two gathers of scalars (3 ms a layer;
 PERF.md, PR 37). The gathers of whole rows and the grouped products run
 again: they are cheap for their bytes.
 
+**The grouped products have two arms, and the operands' shape chooses**
+(:func:`grouped_matmul`). The TPU's compiler lowers ``lax.ragged_dot`` to
+a kernel of its own that walks the (row tile of 512, group) pairs that
+hold a row (not the capacity: a fill of a third of the rows takes a third
+of the time) with each width tiled at the largest power of two up to 512
+that divides it. At 512-wide tiles a pair runs at the matrix unit's peak
+(Kanana-2, SDAR, LFM2, SmallThinker, Laguna-XS.2: arm ``xla``). Where a
+width falls to 128 (Nemotron 3 Nano's 2688 = 21 x 128 and 1856 = 29 x 64:
+a pair is 21 x 15 grid steps of 17 MFLOP, five times slower than the
+pairs' work; PERF.md section 6, PR 51) the products, and their two
+transposes, are ``ops/pallas/grouped_matmul.py``'s: the same pairs at
+tiles that fit the widths, whole where they fit VMEM (arm ``kernel``).
+No flag, argument or model's name: ``supported(rows, weights)`` there
+reads the widths. Which arm a traced product took is counted in the
+process registry: ``p1t_moe_grouped_matmul_arm_total{arm="kernel"|"xla"}``
+(two a layer's forward, three under ``fleet.utils.recompute``, whose
+segment jax traces again for the backward pass).
+
 Names in a traced step: everything under the scope ``moe``; ops
 ``moe_router`` (float32 whatever the autocast), ``moe_dispatch`` (sort,
 gather; backward, the kernel ``p1t_sum_picks_fwd``), ``routed_experts``
-(the grouped products), ``moe_combine`` (the same kernel),
-``moe_overflow``; the shared experts a ``GatedFeedForward`` named
-``shared_experts``.
+(the grouped products: XLA's ``ragged-dot-none``, which carries the scope
+as a frontend attribute, or the kernels ``p1t_grouped_matmul_fwd``,
+``p1t_grouped_matmul_bwd_dx`` and ``p1t_grouped_matmul_bwd_dw``, which carry it in
+their ``op_name``), ``moe_combine`` (``p1t_sum_picks_fwd`` again),
+``moe_overflow``; the shared experts a ``GatedFeedForward`` (without a
+gate: a ``PlainFeedForward``) named ``shared_experts``.
 """
 
 from __future__ import annotations
@@ -122,6 +143,7 @@ from ..core.recompute_keeps import keep_in_recompute
 from ..core.tensor import Tensor
 from ..obs.costmodel import SCOPE_ATTRIBUTE
 from ..obs.registry import process_group
+from ..ops.pallas import grouped_matmul as grouped_kernel
 from ..ops.pallas import sum_picks as sum_picks_kernel
 from .functional.norm import record_state_update
 from .initializer import Constant
@@ -304,10 +326,29 @@ sum_of_picks.defvjp(_sum_fwd, _sum_bwd)
 def grouped_matmul(rows, weights, sizes):
     """``rows`` [m, k] x ``weights`` [groups, k, n] -> [m, n]: the first
     ``sizes[0]`` rows through ``weights[0]`` and so on; rows past the
-    groups are not to be read. The TPU's compiler lowers the product, and
-    autodiff's two transposes of it, to a grouped kernel of its own under
-    its own name (``ragged-dot-none``) and drops the scope; the frontend
-    attribute it keeps says where ``obs.costmodel`` is to count them."""
+    groups are not to be read, and what stands in their output is not
+    defined.
+
+    Two arms, chosen by the operands' shape. **``xla``**: the TPU's
+    compiler lowers ``lax.ragged_dot``, and autodiff's two transposes of
+    it, to a grouped kernel of its own under its own name
+    (``ragged-dot-none``) and drops the scope; the frontend attribute it
+    keeps says where ``obs.costmodel`` is to count them. That kernel walks
+    the (row tile of 512, group) pairs that hold a row, each width tiled
+    at the largest power of two up to 512 that divides it, and runs at
+    the matrix unit's peak a pair at 512-wide tiles. **``kernel``**:
+    where it would tile a width under 256 (2688 and 1856 both fall to
+    128, and the grid steps' own overhead sets the time),
+    ``ops/pallas/grouped_matmul.py`` runs the same pairs with tiles that
+    fit the widths (``supported`` there has the rule; a step that XLA
+    partitions by itself stays with ``xla``). Which arm a traced call
+    took is counted: ``p1t_moe_grouped_matmul_arm_total{arm}``."""
+    kernel = (grouped_kernel.supported(rows, weights)
+              and not in_auto_partitioned_region())
+    process_group("arm").child("kernel" if kernel else "xla") \
+        .counter("moe_grouped_matmul_arm_total").inc()
+    if kernel:
+        return grouped_kernel.grouped_matmul(rows, weights, sizes)
     with set_xla_metadata(**{SCOPE_ATTRIBUTE: "moe/routed_experts"}):
         return jax.lax.ragged_dot(rows, weights, sizes)
 

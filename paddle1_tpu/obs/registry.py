@@ -516,6 +516,11 @@ def process_group(label_key) -> MetricsGroup:
     expert layer's sum of a token's picks is traced on rows: ``kernel``
     where ``ops/pallas/sum_picks.py`` takes the rows' width, ``gather``
     where an index for every pick does; ``nn/layer_moe.py::_sum_picks``),
+    ``moe_grouped_matmul_arm_total{arm}`` (counted when one of the expert
+    layer's grouped products is traced: ``kernel`` where
+    ``ops/pallas/grouped_matmul.py`` takes the operands' widths, ``xla``
+    where the compiler's own grouped kernel does;
+    ``nn/layer_moe.py::grouped_matmul``),
     ``recompute_kept_bytes_total{name}`` and ``recompute_
     kept_values_total{name}`` (what each traced ``fleet.utils.recompute``
     segment was given to keep, by the shapes of the values named inside:

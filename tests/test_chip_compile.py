@@ -40,8 +40,9 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle1_tpu.core.flags import flags_guard
 from paddle1_tpu.ops.pallas import (_common, flash_attention, fused_bn,
-                                    layer_norm, mask_rules, paged_attention,
-                                    short_conv, softmax, ssd_scan, sum_picks)
+                                    grouped_matmul, layer_norm, mask_rules,
+                                    paged_attention, short_conv, softmax,
+                                    ssd_scan, sum_picks)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -145,6 +146,17 @@ def _sum_picks(rows, hidden, tokens, fan, dtype=BF16):
             [((rows, hidden), dtype), ((tokens * fan,), I32)])
 
 
+def _grouped(form, rows, k, n, groups, dtype=BF16):
+    """One form of the grouped product: the other two kernels of the
+    ``custom_vjp`` feed nothing that is returned and go."""
+    def fn(x, w, d, sizes):
+        y, vjp = jax.vjp(
+            lambda x, w: grouped_matmul.grouped_matmul(x, w, sizes), x, w)
+        return y if form == "product" else vjp(d)[form == "dw"]
+    return fn, [((rows, k), dtype), ((groups, k, n), dtype),
+                ((rows, n), dtype), ((groups,), I32)]
+
+
 def _short_conv(grad=False, dtype=BF16):
     def build(batch, seq, channels, taps=3):
         bcx = ((batch, seq, 3 * channels), dtype)
@@ -192,6 +204,8 @@ LFM2 = (1, 16384, 32, 64)
 # 64 heads of 64 over 8 groups of state 128
 NEMOTRON3 = (1, 8192, 32, 128)
 NEMOTRON3_SCAN = (1, 8192, 64, 64, 8)
+# its experts' two grouped products: [9216, k] x [8, k, n]
+NEMOTRON3_EXPERTS = {"up": (2688, 1856), "down": (1856, 2688)}
 
 # smallthinker_21b_a3b.pretrain_s16384's attention calls: 28 query heads
 # over 4 (groups of 7, the first that is no power of two), under the
@@ -302,6 +316,15 @@ CASES = {
     # the most picks supported() admits: SMEM's worst case
     "sum_picks_8192x2048_t32768_top6":
         lambda: _sum_picks(8192, 2048, 32768, 6),
+    # Nemotron 3 Nano's two grouped products, each in its three forms
+    # (ISSUE 51): 9216 rows, 8 held experts, 2688 -> 1856 -> 2688; and
+    # float32 operands, whose widths go in tiles
+    **{"grouped_%s_nemotron3_%s_9216x%dx%d" % (form, which, k, n):
+       (lambda form=form, k=k, n=n: _grouped(form, 9216, k, n, 8))
+       for form in ("product", "dx", "dw")
+       for which, (k, n) in NEMOTRON3_EXPERTS.items()},
+    "grouped_dw_f32_1024x2688x1856_g4":
+        lambda: _grouped("dw", 1024, 2688, 1856, 4, F32),
     "paged_w1_h12_d64_p16": lambda: _paged(1),
     "paged_w4_h12_d64_p16": lambda: _paged(4),
 }
@@ -437,12 +460,13 @@ def test_the_census_of_replicated_statistics_sees_the_parents_form():
           "f32[64,16384,128]"]
 
 
-def _scoped_vmem(call, which=""):
+def _scoped_vmem(call, which="", offset="0"):
     """The scoped VMEM a compiled kernel instruction was allowed
-    (``which`` ""), or what Mosaic used of it ("used_")."""
+    (``which`` ""; behind scalar-prefetched operands it starts at an
+    ``offset`` of its own), or what Mosaic used of it ("used_")."""
     return int(re.search(
-        r'"%sscoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
-        r'"size":"(\d+)"' % which, call).group(1))
+        r'"%sscoped_memory_configs":\[\{"memory_space":"1","offset":"%s",'
+        r'"size":"(\d+)"' % (which, offset), call).group(1))
 
 
 @pytest.mark.parametrize("cell", sorted(BACKWARD_CALLS))
@@ -1095,6 +1119,127 @@ def test_the_grouped_products_keep_their_scope(one_chip, for_the_chip):
                     for w in where)
     assert passes == ([("backward", False)] * 4 + [("backward", True)]
                       + [("forward", False)] * 2), where
+
+
+@pytest.mark.parametrize("which", sorted(NEMOTRON3_EXPERTS))
+@pytest.mark.parametrize("form", ["product", "dx", "dw"])
+def test_a_grouped_product_stays_inside_the_vmem_it_asks_for(
+        form, which, one_chip, for_the_chip):
+    """Each form at Nemotron 3 Nano's two shapes asks for its blocks'
+    bytes (``_block_bytes`` of the tiles ``_tiles`` reads off the shape)
+    and the slack beside them, under half the v5e's 128 MiB, and Mosaic
+    uses less; its table is four scalar-prefetched operands, a step for
+    each of the ``9216 / 256 + 8 - 1`` pairs a fill can hold."""
+    k, n = NEMOTRON3_EXPERTS[which]
+    fn, args = _grouped(form, 9216, k, n, 8)
+    text = jax.jit(fn).lower(*[jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                               for s, dt in args]).compile().as_text()
+    (name, call), = _kernel_calls(text)
+    assert name == {"product": "p1t_grouped_matmul_fwd",
+                    "dx": "p1t_grouped_matmul_bwd_dx",
+                    "dw": "p1t_grouped_matmul_bwd_dw"}[form]
+    tiles = (grouped_matmul._tiles(9216, n, k, 2) if form == "dx" else
+             grouped_matmul._tiles(9216, k, n, 2, dw=form == "dw"))
+    assert tiles[0] == 256 and (form == "dw" or tiles[1:] == (
+        (n, k) if form == "dx" else (k, n)))       # both widths whole
+    allowed, used = _scoped_vmem(call, offset=r"\d+"), _scoped_vmem(
+        call, "used_")
+    assert allowed == (grouped_matmul._block_bytes(*tiles, 2, form == "dw")
+                       + grouped_matmul._VMEM_SLACK) < 64 << 20
+    assert 0 < used < allowed
+    assert call.count("s32[43]{0}") >= 2 and "s32[9]{0}" in call
+
+
+def test_nemotrons_expert_segment_runs_the_repos_grouped_kernels(
+        one_chip, for_the_chip):
+    """The twin of the case above at Nemotron 3 Nano's widths (9216 rows,
+    8 held experts without a gate, 2688 -> 1856 -> 2688), where XLA's own
+    kernel would tile both widths at 128 (ISSUE 51): the segment holds no
+    ``ragged-dot-none``; its seven products are the repo's kernels, each
+    under the expert layer's scope in its pass (two forward, the first
+    again in the recomputed segment, ``dx`` and ``dw`` of each backward),
+    so ``routed_experts_ms`` keeps reading them."""
+    from paddle1_tpu.nn import layer_moe
+    from paddle1_tpu.obs import costmodel
+
+    @jax.checkpoint
+    def segment(xs, up, down, sizes):
+        with jax.named_scope("moe"):
+            with jax.named_scope("moe_dispatch"):
+                xs = xs * 2
+            with jax.named_scope("routed_experts"):
+                out = layer_moe.plain_expert_ffn(
+                    xs, sizes, up, down, layer_moe.GATE_ACTIVATIONS["relu2"])
+            with jax.named_scope("moe_combine"):
+                return out * 3
+
+    def loss(xs, up, down, sizes):
+        with jax.named_scope("loss"), jax.named_scope("Model"):
+            return jnp.sum(segment(xs, up, down, sizes).astype(F32))
+
+    def s(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            s((9216, 2688)), s((8, 2688, 1856)), s((8, 1856, 2688)),
+            s((8,), I32)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    scopes, _ = costmodel.parse_op_scopes(text)
+    calls = [(kernel, scopes[re.search(r"%(\S+) = ", line).group(1)])
+             for kernel, line in _kernel_calls(text)]
+    assert all("/moe/routed_experts/" in where for _, where in calls), calls
+    assert sorted((kernel, costmodel.region_of(where),
+                   "rematted_computation" in where)
+                  for kernel, where in calls) == [
+        ("p1t_grouped_matmul_bwd_dw", "backward", False),
+        ("p1t_grouped_matmul_bwd_dw", "backward", False),
+        ("p1t_grouped_matmul_bwd_dx", "backward", False),
+        ("p1t_grouped_matmul_bwd_dx", "backward", False),
+        ("p1t_grouped_matmul_fwd", "backward", True),
+        ("p1t_grouped_matmul_fwd", "forward", False),
+        ("p1t_grouped_matmul_fwd", "forward", False)], calls
+
+
+# ``lax.ragged_dot``s [rows, k] x [groups, k, n]: each expert cell's two
+# products, and ISSUE 51's two probes
+RAGGED_DOTS = {
+    "nemotron3_up": (9216, 2688, 1856, 8),
+    "nemotron3_down": (9216, 1856, 2688, 8),
+    "kanana2_up": (36864, 2048, 1536, 16),
+    "kanana2_down": (36864, 768, 2048, 16),     # sdar's too, at 49152 rows
+    "sdar_up": (49152, 2048, 1536, 16),
+    "lfm2_up": (24576, 2048, 3072, 8),
+    "lfm2_down": (24576, 1536, 2048, 8),
+    "smallthinker_up": (36864, 2560, 1536, 8),
+    "smallthinker_down": (36864, 768, 2560, 8),
+    "laguna_up": (24576, 2048, 1024, 16),
+    "laguna_down": (24576, 512, 2048, 16),
+    "probe_2560x1792": (9216, 2560, 1792, 8),
+    "probe_2688x2048": (9216, 2688, 2048, 8),
+}
+
+
+@pytest.mark.parametrize("product", sorted(RAGGED_DOTS))
+def test_xla_tile_is_the_tile_xla_gives_a_width(product, one_chip):
+    """``grouped_matmul.xla_tile`` decides which arm a cell's products
+    take, so it is held to the compiler it speaks for: the TPU's grouped
+    kernel says its tiles on its instruction (``ragged_dot_tiling="m,k,
+    n"``), a row tile of 512 and each width at ``xla_tile`` of it. A JAX
+    that changes the rule fails here and moves no cell in silence."""
+    rows, k, n, groups = RAGGED_DOTS[product]
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.lax.ragged_dot).lower(
+            jax.ShapeDtypeStruct((rows, k), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((groups, k, n), BF16, sharding=one_chip),
+            jax.ShapeDtypeStruct((groups,), I32, sharding=one_chip)
+        ).compile().as_text()
+    assert set(re.findall(r'ragged_dot_tiling="([\d,]+)"', text)) == {
+        "512,%d,%d" % (grouped_matmul.xla_tile(k), grouped_matmul.xla_tile(n))}
+    up_or_down = jax.ShapeDtypeStruct((rows, k), BF16), \
+        jax.ShapeDtypeStruct((groups, k, n), BF16)
+    assert grouped_matmul.supported(*up_or_down) == (
+        min(grouped_matmul.xla_tile(k), grouped_matmul.xla_tile(n)) < 256)
 
 
 def test_sum_picks_supported_admits_only_what_fits():

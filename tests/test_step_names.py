@@ -234,8 +234,9 @@ def test_every_pallas_call_site_has_a_name_of_its_own():
                 f"offset {at} has no name"
             names.append(found.group(1))
     # 9 until PR 38, which added the gated short convolution's two; PR 50
-    # the state-space scan's two
-    assert len(names) == 13 and len(set(names)) == len(names), names
+    # the state-space scan's two; PR 51 the grouped product's two sites
+    # (the product's is ``dx``'s too, under a third name)
+    assert len(names) == 15 and len(set(names)) == len(names), names
     assert all(re.match(r"^p1t_[a-z0-9]+(_[a-z0-9]+)*_(fwd|bwd)", n)
                for n in names), names
 
